@@ -255,6 +255,7 @@ class ImagePipeline {
       std::lock_guard<std::mutex> lk(mu_);
       queue_.clear();
       workers_done_ = 0;
+      real_done_ = 0;
       error_.clear();
       pending_.clear();
       stream_end_ = false;
@@ -396,10 +397,19 @@ class ImagePipeline {
       emitted_.fetch_add(1, std::memory_order_relaxed);
       cv_pop_.notify_one();
     }
-    // equal steps across shards: claim and emit count=0 pad batches until
-    // this shard reaches the per-epoch target (consumers treat count as
-    // the real sample count, so metrics skip the padding)
-    while (cfg_.target_batches >= 0) {
+    // equal steps across shards: emit count=0 pad batches until this shard
+    // reaches the per-epoch target (consumers treat count as the real
+    // sample count, so metrics skip the padding).  Only the LAST worker to
+    // run out of records pads: emitted_ counts a real batch when it is
+    // pushed, so a worker that finished early would otherwise see a count
+    // that is still short of the target while another worker is decoding
+    // the final real batch, and pad one batch too many.
+    bool last_worker;
+    {
+      std::lock_guard<std::mutex> lk(mu_);
+      last_worker = (++real_done_ == cfg_.num_threads);
+    }
+    while (last_worker && cfg_.target_batches >= 0) {
       int64_t cur = emitted_.load(std::memory_order_relaxed);
       if (cur >= cfg_.target_batches ||
           stop_.load(std::memory_order_relaxed))
@@ -519,6 +529,7 @@ class ImagePipeline {
   std::atomic<bool> stop_{false};
   bool stream_end_ = false;
   int workers_done_ = 0;
+  int real_done_ = 0;  // workers that ran out of records this epoch (mu_)
   int epoch_ = 0;
   std::string error_;
 };

@@ -27,6 +27,7 @@ class NativeUnsupportedError(ValueError):
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_build_error = None
 
 _CPP_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                         "cpp")
@@ -132,7 +133,7 @@ def _declare(lib):
 
 def lib():
     """The loaded native library, or None if unavailable."""
-    global _lib, _tried
+    global _lib, _tried, _build_error
     if _lib is not None or _tried:
         return _lib
     with _lock:
@@ -146,22 +147,26 @@ def lib():
         try:
             subprocess.run(["make", "-C", _CPP_DIR], check=True,
                            capture_output=True, timeout=300)
-        except subprocess.CalledProcessError as e:
-            import logging
-            logging.getLogger("mxnet_tpu").error(
-                "native runtime build failed (make -C %s):\n%s",
-                _CPP_DIR, (e.stderr or b"").decode(errors="replace")[-2000:])
-            return None
-        except Exception as e:
-            import logging
-            logging.getLogger("mxnet_tpu").error(
-                "native runtime build failed: %s", e)
-            return None
-        try:
             _lib = _declare(ctypes.CDLL(_LIB_PATH))
-        except OSError:
+        except Exception as e:
+            # kept for build_error(): optional users (the host engine, the
+            # raw record reader) carry on without the library, a caller
+            # that ASKED for a native path raises it
+            detail = (getattr(e, "stderr", None) or b"").decode(
+                errors="replace")[-2000:]
+            _build_error = (f"native runtime build failed "
+                            f"(make -C {_CPP_DIR}): {e}\n{detail}")
+            import logging
+            logging.getLogger("mxnet_tpu").error("%s", _build_error)
             _lib = None
         return _lib
+
+
+def build_error():
+    """Why :func:`lib` has no library although one was wanted: the failed
+    build's message, or None (library loaded, or ``MXTPU_NO_NATIVE`` set)."""
+    lib()
+    return _build_error
 
 
 def last_error() -> str:

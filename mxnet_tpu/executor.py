@@ -115,6 +115,7 @@ class Executor:
         self._cached_grads: Optional[Dict[str, object]] = None
         self._monitor_callback = None
         self._jit_cache: Dict[tuple, object] = {}
+        self._fused_probe = None  # (program, arg shapes) of the last fused_step
         # SPMD data-parallel annotation (set_spmd): when a mesh is attached,
         # fused_step compiles ONE shard_map program over it — batch args
         # sharded on the dp axis, params/optimizer state replicated+donated,
@@ -938,8 +939,6 @@ class Executor:
             elif spmd:
                 from jax.sharding import PartitionSpec as P
 
-                from .parallel.collectives import shard_map_compat
-
                 mesh = self._spmd_mesh
                 out_is_batch = list(self._spmd_out_is_batch)
 
@@ -987,10 +986,10 @@ class Executor:
                         # replica-invariant scalars (norms on the allreduced
                         # grads, pmean'd loss): replicated out-spec
                         out_specs = out_specs + (P(),)
-                    return shard_map_compat(
+                    return jax.shard_map(
                         shard_step, mesh=mesh,
                         in_specs=in_specs,
-                        out_specs=out_specs, check=False)(
+                        out_specs=out_specs, check_vma=False)(
                         pvals, gvals, svals, batch_vals, const_vals,
                         aux_vals, lr_vec, wd, t_vec, rng, *sc)
 
@@ -1147,14 +1146,23 @@ class Executor:
                         (pvals, gvals, svals, other, aux_vals, sc_args),
                         repl)
             self._spmd_active = True
-            with _tracing.span("executor.fused_step", cat="executor"):
-                res = fn(pvals, gvals, svals, batch_vals, other, aux_vals,
-                         lr_vec, wd, t_vec, rng, *sc_args)
+            args = (pvals, gvals, svals, batch_vals, other, aux_vals,
+                    lr_vec, wd, t_vec, rng, *sc_args)
         else:
             pvals, gvals, svals = uniquify_donated((pvals, gvals, svals))
-            with _tracing.span("executor.fused_step", cat="executor"):
-                res = fn(pvals, gvals, svals, other, aux_vals, lr_vec, wd,
-                         t_vec, rng, *sc_args)
+            args = (pvals, gvals, svals, other, aux_vals, lr_vec, wd,
+                    t_vec, rng, *sc_args)
+        if self._fused_probe is None or self._fused_probe[0] is not fn:
+            # once per program: the argument shapes, for fused_step_hlo()
+            # (placement only where it was chosen: an uncommitted array
+            # follows the others, as in the call itself)
+            self._fused_probe = (fn, jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=a.sharding if getattr(a, "committed", False)
+                    else None), args))
+        with _tracing.span("executor.fused_step", cat="executor"):
+            res = fn(*args)
         if tele_on:
             res, tele_vals = res[:-1], res[-1]
             self._note_telemetry(tele_vals)
@@ -1181,6 +1189,16 @@ class Executor:
             for name, out in zip(self._out_names, self._outputs):
                 self._monitor_callback(name, out)
         return self._outputs
+
+    def fused_step_hlo(self) -> str:
+        """Optimised HLO text of the program the last :meth:`fused_step`
+        ran, compiled again from its recorded argument shapes — the way to
+        see what the compiler put in (an ``all-reduce`` under a dp mesh,
+        chip_smoke.py ``--chips 4``)."""
+        if self._fused_probe is None:
+            raise MXNetError("fused_step_hlo: no fused step has run")
+        fn, avals = self._fused_probe
+        return fn.lower(*avals).compile().as_text()
 
     # -- train telemetry ----------------------------------------------------------
     def _note_telemetry(self, vals: Dict[str, object]) -> None:

@@ -622,6 +622,12 @@ def ImageRecordIter(**kwargs):
     if use_native:
         from . import _native
 
+        err = _native.build_error()
+        if err:
+            # the native pipeline was asked for (it is the default) and its
+            # build failed: an error, not a quiet switch to the Python path
+            # (use_native=False or MXTPU_NO_NATIVE=1 choose that path)
+            raise MXNetError(err)
         native_ok = _native.lib() is not None and \
             kwargs.get("path_imgrec") and \
             tuple(kwargs.get("data_shape", (3, 224, 224)))[0] == 3

@@ -121,38 +121,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, bq: int, bk: int, causal: bool,
             qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             mask = mask & (kpos <= qpos)
         s = jnp.where(mask, s, _NEG)
-        m_old = m_ref[:, 0]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_old = m_ref[...]                            # (bq, 1) columns
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_old - m_new)
-        l_ref[:, 0] = alpha * l_ref[:, 0] + jnp.sum(p, axis=1)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
+        m_ref[...] = m_new
 
     @pl.when(kj == nk - 1)
     def _emit():
-        o_ref[0] = (acc_ref[:] /
-                    jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
+        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[...], 1e-30)
                     ).astype(o_ref.dtype)
         if lse_ref is not None:
             # per-row logsumexp of the masked scaled scores — the backward
             # kernels' recompute anchor (p = exp(s - lse)).  Padded rows
             # stay finite: their q is zero, so s == 0 on surviving columns.
-            lse_ref[0] = m_ref[:, 0] + jnp.log(
-                jnp.maximum(l_ref[:, 0], 1e-30))
+            # Carried as a (bq, 1) COLUMN of a (BH, T, 1) array: a (1, bq)
+            # row block of (BH, T) is not a legal TPU block
+            # (docs/pallas.md "block-layout rule").
+            lse_ref[0] = m_ref[...] + jnp.log(
+                jnp.maximum(l_ref[...], 1e-30))
 
 
 def _sds(shape, dtype, like):
     # inside shard_map (Ulysses impl="flash") outputs must carry the
     # inputs' varying-mesh-axes annotation or check_vma rejects them
-    # (jax.typeof/vma only exist on jax versions that HAVE check_vma;
-    # older releases use check_rep, where a plain ShapeDtypeStruct is
-    # exactly right)
-    if hasattr(jax, "typeof"):
-        return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 @functools.partial(jax.jit, static_argnames=("t_real", "causal", "bq", "bk",
@@ -169,8 +166,9 @@ def _fwd_call(q3, k3, v3, t_real, causal, bq, bk, scale, interpret,
     o_shape = _sds((bh, t_pad, d), q3.dtype, q3)
     o_spec = pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0))
     if with_lse:
-        out_shape = (o_shape, _sds((bh, t_pad), jnp.float32, q3))
-        out_specs = (o_spec, pl.BlockSpec((1, bq), lambda i, j, kk: (i, j)))
+        out_shape = (o_shape, _sds((bh, t_pad, 1), jnp.float32, q3))
+        out_specs = (o_spec,
+                     pl.BlockSpec((1, bq, 1), lambda i, j, kk: (i, j, 0)))
     else:
         out_shape, out_specs = o_shape, o_spec
     return pl.pallas_call(
@@ -226,10 +224,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = jnp.where(_bwd_mask(qi, kj, bq, bk, t_real, causal), s, _NEG)
-        p = jnp.exp(s - lse_ref[0][:, None])           # masked cols → 0
+        p = jnp.exp(s - lse_ref[0])                    # masked cols → 0
         dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - delta_ref[0])
         acc_ref[:] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -263,13 +261,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = jnp.where(_bwd_mask(qi, kj, bq, bk, t_real, causal), s, _NEG)
-        p = jnp.exp(s - lse_ref[0][:, None])           # (bq, bk)
+        p = jnp.exp(s - lse_ref[0])                    # (bq, bk)
         dv_acc[:] += jax.lax.dot_general(               # pᵀ @ g
             p, g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - delta_ref[0])
         dk_acc[:] += jax.lax.dot_general(               # dsᵀ @ q_scaled
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -291,8 +289,9 @@ def _bwd_call(q3, k3, v3, g3, lse, delta, t_real, causal, bq, bk, scale,
     q_spec_inner = pl.BlockSpec((1, bq, d), lambda i, a, b: (i, b, 0))
     k_spec = pl.BlockSpec((1, bk, d), lambda i, a, b: (i, b, 0))
     k_spec_outer = pl.BlockSpec((1, bk, d), lambda i, a, b: (i, a, 0))
-    row_spec = pl.BlockSpec((1, bq), lambda i, a, b: (i, a))
-    row_spec_inner = pl.BlockSpec((1, bq), lambda i, a, b: (i, b))
+    # lse / delta are (BH, T, 1): (bq, 1) column blocks
+    row_spec = pl.BlockSpec((1, bq, 1), lambda i, a, b: (i, a, 0))
+    row_spec_inner = pl.BlockSpec((1, bq, 1), lambda i, a, b: (i, b, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, bq=bq, bk=bk, causal=causal,
                           scale=scale, t_real=t_real),
@@ -428,7 +427,7 @@ def _flash_bwd(t_real, causal, blocks, scale, res, g):
         # delta = rowsum(dO * O): one cheap elementwise pass; zero-padded g
         # zeroes every padded row's contribution inside the kernels
         delta = jnp.sum(g_pad.astype(jnp.float32)
-                        * o_pad.astype(jnp.float32), axis=-1)
+                        * o_pad.astype(jnp.float32), axis=-1, keepdims=True)
         dq, dk, dv = _bwd_call(_pad_to(q3, t_pad), _pad_to(k3, t_pad),
                                _pad_to(v3, t_pad), g_pad, lse, delta,
                                t_real, causal, bq, bk, scale,

@@ -102,26 +102,11 @@ def _unpack_call(packed2d, thresh, dtype, interpret):
     )(packed2d, thresh)
 
 
-_ALIAS_WARNED = False
-
-
 def _use_interpret() -> bool:
     # TPUMX_PALLAS_INTERPRET=1 forces the interpreter even on a TPU host —
     # the two-backend oracle (tools/tpu_parity.py) needs a CPU-interpreted
     # reference leg that is NOT the native Mosaic lowering being checked.
-    # MXTPU_PALLAS_INTERPRET is the pre-rename spelling, honored with a
-    # one-time warning (every other knob in the tree is TPUMX_*).
-    global _ALIAS_WARNED
-
     forced = os.environ.get("TPUMX_PALLAS_INTERPRET")
-    if forced is None:
-        forced = os.environ.get("MXTPU_PALLAS_INTERPRET")
-        if forced is not None and not _ALIAS_WARNED:
-            _ALIAS_WARNED = True
-            warnings.warn(
-                "MXTPU_PALLAS_INTERPRET is deprecated; use "
-                "TPUMX_PALLAS_INTERPRET (same semantics)",
-                DeprecationWarning, stacklevel=2)
     if forced is not None:
         return forced == "1"
     return jax.default_backend() != "tpu"
@@ -239,12 +224,25 @@ def _bn_norm_call(x2d, scale, shift, block_m, interpret):
     )(x2d, scale.reshape(1, c), shift.reshape(1, c))
 
 
-def _bn_block_m(m: int) -> int:
-    """Largest power-of-two block dividing m; < 8 means the shape is
-    kernel-hostile (odd row counts) and the caller falls back to XLA."""
-    for cand in (1024, 512, 256, 128, 64, 32, 16, 8):
+def _row_cap(c: int) -> int:
+    """Most rows of a ``(rows, c)`` f32 block that stay under ~1 MB of
+    VMEM (in and out tiles are each double-buffered): a power of two in
+    [8, 1024]."""
+    cap = 1024
+    while cap > 8 and cap * c * 4 > (1 << 20):
+        cap //= 2
+    return cap
+
+
+def _bn_block_m(m: int, c: int = 128) -> int:
+    """Largest power-of-two block dividing m that fits VMEM; < 8 means the
+    row count is not a multiple of 8 (batch statistics cannot be padded
+    for free) and the caller falls back to XLA with a warning."""
+    cand = _row_cap(c)
+    while cand >= 8:
         if m % cand == 0:
             return cand
+        cand //= 2
     return 1
 
 
@@ -269,8 +267,9 @@ def bn_train_fused(x, gamma, beta, eps, channel_axis):
     """Fused train-mode BN over channels-minor data.  Returns
     (out, mean, var) — mean/var so the stateful frontends can run their
     running-stat update (gluon calls with output_mean_var=True).  x of any
-    rank with channels on `channel_axis` == last axis; kernel-hostile row
-    counts (odd M) fall back to the jnp reference."""
+    rank with channels on `channel_axis` == last axis; a row count that is
+    not a multiple of 8 runs the jnp reference, with a one-time warning
+    that names the shape."""
     out, _res = _bn_fused_fwd(x, gamma, beta, eps, channel_axis)
     return out
 
@@ -280,8 +279,14 @@ def _bn_fused_fwd(x, gamma, beta, eps, channel_axis):
     c = shape[channel_axis]
     x2d = x.reshape(-1, c)
     m = x2d.shape[0]
-    block_m = _bn_block_m(m)
-    if block_m < 8:  # odd row count: tiny blocks would be slower than XLA
+    block_m = _bn_block_m(m, c)
+    if block_m < 8:
+        # never silent — on the chip a quiet fallback reads as "the kernel
+        # ran".  (The default warning filter shows it once per shape.)
+        warnings.warn(
+            f"bn_train_fused: {m} rows is not a multiple of 8 — running "
+            f"the jnp reference instead of the Pallas kernels for shape "
+            f"{x2d.shape}", RuntimeWarning, stacklevel=3)
         out, mean, var = _bn_train_reference(x, gamma, beta, eps)
         return (out, mean, var), (x, gamma, beta)
     interp = _use_interpret()
@@ -350,21 +355,17 @@ def _ln_call(x2d, gamma, beta, eps, gelu, block_m, interpret):
                   pl.BlockSpec((1, c), lambda i: (0, 0)),
                   pl.BlockSpec((1, c), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_m, c), lambda i: (i, 0)),
-        # same vma-annotation dance as the flash forward: inside shard_map
-        # the output must carry the inputs' varying mesh axes when the jax
-        # generation checks them (jax.typeof only exists on those versions)
-        out_shape=(jax.ShapeDtypeStruct((m, c), x2d.dtype,
-                                        vma=jax.typeof(x2d).vma)
-                   if hasattr(jax, "typeof")
-                   else jax.ShapeDtypeStruct((m, c), x2d.dtype)),
+        # like the flash forward: inside shard_map the output must carry
+        # the inputs' varying mesh axes
+        out_shape=jax.ShapeDtypeStruct((m, c), x2d.dtype,
+                                       vma=jax.typeof(x2d).vma),
         interpret=interpret,
     )(x2d, gamma.reshape(1, c), beta.reshape(1, c))
 
 
 def _ln_reference(x, gamma, beta, eps, gelu):
-    """jnp reference of the fused forward — the vjp donor AND the
-    kernel-hostile-shape fallback.  f32 stats regardless of x dtype (the
-    kernel computes the same way)."""
+    """jnp reference of the fused forward — the vjp donor.  f32 stats
+    regardless of x dtype (the kernel computes the same way)."""
     xf = x.astype(jnp.float32)
     pivot = jax.lax.stop_gradient(xf[..., :1])
     xc = xf - pivot
@@ -382,8 +383,8 @@ def _ln_reference(x, gamma, beta, eps, gelu):
 def layer_norm_fused(x, gamma, beta, eps=1e-5, gelu=False):
     """Fused LayerNorm over the LAST axis of ``x`` (any rank); ``gamma`` /
     ``beta`` are ``(C,)``.  ``gelu=True`` applies the GELU epilogue to the
-    normalized output in the same kernel pass.  Kernel-hostile row counts
-    (odd M) fall back to the jnp reference, like bn_train_fused."""
+    normalized output in the same kernel pass.  Any row count runs the
+    kernel (rows are padded to a legal block)."""
     out, _res = _ln_fused_fwd(x, gamma, beta, eps, gelu)
     return out
 
@@ -392,12 +393,17 @@ def _ln_fused_fwd(x, gamma, beta, eps, gelu):
     shape = x.shape
     c = shape[-1]
     x2d = x.reshape(-1, c)
-    block_m = _bn_block_m(x2d.shape[0])
-    if block_m < 8:
-        return _ln_reference(x, gamma, beta, eps, gelu), (x, gamma, beta)
+    m = x2d.shape[0]
+    # rows are independent, so any row count runs the kernel: pad up to a
+    # legal block (a multiple of 8 rows) and slice the padding off — the
+    # engine's max_slots=4 decode step is such a shape
+    block_m = min(_row_cap(c), -(-m // 8) * 8)
+    m_pad = -(-m // block_m) * block_m
+    if m_pad != m:
+        x2d = jnp.pad(x2d, ((0, m_pad - m), (0, 0)))
     out2d = _ln_call(x2d, gamma, beta, float(eps), bool(gelu), block_m,
                      _use_interpret())
-    return out2d.reshape(shape), (x, gamma, beta)
+    return out2d[:m].reshape(shape), (x, gamma, beta)
 
 
 def _ln_fused_bwd(eps, gelu, res, g):

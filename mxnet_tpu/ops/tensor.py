@@ -39,7 +39,7 @@ _UNARY = {
     "ceil": jnp.ceil,
     "floor": jnp.floor,
     "trunc": jnp.trunc,
-    "fix": jnp.fix,
+    "fix": jnp.trunc,
     "square": jnp.square,
     "sqrt": jnp.sqrt,
     "rsqrt": lambda x: lax.rsqrt(x),

@@ -18,48 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from .mesh import get_mesh
 
 __all__ = ["allreduce", "allgather", "reduce_scatter", "broadcast", "all_to_all",
-           "allreduce_tree", "allreduce_grads_spmd", "shard_map_compat",
-           "axis_size"]
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, inside an SPMD trace — across jax
-    versions (``lax.axis_size`` only exists in newer releases)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    try:
-        return jax.core.axis_frame(axis_name).size
-    except Exception:
-        # last resort: psum of a unit constant (static-folded by jax)
-        return lax.psum(1, axis_name)
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across jax versions.
-
-    Newer jax exports ``jax.shard_map`` (replication check kwarg
-    ``check_vma``); older releases ship it as
-    ``jax.experimental.shard_map.shard_map`` (kwarg ``check_rep``).  Every
-    SPMD entry point in this package goes through here so the fused
-    data-parallel train step runs on whichever jax the image bakes in.
-    ``check=False`` disables the replication/varying-axes checker (graphs may
-    contain pallas_call, which can't declare varying-mesh-axes metadata).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    import inspect
-
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):
-        params = {}
-    kwargs = {}
-    if "check_vma" in params:
-        kwargs["check_vma"] = check
-    elif "check_rep" in params:
-        kwargs["check_rep"] = check
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+           "allreduce_tree", "allreduce_grads_spmd"]
 
 
 def allreduce(x, axis_name: str):
@@ -77,7 +36,7 @@ def reduce_scatter(x, axis_name: str, axis: int = 0):
 
 def broadcast(x, axis_name: str, src: int = 0):
     """Broadcast src's shard to all members of the axis."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if not isinstance(src, jax.core.Tracer):
         # static src (incl. numpy ints): validate now — an out-of-range src
         # would make the mask never fire and psum return silent ZEROS, the
@@ -120,9 +79,9 @@ def allreduce_tree(values: List, mesh: Mesh = None, axis: str = "dp"):
     def _reduce(x):
         return lax.psum(x, axis)
 
-    fn = shard_map_compat(_reduce, mesh=mesh,
+    fn = jax.shard_map(_reduce, mesh=mesh,
                           in_specs=PartitionSpec(axis),
-                          out_specs=PartitionSpec(axis), check=True)
+                          out_specs=PartitionSpec(axis), check_vma=True)
     out = fn(stacked)
     return [out[i] for i in range(len(values))]
 

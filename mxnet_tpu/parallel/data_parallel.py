@@ -270,15 +270,14 @@ class DataParallelTrainer:
 
         def step(params, momenta, aux, residuals, x, y, rng):
             if self._mesh is not None:
-                from .collectives import shard_map_compat
 
                 P = PartitionSpec
-                loss, grads, new_res, new_aux = shard_map_compat(
+                loss, grads, new_res, new_aux = jax.shard_map(
                     local_grads, mesh=self._mesh,
                     in_specs=(P(), P(), P(axis), P(axis), P(axis), P()),
                     out_specs=(P(), P(), P(axis), P()),
                     # pallas_call can't declare varying-mesh-axes metadata
-                    check=False,
+                    check_vma=False,
                 )(params, aux, residuals, x, y, rng)
             else:
                 (loss, new_aux), g = jax.value_and_grad(

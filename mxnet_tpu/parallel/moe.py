@@ -70,9 +70,8 @@ def moe_dispatch_combine(x, router_w, expert_fn, expert_params,
     dispatch → all_to_all over `axis_name` → local expert → all_to_all back
     → combine. Returns (B_local, D).
     """
-    from .collectives import axis_size
 
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     B, D = x.shape
     capacity = max(1, int(B * capacity_factor / n))
     dispatch, combine = top1_routing(x, router_w, n, capacity)
@@ -105,10 +104,9 @@ def moe_apply_sharded(x, router_w, expert_params, expert_fn: Callable,
                                     axis_name=axis_name,
                                     capacity_factor=capacity_factor)
 
-    from .collectives import shard_map_compat
 
-    fn = shard_map_compat(inner, mesh=mesh,
+    fn = jax.shard_map(inner, mesh=mesh,
                           in_specs=(PartitionSpec(axis_name), PartitionSpec(),
                                     pspec),
-                          out_specs=PartitionSpec(axis_name), check=False)
+                          out_specs=PartitionSpec(axis_name), check_vma=False)
     return fn(x, router_w, expert_params)

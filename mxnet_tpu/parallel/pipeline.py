@@ -78,9 +78,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_microbatches,
     gradients.  Differentiable end to end (the round-robin is a ``lax.scan``
     over M + S - 1 ticks).
     """
-    from .collectives import axis_size
 
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     M = x_microbatches.shape[0]
     T = M + n - 1
@@ -133,9 +132,8 @@ def pipeline_apply_sharded(stage_fn: Callable, stacked_params, x_microbatches,
         # the transpose-correct broadcast so grads flow through unscaled
         return psum_bcast(out, axis_name)
 
-    from .collectives import shard_map_compat
 
-    fn = shard_map_compat(inner, mesh=mesh,
+    fn = jax.shard_map(inner, mesh=mesh,
                           in_specs=(pspec, PartitionSpec()),
-                          out_specs=PartitionSpec(), check=False)
+                          out_specs=PartitionSpec(), check_vma=False)
     return fn(stacked_params, x_microbatches)

@@ -64,9 +64,8 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
     Exact (not approximate) attention over the full sequence; K/V ring-rotate
     `n` steps; per-step compute is a local flash-attention block.
     """
-    from .collectives import axis_size
 
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
@@ -105,11 +104,11 @@ def ring_attention_sharded(q, k, v, mesh: Optional[Mesh] = None,
     mesh = mesh or get_mesh()
     spec = PartitionSpec(None, axis_name, None, None)
 
-    from .collectives import shard_map_compat
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis_name, causal=causal),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check=False)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return fn(q, k, v)
 
 

@@ -73,10 +73,10 @@ def ulysses_attention_sharded(q, k, v, mesh: Optional[Mesh] = None,
             f"ulysses_attention_sharded: {heads} heads not divisible by "
             f"mesh axis {axis_name!r} of size {n}")
     spec = PartitionSpec(None, axis_name, None, None)
-    from .collectives import shard_map_compat
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention, axis_name=axis_name,
                           causal=causal, impl=impl),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check=False)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return fn(q, k, v)

@@ -83,12 +83,20 @@ def transformer_lm_init(cfg: TransformerConfig, key) -> Params:
     return p
 
 
-def _ln(x, g, b, eps=1e-5):
+def _ln(x, g, b, eps=1e-5, mesh=None):
     from ..ops import pallas_kernels as _pk
     if _pk.pallas_enabled():
         # fused stats+normalize kernel (docs/pallas.md): one read one
         # write; custom-vjp backward keeps training grads exact
-        return _pk.layer_norm_fused(x, g, b, eps=eps).astype(x.dtype)
+        ln = lambda x, g, b: _pk.layer_norm_fused(  # noqa: E731
+            x, g, b, eps=eps).astype(x.dtype)
+        if mesh is not None:
+            # inside a GSPMD-partitioned program (mp serving) the compiler
+            # cannot partition an opaque Mosaic call: run it per device in
+            # a shard_map over the replicated activations
+            ln = jax.shard_map(ln, mesh=mesh, in_specs=(P(), P(), P()),
+                               out_specs=P(), check_vma=False)
+        return ln(x, g, b)
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + eps) * g + b
@@ -144,7 +152,7 @@ def _scatter_kv_quantized(pool, scale, vals, tables, positions, valid,
     write history, which is what makes greedy tokens batch-composition-
     independent under int8.
 
-    pool: (num_blocks, bs, H, D) int8; scale: (num_blocks, H) f32;
+    pool: (num_blocks, bs, H*D) int8; scale: (num_blocks, H) f32;
     vals: (B, T, H, D) float; tables: (B, W) int32; positions/valid:
     (B, T); max_pos: (B,) last valid position AFTER this write (-1 for
     inactive rows).  Returns (pool, scale).
@@ -161,7 +169,7 @@ def _scatter_kv_quantized(pool, scale, vals, tables, positions, valid,
     tphys = jnp.where(
         j_ok, jnp.take_along_axis(tables, jnp.minimum(tl, W - 1), axis=1),
         0)
-    blk = pool[tphys].astype(jnp.float32) \
+    blk = pool[tphys].reshape(B, nt, bs, H, D).astype(jnp.float32) \
         * scale[tphys][:, :, None, :, None]          # (B, nt, bs, H, D)
     bidx = jnp.arange(B, dtype=jnp.int32)[:, None]
     j = jnp.clip(positions // bs - l0[:, None], 0, nt - 1)
@@ -179,7 +187,7 @@ def _scatter_kv_quantized(pool, scale, vals, tables, positions, valid,
                    axis=(2, 4))                            # (B, nt, H)
     new_s = jnp.maximum(amax, 1e-8) / 127.0
     q = jnp.clip(jnp.round(blk / new_s[:, :, None, :, None]),
-                 -127, 127).astype(jnp.int8)
+                 -127, 127).astype(jnp.int8).reshape(B, nt, bs, H * D)
     # duplicate targets only ever alias the reserved null block 0
     return pool.at[tphys].set(q), scale.at[tphys].set(new_s)
 
@@ -225,8 +233,10 @@ def transformer_lm_decode(params: Params, tokens, positions, lengths,
     lengths : (B,) int32 — valid query count per row; rows with 0 are
         inactive decode slots (their writes are routed to the reserved null
         block 0 and their outputs are garbage).
-    k_pool, v_pool : (n_layers, num_blocks, block_size, n_heads, d_head) —
-        the paged cache pool; block 0 is the null/scratch block.
+    k_pool, v_pool : (n_layers, num_blocks, block_size, n_heads * d_head) —
+        the paged cache pool, heads folded into the minor dim (the
+        lane-dense block layout the paged kernel needs, docs/pallas.md);
+        block 0 is the null/scratch block.
     block_tables : (B, W) int32 — logical block j of row b lives in
         physical block ``block_tables[b, j]``; the gathered context covers
         global positions ``[0, W * block_size)``.
@@ -252,7 +262,7 @@ def transformer_lm_decode(params: Params, tokens, positions, lengths,
         params = jax.tree_util.tree_map(
             lambda p: p.astype(compute_dtype), params)
     B, T = tokens.shape
-    n_layers, num_blocks, block_size, n_heads, d_head = k_pool.shape
+    n_layers, num_blocks, block_size, _ = k_pool.shape
     W = block_tables.shape[1]
     positions = jnp.clip(jnp.asarray(positions, jnp.int32), 0,
                          cfg.max_len - 1)
@@ -302,7 +312,7 @@ def transformer_lm_decode(params: Params, tokens, positions, lengths,
                                              axis=0)
     for i in range(cfg.n_layers):
         g = lambda n: params[f"l{i}_{n}"]  # noqa: B023 — read immediately
-        h = _ln(x, g("ln1_g"), g("ln1_b"))
+        h = _ln(x, g("ln1_g"), g("ln1_b"), mesh=mp_mesh)
         qkv = h @ g("wqkv")
         q, k, v = jnp.split(qkv, 3, axis=-1)
         to_heads = lambda t: t.reshape(B, T, cfg.n_heads, cfg.d_head)
@@ -319,8 +329,9 @@ def transformer_lm_decode(params: Params, tokens, positions, lengths,
             k_scale = k_scale.at[i].set(ks)
             v_scale = v_scale.at[i].set(vs)
         else:
-            k_pool = k_pool.at[i, phys, offs].set(k.astype(k_pool.dtype))
-            v_pool = v_pool.at[i, phys, offs].set(v.astype(v_pool.dtype))
+            fold = lambda t: t.reshape(B, T, cfg.d_model).astype(k_pool.dtype)
+            k_pool = k_pool.at[i, phys, offs].set(fold(k))
+            v_pool = v_pool.at[i, phys, offs].set(fold(v))
         if use_paged and mp_mesh is not None:
             o = _pa.paged_attention_sharded(
                 q, k_pool[i], v_pool[i], block_tables, positions, max_pos,
@@ -338,14 +349,14 @@ def transformer_lm_decode(params: Params, tokens, positions, lengths,
             if quantized:
                 # dequantize at read: per-(block, head) scales broadcast
                 # over the gathered context (docs/quantization.md)
-                k_ctx = (k_pool[i][block_tables].astype(jnp.float32)
-                         * k_scale[i][block_tables][:, :, None, :, None]
-                         ).reshape(B, W * block_size, cfg.n_heads,
-                                   cfg.d_head)
-                v_ctx = (v_pool[i][block_tables].astype(jnp.float32)
-                         * v_scale[i][block_tables][:, :, None, :, None]
-                         ).reshape(B, W * block_size, cfg.n_heads,
-                                   cfg.d_head)
+                deq = lambda pool, sc: (
+                    pool[block_tables].reshape(
+                        B, W, block_size, cfg.n_heads, cfg.d_head
+                    ).astype(jnp.float32)
+                    * sc[block_tables][:, :, None, :, None]
+                ).reshape(B, W * block_size, cfg.n_heads, cfg.d_head)
+                k_ctx = deq(k_pool[i], k_scale[i])
+                v_ctx = deq(v_pool[i], v_scale[i])
             else:
                 k_ctx = k_pool[i][block_tables].reshape(
                     B, W * block_size, cfg.n_heads, cfg.d_head)
@@ -358,9 +369,10 @@ def transformer_lm_decode(params: Params, tokens, positions, lengths,
             # so bucketed table widths never perturb real rows
             o = _pa_reference(q, k_ctx, v_ctx, attn_mask, scale)
         x = x + o.reshape(B, T, cfg.d_model) @ g("wo")
-        h = _ln(x, g("ln2_g"), g("ln2_b"))
+        h = _ln(x, g("ln2_g"), g("ln2_b"), mesh=mp_mesh)
         x = x + jax.nn.gelu(h @ g("w1") + g("b1")) @ g("w2") + g("b2")
-    x = _ln(x, params["lnf_g"], params["lnf_b"])
+    x = _ln(x, params["lnf_g"], params["lnf_b"],
+            mesh=mp_mesh)
     logits = x @ params["tok_emb"].T
     if quantized:
         return logits.astype(jnp.float32), k_pool, v_pool, k_scale, v_scale
@@ -450,8 +462,8 @@ def make_sharded_train_step(mesh: Mesh, cfg: TransformerConfig,
 
         loss, grads = jax.value_and_grad(local_loss)(params)
         # EXPLICIT allreduce of the param cotangents: with replication
-        # checking off (shard_map_compat check=False, the only mode every
-        # jax generation accepts for this graph) no auto-psum is inserted on
+        # checking off (check_vma=False: the graph may hold pallas_call,
+        # which cannot declare varying mesh axes) no auto-psum is inserted on
         # the backward, so each shard holds only its local contribution here
         grads = jax.tree_util.tree_map(lambda g: jax.lax.psum(g, axes), grads)
         loss = jax.lax.psum(loss, axes)  # back to the global mean for report
@@ -461,12 +473,10 @@ def make_sharded_train_step(mesh: Mesh, cfg: TransformerConfig,
                                         params, momenta)
         return loss, params, momenta
 
-    from .collectives import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         shard_step, mesh=mesh,
         in_specs=(repl, repl, data, data, P("sp")),
-        out_specs=(repl, repl, repl), check=False)
+        out_specs=(repl, repl, repl), check_vma=False)
     return jax.jit(fn, donate_argnums=(0, 1))
 
 
@@ -528,7 +538,6 @@ def make_partitioned_train_step(mesh: Mesh, cfg: TransformerConfig,
     rule-sharded weight in the traced program (tests assert the jaxpr).
     Step time now improves with mp, which is the ROADMAP item-2 claim.
     """
-    from .collectives import shard_map_compat
     from .partition_rules import (make_param_specs,
                                   make_shard_and_gather_fns,
                                   mp_compute_enabled,
@@ -594,10 +603,10 @@ def make_partitioned_train_step(mesh: Mesh, cfg: TransformerConfig,
                                         params, momenta)
         return loss, params, momenta
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         shard_step, mesh=mesh,
         in_specs=(pspec_tree, pspec_tree, P("dp"), P("dp"), P()),
-        out_specs=(P(), pspec_tree, pspec_tree), check=False)
+        out_specs=(P(), pspec_tree, pspec_tree), check_vma=False)
     step = jax.jit(fn, donate_argnums=(0, 1))
     shard_fn, gather_fn = make_shard_and_gather_fns(specs, mesh)
     return step, shard_fn, gather_fn

@@ -1130,12 +1130,18 @@ class GenerationService:
 
     def _admit_need(self, r: _GenRequest) -> int:
         """Blocks an admission must secure for ``r``: under incremental
-        allocation just the current context plus the next written
-        position; under reserve-ahead the full worst case."""
+        allocation the current context plus the first iteration's write
+        span — the request decodes in the very iteration that admits it,
+        before :meth:`_grow_blocks_locked` next runs, so a verify chunk or
+        multistep scan that crosses a block boundary there must already
+        own the block it writes (span 1 == the classic next position);
+        under reserve-ahead the full worst case."""
         cfg = self._config
         if cfg.preemption:
             ctx = r.ctx_len if r.ctx_len > 0 else r.prompt_len
-            return blocks_for(ctx + 1, cfg.block_size)
+            return blocks_for(
+                min(ctx + self._iter_span, r.prompt_len + r.max_new),
+                cfg.block_size)
         return blocks_for(r.prompt_len + r.max_new, cfg.block_size)
 
     def _admit_locked(self) -> List[_GenRequest]:

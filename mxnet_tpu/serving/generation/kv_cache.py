@@ -2,7 +2,7 @@
 memory model, recast in tpu-mx's fixed-shape compile-cache idiom).
 
 The device side is two preallocated arrays of shape ``(n_layers,
-num_blocks, block_size, n_heads, d_head)`` — K and V — whose shapes never
+num_blocks, block_size, n_heads * d_head)`` — K and V — whose shapes never
 change for the life of the engine, so every compiled program that touches
 them keeps one signature regardless of how many requests come and go or
 how long their sequences grow.  A request owns a *list of physical blocks*
@@ -219,8 +219,11 @@ class PagedKVCache:
             raise ValueError(
                 f"kv_dtype must be None or 'int8', got {kv_dtype!r}")
         self.kv_dtype = kv_dtype
+        # heads folded into the minor dim: a block is a lane-dense
+        # (block_size, H*D) tile, the layout the paged kernel's blocks
+        # need on the chip (docs/pallas.md "block-layout rule")
         shape = (int(n_layers), self.num_blocks, self.block_size,
-                 int(n_heads), int(d_head))
+                 int(n_heads) * int(d_head))
         store = jnp.dtype(jnp.int8) if kv_dtype == "int8" else self.dtype
         self.k = jnp.zeros(shape, store)
         self.v = jnp.zeros(shape, store)

@@ -277,16 +277,14 @@ class GenerationPrograms:
                 functools.partial(
                     _model_step_q, cfg=cfg, compute_dtype=compute_dtype,
                     attention_kernel=self._kernel,
-                    mp_mesh=(self._mp_mesh if self._kernel == "paged"
-                             else None)),
+                    mp_mesh=self._mp_mesh),
                 donate_argnums=(1, 2, 3, 4))
         else:
             self._jit = jax.jit(
                 functools.partial(
                     _model_step, cfg=cfg, compute_dtype=compute_dtype,
                     attention_kernel=self._kernel,
-                    mp_mesh=(self._mp_mesh if self._kernel == "paged"
-                             else None)),
+                    mp_mesh=self._mp_mesh),
                 donate_argnums=(1, 2))
         # multi-token decoding (docs/generation.md "Speculative
         # decoding"): the verify step shares the model step's operand
@@ -296,7 +294,7 @@ class GenerationPrograms:
         self._step_kw = dict(
             cfg=cfg, compute_dtype=compute_dtype,
             attention_kernel=self._kernel,
-            mp_mesh=(self._mp_mesh if self._kernel == "paged" else None))
+            mp_mesh=self._mp_mesh)
         if kv_dtype == "int8":
             self._jit_verify = jax.jit(
                 functools.partial(_verify_step_q, **self._step_kw),
@@ -340,8 +338,9 @@ class GenerationPrograms:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        # (n_layers, num_blocks, block_size, n_heads, d_head): heads dim 3
-        sh = NamedSharding(self._mp_mesh, P(None, None, None, "mp", None))
+        # (n_layers, num_blocks, block_size, n_heads*d_head): the folded
+        # minor dim splits on head boundaries
+        sh = NamedSharding(self._mp_mesh, P(None, None, None, "mp"))
         if cache.quantized:
             # per-(layer, block, head) scales shard on their head dim 2
             ssh = NamedSharding(self._mp_mesh, P(None, None, "mp"))

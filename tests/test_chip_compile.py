@@ -1,0 +1,260 @@
+"""Ask the TPU compiler for the main paths' kernels, without a chip.
+
+The TPU compiler is installed in the test environment and compiles for a
+chip that is *described*, not attached (``v5e:2x2``).  Interpret-mode
+parity (tests/test_paged_attention.py, test_flash_attention.py,
+test_pallas_kernels.py) says a kernel is *right*; only this says the
+chip's compiler *accepts* it — block shapes that are not ``(8k, 128k)`` or
+whole in their last two dims, VMEM that does not fit, a kernel that cannot
+be partitioned.  Shapes are the ones ``chip_smoke.py`` drives: GPT-2-small
+widths (12 heads x 64, d_model 768), the engine's default ``max_slots=4``
+/ ``block_size=16`` / ``num_blocks=128`` pool, its prefill and table-width
+buckets.
+
+Nothing here touches the topology at import, in a ``skipif`` or in a
+``parametrize`` argument: only one process may hold the TPU library, and
+every xdist worker imports every test file.  The fixtures describe the
+topology when the first test of this file runs, in the one worker that
+was given the file.  A compile that passes is not a chip run.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+H, D, HD = 12, 64, 768            # GPT-2-small heads
+NB, BS = 128, 16                  # engine default pool
+SLOTS = 4                         # engine default max_slots
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip — keep it off and silent
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    """Lower + compile for the described chip; returns the HLO text."""
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _paged_shapes(sds, B, T, W, q_dtype, pool_dtype):
+    from mxnet_tpu.ops import paged_attention as pa
+
+    shapes = [sds((B, W), jnp.int32), sds((B,), jnp.int32),
+              sds((B, T, HD), q_dtype), sds((B, T), jnp.int32),
+              sds((NB, BS, HD), pool_dtype), sds((NB, BS, HD), pool_dtype)]
+    if pool_dtype == jnp.int8:
+        shapes += [sds((NB, H), jnp.float32)] * 2
+    fn = functools.partial(pa._paged_call.__wrapped__, n_heads=H,
+                           scale=pa.attention_scale(D), interpret=False)
+    return fn, shapes
+
+
+# (B, T, W): decode at max_slots over the narrowest / widest table bucket,
+# a spec-verify chunk, a mid prefill bucket, and the top prefill bucket
+# (max_len - 1 = 1023: not a multiple of anything, padded in the wrapper)
+_PAGED_SHAPES = [(SLOTS, 1, 1), (SLOTS, 1, 64), (SLOTS, 4, 64),
+                 (1, 128, 8), (1, 1023, 64)]
+
+
+@pytest.mark.parametrize("q_dtype,pool_dtype", [
+    (jnp.float32, jnp.float32), (jnp.bfloat16, jnp.bfloat16),
+    (jnp.float32, jnp.int8), (jnp.bfloat16, jnp.int8)],
+    ids=["f32", "bf16", "f32-int8", "bf16-int8"])
+@pytest.mark.parametrize("B,T,W", _PAGED_SHAPES,
+                         ids=[f"B{b}-T{t}-W{w}" for b, t, w in _PAGED_SHAPES])
+def test_paged_kernel_compiles(one_chip, B, T, W, q_dtype, pool_dtype):
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    fn, shapes = _paged_shapes(sds, B, T, W, q_dtype, pool_dtype)
+    assert "tpu_custom_call" in _compile(fn, *shapes)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["float", "int8"])
+def test_paged_sharded_compiles(topo, monkeypatch, int8):
+    """mp=4 over the described 2x2: the per-head shard_map of the kernel
+    (12 heads -> 3 per chip)."""
+    from mxnet_tpu.ops import paged_attention as pa
+
+    # this process's backend is the CPU, where the kernels default to the
+    # interpreter: ask for the native lowering the chip would get
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    mesh = Mesh(topo.devices, ("mp",))
+    sds = lambda shape, dt, spec: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, spec))
+    pool_dt = jnp.int8 if int8 else jnp.float32
+    shapes = [sds((SLOTS, 1, H, D), jnp.float32, P(None, None, "mp", None)),
+              sds((NB, BS, HD), pool_dt, P(None, None, "mp")),
+              sds((NB, BS, HD), pool_dt, P(None, None, "mp")),
+              sds((SLOTS, 64), jnp.int32, P()),
+              sds((SLOTS, 1), jnp.int32, P()),
+              sds((SLOTS,), jnp.int32, P())]
+    if int8:
+        shapes += [sds((NB, H), jnp.float32, P(None, "mp"))] * 2
+
+    def fn(q, k, v, t, p, m, ks=None, vs=None):
+        return pa.paged_attention_sharded(q, k, v, t, p, m, mesh=mesh,
+                                          k_scale=ks, v_scale=vs)
+
+    assert "tpu_custom_call" in _compile(fn, *shapes)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("kind", ["fwd", "fwd_lse", "bwd"])
+def test_flash_kernel_compiles(one_chip, kind, dtype):
+    """(B*H, T, D) = (12, 1024, 64): one GPT-2-small sequence."""
+    from mxnet_tpu.ops import flash_attention as fa
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    bh, t = H, 1024
+    bq, bk = fa.select_flash_blocks(D, dtype)
+    x = sds((bh, t, D), dtype)
+    col = sds((bh, t, 1), jnp.float32)
+    kw = dict(t_real=t, causal=True, bq=bq, bk=bk, scale=0.125,
+              interpret=False)
+    if kind == "bwd":
+        fn = functools.partial(fa._bwd_call.__wrapped__, **kw)
+        shapes = [x, x, x, x, col, col]
+    else:
+        fn = functools.partial(fa._fwd_call.__wrapped__,
+                               with_lse=kind == "fwd_lse", **kw)
+        shapes = [x, x, x]
+    assert "tpu_custom_call" in _compile(fn, *shapes)
+
+
+@pytest.mark.parametrize("rows", [SLOTS, 128, 1023],
+                         ids=["decode", "prefill128", "prefill1023"])
+def test_layer_norm_compiles(one_chip, monkeypatch, rows):
+    """Fused LayerNorm at the decode row count (4: padded to a legal block
+    in the wrapper, not swapped for the reference) and prefill buckets."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    fn = lambda x, g, b: pk._ln_fused_fwd(x, g, b, 1e-5, False)[0]
+    text = _compile(fn, sds((rows, HD), jnp.float32),
+                    sds((HD,), jnp.float32), sds((HD,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_batch_norm_kernels_compile(one_chip, monkeypatch):
+    """BN stats + normalize (opt-in via MXTPU_BN_PALLAS, channels-minor):
+    a ResNet-50 stage-1 activation, batch 32 x 56 x 56 rows of 256."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    fn = lambda x, g, b: pk._bn_fused_fwd(x, g, b, 1e-5, 3)[0]
+    text = _compile(fn, sds((32, 56, 56, 256), jnp.bfloat16),
+                    sds((256,), jnp.float32), sds((256,), jnp.float32))
+    assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["float", "int8"])
+def test_decode_step_program_compiles(one_chip, monkeypatch, kv_dtype):
+    """The whole model step the engine dispatches every decode iteration
+    (transformer_lm_decode, paged kernel + fused LN pinned on as they are
+    on a TPU backend) at GPT-2-small widths; depth cut to 2 layers to keep
+    the compile short — layers repeat the same program."""
+    from mxnet_tpu.parallel import transformer as tr
+
+    monkeypatch.setenv("TPUMX_PALLAS", "1")
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    cfg = tr.TransformerConfig(vocab=50257, d_model=HD, n_heads=H,
+                               n_layers=2, d_ff=3072, max_len=1024)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: tr.transformer_lm_init(
+            cfg, jax.random.PRNGKey(0))))
+    pool_dt = jnp.int8 if kv_dtype else jnp.float32
+    pool = sds((cfg.n_layers, NB, BS, HD), pool_dt)
+    shapes = [params, sds((SLOTS, 1), jnp.int32), sds((SLOTS, 1), jnp.int32),
+              sds((SLOTS,), jnp.int32), pool, pool,
+              sds((SLOTS, 64), jnp.int32)]
+    kw = {}
+    if kv_dtype:
+        sc = sds((cfg.n_layers, NB, H), jnp.float32)
+        kw = dict(k_scale=sc, v_scale=sc)
+
+    def step(params, tokens, positions, lengths, kp, vp, tables, **kw):
+        return tr.transformer_lm_decode(params, tokens, positions, lengths,
+                                        kp, vp, tables, cfg,
+                                        attention_kernel="paged", **kw)
+
+    text = jax.jit(step, donate_argnums=(4, 5)).lower(
+        *shapes, **kw).compile().as_text()
+    # per layer: one paged-attention call and two LayerNorm calls, plus
+    # the final LayerNorm
+    assert text.count("tpu_custom_call") >= 3 * cfg.n_layers + 1
+
+
+def test_decode_step_program_compiles_mp4(topo, monkeypatch):
+    """The same step as a GSPMD program over ``mp=4`` (GenerationConfig(
+    mp_devices=4)): params placed by the transformer's partition rules,
+    the pool sharded on its folded minor dim.  The compiler cannot
+    partition an opaque Mosaic call, so every kernel in the program must
+    sit inside a shard_map — the paged kernel per head, fused LayerNorm
+    over the replicated activations (chip_smoke.py --chips 4 first met
+    this on the chip, PR 21)."""
+    from mxnet_tpu.parallel import transformer as tr
+    from mxnet_tpu.parallel.partition_rules import make_param_specs
+
+    monkeypatch.setenv("TPUMX_PALLAS", "1")
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    cfg = tr.TransformerConfig(vocab=50257, d_model=HD, n_heads=H,
+                               n_layers=2, d_ff=3072, max_len=1024)
+    mesh = Mesh(topo.devices, ("mp",))
+    sds = lambda shape, dt, *spec: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, P(*spec)))
+    shapes = jax.eval_shape(lambda: tr.transformer_lm_init(
+        cfg, jax.random.PRNGKey(0)))
+    specs = make_param_specs(tr.transformer_partition_rules(),
+                             {k: v.shape for k, v in shapes.items()}, mesh)
+    params = {k: sds(v.shape, v.dtype, *specs.get(k, ()))
+              for k, v in shapes.items()}
+    pool = sds((cfg.n_layers, NB, BS, HD), jnp.float32,
+               None, None, None, "mp")
+    args = [params, sds((SLOTS, 1), jnp.int32), sds((SLOTS, 1), jnp.int32),
+            sds((SLOTS,), jnp.int32), pool, pool,
+            sds((SLOTS, 64), jnp.int32)]
+
+    def step(params, tokens, positions, lengths, kp, vp, tables):
+        return tr.transformer_lm_decode(params, tokens, positions, lengths,
+                                        kp, vp, tables, cfg,
+                                        attention_kernel="paged",
+                                        mp_mesh=mesh)
+
+    text = jax.jit(step, donate_argnums=(4, 5)).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3 * cfg.n_layers + 1
+    assert "all-reduce" in text     # the row-parallel projections' psum

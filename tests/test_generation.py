@@ -179,7 +179,7 @@ def test_decode_with_cache_matches_full_apply(params, compute_dtype):
     plen, n_steps, bs = 13, 7, 8      # prompt spans blocks 0-1, decode
     prompt = rs.randint(0, CFG.vocab, plen)   # crosses into block 2 (pos 16)
     pool_dt = dt or jnp.float32
-    kp = jnp.zeros((CFG.n_layers, 16, bs, CFG.n_heads, CFG.d_head), pool_dt)
+    kp = jnp.zeros((CFG.n_layers, 16, bs, CFG.d_model), pool_dt)
     vp = jnp.zeros_like(kp)
     table = np.array([[1, 2, 3]], np.int32)
     tb = 16
@@ -217,7 +217,7 @@ def test_inactive_slots_do_not_corrupt_cache(params):
     """A decode step with inactive (length 0) slots writes only to the
     reserved null block 0."""
     bs = 8
-    kp = jnp.zeros((CFG.n_layers, 8, bs, CFG.n_heads, CFG.d_head))
+    kp = jnp.zeros((CFG.n_layers, 8, bs, CFG.d_model))
     vp = jnp.zeros_like(kp)
     # fill block 1 via an active row, with a garbage inactive row alongside
     toks = np.array([[5], [7]], np.int32)
@@ -251,7 +251,7 @@ def test_block_allocator_semantics():
 def test_paged_cache_shapes():
     c = PagedKVCache(n_layers=2, n_heads=4, d_head=8, num_blocks=16,
                      block_size=4)
-    assert c.shape == (2, 16, 4, 4, 8)
+    assert c.shape == (2, 16, 4, 4 * 8)
     assert c.max_positions() == 15 * 4
     assert c.blocks_for(5) == 2
 

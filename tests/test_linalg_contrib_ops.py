@@ -227,10 +227,9 @@ def test_sync_batch_norm_shard_map_global_stats():
         return sync_batch_norm(xl, g, b, rm, rv, fix_gamma=False,
                                axis_name="dp", _training=True)
 
-    from mxnet_tpu.parallel.collectives import shard_map_compat
-
-    out = jax.jit(shard_map_compat(local, mesh=mesh, in_specs=P("dp"),
-                                   out_specs=P("dp")))(jnp.asarray(x))
+    out = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P("dp"),
+                                out_specs=P("dp"), check_vma=False))(
+        jnp.asarray(x))
     ref = batch_norm(jnp.asarray(x), g, b, rm, rv, fix_gamma=False,
                      _training=True)
     assert np.allclose(np.asarray(out), np.asarray(ref), atol=1e-4)

@@ -77,7 +77,7 @@ def test_kernel_matches_gathered_dense(paged, dtype):
     nb, bs, W = 12, 4, 4
     dt = jnp.dtype(dtype)
     mk = lambda *s: jnp.asarray(rs.randn(*s).astype(np.float32)).astype(dt)
-    q, kp, vp = mk(B, T, H, D), mk(nb, bs, H, D), mk(nb, bs, H, D)
+    q, kp, vp = mk(B, T, H, D), mk(nb, bs, H * D), mk(nb, bs, H * D)
     tables = np.zeros((B, W), np.int32)
     tables[0, :4] = [2, 5, 7, 9]     # full table
     tables[1, :2] = [1, 3]           # ragged: shorter context
@@ -107,6 +107,29 @@ def test_kernel_matches_gathered_dense(paged, dtype):
     assert float(jnp.abs(got[3].astype(jnp.float32)).max()) == 0.0
 
 
+def test_kernel_tiles_and_pads_long_chunks(paged):
+    """A chunk longer than one query tile (256 rows) runs as several grid
+    tiles, with T padded up to a whole number of them in the wrapper (the
+    engine's top prefill bucket is max_len - 1): every real row still
+    matches the gathered-dense attend."""
+    rs = np.random.RandomState(1)
+    B, T, H, D = 1, 300, 2, 16
+    nb, bs, W = 48, 8, 40
+    assert pa._query_tile(T, H * D) == 256
+    mk = lambda *s: jnp.asarray(rs.randn(*s).astype(np.float32))
+    q, kp, vp = mk(B, T, H, D), mk(nb, bs, H * D), mk(nb, bs, H * D)
+    tables = np.zeros((B, W), np.int32)
+    tables[0, :38] = rs.permutation(np.arange(1, nb))[:38]   # 304 slots
+    positions = np.arange(T, dtype=np.int32)[None, :]
+    max_pos = np.array([T - 1], np.int32)
+    scale = pa.attention_scale(D)
+    got = pa.paged_attention(q, kp, vp, tables, positions, max_pos, scale)
+    want = _dense_reference(q, kp, vp, tables, positions, scale)
+    assert got.shape == (B, T, H, D)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
 # -- full decode pipeline -----------------------------------------------------------
 def test_decode_pipeline_matches_dense_across_blocks(params, paged,
                                                      monkeypatch):
@@ -121,7 +144,7 @@ def test_decode_pipeline_matches_dense_across_blocks(params, paged,
 
     def run(gate):
         monkeypatch.setenv("TPUMX_PALLAS", gate)
-        kp = jnp.zeros((CFG.n_layers, 16, bs, CFG.n_heads, CFG.d_head))
+        kp = jnp.zeros((CFG.n_layers, 16, bs, CFG.d_model))
         vp = jnp.zeros_like(kp)
         outs = []
         logits, kp, vp = tr.transformer_lm_decode(
@@ -169,7 +192,7 @@ def test_bf16_oracle_token_bitwise(params, paged, monkeypatch):
 
     def run(gate):
         monkeypatch.setenv("TPUMX_PALLAS", gate)
-        kp = jnp.zeros((CFG.n_layers, 16, bs, CFG.n_heads, CFG.d_head),
+        kp = jnp.zeros((CFG.n_layers, 16, bs, CFG.d_model),
                        jnp.bfloat16)
         vp = jnp.zeros_like(kp)
         logits, kp, vp = tr.transformer_lm_decode(
@@ -203,7 +226,7 @@ def test_inactive_slots_null_block_isolation(params, paged):
     """Under the kernel gate, inactive (length-0) decode slots still write
     only to the reserved null block 0 and never corrupt live cache."""
     bs = 8
-    kp = jnp.zeros((CFG.n_layers, 8, bs, CFG.n_heads, CFG.d_head))
+    kp = jnp.zeros((CFG.n_layers, 8, bs, CFG.d_model))
     vp = jnp.zeros_like(kp)
     toks = np.array([[5], [7]], np.int32)
     pos = np.array([[0], [3]], np.int32)
@@ -312,7 +335,7 @@ def test_sharded_kernel_bitwise_matches_unsharded(paged):
     B, T, H, D = 3, 1, 4, 8
     nb, bs, W = 8, 4, 3
     mk = lambda *s: jnp.asarray(rs.randn(*s), jnp.float32)
-    q, kp, vp = mk(B, T, H, D), mk(nb, bs, H, D), mk(nb, bs, H, D)
+    q, kp, vp = mk(B, T, H, D), mk(nb, bs, H * D), mk(nb, bs, H * D)
     tables = np.array([[1, 2, 0], [3, 0, 0], [4, 5, 1]], np.int32)
     positions = np.array([[6], [2], [9]], np.int32)
     max_pos = np.array([6, 2, 9], np.int32)

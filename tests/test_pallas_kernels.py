@@ -176,7 +176,7 @@ def test_batch_norm_pallas_env_flag(monkeypatch):
 @pytest.mark.pallas
 def test_layer_norm_fused_parity():
     """Fused LN stats+normalize kernel: forward AND grads match the jnp
-    two-pass reference; bf16 preserved; odd row counts fall back."""
+    two-pass reference; bf16 preserved; odd row counts are padded."""
     rng = np.random.RandomState(7)
     x = rng.randn(4, 8, 256).astype(np.float32) * 2 + 0.5
     g = rng.rand(256).astype(np.float32) + 0.5
@@ -205,7 +205,7 @@ def test_layer_norm_fused_parity():
                                jnp.asarray(g), jnp.asarray(b))
     assert outb.dtype == jnp.bfloat16
 
-    # odd row count (M = 3*5): kernel-hostile, must fall back cleanly
+    # odd row count (M = 3*5): padded to a legal block, still the kernel
     xo = rng.randn(3, 5, 128).astype(np.float32)
     oo = pk.layer_norm_fused(jnp.asarray(xo), jnp.asarray(g[:128]),
                              jnp.asarray(b[:128]))
@@ -213,6 +213,29 @@ def test_layer_norm_fused_parity():
     ref_o = (xo - mu) / np.sqrt(xo.var(-1, keepdims=True) + 1e-5) \
         * g[:128] + b[:128]
     np.testing.assert_allclose(np.asarray(oo), ref_o, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("rows", [1, 4, 15, 1023])
+def test_layer_norm_runs_the_kernel_at_any_row_count(rows):
+    """No quiet reference: row counts that are not a multiple of 8 (the
+    engine's max_slots=4 decode step, the 1023 prefill bucket) are padded
+    to a legal block and still go through the Pallas call."""
+    x = jnp.ones((rows, 128), jnp.float32)
+    g, b = jnp.ones((128,)), jnp.zeros((128,))
+    jaxpr = str(jax.make_jaxpr(
+        lambda x_: pk.layer_norm_fused(x_, g, b))(x))
+    assert "pallas_call" in jaxpr
+    assert pk.layer_norm_fused(x, g, b).shape == (rows, 128)
+
+
+@pytest.mark.pallas
+def test_bn_fused_reference_fallback_warns_with_shape():
+    """bn_train_fused cannot pad batch statistics for free: a row count
+    that is not a multiple of 8 runs the reference, and says so."""
+    x = jnp.ones((3, 5, 128), jnp.float32)
+    with pytest.warns(RuntimeWarning, match=r"15 rows.*\(15, 128\)"):
+        pk.bn_train_fused(x, jnp.ones((128,)), jnp.zeros((128,)), 1e-5, 2)
 
 
 @pytest.mark.pallas
@@ -284,26 +307,17 @@ def test_transformer_train_step_grads_under_gate(monkeypatch):
 
 
 def test_env_name_canonical_and_alias(monkeypatch):
-    """TPUMX_PALLAS_INTERPRET is canonical; the old MXTPU_ spelling still
-    works but warns once."""
-    import warnings
-
+    """TPUMX_PALLAS_INTERPRET is the one spelling; the pre-rename MXTPU_
+    alias is gone and no longer read."""
     monkeypatch.delenv("TPUMX_PALLAS_INTERPRET", raising=False)
-    monkeypatch.delenv("MXTPU_PALLAS_INTERPRET", raising=False)
     monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "1")
     assert pk._use_interpret() is True
     monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
     assert pk._use_interpret() is False
     monkeypatch.delenv("TPUMX_PALLAS_INTERPRET")
-    monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setattr(pk, "_ALIAS_WARNED", False)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        assert pk._use_interpret() is True
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught)
-    # canonical wins when both are set
-    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
-    assert pk._use_interpret() is False
+    default = pk._use_interpret()          # CPU tests: the interpreter
+    monkeypatch.setenv("MXTPU_" + "PALLAS_INTERPRET", "0")
+    assert pk._use_interpret() is default
 
 
 @pytest.mark.pallas

@@ -226,14 +226,14 @@ def test_broadcast_validates_src_and_matches():
 
     mesh = make_mesh(dp=8)
     with pytest.raises(ValueError):
-        collectives.shard_map_compat(
+        jax.shard_map(
             lambda x: collectives.broadcast(x, "dp", src=12),
             mesh=mesh, in_specs=P("dp"),
-            out_specs=P("dp"))(jnp.arange(8.0))
-    out = collectives.shard_map_compat(
+            out_specs=P("dp"), check_vma=False)(jnp.arange(8.0))
+    out = jax.shard_map(
         lambda x: collectives.broadcast(x, "dp", src=3),
         mesh=mesh, in_specs=P("dp"),
-        out_specs=P("dp"))(jnp.arange(8.0))
+        out_specs=P("dp"), check_vma=False)(jnp.arange(8.0))
     assert np.allclose(np.asarray(out), 3.0)
 
 
@@ -249,8 +249,8 @@ def test_reduce_scatter_allgather_equals_allreduce():
         return collectives.allgather(
             collectives.reduce_scatter(local, "dp"), "dp")[None]
 
-    y = collectives.shard_map_compat(rt, mesh=mesh, in_specs=P("dp"),
-                                     out_specs=P("dp"))(x)
+    y = jax.shard_map(rt, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
+                      check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(y),
                                np.repeat(np.asarray(x).sum(0)[None], 8, 0),
                                rtol=1e-6)

@@ -19,7 +19,6 @@ from jax.sharding import PartitionSpec as P
 import mxnet_tpu as mx
 from mxnet_tpu import sym
 from mxnet_tpu.executor import compile_cache_stats
-from mxnet_tpu.parallel.collectives import shard_map_compat
 from mxnet_tpu.parallel.mesh import make_mesh
 from mxnet_tpu.parallel.pipeline import (pipeline_apply,
                                          pipeline_apply_sharded, psum_bcast)
@@ -72,8 +71,8 @@ def test_pipeline_apply_forward_and_grads_match_sequential():
         loss, g_my = jax.value_and_grad(f)(my_w)
         return loss, lax.all_gather(g_my, "pp", axis=0, tiled=False)
 
-    fn = shard_map_compat(inner, mesh=mesh, in_specs=(P(), P(), P()),
-                          out_specs=(P(), P()), check=False)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(P(), P(), P()),
+                       out_specs=(P(), P()), check_vma=False)
     loss, g_Ws = jax.jit(fn)(Ws, X, ct)
 
     def oracle(Ws):

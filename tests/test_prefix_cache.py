@@ -350,9 +350,14 @@ def test_router_shared_prefix_affinity(params):
         config=RouterConfig(num_replicas=2, affinity=True))
     rs = np.random.RandomState(2)
     shared = rs.randint(0, CFG.vocab, 16)
-    hs = [router.submit(np.concatenate([shared,
-                                        rs.randint(0, CFG.vocab, 4)]),
-                        max_new_tokens=4) for _ in range(5)]
+    prompts = [np.concatenate([shared, rs.randint(0, CFG.vocab, 4)])
+               for _ in range(5)]
+    # the first request runs alone, so its prefix blocks are indexed
+    # before the other four are admitted: whether a request submitted in
+    # the same instant hits or misses is a thread race, not the subject
+    hs = [router.submit(prompts[0], max_new_tokens=4)]
+    assert len(hs[0].result(180)) == 4
+    hs += [router.submit(p, max_new_tokens=4) for p in prompts[1:]]
     for h in hs:
         assert len(h.result(180)) == 4
     replicas = {h.replica for h in hs}

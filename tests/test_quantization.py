@@ -419,7 +419,7 @@ def test_int8_kv_decode_logits_close(lm_params):
     pos = np.arange(T, dtype=np.int32)[None, :]
     ln = np.array([T], np.int32)
     tables = np.array([[1, 2, 3, 4]], np.int32)
-    shape = (CFG.n_layers, 8, bs, CFG.n_heads, CFG.d_head)
+    shape = (CFG.n_layers, 8, bs, CFG.d_model)
     lf, kf, vf = tr.transformer_lm_decode(
         lm_params, toks, pos, ln, jnp.zeros(shape), jnp.zeros(shape),
         tables, CFG, attention_kernel="gather")
@@ -513,7 +513,7 @@ def test_int8_kv_paged_kernel_matches_gather(lm_params, monkeypatch):
     pos = np.arange(T, dtype=np.int32)[None, :]
     ln = np.array([T], np.int32)
     tables = np.array([[1, 2, 3, 4]], np.int32)
-    shape = (CFG.n_layers, 8, bs, CFG.n_heads, CFG.d_head)
+    shape = (CFG.n_layers, 8, bs, CFG.d_model)
     sc = jnp.ones((CFG.n_layers, 8, CFG.n_heads))
     lg, kg, vg, ksg, vsg = tr.transformer_lm_decode(
         lm_params, toks, pos, ln, jnp.zeros(shape, jnp.int8),

@@ -266,19 +266,22 @@ def test_preemption_mid_speculation_bit_identical(params):
     """A pool too small for both worst cases forces preemption while
     speculative decoding is active; the preempted request resumes via
     re-prefill and still matches the greedy oracle bit-for-bit."""
+    # admission secures the context plus one verify chunk (20 + 5 positions
+    # = 4 blocks of 8) per request: 9 usable blocks admit both, and growing
+    # to the 5 blocks each needs by the end (20 + 20 positions) cannot fit
     svc = GenerationService(
         params, CFG,
-        _gc(max_slots=2, num_blocks=8, preemption=True, speculative=True,
+        _gc(max_slots=2, num_blocks=10, preemption=True, speculative=True,
             draft_k=4),
         start=False)
     prompts = [np.tile([1, 2, 3, 4, 5], 4), np.tile([7, 8, 9, 2], 5)]
-    hs = [svc.submit(p, max_new_tokens=12) for p in prompts]
+    hs = [svc.submit(p, max_new_tokens=20) for p in prompts]
     svc.start()
     outs = [h.result(180) for h in hs]
     stats = svc.stats()
     svc.stop()
     for p, got in zip(prompts, outs):
-        assert got == _greedy_oracle(params, p, 12)
+        assert got == _greedy_oracle(params, p, 20)
     assert stats["counts"]["preempted"] >= 1, \
         "the tight pool must have forced at least one preemption"
     assert stats["speculative"]["spec_steps"] >= 1

@@ -218,7 +218,6 @@ def test_tpu_sync_in_program_collectives():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from mxnet_tpu.parallel.collectives import shard_map_compat
     from mxnet_tpu.parallel.mesh import dp_mesh
 
     kv = mx.kv.create("tpu_sync")
@@ -228,16 +227,16 @@ def test_tpu_sync_in_program_collectives():
     def reduce_fn(v):
         return kv.reduce_in_program({"g": v})["g"]
 
-    out = shard_map_compat(reduce_fn, mesh=mesh, in_specs=P("dp"),
-                           out_specs=P("dp"), check=False)(x)
+    out = jax.shard_map(reduce_fn, mesh=mesh, in_specs=P("dp"),
+                        out_specs=P("dp"), check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(out),
                                np.full(NDEV, np.arange(NDEV).sum()))
 
     def bcast_fn(v):
         return kv.broadcast_in_program({"w": v}, src=3)["w"]
 
-    out = shard_map_compat(bcast_fn, mesh=mesh, in_specs=P("dp"),
-                           out_specs=P("dp"), check=False)(x)
+    out = jax.shard_map(bcast_fn, mesh=mesh, in_specs=P("dp"),
+                        out_specs=P("dp"), check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(out), np.full(NDEV, 3.0))
 
 

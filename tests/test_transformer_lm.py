@@ -176,7 +176,7 @@ def test_prefill_vs_decode_logits_parity(compute_dtype):
         jax.tree_util.tree_map(lambda p: p.astype(compute_dtype), params)
     plen, extra, bs = 11, 4, 8
     tokens = RS.randint(0, CFG.vocab, plen + extra).astype(np.int32)
-    kp = jnp.zeros((CFG.n_layers, 8, bs, CFG.n_heads, CFG.d_head),
+    kp = jnp.zeros((CFG.n_layers, 8, bs, CFG.d_model),
                    compute_dtype or jnp.float32)
     vp = jnp.zeros_like(kp)
     table = np.array([[1, 2]], np.int32)

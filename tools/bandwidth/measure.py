@@ -19,8 +19,6 @@ def measure(sizes_mb, iters=10, axis="dp"):
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from mxnet_tpu.parallel.collectives import shard_map_compat
-
     devices = np.asarray(jax.devices())
     mesh = Mesh(devices, (axis,))
     n = len(devices)
@@ -31,10 +29,10 @@ def measure(sizes_mb, iters=10, axis="dp"):
 
         @jax.jit
         def allreduce(x):
-            return shard_map_compat(
+            return jax.shard_map(
                 lambda v: jax.lax.psum(v, axis),
                 mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-                check=True)(x)
+                check_vma=True)(x)
 
         allreduce(x).block_until_ready()  # compile
         t0 = time.perf_counter()

@@ -42,8 +42,18 @@ def worker_env(args, rank):
 def launch_local(args, command):
     procs = []
     for rank in range(args.num_workers):
-        p = subprocess.Popen(command, shell=True,
-                             env=worker_env(args, rank))
+        env = worker_env(args, rank)
+        if args.num_workers > 1 and "JAX_PLATFORMS" not in env:
+            # a chip belongs to ONE process: several workers on this host
+            # cannot each hold it, so a local multi-worker launch is a CPU
+            # rehearsal of the job's wiring (docs/multichip.md).  Export
+            # JAX_PLATFORMS yourself to place workers differently.
+            env["JAX_PLATFORMS"] = "cpu"
+            if rank == 0:
+                print(f"launch.py: {args.num_workers} local workers -> "
+                      "JAX_PLATFORMS=cpu (CPU rehearsal; one process per "
+                      "chip)", file=sys.stderr)
+        p = subprocess.Popen(command, shell=True, env=env)
         procs.append(p)
     rc = 0
     for p in procs:
