@@ -1035,9 +1035,53 @@ class Executor:
         f32 master in-program and recast the weight from it each step.
         """
         from . import engine as _engine
-        from .optimizer import (_pack_state, _unpack_state_into,
-                                fused_counts_uniform, fused_update_plan,
-                                uniquify_donated)
+        from .optimizer import _unpack_state_into
+
+        # two spans, so that a device-idle gap names its owner: the host
+        # work before dispatch, and the dispatch itself
+        with _tracing.span("executor.feed", cat="executor"):
+            fn, args, tele_on, rng = self._fused_feed(
+                optimizer, states, updates, feed, num_steps, kvstore,
+                loss_scaler)
+        with _tracing.span("executor.fused_step", cat="executor"):
+            res = fn(*args)
+        gnames = self._grad_arg_names
+        if tele_on:
+            res, tele_vals = res[:-1], res[-1]
+            self._note_telemetry(tele_vals)
+        if loss_scaler is None:
+            outs, aux_updates, new_grads, new_p, new_s = res
+        else:
+            outs, aux_updates, new_grads, new_p, new_s, new_sc = res
+            loss_scaler.set_state(new_sc)
+        self._outputs = [NDArray(o) for o in outs]
+        for k, v in aux_updates.items():
+            self.aux_dict[k]._data = v
+        for n in gnames:
+            self.arg_dict[n]._data = new_p[n]
+            self.grad_dict[n]._data = new_grads[n]
+            _unpack_state_into(states[n], new_s[n])
+        self._cached_grads = None
+        self._last_rng = rng
+        if _engine.is_naive():  # NaiveEngine forces sync, as everywhere else
+            for o in self._outputs:
+                o.wait_to_read()
+            for n in gnames:
+                self.arg_dict[n].wait_to_read()
+        if self._monitor_callback is not None:
+            for name, out in zip(self._out_names, self._outputs):
+                self._monitor_callback(name, out)
+        return self._outputs
+
+    def _fused_feed(self, optimizer, states, updates, feed, num_steps,
+                    kvstore, loss_scaler):
+        """The host part of :meth:`fused_step` before dispatch: checks, the
+        fed batch, the step's scalars, the cached program and its
+        arguments (placed on the mesh under SPMD, donated buffers made
+        unique).  Returns ``(fn, args, telemetry_on, rng)``."""
+        from . import engine as _engine
+        from .optimizer import (_pack_state, fused_counts_uniform,
+                                fused_update_plan, uniquify_donated)
 
         if self._grouped is not None:
             raise MXNetError("fused_step does not support group2ctx placement")
@@ -1161,34 +1205,7 @@ class Executor:
                     a.shape, a.dtype,
                     sharding=a.sharding if getattr(a, "committed", False)
                     else None), args))
-        with _tracing.span("executor.fused_step", cat="executor"):
-            res = fn(*args)
-        if tele_on:
-            res, tele_vals = res[:-1], res[-1]
-            self._note_telemetry(tele_vals)
-        if loss_scaler is None:
-            outs, aux_updates, new_grads, new_p, new_s = res
-        else:
-            outs, aux_updates, new_grads, new_p, new_s, new_sc = res
-            loss_scaler.set_state(new_sc)
-        self._outputs = [NDArray(o) for o in outs]
-        for k, v in aux_updates.items():
-            self.aux_dict[k]._data = v
-        for n in gnames:
-            self.arg_dict[n]._data = new_p[n]
-            self.grad_dict[n]._data = new_grads[n]
-            _unpack_state_into(states[n], new_s[n])
-        self._cached_grads = None
-        self._last_rng = rng
-        if _engine.is_naive():  # NaiveEngine forces sync, as everywhere else
-            for o in self._outputs:
-                o.wait_to_read()
-            for n in gnames:
-                self.arg_dict[n].wait_to_read()
-        if self._monitor_callback is not None:
-            for name, out in zip(self._out_names, self._outputs):
-                self._monitor_callback(name, out)
-        return self._outputs
+        return fn, args, tele_on, rng
 
     def fused_step_hlo(self) -> str:
         """Optimised HLO text of the program the last :meth:`fused_step`

@@ -360,14 +360,9 @@ class PrefetchingIter(DataIter):
         stop = self._stop
 
         def worker():
-            from .observability import tracing as _tracing
-
             while not stop.is_set():
                 try:
-                    # one span per prefetched batch: host decode time lines
-                    # up against device compute in the unified timeline
-                    with _tracing.span("io.prefetch", cat="io"):
-                        batches = [it.next() for it in self.iters]
+                    batches = [it.next() for it in self.iters]
                 except StopIteration:
                     q.put((gen, None))
                     return

@@ -305,7 +305,8 @@ class BaseModule:
                 end_of_batch = False
                 eval_name_vals = []
                 try:
-                    next_data_batch = next(data_iter)
+                    with _obs.span("fit.input_wait", cat="fit"):
+                        next_data_batch = next(data_iter)
                 except StopIteration:  # resumed exactly at the epoch end
                     end_of_batch = True
                     eval_name_vals = eval_metric.get_name_value()
@@ -318,12 +319,15 @@ class BaseModule:
                         if not self._try_fused_step(data_batch):
                             self.forward_backward(data_batch)
                             self.update()
-                        if isinstance(data_batch, list):
-                            self.update_metric(eval_metric,
-                                               [db.label for db in data_batch],
-                                               pre_sliced=True)
-                        else:
-                            self.update_metric(eval_metric, data_batch.label)
+                        with _obs.span("fit.update_metric", cat="fit"):
+                            if isinstance(data_batch, list):
+                                self.update_metric(
+                                    eval_metric,
+                                    [db.label for db in data_batch],
+                                    pre_sliced=True)
+                            else:
+                                self.update_metric(eval_metric,
+                                                   data_batch.label)
                     step_hist.observe(time.perf_counter() - step_tic)
                     _global_step += 1
                     if _ckpt is not None and _ckpt.after_batch(
@@ -334,8 +338,11 @@ class BaseModule:
                         preempted = True
                         break
                     try:
-                        next_data_batch = next(data_iter)
-                        self.prepare(next_data_batch, sparse_row_id_fn=sparse_row_id_fn)
+                        with _obs.span("fit.input_wait", cat="fit"):
+                            next_data_batch = next(data_iter)
+                        with _obs.span("fit.prepare", cat="fit"):
+                            self.prepare(next_data_batch,
+                                         sparse_row_id_fn=sparse_row_id_fn)
                     except StopIteration:
                         end_of_batch = True
                     if monitor is not None:
@@ -345,8 +352,11 @@ class BaseModule:
                     if batch_end_callback is not None:
                         params = BatchEndParam(epoch=epoch, nbatch=nbatch,
                                                eval_metric=eval_metric, locals=locals())
-                        for cb in _as_list(batch_end_callback):
-                            cb(params)
+                        # a callback that reads the metric is where the
+                        # step's device sync lands
+                        with _obs.span("fit.callbacks", cat="fit"):
+                            for cb in _as_list(batch_end_callback):
+                                cb(params)
                     nbatch += 1
 
                 if preempted:

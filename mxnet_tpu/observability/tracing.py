@@ -36,8 +36,11 @@ span timing/profiler behavior — and everything the engine computes — stays
 byte-identical (docs/observability.md).  Cost discipline with tracing on
 and the profiler stopped: a span is two ``time.perf_counter`` calls, a
 list push/pop, and one deque append — cheap enough for per-batch and
-per-decode-step scopes (bench.py's ``tracing_overhead`` block holds the
-line at < 2%).
+per-decode-step scopes.  On the chip (PERF.md section 6, PR 24) the ten
+spans of a 106 ms engine iteration and the seven of a 203 ms fit step do
+not show: tokens/s at the default and with ``TPUMX_TRACING=0`` read within
+0.4% of each other on two seeds of three and inside the cell's own 4%
+run-to-run spread on the third.
 
 Whether a span emits a *profiler* event is captured at entry (same rule as
 ``profiler.scope``): a span that started under a stopped profiler emits
@@ -179,11 +182,16 @@ class span:
     Under an active :class:`TraceContext` (inherited thread-locally, or
     forced with ``ctx=``) the span gets a span id, parents onto the
     context, narrows the context to itself for the body, and lands in the
-    trace ring with its ids on exit."""
+    trace ring with its ids on exit.
+
+    ``duration_us`` holds the span's own two clock reads once it has
+    exited (None while open, read-only by convention): a caller that keeps
+    a counter at the same boundary adds it up — ``GenerationService``'s
+    ``stats()["phase_ms"]`` — whether or not anything records the span."""
 
     __slots__ = ("name", "cat", "args", "_t0", "_active", "_jax_ctx",
                  "_ctx_in", "_span_id", "_trace_id", "_parent_id",
-                 "_ctx_token", "_traced")
+                 "_ctx_token", "_traced", "duration_us")
 
     def __init__(self, name: str, cat: str = "obs", args: Optional[dict]
                  = None, ctx: Optional[TraceContext] = None):
@@ -191,6 +199,7 @@ class span:
         self.cat = cat
         self.args = args
         self._ctx_in = ctx
+        self.duration_us = None
 
     def __enter__(self):
         stack = getattr(_tls, "stack", None)
@@ -231,6 +240,7 @@ class span:
 
     def __exit__(self, *exc):
         t1 = time.perf_counter() * 1e6
+        self.duration_us = dur = t1 - self._t0
         stack = getattr(_tls, "stack", None)
         if stack:
             stack.pop()
@@ -247,13 +257,13 @@ class span:
                 self.args["span_id"] = self._span_id
                 self.args["parent_span_id"] = self._parent_id
             _ring_append(self.name, self.cat, self._trace_id, self._span_id,
-                         self._parent_id, self._t0, t1 - self._t0, self.args)
+                         self._parent_id, self._t0, dur, self.args)
         # force=True (never a flip of the shared running flag) records a
         # span that was entered under a live profiler even if stop() landed
         # inside it; one entered while stopped stays unrecorded either way
         if self._active:
             _profiler._emit("X", self.name, self.cat, ts=self._t0,
-                            dur=t1 - self._t0, args=self.args, force=True)
+                            dur=dur, args=self.args, force=True)
         return False
 
 

@@ -183,6 +183,7 @@ def _fwd_call(q3, k3, v3, t_real, causal, bq, bk, scale, interpret,
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32)],
         interpret=interpret,
+        name="_fwd_call_flash",
     )(q3, k3, v3)
 
 
@@ -301,6 +302,7 @@ def _bwd_call(q3, k3, v3, g3, lse, delta, t_real, causal, bq, bk, scale,
         out_shape=_sds((bh, t_pad, d), q3.dtype, q3),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="_bwd_call_flash_dq",
     )(q3, k3, v3, g3, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, bq=bq, bk=bk, causal=causal,
@@ -314,6 +316,7 @@ def _bwd_call(q3, k3, v3, g3, lse, delta, t_real, causal, bq, bk, scale,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="_bwd_call_flash_dkv",
     )(q3, k3, v3, g3, lse, delta)
     return dq, dk, dv
 

@@ -161,6 +161,15 @@ def _query_tile(t: int, hd: int) -> int:
     return t if t <= cap else cap
 
 
+def _call_name(t: int, w: int) -> str:
+    """The kernel's name in a device trace: decode and prefill apart, one
+    name per block-table width (and per prefill chunk length).  It starts
+    with the wrapper's name, which trace readers search for, and ends in
+    a letter: a reader that groups operations strips a trailing number."""
+    return f"_paged_call_w{w}_decode" if t == 1 \
+        else f"_paged_call_w{w}_t{t}_prefill"
+
+
 @functools.partial(jax.jit,
                    static_argnames=("n_heads", "scale", "interpret"))
 def _paged_call(tables, max_pos, q, positions, k_pool, v_pool, k_scale=None,
@@ -217,6 +226,7 @@ def _paged_call(tables, max_pos, q, positions, k_pool, v_pool, k_scale=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, t_pad, HD), q.dtype),
         interpret=interpret,
+        name=_call_name(T, W),
     )(*args)
     return out[:, :T]
 

@@ -84,6 +84,7 @@ def _pack_call(g2d, res2d, thresh, interpret):
         out_shape=(jax.ShapeDtypeStruct((rows, 128), jnp.uint32),
                    jax.ShapeDtypeStruct(g2d.shape, g2d.dtype)),
         interpret=interpret,
+        name="_pack_call",
     )(g2d, res2d, thresh)
 
 
@@ -99,6 +100,7 @@ def _unpack_call(packed2d, thresh, dtype, interpret):
         out_specs=pl.BlockSpec((rb, _LANES * 128), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, _LANES * 128), dtype),
         interpret=interpret,
+        name="_unpack_call",
     )(packed2d, thresh)
 
 
@@ -200,6 +202,7 @@ def _bn_stats_call(x2d, pivot, block_m, interpret):
         out_shape=(jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)),
         interpret=interpret,
+        name="_bn_stats_call",
     )(x2d, pivot.reshape(1, c))
     return s1[0], s2[0]
 
@@ -221,6 +224,7 @@ def _bn_norm_call(x2d, scale, shift, block_m, interpret):
         out_specs=pl.BlockSpec((block_m, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, c), x2d.dtype),
         interpret=interpret,
+        name="_bn_norm_call",
     )(x2d, scale.reshape(1, c), shift.reshape(1, c))
 
 
@@ -360,6 +364,7 @@ def _ln_call(x2d, gamma, beta, eps, gelu, block_m, interpret):
         out_shape=jax.ShapeDtypeStruct((m, c), x2d.dtype,
                                        vma=jax.typeof(x2d).vma),
         interpret=interpret,
+        name="_ln_call_gelu" if gelu else "_ln_call",
     )(x2d, gamma.reshape(1, c), beta.reshape(1, c))
 
 

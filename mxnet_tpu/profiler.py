@@ -70,7 +70,13 @@ def start(profile_process="worker"):
         import jax
 
         _state["jax_trace_dir"] = trace_dir
-        jax.profiler.start_trace(trace_dir)
+        # host spans and device operations only: jax's Python tracer
+        # records every frame and slows the host loop by a third on the
+        # chip (PERF.md), so it runs only when set_config(profile_api=True)
+        # asked for API frames
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if _state.get("api") else 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
 
 
 def stop(profile_process="worker"):

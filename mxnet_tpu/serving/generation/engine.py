@@ -376,6 +376,27 @@ class _GenRequest:
         return self.seq_tokens[self.prompt_len:]
 
 
+class _Phase:
+    """A span of the engine loop whose duration is also added to one key
+    of ``stats()["phase_ms"]``: the counter and the span share one pair of
+    clock reads, so the two agree whether or not a profiler runs
+    (docs/observability.md)."""
+
+    __slots__ = ("_acc", "_key", "_span")
+
+    def __init__(self, acc: Dict[str, float], key: str, span):
+        self._acc, self._key, self._span = acc, key, span
+
+    def __enter__(self):
+        self._span.__enter__()
+        return self._span
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._acc[self._key] += self._span.duration_us
+        return False
+
+
 class GenerationStream:
     """Per-request handle: iterate generated tokens as they stream, or
     block on :meth:`result` for the full list."""
@@ -643,6 +664,10 @@ class GenerationService:
                         "draft_proposed": 0, "draft_accepted": 0,
                         "spec_steps": 0, "multistep_steps": 0}
         self._peak_occupancy = 0.0
+        # host microseconds of the loop by phase, from the phase spans' own
+        # clock reads (written by the engine thread only)
+        self._phase_us = {"schedule": 0.0, "build": 0.0, "step": 0.0,
+                          "emit": 0.0, "idle_wait": 0.0}
         self._ttft: "deque[float]" = deque(maxlen=4096)
         self._itl: "deque[float]" = deque(maxlen=4096)
         self._token_times: "deque[float]" = deque(maxlen=8192)
@@ -1039,60 +1064,89 @@ class GenerationService:
         self.stop(drain=True)
 
     # -- the engine loop ----------------------------------------------------------
+    def _phase(self, phase: str, name: str, args: Optional[dict] = None,
+               ctx=None) -> "_Phase":
+        return _Phase(self._phase_us, phase,
+                      _obs.span(name, cat="serving", args=args, ctx=ctx))
+
     def _loop(self) -> None:
         while True:
-            admitted: List[_GenRequest] = []
             with self._lock:
                 if self._killed:
                     return  # crashed-replica simulation: vanish, no cleanup
-                self._purge_waiting_locked()
-                self._evict_locked()
-                if self._closed and not self._drain:
-                    err = ServingClosedError("generation service shut down")
-                    for r in list(self._waiting):
-                        self._finish_locked(r, error=err)
-                    self._waiting.clear()
-                    for i, r in enumerate(self._slots):
-                        if r is not None:
-                            self._release_slot_locked(i, error=err)
+                if not self._closed and not self._waiting \
+                        and all(r is None for r in self._slots):
+                    # nothing queued, nothing running: not an iteration
                     self._update_gauges_locked()
-                    return
-                if self._config.preemption:
-                    self._watermark_preempt_locked()
-                    self._grow_blocks_locked()
-                admitted = self._admit_locked()
-                active = [r for r in self._slots if r is not None]
-                if not active and not admitted:
-                    if self._closed and not self._waiting:
-                        return
-                    self._update_gauges_locked()
-                    self._not_empty.wait(0.05)
+                    with self._phase("idle_wait", "serving.idle_wait"):
+                        self._not_empty.wait(0.05)
                     continue
-                # per-iteration progress snapshot: the blast-radius guard
-                # distinguishes requests the failing step advanced from
-                # untouched ones (the latter are requeued, never failed)
-                progress = {r.rid: r.n_generated
-                            for r in self._slots if r is not None}
-            try:
-                for req in admitted:
-                    try:
-                        self._prefill(req)
-                    except Exception as exc:  # noqa: BLE001 — isolate
-                        self._requeue_or_fail(req, exc)
-                running = [r for r in self._slots
-                           if r is not None and r.state == _RUNNING]
-                self._membership.append(
-                    (self._iteration,
-                     tuple(sorted(r.rid for r in running))))
-                if running:
-                    self._decode_isolated(running)
-            except Exception as exc:  # noqa: BLE001 — the loop must survive
-                # any per-iteration surprise with minimum blast radius:
-                # requeue what the failing iteration never touched
-                self._absorb_iteration_error(exc, progress)
-            self._iteration += 1
-            with self._lock:
+            with _obs.span("serving.iteration", cat="serving",
+                           args={"iteration": self._iteration}):
+                if not self._iterate():
+                    return
+
+    def _iterate(self) -> bool:
+        """One pass of the loop with requests queued or running: schedule
+        under the lock, prefill what was admitted, one decode step over
+        what runs.  False ends the loop."""
+        with self._phase("schedule", "serving.schedule"), self._lock:
+            if self._killed:
+                return False
+            self._purge_waiting_locked()
+            with _obs.span("serving.evict", cat="serving"):
+                self._evict_locked()
+            if self._closed and not self._drain:
+                err = ServingClosedError("generation service shut down")
+                for r in list(self._waiting):
+                    self._finish_locked(r, error=err)
+                self._waiting.clear()
+                for i, r in enumerate(self._slots):
+                    if r is not None:
+                        self._release_slot_locked(i, error=err)
                 self._update_gauges_locked()
+                return False
+            if self._config.preemption:
+                self._watermark_preempt_locked()
+                self._grow_blocks_locked()
+            with _obs.span("serving.admit", cat="serving"):
+                admitted = self._admit_locked()
+            active = [r for r in self._slots if r is not None]
+            if not active and not admitted:
+                # the pass emptied the queue (purged, or closed and
+                # drained), or its head cannot be admitted yet
+                if self._closed and not self._waiting:
+                    return False
+                self._update_gauges_locked()
+                if self._waiting:
+                    self._not_empty.wait(0.05)
+                return True
+            # per-iteration progress snapshot: the blast-radius guard
+            # distinguishes requests the failing step advanced from
+            # untouched ones (the latter are requeued, never failed)
+            progress = {r.rid: r.n_generated
+                        for r in self._slots if r is not None}
+        try:
+            for req in admitted:
+                try:
+                    self._prefill(req)
+                except Exception as exc:  # noqa: BLE001 — isolate
+                    self._requeue_or_fail(req, exc)
+            running = [r for r in self._slots
+                       if r is not None and r.state == _RUNNING]
+            self._membership.append(
+                (self._iteration,
+                 tuple(sorted(r.rid for r in running))))
+            if running:
+                self._decode_isolated(running)
+        except Exception as exc:  # noqa: BLE001 — the loop must survive
+            # any per-iteration surprise with minimum blast radius:
+            # requeue what the failing iteration never touched
+            self._absorb_iteration_error(exc, progress)
+        self._iteration += 1
+        with self._phase("schedule", "serving.schedule"), self._lock:
+            self._update_gauges_locked()
+        return True
 
     # -- scheduling (all _locked helpers hold self._lock) -------------------------
     def _purge_waiting_locked(self) -> None:
@@ -1650,20 +1704,21 @@ class GenerationService:
             now = time.perf_counter()
         r.seg("prefill", now)
         for (off, take, tb, wp) in plan:
-            self._cow_for_write(r, off, take)
-            table = _np.zeros((1, wp), _np.int32)
-            n = min(wp, len(r.blocks))
-            table[0, :n] = r.blocks[:n]
-            tokens = pad_tokens_right(
-                _np.asarray(r.seq_tokens[off:off + take], _np.int32),
-                tb)[None, :]
-            positions = _np.arange(off, off + tb, dtype=_np.int32)[None, :]
+            with self._phase("build", "serving.prefill.build"):
+                self._cow_for_write(r, off, take)
+                table = _np.zeros((1, wp), _np.int32)
+                n = min(wp, len(r.blocks))
+                table[0, :n] = r.blocks[:n]
+                tokens = pad_tokens_right(
+                    _np.asarray(r.seq_tokens[off:off + take], _np.int32),
+                    tb)[None, :]
+                positions = _np.arange(off, off + tb, dtype=_np.int32)[None, :]
             t_rung0 = time.perf_counter()
-            with _obs.span("serving.prefill", cat="serving",
-                           args={"rid": r.rid, "len": ctx,
-                                 "bucket": tb, "off": off,
-                                 "chunks": len(plan),
-                                 "resumed": resumed}, ctx=r.trace):
+            with self._phase("step", "serving.prefill",
+                             args={"rid": r.rid, "len": ctx,
+                                   "bucket": tb, "off": off,
+                                   "chunks": len(plan),
+                                   "resumed": resumed}, ctx=r.trace):
                 # the sampler reads the chunk's last VALID row; only the
                 # final chunk's sample (global position prompt_len-1, the
                 # same seed/counter as the unchunked program) is emitted —
@@ -1688,7 +1743,8 @@ class GenerationService:
         if resumed:
             return
         r.ctx_len = r.prompt_len
-        self._emit_token(r, int(next_tok[0]))
+        with self._phase("emit", "serving.emit"):
+            self._emit_token(r, int(next_tok[0]))
 
     def _decode_step(self, batch: List[_GenRequest]) -> None:
         """One decode iteration over exactly the requests in ``batch``
@@ -1722,43 +1778,44 @@ class GenerationService:
         per running row)."""
         cfg = self._config
         S = cfg.max_slots
-        # copy-on-write append: a slot about to scatter into a shared
-        # block (refcount > 1) gets a private copy first — shared prompt
-        # history is read-only to every writer (idempotent, so bisection
-        # re-entry is safe)
-        if self._prefix is not None:
-            for r in batch:
-                if r.state == _RUNNING:
-                    self._cow_for_write(r, r.ctx_len, 1)
-        rids = {r.rid for r in batch}
-        tokens = _np.zeros((S, 1), _np.int32)
-        positions = _np.zeros((S, 1), _np.int32)
-        lengths = _np.zeros(S, _np.int32)
-        seeds = _np.zeros(S, _np.uint32)
-        counters = _np.zeros(S, _np.uint32)
-        temperature = _np.zeros(S, _np.float32)
-        top_k = _np.zeros(S, _np.int32)
-        top_p = _np.ones(S, _np.float32)
-        max_w = 1
-        for i, r in enumerate(self._slots):
-            if r is None or r.state != _RUNNING or r.rid not in rids:
-                continue
-            tokens[i, 0] = r.seq_tokens[r.ctx_len]
-            positions[i, 0] = r.ctx_len
-            lengths[i] = 1
-            seeds[i] = r.seed
-            counters[i] = r.ctx_len + 1  # index of the token being produced
-            temperature[i] = r.temperature
-            top_k[i] = r.top_k
-            top_p[i] = r.top_p
-            max_w = max(max_w, blocks_for(r.ctx_len + 1, cfg.block_size))
-        w = bucket_batch(max_w, self._width_buckets)
-        tables = _np.zeros((S, w), _np.int32)
-        for i, r in enumerate(self._slots):
-            if r is None or r.state != _RUNNING or r.rid not in rids:
-                continue
-            n = min(w, len(r.blocks))
-            tables[i, :n] = r.blocks[:n]
+        with self._phase("build", "serving.decode.build"):
+            # copy-on-write append: a slot about to scatter into a shared
+            # block (refcount > 1) gets a private copy first — shared prompt
+            # history is read-only to every writer (idempotent, so bisection
+            # re-entry is safe)
+            if self._prefix is not None:
+                for r in batch:
+                    if r.state == _RUNNING:
+                        self._cow_for_write(r, r.ctx_len, 1)
+            rids = {r.rid for r in batch}
+            tokens = _np.zeros((S, 1), _np.int32)
+            positions = _np.zeros((S, 1), _np.int32)
+            lengths = _np.zeros(S, _np.int32)
+            seeds = _np.zeros(S, _np.uint32)
+            counters = _np.zeros(S, _np.uint32)
+            temperature = _np.zeros(S, _np.float32)
+            top_k = _np.zeros(S, _np.int32)
+            top_p = _np.ones(S, _np.float32)
+            max_w = 1
+            for i, r in enumerate(self._slots):
+                if r is None or r.state != _RUNNING or r.rid not in rids:
+                    continue
+                tokens[i, 0] = r.seq_tokens[r.ctx_len]
+                positions[i, 0] = r.ctx_len
+                lengths[i] = 1
+                seeds[i] = r.seed
+                counters[i] = r.ctx_len + 1  # index of the token produced
+                temperature[i] = r.temperature
+                top_k[i] = r.top_k
+                top_p[i] = r.top_p
+                max_w = max(max_w, blocks_for(r.ctx_len + 1, cfg.block_size))
+            w = bucket_batch(max_w, self._width_buckets)
+            tables = _np.zeros((S, w), _np.int32)
+            for i, r in enumerate(self._slots):
+                if r is None or r.state != _RUNNING or r.rid not in rids:
+                    continue
+                n = min(w, len(r.blocks))
+                tables[i, :n] = r.blocks[:n]
         # deterministic failure injection (TPUMX_FAULT_GEN_STEP_FAIL):
         # fires BEFORE dispatch, so the paged pool is never half-written
         if _fault_injector().gen_step_fail(rids):
@@ -1768,31 +1825,32 @@ class GenerationService:
                 f"(TPUMX_FAULT_GEN_STEP_FAIL) at iteration "
                 f"{self._iteration}, batch rids {sorted(rids)}")
         t_step0 = time.perf_counter()
-        with _obs.span("serving.decode", cat="serving",
-                       args={"running": len(batch), "width": int(w),
-                             "iteration": self._iteration}):
+        with self._phase("step", "serving.decode",
+                         args={"running": len(batch), "width": int(w),
+                               "iteration": self._iteration}):
             next_tok, _ = self._programs.run(
                 "gen_decode", self._cache, tokens, positions, lengths,
                 tables, seeds, counters, temperature, top_k, top_p)
         t_step1 = time.perf_counter()
-        traced = _trace.enabled()
-        for i, r in enumerate(self._slots):
-            if r is None or r.state != _RUNNING or r.rid not in rids:
-                continue
-            # Orca attribution: the ONE shared decode step fans out a
-            # child participation span per active request, so each trace
-            # still shows every step that advanced it
-            r.decode_steps += 1
-            if traced and r.trace is not None:
-                _trace.record_event(
-                    "serving.decode.participate", "serving", t_step0,
-                    t_step1, ctx=r.trace,
-                    args={"rid": r.rid, "iteration": self._iteration,
-                          "running": len(batch),
-                          "replica": self._replica_id})
-            r.ctx_len += 1
-            r.mode_tokens["single"] = r.mode_tokens.get("single", 0) + 1
-            self._emit_token(r, int(next_tok[i]))
+        with self._phase("emit", "serving.emit"):
+            traced = _trace.enabled()
+            for i, r in enumerate(self._slots):
+                if r is None or r.state != _RUNNING or r.rid not in rids:
+                    continue
+                # Orca attribution: the ONE shared decode step fans out a
+                # child participation span per active request, so each trace
+                # still shows every step that advanced it
+                r.decode_steps += 1
+                if traced and r.trace is not None:
+                    _trace.record_event(
+                        "serving.decode.participate", "serving", t_step0,
+                        t_step1, ctx=r.trace,
+                        args={"rid": r.rid, "iteration": self._iteration,
+                              "running": len(batch),
+                              "replica": self._replica_id})
+                r.ctx_len += 1
+                r.mode_tokens["single"] = r.mode_tokens.get("single", 0) + 1
+                self._emit_token(r, int(next_tok[i]))
 
     def _propose_drafts(self, batch: List[_GenRequest]) -> Dict[int, List[int]]:
         """Draft proposals per request id (possibly empty lists).  Each
@@ -1864,45 +1922,46 @@ class GenerationService:
         smax = max((len(drafts.get(r.rid, ())) for r in batch
                     if r.state == _RUNNING), default=0)
         tk = bucket_batch(smax + 1, self._verify_buckets)
-        # copy-on-write over the whole verify span: REJECTED writes land
-        # at positions >= ctx_len too, and must never touch a shared
-        # block — this is the rollback guarantee (shared prefix blocks
-        # are physically unreachable from a speculative scatter)
-        if self._prefix is not None:
-            for r in batch:
-                if r.state == _RUNNING:
-                    self._cow_for_write(
-                        r, r.ctx_len, len(drafts.get(r.rid, ())) + 1)
-        tokens = _np.zeros((S, tk), _np.int32)
-        positions = _np.zeros((S, tk), _np.int32)
-        lengths = _np.zeros(S, _np.int32)
-        seeds = _np.zeros(S, _np.uint32)
-        counters = _np.zeros(S, _np.uint32)
-        temperature = _np.zeros(S, _np.float32)
-        top_k = _np.zeros(S, _np.int32)
-        top_p = _np.ones(S, _np.float32)
-        max_w = 1
-        for i, r in enumerate(self._slots):
-            if r is None or r.state != _RUNNING or r.rid not in rids:
-                continue
-            fed = [r.seq_tokens[r.ctx_len]] + drafts.get(r.rid, [])
-            tokens[i, :len(fed)] = fed
-            positions[i] = r.ctx_len + _np.arange(tk, dtype=_np.int32)
-            lengths[i] = len(fed)
-            seeds[i] = r.seed
-            counters[i] = r.ctx_len + 1  # first produced-token index
-            temperature[i] = r.temperature
-            top_k[i] = r.top_k
-            top_p[i] = r.top_p
-            max_w = max(max_w, blocks_for(r.ctx_len + len(fed),
-                                          cfg.block_size))
-        w = bucket_batch(max_w, self._width_buckets)
-        tables = _np.zeros((S, w), _np.int32)
-        for i, r in enumerate(self._slots):
-            if r is None or r.state != _RUNNING or r.rid not in rids:
-                continue
-            n = min(w, len(r.blocks))
-            tables[i, :n] = r.blocks[:n]
+        with self._phase("build", "serving.decode.build"):
+            # copy-on-write over the whole verify span: REJECTED writes land
+            # at positions >= ctx_len too, and must never touch a shared
+            # block — this is the rollback guarantee (shared prefix blocks
+            # are physically unreachable from a speculative scatter)
+            if self._prefix is not None:
+                for r in batch:
+                    if r.state == _RUNNING:
+                        self._cow_for_write(
+                            r, r.ctx_len, len(drafts.get(r.rid, ())) + 1)
+            tokens = _np.zeros((S, tk), _np.int32)
+            positions = _np.zeros((S, tk), _np.int32)
+            lengths = _np.zeros(S, _np.int32)
+            seeds = _np.zeros(S, _np.uint32)
+            counters = _np.zeros(S, _np.uint32)
+            temperature = _np.zeros(S, _np.float32)
+            top_k = _np.zeros(S, _np.int32)
+            top_p = _np.ones(S, _np.float32)
+            max_w = 1
+            for i, r in enumerate(self._slots):
+                if r is None or r.state != _RUNNING or r.rid not in rids:
+                    continue
+                fed = [r.seq_tokens[r.ctx_len]] + drafts.get(r.rid, [])
+                tokens[i, :len(fed)] = fed
+                positions[i] = r.ctx_len + _np.arange(tk, dtype=_np.int32)
+                lengths[i] = len(fed)
+                seeds[i] = r.seed
+                counters[i] = r.ctx_len + 1  # first produced-token index
+                temperature[i] = r.temperature
+                top_k[i] = r.top_k
+                top_p[i] = r.top_p
+                max_w = max(max_w, blocks_for(r.ctx_len + len(fed),
+                                              cfg.block_size))
+            w = bucket_batch(max_w, self._width_buckets)
+            tables = _np.zeros((S, w), _np.int32)
+            for i, r in enumerate(self._slots):
+                if r is None or r.state != _RUNNING or r.rid not in rids:
+                    continue
+                n = min(w, len(r.blocks))
+                tables[i, :n] = r.blocks[:n]
         if _fault_injector().gen_step_fail(rids):
             from ...fault.inject import FaultInjectedError
             raise FaultInjectedError(
@@ -1910,51 +1969,52 @@ class GenerationService:
                 f"(TPUMX_FAULT_GEN_STEP_FAIL) at iteration "
                 f"{self._iteration}, batch rids {sorted(rids)}")
         t_step0 = time.perf_counter()
-        with _obs.span("serving.spec_verify", cat="serving",
-                       args={"running": len(batch), "width": int(w),
-                             "chunk": int(tk),
-                             "iteration": self._iteration}):
+        with self._phase("step", "serving.spec_verify",
+                         args={"running": len(batch), "width": int(w),
+                               "chunk": int(tk),
+                               "iteration": self._iteration}):
             target, accepted = self._programs.run_verify(
                 self._cache, tokens, positions, lengths, tables, seeds,
                 counters, temperature, top_k, top_p)
         t_step1 = time.perf_counter()
-        traced = _trace.enabled()
-        bs = cfg.block_size
-        quantized = self._cache.quantized
-        for i, r in enumerate(self._slots):
-            if r is None or r.state != _RUNNING or r.rid not in rids:
-                continue
-            s_i = int(lengths[i]) - 1  # drafts fed for this row
-            n_emit = int(accepted[i]) + 1
-            r.decode_steps += 1
-            emitted = self._emit_many(
-                r, [int(t) for t in target[i, :n_emit]])
-            acc = max(0, emitted - 1)
-            r.draft_proposed += s_i
-            r.draft_accepted += acc
-            r.mode_tokens["spec"] = r.mode_tokens.get("spec", 0) + emitted
-            self._counts["draft_proposed"] += s_i
-            self._counts["draft_accepted"] += acc
-            if s_i:
-                self._c_draft_proposed.inc(s_i)
-            if acc:
-                self._c_draft_accepted.inc(acc)
-            # int8 pool + partial rejection: the boundary block now holds
-            # accepted entries requantized under a scale that saw the
-            # rejected garbage — never index it for sharing (f32 pools
-            # need no such cap: every write is position-exact)
-            if quantized and s_i > acc and r.ctx_len % bs != 0:
-                safe = (r.ctx_len // bs) * bs
-                r.index_safe_len = (safe if r.index_safe_len is None
-                                    else min(r.index_safe_len, safe))
-            if traced and r.trace is not None:
-                _trace.record_event(
-                    "serving.decode.participate", "serving", t_step0,
-                    t_step1, ctx=r.trace,
-                    args={"rid": r.rid, "iteration": self._iteration,
-                          "running": len(batch), "mode": "spec",
-                          "proposed": s_i, "accepted": acc,
-                          "replica": self._replica_id})
+        with self._phase("emit", "serving.emit"):
+            traced = _trace.enabled()
+            bs = cfg.block_size
+            quantized = self._cache.quantized
+            for i, r in enumerate(self._slots):
+                if r is None or r.state != _RUNNING or r.rid not in rids:
+                    continue
+                s_i = int(lengths[i]) - 1  # drafts fed for this row
+                n_emit = int(accepted[i]) + 1
+                r.decode_steps += 1
+                emitted = self._emit_many(
+                    r, [int(t) for t in target[i, :n_emit]])
+                acc = max(0, emitted - 1)
+                r.draft_proposed += s_i
+                r.draft_accepted += acc
+                r.mode_tokens["spec"] = r.mode_tokens.get("spec", 0) + emitted
+                self._counts["draft_proposed"] += s_i
+                self._counts["draft_accepted"] += acc
+                if s_i:
+                    self._c_draft_proposed.inc(s_i)
+                if acc:
+                    self._c_draft_accepted.inc(acc)
+                # int8 pool + partial rejection: the boundary block now holds
+                # accepted entries requantized under a scale that saw the
+                # rejected garbage — never index it for sharing (f32 pools
+                # need no such cap: every write is position-exact)
+                if quantized and s_i > acc and r.ctx_len % bs != 0:
+                    safe = (r.ctx_len // bs) * bs
+                    r.index_safe_len = (safe if r.index_safe_len is None
+                                        else min(r.index_safe_len, safe))
+                if traced and r.trace is not None:
+                    _trace.record_event(
+                        "serving.decode.participate", "serving", t_step0,
+                        t_step1, ctx=r.trace,
+                        args={"rid": r.rid, "iteration": self._iteration,
+                              "running": len(batch), "mode": "spec",
+                              "proposed": s_i, "accepted": acc,
+                              "replica": self._replica_id})
         self._counts["spec_steps"] += 1
 
     def _choose_multistep_k(self, batch: List[_GenRequest]) -> int:
@@ -1996,38 +2056,39 @@ class GenerationService:
         cfg = self._config
         S = cfg.max_slots
         rids = {r.rid for r in batch if r.state == _RUNNING}
-        if self._prefix is not None:
-            for r in batch:
-                if r.state == _RUNNING:
-                    self._cow_for_write(r, r.ctx_len, k)
-        tokens = _np.zeros(S, _np.int32)
-        positions = _np.zeros(S, _np.int32)
-        lengths = _np.zeros(S, _np.int32)
-        seeds = _np.zeros(S, _np.uint32)
-        counters = _np.zeros(S, _np.uint32)
-        temperature = _np.zeros(S, _np.float32)
-        top_k = _np.zeros(S, _np.int32)
-        top_p = _np.ones(S, _np.float32)
-        max_w = 1
-        for i, r in enumerate(self._slots):
-            if r is None or r.state != _RUNNING or r.rid not in rids:
-                continue
-            tokens[i] = r.seq_tokens[r.ctx_len]
-            positions[i] = r.ctx_len
-            lengths[i] = 1
-            seeds[i] = r.seed
-            counters[i] = r.ctx_len + 1
-            temperature[i] = r.temperature
-            top_k[i] = r.top_k
-            top_p[i] = r.top_p
-            max_w = max(max_w, blocks_for(r.ctx_len + k, cfg.block_size))
-        w = bucket_batch(max_w, self._width_buckets)
-        tables = _np.zeros((S, w), _np.int32)
-        for i, r in enumerate(self._slots):
-            if r is None or r.state != _RUNNING or r.rid not in rids:
-                continue
-            n = min(w, len(r.blocks))
-            tables[i, :n] = r.blocks[:n]
+        with self._phase("build", "serving.decode.build"):
+            if self._prefix is not None:
+                for r in batch:
+                    if r.state == _RUNNING:
+                        self._cow_for_write(r, r.ctx_len, k)
+            tokens = _np.zeros(S, _np.int32)
+            positions = _np.zeros(S, _np.int32)
+            lengths = _np.zeros(S, _np.int32)
+            seeds = _np.zeros(S, _np.uint32)
+            counters = _np.zeros(S, _np.uint32)
+            temperature = _np.zeros(S, _np.float32)
+            top_k = _np.zeros(S, _np.int32)
+            top_p = _np.ones(S, _np.float32)
+            max_w = 1
+            for i, r in enumerate(self._slots):
+                if r is None or r.state != _RUNNING or r.rid not in rids:
+                    continue
+                tokens[i] = r.seq_tokens[r.ctx_len]
+                positions[i] = r.ctx_len
+                lengths[i] = 1
+                seeds[i] = r.seed
+                counters[i] = r.ctx_len + 1
+                temperature[i] = r.temperature
+                top_k[i] = r.top_k
+                top_p[i] = r.top_p
+                max_w = max(max_w, blocks_for(r.ctx_len + k, cfg.block_size))
+            w = bucket_batch(max_w, self._width_buckets)
+            tables = _np.zeros((S, w), _np.int32)
+            for i, r in enumerate(self._slots):
+                if r is None or r.state != _RUNNING or r.rid not in rids:
+                    continue
+                n = min(w, len(r.blocks))
+                tables[i, :n] = r.blocks[:n]
         if _fault_injector().gen_step_fail(rids):
             from ...fault.inject import FaultInjectedError
             raise FaultInjectedError(
@@ -2035,29 +2096,30 @@ class GenerationService:
                 f"(TPUMX_FAULT_GEN_STEP_FAIL) at iteration "
                 f"{self._iteration}, batch rids {sorted(rids)}")
         t_step0 = time.perf_counter()
-        with _obs.span("serving.multistep", cat="serving",
-                       args={"running": len(batch), "width": int(w),
-                             "k": int(k),
-                             "iteration": self._iteration}):
+        with self._phase("step", "serving.multistep",
+                         args={"running": len(batch), "width": int(w),
+                               "k": int(k),
+                               "iteration": self._iteration}):
             toks = self._programs.run_multistep(
                 k, self._cache, tokens, positions, lengths, tables,
                 seeds, counters, temperature, top_k, top_p)
         t_step1 = time.perf_counter()
-        traced = _trace.enabled()
-        for i, r in enumerate(self._slots):
-            if r is None or r.state != _RUNNING or r.rid not in rids:
-                continue
-            r.decode_steps += 1
-            emitted = self._emit_many(r, [int(t) for t in toks[i]])
-            r.mode_tokens["multistep"] = \
-                r.mode_tokens.get("multistep", 0) + emitted
-            if traced and r.trace is not None:
-                _trace.record_event(
-                    "serving.decode.participate", "serving", t_step0,
-                    t_step1, ctx=r.trace,
-                    args={"rid": r.rid, "iteration": self._iteration,
-                          "running": len(batch), "mode": "multistep",
-                          "k": int(k), "replica": self._replica_id})
+        with self._phase("emit", "serving.emit"):
+            traced = _trace.enabled()
+            for i, r in enumerate(self._slots):
+                if r is None or r.state != _RUNNING or r.rid not in rids:
+                    continue
+                r.decode_steps += 1
+                emitted = self._emit_many(r, [int(t) for t in toks[i]])
+                r.mode_tokens["multistep"] = \
+                    r.mode_tokens.get("multistep", 0) + emitted
+                if traced and r.trace is not None:
+                    _trace.record_event(
+                        "serving.decode.participate", "serving", t_step0,
+                        t_step1, ctx=r.trace,
+                        args={"rid": r.rid, "iteration": self._iteration,
+                              "running": len(batch), "mode": "multistep",
+                              "k": int(k), "replica": self._replica_id})
         self._counts["multistep_steps"] += 1
 
     # -- failure isolation (docs/fault_tolerance.md serving rows) -----------------
@@ -2281,6 +2343,8 @@ class GenerationService:
             "running": running,
             "waiting": waiting,
             "iterations": self._iteration,
+            "phase_ms": {k: round(v / 1e3, 3)
+                         for k, v in dict(self._phase_us).items()},
             "counts": counts,
             "kv_blocks": {
                 "total": self._cache.num_blocks - 1,

@@ -32,7 +32,32 @@ from typing import Dict, Optional
 
 import numpy as _np
 
+from ...observability import tracing as _tracing
+
 __all__ = ["GenerationPrograms", "block_copy_pools"]
+
+
+def _step_args(tokens, positions, lengths, block_tables, seeds, counters,
+               temperature, top_k, top_p):
+    """A step program's host arguments in their dtypes."""
+    return (_np.asarray(tokens, _np.int32),
+            _np.asarray(positions, _np.int32),
+            _np.asarray(lengths, _np.int32),
+            _np.asarray(block_tables, _np.int32),
+            _np.asarray(seeds, _np.uint32),
+            _np.asarray(counters, _np.uint32),
+            _np.asarray(temperature, _np.float32),
+            _np.asarray(top_k, _np.int32),
+            _np.asarray(top_p, _np.float32))
+
+
+def _synced(*outs):
+    """The step's sampled tokens as NumPy arrays: the read that waits for
+    the device, under its own span so that the wait is not mistaken for
+    host work (``serving.step.dispatch`` ends where the call returned)."""
+    with _tracing.span("serving.step.sync", cat="serving"):
+        arrays = tuple(_np.asarray(o) for o in outs)
+    return arrays[0] if len(arrays) == 1 else arrays
 
 
 def block_copy_pools(k_pool, v_pool, src, dst, k_scale=None, v_scale=None):
@@ -407,31 +432,22 @@ class GenerationPrograms:
         _executor._note_cache(hit=hit, site=(site_kind, ("lm",)), key=key)
         with self._lock:
             per["hits" if hit else "misses"] += 1
+        next_tokens, last = self._dispatch(self._jit, cache, _step_args(
+            tokens, positions, lengths, block_tables, seeds, counters,
+            temperature, top_k, top_p))
+        return _synced(next_tokens), last
+
+    def _dispatch(self, fn, cache, args):
+        """Call a step program on the cache's pools (the scales too for
+        the int8 pool) and swap the donated pools it returns, last among
+        its outputs, back into the cache; returns the other outputs."""
+        pools = (cache.k, cache.v)
         if self._kv_dtype == "int8":
-            next_tokens, last, k, v, ks, vs = self._jit(
-                self._params, cache.k, cache.v, cache.k_scale,
-                cache.v_scale,
-                _np.asarray(tokens, _np.int32),
-                _np.asarray(positions, _np.int32),
-                _np.asarray(lengths, _np.int32),
-                _np.asarray(block_tables, _np.int32),
-                _np.asarray(seeds, _np.uint32),
-                _np.asarray(counters, _np.uint32),
-                _np.asarray(temperature, _np.float32),
-                _np.asarray(top_k, _np.int32),
-                _np.asarray(top_p, _np.float32))
-            cache.swap(k, v, ks, vs)
-            return _np.asarray(next_tokens), last
-        next_tokens, last, k, v = self._jit(
-            self._params, cache.k, cache.v,
-            _np.asarray(tokens, _np.int32), _np.asarray(positions, _np.int32),
-            _np.asarray(lengths, _np.int32),
-            _np.asarray(block_tables, _np.int32),
-            _np.asarray(seeds, _np.uint32), _np.asarray(counters, _np.uint32),
-            _np.asarray(temperature, _np.float32),
-            _np.asarray(top_k, _np.int32), _np.asarray(top_p, _np.float32))
-        cache.swap(k, v)
-        return _np.asarray(next_tokens), last
+            pools += (cache.k_scale, cache.v_scale)
+        with _tracing.span("serving.step.dispatch", cat="serving"):
+            out = fn(self._params, *pools, *args)
+            cache.swap(*out[-len(pools):])
+        return out[:-len(pools)]
 
     def _note(self, kind: str, key: tuple) -> None:
         """Compile-cache bookkeeping shared by every program family:
@@ -462,25 +478,9 @@ class GenerationPrograms:
         :meth:`run` namespace so warmup enumerates the (Tk, W) ladder."""
         key = self._key("gen_verify", cache, tokens, block_tables)
         self._note("gen_verify", key)
-        args = (_np.asarray(tokens, _np.int32),
-                _np.asarray(positions, _np.int32),
-                _np.asarray(lengths, _np.int32),
-                _np.asarray(block_tables, _np.int32),
-                _np.asarray(seeds, _np.uint32),
-                _np.asarray(counters, _np.uint32),
-                _np.asarray(temperature, _np.float32),
-                _np.asarray(top_k, _np.int32),
-                _np.asarray(top_p, _np.float32))
-        if self._kv_dtype == "int8":
-            target, accepted, k, v, ks, vs = self._jit_verify(
-                self._params, cache.k, cache.v, cache.k_scale,
-                cache.v_scale, *args)
-            cache.swap(k, v, ks, vs)
-        else:
-            target, accepted, k, v = self._jit_verify(
-                self._params, cache.k, cache.v, *args)
-            cache.swap(k, v)
-        return _np.asarray(target), _np.asarray(accepted)
+        return _synced(*self._dispatch(self._jit_verify, cache, _step_args(
+            tokens, positions, lengths, block_tables, seeds, counters,
+            temperature, top_k, top_p)))
 
     def _ms_jit(self, k: int):
         import jax
@@ -515,24 +515,9 @@ class GenerationPrograms:
         key = self._key("gen_multistep", cache, tokens, block_tables)
         key = (key[0], key[1] + (("k", int(k)),))
         self._note("gen_multistep", key)
-        fn = self._ms_jit(int(k))
-        args = (tokens,
-                _np.asarray(positions, _np.int32),
-                _np.asarray(lengths, _np.int32),
-                _np.asarray(block_tables, _np.int32),
-                _np.asarray(seeds, _np.uint32),
-                _np.asarray(counters, _np.uint32),
-                _np.asarray(temperature, _np.float32),
-                _np.asarray(top_k, _np.int32),
-                _np.asarray(top_p, _np.float32))
-        if self._kv_dtype == "int8":
-            toks, kk, vv, ks, vs = fn(self._params, cache.k, cache.v,
-                                      cache.k_scale, cache.v_scale, *args)
-            cache.swap(kk, vv, ks, vs)
-        else:
-            toks, kk, vv = fn(self._params, cache.k, cache.v, *args)
-            cache.swap(kk, vv)
-        return _np.asarray(toks)
+        return _synced(*self._dispatch(self._ms_jit(int(k)), cache, _step_args(
+            tokens, positions, lengths, block_tables, seeds, counters,
+            temperature, top_k, top_p)))
 
     def copy_block(self, cache, src: int, dst: int) -> None:
         """Copy pool block ``src`` onto ``dst`` (scales included for the

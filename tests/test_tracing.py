@@ -716,3 +716,304 @@ def test_stream_stats_live_snapshot_consistent_under_load(params):
     assert not errs
     assert len(out) == 32
     assert h.stats()["replica"] == 0  # the final wide event agrees
+
+
+# -- phase spans of the two hot loops (docs/observability.md section 2) -------------
+def _tiny_fit(n_batches=3, callback=None):
+    """Module.fit on a one-layer symbol; returns the per-step losses the
+    callback read (the metric read is the step's sync, as in a benchmark
+    cell)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import sym
+
+    net = sym.SoftmaxOutput(
+        sym.FullyConnected(sym.Variable("data"), num_hidden=8, name="fc"),
+        sym.Variable("softmax_label"), name="softmax")
+    rs = np.random.RandomState(0)
+    it = mx.io.NDArrayIter(rs.rand(8 * n_batches, 4).astype(np.float32),
+                           rs.randint(0, 8, 8 * n_batches).astype(np.float32),
+                           batch_size=8)
+    losses = []
+
+    def on_batch(param):
+        losses.append(float(param.eval_metric.get()[1]))
+        if callback is not None:
+            callback(param)
+
+    np.random.seed(0)
+    mx.random.seed(0)
+    mod = mx.mod.Module(net, context=mx.cpu())
+    mod.fit(it, num_epoch=1, optimizer="sgd", eval_metric="ce",
+            optimizer_params={"learning_rate": 0.1},
+            batch_end_callback=on_batch)
+    assert mod._fused_step_count == n_batches
+    return losses
+
+
+def _profiled(fn):
+    """Run ``fn`` under mx.profiler; the spans it emitted as chrome-trace
+    events (``args['parent']`` names the enclosing span of the thread)."""
+    profiler.dumps(reset=True, format="json")
+    profiler.set_state("run")
+    try:
+        out = fn()
+    finally:
+        profiler.set_state("stop")
+    events = json.loads(profiler.dumps(reset=True, format="json"))
+    spans = [e for e in events["traceEvents"] if e["ph"] == "X"]
+    for e in spans:
+        e.setdefault("args", {})
+    return out, spans
+
+
+def test_fit_loop_emits_phase_spans_with_parents():
+    n = 3
+    _, events = _profiled(lambda: _tiny_fit(n))
+    parents = {}
+    for e in events:
+        parents.setdefault(e["name"], []).append(e["args"].get("parent"))
+    want = {"fit.input_wait": "fit.epoch[0]", "fit.prepare": "fit.epoch[0]",
+            "fit.callbacks": "fit.epoch[0]", "fit.batch": "fit.epoch[0]",
+            "fit.update_metric": "fit.batch", "executor.feed": "fit.batch",
+            "executor.fused_step": "fit.batch"}
+    for name, parent in want.items():
+        assert set(parents[name]) == {parent}, (name, parents[name])
+    # once per step; the iterator is asked once more, for the batch that
+    # is not there, and nothing is prepared after that
+    per_step = {k: len(v) for k, v in parents.items() if k in want}
+    assert per_step == dict({k: n for k in want}, **{
+        "fit.input_wait": n + 1, "fit.prepare": n - 1})
+    # feed ends where the dispatch starts: no host work between them is
+    # left without a name
+    by = {k: sorted((e["ts"], e["ts"] + e["dur"]) for e in events
+                    if e["name"] == k)
+          for k in ("executor.feed", "executor.fused_step", "fit.batch")}
+    for feed, step, batch in zip(*by.values()):
+        assert batch[0] <= feed[0] <= feed[1] <= step[0] <= step[1] \
+            <= batch[1]
+
+
+def _serve(params, prompts, **kw):
+    svc = GenerationService(params, CFG, _gc(**kw), start=False)
+    svc.warmup()
+    svc.start()
+    try:
+        outs = [h.result(120) for h in
+                [svc.submit(p, max_new_tokens=10) for p in prompts]]
+        time.sleep(0.12)           # the loop retires the last slot and idles
+        return outs, svc.stats()
+    finally:
+        svc.stop()
+
+
+def test_engine_iteration_spans_children_and_phase_ms(params):
+    rs = np.random.RandomState(5)
+    prompts = [rs.randint(0, CFG.vocab, n) for n in (5, 11, 20, 7)]
+    (_, stats), events = _profiled(lambda: _serve(params, prompts))
+    its = [e for e in events if e["name"] == "serving.iteration"]
+    # the engine thread's spans (warm-up ran the programs on this one)
+    mine = [e for e in events if e["name"].startswith("serving.")
+            and e["tid"] == its[0]["tid"]]
+    # one span per engine iteration: a pass that only retires finished
+    # requests repeats the number of the iteration that follows it
+    numbers = {e["args"]["iteration"] for e in its}
+    assert abs(len(numbers) - stats["iterations"]) <= 1
+    assert all(e["args"].get("parent") is None for e in its)
+    parent_of = {}
+    for e in mine:
+        parent_of.setdefault(e["name"], set()).add(e["args"].get("parent"))
+    assert parent_of["serving.idle_wait"] == {None}
+    for child in ("serving.schedule", "serving.decode.build",
+                  "serving.prefill", "serving.decode", "serving.emit"):
+        assert parent_of[child] == {"serving.iteration"}, child
+    for child in ("serving.evict", "serving.admit"):
+        assert parent_of[child] == {"serving.schedule"}, child
+    assert parent_of["serving.step.dispatch"] == parent_of[
+        "serving.step.sync"] == {"serving.prefill", "serving.decode"}
+    # the children of an iteration lie inside it and add up to no more
+    children = [e for e in mine
+                if e["args"].get("parent") == "serving.iteration"]
+    for it in its:
+        t0, t1 = it["ts"], it["ts"] + it["dur"]
+        inside = [c for c in children if t0 <= c["ts"] < t1]
+        assert inside, "an iteration without a schedule span"
+        assert sum(c["dur"] for c in inside) <= it["dur"] + 1.0
+        assert all(c["ts"] + c["dur"] <= t1 + 1.0 for c in inside)
+    steps = [e for e in children
+             if e["name"] in ("serving.prefill", "serving.decode")]
+    assert len([e for e in steps if e["name"] == "serving.decode"]) \
+        <= stats["iterations"]
+
+
+def test_phase_ms_accounts_for_the_iterations(params):
+    """stats()["phase_ms"] is fed by the phase spans' own clock reads:
+    its busy parts (everything but the idle wait) add up to the wall time
+    of the iterations, read from the span ring, within 5%."""
+    # wide enough that a step outweighs the loop's unnamed glue (list
+    # building, span bookkeeping: some tens of microseconds a pass)
+    cfg = tr.TransformerConfig(vocab=40, d_model=256, n_heads=4, n_layers=6,
+                               d_ff=1024, max_len=64)
+    big = tr.transformer_lm_init(cfg, jax.random.PRNGKey(1))
+    svc = GenerationService(big, cfg, _gc(), start=False)
+    svc.warmup()
+    before = svc.stats()["phase_ms"]
+    assert set(before) == {"schedule", "build", "step", "emit", "idle_wait"}
+    tracing.clear()
+    svc.start()
+    try:
+        rs = np.random.RandomState(3)
+        hs = [svc.submit(rs.randint(0, cfg.vocab, n), max_new_tokens=24)
+              for n in (6, 13, 9)]
+        mid = None
+        for h in hs:
+            h.result(120)
+            mid = mid or svc.stats()["phase_ms"]
+        time.sleep(0.12)
+    finally:
+        svc.stop()
+    after = svc.stats()["phase_ms"]
+    assert all(after[k] >= mid[k] >= before[k] for k in before)
+    assert after["step"] > mid["step"] > 0 and after["idle_wait"] > 0
+    # counter and span are one pair of clock reads: each part is the sum
+    # of its spans in the ring
+    phase_of = {"serving.schedule": "schedule", "serving.emit": "emit",
+                "serving.decode.build": "build",
+                "serving.prefill.build": "build",
+                "serving.prefill": "step", "serving.decode": "step",
+                "serving.idle_wait": "idle_wait"}
+    ring = [s for s in tracing.recent_spans() if s["name"] in phase_of]
+    for k in before:
+        spans_ms = sum(s["dur_us"] for s in ring
+                       if phase_of[s["name"]] == k) / 1e3
+        assert after[k] - before[k] == pytest.approx(spans_ms, abs=0.01), k
+    # and the busy parts cover an iteration: never more than its wall
+    # time, and in the median iteration within 5% of it (one pass that the
+    # scheduler of a loaded test host interrupts must not decide this)
+    shares = []
+    for it in tracing.recent_spans(name="serving.iteration"):
+        t0, t1 = it["ts_us"], it["ts_us"] + it["dur_us"]
+        parts = sum(s["dur_us"] for s in ring if t0 <= s["ts_us"] < t1
+                    and phase_of[s["name"]] != "idle_wait")
+        assert parts <= it["dur_us"] + 1.0
+        shares.append(parts / it["dur_us"])
+    assert len(shares) >= 24 and np.median(shares) >= 0.95, shares
+
+
+def test_tracing_off_same_tokens_same_losses_phase_ms_still_counts(
+        params, monkeypatch):
+    rs = np.random.RandomState(7)
+    prompts = [rs.randint(0, CFG.vocab, n) for n in (4, 9, 17)]
+    outs_on, _ = _serve(params, prompts)
+    losses_on = _tiny_fit(3)
+    assert tracing.recent_spans(name="serving.iteration")
+    assert tracing.recent_spans(name="fit.input_wait")
+    tracing.clear()
+    monkeypatch.setenv("TPUMX_TRACING", "0")
+    outs_off, stats_off = _serve(params, prompts)
+    losses_off = _tiny_fit(3)
+    assert outs_off == outs_on and losses_off == losses_on   # bitwise
+    assert tracing.recent_spans() == []
+    phases = stats_off["phase_ms"]
+    assert phases["step"] > 0 and phases["schedule"] > 0 \
+        and phases["emit"] > 0 and phases["build"] > 0
+
+
+def _pallas_sites():
+    """(case, function, arguments, static keywords, the names its
+    pallas_calls must carry) — every ``pallas_call`` site of ``ops/``."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import flash_attention as fa
+    from mxnet_tpu.ops import paged_attention as pa
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    f32 = jnp.float32
+    hd, nb, bs, w = 128, 8, 8, 4
+
+    def paged(b, t):
+        return (jnp.zeros((b, w), jnp.int32), jnp.zeros((b,), jnp.int32),
+                jnp.zeros((b, t, hd), f32), jnp.zeros((b, t), jnp.int32),
+                jnp.zeros((nb, bs, hd), f32), jnp.zeros((nb, bs, hd), f32))
+
+    x2d = jnp.ones((16, 128), f32)
+    row = jnp.ones((128,), f32)
+    q3 = jnp.ones((2, 16, 128), f32)
+    col = jnp.ones((2, 16, 1), f32)
+    flash = dict(t_real=16, causal=True, bq=8, bk=8, scale=0.1)
+    g2d = jnp.ones((8, 16 * 128), f32)
+    return [
+        ("paged_decode", pa._paged_call, paged(2, 1),
+         dict(n_heads=2, scale=0.1), ["_paged_call_w4_decode"]),
+        ("paged_prefill", pa._paged_call, paged(1, 8),
+         dict(n_heads=2, scale=0.1), ["_paged_call_w4_t8_prefill"]),
+        ("ln", pk._ln_call, (x2d, row, row),
+         dict(eps=1e-5, gelu=False, block_m=8), ["_ln_call"]),
+        ("ln_gelu", pk._ln_call, (x2d, row, row),
+         dict(eps=1e-5, gelu=True, block_m=8), ["_ln_call_gelu"]),
+        ("flash_fwd", fa._fwd_call, (q3, q3, q3), flash,
+         ["_fwd_call_flash"]),
+        ("flash_bwd", fa._bwd_call, (q3, q3, q3, q3, col, col), flash,
+         ["_bwd_call_flash_dq", "_bwd_call_flash_dkv"]),
+        ("bn_stats", pk._bn_stats_call, (x2d, row), dict(block_m=8),
+         ["_bn_stats_call"]),
+        ("bn_norm", pk._bn_norm_call, (x2d, row, row), dict(block_m=8),
+         ["_bn_norm_call"]),
+        ("twobit_pack", pk._pack_call, (g2d, g2d, jnp.ones((1, 1), f32)),
+         {}, ["_pack_call"]),
+        ("twobit_unpack", pk._unpack_call,
+         (jnp.ones((8, 128), jnp.uint32), jnp.ones((1, 1), f32)),
+         dict(dtype=f32), ["_unpack_call"]),
+    ]
+
+
+def _pallas_call_names(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+            continue
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                _pallas_call_names(inner, out)
+    return out
+
+
+@pytest.mark.parametrize("case", [
+    "paged_decode", "paged_prefill", "ln", "ln_gelu", "flash_fwd",
+    "flash_bwd", "bn_stats", "bn_norm", "twobit_pack", "twobit_unpack"])
+def test_every_pallas_call_carries_its_trace_name(case):
+    """The names a device trace shows (PERF.md): each starts with its
+    wrapper's name, no two kernels share one, and the paged kernel tells
+    decode from prefill and one table width from another."""
+    import functools
+
+    _, fn, args, kw, names = next(s for s in _pallas_sites()
+                                  if s[0] == case)
+    fn = functools.partial(fn, interpret=True, **kw)
+    assert _pallas_call_names(jax.make_jaxpr(fn)(*args).jaxpr, []) == names
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    assert all(n in text for n in names)
+    everyone = [n for s in _pallas_sites() for n in s[4]]
+    assert len(set(everyone)) == len(everyone) == 11
+
+
+def test_profiler_starts_jax_trace_without_python_frames(tmp_path,
+                                                         monkeypatch):
+    """TPUMX_JAX_TRACE_DIR: the device trace is started with jax's Python
+    tracer off (it slows the host loop), unless API frames were asked
+    for with set_config(profile_api=True)."""
+    seen = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, profiler_options=None: seen.append(
+                            (d, profiler_options.python_tracer_level)))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    monkeypatch.setenv("TPUMX_JAX_TRACE_DIR", str(tmp_path))
+    try:
+        for api in (False, True):
+            profiler.set_config(profile_api=api)
+            profiler.start()
+            profiler.start()       # idempotent: one trace per run
+            profiler.stop()
+    finally:
+        profiler.set_config()
+    assert seen == [(str(tmp_path), 0), (str(tmp_path), 1)]
