@@ -1118,8 +1118,9 @@ def pallas_kernels_bench():
     B, H, D, bs, W = 8, 8, 64, 32, 16
     nb = B * W + 1
     q = jnp.asarray(rs.randn(B, 1, H, D).astype(np.float32))
-    kp = jnp.asarray(rs.randn(nb, bs, H, D).astype(np.float32))
-    vp = jnp.asarray(rs.randn(nb, bs, H, D).astype(np.float32))
+    # one layer of the layered, head-folded pool the kernel is handed whole
+    kp = jnp.asarray(rs.randn(1, nb, bs, H * D).astype(np.float32))
+    vp = jnp.asarray(rs.randn(1, nb, bs, H * D).astype(np.float32))
     tables = np.arange(1, B * W + 1, dtype=np.int32).reshape(B, W)
     positions = np.full((B, 1), W * bs - 1, np.int32)
     max_pos = np.full(B, W * bs - 1, np.int32)
@@ -1130,8 +1131,8 @@ def pallas_kernels_bench():
 
     @jax.jit
     def dense(q, kp, vp, jt, mask):
-        k_ctx = kp[jt].reshape(B, W * bs, H, D)
-        v_ctx = vp[jt].reshape(B, W * bs, H, D)
+        k_ctx = kp[0][jt].reshape(B, W * bs, H, D)
+        v_ctx = vp[0][jt].reshape(B, W * bs, H, D)
         return pa.paged_attention_reference(q, k_ctx, v_ctx, mask,
                                             jnp.float32(scale))
 

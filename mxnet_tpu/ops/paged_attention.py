@@ -4,32 +4,46 @@ kernel).
 The generation engine's decode hot path (parallel/transformer.py
 ``transformer_lm_decode``) historically GATHERED the whole paged KV context
 into contiguous ``(B, W*bs, H, D)`` arrays and ran dense attention over the
-full table-width bucket every token — per-token HBM traffic scaling with
-the bucket width, and a full materialized copy of the cache slice besides.
-This kernel walks the block table INSIDE the kernel instead: the table is a
-scalar-prefetch operand (``PrefetchScalarGridSpec``), so the index map
-streams exactly the K/V blocks the row owns from the donated pool straight
-through VMEM, accumulating with the online-softmax m/l recurrence (the same
-scheme as ops/flash_attention.py's forward).  Null table slots (the block-0
-sentinel) and blocks past the row's last written position are redirected to
-block 0 and skipped — consecutive identical block indices mean Mosaic never
-re-issues the DMA, so dead grid steps cost neither bandwidth nor compute.
+full table-width bucket every token.  This kernel walks the block table
+INSIDE the kernel instead, and it is handed the WHOLE layered pool
+``(n_layers, num_blocks, bs, H*D)`` plus the layer's index (a scalar
+operand: every layer's call is the same kernel): a ``pallas_call`` is an
+opaque custom call, so a ``k_pool[i]`` operand would be materialized — the
+whole pool copied once per step (PERF.md, PR 25).  The kernel fetches its
+own pages from the donated pool in place.
 
-One kernel serves BOTH generation phases: decode (``T=1`` single queries
-per slot) and (chunked) prefill (``T=seq-bucket`` chunk attending to
-everything already cached, including its own freshly scattered K/V).
-Masking is by cache-position <= query-position, exactly the dense path's
-mask, so bucketed table widths never perturb real rows.
+Two bodies, chosen by what the call sees in its shapes:
 
-The pool keeps heads FOLDED into its minor dim — ``(num_blocks, bs, H*D)``
-— so a K/V block is a lane-dense ``(bs, H*D)`` tile the TPU compiler
-accepts (docs/pallas.md "block-layout rule"); the kernel's per-head loop
-slices lanes.
+* **chunk** (``T > 1``: prefill, speculative verify; also an int8 pool and
+  pages that are not whole sublane tiles): grid ``(B, T tiles, W)``, the
+  table a scalar-prefetch operand, one ``(bs, H*D)`` K/V page per grid
+  step through a BlockSpec whose index map returns ``(layer, block, 0,
+  0)``.  Null table slots and pages past the row's last position are
+  redirected to block 0 and skipped.  Up to 256 query rows a tile feed the
+  MXU and amortise the grid step.
+* **decode** (``T == 1``): a single query a row is bound by steps and
+  bytes, so the pools stay in HBM (``memory_space=ANY``) and the body
+  issues its own DMAs: grid ``(B,)``, a ``fori_loop`` over the row's LIVE
+  page groups only — ``ceil((max_pos // bs + 1) / P)`` trips — each trip
+  waiting for ``P`` pages of K and V in one half of a double buffer while
+  the next group (or the next row's first) lands in the other half, then
+  one online-softmax update over ``P * bs`` cache positions with all heads
+  at once.  Dead pages are never visited: the table's width bucket costs
+  nothing.
+
+Both accumulate with the online-softmax m/l recurrence in f32 (the same
+scheme as ops/flash_attention.py's forward).  Masking is by cache-position
+<= query-position, exactly the dense path's mask, so bucketed table widths
+never perturb real rows.
+
+The pool keeps heads FOLDED into its minor dim, so a K/V page is a
+lane-dense ``(bs, H*D)`` tile the TPU compiler accepts (docs/pallas.md
+"block-layout rule").
 
 Gating: ``mxnet_tpu.ops.pallas_kernels.pallas_enabled()`` — default on for
 TPU, ``TPUMX_PALLAS=0`` restores the gather+dense XLA path
 (``paged_attention_reference`` below IS that path).  On CPU the same
-kernel runs through the Pallas interpreter (tier-1's parity leg);
+kernels run through the Pallas interpreter (tier-1's parity leg);
 tests/test_chip_compile.py asks the TPU compiler for the real shapes.
 """
 from __future__ import annotations
@@ -73,8 +87,8 @@ def paged_attention_reference(q, k_ctx, v_ctx, attn_mask, scale):
     return o
 
 
-def _paged_kernel(tables_ref, maxpos_ref, q_ref, pos_ref, k_ref, v_ref,
-                  *refs, bs: int, bt: int, n_heads: int, d_head: int,
+def _paged_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, pos_ref, k_ref,
+                  v_ref, *refs, bs: int, bt: int, n_heads: int, d_head: int,
                   scale: float, quantized: bool):
     # grid = (B, T tiles, W); W is the INNERMOST (sequential) dim, so the
     # VMEM scratch (acc/m/l) carries the online-softmax state across the
@@ -150,6 +164,142 @@ def _paged_kernel(tables_ref, maxpos_ref, q_ref, pos_ref, k_ref, v_ref,
                                ).astype(o_ref.dtype)
 
 
+def _decode_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, sel_ref,
+                   selt_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref,
+                   den_ref, acc_ref, trip_ref, *, bs: int, pages: int,
+                   scale: float):
+    # grid = (B,), one single-query row a step.  The pools are whole HBM
+    # arrays; this body fetches the row's live pages itself, ``pages`` of
+    # them a trip, into one half of the (2, pages, bs, H*D) buffers while
+    # the other half is being computed on.  ``trip_ref`` counts trips over
+    # the whole call: a trip's half is its parity, so the prefetch can run
+    # on across the end of a row into the next row's first group.
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    W = tables_ref.shape[1]
+    n = pages * bs
+    hp = sel_ref.shape[1]
+    layer = layer_ref[0]
+
+    def live_pages(row):
+        # max_pos == -1 (inactive row): no page at all
+        return jnp.minimum(jax.lax.div(maxpos_ref[row] + bs, bs), W)
+
+    def groups(row):
+        return jax.lax.div(live_pages(row) + pages - 1, pages)
+
+    def page_copies(row, g, half, enabled=True):
+        """(fetched?, K copy, V copy) of each page of group ``g`` of
+        ``row``: the same scalars decide the start and the wait.  Null
+        table entries and pages past the row's last position are neither
+        fetched nor waited for."""
+        live = live_pages(row)
+        out = []
+        for j in range(pages):
+            idx = g * pages + j
+            blk = tables_ref[row, jnp.minimum(idx, W - 1)]
+            out.append(((idx < live) & (blk != 0) & enabled,
+                        pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                              kbuf.at[half, j],
+                                              sem.at[0, half]),
+                        pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                              vbuf.at[half, j],
+                                              sem.at[1, half])))
+        return out
+
+    def start(copies):
+        for fetched, kc, vc in copies:
+            @pl.when(fetched)
+            def _():
+                kc.start()
+                vc.start()
+
+    def wait(copies):
+        for fetched, kc, vc in copies:
+            @pl.when(fetched)
+            def _():
+                kc.wait()
+                vc.wait()
+
+    @pl.when(b == 0)
+    def _first_row():
+        trip_ref[0] = 0
+        # a page that is not fetched leaves its slot as it was: keep what
+        # a zero probability multiplies finite (K needs none of this: a
+        # masked score is replaced, not multiplied)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    # the row before prefetched this row's first group at its last trip;
+    # a row that ran no trip (inactive) prefetched nothing
+    @pl.when((b == 0) | (groups(jnp.maximum(b - 1, 0)) == 0))
+    def _own_first_group():
+        start(page_copies(b, 0, jax.lax.rem(trip_ref[0], 2)))
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    den_ref[...] = jnp.zeros_like(den_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q = q_ref[0].astype(jnp.float32) * scale                   # (1, H*D)
+    max_pos = maxpos_ref[b]
+    n_groups = groups(b)
+    row_in_page = jax.lax.broadcasted_iota(jnp.int32, (bs, hp), 0)
+    dot = functools.partial(jax.lax.dot_general,
+                            dimension_numbers=(((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+
+    def trip(g, _):
+        t = trip_ref[0]
+        half = jax.lax.rem(t, 2)
+        more = g + 1 < n_groups
+        start(page_copies(jnp.where(more, b, jnp.minimum(b + 1, n_rows - 1)),
+                          jnp.where(more, g + 1, 0), 1 - half,
+                          more | (b + 1 < n_rows)))
+        mine = page_copies(b, g, half)
+        wait(mine)
+        k = kbuf[half].astype(jnp.float32).reshape(n, -1)      # (n, H*D)
+        v = vbuf[half].astype(jnp.float32).reshape(n, -1)
+        # every head's scores at once: the 0/1 selector sums head h's
+        # lanes of k * q into column h
+        s = dot(k * q, sel_ref[...])                           # (n, hp)
+        # cache pos <= query pos, page by page; a page that was not
+        # fetched holds another page's values: masked whole
+        ok = jnp.concatenate(
+            [row_in_page <= jnp.where(fetched, max_pos, -1)
+             - (g * pages + j) * bs
+             for j, (fetched, _, _) in enumerate(mine)], axis=0)
+        s = jnp.where(ok, s, _NEG)
+        m_old = m_ref[...]                                     # (1, hp)
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_old - m_new)
+        m_ref[...] = m_new
+        # probabilities (and alpha, in 8 rows more of the same product)
+        # back from one column a head to the head's lanes
+        wide = dot(jnp.concatenate(
+            [p, jnp.broadcast_to(alpha, (8, hp))], axis=0), selt_ref[...])
+        p_wide, alpha_wide = wide[:n], wide[n:]
+
+        def rows_summed(x):     # (n, H*D) -> (8, H*D): whole vregs added,
+            out = x[0:8]        # the last 8 -> 1 waits for the row's end
+            for r in range(8, n, 8):
+                out = out + x[r:r + 8]
+            return out
+
+        # numerator and normalizer from the SAME expanded values: what
+        # the product rounded of p and alpha cancels in their ratio
+        acc_ref[...] = acc_ref[...] * alpha_wide + rows_summed(p_wide * v)
+        den_ref[...] = den_ref[...] * alpha_wide + rows_summed(p_wide)
+        trip_ref[0] = t + 1
+
+    jax.lax.fori_loop(0, n_groups, trip, None)
+    # rows that ran no trip (inactive slots) and rows whose pages were all
+    # null emit 0
+    den = jnp.sum(den_ref[...], axis=0, keepdims=True)
+    o_ref[0] = (jnp.sum(acc_ref[...], axis=0, keepdims=True)
+                / jnp.maximum(den, 1e-30)).astype(o_ref.dtype)
+
+
 def _query_tile(t: int, hd: int) -> int:
     """Query rows per grid step: the whole chunk while its f32 tile stays
     under ~1 MB of VMEM (q, out and the accumulator each hold one, q/out
@@ -161,6 +311,24 @@ def _query_tile(t: int, hd: int) -> int:
     return t if t <= cap else cap
 
 
+def _decode_pages(t: int, w: int, bs: int, hd: int, pool_dtype,
+                  quantized: bool) -> int:
+    """Pages a trip of the decode body fetches, or 0 where the call takes
+    the chunk body: more than one query a row, an int8 pool (its scales
+    ride the chunk body's index maps), or a page that is not whole tiles
+    of the pool's dtype — ``bs`` a multiple of the sublane tile, ``H*D``
+    of 128 lanes (the body reads ``pages`` of them as one ``(pages * bs,
+    H*D)`` tile; a head slice of 3 x 64 lanes under an mp mesh is
+    refused).  Otherwise about 128 cache positions a trip — one MXU pass
+    of rows — while K and V, double-buffered, stay under 4 MB of VMEM,
+    and never more pages than the table is wide."""
+    item = jnp.dtype(pool_dtype).itemsize
+    if t != 1 or quantized or bs % (32 // item) or hd % 128:
+        return 0
+    fit = (4 << 20) // (4 * bs * hd * item)
+    return max(1, min(128 // bs, fit, w))
+
+
 def _call_name(t: int, w: int) -> str:
     """The kernel's name in a device trace: decode and prefill apart, one
     name per block-table width (and per prefill chunk length).  It starts
@@ -170,18 +338,81 @@ def _call_name(t: int, w: int) -> str:
         else f"_paged_call_w{w}_t{t}_prefill"
 
 
+def _head_selector(n_heads: int, d_head: int):
+    """``(H*D, hp)`` 0/1: column h selects head h's lanes; ``hp`` is H
+    rounded up to whole 128-lane tiles."""
+    import numpy as _np
+
+    hp = -(-n_heads // 128) * 128
+    lanes = _np.arange(n_heads * d_head)
+    sel = _np.zeros((lanes.size, hp), _np.float32)
+    sel[lanes, lanes // d_head] = 1
+    return sel
+
+
+def _decode_call(tables, max_pos, layer, q, k_pool, v_pool, *, n_heads,
+                 scale, pages, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, _, HD = q.shape
+    bs = k_pool.shape[2]
+    W = tables.shape[1]
+    sel = _head_selector(n_heads, HD // n_heads)
+    hp = sel.shape[1]
+    row = pl.BlockSpec((1, 1, HD), lambda b, *_: (b, 0, 0))
+    whole = lambda a: pl.BlockSpec(a.shape, lambda b, *_: (0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[row, whole(sel), whole(sel.T), hbm, hbm],
+        out_specs=row,
+        scratch_shapes=[pltpu.VMEM((2, pages, bs, HD), k_pool.dtype),
+                        pltpu.VMEM((2, pages, bs, HD), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.VMEM((1, hp), jnp.float32),     # m
+                        pltpu.VMEM((8, HD), jnp.float32),     # normalizer
+                        pltpu.VMEM((8, HD), jnp.float32),     # numerator
+                        pltpu.SMEM((1,), jnp.int32)],         # trips
+    )
+    kernel = functools.partial(_decode_kernel, bs=bs, pages=pages,
+                               scale=scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, HD), q.dtype),
+        # rows run in order: the double buffer's parity and the prefetch
+        # of the next row's first group carry from one row to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=_call_name(1, W),
+    )(tables, max_pos, layer, q, jnp.asarray(sel), jnp.asarray(sel.T),
+      k_pool, v_pool)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("n_heads", "scale", "interpret"))
-def _paged_call(tables, max_pos, q, positions, k_pool, v_pool, k_scale=None,
-                v_scale=None, *, n_heads, scale, interpret):
-    """q: (B, T, H*D); positions: (B, T); pools: (num_blocks, bs, H*D);
-    scales (int8 pool only): (num_blocks, H).  Returns (B, T, H*D)."""
+def _paged_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
+                k_scale=None, v_scale=None, *, n_heads, scale, interpret):
+    """q: (B, T, H*D); positions: (B, T); pools: the WHOLE layered pool
+    (n_layers, num_blocks, bs, H*D), read at ``layer`` — a (1,) int32
+    OPERAND (the third scalar-prefetch one), not a static: the model's 36
+    calls then share one trace and one lowered kernel (as statics they
+    were 36 kernels to lower for each program — 7 minutes of every
+    warm-up at GPT-2-large, PERF.md PR 25); scales (int8 pool only): ONE
+    layer's (num_blocks, H).  Returns (B, T, H*D)."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, HD = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     W = tables.shape[1]
     quantized = k_scale is not None
+    pages = _decode_pages(T, W, bs, HD, k_pool.dtype, quantized)
+    if pages:
+        return _decode_call(tables, max_pos, layer, q, k_pool, v_pool,
+                            n_heads=n_heads, scale=scale, pages=pages,
+                            interpret=interpret)
     bt = _query_tile(T, HD)
     t_pad = -(-T // bt) * bt
     if t_pad != T:
@@ -190,27 +421,33 @@ def _paged_call(tables, max_pos, q, positions, k_pool, v_pool, k_scale=None,
         q = jnp.pad(q, ((0, 0), (0, t_pad - T), (0, 0)))
         positions = jnp.pad(positions, ((0, 0), (0, t_pad - T)))
 
-    def kv_index(b, t, w, tables_ref, maxpos_ref):
+    def block_index(b, t, w, tables_ref, maxpos_ref, layer_ref):
         # dead blocks redirect to the null block: consecutive identical
         # indices skip the re-fetch, so dead grid steps cost no HBM traffic
-        blk = tables_ref[b, w]
-        return (jnp.where(w * bs > maxpos_ref[b], 0, blk), 0, 0)
+        return jnp.where(w * bs > maxpos_ref[b], 0, tables_ref[b, w])
+
+    def page_index(b, t, w, tables_ref, maxpos_ref, layer_ref):
+        return (layer_ref[0],
+                block_index(b, t, w, tables_ref, maxpos_ref, layer_ref), 0, 0)
 
     q_spec = pl.BlockSpec((1, bt, HD), lambda b, t, w, *_: (b, t, 0))
+    # one page of the layered pool, fetched in place: the layer's slice is
+    # never an operand, so XLA has nothing to copy
+    kv_spec = pl.BlockSpec((None, 1, bs, HD), page_index)
     in_specs = [q_spec,
                 # positions ride as a (bt, 1) COLUMN: a (1, T) row block of
                 # a (B, T) array is not a legal TPU block for B > 1
                 pl.BlockSpec((1, bt, 1), lambda b, t, w, *_: (b, t, 0)),
-                pl.BlockSpec((1, bs, HD), kv_index),
-                pl.BlockSpec((1, bs, HD), kv_index)]
-    args = [tables, max_pos, q, positions[:, :, None], k_pool, v_pool]
+                kv_spec, kv_spec]
+    args = [tables, max_pos, layer, q, positions[:, :, None], k_pool, v_pool]
     if quantized:
         # (num_blocks, H) -> (num_blocks, 1, H): a (1, 1, H) block is
         # whole in its last two dims
-        in_specs += [pl.BlockSpec((1, 1, n_heads), kv_index)] * 2
+        in_specs += [pl.BlockSpec((1, 1, n_heads),
+                                  lambda *a: (block_index(*a), 0, 0))] * 2
         args += [k_scale[:, None, :], v_scale[:, None, :]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, t_pad // bt, W),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -232,16 +469,18 @@ def _paged_call(tables, max_pos, q, positions, k_pool, v_pool, k_scale=None,
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
-                    scale=None, k_scale=None, v_scale=None):
+                    scale=None, k_scale=None, v_scale=None, *, layer: int = 0):
     """Attention of ``q`` against a paged KV pool, walking the block table
     in-kernel.
 
     Parameters
     ----------
     q : (B, T, H, D) — this chunk's queries (T=1 decode, T=bucket prefill).
-    k_pool, v_pool : (num_blocks, block_size, H*D) — ONE layer's pool
-        (already holding this chunk's scattered K/V), heads folded into
-        the minor dim (head h owns lanes ``[h*D, (h+1)*D)``).
+    k_pool, v_pool : (n_layers, num_blocks, block_size, H*D) — the WHOLE
+        layered pool (already holding this chunk's scattered K/V), heads
+        folded into the minor dim (head h owns lanes ``[h*D, (h+1)*D)``).
+        The kernel fetches its pages from it in place; a per-layer slice
+        would be copied by XLA before an opaque kernel call.
     block_tables : (B, W) int32 — physical block of each logical block;
         0 is the null sentinel.
     positions : (B, T) int32 — global position of each query (in-range).
@@ -249,11 +488,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
         inactive rows: every block is skipped and the output is 0).
     scale : float, optional — softmax scale; default
         :func:`attention_scale` of D.
-    k_scale, v_scale : (num_blocks, H) f32, optional — per-(block, head)
-        dequantization scales for an INT8 pool (docs/quantization.md):
-        the kernel dequantizes each K/V tile in VMEM, with the scales
-        index-mapped through the same scalar-prefetched block table as
-        the blocks themselves.
+    k_scale, v_scale : (num_blocks, H) f32, optional — ONE layer's
+        per-(block, head) dequantization scales for an INT8 pool
+        (docs/quantization.md): the kernel dequantizes each K/V tile in
+        VMEM, with the scales index-mapped through the same
+        scalar-prefetched block table as the blocks themselves.
+    layer : int — which layer of the pool to read (the model's layer loop
+        is unrolled, so a Python constant; it reaches the kernel as an
+        operand, so every layer's call is the same kernel).
 
     Returns (B, T, H, D) in q's dtype, matching
     :func:`paged_attention_reference` at rtol 1e-5 (f32) on valid rows.
@@ -268,25 +510,26 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
         v_scale = jnp.asarray(v_scale, jnp.float32)
     out = _paged_call(
         jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(max_pos, jnp.int32), q.reshape(B, T, H * D),
-        jnp.asarray(positions, jnp.int32), k_pool, v_pool, k_scale, v_scale,
-        n_heads=H, scale=float(scale), interpret=_use_interpret())
+        jnp.asarray(max_pos, jnp.int32), jnp.full((1,), layer, jnp.int32),
+        q.reshape(B, T, H * D), jnp.asarray(positions, jnp.int32), k_pool,
+        v_pool, k_scale, v_scale, n_heads=H, scale=float(scale),
+        interpret=_use_interpret())
     return out.reshape(B, T, H, D)
 
 
 def paged_attention_sharded(q, k_pool, v_pool, block_tables, positions,
                             max_pos, mesh, axis: str = "mp", scale=None,
-                            k_scale=None, v_scale=None):
+                            k_scale=None, v_scale=None, *, layer: int = 0):
     """:func:`paged_attention` partitioned PER HEAD over a model-parallel
     mesh axis (docs/sharding.md, docs/generation.md).
 
     An opaque ``pallas_call`` cannot be partitioned by GSPMD.  But every
     head is independent — so a ``shard_map`` over the head dimension runs
     the SAME kernel on each mp rank's head slice (Q and the output on
-    their head dim, the folded K/V pools on their ``H*D`` minor dim, which
-    splits on head boundaries; block tables / positions replicated — they
-    are head-invariant).  Per-head numerics are bit-identical to the
-    unsharded kernel.
+    their head dim, the folded layered K/V pools on their ``H*D`` minor
+    dim, which splits on head boundaries; block tables / positions
+    replicated — they are head-invariant).  Per-head numerics are
+    bit-identical to the unsharded kernel.
 
     Requires ``H % mesh.shape[axis] == 0`` (the caller gates kernel choice
     on this at service construction).  Works inside an outer GSPMD ``jit``:
@@ -306,7 +549,7 @@ def paged_attention_sharded(q, k_pool, v_pool, block_tables, positions,
     if scale is None:
         scale = attention_scale(q.shape[3])
     qspec = P(None, None, axis, None)   # heads at dim 2 of q and the output
-    pspec = P(None, None, axis)         # folded heads: the pools' minor dim
+    pspec = P(None, None, None, axis)   # folded heads: the pools' minor dim
     args = [q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
             jnp.asarray(positions, jnp.int32),
             jnp.asarray(max_pos, jnp.int32)]
@@ -320,7 +563,7 @@ def paged_attention_sharded(q, k_pool, v_pool, block_tables, positions,
 
     def local(q, k, v, t, p, m, ks=None, vs=None):
         return paged_attention(q, k, v, t, p, m, scale=scale, k_scale=ks,
-                               v_scale=vs)
+                               v_scale=vs, layer=layer)
 
     # pallas_call cannot declare varying-mesh-axes metadata
     return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),

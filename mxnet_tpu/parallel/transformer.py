@@ -136,10 +136,12 @@ def transformer_lm_apply(params: Params, tokens, positions,
     return x @ params["tok_emb"].T  # tied embeddings
 
 
-def _scatter_kv_quantized(pool, scale, vals, tables, positions, valid,
-                          max_pos, nt: int):
-    """Quantizing scatter into ONE layer's int8 paged pool
-    (docs/quantization.md).
+def _scatter_kv_quantized(pool, scale, layer: int, vals, tables, positions,
+                          valid, max_pos, nt: int):
+    """Quantizing scatter into layer ``layer`` of the int8 paged pool
+    (docs/quantization.md), in place: the touched blocks are gathered
+    from and scattered back into the WHOLE layered pool, so no one-layer
+    slice of it is ever made (a slice of a donated pool is a copy).
 
     Only the ``nt`` logical blocks this chunk's contiguous positions can
     touch are gathered (decode: exactly one block per row), dequantized
@@ -152,13 +154,13 @@ def _scatter_kv_quantized(pool, scale, vals, tables, positions, valid,
     write history, which is what makes greedy tokens batch-composition-
     independent under int8.
 
-    pool: (num_blocks, bs, H*D) int8; scale: (num_blocks, H) f32;
-    vals: (B, T, H, D) float; tables: (B, W) int32; positions/valid:
-    (B, T); max_pos: (B,) last valid position AFTER this write (-1 for
-    inactive rows).  Returns (pool, scale).
+    pool: (n_layers, num_blocks, bs, H*D) int8; scale: (n_layers,
+    num_blocks, H) f32; vals: (B, T, H, D) float; tables: (B, W) int32;
+    positions/valid: (B, T); max_pos: (B,) last valid position AFTER this
+    write (-1 for inactive rows).  Returns (pool, scale).
     """
     B, T, H, D = vals.shape
-    bs = pool.shape[1]
+    bs = pool.shape[2]
     W = tables.shape[1]
     # positions are contiguous per row, so the row's first entry names the
     # first touched logical block (all-invalid rows write to block 0)
@@ -169,8 +171,8 @@ def _scatter_kv_quantized(pool, scale, vals, tables, positions, valid,
     tphys = jnp.where(
         j_ok, jnp.take_along_axis(tables, jnp.minimum(tl, W - 1), axis=1),
         0)
-    blk = pool[tphys].reshape(B, nt, bs, H, D).astype(jnp.float32) \
-        * scale[tphys][:, :, None, :, None]          # (B, nt, bs, H, D)
+    blk = pool[layer, tphys].reshape(B, nt, bs, H, D).astype(jnp.float32) \
+        * scale[layer, tphys][:, :, None, :, None]   # (B, nt, bs, H, D)
     bidx = jnp.arange(B, dtype=jnp.int32)[:, None]
     j = jnp.clip(positions // bs - l0[:, None], 0, nt - 1)
     o = positions % bs
@@ -189,7 +191,7 @@ def _scatter_kv_quantized(pool, scale, vals, tables, positions, valid,
     q = jnp.clip(jnp.round(blk / new_s[:, :, None, :, None]),
                  -127, 127).astype(jnp.int8).reshape(B, nt, bs, H * D)
     # duplicate targets only ever alias the reserved null block 0
-    return pool.at[tphys].set(q), scale.at[tphys].set(new_s)
+    return pool.at[layer, tphys].set(q), scale.at[layer, tphys].set(new_s)
 
 
 def _touched_blocks(T: int, block_size: int) -> int:
@@ -318,33 +320,30 @@ def transformer_lm_decode(params: Params, tokens, positions, lengths,
         to_heads = lambda t: t.reshape(B, T, cfg.n_heads, cfg.d_head)
         q, k, v = to_heads(q), to_heads(k), to_heads(v)
         if quantized:
-            kp, ks = _scatter_kv_quantized(k_pool[i], k_scale[i], k,
-                                           block_tables, positions, valid,
-                                           max_pos, nt)
-            vp, vs = _scatter_kv_quantized(v_pool[i], v_scale[i], v,
-                                           block_tables, positions, valid,
-                                           max_pos, nt)
-            k_pool = k_pool.at[i].set(kp)
-            v_pool = v_pool.at[i].set(vp)
-            k_scale = k_scale.at[i].set(ks)
-            v_scale = v_scale.at[i].set(vs)
+            k_pool, k_scale = _scatter_kv_quantized(
+                k_pool, k_scale, i, k, block_tables, positions, valid,
+                max_pos, nt)
+            v_pool, v_scale = _scatter_kv_quantized(
+                v_pool, v_scale, i, v, block_tables, positions, valid,
+                max_pos, nt)
         else:
             fold = lambda t: t.reshape(B, T, cfg.d_model).astype(k_pool.dtype)
             k_pool = k_pool.at[i, phys, offs].set(fold(k))
             v_pool = v_pool.at[i, phys, offs].set(fold(v))
-        if use_paged and mp_mesh is not None:
-            o = _pa.paged_attention_sharded(
-                q, k_pool[i], v_pool[i], block_tables, positions, max_pos,
-                mesh=mp_mesh, axis="mp", scale=kernel_scale,
-                k_scale=k_scale[i] if quantized else None,
-                v_scale=v_scale[i] if quantized else None)
-        elif use_paged:
-            o = _pa.paged_attention(q, k_pool[i], v_pool[i], block_tables,
-                                    positions, max_pos, scale=kernel_scale,
-                                    k_scale=k_scale[i] if quantized
-                                    else None,
-                                    v_scale=v_scale[i] if quantized
-                                    else None)
+        if use_paged:
+            # the kernel is handed the WHOLE pool and the layer's index and
+            # fetches its own pages: a ``k_pool[i]`` operand of an opaque
+            # kernel call would be copied, the whole pool once a step
+            kw = dict(scale=kernel_scale, layer=i,
+                      k_scale=k_scale[i] if quantized else None,
+                      v_scale=v_scale[i] if quantized else None)
+            if mp_mesh is not None:
+                o = _pa.paged_attention_sharded(
+                    q, k_pool, v_pool, block_tables, positions, max_pos,
+                    mesh=mp_mesh, axis="mp", **kw)
+            else:
+                o = _pa.paged_attention(q, k_pool, v_pool, block_tables,
+                                        positions, max_pos, **kw)
         else:
             if quantized:
                 # dequantize at read: per-(block, head) scales broadcast

@@ -19,6 +19,7 @@ was given the file.  A compile that passes is not a chip run.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,15 +67,23 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-def _paged_shapes(sds, B, T, W, q_dtype, pool_dtype):
+LAYERS = 2                        # of the layered pool the kernels are handed
+
+
+def _paged_shapes(sds, B, T, W, q_dtype, pool_dtype, heads=H, layers=LAYERS,
+                  nb=NB):
+    """The paged call on the WHOLE layered pool; the layer it reads is an
+    operand."""
     from mxnet_tpu.ops import paged_attention as pa
 
+    hd = heads * D
+    pool = sds((layers, nb, BS, hd), pool_dtype)
     shapes = [sds((B, W), jnp.int32), sds((B,), jnp.int32),
-              sds((B, T, HD), q_dtype), sds((B, T), jnp.int32),
-              sds((NB, BS, HD), pool_dtype), sds((NB, BS, HD), pool_dtype)]
+              sds((1,), jnp.int32), sds((B, T, hd), q_dtype),
+              sds((B, T), jnp.int32), pool, pool]
     if pool_dtype == jnp.int8:
-        shapes += [sds((NB, H), jnp.float32)] * 2
-    fn = functools.partial(pa._paged_call.__wrapped__, n_heads=H,
+        shapes += [sds((nb, heads), jnp.float32)] * 2
+    fn = functools.partial(pa._paged_call.__wrapped__, n_heads=heads,
                            scale=pa.attention_scale(D), interpret=False)
     return fn, shapes
 
@@ -98,31 +107,66 @@ def test_paged_kernel_compiles(one_chip, B, T, W, q_dtype, pool_dtype):
     assert "tpu_custom_call" in _compile(fn, *shapes)
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["float", "int8"])
-def test_paged_sharded_compiles(topo, monkeypatch, int8):
-    """mp=4 over the described 2x2: the per-head shard_map of the kernel
-    (12 heads -> 3 per chip)."""
+# the benchmark's cell (PERF.md section 4): GPT-2-large, 20 heads x 64,
+# 36 layers, 1536 blocks of 16, 32 slots — the decode body's real call
+# (8 pages a trip out of a 9 GB pool that stays in HBM), one prefill
+# chunk, and the top prefill bucket
+_CELL_SHAPES = [(32, 1, 64), (32, 1, 8), (1, 128, 8), (1, 1023, 64)]
+
+
+@pytest.mark.parametrize("B,T,W", _CELL_SHAPES,
+                         ids=[f"B{b}-T{t}-W{w}" for b, t, w in _CELL_SHAPES])
+def test_paged_kernel_compiles_at_the_cells_shapes(one_chip, B, T, W):
+    from mxnet_tpu.ops import paged_attention as pa
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    fn, shapes = _paged_shapes(sds, B, T, W, jnp.float32, jnp.float32,
+                               heads=20, layers=36, nb=1536)
+    assert pa._decode_pages(T, W, BS, 1280, jnp.float32, False) \
+        == (8 if T == 1 else 0)
+    text = _compile(fn, *shapes)
+    assert "tpu_custom_call" in text
+    # the pool is an operand of the kernel itself: nothing pool-sized,
+    # layered or one layer of it, is made on the way in
+    made = [ln for ln in text.splitlines()
+            if re.search(r"= f32\[(36,)?1536,16,1280\]", ln)
+            and " parameter(" not in ln]
+    assert made == []
+
+
+@pytest.mark.parametrize("mp,heads,int8", [
+    (4, H, False), (4, H, True), (2, 20, False)],
+    ids=["mp4-float", "mp4-int8", "mp2-large-float"])
+def test_paged_sharded_compiles(topo, monkeypatch, mp, heads, int8):
+    """The per-head shard_map of the kernel over the layered pool.  mp=4
+    over the described 2x2 leaves 3 of 12 heads a chip: 192 lanes, one
+    and a half tiles, so even T == 1 takes the chunk body there.  mp=2 of
+    GPT-2-large's 20 heads leaves 640 lanes: the decode body."""
     from mxnet_tpu.ops import paged_attention as pa
 
     # this process's backend is the CPU, where the kernels default to the
     # interpreter: ask for the native lowering the chip would get
     monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
-    mesh = Mesh(topo.devices, ("mp",))
+    mesh = Mesh(topo.devices[:mp], ("mp",))
     sds = lambda shape, dt, spec: jax.ShapeDtypeStruct(
         shape, dt, sharding=NamedSharding(mesh, spec))
     pool_dt = jnp.int8 if int8 else jnp.float32
-    shapes = [sds((SLOTS, 1, H, D), jnp.float32, P(None, None, "mp", None)),
-              sds((NB, BS, HD), pool_dt, P(None, None, "mp")),
-              sds((NB, BS, HD), pool_dt, P(None, None, "mp")),
+    hd = heads * D
+    assert bool(pa._decode_pages(1, 64, BS, hd // mp, pool_dt, int8)) \
+        == (mp == 2)
+    pool = sds((LAYERS, NB, BS, hd), pool_dt, P(None, None, None, "mp"))
+    shapes = [sds((SLOTS, 1, heads, D), jnp.float32,
+                  P(None, None, "mp", None)), pool, pool,
               sds((SLOTS, 64), jnp.int32, P()),
               sds((SLOTS, 1), jnp.int32, P()),
               sds((SLOTS,), jnp.int32, P())]
     if int8:
-        shapes += [sds((NB, H), jnp.float32, P(None, "mp"))] * 2
+        shapes += [sds((NB, heads), jnp.float32, P(None, "mp"))] * 2
 
     def fn(q, k, v, t, p, m, ks=None, vs=None):
         return pa.paged_attention_sharded(q, k, v, t, p, m, mesh=mesh,
-                                          k_scale=ks, v_scale=vs)
+                                          k_scale=ks, v_scale=vs,
+                                          layer=LAYERS - 1)
 
     assert "tpu_custom_call" in _compile(fn, *shapes)
 
@@ -216,6 +260,20 @@ def test_decode_step_program_compiles(one_chip, monkeypatch, kv_dtype):
     # per layer: one paged-attention call and two LayerNorm calls, plus
     # the final LayerNorm
     assert text.count("tpu_custom_call") >= 3 * cfg.n_layers + 1
+    # the donated pools are updated in place and read in place: the only
+    # pool-sized results are the scatters into the whole pool — never one
+    # layer of it, never a copy (PERF.md, PR 25: the parent's program
+    # copied each layer's slice for its kernel call, 9 GB a step)
+    dt = "s8" if kv_dtype else "f32"
+    one_layer = f"= {dt}[{NB},{BS},{HD}]"
+    whole = f"= {dt}[{cfg.n_layers},{NB},{BS},{HD}]"
+    assert one_layer not in text
+    made = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
+            for ln in text.splitlines() if whole in ln}
+    # (copy-start / copy-done: the compiler moving this test's 3 MB pool
+    # into fast memory whole, not a copy of it in HBM)
+    assert made <= {"parameter", "scatter", "fusion", "copy-start",
+                    "copy-done"}, made
 
 
 def test_decode_step_program_compiles_mp4(topo, monkeypatch):

@@ -91,7 +91,8 @@ def test_kernel_matches_gathered_dense(paged, dtype):
     max_pos = np.where(valid, positions, -1).max(axis=1).astype(np.int32)
     scale = pa.attention_scale(D)
 
-    got = pa.paged_attention(q, kp, vp, tables, positions, max_pos, scale)
+    got = pa.paged_attention(q, kp[None], vp[None], tables, positions,
+                             max_pos, scale)
     want = _dense_reference(q, kp, vp, tables, positions, scale)
     assert got.dtype == dt
     tol = dict(rtol=1e-5, atol=1e-5) if dt == jnp.float32 \
@@ -123,11 +124,242 @@ def test_kernel_tiles_and_pads_long_chunks(paged):
     positions = np.arange(T, dtype=np.int32)[None, :]
     max_pos = np.array([T - 1], np.int32)
     scale = pa.attention_scale(D)
-    got = pa.paged_attention(q, kp, vp, tables, positions, max_pos, scale)
+    got = pa.paged_attention(q, kp[None], vp[None], tables, positions,
+                             max_pos, scale)
     want = _dense_reference(q, kp, vp, tables, positions, scale)
     assert got.shape == (B, T, H, D)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+# -- the layered pool, and the decode body's page loop -------------------------------
+def _layered_case(lens, W, bs=16, H=2, D=64, layers=3, T=1, dtype=np.float32,
+                  null_at=(), seed=0):
+    """A pool whose layers differ, rows of the given context lengths (0:
+    an inactive row) on a shuffled block table, and T queries a row that
+    end at the row's last position.  ``null_at``: (row, logical block)
+    table entries nulled INSIDE the live range."""
+    rs = np.random.RandomState(seed)
+    B = len(lens)
+    need = sum(-(-n // bs) for n in lens)
+    nb = need + 8
+    dt = jnp.dtype(dtype)
+    mk = lambda *s: jnp.asarray(rs.randn(*s).astype(np.float32)).astype(dt)
+    q = mk(B, T, H, D)
+    kp, vp = mk(layers, nb, bs, H * D), mk(layers, nb, bs, H * D)
+    tables = np.zeros((B, W), np.int32)
+    perm, o = rs.permutation(np.arange(1, nb)), 0
+    for b, n in enumerate(lens):
+        k = -(-n // bs)
+        tables[b, :k] = perm[o:o + k]
+        o += k
+    for b, j in null_at:
+        tables[b, j] = 0
+    positions = np.zeros((B, T), np.int32)
+    for b, n in enumerate(lens):
+        positions[b] = np.maximum(np.arange(n - T, n), 0)
+    max_pos = np.asarray([n - 1 for n in lens], np.int32)
+    return q, kp, vp, tables, positions, max_pos
+
+
+def _layered_reference(q, kp, vp, tables, positions, layer, scale):
+    """The gathered-dense attend on ONE layer's slice, null table entries
+    masked out (the kernels never read them)."""
+    B, T, H, D = q.shape
+    W, bs = tables.shape[1], kp.shape[2]
+    jt = jnp.asarray(tables)
+    k_ctx = kp[layer][jt].reshape(B, W * bs, H, D)
+    v_ctx = vp[layer][jt].reshape(B, W * bs, H, D)
+    ctx_pos = np.arange(W * bs, dtype=np.int32)
+    mask = (ctx_pos[None, None, :] <= positions[:, :, None]) \
+        & np.repeat(tables != 0, bs, axis=1)[:, None, :]
+    return pa.paged_attention_reference(q, k_ctx, v_ctx, jnp.asarray(mask),
+                                        jnp.float32(scale))
+
+
+def _assert_rows_match(got, want, lens, tol):
+    for b, n in enumerate(lens):
+        if n:
+            np.testing.assert_allclose(
+                np.asarray(got[b], np.float32),
+                np.asarray(want[b], np.float32), err_msg=f"row {b} (context {n})", **tol)
+        else:       # inactive rows emit exactly zero, never NaN
+            assert float(jnp.abs(got[b].astype(jnp.float32)).max()) == 0.0
+
+
+F32_TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("T", [1, 4], ids=["decode", "chunk"])
+@pytest.mark.parametrize("layer", [1, 2])
+def test_layered_pool_reads_its_own_layer(paged, T, layer):
+    """The kernel is handed the whole ``(n_layers, num_blocks, bs, H*D)``
+    pool and reads the layer it is told to — both bodies — not layer 0."""
+    lens = [37, 5, 0, 64]
+    q, kp, vp, tables, positions, max_pos = _layered_case(lens, W=4, T=T)
+    scale = pa.attention_scale(q.shape[3])
+    got = pa.paged_attention(q, kp, vp, tables, positions, max_pos, scale,
+                             layer=layer)
+    want = _layered_reference(q, kp, vp, tables, positions, layer, scale)
+    for b, n in enumerate(lens):
+        valid = min(T, n)
+        if n:
+            np.testing.assert_allclose(np.asarray(got[b, T - valid:]),
+                                       np.asarray(want[b, T - valid:]),
+                                       err_msg=f"row {b}", **F32_TOL)
+    other = _layered_reference(q, kp, vp, tables, positions, 0, scale)
+    assert float(jnp.abs(got[0, -1] - other[0, -1]).max()) > 1e-2
+
+
+# page = 16 positions, a trip's group = 8 pages = 128 positions at W = 64
+_DECODE_EDGES = {
+    "one_token": ([1, 2], ()),
+    "ends_on_a_page": ([16, 32, 48], ()),
+    "one_past_a_page": ([17, 33], ()),
+    "ends_on_a_group": ([128, 256], ()),
+    "one_past_a_group": ([129, 257], ()),
+    "pages_not_a_multiple_of_group": ([176, 80, 300], ()),    # 11, 5, 19
+    "inactive_rows": ([0, 200, 0, 0, 9], ()),
+    "inactive_first_and_last": ([0, 0, 130, 0], ()),
+    "null_inside_the_table": ([200, 150, 40], ((0, 3), (0, 9), (1, 0),
+                                               (2, 1))),
+    "whole_group_null": ([300], tuple((0, j) for j in range(8, 16))),
+    "all_pages_null": ([40, 20], ((0, 0), (0, 1), (0, 2))),
+    "mixed_short_and_1000": ([3, 1000, 40, 517, 1, 1000], ()),
+}
+
+
+@pytest.mark.parametrize("case", list(_DECODE_EDGES))
+def test_decode_body_page_loop_edges(paged, case):
+    """The single-query body walks the row's LIVE pages only, 8 a trip,
+    prefetching across the end of a row: every edge the page loop has."""
+    lens, null_at = _DECODE_EDGES[case]
+    q, kp, vp, tables, positions, max_pos = _layered_case(
+        lens, W=64, null_at=null_at, seed=len(case))
+    assert pa._decode_pages(1, 64, 16, 128, kp.dtype, False) == 8
+    scale = pa.attention_scale(q.shape[3])
+    got = pa.paged_attention(q, kp, vp, tables, positions, max_pos, scale,
+                             layer=1)
+    want = _layered_reference(q, kp, vp, tables, positions, 1, scale)
+    if case == "all_pages_null":     # nothing to attend to: 0, like a dead row
+        assert float(jnp.abs(got[0]).max()) == 0.0
+        lens = [0] + lens[1:]
+        got = got.at[0].set(0)
+    _assert_rows_match(got, want, lens, F32_TOL)
+    assert bool(jnp.all(jnp.isfinite(got)))
+
+
+@pytest.mark.parametrize("dtype", [np.float32, "bfloat16"])
+@pytest.mark.parametrize("W", [1, 4, 64])
+def test_decode_body_dtypes_and_widths(paged, dtype, W):
+    """f32 and bf16 pools through the single-query body at a table one
+    page wide (a trip fetches 1 page), four, and 64 (8 a trip)."""
+    top = W * 16
+    lens = [top, max(1, top // 3), 0, min(7, top)]
+    q, kp, vp, tables, positions, max_pos = _layered_case(
+        lens, W=W, dtype=dtype, seed=W)
+    assert pa._decode_pages(1, W, 16, 128, kp.dtype, False) == min(W, 8)
+    got = pa.paged_attention(q, kp, vp, tables, positions, max_pos, layer=2)
+    want = _layered_reference(q, kp, vp, tables, positions, 2,
+                              pa.attention_scale(q.shape[3]))
+    assert got.dtype == kp.dtype
+    tol = F32_TOL if kp.dtype == jnp.float32 else dict(rtol=2e-2, atol=2e-2)
+    _assert_rows_match(got, want, lens, tol)
+
+
+@pytest.mark.parametrize("T", [1, 4], ids=["decode", "chunk"])
+def test_int8_pool_layered(paged, T):
+    """An int8 pool takes the chunk body at every T (its per-(block,
+    head) scales ride that body's index maps): the layered pool, one
+    layer's scales, against the dequantized gather."""
+    lens = [37, 5, 0, 64]
+    q, kp, vp, tables, positions, max_pos = _layered_case(lens, W=4, T=T)
+    rs = np.random.RandomState(7)
+    L, nb, bs, HD = kp.shape
+    H = q.shape[2]
+    kq = jnp.asarray(rs.randint(-127, 128, kp.shape), jnp.int8)
+    vq = jnp.asarray(rs.randint(-127, 128, vp.shape), jnp.int8)
+    ks = jnp.asarray(np.abs(rs.randn(L, nb, H)) * 0.02 + 0.01, jnp.float32)
+    vs = jnp.asarray(np.abs(rs.randn(L, nb, H)) * 0.02 + 0.01, jnp.float32)
+    assert pa._decode_pages(T, 4, bs, HD, kq.dtype, True) == 0
+    layer = 2
+    got = pa.paged_attention(q, kq, vq, tables, positions, max_pos,
+                             k_scale=ks[layer], v_scale=vs[layer],
+                             layer=layer)
+    deq = lambda p, s: (p.astype(jnp.float32).reshape(L, nb, bs, H, -1)
+                        * s[:, :, None, :, None]).reshape(p.shape)
+    want = _layered_reference(q, deq(kq, ks), deq(vq, vs), tables,
+                              positions, layer,
+                              pa.attention_scale(q.shape[3]))
+    for b, n in enumerate(lens):
+        valid = min(T, n)
+        if n:
+            np.testing.assert_allclose(np.asarray(got[b, T - valid:]),
+                                       np.asarray(want[b, T - valid:]),
+                                       rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("shape,pages", [
+    (dict(t=1, w=64, bs=16, hd=1280, dt="float32", q=False), 8),   # the cell
+    (dict(t=1, w=64, bs=16, hd=1280, dt="bfloat16", q=False), 8),
+    (dict(t=1, w=2, bs=16, hd=1280, dt="float32", q=False), 2),    # narrow
+    (dict(t=1, w=64, bs=32, hd=256, dt="float32", q=False), 4),    # 128 pos
+    (dict(t=1, w=64, bs=16, hd=16384, dt="float32", q=False), 1),  # VMEM bound
+    (dict(t=4, w=64, bs=16, hd=1280, dt="float32", q=False), 0),   # a chunk
+    (dict(t=1, w=64, bs=16, hd=1280, dt="int8", q=True), 0),       # int8 pool
+    (dict(t=1, w=64, bs=4, hd=1280, dt="float32", q=False), 0),    # part tile
+    (dict(t=1, w=64, bs=8, hd=1280, dt="bfloat16", q=False), 0),   # part tile
+    (dict(t=1, w=64, bs=16, hd=192, dt="float32", q=False), 0),    # 1.5 lanes
+], ids=lambda v: "-".join(f"{k}{x}" for k, x in v.items())
+    if isinstance(v, dict) else str(v))
+def test_body_is_chosen_from_the_shapes(shape, pages):
+    """Which body a call takes, and how many pages a trip of the decode
+    body fetches, follow from the call's shapes alone."""
+    assert pa._decode_pages(shape["t"], shape["w"], shape["bs"], shape["hd"],
+                            jnp.dtype(shape["dt"]), shape["q"]) == pages
+
+
+def _one_layer_values(jaxpr, pool_shape, found):
+    """Equations OUTSIDE a kernel call whose result is one layer's slice
+    of the pool, ``(num_blocks, bs, H*D)`` with or without a leading 1."""
+    one = (pool_shape[1:], (1,) + pool_shape[1:])
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if any(getattr(v.aval, "shape", None) in one for v in eqn.outvars):
+            found.append(str(eqn.primitive))
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                _one_layer_values(inner, pool_shape, found)
+    return found
+
+
+@pytest.mark.parametrize("kv", ["float", "int8"])
+@pytest.mark.parametrize("T", [1, 16], ids=["decode", "prefill"])
+def test_model_step_never_slices_the_pool(params, T, kv):
+    """The paged model step hands the kernel the whole pool: no equation
+    outside the kernel call makes a (num_blocks, bs, H*D) array of the
+    (n_layers, num_blocks, bs, H*D) one — XLA would copy it before the
+    opaque call, the whole pool once a step (PERF.md, PR 25) — and the
+    int8 scatter requantizes its blocks in the whole pool too.  The
+    gather path does slice (XLA fuses that slice into its gather)."""
+    B, W, bs = 2, 2, 8
+    shape = (CFG.n_layers, 16, bs, CFG.d_model)
+    pool = jnp.zeros(shape, jnp.int8 if kv == "int8" else jnp.float32)
+    kw = {}
+    if kv == "int8":
+        sc = jnp.ones((CFG.n_layers, 16, CFG.n_heads))
+        kw = dict(k_scale=sc, v_scale=sc)
+    args = (np.zeros((B, T), np.int32), np.zeros((B, T), np.int32),
+            np.ones(B, np.int32), pool, pool, np.ones((B, W), np.int32))
+
+    def step(kernel):
+        return jax.make_jaxpr(lambda p, *a: tr.transformer_lm_decode(
+            p, *a, CFG, attention_kernel=kernel, **kw))(params, *args)
+
+    assert _one_layer_values(step("paged").jaxpr, shape, []) == []
+    assert _one_layer_values(step("gather").jaxpr, shape, []) != []
 
 
 # -- full decode pipeline -----------------------------------------------------------
@@ -339,10 +571,11 @@ def test_sharded_kernel_bitwise_matches_unsharded(paged):
     tables = np.array([[1, 2, 0], [3, 0, 0], [4, 5, 1]], np.int32)
     positions = np.array([[6], [2], [9]], np.int32)
     max_pos = np.array([6, 2, 9], np.int32)
-    want = pa.paged_attention(q, kp, vp, tables, positions, max_pos)
+    want = pa.paged_attention(q, kp[None], vp[None], tables, positions,
+                              max_pos)
     mesh = make_mesh({"mp": 2}, install=False)
-    got = pa.paged_attention_sharded(q, kp, vp, tables, positions, max_pos,
-                                     mesh=mesh)
+    got = pa.paged_attention_sharded(q, kp[None], vp[None], tables,
+                                     positions, max_pos, mesh=mesh)
     assert np.array_equal(np.asarray(got), np.asarray(want))
     # an indivisible head count is refused with a clear error, not an
     # opaque shard_map failure
@@ -350,8 +583,8 @@ def test_sharded_kernel_bitwise_matches_unsharded(paged):
 
     mesh8 = make_mesh({"mp": 8}, install=False)
     with pytest.raises(MXNetError):
-        pa.paged_attention_sharded(q, kp, vp, tables, positions, max_pos,
-                                   mesh=mesh8)
+        pa.paged_attention_sharded(q, kp[None], vp[None], tables,
+                                   positions, max_pos, mesh=mesh8)
 
 
 def test_service_mp2_decodes_through_paged_kernel(params, paged):

@@ -932,8 +932,10 @@ def _pallas_sites():
 
     def paged(b, t):
         return (jnp.zeros((b, w), jnp.int32), jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b, t, hd), f32), jnp.zeros((b, t), jnp.int32),
-                jnp.zeros((nb, bs, hd), f32), jnp.zeros((nb, bs, hd), f32))
+                jnp.zeros((1,), jnp.int32), jnp.zeros((b, t, hd), f32),
+                jnp.zeros((b, t), jnp.int32),
+                jnp.zeros((1, nb, bs, hd), f32),
+                jnp.zeros((1, nb, bs, hd), f32))
 
     x2d = jnp.ones((16, 128), f32)
     row = jnp.ones((128,), f32)

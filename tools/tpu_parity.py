@@ -259,6 +259,21 @@ def build_cases():
     tbl_paged = np.array([[1, 2, 0], [3, 0, 0], [0, 0, 0]], np.int32)
     pos_paged = np.array([[6], [2], [0]], np.int32)
     maxpos_paged = np.array([6, 2, -1], np.int32)
+    # the kernel is handed the WHOLE layered pool and a layer index: these
+    # pages (4 x 32: part tiles) take the chunk body even at T == 1
+    layered = lambda a: np.stack([np.zeros_like(a), a])
+    # ...and whole-tile pages (16 x 128) take the decode body, which
+    # fetches its own pages: 3 a trip here, rows of 1 / 2 / 0 trips, a
+    # null entry inside the table.  Its own stream: the draws above and
+    # below keep their values.
+    rng_dec = np.random.RandomState(25)
+    q_dec = rng_dec.randn(4, 1, 2, 64).astype(np.float32)
+    kp_dec = rng_dec.randn(2, 12, 16, 128).astype(np.float32)
+    vp_dec = rng_dec.randn(2, 12, 16, 128).astype(np.float32)
+    tbl_dec = np.array([[1, 2, 0], [3, 4, 5], [0, 0, 0], [6, 0, 7]],
+                       np.int32)
+    pos_dec = np.array([[20], [47], [0], [40]], np.int32)
+    maxpos_dec = np.array([20, 47, -1, 40], np.int32)
 
     def pallas_flash_bwd():
         import jax
@@ -293,8 +308,21 @@ def build_cases():
 
         def body(put):
             out = pa.paged_attention(
-                put(q_paged), put(kp_paged), put(vp_paged), put(tbl_paged),
-                put(pos_paged), put(maxpos_paged))
+                put(q_paged), put(layered(kp_paged)),
+                put(layered(vp_paged)), put(tbl_paged), put(pos_paged),
+                put(maxpos_paged), layer=1)
+            return [np.asarray(out)]
+
+        return _pallas_leg(body)
+
+    def pallas_paged_decode():
+        from mxnet_tpu.ops import paged_attention as pa
+
+        def body(put):
+            assert pa._decode_pages(1, 3, 16, 128, kp_dec.dtype, False) == 3
+            out = pa.paged_attention(
+                put(q_dec), put(kp_dec), put(vp_dec), put(tbl_dec),
+                put(pos_dec), put(maxpos_dec), layer=1)
             return [np.asarray(out)]
 
         return _pallas_leg(body)
@@ -303,7 +331,8 @@ def build_cases():
               ("pallas_bn_train_fused", pallas_bn),
               ("pallas_flash_attention_bwd", pallas_flash_bwd),
               ("pallas_layer_norm_fused", pallas_layer_norm),
-              ("pallas_paged_attention", pallas_paged)]
+              ("pallas_paged_attention", pallas_paged),
+              ("pallas_paged_attention_decode", pallas_paged_decode)]
 
     # int8 quantization family (docs/quantization.md): the serving
     # quantize/dequantize kernels and the quantized FC/conv twins run as
@@ -348,9 +377,10 @@ def build_cases():
 
         def body(put):
             out = pa.paged_attention(
-                put(q_paged), put(kq_paged), put(vq_paged), put(tbl_paged),
-                put(pos_paged), put(maxpos_paged),
-                k_scale=put(ks_paged), v_scale=put(vs_paged))
+                put(q_paged), put(layered(kq_paged)),
+                put(layered(vq_paged)), put(tbl_paged), put(pos_paged),
+                put(maxpos_paged), k_scale=put(ks_paged),
+                v_scale=put(vs_paged), layer=1)
             return [np.asarray(out)]
 
         return _pallas_leg(body)
