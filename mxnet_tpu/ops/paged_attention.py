@@ -14,13 +14,22 @@ own pages from the donated pool in place.
 
 Two bodies, chosen by what the call sees in its shapes:
 
-* **chunk** (``T > 1``: prefill, speculative verify; also an int8 pool and
-  pages that are not whole sublane tiles): grid ``(B, T tiles, W)``, the
-  table a scalar-prefetch operand, one ``(bs, H*D)`` K/V page per grid
-  step through a BlockSpec whose index map returns ``(layer, block, 0,
-  0)``.  Null table slots and pages past the row's last position are
-  redirected to block 0 and skipped.  Up to 256 query rows a tile feed the
-  MXU and amortise the grid step.
+* **chunk** (``T > 1``: prefill, speculative verify, a block-diffusion
+  step; also an int8 pool and pages that are not whole sublane tiles):
+  grid ``(B, T tiles, W / pages)``, the table a scalar-prefetch operand,
+  ``(bs, H*D)`` K/V pages through BlockSpecs whose index maps return
+  ``(layer, block, 0, 0)`` — one page a grid step, or, for grouped heads,
+  8 (the pool is then an operand 8 times over, each with its own index
+  map).  Null table slots and pages past the last position the TILE's
+  queries may read are redirected to block 0 and skipped.  Up to 256 query
+  rows a tile feed the MXU and amortise the grid step.  **Grouped heads**
+  (the pool folded ``Hkv * D`` with ``Hkv`` dividing the query heads):
+  the ``G = H / Hkv`` query heads of a KV head become ``G * T`` query rows
+  against that head's lanes — the wrapper regroups ``q`` to ``(B, G*T,
+  Hkv*D)``, as wide as a page, and the body is the same; K and V are never
+  repeated in HBM.  The mask is "cache position <= the position given for
+  the query", so a model with a block mask passes each query's block-end
+  position.
 * **decode** (``T == 1``): a single query a row is bound by steps and
   bytes, so the pools stay in HBM (``memory_space=ANY``) and the body
   issues its own DMAs: grid ``(B,)``, a ``fori_loop`` over the row's LIVE
@@ -55,6 +64,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 _NEG = -1e30
+_GROUPED_PAGES = 8      # K/V pages a grid step of the chunk body, grouped heads
 
 __all__ = ["paged_attention", "paged_attention_reference", "attention_scale",
            "paged_attention_sharded"]
@@ -73,11 +83,25 @@ def paged_attention_reference(q, k_ctx, v_ctx, attn_mask, scale):
     """The gather+dense attend, verbatim from transformer_lm_decode — the
     ``TPUMX_PALLAS=0`` path and the kernel's parity oracle.
 
-    q: (B, T, H, D); k_ctx/v_ctx: (B, W*bs, H, D) gathered context;
-    attn_mask: (B, T, W*bs) bool; scale: f32 scalar.  Same numerics as
-    ring_attention.local_attention: f32 scores and accumulation, masked
-    slots at exactly 0 probability.
+    q: (B, T, H, D); k_ctx/v_ctx: (B, W*bs, Hkv, D) gathered context,
+    ``Hkv`` dividing ``H`` (query head h reads KV head ``h // (H //
+    Hkv)``; K and V are never repeated); attn_mask: (B, T, W*bs) bool —
+    the caller's, so a block mask is the caller's too; scale: f32 scalar.
+    Same numerics as ring_attention.local_attention: f32 scores and
+    accumulation, masked slots at exactly 0 probability.
     """
+    B, T, H, D = q.shape
+    Hkv = k_ctx.shape[2]
+    if Hkv != H:
+        # the G query heads of a KV head ride the query axis: (B, G*T,
+        # Hkv, D) against the unrepeated context
+        G = H // Hkv
+        q = q.reshape(B, T, Hkv, G, D).transpose(0, 3, 1, 2, 4) \
+            .reshape(B, G * T, Hkv, D)
+        o = paged_attention_reference(q, k_ctx, v_ctx,
+                                      jnp.tile(attn_mask, (1, G, 1)), scale)
+        return o.reshape(B, G, T, Hkv, D).transpose(0, 2, 3, 1, 4) \
+            .reshape(B, T, H, D)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k_ctx,
                    preferred_element_type=jnp.float32) * scale
     s = jnp.where(attn_mask[:, None], s, _NEG)
@@ -87,17 +111,24 @@ def paged_attention_reference(q, k_ctx, v_ctx, attn_mask, scale):
     return o
 
 
-def _paged_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, pos_ref, k_ref,
-                  v_ref, *refs, bs: int, bt: int, n_heads: int, d_head: int,
-                  scale: float, quantized: bool):
-    # grid = (B, T tiles, W); W is the INNERMOST (sequential) dim, so the
-    # VMEM scratch (acc/m/l) carries the online-softmax state across the
-    # row's cache blocks while only ONE (bs, H*D) K/V tile is resident.
+def _paged_kernel(tables_ref, maxpos_ref, tilemax_ref, layer_ref, q_ref,
+                  pos_ref, *refs, bs: int, bt: int, n_heads: int, d_head: int,
+                  scale: float, quantized: bool, pages: int):
+    # grid = (B, T tiles, W / pages); the page groups are the INNERMOST
+    # (sequential) dim, so the VMEM scratch (acc/m/l) carries the
+    # online-softmax state across the row's cache blocks while only
+    # ``pages`` (bs, H*D) K/V tiles are resident: the pool is an operand
+    # ``pages`` times over, each with its own index map, so one grid step
+    # fetches that many pages of the table wherever they lie (a grid step
+    # costs about 0.35 us whatever it does: a page a step is all overhead
+    # for a few query rows).
     # Heads are FOLDED into the lane dim (docs/pallas.md "block-layout
     # rule"): every block's last two dims are whole or (8k, 128k), which
     # is what Mosaic accepts; a block that cut one head out of an
     # (..., H, D) array was refused.  The static per-head loop slices
     # lanes [h*D, (h+1)*D) of the resident tiles.
+    k_refs, v_refs, refs = refs[:pages], refs[pages:2 * pages], \
+        refs[2 * pages:]
     if quantized:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
     else:
@@ -105,6 +136,7 @@ def _paged_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, pos_ref, k_ref,
     b = pl.program_id(0)
     w = pl.program_id(2)
     nw = pl.num_programs(2)
+    n = pages * bs
 
     @pl.when(w == 0)
     def _init():
@@ -112,17 +144,30 @@ def _paged_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, pos_ref, k_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # dead blocks: null sentinel (table entry 0 — the allocator never hands
-    # out physical block 0) or wholly past the row's last valid query
-    # position.  The index map already redirected their DMA to block 0.
-    live = (tables_ref[b, w] != 0) & (w * bs <= maxpos_ref[b])
+    # dead groups: null sentinel (table entry 0 — the allocator never
+    # hands out physical block 0; a table is filled from its front, so a
+    # group whose first page is null is null throughout) or wholly past
+    # the last position any query of this tile may read.  The index maps
+    # already redirected their DMAs to block 0.  Inside a live group a
+    # page past a query's position (a null one among them) is masked.
+    live = (tables_ref[b, w * pages] != 0) \
+        & (w * n <= jnp.minimum(maxpos_ref[b],
+                                tilemax_ref[b, pl.program_id(1)]))
 
     @pl.when(live)
     def _step():
-        q = q_ref[0].astype(jnp.float32) * scale               # (bt, H*D)
-        k = k_ref[0].astype(jnp.float32)                       # (bs, H*D)
-        v = v_ref[0].astype(jnp.float32)
-        ctx = w * bs + jax.lax.broadcasted_iota(jnp.int32, (bt, bs), 1)
+        # what the products take: a bfloat16 pool's pages as they are
+        # (the MXU multiplies float32 operands in one bfloat16 pass
+        # anyway, PERF.md PR 25: converting K and V first only costs
+        # vector work), everything else in float32
+        mxu = k_refs[0].dtype if k_refs[0].dtype == jnp.bfloat16 \
+            else jnp.float32
+        q = (q_ref[0].astype(jnp.float32) * scale).astype(mxu)  # (bt, H*D)
+        join = lambda rs: rs[0][0] if pages == 1 else jnp.concatenate(  # noqa: E731
+            [r[0] for r in rs], axis=0)
+        k = join(k_refs).astype(mxu)                           # (n, H*D)
+        v = join(v_refs).astype(mxu)
+        ctx = w * n + jax.lax.broadcasted_iota(jnp.int32, (bt, n), 1)
         mask = ctx <= pos_ref[0]            # cache pos <= query pos (bt, 1)
         head = jax.lax.broadcasted_iota(jnp.int32, (bt, n_heads), 1)
         m_all = m_ref[...]                                     # (bt, H)
@@ -146,7 +191,7 @@ def _paged_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, pos_ref, k_ref,
             l_new = alpha * l_all[:, h:h + 1] + jnp.sum(p, axis=1,
                                                         keepdims=True)
             acc_ref[:, sl] = acc_ref[:, sl] * alpha + jax.lax.dot_general(
-                p, vh, (((1,), (0,)), ((), ())),
+                p.astype(mxu), vh, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_all = jnp.where(head == h, m_new, m_all)
             l_all = jnp.where(head == h, l_new, l_all)
@@ -164,24 +209,14 @@ def _paged_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, pos_ref, k_ref,
                                ).astype(o_ref.dtype)
 
 
-def _decode_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, sel_ref,
-                   selt_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref,
-                   den_ref, acc_ref, trip_ref, *, bs: int, pages: int,
-                   scale: float):
-    # grid = (B,), one single-query row a step.  The pools are whole HBM
-    # arrays; this body fetches the row's live pages itself, ``pages`` of
-    # them a trip, into one half of the (2, pages, bs, H*D) buffers while
-    # the other half is being computed on.  ``trip_ref`` counts trips over
-    # the whole call: a trip's half is its parity, so the prefetch can run
-    # on across the end of a row into the next row's first group.
+def _live_page_fetch(tables_ref, maxpos_ref, layer, k_hbm, v_hbm, kbuf, vbuf,
+                     sem, bs: int, pages: int):
+    """What the bodies that fetch their own pages share: ``(groups,
+    page_copies, start, wait)`` over the row's LIVE pages, ``pages`` of
+    them a group, into one half of the ``(2, pages, bs, H*D)`` buffers."""
     from jax.experimental.pallas import tpu as pltpu
 
-    b = pl.program_id(0)
-    n_rows = pl.num_programs(0)
     W = tables_ref.shape[1]
-    n = pages * bs
-    hp = sel_ref.shape[1]
-    layer = layer_ref[0]
 
     def live_pages(row):
         # max_pos == -1 (inactive row): no page at all
@@ -222,6 +257,29 @@ def _decode_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, sel_ref,
             def _():
                 kc.wait()
                 vc.wait()
+
+    return groups, page_copies, start, wait
+
+
+def _decode_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, sel_ref,
+                   selt_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref,
+                   den_ref, acc_ref, trip_ref, *, bs: int, pages: int,
+                   scale: float):
+    # grid = (B,), one single-query row a step.  The pools are whole HBM
+    # arrays; this body fetches the row's live pages itself, ``pages`` of
+    # them a trip, into one half of the (2, pages, bs, H*D) buffers while
+    # the other half is being computed on.  ``trip_ref`` counts trips over
+    # the whole call: a trip's half is its parity, so the prefetch can run
+    # on across the end of a row into the next row's first group.
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    n = pages * bs
+    hp = sel_ref.shape[1]
+    layer = layer_ref[0]
+
+    groups, page_copies, start, wait = _live_page_fetch(
+        tables_ref, maxpos_ref, layer, k_hbm, v_hbm, kbuf, vbuf, sem, bs,
+        pages)
 
     @pl.when(b == 0)
     def _first_row():
@@ -300,6 +358,93 @@ def _decode_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, sel_ref,
                 / jnp.maximum(den, 1e-30)).astype(o_ref.dtype)
 
 
+def _rows_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, pos_ref, k_hbm,
+                 v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref,
+                 trip_ref, *, bs: int, pages: int, n_heads: int, d_head: int,
+                 scale: float):
+    # grid = (B,), one row's few query rows a step (a block-diffusion
+    # step: the block's positions x the query heads of a KV head).  As the
+    # decode body: the pools stay in HBM and the row's LIVE page groups
+    # are fetched by this body, double-buffered, the next row's first
+    # group behind this row's last — a dead page costs nothing, where the
+    # chunk body pays a grid step for it (0.84 us with 16 page operands,
+    # PERF.md PR 26).  The arithmetic is the chunk body's: per KV head an
+    # online-softmax update of its (rows, D) slice over the group's
+    # ``pages * bs`` cache positions, masked by the position each query
+    # row was given.
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    n = pages * bs
+    rows = q_ref.shape[1]
+    groups, page_copies, start, wait = _live_page_fetch(
+        tables_ref, maxpos_ref, layer_ref[0], k_hbm, v_hbm, kbuf, vbuf, sem,
+        bs, pages)
+
+    @pl.when(b == 0)
+    def _first_row():
+        trip_ref[0] = 0
+        # a page that is not fetched leaves its slot as it was: keep what
+        # a zero probability multiplies finite
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when((b == 0) | (groups(jnp.maximum(b - 1, 0)) == 0))
+    def _own_first_group():
+        start(page_copies(b, 0, jax.lax.rem(trip_ref[0], 2)))
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    mxu = kbuf.dtype if kbuf.dtype == jnp.bfloat16 else jnp.float32
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(mxu)     # (rows, H*D)
+    pos = pos_ref[0]                                           # (rows, 1)
+    n_groups = groups(b)
+    head = jax.lax.broadcasted_iota(jnp.int32, (rows, n_heads), 1)
+
+    def trip(g, _):
+        t = trip_ref[0]
+        half = jax.lax.rem(t, 2)
+        more = g + 1 < n_groups
+        start(page_copies(jnp.where(more, b, jnp.minimum(b + 1, n_rows - 1)),
+                          jnp.where(more, g + 1, 0), 1 - half,
+                          more | (b + 1 < n_rows)))
+        wait(page_copies(b, g, half))
+        k = kbuf[half].reshape(n, -1).astype(mxu)              # (n, H*D)
+        v = vbuf[half].reshape(n, -1).astype(mxu)
+        # a page that was not fetched lies past the row's last position,
+        # so past every query's: the position mask covers it
+        mask = g * n + jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1) \
+            <= pos
+        m_all, l_all = m_ref[...], l_ref[...]                  # (rows, H)
+        for h in range(n_heads):
+            sl = slice(h * d_head, (h + 1) * d_head)
+            s = jax.lax.dot_general(q[:, sl], k[:, sl],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, _NEG)
+            m_old = m_all[:, h:h + 1]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_old - m_new)
+            l_new = alpha * l_all[:, h:h + 1] + jnp.sum(p, axis=1,
+                                                        keepdims=True)
+            acc_ref[:, sl] = acc_ref[:, sl] * alpha + jax.lax.dot_general(
+                p.astype(mxu), v[:, sl], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_all = jnp.where(head == h, m_new, m_all)
+            l_all = jnp.where(head == h, l_new, l_all)
+        m_ref[...] = m_all
+        l_ref[...] = l_all
+        trip_ref[0] = t + 1
+
+    jax.lax.fori_loop(0, n_groups, trip, None)
+    # rows that ran no trip (inactive slots) emit 0
+    l_all = jnp.maximum(l_ref[...], 1e-30)
+    for h in range(n_heads):
+        sl = slice(h * d_head, (h + 1) * d_head)
+        o_ref[0, :, sl] = (acc_ref[:, sl] / l_all[:, h:h + 1]
+                           ).astype(o_ref.dtype)
+
+
 def _query_tile(t: int, hd: int) -> int:
     """Query rows per grid step: the whole chunk while its f32 tile stays
     under ~1 MB of VMEM (q, out and the accumulator each hold one, q/out
@@ -329,13 +474,33 @@ def _decode_pages(t: int, w: int, bs: int, hd: int, pool_dtype,
     return max(1, min(128 // bs, fit, w))
 
 
-def _call_name(t: int, w: int) -> str:
+def _rows_pages(t: int, groups: int, w: int, bs: int, hd: int, pool_dtype,
+                quantized: bool) -> int:
+    """Pages a trip of the rows body fetches, or 0 where the call takes
+    the chunk body.  The rows body is for grouped heads with a few query
+    rows a batch row (a block-diffusion step): up to 256 of them, whole
+    sublane tiles, over a float pool whose pages are whole tiles (as the
+    decode body asks).  About 256 cache positions a trip: a trip costs
+    about 3 us whatever it holds, 0.8 ms a call at 16 pages against 1.0 at
+    8 (PERF.md PR 26)."""
+    item = jnp.dtype(pool_dtype).itemsize
+    if groups == 1 or t > 256 or t % 8 or quantized \
+            or bs % (32 // item) or hd % 128:
+        return 0
+    return max(1, min(256 // bs, w))
+
+
+def _call_name(t: int, w: int, call=None) -> str:
     """The kernel's name in a device trace: decode and prefill apart, one
-    name per block-table width (and per prefill chunk length).  It starts
-    with the wrapper's name, which trace readers search for, and ends in
-    a letter: a reader that groups operations strips a trailing number."""
-    return f"_paged_call_w{w}_decode" if t == 1 \
-        else f"_paged_call_w{w}_t{t}_prefill"
+    name per block-table width (and per prefill chunk length); a model
+    may name its calls itself (``call="block"``: the block-diffusion
+    step's).  It starts with the wrapper's name, which trace readers
+    search for, and ends in a letter: a reader that groups operations
+    strips a trailing number."""
+    if call is None:
+        call = "decode" if t == 1 else "prefill"
+    return f"_paged_call_w{w}_decode" if call == "decode" \
+        else f"_paged_call_w{w}_t{t}_{call}"
 
 
 def _head_selector(n_heads: int, d_head: int):
@@ -391,17 +556,60 @@ def _decode_call(tables, max_pos, layer, q, k_pool, v_pool, *, n_heads,
       k_pool, v_pool)
 
 
+def _rows_call(tables, max_pos, layer, q, positions, k_pool, v_pool, *,
+               n_heads, scale, pages, interpret, name):
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, R, HD = q.shape
+    bs = k_pool.shape[2]
+    row = pl.BlockSpec((1, R, HD), lambda b, *_: (b, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[row, pl.BlockSpec((1, R, 1), lambda b, *_: (b, 0, 0)),
+                  hbm, hbm],
+        out_specs=row,
+        scratch_shapes=[pltpu.VMEM((2, pages, bs, HD), k_pool.dtype),
+                        pltpu.VMEM((2, pages, bs, HD), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.VMEM((R, n_heads), jnp.float32),     # m
+                        pltpu.VMEM((R, n_heads), jnp.float32),     # l
+                        pltpu.VMEM((R, HD), jnp.float32),          # acc
+                        pltpu.SMEM((1,), jnp.int32)],              # trips
+    )
+    kernel = functools.partial(_rows_kernel, bs=bs, pages=pages,
+                               n_heads=n_heads, d_head=HD // n_heads,
+                               scale=scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, R, HD), q.dtype),
+        # rows run in order: the double buffer's parity and the prefetch
+        # of the next row's first group carry from one row to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=name,
+    )(tables, max_pos, layer, q, positions[:, :, None], k_pool, v_pool)
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("n_heads", "scale", "interpret"))
+                   static_argnames=("n_heads", "scale", "interpret", "groups",
+                                    "call"))
 def _paged_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
-                k_scale=None, v_scale=None, *, n_heads, scale, interpret):
+                k_scale=None, v_scale=None, *, n_heads, scale, interpret,
+                groups=1, call=None):
     """q: (B, T, H*D); positions: (B, T); pools: the WHOLE layered pool
     (n_layers, num_blocks, bs, H*D), read at ``layer`` — a (1,) int32
-    OPERAND (the third scalar-prefetch one), not a static: the model's 36
+    OPERAND (the last scalar-prefetch one), not a static: the model's 36
     calls then share one trace and one lowered kernel (as statics they
     were 36 kernels to lower for each program — 7 minutes of every
     warm-up at GPT-2-large, PERF.md PR 25); scales (int8 pool only): ONE
-    layer's (num_blocks, H).  Returns (B, T, H*D)."""
+    layer's (num_blocks, H).  ``groups`` > 1: the query rows are
+    ``groups`` query heads' rows of one KV head each, group-major
+    (:func:`paged_attention` regroups them), and ``n_heads`` counts KV
+    heads; only the call's name needs to know.  Returns (B, T, H*D)."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, HD = q.shape
@@ -413,6 +621,12 @@ def _paged_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
         return _decode_call(tables, max_pos, layer, q, k_pool, v_pool,
                             n_heads=n_heads, scale=scale, pages=pages,
                             interpret=interpret)
+    pages = _rows_pages(T, groups, W, bs, HD, k_pool.dtype, quantized)
+    if pages:
+        return _rows_call(tables, max_pos, layer, q, positions, k_pool,
+                          v_pool, n_heads=n_heads, scale=scale, pages=pages,
+                          interpret=interpret,
+                          name=_call_name(T // groups, W, call))
     bt = _query_tile(T, HD)
     t_pad = -(-T // bt) * bt
     if t_pad != T:
@@ -421,34 +635,48 @@ def _paged_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
         q = jnp.pad(q, ((0, 0), (0, t_pad - T), (0, 0)))
         positions = jnp.pad(positions, ((0, 0), (0, t_pad - T)))
 
-    def block_index(b, t, w, tables_ref, maxpos_ref, layer_ref):
+    # pages a grid step: one for the queries of one head each (a prefill
+    # tile of 256 rows fills the step); for grouped heads, whose block
+    # step has a few rows a KV head, 8 pages — 128 cache positions, one
+    # MXU pass of columns — as separate operands of the same pool
+    pages = 1 if quantized or groups == 1 else \
+        next(p for p in (_GROUPED_PAGES, 4, 2, 1) if W % p == 0)
+    # the last position any query of a tile may read: a tile of a long
+    # chunk stops at its own pages, not at the chunk's last
+    tile_max = jnp.max(positions.reshape(B, t_pad // bt, bt), axis=2)
+
+    def block_index(j, b, t, w, tables_ref, maxpos_ref, tilemax_ref,
+                    layer_ref):
         # dead blocks redirect to the null block: consecutive identical
         # indices skip the re-fetch, so dead grid steps cost no HBM traffic
-        return jnp.where(w * bs > maxpos_ref[b], 0, tables_ref[b, w])
+        page = w * pages + j
+        dead = page * bs > jnp.minimum(maxpos_ref[b], tilemax_ref[b, t])
+        return jnp.where(dead, 0, tables_ref[b, page])
 
-    def page_index(b, t, w, tables_ref, maxpos_ref, layer_ref):
-        return (layer_ref[0],
-                block_index(b, t, w, tables_ref, maxpos_ref, layer_ref), 0, 0)
+    def page_index(j):
+        return lambda *a: (a[-1][0], block_index(j, *a), 0, 0)
 
     q_spec = pl.BlockSpec((1, bt, HD), lambda b, t, w, *_: (b, t, 0))
-    # one page of the layered pool, fetched in place: the layer's slice is
+    # pages of the layered pool, fetched in place: the layer's slice is
     # never an operand, so XLA has nothing to copy
-    kv_spec = pl.BlockSpec((None, 1, bs, HD), page_index)
+    kv_specs = [pl.BlockSpec((None, 1, bs, HD), page_index(j))
+                for j in range(pages)]
     in_specs = [q_spec,
                 # positions ride as a (bt, 1) COLUMN: a (1, T) row block of
                 # a (B, T) array is not a legal TPU block for B > 1
                 pl.BlockSpec((1, bt, 1), lambda b, t, w, *_: (b, t, 0)),
-                kv_spec, kv_spec]
-    args = [tables, max_pos, layer, q, positions[:, :, None], k_pool, v_pool]
+                *kv_specs, *kv_specs]
+    args = [tables, max_pos, tile_max, layer, q, positions[:, :, None],
+            *[k_pool] * pages, *[v_pool] * pages]
     if quantized:
         # (num_blocks, H) -> (num_blocks, 1, H): a (1, 1, H) block is
         # whole in its last two dims
         in_specs += [pl.BlockSpec((1, 1, n_heads),
-                                  lambda *a: (block_index(*a), 0, 0))] * 2
+                                  lambda *a: (block_index(0, *a), 0, 0))] * 2
         args += [k_scale[:, None, :], v_scale[:, None, :]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, t_pad // bt, W),
+        num_scalar_prefetch=4,
+        grid=(B, t_pad // bt, W // pages),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((bt, HD), jnp.float32),
@@ -457,33 +685,41 @@ def _paged_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
     )
     kernel = functools.partial(_paged_kernel, bs=bs, bt=bt, n_heads=n_heads,
                                d_head=HD // n_heads, scale=scale,
-                               quantized=quantized)
+                               quantized=quantized, pages=pages)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, t_pad, HD), q.dtype),
         interpret=interpret,
-        name=_call_name(T, W),
+        name=_call_name(T // groups, W, call),
     )(*args)
     return out[:, :T]
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
-                    scale=None, k_scale=None, v_scale=None, *, layer: int = 0):
+                    scale=None, k_scale=None, v_scale=None, *, layer: int = 0,
+                    call=None):
     """Attention of ``q`` against a paged KV pool, walking the block table
     in-kernel.
 
     Parameters
     ----------
     q : (B, T, H, D) — this chunk's queries (T=1 decode, T=bucket prefill).
-    k_pool, v_pool : (n_layers, num_blocks, block_size, H*D) — the WHOLE
+    k_pool, v_pool : (n_layers, num_blocks, block_size, Hkv*D) — the WHOLE
         layered pool (already holding this chunk's scattered K/V), heads
-        folded into the minor dim (head h owns lanes ``[h*D, (h+1)*D)``).
+        folded into the minor dim (KV head h owns lanes ``[h*D, (h+1)*D)``).
         The kernel fetches its pages from it in place; a per-layer slice
-        would be copied by XLA before an opaque kernel call.
+        would be copied by XLA before an opaque kernel call.  ``Hkv`` may
+        divide ``H`` (grouped-query attention): query head h reads KV head
+        ``h // (H // Hkv)``'s lanes, and K and V are never repeated — the
+        ``H // Hkv`` query heads of a KV head become ``T * H // Hkv`` query
+        rows against that head's page (the chunk body).
     block_tables : (B, W) int32 — physical block of each logical block;
         0 is the null sentinel.
-    positions : (B, T) int32 — global position of each query (in-range).
+    positions : (B, T) int32 — global position of each query (in-range):
+        the LAST cache position it may read.  A model whose mask is not
+        "cache position <= query position" passes that position here (a
+        block mask: the end of the query's block).
     max_pos : (B,) int32 — last VALID query position per row (−1 for
         inactive rows: every block is skipped and the output is 0).
     scale : float, optional — softmax scale; default
@@ -496,6 +732,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
     layer : int — which layer of the pool to read (the model's layer loop
         is unrolled, so a Python constant; it reaches the kernel as an
         operand, so every layer's call is the same kernel).
+    call : str, optional — names the call in a device trace
+        (``_paged_call_w<W>_t<T>_<call>``); default ``decode`` / ``prefill``
+        by ``T``.
 
     Returns (B, T, H, D) in q's dtype, matching
     :func:`paged_attention_reference` at rtol 1e-5 (f32) on valid rows.
@@ -508,12 +747,23 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
     if k_scale is not None:
         k_scale = jnp.asarray(k_scale, jnp.float32)
         v_scale = jnp.asarray(v_scale, jnp.float32)
+    positions = jnp.asarray(positions, jnp.int32)
+    Hkv = k_pool.shape[3] // D
+    G = H // Hkv
+    if G > 1:
+        # (B, T, Hkv, G, D) -> (B, G*T, Hkv*D): group-major query rows, as
+        # wide as a K/V page; positions repeat per group
+        q = q.reshape(B, T, Hkv, G, D).transpose(0, 3, 1, 2, 4)
+        positions = jnp.tile(positions, (1, G))
     out = _paged_call(
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(max_pos, jnp.int32), jnp.full((1,), layer, jnp.int32),
-        q.reshape(B, T, H * D), jnp.asarray(positions, jnp.int32), k_pool,
-        v_pool, k_scale, v_scale, n_heads=H, scale=float(scale),
-        interpret=_use_interpret())
+        q.reshape(B, G * T, Hkv * D), positions, k_pool,
+        v_pool, k_scale, v_scale, n_heads=Hkv, scale=float(scale),
+        interpret=_use_interpret(), groups=G, call=call)
+    if G > 1:
+        return out.reshape(B, G, T, Hkv, D).transpose(0, 2, 3, 1, 4) \
+            .reshape(B, T, H, D)
     return out.reshape(B, T, H, D)
 
 

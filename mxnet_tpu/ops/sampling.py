@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from .registry import register
 
 __all__ = ["temperature_scale", "top_k_mask", "top_p_mask", "sample_logits",
-           "speculative_verify", "fold_keys", "NEG_INF"]
+           "speculative_verify", "fold_keys", "block_unmask", "NEG_INF"]
 
 #: same finite -inf stand-in the attention masks use (exp() underflows to
 #: exactly 0.0 in f32, and finite values keep XLA's max/where paths simple)
@@ -206,3 +206,33 @@ def sampling_top_p(logits, rng_key=None, p=1.0, temperature=1.0):
     with mass >= p, then temperature-sample (``p >= 1`` disables)."""
     return sampling_temperature(top_p_mask(logits, float(p)), rng_key=rng_key,
                                 temperature=temperature)
+
+
+def block_unmask(logits, masked, n_unmask):
+    """The choice a denoise pass of generation by diffusion over blocks
+    makes (greedy, ``low_confidence_static`` remasking; docs/generation.md
+    "Block-diffusion generation"), with no sort over the vocabulary.
+
+    ``logits`` (S, L, vocab): the block's own positions' logits; ``masked``
+    (S, L) bool: which positions still hold MASK; ``n_unmask`` (S,) int:
+    how many of them this pass unmasks.  At every position ``x0`` is the
+    argmax and its confidence the softmax probability of ``x0`` (``1 /
+    sum(exp(logits - max))``); a row's ``n_unmask`` most confident masked
+    positions (ties: the lower position) take their ``x0``.  Returns (S,
+    L) int32: the new token id there, -1 everywhere else."""
+    logits = jnp.asarray(logits, jnp.float32)
+    L = logits.shape[1]
+    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    conf = 1.0 / jnp.sum(jnp.exp(logits - top), axis=-1)       # (S, L)
+    conf = jnp.where(masked, conf, -jnp.inf)
+    n = jnp.asarray(n_unmask, jnp.int32)
+    at = jnp.arange(L, dtype=jnp.int32)[None, :]
+    chosen = jnp.zeros(conf.shape, bool)
+    for j in range(L):
+        # argmax returns the first maximum: the lower position on a tie
+        pick = (at == jnp.argmax(conf, axis=1)[:, None]) \
+            & (jnp.max(conf, axis=1) > -jnp.inf)[:, None] & (j < n)[:, None]
+        chosen = chosen | pick
+        conf = jnp.where(pick, -jnp.inf, conf)
+    return jnp.where(chosen, x0, -1)

@@ -54,6 +54,45 @@ class TransformerConfig:
         return self.d_model // self.n_heads
 
 
+@dataclass(frozen=True)
+class TransformerLM:
+    """GPT-2's block as the generation engine takes a model (the seam of
+    ``serving/generation/programs.py``): the cache-aware step
+    (:func:`transformer_lm_decode`), vocabulary, longest position, the
+    heads a model-parallel mesh must divide, and the cache spec
+    :class:`~mxnet_tpu.serving.generation.kv_cache.PagedKVCache` is built
+    from.  One token a row a step (``block_len`` 0); every program family
+    of the engine is offered."""
+    cfg: TransformerConfig
+    compute_dtype: object = None
+    block_len = 0
+    offers = frozenset({"sampling", "speculative", "multistep", "int8",
+                        "mp", "amp"})
+
+    @property
+    def vocab(self) -> int:
+        return self.cfg.vocab
+
+    @property
+    def max_len(self) -> int:
+        return self.cfg.max_len
+
+    @property
+    def heads(self) -> int:
+        return self.cfg.n_heads
+
+    def cache_spec(self) -> dict:
+        return dict(n_layers=self.cfg.n_layers, n_heads=self.cfg.n_heads,
+                    d_head=self.cfg.d_head,
+                    dtype=self.compute_dtype or jnp.float32)
+
+    def step(self, params, tokens, positions, lengths, k_pool, v_pool,
+             block_tables, **kw):
+        return transformer_lm_decode(
+            params, tokens, positions, lengths, k_pool, v_pool,
+            block_tables, self.cfg, compute_dtype=self.compute_dtype, **kw)
+
+
 def transformer_lm_init(cfg: TransformerConfig, key) -> Params:
     """Scaled-normal init; residual-out projections down-scaled by
     1/sqrt(2*n_layers) (standard GPT-2 style stabilization)."""
@@ -200,6 +239,25 @@ def _touched_blocks(T: int, block_size: int) -> int:
     return (T + block_size - 2) // block_size + 1
 
 
+def paged_write_coords(positions, lengths, block_tables, block_size: int,
+                       max_len: int):
+    """Where a chunk's K/V go in the paged pool, shared by every layer and
+    by every model that decodes through it: ``(positions clipped to the
+    model's range, valid (B, T), physical block, offset in it)``.  Logical
+    block -> physical block via the table; invalid (padded / inactive-slot)
+    queries write into the reserved null block 0 instead of clobbering
+    real cache."""
+    T = positions.shape[1]
+    W = block_tables.shape[1]
+    positions = jnp.clip(jnp.asarray(positions, jnp.int32), 0, max_len - 1)
+    valid = jnp.arange(T, dtype=jnp.int32)[None, :] < \
+        jnp.asarray(lengths, jnp.int32)[:, None]            # (B, T)
+    logical = jnp.clip(positions // block_size, 0, W - 1)
+    phys = jnp.where(valid,
+                     jnp.take_along_axis(block_tables, logical, axis=1), 0)
+    return positions, valid, phys, positions % block_size
+
+
 def transformer_lm_decode(params: Params, tokens, positions, lengths,
                           k_pool, v_pool, block_tables,
                           cfg: TransformerConfig, compute_dtype=None,
@@ -266,17 +324,8 @@ def transformer_lm_decode(params: Params, tokens, positions, lengths,
     B, T = tokens.shape
     n_layers, num_blocks, block_size, _ = k_pool.shape
     W = block_tables.shape[1]
-    positions = jnp.clip(jnp.asarray(positions, jnp.int32), 0,
-                         cfg.max_len - 1)
-    valid = jnp.arange(T, dtype=jnp.int32)[None, :] < \
-        jnp.asarray(lengths, jnp.int32)[:, None]            # (B, T)
-    # write coordinates, shared by every layer: logical block -> physical
-    # block via the table; invalid (padded / inactive-slot) queries write
-    # into the reserved null block 0 instead of clobbering real cache
-    logical = jnp.clip(positions // block_size, 0, W - 1)
-    phys = jnp.where(valid,
-                     jnp.take_along_axis(block_tables, logical, axis=1), 0)
-    offs = positions % block_size
+    positions, valid, phys, offs = paged_write_coords(
+        positions, lengths, block_tables, block_size, cfg.max_len)
     # gathered context is in LOGICAL order: flat index j holds position j
     ctx_pos = jnp.arange(W * block_size, dtype=jnp.int32)
     attn_mask = ctx_pos[None, None, :] <= positions[:, :, None]  # (B,T,W*bs)

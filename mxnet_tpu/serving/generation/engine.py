@@ -58,7 +58,7 @@ from ..batcher import (BACKPRESSURE_POLICIES, DeadlineExceededError,
 from ..bucketing import (batch_buckets, bucket_batch, bucket_seq_len,
                          pad_tokens_right, seq_buckets)
 from .kv_cache import PagedKVCache, blocks_for
-from .programs import GenerationPrograms
+from .programs import GenerationPrograms, as_model
 
 __all__ = ["GenerationConfig", "GenerationService", "GenerationStream",
            "GenerationStepError"]
@@ -285,7 +285,8 @@ class _GenRequest:
                  "decode_steps", "n_retries", "token_log", "wide_event",
                  "lock", "cached_len", "cached_total", "cow_copies",
                  "charged_blocks", "draft_proposed", "draft_accepted",
-                 "mode_tokens", "index_safe_len")
+                 "mode_tokens", "index_safe_len", "block",
+                 "block_masked", "block_at", "block_pass", "unmask_pass")
 
     def __init__(self, rid, prompt, bucket, max_new, temperature, top_k,
                  top_p, seed, eos_token, deadline, on_token, priority=0):
@@ -334,6 +335,17 @@ class _GenRequest:
         self.draft_accepted = 0
         self.mode_tokens: Dict[str, int] = {}
         self.index_safe_len: Optional[int] = None
+        # generation by diffusion over blocks (docs/generation.md): the
+        # block in flight at positions ctx_len.. — its token ids, which of
+        # them still hold MASK (a flag per position, never read off the
+        # id: a prompt may hold the mask id), the pass each was unmasked
+        # at, the pass number — and, per generated token, the pass that
+        # unmasked it (what a reference needs to rebuild the block states)
+        self.block: Optional[List[int]] = None
+        self.block_masked: List[bool] = []
+        self.block_at: List[int] = []
+        self.block_pass = 0
+        self.unmask_pass: List[int] = []
         # latency attribution (docs/observability.md): the request's
         # lifetime is partitioned into contiguous segments — queue,
         # admission, prefill, decode, preempted — whose transition points
@@ -543,8 +555,15 @@ class GenerationService:
     Parameters
     ----------
     params : dict of jnp arrays
-        Transformer LM parameters (``transformer_lm_init`` layout).
+        The model's parameters (``transformer_lm_init`` layout for a
+        ``TransformerConfig``).
     model_cfg : :class:`~mxnet_tpu.parallel.transformer.TransformerConfig`
+        or a model object (:func:`~.programs.as_model`: its step, cache
+        spec, vocabulary and longest position), e.g.
+        :class:`~mxnet_tpu.parallel.sdar_moe.SdarMoeLM`.  A model with a
+        ``block_len`` generates by diffusion over blocks
+        (docs/generation.md); what a model does not offer (sampling
+        knobs, speculation, multistep, int8 KV, an mp mesh) is refused.
     config : :class:`GenerationConfig`, optional
     start : bool
         When False the engine loop is not launched until :meth:`start` —
@@ -566,11 +585,26 @@ class GenerationService:
         compute_dtype = None
         if cfg.amp_dtype:
             compute_dtype = jnp.dtype(cfg.amp_dtype)
+        model = self._model = as_model(model_cfg, compute_dtype)
+        for what, asked in (("amp", cfg.amp_dtype),
+                            ("speculative", cfg.speculative),
+                            ("multistep", cfg.multistep_k >= 2),
+                            ("int8", cfg.kv_dtype == "int8"),
+                            ("mp", cfg.mp_devices > 1)):
+            if asked and what not in model.offers:
+                raise ValueError(
+                    f"{type(model).__name__} does not offer {what!r} "
+                    f"(it offers {sorted(model.offers)})")
+        self._block_len = L = int(model.block_len)
+        if L and (cfg.block_size % L or model.max_len % L):
+            # a full page's K/V then depend only on tokens up to the
+            # page's end, which keeps the prefix index's chained hash valid
+            raise ValueError(
+                f"block_size {cfg.block_size} and max_len {model.max_len} "
+                f"must be multiples of the model's block length {L}")
         self._cache = PagedKVCache(
-            model_cfg.n_layers, model_cfg.n_heads, model_cfg.d_head,
-            cfg.num_blocks, cfg.block_size,
-            dtype=compute_dtype or jnp.float32,
-            kv_dtype=cfg.kv_dtype)
+            num_blocks=cfg.num_blocks, block_size=cfg.block_size,
+            kv_dtype=cfg.kv_dtype, **model.cache_spec())
         self._cache.allocator.set_watermarks(cfg.watermark_high,
                                              cfg.watermark_low)
         # prefix caching (docs/generation.md "prefix caching"): the chain-
@@ -583,8 +617,7 @@ class GenerationService:
             capacity_blocks=cfg.prefix_cache_blocks)
             if cfg.prefix_cache else None)
         self._pc_evictions_seen = 0
-        self._programs = GenerationPrograms(params, model_cfg,
-                                            compute_dtype=compute_dtype,
+        self._programs = GenerationPrograms(params, model,
                                             mp_devices=cfg.mp_devices,
                                             shard_rules=cfg.shard_rules,
                                             kv_dtype=cfg.kv_dtype)
@@ -596,6 +629,14 @@ class GenerationService:
         max_prompt = model_cfg.max_len - 1
         self._seq_buckets = (cfg.seq_buckets if cfg.seq_buckets
                              else seq_buckets(max_prompt))
+        if L:
+            # prefill chunks are whole blocks (the ladder's own cap,
+            # max_len - 1, is not)
+            if cfg.seq_buckets and any(b % L for b in cfg.seq_buckets):
+                raise ValueError(
+                    f"seq_buckets {cfg.seq_buckets} must be multiples of "
+                    f"the model's block length {L}")
+            self._seq_buckets = [b for b in self._seq_buckets if b % L == 0]
         if self._seq_buckets[-1] > max_prompt:
             raise ValueError(
                 f"largest seq bucket {self._seq_buckets[-1]} exceeds the "
@@ -618,7 +659,7 @@ class GenerationService:
         # growth reserves this span ahead (1 = classic single-token)
         self._iter_span = max(
             1, (cfg.draft_k + 1) if cfg.speculative else 1,
-            cfg.multistep_k)
+            cfg.multistep_k, L)
         self._draft = None
         if cfg.speculative and cfg.draft_mode == "model":
             if draft_params is None or draft_cfg is None:
@@ -662,7 +703,20 @@ class GenerationService:
                         "prefix_evictions": 0, "cached_tokens": 0,
                         "prefill_tokens": 0, "cow_copies": 0,
                         "draft_proposed": 0, "draft_accepted": 0,
-                        "spec_steps": 0, "multistep_steps": 0}
+                        "spec_steps": 0, "multistep_steps": 0,
+                        # generation by diffusion over blocks: program
+                        # calls, rows fed over them, rows on their commit
+                        # pass, tokens emitted at commits, and (from the
+                        # program) experts routed at least one token,
+                        # summed over the layers of every call
+                        "block_passes": 0, "block_row_passes": 0,
+                        "block_commit_row_passes": 0,
+                        "block_tokens_committed": 0,
+                        "block_experts_touched": 0,
+                        # cache positions the fed rows' queries could
+                        # read (context + block, summed over rows), and
+                        # the prefill chunks fed beside the block steps
+                        "block_ctx_tokens": 0, "block_prefill_chunks": 0}
         self._peak_occupancy = 0.0
         # host microseconds of the loop by phase, from the phase spans' own
         # clock reads (written by the engine thread only)
@@ -777,6 +831,12 @@ class GenerationService:
                       else cfg.max_new_tokens)
         if max_new < 1:
             raise ValueError("max_new_tokens must be >= 1")
+        if "sampling" not in self._model.offers and (
+                temperature > 0 or top_k > 0 or top_p < 1.0):
+            raise ValueError(
+                f"{type(self._model).__name__} generates greedily "
+                f"(temperature {temperature}, top_k {top_k}, top_p {top_p} "
+                f"asked for): sampling is not offered by this model")
         total = int(prompt.size) + max_new
         if total > self._model_cfg.max_len:
             raise ValueError(
@@ -899,9 +959,13 @@ class GenerationService:
         S = cfg.max_slots
         zeros_s = _np.zeros(S, _np.int32)
         with _obs.span("serving.warmup", cat="serving"):
+            sigs, widths = self._prefill_signatures(), self._width_buckets
+            if self._block_len:
+                self._warmup_block(sigs, widths)
+                sigs = widths = ()
             # every (T, W) pair the chunk planner can emit — the plain
             # per-rung ladder when chunked prefill is off
-            for tb, wp in self._prefill_signatures():
+            for tb, wp in sigs:
                 self._programs.run(
                     "gen_prefill", self._cache,
                     _np.zeros((1, tb), _np.int32),
@@ -910,7 +974,7 @@ class GenerationService:
                     _np.zeros(1, _np.uint32), _np.zeros(1, _np.uint32),
                     _np.zeros(1, _np.float32), _np.zeros(1, _np.int32),
                     _np.ones(1, _np.float32))
-            for w in self._width_buckets:
+            for w in widths:
                 self._programs.run(
                     "gen_decode", self._cache,
                     _np.zeros((S, 1), _np.int32),
@@ -956,6 +1020,23 @@ class GenerationService:
                 self._programs.copy_block(self._cache, 0, 0)
         _obs.mark_warm()
         return self._programs.compiled_signatures() - before
+
+    def _warmup_block(self, sigs, widths) -> None:
+        """A block-diffusion model's program set: the cache-filling
+        prefill per (T, W), the block step per table width."""
+        S, L = self._config.max_slots, self._block_len
+        zeros_s = _np.zeros(S, _np.int32)
+        for tb, wp in sigs:
+            self._programs.run_fill(
+                self._cache, _np.zeros((1, tb), _np.int32),
+                _np.zeros((1, tb), _np.int32), _np.zeros(1, _np.int32),
+                _np.zeros((1, wp), _np.int32))
+        for w in widths:
+            self._programs.run_block(
+                self._cache, _np.zeros((S, L), _np.int32),
+                _np.zeros((S, L), _np.int32), zeros_s,
+                _np.zeros((S, w), _np.int32), _np.zeros((S, L), bool),
+                zeros_s)
 
     def stop(self, drain: bool = True, timeout: Optional[float] = None,
              reject_queued: bool = False) -> None:
@@ -1356,6 +1437,7 @@ class GenerationService:
                              "blocks": len(r.blocks or ()),
                              "kind": counter}, ctx=r.trace):
             self._slots[i] = None
+            r.block = None   # a block in flight is dropped; commits stay
             if r.blocks:
                 # a preempted request's written context is valid history:
                 # index its full blocks so the decref below leaves them
@@ -1614,12 +1696,16 @@ class GenerationService:
         cfg = self._config
         out = {(tb, blocks_for(tb, cfg.block_size))
                for tb in self._seq_buckets}
+        # a block-diffusion model prefills whole blocks only, every
+        # context through the chunk walk, and never needs a cached
+        # prompt's last logits
+        step = self._block_len or 1
         if cfg.chunked_prefill:
-            for L in range(1, self._seq_buckets[-1] + 1):
+            for L in range(step, self._seq_buckets[-1] + 1, step):
                 for (_, _, tb, w) in self._chunk_plan(L):
                     out.add((tb, w))
-        if cfg.preemption:
-            for L in range(1, self._model_cfg.max_len):
+        if cfg.preemption or self._block_len:
+            for L in range(step, self._model_cfg.max_len, step):
                 for (_, _, tb, w) in self._chunk_plan(L, force_chunked=True):
                     out.add((tb, w))
         if cfg.prefix_cache:
@@ -1631,7 +1717,7 @@ class GenerationService:
             max_ctx = self._model_cfg.max_len - 1
             seen = set()
             for start in range(bs, max_ctx, bs):
-                for ctx in range(start + 1, max_ctx + 1):
+                for ctx in range(start + step, max_ctx + 1, step):
                     off, rem = start, ctx - start
                     while rem > 0 and (off, rem) not in seen:
                         seen.add((off, rem))
@@ -1646,12 +1732,31 @@ class GenerationService:
             # position p-1 (only block-aligned prompt lengths can be
             # fully cached, and fresh prompts are bounded by the ladder)
             tb0 = self._seq_buckets[0]
-            for p in range(bs, self._seq_buckets[-1] + 1, bs):
+            for p in (() if self._block_len else
+                      range(bs, self._seq_buckets[-1] + 1, bs)):
                 out.add((tb0, bucket_batch(blocks_for(p - 1 + tb0, bs),
                                            self._width_buckets)))
         return sorted(out)
 
+    def _chunk_inputs(self, r: _GenRequest, off: int, take: int, tb: int,
+                      wp: int):
+        """The host part of one prefill chunk (span
+        ``serving.prefill.build``): copy-on-write over its span, then
+        ``(tokens (1, tb), positions (1, tb), table (1, wp))``."""
+        with self._phase("build", "serving.prefill.build"):
+            self._cow_for_write(r, off, take)
+            table = _np.zeros((1, wp), _np.int32)
+            n = min(wp, len(r.blocks))
+            table[0, :n] = r.blocks[:n]
+            tokens = pad_tokens_right(
+                _np.asarray(r.seq_tokens[off:off + take], _np.int32),
+                tb)[None, :]
+            positions = _np.arange(off, off + tb, dtype=_np.int32)[None, :]
+        return tokens, positions, table
+
     def _prefill(self, r: _GenRequest) -> None:
+        if self._block_len:
+            return self._block_prefill(r)
         cfg = self._config
         next_tok = None
         # re-admission after preemption: replay the WHOLE cached context
@@ -1704,15 +1809,8 @@ class GenerationService:
             now = time.perf_counter()
         r.seg("prefill", now)
         for (off, take, tb, wp) in plan:
-            with self._phase("build", "serving.prefill.build"):
-                self._cow_for_write(r, off, take)
-                table = _np.zeros((1, wp), _np.int32)
-                n = min(wp, len(r.blocks))
-                table[0, :n] = r.blocks[:n]
-                tokens = pad_tokens_right(
-                    _np.asarray(r.seq_tokens[off:off + take], _np.int32),
-                    tb)[None, :]
-                positions = _np.arange(off, off + tb, dtype=_np.int32)[None, :]
+            tokens, positions, table = self._chunk_inputs(r, off, take, tb,
+                                                          wp)
             t_rung0 = time.perf_counter()
             with self._phase("step", "serving.prefill",
                              args={"rid": r.rid, "len": ctx,
@@ -1762,6 +1860,9 @@ class GenerationService:
         single-token step.  All three paths emit identical token VALUES —
         they differ only in how many tokens one device dispatch yields."""
         cfg = self._config
+        if self._block_len:
+            self._block_step(batch)
+            return
         if cfg.speculative:
             drafts = self._propose_drafts(batch)
             if any(drafts.values()):
@@ -2016,6 +2117,163 @@ class GenerationService:
                               "proposed": s_i, "accepted": acc,
                               "replica": self._replica_id})
         self._counts["spec_steps"] += 1
+
+    # -- generation by diffusion over blocks (docs/generation.md) -----------------
+    def _open_block(self, r: _GenRequest) -> None:
+        """A fresh block at ``r.ctx_len``: the tokens the sequence already
+        holds there (a prompt's leftover, fewer than a block), MASK behind
+        them."""
+        L = self._block_len
+        known = r.seq_tokens[r.ctx_len:r.ctx_len + L]
+        n = L - len(known)
+        r.block = known + [self._model.mask_id] * n
+        r.block_masked = [False] * len(known) + [True] * n
+        r.block_at = [-1] * L
+        r.block_pass = 0
+
+    def _block_prefill(self, r: _GenRequest) -> None:
+        """Admission of a request of a block-diffusion model: the whole
+        blocks of its context (the prompt's; after a preemption, all that
+        was committed) go through the chunk plan into the cache under the
+        block mask — offsets and lengths whole blocks, no logits, nothing
+        emitted — and the tokens left over open the block in flight."""
+        cfg = self._config
+        L = self._block_len
+        resumed = r.n_generated > 0
+        ctx = r.ctx_len if r.ctx_len > 0 else (r.prompt_len // L) * L
+        cached = min(r.cached_len, ctx)
+        if cached >= ctx:
+            plan = []
+        elif cached > 0:
+            plan = self._chunk_plan(ctx, start=cached)
+        else:
+            plan = self._chunk_plan(ctx, force_chunked=True)
+        now = time.perf_counter()
+        if r.trace is not None:
+            _trace.record_event("gen.admit", "serving", r.seg_t0, now,
+                                ctx=r.trace,
+                                args={"rid": r.rid, "resumed": resumed,
+                                      "blocks": len(r.blocks or ()),
+                                      "cached": cached,
+                                      "replica": self._replica_id})
+        if cached > 0:
+            r.seg("prefix_reuse", now)
+            now = time.perf_counter()
+        r.seg("prefill", now)
+        for (off, take, tb, wp) in plan:
+            tokens, positions, table = self._chunk_inputs(r, off, take, tb,
+                                                          wp)
+            t_rung0 = time.perf_counter()
+            with self._phase("step", "serving.prefill",
+                             args={"rid": r.rid, "len": ctx, "bucket": tb,
+                                   "off": off, "chunks": len(plan),
+                                   "resumed": resumed}, ctx=r.trace):
+                self._programs.run_fill(self._cache, tokens, positions,
+                                        _np.asarray([take], _np.int32),
+                                        table)
+            r.rung_s[tb] = r.rung_s.get(tb, 0.0) \
+                + (time.perf_counter() - t_rung0)
+        self._counts["prefill_tokens"] += sum(p[1] for p in plan)
+        self._counts["block_prefill_chunks"] += len(plan)
+        r.seg("decode", time.perf_counter())
+        if self._prefix is not None and not resumed and ctx > 0:
+            self._prefix.insert(r.seq_tokens[:ctx], r.blocks)
+        r.ctx_len = ctx
+        self._open_block(r)
+
+    def _block_step(self, batch: List[_GenRequest]) -> None:
+        """One pass of generation by diffusion over blocks, sibling of
+        :meth:`_spec_step`: every running row feeds its block of ``L``
+        token ids at ``ctx_len .. ctx_len + L - 1`` (K/V written there,
+        an earlier pass's overwritten; copy-on-write over the span as the
+        verify step does).  A row whose block holds MASK is on a denoise
+        pass: the program unmasks its most confident masked positions.  A
+        row with none left is on its commit pass — the same program, so
+        rows in different passes share one batch — after which the block's
+        K/V are those of its finished tokens: they are emitted at once,
+        ``ctx_len`` moves a block on, a fresh block opens."""
+        cfg = self._config
+        S, L = cfg.max_slots, self._block_len
+        schedule = self._model.unmask_schedule
+        rids = {r.rid for r in batch if r.state == _RUNNING}
+        with self._phase("build", "serving.block.build"):
+            tokens = _np.zeros((S, L), _np.int32)
+            positions = _np.zeros((S, L), _np.int32)
+            lengths = _np.zeros(S, _np.int32)
+            masked = _np.zeros((S, L), bool)
+            n_unmask = _np.zeros(S, _np.int32)
+            rows = []
+            max_w = 1
+            for i, r in enumerate(self._slots):
+                if r is None or r.state != _RUNNING or r.rid not in rids:
+                    continue
+                if self._prefix is not None:
+                    self._cow_for_write(r, r.ctx_len, L)
+                rows.append((i, r))
+                tokens[i] = r.block
+                positions[i] = r.ctx_len + _np.arange(L, dtype=_np.int32)
+                lengths[i] = L
+                masked[i] = r.block_masked
+                if any(r.block_masked):
+                    n_unmask[i] = schedule[min(r.block_pass,
+                                               len(schedule) - 1)]
+                max_w = max(max_w, blocks_for(r.ctx_len + L, cfg.block_size))
+            w = bucket_batch(max_w, self._width_buckets)
+            tables = _np.zeros((S, w), _np.int32)
+            for i, r in rows:
+                n = min(w, len(r.blocks))
+                tables[i, :n] = r.blocks[:n]
+        if _fault_injector().gen_step_fail(rids):
+            from ...fault.inject import FaultInjectedError
+            raise FaultInjectedError(
+                f"injected decode-step failure "
+                f"(TPUMX_FAULT_GEN_STEP_FAIL) at iteration "
+                f"{self._iteration}, batch rids {sorted(rids)}")
+        with self._phase("step", "serving.block_step",
+                         args={"running": len(rows), "width": int(w),
+                               "iteration": self._iteration}):
+            unmasked, touched, _ = self._programs.run_block(
+                self._cache, tokens, positions, lengths, tables, masked,
+                n_unmask)
+        with self._phase("emit", "serving.emit"):
+            counts = self._counts
+            for i, r in rows:
+                r.decode_steps += 1
+                if not any(r.block_masked):
+                    counts["block_commit_row_passes"] += 1
+                    self._commit_block(r)
+                    continue
+                for j in range(L):
+                    if r.block_masked[j] and unmasked[i, j] >= 0:
+                        r.block[j] = int(unmasked[i, j])
+                        r.block_masked[j] = False
+                        r.block_at[j] = r.block_pass
+                r.block_pass += 1
+            counts["block_passes"] += 1
+            counts["block_row_passes"] += len(rows)
+            counts["block_ctx_tokens"] += int(positions[:, -1].sum()) \
+                + len(rows)
+            counts["block_experts_touched"] += int(touched)
+
+    def _commit_block(self, r: _GenRequest) -> None:
+        """After a row's commit pass: emit the block's tokens the
+        sequence does not hold yet (cut after an end-of-sequence id or at
+        ``max_new_tokens``), move the context a block on and open the
+        next block."""
+        L = self._block_len
+        ctx0 = r.ctx_len
+        have = len(r.seq_tokens) - ctx0     # a prompt's leftover
+        n = self._emit_many(r, r.block[have:])
+        r.unmask_pass.extend(r.block_at[have:have + n])
+        r.mode_tokens["block"] = r.mode_tokens.get("block", 0) + n
+        self._counts["block_tokens_committed"] += n
+        if r.state == _RUNNING:
+            r.ctx_len = ctx0 + L
+            self._open_block(r)
+        else:
+            # finished inside the block: what the sequence holds is what
+            # the prefix index may see (full pages of it are whole blocks)
+            r.ctx_len = len(r.seq_tokens)
 
     def _choose_multistep_k(self, batch: List[_GenRequest]) -> int:
         """Adaptive scan length (docs/generation.md "multi-step
@@ -2367,9 +2625,20 @@ class GenerationService:
             "ttft_ms": {"p50": _ms(pct(ttft, 50)), "p99": _ms(pct(ttft, 99))},
             "inter_token_ms": {"p50": _ms(pct(itl, 50)),
                                "p99": _ms(pct(itl, 99))},
-            "decode_mode": ("spec" if self._config.speculative else
+            "decode_mode": ("block" if self._block_len else
+                            "spec" if self._config.speculative else
                             "multistep" if self._config.multistep_k >= 2
                             else "single"),
+            "block_diffusion": (None if not self._block_len else {
+                "block_length": self._block_len,
+                "passes": counts["block_passes"],
+                "row_passes": counts["block_row_passes"],
+                "commit_row_passes": counts["block_commit_row_passes"],
+                "tokens_committed": counts["block_tokens_committed"],
+                "experts_touched": counts["block_experts_touched"],
+                "ctx_tokens": counts["block_ctx_tokens"],
+                "prefill_chunks": counts["block_prefill_chunks"],
+            }),
             "speculative": (None if not self._config.speculative else {
                 "draft_mode": self._config.draft_mode,
                 "draft_k": self._config.draft_k,

@@ -1,9 +1,18 @@
 """The generation engine's compiled model programs.
 
 ONE traced step function serves both phases — prefill (B=1, T=seq-bucket)
-and decode (B=max_slots, T=1) — built from
-:func:`~mxnet_tpu.parallel.transformer.transformer_lm_decode` plus the
-per-row sampling kernel from :mod:`mxnet_tpu.ops.sampling`.  Each distinct
+and decode (B=max_slots, T=1) — built from the MODEL's cache-aware step
+plus the per-row sampling kernel from :mod:`mxnet_tpu.ops.sampling`.  A
+model (:func:`as_model`) is an object with ``step(params, tokens,
+positions, lengths, k_pool, v_pool, block_tables, *, attention_kernel,
+...) -> (logits, k_pool, v_pool, ...)``, its ``vocab``, ``max_len``,
+``heads``, ``cache_spec()`` (what :class:`PagedKVCache` is built from),
+``block_len`` (0: one token a row a step) and ``offers`` (the program
+families it can run).  :class:`~mxnet_tpu.parallel.transformer
+.TransformerLM` (GPT-2's block, ``transformer_lm_decode``) is the first,
+:class:`~mxnet_tpu.parallel.sdar_moe.SdarMoeLM` (grouped-KV rotary block,
+sparse experts, generation by diffusion over blocks: ``gen_block``) the
+second.  Each distinct
 ``(kind, batch, chunk, table-width)`` signature compiles exactly once;
 every lookup is fed through ``executor._note_cache`` so these programs
 appear in :func:`mxnet_tpu.executor.compile_cache_stats` (sites
@@ -34,7 +43,21 @@ import numpy as _np
 
 from ...observability import tracing as _tracing
 
-__all__ = ["GenerationPrograms", "block_copy_pools"]
+__all__ = ["GenerationPrograms", "block_copy_pools", "as_model"]
+
+
+def as_model(model, compute_dtype=None):
+    """The model object of a service: a ``TransformerConfig`` becomes
+    the GPT-2 block's :class:`TransformerLM`; anything that already has a
+    ``step`` is taken as it is."""
+    if hasattr(model, "step"):
+        return model
+    from ...parallel.transformer import TransformerConfig, TransformerLM
+
+    if isinstance(model, TransformerConfig):
+        return TransformerLM(model, compute_dtype)
+    raise TypeError(f"not a generation model: {model!r} (a TransformerConfig "
+                    f"or an object with step / cache_spec / vocab / max_len)")
 
 
 def _step_args(tokens, positions, lengths, block_tables, seeds, counters,
@@ -88,16 +111,13 @@ def block_copy_pools(k_pool, v_pool, src, dst, k_scale=None, v_scale=None):
 
 def _model_step(params, k_pool, v_pool, tokens, positions, lengths,
                 block_tables, seeds, counters, temperature, top_k, top_p,
-                *, cfg, compute_dtype, attention_kernel="gather",
-                mp_mesh=None):
+                *, model, attention_kernel="gather", mp_mesh=None):
     import jax.numpy as jnp
 
     from ...ops.sampling import sample_logits
-    from ...parallel.transformer import transformer_lm_decode
 
-    logits, k_pool, v_pool = transformer_lm_decode(
+    logits, k_pool, v_pool = model.step(
         params, tokens, positions, lengths, k_pool, v_pool, block_tables,
-        cfg, compute_dtype=compute_dtype,
         attention_kernel=attention_kernel, mp_mesh=mp_mesh)
     # logits at the LAST VALID position of each row feed the sampler
     # (prefill: position len-1 predicts token len; decode: T=1 row 0)
@@ -112,7 +132,7 @@ def _model_step(params, k_pool, v_pool, tokens, positions, lengths,
 
 def _model_step_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
                   positions, lengths, block_tables, seeds, counters,
-                  temperature, top_k, top_p, *, cfg, compute_dtype,
+                  temperature, top_k, top_p, *, model,
                   attention_kernel="gather", mp_mesh=None):
     """The int8-KV variant of :func:`_model_step` (docs/quantization.md):
     the per-(layer, block, head) scale arrays ride as two extra DONATED
@@ -121,11 +141,9 @@ def _model_step_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
     import jax.numpy as jnp
 
     from ...ops.sampling import sample_logits
-    from ...parallel.transformer import transformer_lm_decode
 
-    logits, k_pool, v_pool, k_scale, v_scale = transformer_lm_decode(
+    logits, k_pool, v_pool, k_scale, v_scale = model.step(
         params, tokens, positions, lengths, k_pool, v_pool, block_tables,
-        cfg, compute_dtype=compute_dtype,
         attention_kernel=attention_kernel, mp_mesh=mp_mesh,
         k_scale=k_scale, v_scale=v_scale)
     last_idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0,
@@ -139,8 +157,7 @@ def _model_step_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
 
 def _verify_step(params, k_pool, v_pool, tokens, positions, lengths,
                  block_tables, seeds, counters, temperature, top_k, top_p,
-                 *, cfg, compute_dtype, attention_kernel="gather",
-                 mp_mesh=None):
+                 *, model, attention_kernel="gather", mp_mesh=None):
     """Speculative verify (docs/generation.md "Speculative decoding"):
     ONE cache-aware multi-query step over ``[pending, d_1..d_s]`` per row
     — the same chunked-prefill path as :func:`_model_step`, but ALL valid
@@ -148,11 +165,9 @@ def _verify_step(params, k_pool, v_pool, tokens, positions, lengths,
     just the last one.  Returns per-position target tokens plus the
     leading accepted-draft count per row."""
     from ...ops.sampling import speculative_verify
-    from ...parallel.transformer import transformer_lm_decode
 
-    logits, k_pool, v_pool = transformer_lm_decode(
+    logits, k_pool, v_pool = model.step(
         params, tokens, positions, lengths, k_pool, v_pool, block_tables,
-        cfg, compute_dtype=compute_dtype,
         attention_kernel=attention_kernel, mp_mesh=mp_mesh)
     target, accepted = speculative_verify(
         logits, tokens, seeds, counters, temperature, top_k, top_p,
@@ -162,15 +177,13 @@ def _verify_step(params, k_pool, v_pool, tokens, positions, lengths,
 
 def _verify_step_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
                    positions, lengths, block_tables, seeds, counters,
-                   temperature, top_k, top_p, *, cfg, compute_dtype,
+                   temperature, top_k, top_p, *, model,
                    attention_kernel="gather", mp_mesh=None):
     """int8-KV variant of :func:`_verify_step` (scales donated along)."""
     from ...ops.sampling import speculative_verify
-    from ...parallel.transformer import transformer_lm_decode
 
-    logits, k_pool, v_pool, k_scale, v_scale = transformer_lm_decode(
+    logits, k_pool, v_pool, k_scale, v_scale = model.step(
         params, tokens, positions, lengths, k_pool, v_pool, block_tables,
-        cfg, compute_dtype=compute_dtype,
         attention_kernel=attention_kernel, mp_mesh=mp_mesh,
         k_scale=k_scale, v_scale=v_scale)
     target, accepted = speculative_verify(
@@ -181,8 +194,7 @@ def _verify_step_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
 
 def _multistep(params, k_pool, v_pool, tokens, positions, lengths,
                block_tables, seeds, counters, temperature, top_k, top_p,
-               *, k, cfg, compute_dtype, attention_kernel="gather",
-               mp_mesh=None):
+               *, k, model, attention_kernel="gather", mp_mesh=None):
     """``k`` decode iterations inside ONE donated program via
     ``lax.scan`` (docs/generation.md "multi-step decoding") — each scan
     iteration is exactly the single-step decode math (same (S, 1) model
@@ -196,14 +208,13 @@ def _multistep(params, k_pool, v_pool, tokens, positions, lengths,
     import jax.numpy as jnp
 
     from ...ops.sampling import sample_logits
-    from ...parallel.transformer import transformer_lm_decode
 
     def body(carry, _):
         k_pool, v_pool, tok, pos, ctr = carry
-        logits, k_pool, v_pool = transformer_lm_decode(
+        logits, k_pool, v_pool = model.step(
             params, tok[:, None], pos[:, None], lengths, k_pool, v_pool,
-            block_tables, cfg, compute_dtype=compute_dtype,
-            attention_kernel=attention_kernel, mp_mesh=mp_mesh)
+            block_tables, attention_kernel=attention_kernel,
+            mp_mesh=mp_mesh)
         nxt = sample_logits(logits[:, 0, :], seeds, ctr, temperature,
                             top_k, top_p)
         return (k_pool, v_pool, nxt, pos + 1, ctr + 1), nxt
@@ -219,7 +230,7 @@ def _multistep(params, k_pool, v_pool, tokens, positions, lengths,
 
 def _multistep_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
                  positions, lengths, block_tables, seeds, counters,
-                 temperature, top_k, top_p, *, k, cfg, compute_dtype,
+                 temperature, top_k, top_p, *, k, model,
                  attention_kernel="gather", mp_mesh=None):
     """int8-KV variant of :func:`_multistep`: the scale arrays join the
     scan carry, and because each iteration scatters exactly one position
@@ -229,14 +240,13 @@ def _multistep_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
     import jax.numpy as jnp
 
     from ...ops.sampling import sample_logits
-    from ...parallel.transformer import transformer_lm_decode
 
     def body(carry, _):
         k_pool, v_pool, k_scale, v_scale, tok, pos, ctr = carry
-        logits, k_pool, v_pool, k_scale, v_scale = transformer_lm_decode(
+        logits, k_pool, v_pool, k_scale, v_scale = model.step(
             params, tok[:, None], pos[:, None], lengths, k_pool, v_pool,
-            block_tables, cfg, compute_dtype=compute_dtype,
-            attention_kernel=attention_kernel, mp_mesh=mp_mesh,
+            block_tables, attention_kernel=attention_kernel,
+            mp_mesh=mp_mesh,
             k_scale=k_scale, v_scale=v_scale)
         nxt = sample_logits(logits[:, 0, :], seeds, ctr, temperature,
                             top_k, top_p)
@@ -252,16 +262,48 @@ def _multistep_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
     return jnp.transpose(toks), k_pool, v_pool, k_scale, v_scale
 
 
+def _block_fill(params, k_pool, v_pool, tokens, positions, lengths,
+                block_tables, *, model, attention_kernel="gather"):
+    """Prefill of a block-diffusion model: whole blocks of context written
+    into the cache, no logits — the first block step reads the first
+    generated positions' own logits."""
+    _, k_pool, v_pool, _ = model.step(
+        params, tokens, positions, lengths, k_pool, v_pool, block_tables,
+        attention_kernel=attention_kernel, call="prefill",
+        want_logits=False)
+    return k_pool, v_pool
+
+
+def _block_step(params, k_pool, v_pool, tokens, positions, lengths,
+                block_tables, masked, n_unmask, *, model,
+                attention_kernel="gather"):
+    """One pass of generation by diffusion over blocks (docs/generation.md
+    "Block-diffusion generation"): every row feeds its block of
+    ``model.block_len`` token ids, MASK where ``masked``, at positions
+    ``ctx .. ctx + L - 1``; K/V are written at the block's positions
+    (overwriting an earlier pass's), and in the same program the rows'
+    ``n_unmask`` most confident masked positions are chosen.  A row with
+    no MASK is on its commit pass: the same program, its tokens ignored.
+    Returns ``(unmasked (S, L): the new token id, -1 where nothing was
+    unmasked; experts touched, summed over layers; logits (S, L, vocab);
+    pools)``."""
+    from ...ops.sampling import block_unmask
+
+    logits, k_pool, v_pool, touched = model.step(
+        params, tokens, positions, lengths, k_pool, v_pool, block_tables,
+        attention_kernel=attention_kernel, call="block")
+    return (block_unmask(logits, masked, n_unmask), touched, logits,
+            k_pool, v_pool)
+
+
 class GenerationPrograms:
     """Owns the jitted step + per-signature compile accounting."""
 
-    def __init__(self, params, cfg, compute_dtype=None, mp_devices: int = 1,
-                 shard_rules=None, kv_dtype=None):
+    def __init__(self, params, model, compute_dtype=None,
+                 mp_devices: int = 1, shard_rules=None, kv_dtype=None):
         import jax
-        import jax.numpy as jnp
 
-        self._cfg = cfg
-        self._compute_dtype = compute_dtype
+        model = self._model = as_model(model, compute_dtype)
         # int8 paged KV cache (docs/quantization.md): the jitted step
         # gains the two donated scale operands and every program key a
         # ("kv_dtype", "int8") component; None keeps the classic layout
@@ -294,37 +336,37 @@ class GenerationPrograms:
         from ...ops.pallas_kernels import pallas_enabled
 
         mp_ok = (self._mp_mesh is None
-                 or cfg.n_heads % int(self._mp_mesh.shape["mp"]) == 0)
+                 or model.heads % int(self._mp_mesh.shape["mp"]) == 0)
         self._kernel = "paged" if pallas_enabled() and mp_ok else "gather"
         self._params = self._place_params(params)
-        if kv_dtype == "int8":
-            self._jit = jax.jit(
-                functools.partial(
-                    _model_step_q, cfg=cfg, compute_dtype=compute_dtype,
-                    attention_kernel=self._kernel,
-                    mp_mesh=self._mp_mesh),
-                donate_argnums=(1, 2, 3, 4))
-        else:
-            self._jit = jax.jit(
-                functools.partial(
-                    _model_step, cfg=cfg, compute_dtype=compute_dtype,
-                    attention_kernel=self._kernel,
-                    mp_mesh=self._mp_mesh),
-                donate_argnums=(1, 2))
         # multi-token decoding (docs/generation.md "Speculative
         # decoding"): the verify step shares the model step's operand
         # layout but returns per-position targets + accept counts; the
         # multistep scan needs one jitted partial per static k (built
         # lazily — creating a jit wrapper traces nothing)
-        self._step_kw = dict(
-            cfg=cfg, compute_dtype=compute_dtype,
-            attention_kernel=self._kernel,
-            mp_mesh=self._mp_mesh)
-        if kv_dtype == "int8":
+        self._step_kw = dict(model=model, attention_kernel=self._kernel,
+                             mp_mesh=self._mp_mesh)
+        self._jit = self._jit_verify = self._jit_fill = self._jit_block \
+            = None
+        if model.block_len:
+            # generation by diffusion over blocks (docs/generation.md):
+            # a prefill that only fills the cache, and the block step
+            kw = dict(model=model, attention_kernel=self._kernel)
+            self._jit_fill = jax.jit(functools.partial(_block_fill, **kw),
+                                     donate_argnums=(1, 2))
+            self._jit_block = jax.jit(functools.partial(_block_step, **kw),
+                                      donate_argnums=(1, 2))
+        elif kv_dtype == "int8":
+            self._jit = jax.jit(
+                functools.partial(_model_step_q, **self._step_kw),
+                donate_argnums=(1, 2, 3, 4))
             self._jit_verify = jax.jit(
                 functools.partial(_verify_step_q, **self._step_kw),
                 donate_argnums=(1, 2, 3, 4))
         else:
+            self._jit = jax.jit(
+                functools.partial(_model_step, **self._step_kw),
+                donate_argnums=(1, 2))
             self._jit_verify = jax.jit(
                 functools.partial(_verify_step, **self._step_kw),
                 donate_argnums=(1, 2))
@@ -481,6 +523,32 @@ class GenerationPrograms:
         return _synced(*self._dispatch(self._jit_verify, cache, _step_args(
             tokens, positions, lengths, block_tables, seeds, counters,
             temperature, top_k, top_p)))
+
+    def run_fill(self, cache, tokens, positions, lengths, block_tables):
+        """A block-diffusion model's prefill chunk (site ``gen_prefill``):
+        fills the cache, returns nothing to read."""
+        tokens = _np.asarray(tokens, _np.int32)
+        block_tables = _np.asarray(block_tables, _np.int32)
+        self._note("gen_prefill", self._key("gen_prefill", cache, tokens,
+                                            block_tables))
+        self._dispatch(self._jit_fill, cache, (
+            tokens, _np.asarray(positions, _np.int32),
+            _np.asarray(lengths, _np.int32), block_tables))
+
+    def run_block(self, cache, tokens, positions, lengths, block_tables,
+                  masked, n_unmask):
+        """One block step (site ``gen_block``): returns ``(unmasked np(S,
+        L), experts touched np(), logits (S, L, vocab) on the device)``
+        — see :func:`_block_step`."""
+        tokens = _np.asarray(tokens, _np.int32)
+        block_tables = _np.asarray(block_tables, _np.int32)
+        self._note("gen_block", self._key("gen_block", cache, tokens,
+                                          block_tables))
+        unmasked, touched, logits = self._dispatch(self._jit_block, cache, (
+            tokens, _np.asarray(positions, _np.int32),
+            _np.asarray(lengths, _np.int32), block_tables,
+            _np.asarray(masked, _np.bool_), _np.asarray(n_unmask, _np.int32)))
+        return _synced(unmasked, touched) + (logits,)
 
     def _ms_jit(self, k: int):
         import jax

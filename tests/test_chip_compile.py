@@ -250,13 +250,19 @@ def test_decode_step_program_compiles(one_chip, monkeypatch, kv_dtype):
         sc = sds((cfg.n_layers, NB, H), jnp.float32)
         kw = dict(k_scale=sc, v_scale=sc)
 
+    # through the engine's model seam (serving/generation/programs.py):
+    # GPT-2's block is its first model, and compiles to the same program
+    model = tr.TransformerLM(cfg)
+
     def step(params, tokens, positions, lengths, kp, vp, tables, **kw):
-        return tr.transformer_lm_decode(params, tokens, positions, lengths,
-                                        kp, vp, tables, cfg,
-                                        attention_kernel="paged", **kw)
+        return model.step(params, tokens, positions, lengths, kp, vp,
+                          tables, attention_kernel="paged", **kw)
 
     text = jax.jit(step, donate_argnums=(4, 5)).lower(
         *shapes, **kw).compile().as_text()
+    # the kernel's names in a device trace are the parent's
+    assert "_paged_call_w64_decode" in text
+    assert not re.search(r"_paged_call_w\d+_t\d+_", text)
     # per layer: one paged-attention call and two LayerNorm calls, plus
     # the final LayerNorm
     assert text.count("tpu_custom_call") >= 3 * cfg.n_layers + 1
@@ -316,3 +322,103 @@ def test_decode_step_program_compiles_mp4(topo, monkeypatch):
         *args).compile().as_text()
     assert text.count("tpu_custom_call") >= 3 * cfg.n_layers + 1
     assert "all-reduce" in text     # the row-parallel projections' psum
+
+
+# the sdar-30b-a3b configuration's cell (PERF.md section 4): hidden 2048,
+# 32 query heads over 4 KV heads of 128, 128 experts of 768 top-8,
+# vocabulary 151,936, 6 layers, bfloat16 parameters and a bfloat16 pool of
+# 4608 blocks of 16 folded 512 wide, 64 slots, blocks of 4
+def _sdar_cell(sds, n_layers):
+    from mxnet_tpu.parallel import sdar_moe as sm
+
+    cfg = sm.SdarMoeConfig(num_hidden_layers=n_layers)
+    model = sm.SdarMoeLM(cfg, max_len=4096)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: sm.sdar_moe_init(cfg, jax.random.PRNGKey(0),
+                                                jnp.bfloat16)))
+    pool = sds((n_layers, 4608, BS, 512), jnp.bfloat16)
+    return model, params, pool
+
+
+# what may produce a pool-sized value: the donated parameter, the scatters
+# into it (alone, fused, or as an update of a reshaped view) — never a copy
+_IN_PLACE = {"parameter", "scatter", "fusion", "bitcast",
+             "dynamic-update-slice", "copy-start", "copy-done"}
+
+
+def _pool_makers(text, n_layers):
+    whole = f"= bf16[{n_layers},4608,{BS},512]"
+    assert f"= bf16[4608,{BS},512]" not in text     # never one layer of it
+    return {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
+            for ln in text.splitlines() if whole in ln}
+
+
+@pytest.mark.parametrize("M,K,N", [(2048, 2048, 768), (2048, 768, 2048),
+                                   (16384, 2048, 768), (16384, 768, 2048)])
+def test_grouped_matmul_kernel_compiles_at_the_cells_shapes(one_chip, M, K,
+                                                            N):
+    """The expert products of a block step (64 rows x 4 x top-8) and of a
+    2048-token prefill chunk: a whole expert matrix a grid step."""
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = _compile(
+        functools.partial(gm._gmm_call.__wrapped__, interpret=False),
+        sds((M, K), jnp.bfloat16), sds((128, K, N), jnp.bfloat16),
+        sds((128,), jnp.int32))
+    assert "tpu_custom_call" in text and "_gmm_call" in text
+
+
+def test_block_step_program_compiles_at_the_cells_shapes(one_chip,
+                                                         monkeypatch):
+    """``gen_block`` as the service dispatches it at the cell's widest
+    table: the chip's compiler accepts the grouped-head chunk body on the
+    whole layered pool, the grouped expert products stay grouped (no
+    dense ``(tokens, experts, width)`` product), and the program fits."""
+    from mxnet_tpu.serving.generation import programs as gp
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    n_layers, S, L, W = 6, 64, 4, 256
+    model, params, pool = _sdar_cell(sds, n_layers)
+    fn = jax.jit(functools.partial(gp._block_step, model=model,
+                                   attention_kernel="paged"),
+                 donate_argnums=(1, 2))
+    compiled = fn.lower(
+        params, pool, pool, sds((S, L), jnp.int32), sds((S, L), jnp.int32),
+        sds((S,), jnp.int32), sds((S, W), jnp.int32), sds((S, L), jnp.bool_),
+        sds((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("_paged_call_w256_t4_block") >= n_layers
+    assert not re.search(r"_paged_call_w\d+_(decode|t\d+_prefill)", text)
+    # three grouped products a layer, each a kernel
+    assert len(re.findall(r"%_gmm_call[\w.\-]* = f32\[2048,", text)) \
+        == 3 * n_layers
+    assert " sort(" in text and "f32[256,128,768]" not in text
+    assert _pool_makers(text, n_layers) <= _IN_PLACE
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes < 13e9
+
+
+@pytest.mark.parametrize("T,W", [(512, 32), (2048, 256)])
+def test_block_prefill_program_compiles_at_the_cells_shapes(one_chip,
+                                                            monkeypatch,
+                                                            T, W):
+    """The cache-filling prefill of the cell's two chunk lengths (no
+    head); depth 2: layers repeat the same program."""
+    from mxnet_tpu.serving.generation import programs as gp
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    model, params, pool = _sdar_cell(sds, 2)
+    fn = jax.jit(functools.partial(gp._block_fill, model=model,
+                                   attention_kernel="paged"),
+                 donate_argnums=(1, 2))
+    text = fn.lower(params, pool, pool, sds((1, T), jnp.int32),
+                    sds((1, T), jnp.int32), sds((1,), jnp.int32),
+                    sds((1, W), jnp.int32)).compile().as_text()
+    assert text.count(f"_paged_call_w{W}_t{T}_prefill") >= 2
+    assert "151936]" not in text.replace("bf16[151936,2048]", "")  # no head
+    assert _pool_makers(text, 2) <= _IN_PLACE

@@ -641,3 +641,40 @@ def test_generation_mp_axis_matches_single_device(params):
         return outs
 
     assert run(2) == run(1)
+
+
+def test_gpt2_programs_through_the_model_seam_are_the_parents():
+    """GPT-2's block is the first model behind the engine's seam
+    (``programs.as_model``): at the benchmark cell's ladder and pool
+    geometry it warms the 15 programs the parent compiled — the same
+    kinds and signatures, no block-diffusion program among them."""
+    from mxnet_tpu.parallel.transformer import TransformerLM
+    from mxnet_tpu.serving.generation.programs import as_model
+
+    cfg = tr.TransformerConfig(vocab=61, d_model=16, n_heads=2,
+                               n_layers=1, d_ff=32, max_len=1024)
+    params = tr.transformer_lm_init(cfg, jax.random.PRNGKey(0))
+    model = as_model(cfg)
+    assert isinstance(model, TransformerLM) and as_model(model) is model
+    assert (model.vocab, model.max_len, model.heads, model.block_len) \
+        == (61, 1024, 2, 0)
+    assert model.cache_spec() == dict(n_layers=1, n_heads=2, d_head=8,
+                                      dtype=jnp.float32)
+    with pytest.raises(TypeError):
+        as_model(object())
+    svc = GenerationService(params, cfg, GenerationConfig(
+        max_slots=32, block_size=16, num_blocks=64,
+        seq_buckets=(128, 512, 1023)), start=False)
+    assert svc.warmup() == 15
+    pool = ("kv_pool", (1, 64, 16, 16), "float32")
+    want = {("gen_prefill", (("tokens", (1, t), "int32"),
+                             ("block_tables", (1, w), "int32"), pool))
+            for t, w in ((128, 8), (128, 16), (128, 32), (128, 64),
+                         (512, 32), (512, 64), (1023, 64))}
+    want |= {("gen_decode", (("tokens", (32, 1), "int32"),
+                             ("block_tables", (32, w), "int32"), pool))
+             for w in (1, 2, 4, 8, 16, 32, 64)}
+    want |= {("gen_block_copy", (pool,))}
+    assert set(svc.compile_stats()) == want
+    assert svc.stats()["decode_mode"] == "single"
+    assert svc.stats()["block_diffusion"] is None
