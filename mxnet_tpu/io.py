@@ -17,6 +17,7 @@ from typing import List, Optional
 
 import numpy as _np
 
+from . import observability as _obs
 from .base import MXNetError
 from .ndarray import array as nd_array
 from .ndarray.ndarray import NDArray
@@ -179,7 +180,18 @@ class DataIter:
 
 
 class NDArrayIter(DataIter):
-    """Iterate over ndarray/numpy data (reference: io.py:546)."""
+    """Iterate over ndarray/numpy data (reference: io.py:546).
+
+    The arrays are referenced, not copied.  A batch whose rows are one
+    ascending, contiguous run of the source (every whole batch of an
+    unshuffled iterator) is handed to ``device_put`` as a view, with no
+    host copy; any other batch (a shuffled order, the wrapped last batch
+    of ``pad`` / ``roll_over``) is gathered into a fresh buffer.  Either
+    way a batch that ``next()`` has returned is not changed by a later
+    write to the source: the view path waits for the batch's transfer,
+    because ``device_put`` keeps reading a host array after it returns.
+    Rows written before their batch is taken do show in it
+    (docs/perf_guide.md section 6)."""
 
     def __init__(self, data, label=None, batch_size=1, shuffle=False,
                  last_batch_handle="pad", data_name="data", label_name="softmax_label"):
@@ -243,24 +255,48 @@ class NDArrayIter(DataIter):
         need to reproduce the current position)."""
         return max(0, (self.cursor + self.batch_size) // self.batch_size)
 
-    def _take(self, arrays):
-        out = []
-        for k, v in arrays:
-            if self.cursor + self.batch_size <= self.num_data:
-                sel = self.idx[max(self.cursor, 0):self.cursor + self.batch_size]
-            else:
-                pad = self.batch_size - (self.num_data - self.cursor)
-                sel = _np.concatenate([self.idx[self.cursor:], self.idx[:pad]])
-                if self.last_batch_handle == "roll_over":
-                    self._rolled = pad
-            out.append(nd_array(v[sel]))
+    def _rows(self):
+        """Source rows of the batch under the cursor: a ``slice`` where they
+        are one ascending run ``a, a+1, ...`` of the source, so that
+        ``v[rows]`` is a view; otherwise the index array NumPy gathers by.
+        Read off the batch's own indices: a shuffled order, the wrapped
+        last batch of ``pad`` / ``roll_over`` and anything else that
+        permuted ``self.idx`` gather as they always did."""
+        end = self.cursor + self.batch_size
+        if end > self.num_data:
+            pad = end - self.num_data
+            if self.last_batch_handle == "roll_over":
+                self._rolled = pad
+            return _np.concatenate([self.idx[self.cursor:], self.idx[:pad]])
+        sel = self.idx[max(self.cursor, 0):end]
+        if len(sel) and (_np.diff(sel) == 1).all():
+            first = int(sel[0])
+            return slice(first, first + len(sel))
+        return sel
+
+    def _take(self, arrays, rows):
+        out = [nd_array(v[rows]) for _, v in arrays]
+        if isinstance(rows, slice):
+            # a view is the caller's own memory, and device_put still reads
+            # it after it has returned (on the CPU backend too): wait for
+            # the transfers, so that no later write to the source reaches
+            # the batch served.  The gather hands over a private temporary.
+            for a in out:
+                a.wait_to_read()
         return out
 
     def getdata(self):
-        return self._take(self.data)
+        rows = self._rows()
+        _obs.registry().counter(
+            "io_ndarrayiter_batches_total",
+            labels={"path": "view" if isinstance(rows, slice) else "gather"},
+            help="NDArrayIter batches served as a view of the source (one "
+                 "contiguous run of rows, no host copy) or by a gather"
+        ).inc()
+        return self._take(self.data, rows)
 
     def getlabel(self):
-        return self._take(self.label)
+        return self._take(self.label, self._rows())
 
     def getpad(self):
         if self.last_batch_handle == "pad" and self.cursor + self.batch_size > self.num_data:

@@ -106,6 +106,168 @@ def test_prefetching_iter():
     assert len(batches) == 4
 
 
+def _plain_epochs(n, batch, shuffle, handle, seed, epochs):
+    """What ``NDArrayIter`` serves, worked out here with nothing of the
+    iterator's: per epoch a list of ``(source rows, pad)``, every batch's
+    rows an index array for a plain ``v[rows]`` gather.  ``reset()`` runs
+    once in the constructor and once before each later epoch."""
+    rng = np.random.RandomState(seed)
+    idx = np.arange(n)
+    num = (n // batch) * batch if handle == "discard" else n
+    rolled, out = 0, []
+    for _ in range(epochs):
+        consumed = idx[:rolled].copy() \
+            if handle == "roll_over" and rolled else None
+        if shuffle:
+            rng.shuffle(idx)
+        start = 0
+        if consumed is not None:
+            mask = np.isin(idx, consumed)
+            idx = np.concatenate([idx[mask], idx[~mask]])
+            start = len(consumed)
+        rolled, epoch = 0, []
+        for cur in range(start, num, batch):
+            over = max(cur + batch - num, 0)
+            rows = np.concatenate([idx[cur:cur + batch], idx[:over]])
+            if over and handle == "roll_over":
+                rolled = over
+            epoch.append((rows, over if handle == "pad" else 0))
+        out.append(epoch)
+    return out
+
+
+def _iter_source(n):
+    X = np.arange(n * 6, dtype=np.float32).reshape(n, 2, 3) / 7.0
+    y = np.arange(n, dtype=np.float32) * 3.0
+    return X, y
+
+
+def _assert_serves(it, X, y, epochs):
+    for e, want in enumerate(epochs):
+        if e:
+            it.reset()
+        got = list(it)
+        assert len(got) == len(want)
+        for b, (rows, pad) in zip(got, want):
+            data, label = b.data[0].asnumpy(), b.label[0].asnumpy()
+            assert data.dtype == X.dtype and data.shape == X[rows].shape
+            np.testing.assert_array_equal(data, X[rows])
+            np.testing.assert_array_equal(label, y[rows])
+            assert b.pad == pad
+
+
+@pytest.mark.parametrize("n,batch", [(12, 4), (10, 4), (9, 2)])
+@pytest.mark.parametrize("handle", ["pad", "discard", "roll_over"])
+@pytest.mark.parametrize("shuffle", [False, True])
+def test_ndarray_iter_serves_what_a_plain_gather_gives(shuffle, handle, n,
+                                                       batch):
+    """View or gather, every batch, pad and the order over two epochs are
+    the plain gather's (sizes that divide and that do not)."""
+    X, y = _iter_source(n)
+    np.random.seed(1234)
+    it = mx.io.NDArrayIter(X, y, batch_size=batch, shuffle=shuffle,
+                           last_batch_handle=handle)
+    _assert_serves(it, X, y,
+                   _plain_epochs(n, batch, shuffle, handle, 1234, 2))
+
+
+@pytest.mark.parametrize("shuffle", [False, True])
+def test_ndarray_iter_seek_tell_fast_forward(shuffle):
+    X, y = _iter_source(22)
+    np.random.seed(99)
+    it = mx.io.NDArrayIter(X, y, batch_size=4, shuffle=shuffle)
+    (want,) = _plain_epochs(22, 4, shuffle, "pad", 99, 1)
+    next(it)
+    assert it.tell() == 1
+    it.seek(3)
+    for k in (3, 4, 5):             # 5 is the wrapped last batch
+        b = next(it)
+        assert it.tell() == k + 1 and b.pad == want[k][1]
+        np.testing.assert_array_equal(b.data[0].asnumpy(), X[want[k][0]])
+    it.seek(0)
+    assert mx.io.fast_forward(it, 2) == 2 and it.tell() == 2
+    np.testing.assert_array_equal(next(it).label[0].asnumpy(),
+                                  y[want[2][0]])
+
+
+@pytest.mark.parametrize("round_batch", [True, False])
+def test_csv_iter_serves_what_a_plain_gather_gives(tmp_path, round_batch):
+    X, y = _iter_source(10)
+    np.savetxt(tmp_path / "d.csv", X.reshape(10, 6), delimiter=",",
+               fmt="%.9g")
+    np.savetxt(tmp_path / "l.csv", y, delimiter=",", fmt="%.9g")
+    it = mx.io.CSVIter(str(tmp_path / "d.csv"), (2, 3),
+                       label_csv=str(tmp_path / "l.csv"), batch_size=4,
+                       round_batch=round_batch)
+    handle = "roll_over" if round_batch else "pad"
+    _assert_serves(it, X, y, _plain_epochs(10, 4, False, handle, 0, 2))
+
+
+@pytest.mark.parametrize("shuffle,batch,shares", [
+    (False, 4, [True, True, False]),     # two runs, then the wrapped batch
+    (True, 4, [False, False, False]),    # a permuted order gathers
+    (True, 1, [True] * 10),              # one row is a run whatever the order
+])
+def test_ndarray_iter_contiguous_batch_is_a_view(monkeypatch, shuffle, batch,
+                                                 shares):
+    """A contiguous batch makes no host copy: what reaches ``device_put``
+    shares memory with the source; a shuffled or wrapped batch does not."""
+    X, y = _iter_source(10)
+    np.random.seed(5)                    # this order has no run of four
+    it = mx.io.NDArrayIter(X, y, batch_size=batch, shuffle=shuffle)
+    seen = []                            # host arrays handed to nd.array
+
+    def spy(source):
+        seen.append(source)
+        return mx.nd.array(source)
+
+    monkeypatch.setattr(mx.io, "nd_array", spy)
+    assert len(list(it)) == len(shares)
+    data, labels = seen[0::2], seen[1::2]
+    assert [np.shares_memory(a, X) for a in data] == shares
+    assert [np.shares_memory(a, y) for a in labels] == shares
+
+
+def test_ndarray_iter_batch_does_not_alias_the_source():
+    """Writing to the source once ``next()`` has returned does not reach
+    the batch served.  Batches of 16 MB: ``device_put`` reads a host array
+    of that size after it has returned, so an unfenced view loses this."""
+    n, batch = 48, 16
+    X = np.random.RandomState(0).rand(n, 512, 512).astype(np.float32)
+    it = mx.io.NDArrayIter(X, np.arange(n, dtype=np.float32),
+                           batch_size=batch)
+    for k, b in enumerate(it):
+        rows = slice(k * batch, (k + 1) * batch)
+        keep = X[rows].copy()
+        X[rows] = -1.0
+        np.testing.assert_array_equal(b.data[0].asnumpy(), keep)
+        X[rows] = keep
+    assert k == 2
+
+
+def test_ndarray_iter_counts_views_and_gathers():
+    def served():
+        reg = mx.observability.registry()
+        return [reg.counter("io_ndarrayiter_batches_total",
+                            labels={"path": p}).value
+                for p in ("view", "gather")]
+
+    X, y = _iter_source(10)
+    before = served()
+    it = mx.io.NDArrayIter(X, y, batch_size=4)        # 2 runs + 1 wrapped
+    list(it)
+    it.reset()
+    list(it)
+    assert [a - b for a, b in zip(served(), before)] == [4, 2]
+    before = served()
+    np.random.seed(5)
+    list(mx.io.NDArrayIter(X, y, batch_size=4, shuffle=True,
+                           last_batch_handle="discard"))
+    assert [a - b for a, b in zip(served(), before)] == [0, 2]
+    text = mx.observability.to_prometheus()
+    assert 'io_ndarrayiter_batches_total{path="view"}' in text
+
+
 def test_recordio_roundtrip(tmp_path):
     from mxnet_tpu import recordio
 
