@@ -316,8 +316,14 @@ class SdarMoeLM:
                     n_heads=self.cfg.num_key_value_heads,
                     d_head=self.cfg.head_dim, dtype=self.kv_dtype)
 
-    def step(self, params, tokens, positions, lengths, k_pool, v_pool,
-             block_tables, **kw):
-        return sdar_moe_decode(params, tokens, positions, lengths, k_pool,
-                               v_pool, block_tables, self.cfg,
-                               max_len=self.max_len, **kw)
+    def step(self, params, tokens, positions, lengths, pools, block_tables,
+             *, attention_kernel, mp_mesh=None, call=None, want_logits=True):
+        """The serving seam's one contract (``programs.py``): ``pools`` is
+        ``(k, v)``; returns ``(logits, pools, aux)``, ``aux`` the experts
+        touched.  No mesh is offered, so ``mp_mesh`` is always None."""
+        logits, k_pool, v_pool, touched = sdar_moe_decode(
+            params, tokens, positions, lengths, *pools, block_tables,
+            self.cfg, attention_kernel=attention_kernel,
+            call=call or "prefill", want_logits=want_logits,
+            max_len=self.max_len)
+        return logits, (k_pool, v_pool), touched

@@ -86,11 +86,22 @@ class TransformerLM:
                     d_head=self.cfg.d_head,
                     dtype=self.compute_dtype or jnp.float32)
 
-    def step(self, params, tokens, positions, lengths, k_pool, v_pool,
-             block_tables, **kw):
-        return transformer_lm_decode(
+    def step(self, params, tokens, positions, lengths, pools, block_tables,
+             *, attention_kernel, mp_mesh=None, call=None, want_logits=True):
+        """The serving seam's one contract (``programs.py``): ``pools`` is
+        ``(k, v)`` or, for the int8 pool, ``(k, v, k_scale, v_scale)``;
+        returns ``(logits, pools, aux)`` with ``pools`` as it came in.
+        GPT-2's block names its kernel calls by their shape and always
+        computes logits (``call`` / ``want_logits`` are the block-diffusion
+        model's), and has nothing else to hand back (``aux`` None)."""
+        k_pool, v_pool, *scales = pools
+        k_scale, v_scale = scales or (None, None)
+        logits, *pools = transformer_lm_decode(
             params, tokens, positions, lengths, k_pool, v_pool,
-            block_tables, self.cfg, compute_dtype=self.compute_dtype, **kw)
+            block_tables, self.cfg, compute_dtype=self.compute_dtype,
+            attention_kernel=attention_kernel, mp_mesh=mp_mesh,
+            k_scale=k_scale, v_scale=v_scale)
+        return logits, tuple(pools), None
 
 
 def transformer_lm_init(cfg: TransformerConfig, key) -> Params:

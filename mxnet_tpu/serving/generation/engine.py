@@ -43,7 +43,8 @@ import queue
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import numpy as _np
 
@@ -407,6 +408,26 @@ class _Phase:
         self._span.__exit__(*exc)
         self._acc[self._key] += self._span.duration_us
         return False
+
+
+class _StepInputs(NamedTuple):
+    """The host side of one model step over the decode slots
+    (:meth:`GenerationService._build_step`): the ``(slot, request)`` rows
+    it feeds, the bucketed table width, and the program's operands —
+    ``sampler`` is ``(seeds, counters, temperature, top_k, top_p)``, or
+    empty for a step that samples nothing."""
+    rows: list
+    width: int
+    tokens: "_np.ndarray"
+    positions: "_np.ndarray"
+    lengths: "_np.ndarray"
+    tables: "_np.ndarray"
+    sampler: tuple
+
+    @property
+    def operands(self) -> tuple:
+        return (self.tokens, self.positions, self.lengths, self.tables,
+                *self.sampler)
 
 
 class GenerationStream:
@@ -954,10 +975,12 @@ class GenerationService:
         ``observability.mark_warm()`` — with ``TPUMX_FREEZE_COMPILES=1``
         any later compile-cache miss raises instead of stalling the loop.
         Returns the number of programs compiled by this call."""
-        cfg = self._config
         before = self._programs.compiled_signatures()
-        S = cfg.max_slots
-        zeros_s = _np.zeros(S, _np.int32)
+        # every program's zero operands: an empty batch through the step
+        # builder — all rows length 0, so warmup writes only to the null
+        # block
+        zeros = lambda T, w, **kw: self._build_step(  # noqa: E731
+            (), T, width=w, **kw)
         with _obs.span("serving.warmup", cat="serving"):
             sigs, widths = self._prefill_signatures(), self._width_buckets
             if self._block_len:
@@ -966,53 +989,31 @@ class GenerationService:
             # every (T, W) pair the chunk planner can emit — the plain
             # per-rung ladder when chunked prefill is off
             for tb, wp in sigs:
-                self._programs.run(
-                    "gen_prefill", self._cache,
-                    _np.zeros((1, tb), _np.int32),
-                    _np.zeros((1, tb), _np.int32), _np.zeros(1, _np.int32),
-                    _np.zeros((1, wp), _np.int32),
-                    _np.zeros(1, _np.uint32), _np.zeros(1, _np.uint32),
-                    _np.zeros(1, _np.float32), _np.zeros(1, _np.int32),
-                    _np.ones(1, _np.float32))
+                self._programs.run("gen_prefill", self._cache,
+                                   *zeros(tb, wp, slots=1).operands)
             for w in widths:
-                self._programs.run(
-                    "gen_decode", self._cache,
-                    _np.zeros((S, 1), _np.int32),
-                    _np.zeros((S, 1), _np.int32), zeros_s,
-                    _np.zeros((S, w), _np.int32),
-                    zeros_s.astype(_np.uint32), zeros_s.astype(_np.uint32),
-                    zeros_s.astype(_np.float32), zeros_s,
-                    _np.ones(S, _np.float32))
+                self._programs.run("gen_decode", self._cache,
+                                   *zeros(1, w).operands)
             # speculative verify (docs/generation.md "Speculative
-            # decoding"): every (Tk, W) pair on the ladders — all rows
-            # length 0, so warmup writes only to the null block
+            # decoding"): every (Tk, W) pair on the ladders
             for tk in self._verify_buckets:
                 for w in self._width_buckets:
-                    self._programs.run_verify(
-                        self._cache,
-                        _np.zeros((S, tk), _np.int32),
-                        _np.zeros((S, tk), _np.int32), zeros_s,
-                        _np.zeros((S, w), _np.int32),
-                        zeros_s.astype(_np.uint32),
-                        zeros_s.astype(_np.uint32),
-                        zeros_s.astype(_np.float32), zeros_s,
-                        _np.ones(S, _np.float32))
+                    self._programs.run_verify(self._cache,
+                                              *zeros(tk, w).operands)
             # multistep scan: one program per (k, W)
             for k in self._ms_buckets:
                 for w in self._width_buckets:
+                    z = zeros(1, w)
                     self._programs.run_multistep(
-                        k, self._cache, zeros_s, zeros_s, zeros_s,
-                        _np.zeros((S, w), _np.int32),
-                        zeros_s.astype(_np.uint32),
-                        zeros_s.astype(_np.uint32),
-                        zeros_s.astype(_np.float32), zeros_s,
-                        _np.ones(S, _np.float32))
+                        k, self._cache, z.tokens[:, 0], z.positions[:, 0],
+                        *z.operands[2:])
             if self._draft is not None:
                 # the draft proposer is ONE (S, window, k) program
+                S = self._config.max_slots
                 self._draft.propose(
                     _np.zeros((S, self._draft.window), _np.int32),
                     _np.zeros((S, self._draft.window), _np.int32),
-                    zeros_s)
+                    _np.zeros(S, _np.int32))
             if self._prefix is not None:
                 # the CoW block copy is part of the steady-state set;
                 # copying the reserved null block onto itself warms it
@@ -1025,18 +1026,14 @@ class GenerationService:
         """A block-diffusion model's program set: the cache-filling
         prefill per (T, W), the block step per table width."""
         S, L = self._config.max_slots, self._block_len
-        zeros_s = _np.zeros(S, _np.int32)
         for tb, wp in sigs:
-            self._programs.run_fill(
-                self._cache, _np.zeros((1, tb), _np.int32),
-                _np.zeros((1, tb), _np.int32), _np.zeros(1, _np.int32),
-                _np.zeros((1, wp), _np.int32))
+            self._programs.run_fill(self._cache, *self._build_step(
+                (), tb, sampler=False, slots=1, width=wp).operands)
         for w in widths:
             self._programs.run_block(
-                self._cache, _np.zeros((S, L), _np.int32),
-                _np.zeros((S, L), _np.int32), zeros_s,
-                _np.zeros((S, w), _np.int32), _np.zeros((S, L), bool),
-                zeros_s)
+                self._cache,
+                *self._build_step((), L, sampler=False, width=w).operands,
+                _np.zeros((S, L), bool), _np.zeros(S, _np.int32))
 
     def stop(self, drain: bool = True, timeout: Optional[float] = None,
              reject_queued: bool = False) -> None:
@@ -1874,81 +1871,123 @@ class GenerationService:
             return
         self._single_step(batch)
 
-    def _single_step(self, batch: List[_GenRequest]) -> None:
-        """The classic one-token decode program (T=1, one sampled token
-        per running row)."""
+    def _build_step(self, batch: Sequence[_GenRequest], T: int, feed=None,
+                    writes: Optional[int] = None, sampler: bool = True,
+                    slots: Optional[int] = None,
+                    width: Optional[int] = None) -> _StepInputs:
+        """The host side of one model step, written once for every step
+        kind.  The slots are walked ONCE: a row is a slot whose running
+        request is in ``batch`` (slots outside it stay inactive: length
+        0, null-block table).  A row feeds ``feed(request)`` — up to ``T``
+        token ids — at positions ``ctx_len ..`` and will write ``writes``
+        positions there (default: as many as it feeds); before that write
+        its span is made private, then its ``tokens (S, T)``,
+        ``positions``, ``lengths`` and, with ``sampler``, its seed, the
+        index of the first token it produces (the counter) and its
+        sampling knobs are filled.  The widest table needed is bucketed on
+        the pow2 ladder and ``tables`` filled.  Last, BEFORE any dispatch
+        (the paged pool is never half-written), the deterministic failure
+        injection ``TPUMX_FAULT_GEN_STEP_FAIL``.
+
+        An empty batch gives warm-up its zero operands: ``slots`` rows
+        (prefill programs run at 1) at table width ``width``."""
         cfg = self._config
-        S = cfg.max_slots
-        with self._phase("build", "serving.decode.build"):
-            # copy-on-write append: a slot about to scatter into a shared
-            # block (refcount > 1) gets a private copy first — shared prompt
-            # history is read-only to every writer (idempotent, so bisection
-            # re-entry is safe)
-            if self._prefix is not None:
-                for r in batch:
-                    if r.state == _RUNNING:
-                        self._cow_for_write(r, r.ctx_len, 1)
-            rids = {r.rid for r in batch}
-            tokens = _np.zeros((S, 1), _np.int32)
-            positions = _np.zeros((S, 1), _np.int32)
-            lengths = _np.zeros(S, _np.int32)
-            seeds = _np.zeros(S, _np.uint32)
-            counters = _np.zeros(S, _np.uint32)
-            temperature = _np.zeros(S, _np.float32)
-            top_k = _np.zeros(S, _np.int32)
-            top_p = _np.ones(S, _np.float32)
-            max_w = 1
-            for i, r in enumerate(self._slots):
-                if r is None or r.state != _RUNNING or r.rid not in rids:
-                    continue
-                tokens[i, 0] = r.seq_tokens[r.ctx_len]
-                positions[i, 0] = r.ctx_len
-                lengths[i] = 1
+        S = slots or cfg.max_slots
+        rids = {r.rid for r in batch if r.state == _RUNNING}
+        # the token rows are laid out in one flat Python list and the
+        # positions in one array expression, each converted once: a NumPy
+        # assignment a row costs more than the row
+        flat = [0] * (S * T)
+        ctx = _np.zeros(S, _np.int32)
+        lengths = _np.zeros(S, _np.int32)
+        knobs = ()
+        if sampler:
+            seeds, counters, temperature, top_k, top_p = knobs = (
+                _np.zeros(S, _np.uint32), _np.zeros(S, _np.uint32),
+                _np.zeros(S, _np.float32), _np.zeros(S, _np.int32),
+                _np.ones(S, _np.float32))
+        rows, end = [], 0      # end: the last position any row writes, +1
+        for i, r in enumerate(self._slots):
+            if r is None or r.state != _RUNNING or r.rid not in rids:
+                continue
+            fed = feed(r)
+            n, c = len(fed), r.ctx_len
+            span = writes or n
+            # copy-on-write append: a row about to scatter into a shared
+            # block (refcount > 1) gets a private copy first — shared
+            # prompt history is read-only to every writer (idempotent, so
+            # bisection re-entry is safe).  Over the WHOLE span: a verify
+            # step's REJECTED writes land at positions >= ctx_len too,
+            # and must never touch a shared block — this is the rollback
+            # guarantee (shared prefix blocks are physically unreachable
+            # from a speculative scatter)
+            self._cow_for_write(r, c, span)
+            rows.append((i, r))
+            flat[i * T:i * T + n] = fed
+            ctx[i] = c
+            lengths[i] = n
+            if sampler:
                 seeds[i] = r.seed
-                counters[i] = r.ctx_len + 1  # index of the token produced
+                counters[i] = c + 1
                 temperature[i] = r.temperature
                 top_k[i] = r.top_k
                 top_p[i] = r.top_p
-                max_w = max(max_w, blocks_for(r.ctx_len + 1, cfg.block_size))
-            w = bucket_batch(max_w, self._width_buckets)
-            tables = _np.zeros((S, w), _np.int32)
-            for i, r in enumerate(self._slots):
-                if r is None or r.state != _RUNNING or r.rid not in rids:
-                    continue
-                n = min(w, len(r.blocks))
-                tables[i, :n] = r.blocks[:n]
-        # deterministic failure injection (TPUMX_FAULT_GEN_STEP_FAIL):
-        # fires BEFORE dispatch, so the paged pool is never half-written
-        if _fault_injector().gen_step_fail(rids):
+            end = max(end, c + span)
+        tokens = _np.array(flat, _np.int32).reshape(S, T)
+        # a row's tokens sit at ctx_len .. ctx_len + T - 1; the other rows
+        # stay at 0
+        positions = _np.where(lengths[:, None] > 0,
+                              ctx[:, None] + _np.arange(T, dtype=_np.int32),
+                              0)
+        w = width or bucket_batch(blocks_for(end, cfg.block_size),
+                                  self._width_buckets)
+        tables = _np.zeros((S, w), _np.int32)
+        for i, r in rows:
+            blocks = r.blocks[:w]
+            tables[i, :len(blocks)] = blocks
+        # warm-up's empty batch is no step: it does not advance the
+        # injector's count of invocations
+        if batch and _fault_injector().gen_step_fail(rids):
             from ...fault.inject import FaultInjectedError
             raise FaultInjectedError(
                 f"injected decode-step failure "
                 f"(TPUMX_FAULT_GEN_STEP_FAIL) at iteration "
                 f"{self._iteration}, batch rids {sorted(rids)}")
+        return _StepInputs(rows, int(w), tokens, positions, lengths, tables,
+                           knobs)
+
+    def _participated(self, r: _GenRequest, t0: float, t1: float,
+                      running: int, **mode) -> None:
+        """Orca attribution: the ONE shared decode step fans out a child
+        participation span per active request, so each trace still shows
+        every step that advanced it."""
+        if r.trace is not None:
+            _trace.record_event(
+                "serving.decode.participate", "serving", t0, t1,
+                ctx=r.trace,
+                args={"rid": r.rid, "iteration": self._iteration,
+                      "running": running, **mode,
+                      "replica": self._replica_id})
+
+    def _single_step(self, batch: List[_GenRequest]) -> None:
+        """The classic one-token decode program (T=1, one sampled token
+        per running row)."""
+        with self._phase("build", "serving.decode.build"):
+            b = self._build_step(batch, 1,
+                                 lambda r: [r.seq_tokens[r.ctx_len]])
         t_step0 = time.perf_counter()
         with self._phase("step", "serving.decode",
-                         args={"running": len(batch), "width": int(w),
+                         args={"running": len(batch), "width": b.width,
                                "iteration": self._iteration}):
-            next_tok, _ = self._programs.run(
-                "gen_decode", self._cache, tokens, positions, lengths,
-                tables, seeds, counters, temperature, top_k, top_p)
+            next_tok, _ = self._programs.run("gen_decode", self._cache,
+                                             *b.operands)
         t_step1 = time.perf_counter()
         with self._phase("emit", "serving.emit"):
             traced = _trace.enabled()
-            for i, r in enumerate(self._slots):
-                if r is None or r.state != _RUNNING or r.rid not in rids:
-                    continue
-                # Orca attribution: the ONE shared decode step fans out a
-                # child participation span per active request, so each trace
-                # still shows every step that advanced it
+            for i, r in b.rows:
                 r.decode_steps += 1
-                if traced and r.trace is not None:
-                    _trace.record_event(
-                        "serving.decode.participate", "serving", t_step0,
-                        t_step1, ctx=r.trace,
-                        args={"rid": r.rid, "iteration": self._iteration,
-                              "running": len(batch),
-                              "replica": self._replica_id})
+                if traced:
+                    self._participated(r, t_step0, t_step1, len(batch))
                 r.ctx_len += 1
                 r.mode_tokens["single"] = r.mode_tokens.get("single", 0) + 1
                 self._emit_token(r, int(next_tok[i]))
@@ -2017,75 +2056,27 @@ class GenerationService:
         the leading run of target-matching tokens (plus the bonus token).
         Rows with no drafts ride along at chunk length 1 — for them this
         IS the single-token step."""
-        cfg = self._config
-        S = cfg.max_slots
-        rids = {r.rid for r in batch if r.state == _RUNNING}
         smax = max((len(drafts.get(r.rid, ())) for r in batch
                     if r.state == _RUNNING), default=0)
         tk = bucket_batch(smax + 1, self._verify_buckets)
         with self._phase("build", "serving.decode.build"):
-            # copy-on-write over the whole verify span: REJECTED writes land
-            # at positions >= ctx_len too, and must never touch a shared
-            # block — this is the rollback guarantee (shared prefix blocks
-            # are physically unreachable from a speculative scatter)
-            if self._prefix is not None:
-                for r in batch:
-                    if r.state == _RUNNING:
-                        self._cow_for_write(
-                            r, r.ctx_len, len(drafts.get(r.rid, ())) + 1)
-            tokens = _np.zeros((S, tk), _np.int32)
-            positions = _np.zeros((S, tk), _np.int32)
-            lengths = _np.zeros(S, _np.int32)
-            seeds = _np.zeros(S, _np.uint32)
-            counters = _np.zeros(S, _np.uint32)
-            temperature = _np.zeros(S, _np.float32)
-            top_k = _np.zeros(S, _np.int32)
-            top_p = _np.ones(S, _np.float32)
-            max_w = 1
-            for i, r in enumerate(self._slots):
-                if r is None or r.state != _RUNNING or r.rid not in rids:
-                    continue
-                fed = [r.seq_tokens[r.ctx_len]] + drafts.get(r.rid, [])
-                tokens[i, :len(fed)] = fed
-                positions[i] = r.ctx_len + _np.arange(tk, dtype=_np.int32)
-                lengths[i] = len(fed)
-                seeds[i] = r.seed
-                counters[i] = r.ctx_len + 1  # first produced-token index
-                temperature[i] = r.temperature
-                top_k[i] = r.top_k
-                top_p[i] = r.top_p
-                max_w = max(max_w, blocks_for(r.ctx_len + len(fed),
-                                              cfg.block_size))
-            w = bucket_batch(max_w, self._width_buckets)
-            tables = _np.zeros((S, w), _np.int32)
-            for i, r in enumerate(self._slots):
-                if r is None or r.state != _RUNNING or r.rid not in rids:
-                    continue
-                n = min(w, len(r.blocks))
-                tables[i, :n] = r.blocks[:n]
-        if _fault_injector().gen_step_fail(rids):
-            from ...fault.inject import FaultInjectedError
-            raise FaultInjectedError(
-                f"injected decode-step failure "
-                f"(TPUMX_FAULT_GEN_STEP_FAIL) at iteration "
-                f"{self._iteration}, batch rids {sorted(rids)}")
+            b = self._build_step(
+                batch, tk,
+                lambda r: [r.seq_tokens[r.ctx_len]] + drafts.get(r.rid, []))
         t_step0 = time.perf_counter()
         with self._phase("step", "serving.spec_verify",
-                         args={"running": len(batch), "width": int(w),
+                         args={"running": len(batch), "width": b.width,
                                "chunk": int(tk),
                                "iteration": self._iteration}):
-            target, accepted = self._programs.run_verify(
-                self._cache, tokens, positions, lengths, tables, seeds,
-                counters, temperature, top_k, top_p)
+            target, accepted = self._programs.run_verify(self._cache,
+                                                         *b.operands)
         t_step1 = time.perf_counter()
         with self._phase("emit", "serving.emit"):
             traced = _trace.enabled()
-            bs = cfg.block_size
+            bs = self._config.block_size
             quantized = self._cache.quantized
-            for i, r in enumerate(self._slots):
-                if r is None or r.state != _RUNNING or r.rid not in rids:
-                    continue
-                s_i = int(lengths[i]) - 1  # drafts fed for this row
+            for i, r in b.rows:
+                s_i = int(b.lengths[i]) - 1  # drafts fed for this row
                 n_emit = int(accepted[i]) + 1
                 r.decode_steps += 1
                 emitted = self._emit_many(
@@ -2108,14 +2099,10 @@ class GenerationService:
                     safe = (r.ctx_len // bs) * bs
                     r.index_safe_len = (safe if r.index_safe_len is None
                                         else min(r.index_safe_len, safe))
-                if traced and r.trace is not None:
-                    _trace.record_event(
-                        "serving.decode.participate", "serving", t_step0,
-                        t_step1, ctx=r.trace,
-                        args={"rid": r.rid, "iteration": self._iteration,
-                              "running": len(batch), "mode": "spec",
-                              "proposed": s_i, "accepted": acc,
-                              "replica": self._replica_id})
+                if traced:
+                    self._participated(r, t_step0, t_step1, len(batch),
+                                       mode="spec", proposed=s_i,
+                                       accepted=acc)
         self._counts["spec_steps"] += 1
 
     # -- generation by diffusion over blocks (docs/generation.md) -----------------
@@ -2192,49 +2179,23 @@ class GenerationService:
         rows in different passes share one batch — after which the block's
         K/V are those of its finished tokens: they are emitted at once,
         ``ctx_len`` moves a block on, a fresh block opens."""
-        cfg = self._config
-        S, L = cfg.max_slots, self._block_len
+        S, L = self._config.max_slots, self._block_len
         schedule = self._model.unmask_schedule
-        rids = {r.rid for r in batch if r.state == _RUNNING}
         with self._phase("build", "serving.block.build"):
-            tokens = _np.zeros((S, L), _np.int32)
-            positions = _np.zeros((S, L), _np.int32)
-            lengths = _np.zeros(S, _np.int32)
+            b = self._build_step(batch, L, lambda r: r.block, sampler=False)
+            rows = b.rows
             masked = _np.zeros((S, L), bool)
             n_unmask = _np.zeros(S, _np.int32)
-            rows = []
-            max_w = 1
-            for i, r in enumerate(self._slots):
-                if r is None or r.state != _RUNNING or r.rid not in rids:
-                    continue
-                if self._prefix is not None:
-                    self._cow_for_write(r, r.ctx_len, L)
-                rows.append((i, r))
-                tokens[i] = r.block
-                positions[i] = r.ctx_len + _np.arange(L, dtype=_np.int32)
-                lengths[i] = L
+            for i, r in rows:
                 masked[i] = r.block_masked
                 if any(r.block_masked):
                     n_unmask[i] = schedule[min(r.block_pass,
                                                len(schedule) - 1)]
-                max_w = max(max_w, blocks_for(r.ctx_len + L, cfg.block_size))
-            w = bucket_batch(max_w, self._width_buckets)
-            tables = _np.zeros((S, w), _np.int32)
-            for i, r in rows:
-                n = min(w, len(r.blocks))
-                tables[i, :n] = r.blocks[:n]
-        if _fault_injector().gen_step_fail(rids):
-            from ...fault.inject import FaultInjectedError
-            raise FaultInjectedError(
-                f"injected decode-step failure "
-                f"(TPUMX_FAULT_GEN_STEP_FAIL) at iteration "
-                f"{self._iteration}, batch rids {sorted(rids)}")
         with self._phase("step", "serving.block_step",
-                         args={"running": len(rows), "width": int(w),
+                         args={"running": len(rows), "width": b.width,
                                "iteration": self._iteration}):
             unmasked, touched, _ = self._programs.run_block(
-                self._cache, tokens, positions, lengths, tables, masked,
-                n_unmask)
+                self._cache, *b.operands, masked, n_unmask)
         with self._phase("emit", "serving.emit"):
             counts = self._counts
             for i, r in rows:
@@ -2251,7 +2212,7 @@ class GenerationService:
                 r.block_pass += 1
             counts["block_passes"] += 1
             counts["block_row_passes"] += len(rows)
-            counts["block_ctx_tokens"] += int(positions[:, -1].sum()) \
+            counts["block_ctx_tokens"] += int(b.positions[:, -1].sum()) \
                 + len(rows)
             counts["block_experts_touched"] += int(touched)
 
@@ -2311,73 +2272,30 @@ class GenerationService:
         same per-iteration math as :meth:`_single_step` (tokens and int8
         write pattern bit-identical), with k-1 host↔device round trips
         amortized away."""
-        cfg = self._config
-        S = cfg.max_slots
-        rids = {r.rid for r in batch if r.state == _RUNNING}
         with self._phase("build", "serving.decode.build"):
-            if self._prefix is not None:
-                for r in batch:
-                    if r.state == _RUNNING:
-                        self._cow_for_write(r, r.ctx_len, k)
-            tokens = _np.zeros(S, _np.int32)
-            positions = _np.zeros(S, _np.int32)
-            lengths = _np.zeros(S, _np.int32)
-            seeds = _np.zeros(S, _np.uint32)
-            counters = _np.zeros(S, _np.uint32)
-            temperature = _np.zeros(S, _np.float32)
-            top_k = _np.zeros(S, _np.int32)
-            top_p = _np.ones(S, _np.float32)
-            max_w = 1
-            for i, r in enumerate(self._slots):
-                if r is None or r.state != _RUNNING or r.rid not in rids:
-                    continue
-                tokens[i] = r.seq_tokens[r.ctx_len]
-                positions[i] = r.ctx_len
-                lengths[i] = 1
-                seeds[i] = r.seed
-                counters[i] = r.ctx_len + 1
-                temperature[i] = r.temperature
-                top_k[i] = r.top_k
-                top_p[i] = r.top_p
-                max_w = max(max_w, blocks_for(r.ctx_len + k, cfg.block_size))
-            w = bucket_batch(max_w, self._width_buckets)
-            tables = _np.zeros((S, w), _np.int32)
-            for i, r in enumerate(self._slots):
-                if r is None or r.state != _RUNNING or r.rid not in rids:
-                    continue
-                n = min(w, len(r.blocks))
-                tables[i, :n] = r.blocks[:n]
-        if _fault_injector().gen_step_fail(rids):
-            from ...fault.inject import FaultInjectedError
-            raise FaultInjectedError(
-                f"injected decode-step failure "
-                f"(TPUMX_FAULT_GEN_STEP_FAIL) at iteration "
-                f"{self._iteration}, batch rids {sorted(rids)}")
+            b = self._build_step(batch, 1,
+                                 lambda r: [r.seq_tokens[r.ctx_len]],
+                                 writes=k)
         t_step0 = time.perf_counter()
         with self._phase("step", "serving.multistep",
-                         args={"running": len(batch), "width": int(w),
+                         args={"running": len(batch), "width": b.width,
                                "k": int(k),
                                "iteration": self._iteration}):
+            # the scan carries (S,) vectors: the FIRST iteration's values
             toks = self._programs.run_multistep(
-                k, self._cache, tokens, positions, lengths, tables,
-                seeds, counters, temperature, top_k, top_p)
+                k, self._cache, b.tokens[:, 0], b.positions[:, 0],
+                *b.operands[2:])
         t_step1 = time.perf_counter()
         with self._phase("emit", "serving.emit"):
             traced = _trace.enabled()
-            for i, r in enumerate(self._slots):
-                if r is None or r.state != _RUNNING or r.rid not in rids:
-                    continue
+            for i, r in b.rows:
                 r.decode_steps += 1
                 emitted = self._emit_many(r, [int(t) for t in toks[i]])
                 r.mode_tokens["multistep"] = \
                     r.mode_tokens.get("multistep", 0) + emitted
-                if traced and r.trace is not None:
-                    _trace.record_event(
-                        "serving.decode.participate", "serving", t_step0,
-                        t_step1, ctx=r.trace,
-                        args={"rid": r.rid, "iteration": self._iteration,
-                              "running": len(batch), "mode": "multistep",
-                              "k": int(k), "replica": self._replica_id})
+                if traced:
+                    self._participated(r, t_step0, t_step1, len(batch),
+                                       mode="multistep", k=int(k))
         self._counts["multistep_steps"] += 1
 
     # -- failure isolation (docs/fault_tolerance.md serving rows) -----------------

@@ -192,10 +192,11 @@ class PagedKVCache:
     """The device-side pool: K/V arrays plus the allocator that parcels
     their blocks out to requests.
 
-    The arrays are owned functionally: the engine threads them through its
-    donated compiled programs and stores the returned (aliased) arrays
-    back via :meth:`swap` — the pool is updated in place on device, and
-    this object always points at the live copy.
+    The arrays are owned functionally, as one tuple ``pools``: the engine
+    threads it through its donated compiled programs and stores the
+    returned (aliased) arrays back via :meth:`swap` — the pool is updated
+    in place on device, and this object always points at the live copy
+    (``k``, ``v``, ``k_scale``, ``v_scale`` read its members).
 
     ``kv_dtype="int8"`` (docs/quantization.md) stores the pool QUANTIZED:
     K/V become int8 with symmetric per-``(layer, block, head)`` scales in
@@ -225,23 +226,37 @@ class PagedKVCache:
         shape = (int(n_layers), self.num_blocks, self.block_size,
                  int(n_heads) * int(d_head))
         store = jnp.dtype(jnp.int8) if kv_dtype == "int8" else self.dtype
-        self.k = jnp.zeros(shape, store)
-        self.v = jnp.zeros(shape, store)
+        # the device arrays as the ONE operand every program takes, donates
+        # and returns: (k, v), or (k, v, k_scale, v_scale) for the int8 pool
+        self.pools = (jnp.zeros(shape, store), jnp.zeros(shape, store))
         if kv_dtype == "int8":
             sshape = (int(n_layers), self.num_blocks, int(n_heads))
             # unwritten blocks carry scale 1: their (masked-out-of-
             # attention) garbage dequantizes to bounded values and the
             # first real write recomputes the scale from scratch
-            self.k_scale = jnp.ones(sshape, jnp.float32)
-            self.v_scale = jnp.ones(sshape, jnp.float32)
-        else:
-            self.k_scale = None
-            self.v_scale = None
+            self.pools += (jnp.ones(sshape, jnp.float32),
+                           jnp.ones(sshape, jnp.float32))
         self.allocator = BlockAllocator(self.num_blocks)
 
     @property
     def quantized(self) -> bool:
         return self.kv_dtype == "int8"
+
+    @property
+    def k(self):
+        return self.pools[0]
+
+    @property
+    def v(self):
+        return self.pools[1]
+
+    @property
+    def k_scale(self):
+        return self.pools[2] if self.quantized else None
+
+    @property
+    def v_scale(self):
+        return self.pools[3] if self.quantized else None
 
     @property
     def shape(self):
@@ -254,14 +269,9 @@ class PagedKVCache:
         """Positions one request could address if it owned every block."""
         return (self.num_blocks - 1) * self.block_size
 
-    def swap(self, k, v, k_scale=None, v_scale=None) -> None:
+    def swap(self, pools) -> None:
         """Adopt the pool arrays returned by a donated program call."""
-        self.k = k
-        self.v = v
-        if k_scale is not None:
-            self.k_scale = k_scale
-        if v_scale is not None:
-            self.v_scale = v_scale
+        self.pools = tuple(pools)
 
     def snapshot_blocks(self, blocks: List[int]) -> Dict[str, "object"]:
         """Device-bit snapshot of the given physical blocks (K, V and —
@@ -282,10 +292,7 @@ class PagedKVCache:
         return out
 
     def nbytes(self) -> int:
-        n = int(self.k.nbytes) + int(self.v.nbytes)
-        if self.k_scale is not None:
-            n += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
-        return n
+        return sum(int(p.nbytes) for p in self.pools)
 
     @staticmethod
     def bytes_per_block(n_layers: int, n_heads: int, d_head: int,

@@ -1,28 +1,37 @@
 """The generation engine's compiled model programs.
 
-ONE traced step function serves both phases — prefill (B=1, T=seq-bucket)
-and decode (B=max_slots, T=1) — built from the MODEL's cache-aware step
-plus the per-row sampling kernel from :mod:`mxnet_tpu.ops.sampling`.  A
-model (:func:`as_model`) is an object with ``step(params, tokens,
-positions, lengths, k_pool, v_pool, block_tables, *, attention_kernel,
-...) -> (logits, k_pool, v_pool, ...)``, its ``vocab``, ``max_len``,
-``heads``, ``cache_spec()`` (what :class:`PagedKVCache` is built from),
-``block_len`` (0: one token a row a step) and ``offers`` (the program
-families it can run).  :class:`~mxnet_tpu.parallel.transformer
-.TransformerLM` (GPT-2's block, ``transformer_lm_decode``) is the first,
+A program kind is ONE traced function, whatever the pool: the cache's
+device arrays travel as one operand, ``pools`` — ``(k, v)``, or ``(k, v,
+k_scale, v_scale)`` for the int8 pool (docs/quantization.md) — that every
+kind takes after the parameters, donates, and returns last, so the decode
+loop updates the cache in place on device instead of copying
+``O(num_blocks)`` memory every token.  The kinds: ``gen_prefill`` (B=1,
+T=seq-bucket) and ``gen_decode`` (B=max_slots, T=1) are the same
+:func:`_model_step`, the MODEL's cache-aware step plus the per-row
+sampling kernel from :mod:`mxnet_tpu.ops.sampling`; ``gen_verify``,
+``gen_multistep``; a block-diffusion model's ``gen_prefill``
+(:func:`_block_fill`) and ``gen_block``; and ``gen_block_copy``.
+
+A model (:func:`as_model`) is an object with ``step(params, tokens,
+positions, lengths, pools, block_tables, *, attention_kernel,
+mp_mesh=None, call=None, want_logits=True) -> (logits, pools, aux)`` —
+``pools`` the structure that came in, ``aux`` whatever else the program
+must hand back — its ``vocab``, ``max_len``, ``heads``, ``cache_spec()``
+(what :class:`PagedKVCache` is built from), ``block_len`` (0: one token a
+row a step) and ``offers`` (the program families it can run).
+:class:`~mxnet_tpu.parallel.transformer.TransformerLM` (GPT-2's block,
+``transformer_lm_decode``) is the first,
 :class:`~mxnet_tpu.parallel.sdar_moe.SdarMoeLM` (grouped-KV rotary block,
-sparse experts, generation by diffusion over blocks: ``gen_block``) the
-second.  Each distinct
-``(kind, batch, chunk, table-width)`` signature compiles exactly once;
-every lookup is fed through ``executor._note_cache`` so these programs
+sparse experts, generation by diffusion over blocks) the second.
+
+Each distinct ``(kind, batch, chunk, table-width)`` signature compiles
+exactly once.  Every kind runs through :meth:`GenerationPrograms._run`,
+whose lookup is fed through ``executor._note_cache`` so these programs
 appear in :func:`mxnet_tpu.executor.compile_cache_stats` (sites
-``gen_prefill`` / ``gen_decode``), are explained by
+``gen_prefill`` / ``gen_decode`` / ...), are explained by
 ``TPUMX_EXPLAIN_RECOMPILES=1``, and are *refused* post-warmup under
 ``TPUMX_FREEZE_COMPILES=1`` — the same zero-recompile discipline as the
 fused train step and the bucketed serving cache.
-
-KV pools are donated: the decode loop updates the cache in place on device
-instead of copying ``O(num_blocks)`` memory every token.
 
 Preemption (docs/generation.md "incremental allocation + victim
 preemption") adds NO program shapes to this family: a preempted request's
@@ -60,18 +69,16 @@ def as_model(model, compute_dtype=None):
                     f"or an object with step / cache_spec / vocab / max_len)")
 
 
-def _step_args(tokens, positions, lengths, block_tables, seeds, counters,
-               temperature, top_k, top_p):
-    """A step program's host arguments in their dtypes."""
-    return (_np.asarray(tokens, _np.int32),
-            _np.asarray(positions, _np.int32),
-            _np.asarray(lengths, _np.int32),
-            _np.asarray(block_tables, _np.int32),
-            _np.asarray(seeds, _np.uint32),
-            _np.asarray(counters, _np.uint32),
-            _np.asarray(temperature, _np.float32),
-            _np.asarray(top_k, _np.int32),
-            _np.asarray(top_p, _np.float32))
+_SAMPLER_DTYPES = (_np.uint32, _np.uint32, _np.float32, _np.int32,
+                   _np.float32)
+
+
+def _step_args(tokens, positions, lengths, block_tables, *sampler):
+    """A step program's host arguments in their dtypes; ``sampler`` is
+    ``(seeds, counters, temperature, top_k, top_p)`` or nothing."""
+    return tuple(_np.asarray(a, _np.int32) for a in
+                 (tokens, positions, lengths, block_tables)) + tuple(
+        _np.asarray(a, dt) for a, dt in zip(sampler, _SAMPLER_DTYPES))
 
 
 def _synced(*outs):
@@ -83,16 +90,16 @@ def _synced(*outs):
     return arrays[0] if len(arrays) == 1 else arrays
 
 
-def block_copy_pools(k_pool, v_pool, src, dst, k_scale=None, v_scale=None):
+def block_copy_pools(pools, src, dst):
     """Copy physical block ``src`` onto ``dst`` across every layer of the
     paged pool — the copy-on-write primitive of prefix caching
     (docs/generation.md): a writer whose tail block is shared gets a
     private copy BEFORE its first scatter, so shared prompt history is
     never mutated.  ``src``/``dst``: shape-(1,) int32.  For the int8 pool
-    the per-(layer, block, head) scales ride along — a block's bits are
-    only meaningful with its scales, so they copy as one unit.  Returns
-    ``(k_pool, v_pool)`` or ``(k_pool, v_pool, k_scale, v_scale)``;
-    called with donation the copy happens in place on device."""
+    the per-(layer, block, head) scales ride along in ``pools`` — a
+    block's bits are only meaningful with its scales, so they copy as one
+    unit.  Returns ``pools``; called with donation the copy happens in
+    place on device."""
     import jax
     import jax.numpy as jnp
 
@@ -103,21 +110,18 @@ def block_copy_pools(k_pool, v_pool, src, dst, k_scale=None, v_scale=None):
         blk = jax.lax.dynamic_slice_in_dim(pool, s, 1, axis=1)
         return jax.lax.dynamic_update_slice_in_dim(pool, blk, d, axis=1)
 
-    k_pool, v_pool = cp(k_pool), cp(v_pool)
-    if k_scale is not None:
-        return k_pool, v_pool, cp(k_scale), cp(v_scale)
-    return k_pool, v_pool
+    return tuple(cp(pool) for pool in pools)
 
 
-def _model_step(params, k_pool, v_pool, tokens, positions, lengths,
-                block_tables, seeds, counters, temperature, top_k, top_p,
-                *, model, attention_kernel="gather", mp_mesh=None):
+def _model_step(params, pools, tokens, positions, lengths, block_tables,
+                seeds, counters, temperature, top_k, top_p, *, model,
+                attention_kernel="gather", mp_mesh=None):
     import jax.numpy as jnp
 
     from ...ops.sampling import sample_logits
 
-    logits, k_pool, v_pool = model.step(
-        params, tokens, positions, lengths, k_pool, v_pool, block_tables,
+    logits, pools, _ = model.step(
+        params, tokens, positions, lengths, pools, block_tables,
         attention_kernel=attention_kernel, mp_mesh=mp_mesh)
     # logits at the LAST VALID position of each row feed the sampler
     # (prefill: position len-1 predicts token len; decode: T=1 row 0)
@@ -127,37 +131,12 @@ def _model_step(params, k_pool, v_pool, tokens, positions, lengths,
                                axis=1)[:, 0, :]
     next_tokens = sample_logits(last, seeds, counters, temperature,
                                 top_k, top_p)
-    return next_tokens, last, k_pool, v_pool
+    return next_tokens, last, pools
 
 
-def _model_step_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
-                  positions, lengths, block_tables, seeds, counters,
-                  temperature, top_k, top_p, *, model,
-                  attention_kernel="gather", mp_mesh=None):
-    """The int8-KV variant of :func:`_model_step` (docs/quantization.md):
-    the per-(layer, block, head) scale arrays ride as two extra DONATED
-    pool operands — a separate traced function so the unquantized
-    program layout stays byte-identical when ``kv_dtype`` is off."""
-    import jax.numpy as jnp
-
-    from ...ops.sampling import sample_logits
-
-    logits, k_pool, v_pool, k_scale, v_scale = model.step(
-        params, tokens, positions, lengths, k_pool, v_pool, block_tables,
-        attention_kernel=attention_kernel, mp_mesh=mp_mesh,
-        k_scale=k_scale, v_scale=v_scale)
-    last_idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0,
-                        tokens.shape[1] - 1)
-    last = jnp.take_along_axis(logits, last_idx[:, None, None],
-                               axis=1)[:, 0, :]
-    next_tokens = sample_logits(last, seeds, counters, temperature,
-                                top_k, top_p)
-    return next_tokens, last, k_pool, v_pool, k_scale, v_scale
-
-
-def _verify_step(params, k_pool, v_pool, tokens, positions, lengths,
-                 block_tables, seeds, counters, temperature, top_k, top_p,
-                 *, model, attention_kernel="gather", mp_mesh=None):
+def _verify_step(params, pools, tokens, positions, lengths, block_tables,
+                 seeds, counters, temperature, top_k, top_p, *, model,
+                 attention_kernel="gather", mp_mesh=None):
     """Speculative verify (docs/generation.md "Speculative decoding"):
     ONE cache-aware multi-query step over ``[pending, d_1..d_s]`` per row
     — the same chunked-prefill path as :func:`_model_step`, but ALL valid
@@ -166,42 +145,27 @@ def _verify_step(params, k_pool, v_pool, tokens, positions, lengths,
     leading accepted-draft count per row."""
     from ...ops.sampling import speculative_verify
 
-    logits, k_pool, v_pool = model.step(
-        params, tokens, positions, lengths, k_pool, v_pool, block_tables,
+    logits, pools, _ = model.step(
+        params, tokens, positions, lengths, pools, block_tables,
         attention_kernel=attention_kernel, mp_mesh=mp_mesh)
     target, accepted = speculative_verify(
         logits, tokens, seeds, counters, temperature, top_k, top_p,
         lengths)
-    return target, accepted, k_pool, v_pool
+    return target, accepted, pools
 
 
-def _verify_step_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
-                   positions, lengths, block_tables, seeds, counters,
-                   temperature, top_k, top_p, *, model,
-                   attention_kernel="gather", mp_mesh=None):
-    """int8-KV variant of :func:`_verify_step` (scales donated along)."""
-    from ...ops.sampling import speculative_verify
-
-    logits, k_pool, v_pool, k_scale, v_scale = model.step(
-        params, tokens, positions, lengths, k_pool, v_pool, block_tables,
-        attention_kernel=attention_kernel, mp_mesh=mp_mesh,
-        k_scale=k_scale, v_scale=v_scale)
-    target, accepted = speculative_verify(
-        logits, tokens, seeds, counters, temperature, top_k, top_p,
-        lengths)
-    return target, accepted, k_pool, v_pool, k_scale, v_scale
-
-
-def _multistep(params, k_pool, v_pool, tokens, positions, lengths,
-               block_tables, seeds, counters, temperature, top_k, top_p,
-               *, k, model, attention_kernel="gather", mp_mesh=None):
+def _multistep(params, pools, tokens, positions, lengths, block_tables,
+               seeds, counters, temperature, top_k, top_p, *, k, model,
+               attention_kernel="gather", mp_mesh=None):
     """``k`` decode iterations inside ONE donated program via
     ``lax.scan`` (docs/generation.md "multi-step decoding") — each scan
     iteration is exactly the single-step decode math (same (S, 1) model
     call, same ``(seed, position)`` sampler keying, same one-position
     scatter), so tokens match the step-at-a-time path and the int8 pool's
-    write pattern is bit-identical; only the host↔device round-trips in
-    between are amortized away.  ``tokens``/``positions``/``counters``
+    write pattern is bit-identical (the scales ride in the carry with
+    their pools, and the masked-absmax requantization touches blocks in
+    the order single-step decode would); only the host↔device round-trips
+    in between are amortized away.  ``tokens``/``positions``/``counters``
     are the FIRST iteration's (S,) values; rows with ``lengths == 0`` are
     inactive throughout (null-block writes).  Returns (S, k) tokens."""
     import jax
@@ -210,73 +174,38 @@ def _multistep(params, k_pool, v_pool, tokens, positions, lengths,
     from ...ops.sampling import sample_logits
 
     def body(carry, _):
-        k_pool, v_pool, tok, pos, ctr = carry
-        logits, k_pool, v_pool = model.step(
-            params, tok[:, None], pos[:, None], lengths, k_pool, v_pool,
+        pools, tok, pos, ctr = carry
+        logits, pools, _ = model.step(
+            params, tok[:, None], pos[:, None], lengths, pools,
             block_tables, attention_kernel=attention_kernel,
             mp_mesh=mp_mesh)
         nxt = sample_logits(logits[:, 0, :], seeds, ctr, temperature,
                             top_k, top_p)
-        return (k_pool, v_pool, nxt, pos + 1, ctr + 1), nxt
+        return (pools, nxt, pos + 1, ctr + 1), nxt
 
-    init = (k_pool, v_pool,
+    init = (pools,
             jnp.asarray(tokens, jnp.int32),
             jnp.asarray(positions, jnp.int32),
             jnp.asarray(counters, jnp.uint32))
-    (k_pool, v_pool, _, _, _), toks = jax.lax.scan(
-        body, init, None, length=k)
-    return jnp.transpose(toks), k_pool, v_pool  # (S, k)
+    (pools, _, _, _), toks = jax.lax.scan(body, init, None, length=k)
+    return jnp.transpose(toks), pools  # (S, k)
 
 
-def _multistep_q(params, k_pool, v_pool, k_scale, v_scale, tokens,
-                 positions, lengths, block_tables, seeds, counters,
-                 temperature, top_k, top_p, *, k, model,
-                 attention_kernel="gather", mp_mesh=None):
-    """int8-KV variant of :func:`_multistep`: the scale arrays join the
-    scan carry, and because each iteration scatters exactly one position
-    per row (the single-step pattern), the masked-absmax requantization
-    touches blocks in the same order single-step decode would."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...ops.sampling import sample_logits
-
-    def body(carry, _):
-        k_pool, v_pool, k_scale, v_scale, tok, pos, ctr = carry
-        logits, k_pool, v_pool, k_scale, v_scale = model.step(
-            params, tok[:, None], pos[:, None], lengths, k_pool, v_pool,
-            block_tables, attention_kernel=attention_kernel,
-            mp_mesh=mp_mesh,
-            k_scale=k_scale, v_scale=v_scale)
-        nxt = sample_logits(logits[:, 0, :], seeds, ctr, temperature,
-                            top_k, top_p)
-        return (k_pool, v_pool, k_scale, v_scale, nxt, pos + 1,
-                ctr + 1), nxt
-
-    init = (k_pool, v_pool, k_scale, v_scale,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(positions, jnp.int32),
-            jnp.asarray(counters, jnp.uint32))
-    (k_pool, v_pool, k_scale, v_scale, _, _, _), toks = jax.lax.scan(
-        body, init, None, length=k)
-    return jnp.transpose(toks), k_pool, v_pool, k_scale, v_scale
-
-
-def _block_fill(params, k_pool, v_pool, tokens, positions, lengths,
-                block_tables, *, model, attention_kernel="gather"):
+def _block_fill(params, pools, tokens, positions, lengths, block_tables,
+                *, model, attention_kernel="gather", mp_mesh=None):
     """Prefill of a block-diffusion model: whole blocks of context written
     into the cache, no logits — the first block step reads the first
     generated positions' own logits."""
-    _, k_pool, v_pool, _ = model.step(
-        params, tokens, positions, lengths, k_pool, v_pool, block_tables,
-        attention_kernel=attention_kernel, call="prefill",
+    _, pools, _ = model.step(
+        params, tokens, positions, lengths, pools, block_tables,
+        attention_kernel=attention_kernel, mp_mesh=mp_mesh, call="prefill",
         want_logits=False)
-    return k_pool, v_pool
+    return (pools,)
 
 
-def _block_step(params, k_pool, v_pool, tokens, positions, lengths,
-                block_tables, masked, n_unmask, *, model,
-                attention_kernel="gather"):
+def _block_step(params, pools, tokens, positions, lengths, block_tables,
+                masked, n_unmask, *, model, attention_kernel="gather",
+                mp_mesh=None):
     """One pass of generation by diffusion over blocks (docs/generation.md
     "Block-diffusion generation"): every row feeds its block of
     ``model.block_len`` token ids, MASK where ``masked``, at positions
@@ -289,24 +218,21 @@ def _block_step(params, k_pool, v_pool, tokens, positions, lengths,
     pools)``."""
     from ...ops.sampling import block_unmask
 
-    logits, k_pool, v_pool, touched = model.step(
-        params, tokens, positions, lengths, k_pool, v_pool, block_tables,
-        attention_kernel=attention_kernel, call="block")
-    return (block_unmask(logits, masked, n_unmask), touched, logits,
-            k_pool, v_pool)
+    logits, pools, touched = model.step(
+        params, tokens, positions, lengths, pools, block_tables,
+        attention_kernel=attention_kernel, mp_mesh=mp_mesh, call="block")
+    return block_unmask(logits, masked, n_unmask), touched, logits, pools
 
 
 class GenerationPrograms:
-    """Owns the jitted step + per-signature compile accounting."""
+    """Owns the jitted programs + per-signature compile accounting."""
 
     def __init__(self, params, model, compute_dtype=None,
                  mp_devices: int = 1, shard_rules=None, kv_dtype=None):
-        import jax
-
         model = self._model = as_model(model, compute_dtype)
-        # int8 paged KV cache (docs/quantization.md): the jitted step
-        # gains the two donated scale operands and every program key a
-        # ("kv_dtype", "int8") component; None keeps the classic layout
+        # int8 paged KV cache (docs/quantization.md): the pools operand
+        # holds the two scale arrays too, and every program key gains a
+        # ("kv_dtype", "int8") component; None keeps the classic keys
         # byte-identical
         self._kv_dtype = kv_dtype
         # model-parallel serving (docs/sharding.md): with mp_devices > 1 the
@@ -339,48 +265,27 @@ class GenerationPrograms:
                  or model.heads % int(self._mp_mesh.shape["mp"]) == 0)
         self._kernel = "paged" if pallas_enabled() and mp_ok else "gather"
         self._params = self._place_params(params)
-        # multi-token decoding (docs/generation.md "Speculative
-        # decoding"): the verify step shares the model step's operand
-        # layout but returns per-position targets + accept counts; the
-        # multistep scan needs one jitted partial per static k (built
-        # lazily — creating a jit wrapper traces nothing)
         self._step_kw = dict(model=model, attention_kernel=self._kernel,
                              mp_mesh=self._mp_mesh)
-        self._jit = self._jit_verify = self._jit_fill = self._jit_block \
-            = None
-        if model.block_len:
-            # generation by diffusion over blocks (docs/generation.md):
-            # a prefill that only fills the cache, and the block step
-            kw = dict(model=model, attention_kernel=self._kernel)
-            self._jit_fill = jax.jit(functools.partial(_block_fill, **kw),
-                                     donate_argnums=(1, 2))
-            self._jit_block = jax.jit(functools.partial(_block_step, **kw),
-                                      donate_argnums=(1, 2))
-        elif kv_dtype == "int8":
-            self._jit = jax.jit(
-                functools.partial(_model_step_q, **self._step_kw),
-                donate_argnums=(1, 2, 3, 4))
-            self._jit_verify = jax.jit(
-                functools.partial(_verify_step_q, **self._step_kw),
-                donate_argnums=(1, 2, 3, 4))
-        else:
-            self._jit = jax.jit(
-                functools.partial(_model_step, **self._step_kw),
-                donate_argnums=(1, 2))
-            self._jit_verify = jax.jit(
-                functools.partial(_verify_step, **self._step_kw),
-                donate_argnums=(1, 2))
-        self._jit_ms: Dict[int, object] = {}
-        # the prefix-cache CoW block copy (docs/generation.md "prefix
-        # caching"): ONE signature per pool family, donated like the
-        # model step so the copy is an in-place device-side move
-        if kv_dtype == "int8":
-            self._jit_copy = jax.jit(block_copy_pools,
-                                     donate_argnums=(0, 1, 4, 5))
-        else:
-            self._jit_copy = jax.jit(
-                lambda k, v, s, d: block_copy_pools(k, v, s, d),
-                donate_argnums=(0, 1))
+        # program kind -> (the function it traces, its site in
+        # compile_cache_stats()["by_site"]).  Program variants count per
+        # site — "gen_decode_paged" next to the classic "gen_decode", the
+        # int8-pool family "_int8"-suffixed; the block copy runs no
+        # attention, so its site is not named for the kernel
+        variant = "" if self._kernel == "gather" else f"_{self._kernel}"
+        int8 = "_int8" if kv_dtype == "int8" else ""
+        self._kinds = {
+            kind: (fn, kind + (variant if fn is not block_copy_pools
+                               else "") + int8)
+            for kind, fn in (
+                ("gen_prefill",
+                 _block_fill if model.block_len else _model_step),
+                ("gen_decode", _model_step),
+                ("gen_verify", _verify_step),
+                ("gen_multistep", _multistep),
+                ("gen_block", _block_step),
+                ("gen_block_copy", block_copy_pools))}
+        self._jits: Dict[tuple, object] = {}
         self._lock = threading.Lock()
         self._stats: Dict[tuple, Dict[str, int]] = {}
 
@@ -406,17 +311,11 @@ class GenerationPrograms:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         # (n_layers, num_blocks, block_size, n_heads*d_head): the folded
-        # minor dim splits on head boundaries
-        sh = NamedSharding(self._mp_mesh, P(None, None, None, "mp"))
-        if cache.quantized:
-            # per-(layer, block, head) scales shard on their head dim 2
-            ssh = NamedSharding(self._mp_mesh, P(None, None, "mp"))
-            cache.swap(jax.device_put(cache.k, sh),
-                       jax.device_put(cache.v, sh),
-                       jax.device_put(cache.k_scale, ssh),
-                       jax.device_put(cache.v_scale, ssh))
-            return
-        cache.swap(jax.device_put(cache.k, sh), jax.device_put(cache.v, sh))
+        # minor dim splits on head boundaries; the int8 pool's
+        # per-(layer, block, head) scales shard on their head dim 2
+        specs = (P(None, None, None, "mp"),) * 2 + (P(None, None, "mp"),) * 2
+        cache.swap(jax.device_put(pool, NamedSharding(self._mp_mesh, spec))
+                   for pool, spec in zip(cache.pools, specs))
 
     def refresh_params(self, params) -> None:
         """Swap in updated model weights (programs are shape-keyed, so no
@@ -427,16 +326,18 @@ class GenerationPrograms:
     @property
     def kernel(self) -> str:
         """Active decode-attention implementation: ``"paged"`` (the Pallas
-        block-table-walking kernel, docs/pallas.md) or ``"gather"`` (the
+        block-table-walking kernel, docs/pallas.md; per head under an mp
+        mesh whose axis the heads divide) or ``"gather"`` (the
         gather+dense XLA path).  Frozen at construction from the
-        ``TPUMX_PALLAS`` gate (gather under an mp mesh) — the bench
-        trajectory attributes wins via this field."""
+        ``TPUMX_PALLAS`` gate."""
         return self._kernel
 
-    def _key(self, kind: str, cache, tokens, block_tables) -> tuple:
-        sig = (("tokens", tuple(tokens.shape), "int32"),
-               ("block_tables", tuple(block_tables.shape), "int32"),
-               ("kv_pool", cache.shape, str(cache.k.dtype)))
+    def _key(self, kind: str, cache, tokens=None, block_tables=None,
+             k: Optional[int] = None) -> tuple:
+        sig = (("kv_pool", cache.shape, str(cache.k.dtype)),)
+        if tokens is not None:
+            sig = (("tokens", tuple(tokens.shape), "int32"),
+                   ("block_tables", tuple(block_tables.shape), "int32")) + sig
         # the paged kernel variant keys its programs separately, while
         # gather (TPUMX_PALLAS=0) keys stay byte-identical to the
         # pre-kernel layout — warm caches and freeze sets carry over
@@ -446,69 +347,65 @@ class GenerationPrograms:
         # kv_dtype off leaves every pre-existing key byte-identical
         if self._kv_dtype == "int8":
             sig = sig + (("kv_dtype", "int8"),)
+        if k is not None:
+            sig = sig + (("k", k),)
         return (kind, sig)
+
+    def _run(self, kind: str, cache, args, k: Optional[int] = None) -> tuple:
+        """The one way a program runs: note the compile-cache lookup, call
+        the kind's jitted function on the cache's ``pools`` (donated),
+        swap what it returns, last among its outputs, back into the cache;
+        returns the other outputs.  The note — per-signature hit/miss
+        counts plus the ``_note_cache`` call that feeds freeze/explain —
+        happens BEFORE dispatch, so a frozen service raises
+        :class:`FreezeCompilesError` without burning an XLA compile."""
+        from ... import executor as _executor
+
+        fn, site = self._kinds[kind]
+        # the block copy has no model in it: no parameters, no tokens or
+        # tables in its key, and its caller's serving.cow_copy span times it
+        step = fn is not block_copy_pools
+        key = self._key(kind, cache, args[0], args[3], k) if step \
+            else self._key(kind, cache)
+        with self._lock:
+            per = self._stats.get(key)
+            hit = per is not None
+            if per is None:
+                per = self._stats[key] = {"hits": 0, "misses": 0}
+            jitted = self._jits.get((fn, k))
+            if jitted is None:
+                # one jit wrapper per (function, static k): creating one
+                # traces nothing
+                import jax
+
+                if step:
+                    kw = self._step_kw if k is None \
+                        else dict(self._step_kw, k=k)
+                    jitted = jax.jit(functools.partial(fn, **kw),
+                                     donate_argnums=(1,))
+                else:
+                    jitted = jax.jit(fn, donate_argnums=(0,))
+                self._jits[fn, k] = jitted
+        _executor._note_cache(hit=hit, site=(site, ("lm",)), key=key)
+        with self._lock:
+            per["hits" if hit else "misses"] += 1
+        if not step:
+            cache.swap(jitted(cache.pools, *args))
+            return ()
+        with _tracing.span("serving.step.dispatch", cat="serving"):
+            *out, pools = jitted(self._params, cache.pools, *args)
+            cache.swap(pools)
+        return tuple(out)
 
     def run(self, kind: str, cache, tokens, positions, lengths,
             block_tables, seeds, counters, temperature, top_k, top_p):
         """Execute one step; returns ``(next_tokens np(B,), last_logits)``.
 
-        ``cache`` is updated in place (donated pools swapped back).  The
-        compile-cache note happens BEFORE dispatch, so a frozen service
-        raises :class:`FreezeCompilesError` without burning an XLA compile.
-        """
-        from ... import executor as _executor
-
-        kernel = self.kernel
-        key = self._key(kind, cache, tokens, block_tables)
-        with self._lock:
-            per = self._stats.get(key)
-            hit = per is not None
-            if per is None:
-                per = self._stats[key] = {"hits": 0, "misses": 0}
-        # program variants count per-site in compile_cache_stats()["by_site"]
-        # — "gen_decode_paged" next to the classic "gen_decode", with the
-        # int8-pool family as its own "_int8"-suffixed site
-        site_kind = kind if kernel == "gather" else f"{kind}_{kernel}"
-        if self._kv_dtype == "int8":
-            site_kind = f"{site_kind}_int8"
-        _executor._note_cache(hit=hit, site=(site_kind, ("lm",)), key=key)
-        with self._lock:
-            per["hits" if hit else "misses"] += 1
-        next_tokens, last = self._dispatch(self._jit, cache, _step_args(
+        ``cache`` is updated in place (donated pools swapped back)."""
+        next_tokens, last = self._run(kind, cache, _step_args(
             tokens, positions, lengths, block_tables, seeds, counters,
             temperature, top_k, top_p))
         return _synced(next_tokens), last
-
-    def _dispatch(self, fn, cache, args):
-        """Call a step program on the cache's pools (the scales too for
-        the int8 pool) and swap the donated pools it returns, last among
-        its outputs, back into the cache; returns the other outputs."""
-        pools = (cache.k, cache.v)
-        if self._kv_dtype == "int8":
-            pools += (cache.k_scale, cache.v_scale)
-        with _tracing.span("serving.step.dispatch", cat="serving"):
-            out = fn(self._params, *pools, *args)
-            cache.swap(*out[-len(pools):])
-        return out[:-len(pools)]
-
-    def _note(self, kind: str, key: tuple) -> None:
-        """Compile-cache bookkeeping shared by every program family:
-        per-signature hit/miss counts plus the ``_note_cache`` call that
-        feeds freeze/explain — BEFORE dispatch, like :meth:`run`."""
-        from ... import executor as _executor
-
-        with self._lock:
-            per = self._stats.get(key)
-            hit = per is not None
-            if per is None:
-                per = self._stats[key] = {"hits": 0, "misses": 0}
-        site_kind = kind if self.kernel == "gather" \
-            else f"{kind}_{self.kernel}"
-        if self._kv_dtype == "int8":
-            site_kind = f"{site_kind}_int8"
-        _executor._note_cache(hit=hit, site=(site_kind, ("lm",)), key=key)
-        with self._lock:
-            per["hits" if hit else "misses"] += 1
 
     def run_verify(self, cache, tokens, positions, lengths, block_tables,
                    seeds, counters, temperature, top_k, top_p):
@@ -518,56 +415,25 @@ class GenerationPrograms:
         np(S,))`` — see :func:`~mxnet_tpu.ops.sampling.speculative_verify`
         for the emit contract.  Site ``gen_verify``; keys share the
         :meth:`run` namespace so warmup enumerates the (Tk, W) ladder."""
-        key = self._key("gen_verify", cache, tokens, block_tables)
-        self._note("gen_verify", key)
-        return _synced(*self._dispatch(self._jit_verify, cache, _step_args(
+        return _synced(*self._run("gen_verify", cache, _step_args(
             tokens, positions, lengths, block_tables, seeds, counters,
             temperature, top_k, top_p)))
 
     def run_fill(self, cache, tokens, positions, lengths, block_tables):
         """A block-diffusion model's prefill chunk (site ``gen_prefill``):
         fills the cache, returns nothing to read."""
-        tokens = _np.asarray(tokens, _np.int32)
-        block_tables = _np.asarray(block_tables, _np.int32)
-        self._note("gen_prefill", self._key("gen_prefill", cache, tokens,
-                                            block_tables))
-        self._dispatch(self._jit_fill, cache, (
-            tokens, _np.asarray(positions, _np.int32),
-            _np.asarray(lengths, _np.int32), block_tables))
+        self._run("gen_prefill", cache, _step_args(
+            tokens, positions, lengths, block_tables))
 
     def run_block(self, cache, tokens, positions, lengths, block_tables,
                   masked, n_unmask):
         """One block step (site ``gen_block``): returns ``(unmasked np(S,
         L), experts touched np(), logits (S, L, vocab) on the device)``
         — see :func:`_block_step`."""
-        tokens = _np.asarray(tokens, _np.int32)
-        block_tables = _np.asarray(block_tables, _np.int32)
-        self._note("gen_block", self._key("gen_block", cache, tokens,
-                                          block_tables))
-        unmasked, touched, logits = self._dispatch(self._jit_block, cache, (
-            tokens, _np.asarray(positions, _np.int32),
-            _np.asarray(lengths, _np.int32), block_tables,
+        unmasked, touched, logits = self._run("gen_block", cache, _step_args(
+            tokens, positions, lengths, block_tables) + (
             _np.asarray(masked, _np.bool_), _np.asarray(n_unmask, _np.int32)))
         return _synced(unmasked, touched) + (logits,)
-
-    def _ms_jit(self, k: int):
-        import jax
-
-        with self._lock:
-            fn = self._jit_ms.get(k)
-            if fn is None:
-                if self._kv_dtype == "int8":
-                    fn = jax.jit(
-                        functools.partial(_multistep_q, k=k,
-                                          **self._step_kw),
-                        donate_argnums=(1, 2, 3, 4))
-                else:
-                    fn = jax.jit(
-                        functools.partial(_multistep, k=k,
-                                          **self._step_kw),
-                        donate_argnums=(1, 2))
-                self._jit_ms[k] = fn
-        return fn
 
     def run_multistep(self, k: int, cache, tokens, positions, lengths,
                       block_tables, seeds, counters, temperature, top_k,
@@ -579,13 +445,9 @@ class GenerationPrograms:
         program signature (``("k", k)`` key component, site
         ``gen_multistep``) — the engine's pow2 k-ladder keeps the family
         finite for warmup."""
-        tokens = _np.asarray(tokens, _np.int32)
-        key = self._key("gen_multistep", cache, tokens, block_tables)
-        key = (key[0], key[1] + (("k", int(k)),))
-        self._note("gen_multistep", key)
-        return _synced(*self._dispatch(self._ms_jit(int(k)), cache, _step_args(
+        return _synced(*self._run("gen_multistep", cache, _step_args(
             tokens, positions, lengths, block_tables, seeds, counters,
-            temperature, top_k, top_p)))
+            temperature, top_k, top_p), k=int(k)))
 
     def copy_block(self, cache, src: int, dst: int) -> None:
         """Copy pool block ``src`` onto ``dst`` (scales included for the
@@ -594,35 +456,8 @@ class GenerationPrograms:
         ``gen_block_copy`` with the same freeze/explain discipline as the
         model steps; warmed by ``GenerationService.warmup`` whenever the
         prefix cache is enabled."""
-        from ... import executor as _executor
-
-        sig = (("kv_pool", cache.shape, str(cache.k.dtype)),)
-        # same key namespacing as _key(): the paged-kernel service and the
-        # int8 pool each keep their whole program family distinct
-        if self.kernel == "paged":
-            sig = sig + (("kernel", "paged"),)
-        if self._kv_dtype == "int8":
-            sig = sig + (("kv_dtype", "int8"),)
-        key = ("gen_block_copy", sig)
-        with self._lock:
-            per = self._stats.get(key)
-            hit = per is not None
-            if per is None:
-                per = self._stats[key] = {"hits": 0, "misses": 0}
-        site = "gen_block_copy_int8" if self._kv_dtype == "int8" \
-            else "gen_block_copy"
-        _executor._note_cache(hit=hit, site=(site, ("lm",)), key=key)
-        with self._lock:
-            per["hits" if hit else "misses"] += 1
-        s = _np.asarray([src], _np.int32)
-        d = _np.asarray([dst], _np.int32)
-        if self._kv_dtype == "int8":
-            k, v, ks, vs = self._jit_copy(cache.k, cache.v, s, d,
-                                          cache.k_scale, cache.v_scale)
-            cache.swap(k, v, ks, vs)
-            return
-        k, v = self._jit_copy(cache.k, cache.v, s, d)
-        cache.swap(k, v)
+        self._run("gen_block_copy", cache, (_np.asarray([src], _np.int32),
+                                            _np.asarray([dst], _np.int32)))
 
     def compile_stats(self) -> Dict[tuple, Dict[str, int]]:
         """Per-signature ``{"hits", "misses"}`` — every signature compiled

@@ -242,24 +242,23 @@ def test_decode_step_program_compiles(one_chip, monkeypatch, kv_dtype):
             cfg, jax.random.PRNGKey(0))))
     pool_dt = jnp.int8 if kv_dtype else jnp.float32
     pool = sds((cfg.n_layers, NB, BS, HD), pool_dt)
-    shapes = [params, sds((SLOTS, 1), jnp.int32), sds((SLOTS, 1), jnp.int32),
-              sds((SLOTS,), jnp.int32), pool, pool,
-              sds((SLOTS, 64), jnp.int32)]
-    kw = {}
+    pools = (pool, pool)
     if kv_dtype:
         sc = sds((cfg.n_layers, NB, H), jnp.float32)
-        kw = dict(k_scale=sc, v_scale=sc)
+        pools += (sc, sc)
+    shapes = [params, sds((SLOTS, 1), jnp.int32), sds((SLOTS, 1), jnp.int32),
+              sds((SLOTS,), jnp.int32), pools, sds((SLOTS, 64), jnp.int32)]
 
     # through the engine's model seam (serving/generation/programs.py):
     # GPT-2's block is its first model, and compiles to the same program
     model = tr.TransformerLM(cfg)
 
-    def step(params, tokens, positions, lengths, kp, vp, tables, **kw):
-        return model.step(params, tokens, positions, lengths, kp, vp,
-                          tables, attention_kernel="paged", **kw)
+    def step(params, tokens, positions, lengths, pools, tables):
+        return model.step(params, tokens, positions, lengths, pools,
+                          tables, attention_kernel="paged")[:2]
 
-    text = jax.jit(step, donate_argnums=(4, 5)).lower(
-        *shapes, **kw).compile().as_text()
+    text = jax.jit(step, donate_argnums=(4,)).lower(
+        *shapes).compile().as_text()
     # the kernel's names in a device trace are the parent's
     assert "_paged_call_w64_decode" in text
     assert not re.search(r"_paged_call_w\d+_t\d+_", text)
@@ -384,9 +383,9 @@ def test_block_step_program_compiles_at_the_cells_shapes(one_chip,
     model, params, pool = _sdar_cell(sds, n_layers)
     fn = jax.jit(functools.partial(gp._block_step, model=model,
                                    attention_kernel="paged"),
-                 donate_argnums=(1, 2))
+                 donate_argnums=(1,))
     compiled = fn.lower(
-        params, pool, pool, sds((S, L), jnp.int32), sds((S, L), jnp.int32),
+        params, (pool, pool), sds((S, L), jnp.int32), sds((S, L), jnp.int32),
         sds((S,), jnp.int32), sds((S, W), jnp.int32), sds((S, L), jnp.bool_),
         sds((S,), jnp.int32)).compile()
     text = compiled.as_text()
@@ -415,8 +414,8 @@ def test_block_prefill_program_compiles_at_the_cells_shapes(one_chip,
     model, params, pool = _sdar_cell(sds, 2)
     fn = jax.jit(functools.partial(gp._block_fill, model=model,
                                    attention_kernel="paged"),
-                 donate_argnums=(1, 2))
-    text = fn.lower(params, pool, pool, sds((1, T), jnp.int32),
+                 donate_argnums=(1,))
+    text = fn.lower(params, (pool, pool), sds((1, T), jnp.int32),
                     sds((1, T), jnp.int32), sds((1,), jnp.int32),
                     sds((1, W), jnp.int32)).compile().as_text()
     assert text.count(f"_paged_call_w{W}_t{T}_prefill") >= 2

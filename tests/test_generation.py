@@ -678,3 +678,156 @@ def test_gpt2_programs_through_the_model_seam_are_the_parents():
     assert set(svc.compile_stats()) == want
     assert svc.stats()["decode_mode"] == "single"
     assert svc.stats()["block_diffusion"] is None
+
+
+# -- one serving step: the batch builder and the program kinds ----------------------
+_X12, _Z30, _M = 13, 31, 39     # the pending tokens of rows X and Z; a MASK id
+
+# case -> (T, what a row feeds, positions written, sampler arrays asked for,
+#          tokens and lengths of rows X (slot 0) and Z (slot 3), table width)
+_BUILDER_CASES = {
+    "single": (1, lambda r: [r.seq_tokens[r.ctx_len]], None, True,
+               [_X12], 1, [_Z30], 1, 4),
+    # drafts of unequal length: X proposes two, Z none; Tk buckets to 4
+    "verify": (4, lambda r: [r.seq_tokens[r.ctx_len]] + {0: [7, 8]}.get(
+        r.rid, []), None, True, [_X12, 7, 8, 0], 3, [_Z30, 0, 0, 0], 1, 4),
+    # k = 4 writes 30 .. 33: a fifth block, so the width buckets to 8
+    "multistep": (1, lambda r: [r.seq_tokens[r.ctx_len]], 4, True,
+                  [_X12], 1, [_Z30], 1, 8),
+    "block": (4, lambda r: r.block, None, False,
+              [_X12, _M, _M, _M], 4, [_Z30, _M, _M, _M], 4, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BUILDER_CASES))
+def test_step_builder_against_arrays_written_by_hand(params, case):
+    """The one batch builder of the four step kinds, on four slots — X, an
+    empty one, Y outside the batch, Z — against every operand written out
+    by hand: the rows, tokens / positions / lengths, the sampler arrays
+    (none for the block step), the widest table bucketed on the pow2
+    ladder, and the copy-on-write of X's shared tail block BEFORE it is
+    written."""
+    from mxnet_tpu.serving.generation.engine import _RUNNING, _GenRequest
+
+    T, feed, writes, sampler, x_tok, x_len, z_tok, z_len, w = \
+        _BUILDER_CASES[case]
+    svc = GenerationService(params, CFG, _gc(max_slots=4), start=False)
+    alloc = svc._cache.allocator
+
+    def request(rid, ctx, n_blocks, **kw):
+        r = _GenRequest(rid, list(range(1, ctx + 2)), 32, 16,
+                        kw.get("temperature", 0.0), kw.get("top_k", 0),
+                        kw.get("top_p", 1.0), kw.get("seed", 0), None, None,
+                        None)
+        r.state, r.ctx_len, r.blocks = _RUNNING, ctx, alloc.allocate(n_blocks)
+        r.block = [r.seq_tokens[ctx]] + [_M] * 3
+        return r
+
+    x = request(0, 12, 3, seed=5, temperature=0.7, top_k=3, top_p=0.9)
+    y = request(1, 20, 3, seed=7)
+    z = request(2, 30, 5, seed=9)
+    assert (x.blocks, y.blocks, z.blocks) == (
+        [1, 2, 3], [4, 5, 6], [7, 8, 9, 10, 11])
+    svc._slots[:] = [x, None, y, z]
+    # X's tail block (positions 8 .. 15) is shared and holds history
+    alloc.incref([2])
+    k, v = svc._cache.pools
+    svc._cache.swap((k.at[:, 2].set(1.5), v.at[:, 2].set(-2.5)))
+
+    b = svc._build_step([x, z], T, feed, writes=writes, sampler=sampler)
+
+    assert b.rows == [(0, x), (3, z)] and b.width == w
+    assert x.blocks == [1, 12, 3] and x.cow_copies == 1
+    assert alloc.refcount(2) == 1 and alloc.refcount(12) == 1
+    np.testing.assert_array_equal(np.asarray(svc._cache.k)[:, 12], 1.5)
+    np.testing.assert_array_equal(np.asarray(svc._cache.v)[:, 12], -2.5)
+    zero = [0] * T
+    np.testing.assert_array_equal(b.tokens, [x_tok, zero, zero, z_tok])
+    np.testing.assert_array_equal(
+        b.positions, [list(range(12, 12 + T)), zero, zero,
+                      list(range(30, 30 + T))])
+    np.testing.assert_array_equal(b.lengths, [x_len, 0, 0, z_len])
+    pad = [0] * (w - 4)
+    np.testing.assert_array_equal(
+        b.tables, [([1, 12, 3, 0] + pad), [0] * w, [0] * w,
+                   ([7, 8, 9, 10] + [11, 0, 0, 0][:w - 4])])
+    for a in (b.tokens, b.positions, b.lengths, b.tables):
+        assert a.dtype == np.int32
+    if not sampler:
+        assert b.sampler == () and len(b.operands) == 4
+        return
+    seeds, counters, temperature, top_k, top_p = b.sampler
+    assert b.operands[4:] == b.sampler
+    np.testing.assert_array_equal(seeds, np.asarray([5, 0, 0, 9], np.uint32))
+    np.testing.assert_array_equal(counters,
+                                  np.asarray([13, 0, 0, 31], np.uint32))
+    np.testing.assert_array_equal(temperature,
+                                  np.asarray([0.7, 0, 0, 0], np.float32))
+    np.testing.assert_array_equal(top_k, np.asarray([3, 0, 0, 0], np.int32))
+    np.testing.assert_array_equal(top_p,
+                                  np.asarray([0.9, 1, 1, 1], np.float32))
+    assert [a.dtype for a in b.sampler] == [
+        np.uint32, np.uint32, np.float32, np.int32, np.float32]
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_a_program_kind_is_one_function_and_notes_the_parents_keys(
+        params, monkeypatch, kv_dtype):
+    """Every program kind is traced from ONE function whatever the pool
+    (the cache's arrays travel as one operand), and a fixed script of calls
+    feeds ``executor._note_cache`` the (hit, site, key) sequence the twin
+    functions did (the tuples are the parent commit's output)."""
+    from mxnet_tpu import executor
+    from mxnet_tpu.serving.generation import programs as gp
+
+    monkeypatch.setenv("TPUMX_PALLAS", "0")
+    progs = gp.GenerationPrograms(params, CFG, kv_dtype=kv_dtype)
+    assert {kind: fn for kind, (fn, _) in progs._kinds.items()} == {
+        "gen_prefill": gp._model_step, "gen_decode": gp._model_step,
+        "gen_verify": gp._verify_step, "gen_multistep": gp._multistep,
+        "gen_block": gp._block_step, "gen_block_copy": gp.block_copy_pools}
+    assert not any(hasattr(gp, name) for name in (
+        "_model_step_q", "_verify_step_q", "_multistep_q"))
+    cache = PagedKVCache(num_blocks=16, block_size=8, kv_dtype=kv_dtype,
+                         n_layers=CFG.n_layers, n_heads=CFG.n_heads,
+                         d_head=CFG.d_head, dtype=jnp.float32)
+    assert len(cache.pools) == (4 if kv_dtype else 2)
+    assert cache.pools[:2] == (cache.k, cache.v)
+    seen = []
+    note = executor._note_cache
+    monkeypatch.setattr(
+        executor, "_note_cache",
+        lambda hit, site, key: (seen.append((hit, site, key)),
+                                note(hit=hit, site=site, key=key))[1])
+    S = 3
+    z = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
+    knobs = lambda n: (z(n), z(n), z(n).astype(np.float32), z(n),  # noqa: E731
+                       np.ones(n, np.float32))
+    progs.run("gen_prefill", cache, z(1, 16), z(1, 16), z(1), z(1, 2),
+              *knobs(1))
+    for _ in range(2):
+        progs.run("gen_decode", cache, z(S, 1), z(S, 1), z(S), z(S, 4),
+                  *knobs(S))
+    progs.run_verify(cache, z(S, 4), z(S, 4), z(S), z(S, 4), *knobs(S))
+    progs.run_multistep(4, cache, z(S), z(S), z(S), z(S, 4), *knobs(S))
+    for _ in range(2):
+        progs.copy_block(cache, 0, 0)
+    q = "_int8" if kv_dtype else ""
+    pool = (("kv_pool", (2, 16, 8, 32), "int8" if kv_dtype else "float32"),)
+    fam = pool + ((("kv_dtype", "int8"),) if kv_dtype else ())
+    sig = lambda t, w: (("tokens", t, "int32"),  # noqa: E731
+                        ("block_tables", w, "int32"))
+    decode = ("gen_decode", sig((3, 1), (3, 4)) + fam)
+    copy = ("gen_block_copy", fam)
+    assert seen == [
+        (False, ("gen_prefill" + q, ("lm",)),
+         ("gen_prefill", sig((1, 16), (1, 2)) + fam)),
+        (False, ("gen_decode" + q, ("lm",)), decode),
+        (True, ("gen_decode" + q, ("lm",)), decode),
+        (False, ("gen_verify" + q, ("lm",)),
+         ("gen_verify", sig((3, 4), (3, 4)) + fam)),
+        (False, ("gen_multistep" + q, ("lm",)),
+         ("gen_multistep", sig((3,), (3, 4)) + fam + (("k", 4),))),
+        (False, ("gen_block_copy" + q, ("lm",)), copy),
+        (True, ("gen_block_copy" + q, ("lm",)), copy)]
+    assert progs.compiled_signatures() == 5

@@ -418,8 +418,8 @@ def build_cases():
 
         from mxnet_tpu.serving.generation.programs import block_copy_pools
 
-        k, v = jax.jit(lambda kp, vp, s, d: block_copy_pools(kp, vp, s, d))(
-            put(kp_cow), put(vp_cow), put(src_cow), put(dst_cow))
+        k, v = jax.jit(block_copy_pools)(
+            (put(kp_cow), put(vp_cow)), put(src_cow), put(dst_cow))
         return [np.asarray(k), np.asarray(v)]
 
     def kv_block_copy_int8(put):
@@ -428,8 +428,8 @@ def build_cases():
         from mxnet_tpu.serving.generation.programs import block_copy_pools
 
         k, v, ks, vs = jax.jit(block_copy_pools)(
-            put(kq_cow), put(vq_cow), put(src_cow), put(dst_cow),
-            put(ks_cow), put(vs_cow))
+            (put(kq_cow), put(vq_cow), put(ks_cow), put(vs_cow)),
+            put(src_cow), put(dst_cow))
         return [np.asarray(k).astype(np.float32),
                 np.asarray(v).astype(np.float32),
                 np.asarray(ks), np.asarray(vs)]
