@@ -59,7 +59,7 @@ from ..batcher import (BACKPRESSURE_POLICIES, DeadlineExceededError,
 from ..bucketing import (batch_buckets, bucket_batch, bucket_seq_len,
                          pad_tokens_right, seq_buckets)
 from .kv_cache import PagedKVCache, blocks_for
-from .programs import GenerationPrograms, as_model
+from .programs import GenerationPrograms, _synced, as_model
 
 __all__ = ["GenerationConfig", "GenerationService", "GenerationStream",
            "GenerationStepError"]
@@ -430,6 +430,27 @@ class _StepInputs(NamedTuple):
                 *self.sampler)
 
 
+class _Flight(NamedTuple):
+    """A one-token decode step that has been dispatched and not read
+    (docs/generation.md "the step in flight"): the rows it fed, its
+    sampled tokens as :meth:`GenerationPrograms.run` returned them, the
+    clock around its dispatch, the iteration its participation events
+    name, and the ids of its rows."""
+    step: _StepInputs
+    tokens: object
+    t0: float
+    t1: float
+    iteration: int
+    rids: frozenset
+
+
+class _LandFirst(Exception):
+    """Raised under the schedule when a row of the step in flight has to
+    leave its slot — a preemption, a cancel, a deadline, a shutdown that
+    does not drain: its newest token is still on the device, so the
+    engine reads and emits that step first and schedules again."""
+
+
 class GenerationStream:
     """Per-request handle: iterate generated tokens as they stream, or
     block on :meth:`result` for the full list."""
@@ -710,6 +731,16 @@ class GenerationService:
         self._consec_step_failures = 0
         self._max_error_requeues = 3  # error-path requeue budget per request
         self._iteration = 0
+        # the one-token decode step dispatched and not read yet; only a
+        # service whose every decode step is that step leaves one in
+        # flight — a verify chunk's proposer, a scan and a block step all
+        # need the last values on the host before they can build.  Nor
+        # under an mp mesh: a step's tokens come back committed to the
+        # mesh, and fed onward they would key a second lowering of every
+        # decode width
+        self._flight: Optional[_Flight] = None
+        self._runs_ahead = not (cfg.speculative or self._ms_buckets or L
+                                or cfg.mp_devices > 1)
         self._membership: "deque[Tuple[int, Tuple[int, ...]]]" = \
             deque(maxlen=4096)
         self._worker: Optional[threading.Thread] = None
@@ -725,6 +756,10 @@ class GenerationService:
                         "prefill_tokens": 0, "cow_copies": 0,
                         "draft_proposed": 0, "draft_accepted": 0,
                         "spec_steps": 0, "multistep_steps": 0,
+                        # decode steps dispatched before the last one's
+                        # tokens were read, and those dispatched with
+                        # nothing in flight
+                        "steps_ahead": 0, "steps_drained": 0,
                         # generation by diffusion over blocks: program
                         # calls, rows fed over them, rows on their commit
                         # pass, tokens emitted at commits, and (from the
@@ -992,8 +1027,14 @@ class GenerationService:
                 self._programs.run("gen_prefill", self._cache,
                                    *zeros(tb, wp, slots=1).operands)
             for w in widths:
-                self._programs.run("gen_decode", self._cache,
-                                   *zeros(1, w).operands)
+                z = zeros(1, w)
+                toks, _ = self._programs.run("gen_decode", self._cache,
+                                             *z.operands)
+            if self._runs_ahead:
+                # the step in flight hands its tokens on through one
+                # slot-sized program with no model in it
+                _synced(self._programs.carry_tokens(toks, z.tokens,
+                                                    z.lengths > 0))
             # speculative verify (docs/generation.md "Speculative
             # decoding"): every (Tk, W) pair on the ladders
             for tk in self._verify_buckets:
@@ -1166,8 +1207,55 @@ class GenerationService:
 
     def _iterate(self) -> bool:
         """One pass of the loop with requests queued or running: schedule
-        under the lock, prefill what was admitted, one decode step over
-        what runs.  False ends the loop."""
+        under the lock, prefill what was admitted, dispatch one decode
+        step over what runs, and read and emit the step before it
+        (docs/generation.md "the step in flight").  False ends the
+        loop."""
+        try:
+            plan = self._schedule()
+        except _LandFirst:
+            self._land()
+            plan = self._schedule()
+        if isinstance(plan, bool):
+            return plan
+        admitted, progress = plan
+        try:
+            for req in admitted:
+                try:
+                    self._prefill(req)
+                except Exception as exc:  # noqa: BLE001 — isolate
+                    self._requeue_or_fail(req, exc)
+            # a row whose token in flight is its last is not fed again
+            running = [r for r in self._slots
+                       if r is not None and r.state == _RUNNING
+                       and not (self._lead(r)
+                                and r.n_generated + 1 >= r.max_new)]
+            self._membership.append(
+                (self._iteration,
+                 tuple(sorted(r.rid for r in running))))
+            if running:
+                self._decode_isolated(running)
+            else:
+                # nothing left to feed: the pass reads the last step
+                self._land()
+        except Exception as exc:  # noqa: BLE001 — the loop must survive
+            # any per-iteration surprise with minimum blast radius:
+            # requeue what the failing iteration never touched (with
+            # nothing in flight: a row leaves its slot at rest)
+            self._land()
+            self._absorb_iteration_error(exc, progress)
+        self._iteration += 1
+        with self._phase("schedule", "serving.schedule"), self._lock:
+            self._update_gauges_locked()
+        return True
+
+    def _schedule(self):
+        """The locked part of an iteration: purge, evict, preempt, grow,
+        admit.  Returns ``(admitted, progress)``, or what :meth:`_iterate`
+        is to return at once: True when the pass has nothing to run, False
+        when the loop ends.  Raises
+        :class:`_LandFirst` when a row of the step in flight must leave
+        its slot; everything done before that is safe to do again."""
         with self._phase("schedule", "serving.schedule"), self._lock:
             if self._killed:
                 return False
@@ -1175,6 +1263,8 @@ class GenerationService:
             with _obs.span("serving.evict", cat="serving"):
                 self._evict_locked()
             if self._closed and not self._drain:
+                if self._flight is not None:
+                    raise _LandFirst
                 err = ServingClosedError("generation service shut down")
                 for r in list(self._waiting):
                     self._finish_locked(r, error=err)
@@ -1192,7 +1282,11 @@ class GenerationService:
             active = [r for r in self._slots if r is not None]
             if not active and not admitted:
                 # the pass emptied the queue (purged, or closed and
-                # drained), or its head cannot be admitted yet
+                # drained), or its head cannot be admitted yet; a step
+                # still in flight fed only rows that have ended since
+                # (an end-of-sequence id found a step late): nothing of
+                # it is wanted
+                self._flight = None
                 if self._closed and not self._waiting:
                     return False
                 self._update_gauges_locked()
@@ -1204,27 +1298,14 @@ class GenerationService:
             # untouched ones (the latter are requeued, never failed)
             progress = {r.rid: r.n_generated
                         for r in self._slots if r is not None}
-        try:
-            for req in admitted:
-                try:
-                    self._prefill(req)
-                except Exception as exc:  # noqa: BLE001 — isolate
-                    self._requeue_or_fail(req, exc)
-            running = [r for r in self._slots
-                       if r is not None and r.state == _RUNNING]
-            self._membership.append(
-                (self._iteration,
-                 tuple(sorted(r.rid for r in running))))
-            if running:
-                self._decode_isolated(running)
-        except Exception as exc:  # noqa: BLE001 — the loop must survive
-            # any per-iteration surprise with minimum blast radius:
-            # requeue what the failing iteration never touched
-            self._absorb_iteration_error(exc, progress)
-        self._iteration += 1
-        with self._phase("schedule", "serving.schedule"), self._lock:
-            self._update_gauges_locked()
-        return True
+        return admitted, progress
+
+    def _lead(self, r: _GenRequest) -> int:
+        """1 while ``r``'s newest token is that of the step in flight:
+        the host's ``ctx_len`` and ``n_generated`` are then one behind
+        what the device has written and sampled."""
+        f = self._flight
+        return int(f is not None and r.rid in f.rids)
 
     # -- scheduling (all _locked helpers hold self._lock) -------------------------
     def _purge_waiting_locked(self) -> None:
@@ -1251,11 +1332,15 @@ class GenerationService:
             if r is None:
                 continue
             if r.cancel_requested and r.state == _RUNNING:
+                if self._lead(r):
+                    raise _LandFirst
                 self._counts["cancelled"] += 1
                 self._release_slot_locked(i, reason=_CANCELLED)
             elif r.state in (_FINISHED, _FAILED, _CANCELLED):
                 self._release_slot_locked(i)
             elif r.expired(now):
+                if self._lead(r):
+                    raise _LandFirst
                 self._counts["expired"] += 1
                 self._release_slot_locked(i, error=DeadlineExceededError(
                     f"deadline exceeded after {r.n_generated} tokens"))
@@ -1428,6 +1513,8 @@ class GenerationService:
         it through the chunked-prefill rungs (tokens stay bit-identical:
         sampling is keyed on (seed, position) only)."""
         r = self._slots[i]
+        if self._lead(r):
+            raise _LandFirst
         r.seg("preempted", time.perf_counter())
         with _obs.span("serving.preempt", cat="serving",
                        args={"rid": r.rid, "ctx": r.ctx_len,
@@ -1505,7 +1592,7 @@ class GenerationService:
             # and the cap at prompt+max_new means single-token services
             # are byte-identical
             need = blocks_for(
-                min(r.ctx_len + self._iter_span,
+                min(r.ctx_len + self._lead(r) + self._iter_span,
                     r.prompt_len + r.max_new), cfg.block_size)
             while len(r.blocks) < need:
                 got = self._alloc_reclaiming(need - len(r.blocks))
@@ -1826,6 +1913,10 @@ class GenerationService:
                     _np.asarray([r.temperature], _np.float32),
                     _np.asarray([r.top_k], _np.int32),
                     _np.asarray([r.top_p], _np.float32))
+                if not resumed and off + take >= ctx:
+                    # the one read of a prefill: it waits for the chunks
+                    # before it too (and for a decode step in flight)
+                    next_tok = _synced(next_tok)
             r.rung_s[tb] = r.rung_s.get(tb, 0.0) \
                 + (time.perf_counter() - t_rung0)
         self._counts["prefill_tokens"] += sum(p[1] for p in plan)
@@ -1841,7 +1932,8 @@ class GenerationService:
         with self._phase("emit", "serving.emit"):
             self._emit_token(r, int(next_tok[0]))
 
-    def _decode_step(self, batch: List[_GenRequest]) -> None:
+    def _decode_step(self, batch: List[_GenRequest],
+                     ahead: bool = False) -> None:
         """One decode iteration over exactly the requests in ``batch``
         (slots outside it stay inactive: length 0, null-block table) —
         the full running set normally, a bisection subset when isolating
@@ -1855,7 +1947,12 @@ class GenerationService:
         multistep is enabled and the adaptive policy allows, k decode
         iterations run inside one scanned program; otherwise the classic
         single-token step.  All three paths emit identical token VALUES —
-        they differ only in how many tokens one device dispatch yields."""
+        they differ only in how many tokens one device dispatch yields.
+
+        ``ahead`` lets the single-token step of a service that runs no
+        other kind leave its tokens on the device (docs/generation.md
+        "the step in flight"); every other step is read before it
+        returns."""
         cfg = self._config
         if self._block_len:
             self._block_step(batch)
@@ -1869,17 +1966,20 @@ class GenerationService:
         if k >= 2:
             self._multistep_step(batch, k)
             return
-        self._single_step(batch)
+        self._single_step(batch, ahead and self._runs_ahead)
 
     def _build_step(self, batch: Sequence[_GenRequest], T: int, feed=None,
                     writes: Optional[int] = None, sampler: bool = True,
                     slots: Optional[int] = None,
-                    width: Optional[int] = None) -> _StepInputs:
+                    width: Optional[int] = None,
+                    lead=frozenset()) -> _StepInputs:
         """The host side of one model step, written once for every step
         kind.  The slots are walked ONCE: a row is a slot whose running
         request is in ``batch`` (slots outside it stay inactive: length
         0, null-block table).  A row feeds ``feed(request)`` — up to ``T``
-        token ids — at positions ``ctx_len ..`` and will write ``writes``
+        token ids — at positions ``ctx_len ..`` (one further for a row
+        whose id is in ``lead``: its newest token is that of the step in
+        flight, which wrote ``ctx_len``) and will write ``writes``
         positions there (default: as many as it feeds); before that write
         its span is made private, then its ``tokens (S, T)``,
         ``positions``, ``lengths`` and, with ``sampler``, its seed, the
@@ -1911,7 +2011,7 @@ class GenerationService:
             if r is None or r.state != _RUNNING or r.rid not in rids:
                 continue
             fed = feed(r)
-            n, c = len(fed), r.ctx_len
+            n, c = len(fed), r.ctx_len + (r.rid in lead)
             span = writes or n
             # copy-on-write append: a row about to scatter into a shared
             # block (refcount > 1) gets a private copy first — shared
@@ -1957,7 +2057,8 @@ class GenerationService:
                            knobs)
 
     def _participated(self, r: _GenRequest, t0: float, t1: float,
-                      running: int, **mode) -> None:
+                      running: int, iteration: Optional[int] = None,
+                      **mode) -> None:
         """Orca attribution: the ONE shared decode step fans out a child
         participation span per active request, so each trace still shows
         every step that advanced it."""
@@ -1965,29 +2066,92 @@ class GenerationService:
             _trace.record_event(
                 "serving.decode.participate", "serving", t0, t1,
                 ctx=r.trace,
-                args={"rid": r.rid, "iteration": self._iteration,
+                args={"rid": r.rid,
+                      "iteration": (self._iteration if iteration is None
+                                    else iteration),
                       "running": running, **mode,
                       "replica": self._replica_id})
 
-    def _single_step(self, batch: List[_GenRequest]) -> None:
+    def _single_step(self, batch: List[_GenRequest],
+                     ahead: bool = False) -> None:
         """The classic one-token decode program (T=1, one sampled token
-        per running row)."""
+        per running row).  With a step in flight this one is built from
+        counts alone — a row of that step sits one position further and
+        takes its token from the device (``carry_tokens``), a row that
+        joins brings its own from the host — and dispatched BEFORE that
+        step's tokens are read and emitted.  With ``ahead`` the step
+        stays in flight itself; otherwise it is read before returning."""
+        last = self._flight
+        lead = last.rids if last is not None else frozenset()
         with self._phase("build", "serving.decode.build"):
-            b = self._build_step(batch, 1,
-                                 lambda r: [r.seq_tokens[r.ctx_len]])
+            b = self._build_step(
+                batch, 1, lambda r: [0 if r.rid in lead
+                                     else r.seq_tokens[r.ctx_len]],
+                lead=lead)
+            tokens = b.tokens
+            if last is not None:
+                # whatever run() returned for the last step is what the
+                # rows that continue are fed, as it is what they are
+                # served
+                keep = _np.zeros(len(b.lengths), bool)
+                for i, r in b.rows:
+                    keep[i] = r.rid in lead
+                tokens = self._programs.carry_tokens(last.tokens, tokens,
+                                                     keep)
         t_step0 = time.perf_counter()
         with self._phase("step", "serving.decode",
                          args={"running": len(batch), "width": b.width,
                                "iteration": self._iteration}):
             next_tok, _ = self._programs.run("gen_decode", self._cache,
-                                             *b.operands)
-        t_step1 = time.perf_counter()
+                                             tokens, *b.operands[1:])
+            step = _Flight(
+                b, next_tok, t_step0, time.perf_counter(), self._iteration,
+                frozenset(r.rid for _, r in b.rows))
+            self._counts["steps_drained" if last is None
+                         else "steps_ahead"] += 1
+            # the reads, both inside this span: the last step's, which the
+            # device has had a whole host iteration to finish (if it
+            # fails, that step stays the one in flight and this one, fed
+            # from it, is dropped), and without ``ahead`` this step's own
+            read = None if last is None else _synced(last.tokens)
+            self._flight = step if ahead else None
+            own = None if ahead else _synced(next_tok)
+        if last is not None:
+            self._emit_flight(last, read)
+        if not ahead:
+            self._emit_flight(step, own)
+
+    def _land(self) -> None:
+        """Read and emit the step in flight, if there is one: whatever
+        needs the values on the host or the rows at rest calls this
+        first.  A read that fails costs no token: the rows keep the
+        host's view, and the next step feeds them from it again."""
+        f, self._flight = self._flight, None
+        if f is None:
+            return
+        try:
+            with self._phase("step", "serving.decode",
+                             args={"iteration": f.iteration}):
+                read = _synced(f.tokens)
+        except Exception as exc:  # noqa: BLE001 — the device's error
+            self._note_step_failure(exc)
+            return
+        self._emit_flight(f, read)
+
+    def _emit_flight(self, f: _Flight, next_tok) -> None:
+        """Emit a one-token step's tokens.  A row that ended since the
+        step was dispatched (an end-of-sequence id found a step late) or
+        was cancelled takes nothing: its token is dropped, and its K/V at
+        ``ctx_len`` is past what the prefix index is ever shown."""
         with self._phase("emit", "serving.emit"):
             traced = _trace.enabled()
-            for i, r in b.rows:
+            for i, r in f.step.rows:
+                if r.state != _RUNNING or r.cancel_requested:
+                    continue
                 r.decode_steps += 1
                 if traced:
-                    self._participated(r, t_step0, t_step1, len(batch))
+                    self._participated(r, f.t0, f.t1, len(f.step.rows),
+                                       iteration=f.iteration)
                 r.ctx_len += 1
                 r.mode_tokens["single"] = r.mode_tokens.get("single", 0) + 1
                 self._emit_token(r, int(next_tok[i]))
@@ -2104,6 +2268,7 @@ class GenerationService:
                                        mode="spec", proposed=s_i,
                                        accepted=acc)
         self._counts["spec_steps"] += 1
+        self._counts["steps_drained"] += 1
 
     # -- generation by diffusion over blocks (docs/generation.md) -----------------
     def _open_block(self, r: _GenRequest) -> None:
@@ -2211,6 +2376,7 @@ class GenerationService:
                         r.block_at[j] = r.block_pass
                 r.block_pass += 1
             counts["block_passes"] += 1
+            counts["steps_drained"] += 1
             counts["block_row_passes"] += len(rows)
             counts["block_ctx_tokens"] += int(b.positions[:, -1].sum()) \
                 + len(rows)
@@ -2297,6 +2463,7 @@ class GenerationService:
                     self._participated(r, t_step0, t_step1, len(batch),
                                        mode="multistep", k=int(k))
         self._counts["multistep_steps"] += 1
+        self._counts["steps_drained"] += 1
 
     # -- failure isolation (docs/fault_tolerance.md serving rows) -----------------
     def _note_step_failure(self, exc: BaseException) -> None:
@@ -2311,11 +2478,16 @@ class GenerationService:
         while every healthy slot still advances this iteration."""
         for attempt in (0, 1):
             try:
-                self._decode_step(running)
+                # only the first attempt may leave its step in flight
+                self._decode_step(running, ahead=attempt == 0)
                 self._consec_step_failures = 0
                 return
             except Exception as exc:  # noqa: BLE001 — isolate below
                 self._note_step_failure(exc)
+                # the retry and the bisection run with nothing in flight:
+                # the step before the failed one is read and emitted
+                # first, so no token of it is lost
+                self._land()
                 for r in running:  # attributed per request (wide event)
                     r.n_retries += 1
         self._bisect_decode(running)

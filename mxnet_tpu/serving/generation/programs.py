@@ -24,6 +24,14 @@ row a step) and ``offers`` (the program families it can run).
 :class:`~mxnet_tpu.parallel.sdar_moe.SdarMoeLM` (grouped-KV rotary block,
 sparse experts, generation by diffusion over blocks) the second.
 
+No step waits for the device: :meth:`GenerationPrograms.run` hands back
+what the jitted call returned, and its caller reads the sampled tokens
+(:func:`_synced`) when it needs their values — the engine after it has
+dispatched the NEXT decode step, whose ``tokens`` operand
+:meth:`GenerationPrograms.carry_tokens` merges on the device from the
+tokens still there and the host's (docs/generation.md "The step in
+flight"; one slot-sized program, no model in it, not a signature below).
+
 Each distinct ``(kind, batch, chunk, table-width)`` signature compiles
 exactly once.  Every kind runs through :meth:`GenerationPrograms._run`,
 whose lookup is fed through ``executor._note_cache`` so these programs
@@ -74,17 +82,26 @@ _SAMPLER_DTYPES = (_np.uint32, _np.uint32, _np.float32, _np.int32,
 
 
 def _step_args(tokens, positions, lengths, block_tables, *sampler):
-    """A step program's host arguments in their dtypes; ``sampler`` is
-    ``(seeds, counters, temperature, top_k, top_p)`` or nothing."""
-    return tuple(_np.asarray(a, _np.int32) for a in
-                 (tokens, positions, lengths, block_tables)) + tuple(
+    """A step program's arguments in their dtypes; ``sampler`` is
+    ``(seeds, counters, temperature, top_k, top_p)`` or nothing.
+    ``tokens`` already on the device (the last step's, carried onward:
+    :meth:`GenerationPrograms.carry_tokens`) go in as they are — a NumPy
+    conversion would wait for the step that produces them."""
+    import jax
+
+    if not isinstance(tokens, jax.Array):
+        tokens = _np.asarray(tokens, _np.int32)
+    return (tokens,) + tuple(_np.asarray(a, _np.int32) for a in
+                             (positions, lengths, block_tables)) + tuple(
         _np.asarray(a, dt) for a, dt in zip(sampler, _SAMPLER_DTYPES))
 
 
 def _synced(*outs):
     """The step's sampled tokens as NumPy arrays: the read that waits for
     the device, under its own span so that the wait is not mistaken for
-    host work (``serving.step.dispatch`` ends where the call returned)."""
+    host work (``serving.step.dispatch`` ends where the call returned).
+    :meth:`GenerationPrograms.run` leaves this read to its caller, which
+    may make it after it has dispatched the next step."""
     with _tracing.span("serving.step.sync", cat="serving"):
         arrays = tuple(_np.asarray(o) for o in outs)
     return arrays[0] if len(arrays) == 1 else arrays
@@ -111,6 +128,15 @@ def block_copy_pools(pools, src, dst):
         return jax.lax.dynamic_update_slice_in_dim(pool, blk, d, axis=1)
 
     return tuple(cp(pool) for pool in pools)
+
+
+def _carry(prev, tokens, keep):
+    """The next step's ``tokens (S, 1)``: the last step's sampled token
+    ``prev (S,)`` in the rows that ``keep``, the host's everywhere else.
+    No model in it: one slot-sized program a service."""
+    import jax.numpy as jnp
+
+    return jnp.where(keep[:, None], prev[:, None].astype(jnp.int32), tokens)
 
 
 def _model_step(params, pools, tokens, positions, lengths, block_tables,
@@ -286,6 +312,9 @@ class GenerationPrograms:
                 ("gen_block", _block_step),
                 ("gen_block_copy", block_copy_pools))}
         self._jits: Dict[tuple, object] = {}
+        import jax
+
+        self._carry_jit = jax.jit(_carry)   # traces nothing until called
         self._lock = threading.Lock()
         self._stats: Dict[tuple, Dict[str, int]] = {}
 
@@ -399,13 +428,28 @@ class GenerationPrograms:
 
     def run(self, kind: str, cache, tokens, positions, lengths,
             block_tables, seeds, counters, temperature, top_k, top_p):
-        """Execute one step; returns ``(next_tokens np(B,), last_logits)``.
+        """Dispatch one step; returns ``(next_tokens (B,), last_logits)``
+        as the jitted call returned them, on the device and not waited
+        for: the caller reads the tokens (:func:`_synced`) when it needs
+        their values, which for a decode step is after it has dispatched
+        the next one (docs/generation.md "the step in flight").
 
         ``cache`` is updated in place (donated pools swapped back)."""
-        next_tokens, last = self._run(kind, cache, _step_args(
+        return self._run(kind, cache, _step_args(
             tokens, positions, lengths, block_tables, seeds, counters,
             temperature, top_k, top_p))
-        return _synced(next_tokens), last
+
+    def carry_tokens(self, prev, tokens, keep):
+        """The ``tokens`` operand of a decode step dispatched while the
+        last one's ``prev (S,)`` tokens are still on the device: ``prev``
+        in the rows that ``keep (S,) bool``, the host's ``tokens (S, 1)``
+        in the others, merged on the device without a read.  ``prev`` is
+        the object :meth:`run` returned, whatever it is: one that is
+        already a NumPy array is merged here on the host."""
+        tokens, keep = _np.asarray(tokens, _np.int32), _np.asarray(keep, bool)
+        if isinstance(prev, _np.ndarray):
+            return _np.where(keep[:, None], prev[:, None], tokens)
+        return self._carry_jit(prev, tokens, keep)
 
     def run_verify(self, cache, tokens, positions, lengths, block_tables,
                    seeds, counters, temperature, top_k, top_p):

@@ -365,6 +365,105 @@ def test_prefill_error_requeues_then_fails_typed(params, monkeypatch):
     assert stats["counts"]["requeued"] == svc._max_error_requeues
 
 
+def test_preemption_and_failed_step_land_the_step_in_flight_first(
+        params, monkeypatch):
+    """With a step in flight, a preemption and an injected step failure
+    each read and emit that step before anything else happens to its
+    rows: every stream is the uncontended oracle's, every token reaches
+    its callback once, and no row leaves its slot with a token unread."""
+    from mxnet_tpu.serving.generation.engine import _LandFirst
+
+    # the 3rd decode invocation fails before its dispatch, step 2 unread
+    monkeypatch.setenv("TPUMX_FAULT_GEN_STEP_FAIL", "3")
+    injector().reset()
+    # 7 allocatable blocks of 8 positions; each request grows to 4 blocks
+    svc = GenerationService(params, CFG,
+                            _gc(max_slots=2, num_blocks=8, preemption=True),
+                            start=False)
+    svc.warmup()
+    preempt, calls = svc._preempt_slot_locked, []
+
+    def preempting(i, counter="preempted"):
+        r = svc._slots[i]
+        try:
+            preempt(i, counter)
+        except _LandFirst:
+            calls.append(("land first", r.rid, r.n_generated))
+            raise
+        calls.append((counter, r.rid, r.n_generated))
+
+    monkeypatch.setattr(svc, "_preempt_slot_locked", preempting)
+    note, failed_at = svc._note_step_failure, []
+    monkeypatch.setattr(svc, "_note_step_failure", lambda exc: (
+        failed_at.append(svc._flight is not None), note(exc))[1])
+    rs = np.random.RandomState(1)
+    prompts = [rs.randint(0, CFG.vocab, 20) for _ in range(2)]
+    seen = [[], []]
+    hs = [svc.submit(p, max_new_tokens=12,
+                     on_token=lambda rid, t, s=s: s.append(t))
+          for p, s in zip(prompts, seen)]
+    svc.start()
+    outs = [h.result(120) for h in hs]
+    time.sleep(0.12)
+    stats = svc.stats()
+    svc.stop()
+    for p, got, cb in zip(prompts, outs, seen):
+        assert got == cb == _greedy_oracle(params, p, 12)
+    c = stats["counts"]
+    assert c["preempted"] >= 1 and c["step_failures"] == 1
+    assert c["failed"] == 0 and c["tokens"] == 24
+    assert c["steps_ahead"] >= 1 and c["steps_drained"] >= 3
+    # the failure met a step in flight, and so did the first preemption:
+    # it was refused, the step landed, and the same row was preempted at
+    # rest with one token more
+    assert failed_at == [True]
+    first = calls.index(next(c for c in calls if c[0] == "land first"))
+    _, rid, n = calls[first]
+    assert calls[first + 1] == ("preempted", rid, n + 1)
+
+
+@pytest.mark.parametrize("times", [1, 2])
+def test_failed_read_of_the_step_in_flight_costs_no_token(
+        params, monkeypatch, times):
+    """The read of step n, made after step n + 1 was dispatched, raises —
+    once (the landing's second read succeeds) or twice (the step is given
+    up and its rows are fed from the host's view again): either way every
+    stream is the oracle's and nothing fails."""
+    from mxnet_tpu.serving.generation import engine as engine_mod
+
+    svc = GenerationService(params, CFG, _gc(max_slots=2), start=False)
+    svc.warmup()
+    synced, reads, raised = engine_mod._synced, [0], []
+
+    def failing(*outs):
+        f = svc._flight
+        if f is not None and outs[0] is f.tokens:
+            reads[0] += 1
+            if reads[0] >= 3 and len(raised) < times:
+                raised.append(reads[0])
+                raise RuntimeError("injected read failure")
+        return synced(*outs)
+
+    monkeypatch.setattr(engine_mod, "_synced", failing)
+    rs = np.random.RandomState(8)
+    prompts = [rs.randint(0, CFG.vocab, n) for n in (6, 15)]
+    seen = [[], []]
+    hs = [svc.submit(p, max_new_tokens=10,
+                     on_token=lambda rid, t, s=s: s.append(t))
+          for p, s in zip(prompts, seen)]
+    svc.start()
+    outs = [h.result(120) for h in hs]
+    time.sleep(0.12)
+    stats = svc.stats()
+    svc.stop()
+    assert len(raised) == times
+    for p, got, cb in zip(prompts, outs, seen):
+        assert got == cb == _greedy_oracle(params, p, 10)
+    c = stats["counts"]
+    assert c["step_failures"] == times and c["failed"] == 0
+    assert c["quarantined"] == 0 and c["tokens"] == 20
+
+
 # -- satellite: stream expiry under a stalled worker --------------------------------
 def test_result_timeout_expiry_while_worker_stalled(params):
     """GenerationStream.result(timeout=) raises TimeoutError when the

@@ -831,3 +831,249 @@ def test_a_program_kind_is_one_function_and_notes_the_parents_keys(
         (False, ("gen_block_copy" + q, ("lm",)), copy),
         (True, ("gen_block_copy" + q, ("lm",)), copy)]
     assert progs.compiled_signatures() == 5
+
+
+# -- the step in flight (docs/generation.md) ----------------------------------------
+_XLA_COMPILES = []
+
+
+def _xla_compiles():
+    """Backend compiles of this process since the first call, by jax's own
+    monitoring event (what the benchmark's ``compiles_after_warmup``
+    counts); the listener is registered once."""
+    if not _XLA_COMPILES:
+        import jax.monitoring as mon
+
+        _XLA_COMPILES.append(0)
+
+        def on_duration(event, secs, **_):
+            if event.endswith("backend_compile_duration"):
+                _XLA_COMPILES[0] += 1
+
+        mon.register_event_duration_secs_listener(on_duration)
+    return _XLA_COMPILES[0]
+
+
+def _mixed_prompts():
+    rs = np.random.RandomState(11)
+    shared = rs.randint(0, CFG.vocab, 16)
+    return {"greedy": rs.randint(0, CFG.vocab, 12),
+            "sampled": rs.randint(0, CFG.vocab, 7),
+            "eos": np.arange(3, 12) % CFG.vocab,
+            "cancel": rs.randint(0, CFG.vocab, 9),
+            "chunked": rs.randint(0, CFG.vocab, 30),
+            "shared_a": shared, "shared_b": shared,
+            "shared_long": np.concatenate([shared, [5, 6, 7]])}
+
+
+def _mixed_workload(params, ahead, eos, watch=None):
+    """Greedy and seeded-sampling rows on three slots; requests that end
+    by ``max_new_tokens``, by an end-of-sequence id and by a cancel from
+    their own callback; a chunked prompt and two prefix-cache hits (one a
+    whole cached prompt: copy-on-write) admitted mid-run from a callback.
+    Returns ``(streams, callback streams, finish reasons, stats)`` by
+    request name; ``watch(svc)`` runs on the warmed service before it
+    starts."""
+    svc = GenerationService(params, CFG,
+                            _gc(max_slots=3, num_blocks=48), start=False)
+    svc.warmup()
+    if not ahead:
+        svc._runs_ahead = False          # every step is read at once
+    if watch is not None:
+        watch(svc)
+    prompts = _mixed_prompts()
+    handles, seen = {}, {name: [] for name in prompts}
+
+    def submit(name, n, **kw):
+        def on_token(rid, tok):
+            seen[name].append(tok)
+            if name == "cancel" and len(seen[name]) == 4:
+                handles[name].cancel()
+            if name == "greedy" and len(seen[name]) == 3:
+                submit("chunked", 9)
+                submit("shared_b", 6)
+            if name == "greedy" and len(seen[name]) == 9:
+                submit("shared_long", 5, temperature=0.7, seed=21)
+        handles[name] = svc.submit(prompts[name], max_new_tokens=n,
+                                   on_token=on_token, **kw)
+
+    submit("greedy", 16)
+    submit("sampled", 12, temperature=0.8, top_k=10, seed=7)
+    submit("shared_a", 8)
+    submit("eos", 12, temperature=1.0, seed=3, eos_token=eos)
+    submit("cancel", 14)
+    svc.start()
+    try:
+        deadline = time.perf_counter() + 120
+        while len(handles) < len(prompts) and time.perf_counter() < deadline:
+            time.sleep(0.01)
+        out = {name: h.result(120) for name, h in handles.items()}
+        reasons = {name: h.finish_reason for name, h in handles.items()}
+        time.sleep(0.12)          # the loop retires the last slot and idles
+        return out, seen, reasons, svc.stats()
+    finally:
+        svc.stop()
+
+
+def _eos_of_the_mixed_workload(params):
+    """The "eos" row's sampled stream without an end-of-sequence id, and
+    the index of an id it first samples at a decode step, not at its
+    prefill."""
+    svc = GenerationService(params, CFG, _gc(max_slots=1))
+    try:
+        alone = svc.generate(_mixed_prompts()["eos"], max_new_tokens=12,
+                             temperature=1.0, seed=3, timeout=120)
+    finally:
+        svc.stop()
+    return alone, next(j for j in range(2, 12) if alone[j] not in alone[:j])
+
+
+def test_step_in_flight_serves_the_drained_streams(params):
+    """(a) token for token, whatever ends a request and whenever one joins:
+    the engine that reads step n after it dispatched step n + 1 serves
+    what the engine that reads every step at once serves."""
+    alone, j = _eos_of_the_mixed_workload(params)
+    got, got_cb, got_why, stats = _mixed_workload(params, True, alone[j])
+    ref, ref_cb, ref_why, ref_stats = _mixed_workload(params, False, alone[j])
+    assert got == ref and got_cb == ref_cb and got_why == ref_why
+    assert got_cb == got                      # every token delivered, once
+    assert got["eos"] == alone[:j + 1] and got_why["eos"] == "eos"
+    assert len(got["cancel"]) == 4 and got_why["cancel"] == "cancelled"
+    prompts = _mixed_prompts()
+    for name, n in (("greedy", 16), ("chunked", 9), ("shared_b", 6)):
+        assert got[name] == _greedy_oracle(params, prompts[name], n), name
+    for s in (stats, ref_stats):
+        assert s["counts"]["prefix_hits"] >= 2
+        assert s["counts"]["cow_copies"] >= 1
+        assert s["counts"]["failed"] == 0
+    c, rc = stats["counts"], ref_stats["counts"]
+    assert c["steps_ahead"] > c["steps_drained"] >= 1
+    assert rc["steps_ahead"] == 0 and rc["steps_drained"] > 0
+    assert c["tokens"] == rc["tokens"] == sum(len(t) for t in got.values())
+
+
+def test_next_step_is_dispatched_before_the_last_one_is_read(
+        params, monkeypatch):
+    """(b) with rows running and nothing to drain, step n + 1's dispatch
+    comes before step n's read; ``steps_ahead`` and ``steps_drained``
+    count every dispatched decode step between them."""
+    from mxnet_tpu.serving.generation import engine as engine_mod
+
+    log, steps = [], {}
+    synced = engine_mod._synced
+
+    def reading(*outs):
+        if id(outs[0]) in steps:
+            log.append(("read", steps[id(outs[0])]))
+        return synced(*outs)
+
+    monkeypatch.setattr(engine_mod, "_synced", reading)
+    svc = GenerationService(params, CFG, _gc(max_slots=3), start=False)
+    svc.warmup()
+    run = svc._programs.run
+    kept = []                            # ids stay unique while these live
+
+    def dispatching(kind, *args, **kw):
+        out = run(kind, *args, **kw)
+        if kind == "gen_decode":
+            kept.append(out[0])
+            steps[id(out[0])] = len(steps)
+            log.append(("dispatch", steps[id(out[0])]))
+        return out
+
+    monkeypatch.setattr(svc._programs, "run", dispatching)
+    rs = np.random.RandomState(2)
+    hs = [svc.submit(rs.randint(0, CFG.vocab, n), max_new_tokens=12)
+          for n in (5, 9, 14)]
+    svc.start()
+    try:
+        outs = [h.result(120) for h in hs]
+        time.sleep(0.12)
+        stats = svc.stats()
+    finally:
+        svc.stop()
+    assert all(len(o) == 12 for o in outs)
+    n = len(steps)
+    # all three are admitted in the first pass and end together: 11 decode
+    # steps, each but the first dispatched with the one before it unread
+    assert n == 11 and [e for e in log if e[0] == "read"] == [
+        ("read", i) for i in range(n)]
+    for i in range(n - 1):
+        assert log.index(("dispatch", i + 1)) < log.index(("read", i)), i
+    c = stats["counts"]
+    assert (c["steps_ahead"], c["steps_drained"]) == (n - 1, 1)
+    # the last pass dispatches nothing: it reads step n - 1
+    assert stats["iterations"] == n + 1
+
+
+def test_end_of_sequence_row_takes_nothing_after_it(params):
+    """(c) a row that ends on an end-of-sequence id is found a step late:
+    the token of its extra step is dropped, and the prefix index is shown
+    its context without the position that step wrote."""
+    alone, j = _eos_of_the_mixed_workload(params)
+    prompt = _mixed_prompts()["eos"]
+    svc = GenerationService(params, CFG, _gc(max_slots=2), start=False)
+    svc.warmup()
+    shown = []
+    insert = svc._prefix.insert
+    svc._prefix.insert = lambda toks, blocks: (
+        shown.append(list(toks)), insert(toks, blocks))[1]
+    fed = []                             # rows of every decode dispatch
+    run = svc._programs.run
+
+    def dispatching(kind, cache, tokens, positions, lengths, *rest):
+        if kind == "gen_decode":
+            fed.append([int(p) for p, n in zip(positions[:, 0], lengths)
+                        if n])
+        return run(kind, cache, tokens, positions, lengths, *rest)
+
+    svc._programs.run = dispatching
+    seen = []
+    other = svc.submit(np.arange(20) % CFG.vocab, max_new_tokens=16)
+    h = svc.submit(prompt, max_new_tokens=12, temperature=1.0, seed=3,
+                   eos_token=alone[j], on_token=lambda rid, t: seen.append(t))
+    svc.start()
+    try:
+        out = h.result(120)
+        other.result(120)
+        time.sleep(0.12)
+    finally:
+        svc.stop()
+    assert out == seen == alone[:j + 1] and h.finish_reason == "eos"
+    req = h._req
+    assert req.n_generated == j + 1 and req.decode_steps == j
+    assert req.ctx_len == len(prompt) + j
+    # the extra step did run: the row was fed at the position after its
+    # last token's, which nothing was emitted for
+    assert any(len(prompt) + j in row for row in fed)
+    mine = [t for t in shown if t[:len(prompt)] == list(prompt)]
+    assert mine and max(len(t) for t in mine) == len(prompt) + j
+    assert mine[-1] == list(prompt) + out[:-1]
+
+
+def test_warmup_compiles_the_parents_programs_and_nothing_after(
+        params, monkeypatch):
+    """(e) the step in flight adds no model program — ten for this
+    configuration, as on the parent commit: five prefill signatures, four
+    table widths of the decode step, the block copy — and a warmed service
+    compiles nothing under the mixed workload, by this repo's count and by
+    XLA's own."""
+    from mxnet_tpu.executor import compile_cache_stats
+
+    alone, j = _eos_of_the_mixed_workload(params)
+    monkeypatch.setenv("TPUMX_FREEZE_COMPILES", "1")
+    _xla_compiles()
+    marks = {}
+
+    def watch(svc):
+        kinds = sorted(k[0] for k in svc.compile_stats())
+        assert len(kinds) == 10 and kinds.count("gen_decode") == 4 \
+            and kinds.count("gen_prefill") == 5
+        marks["warm"] = (compile_cache_stats()["misses"], _xla_compiles())
+
+    got, _, _, stats = _mixed_workload(params, True, alone[j], watch=watch)
+    assert (compile_cache_stats()["misses"], _xla_compiles()) \
+        == marks["warm"]
+    assert stats["counts"]["steps_ahead"] > 0 \
+        and stats["counts"]["failed"] == 0
+    assert stats["compiled_signatures"] == 10
