@@ -12,11 +12,16 @@ The chip's own ragged dot walks small tiles of ``w`` (at the block step's
 shapes it read 24% of the HBM roofline, PERF.md PR 26: thousands of grid
 steps of a fraction of a microsecond each).  This kernel's unit of work is
 a (row tile, group) pair that intersect — at most ``M / tm + G - 1`` of
-them, the grid — and a step takes the group's WHOLE ``(K, N)`` matrix (3
-MB of bfloat16 at 2048 x 768) against the tile's ``tm`` rows, keeps the
-rows that belong to the group, and adds them into the tile's output, which
-stays resident while consecutive steps share the tile.  Steps are as long
-as their matrix takes to arrive.
+them, the grid's inner dimension — and a step takes the group's WHOLE
+``(K, N)`` matrix (3 MB of bfloat16 at 2048 x 768) against the tile's
+``tm`` rows, keeps the rows that belong to the group, and adds them into
+the tile's output, which stays resident while consecutive steps share the
+tile.  Steps are as long as their matrix takes to arrive.  A matrix too
+large to hold twice in fast memory (7168 x 2048 is 29 MB) is cut along
+``N`` into the fewest equal column tiles of whole 128-lane groups that
+stay under ``_W_TILE_BYTES`` — the grid's OUTER dimension, so a touched
+expert is still read once, a column tile a pass over the items, and only
+the few row tiles that hold assignments are read again each pass.
 
 Work items come from ``sizes`` alone (``_work_items``, plain ``jnp``): for
 item ``i`` its group, its row tile, and how many items are real; the rest
@@ -34,6 +39,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 __all__ = ["grouped_matmul"]
+
+_W_TILE_BYTES = 8 << 20     # the most a step's (K, tn) slice of a matrix holds
+
+
+def _column_tile(K: int, N: int, itemsize: int) -> int:
+    """Columns a grid step takes of a group's ``(K, N)`` matrix: all of
+    them where the matrix is under ``_W_TILE_BYTES``, else the widest
+    divisor of ``N`` in whole 128-lane groups that is."""
+    if K * N * itemsize <= _W_TILE_BYTES or N % 128:
+        return N
+    lanes = N // 128
+    fit = max(1, _W_TILE_BYTES // (K * 128 * itemsize))
+    return 128 * max(d for d in range(1, lanes + 1)
+                     if lanes % d == 0 and d <= fit)
 
 
 def _work_items(sizes, m: int, tm: int):
@@ -60,7 +79,7 @@ def _work_items(sizes, m: int, tm: int):
 
 def _gmm_kernel(group_ref, tile_ref, start_ref, end_ref, n_ref, x_ref, w_ref,
                 o_ref, *, tm: int):
-    i = pl.program_id(0)
+    i = pl.program_id(1)
     tile = tile_ref[i]
 
     @pl.when((i == 0) | (tile_ref[jnp.maximum(i - 1, 0)] != tile))
@@ -91,23 +110,25 @@ def _gmm_call(x, w, sizes, *, interpret):
     if m_pad != M:
         x = jnp.pad(x, ((0, m_pad - M), (0, 0)))
     group, tile, starts, ends, n_items = _work_items(sizes, m_pad, tm)
+    item = jnp.dtype(w.dtype).itemsize
+    tn = _column_tile(K, N, item)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(m_pad // tm + G - 1,),
-        in_specs=[pl.BlockSpec((tm, K), lambda i, g, t, *_: (t[i], 0)),
-                  pl.BlockSpec((1, K, N), lambda i, g, *_: (g[i], 0, 0))],
-        out_specs=pl.BlockSpec((tm, N), lambda i, g, t, *_: (t[i], 0)),
+        grid=(N // tn, m_pad // tm + G - 1),
+        in_specs=[pl.BlockSpec((tm, K), lambda j, i, g, t, *_: (t[i], 0)),
+                  pl.BlockSpec((1, K, tn),
+                               lambda j, i, g, *_: (g[i], 0, j))],
+        out_specs=pl.BlockSpec((tm, tn), lambda j, i, g, t, *_: (t[i], j)),
     )
-    item = jnp.dtype(w.dtype).itemsize
-    # double-buffered: a group's matrix, the row tile, the f32 output tile
-    need = 2 * (K * N * item + tm * K * jnp.dtype(x.dtype).itemsize
-                + tm * N * 4) + tm * N * 4
+    # double-buffered: a group's column tile, the row tile, the f32 output
+    need = 2 * (K * tn * item + tm * K * jnp.dtype(x.dtype).itemsize
+                + tm * tn * 4) + tm * tn * 4
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, N), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=int(min(100 << 20, need + (8 << 20)))),
         interpret=interpret,
         name="_gmm_call",

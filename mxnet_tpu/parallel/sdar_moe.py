@@ -39,7 +39,7 @@ from .transformer import paged_write_coords
 Params = Dict[str, jnp.ndarray]
 
 __all__ = ["SdarMoeConfig", "SdarMoeLM", "sdar_moe_init", "sdar_moe_decode",
-           "moe_grouped", "unmask_schedule"]
+           "moe_grouped", "expert_products", "unmask_schedule"]
 
 
 @dataclass(frozen=True)
@@ -147,33 +147,31 @@ def _rope(x, positions, theta):
     return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
 
 
-def moe_grouped(h, router, wg, wu, wd, top_k: int, norm_topk: bool = True,
-                experts_held: Optional[Tuple[int, int]] = None,
-                pallas: bool = False):
-    """The sparse expert layer, dropless: route every token of ``h``
-    (N, d) over ALL ``router.shape[1]`` experts, sort the ``N * top_k``
-    assignments by expert, and compute one grouped matrix product per
-    projection — ``pallas``: the kernel of ``ops/grouped_matmul.py`` (a
-    whole expert matrix a grid step: the few rows an expert gets in a
-    serving step are bound by reading its weights once); else
+def expert_products(h, w, e, wg, wu, wd,
+                    experts_held: Optional[Tuple[int, int]] = None,
+                    pallas: bool = False):
+    """The expert layer behind its router, dropless: given every token's
+    chosen experts ``e`` (N, k) — ids over ALL the layer's experts — and
+    their weights ``w`` (N, k), sort the ``N * k`` assignments by expert
+    and compute one grouped matrix product per projection — ``pallas``:
+    the kernel of ``ops/grouped_matmul.py`` (an expert's matrix a grid
+    step, or a column tile of it: the few rows an expert gets in a serving
+    step are bound by reading its weights once); else
     ``jax.lax.ragged_dot``.  No ``(tokens, E, F)`` intermediate, no
-    capacity, no dropped token.
+    capacity, no dropped token.  Whoever routes (softmax and top-k here,
+    sigmoid scores over groups in ``latent_moe.py``) is the caller.
 
     ``experts_held = (lo, hi)`` says which experts ``wg`` / ``wu`` / ``wd``
-    hold (default: all): assignments to others add nothing here — a chip
-    that holds a share of the experts computes its share of the result.
+    hold (default: as many as they hold, from 0): assignments to others add
+    nothing here — a chip that holds a share of the experts computes its
+    share of the result, and nothing stands in for the other chips.
 
-    Returns ``(y (N, d) float32, touched)``: ``touched`` counts the held
-    experts at least one token was routed to."""
-    N = h.shape[0]
-    E = router.shape[1]
-    lo, hi = experts_held if experts_held is not None else (0, E)
+    Returns ``(y (N, d) float32, sizes (held,) int32)``: ``sizes`` counts
+    the assignments each held expert got."""
+    N, top_k = e.shape
+    lo, hi = experts_held if experts_held is not None else (0, wg.shape[0])
     held = hi - lo
     assert wg.shape[0] == held
-    p = jax.nn.softmax(_mm(h, router), axis=-1)                # (N, E) f32
-    w, e = jax.lax.top_k(p, top_k)                             # (N, k)
-    if norm_topk:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
     local = e.reshape(-1) - lo
     mine = (local >= 0) & (local < held)
     # assignments to experts held elsewhere sort behind every group
@@ -194,6 +192,23 @@ def moe_grouped(h, router, wg, wu, wd, top_k: int, norm_topk: bool = True,
     ys = jnp.where(mine[order][:, None], ys * w.reshape(-1)[order][:, None],
                    0.0)
     y = jnp.zeros((N, h.shape[1]), jnp.float32).at[token].add(ys)
+    return y, sizes
+
+
+def moe_grouped(h, router, wg, wu, wd, top_k: int, norm_topk: bool = True,
+                experts_held: Optional[Tuple[int, int]] = None,
+                pallas: bool = False):
+    """This model's sparse layer: route every token of ``h`` (N, d) over
+    ALL ``router.shape[1]`` experts — softmax, top-k, renormalise — then
+    :func:`expert_products`.
+
+    Returns ``(y (N, d) float32, touched)``: ``touched`` counts the held
+    experts at least one token was routed to."""
+    p = jax.nn.softmax(_mm(h, router), axis=-1)                # (N, E) f32
+    w, e = jax.lax.top_k(p, top_k)                             # (N, k)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    y, sizes = expert_products(h, w, e, wg, wu, wd, experts_held, pallas)
     return y, jnp.sum(sizes > 0)
 
 

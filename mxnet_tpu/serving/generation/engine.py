@@ -435,13 +435,15 @@ class _Flight(NamedTuple):
     (docs/generation.md "the step in flight"): the rows it fed, its
     sampled tokens as :meth:`GenerationPrograms.run` returned them, the
     clock around its dispatch, the iteration its participation events
-    name, and the ids of its rows."""
+    name, the ids of its rows, and the counts (``aux``) of the programs
+    dispatched up to and with it that nobody has read yet."""
     step: _StepInputs
     tokens: object
     t0: float
     t1: float
     iteration: int
     rids: frozenset
+    aux: tuple = ()
 
 
 class _LandFirst(Exception):
@@ -644,6 +646,8 @@ class GenerationService:
             raise ValueError(
                 f"block_size {cfg.block_size} and max_len {model.max_len} "
                 f"must be multiples of the model's block length {L}")
+        # the cache is what the model's spec says: the classic K/V pair
+        # of folded heads, or the pools it names (latent attention: one)
         self._cache = PagedKVCache(
             num_blocks=cfg.num_blocks, block_size=cfg.block_size,
             kv_dtype=cfg.kv_dtype, **model.cache_spec())
@@ -683,10 +687,26 @@ class GenerationService:
             raise ValueError(
                 f"largest seq bucket {self._seq_buckets[-1]} exceeds the "
                 f"model's max prompt length {max_prompt}")
+        # the ladder's top is the longest prompt the service takes; the
+        # rungs a prompt is cut into stop at the longest chunk the model's
+        # prefill program takes, where it names one (its temporaries grow
+        # with the chunk) and prompts are chunked at all
+        self._prompt_buckets = self._seq_buckets
+        cap = getattr(model, "longest_chunk", None)
+        if cap and cfg.chunked_prefill:
+            self._seq_buckets = [b for b in self._seq_buckets if b <= cap] \
+                or self._seq_buckets[:1]
         # decode block-table widths: pow2 ladder up to the blocks needed to
         # address max_len positions (the cap itself kept, like batch_buckets)
         self._width_buckets = batch_buckets(
             blocks_for(model_cfg.max_len, cfg.block_size))
+        # a model whose kernels fetch live pages only, in prefill as in
+        # decode, pays nothing for a table's width: one width, the widest,
+        # and a program a chunk length instead of one a (length, width)
+        self._one_width = (getattr(model, "one_table_width", False)
+                           and self._programs.kernel == "paged")
+        if self._one_width:
+            self._width_buckets = self._width_buckets[-1:]
         # multi-token decoding (docs/generation.md "Speculative
         # decoding"): the verify chunk length Tk = s + 1 (pending token +
         # s drafts) is pow2-bucketed so warmup enumerates the full
@@ -773,6 +793,10 @@ class GenerationService:
                         # read (context + block, summed over rows), and
                         # the prefill chunks fed beside the block steps
                         "block_ctx_tokens": 0, "block_prefill_chunks": 0}
+        # counts a one-token model's PROGRAM makes and hands back with
+        # every step (``aux``; docs/observability.md), summed here when
+        # the step's tokens are read
+        self._counts.update(dict.fromkeys(getattr(model, "counters", ()), 0))
         self._peak_occupancy = 0.0
         # host microseconds of the loop by phase, from the phase spans' own
         # clock reads (written by the engine thread only)
@@ -882,7 +906,7 @@ class GenerationService:
                 f"prompt token ids must be in [0, {self._model_cfg.vocab})")
         # over-long prompts are rejected HERE (bucket_seq_len raises), the
         # enqueue-time contract the fixed-shape serving layer shares
-        bucket = bucket_seq_len(prompt.size, self._seq_buckets)
+        bucket = bucket_seq_len(prompt.size, self._prompt_buckets)
         max_new = int(max_new_tokens if max_new_tokens is not None
                       else cfg.max_new_tokens)
         if max_new < 1:
@@ -1752,7 +1776,7 @@ class GenerationService:
         chunked = cfg.chunked_prefill or force_chunked
         if not chunked or prompt_len <= rungs[0]:
             tb = bucket_seq_len(prompt_len, rungs)
-            return [(0, prompt_len, tb, blocks_for(tb, cfg.block_size))]
+            return [(0, prompt_len, tb, self._rung_width(tb))]
         chunks = []
         off = 0
         while off < prompt_len:
@@ -1766,8 +1790,14 @@ class GenerationService:
             off += take
         if len(chunks) == 1:  # exactly one rung: identical to legacy
             tb = bucket_seq_len(prompt_len, rungs)
-            return [(0, prompt_len, tb, blocks_for(tb, cfg.block_size))]
+            return [(0, prompt_len, tb, self._rung_width(tb))]
         return chunks
+
+    def _rung_width(self, tb: int) -> int:
+        """Table width of a prompt padded whole to its rung: the blocks
+        the rung spans, or the service's one width."""
+        return self._width_buckets[0] if self._one_width \
+            else blocks_for(tb, self._config.block_size)
 
     def _prefill_signatures(self):
         """Every (T, W) prefill signature the chunk planner can emit —
@@ -1778,14 +1808,13 @@ class GenerationService:
         through already-warmed rungs (the zero-recompile guarantee holds
         under ``TPUMX_FREEZE_COMPILES=1`` with preemption active)."""
         cfg = self._config
-        out = {(tb, blocks_for(tb, cfg.block_size))
-               for tb in self._seq_buckets}
+        out = {(tb, self._rung_width(tb)) for tb in self._seq_buckets}
         # a block-diffusion model prefills whole blocks only, every
         # context through the chunk walk, and never needs a cached
         # prompt's last logits
         step = self._block_len or 1
         if cfg.chunked_prefill:
-            for L in range(step, self._seq_buckets[-1] + 1, step):
+            for L in range(step, self._prompt_buckets[-1] + 1, step):
                 for (_, _, tb, w) in self._chunk_plan(L):
                     out.add((tb, w))
         if cfg.preemption or self._block_len:
@@ -1817,7 +1846,7 @@ class GenerationService:
             # fully cached, and fresh prompts are bounded by the ladder)
             tb0 = self._seq_buckets[0]
             for p in (() if self._block_len else
-                      range(bs, self._seq_buckets[-1] + 1, bs)):
+                      range(bs, self._prompt_buckets[-1] + 1, bs)):
                 out.add((tb0, bucket_batch(blocks_for(p - 1 + tb0, bs),
                                            self._width_buckets)))
         return sorted(out)
@@ -1917,6 +1946,7 @@ class GenerationService:
                     # the one read of a prefill: it waits for the chunks
                     # before it too (and for a decode step in flight)
                     next_tok = _synced(next_tok)
+                    self._count_aux(self._programs.take_aux())
             r.rung_s[tb] = r.rung_s.get(tb, 0.0) \
                 + (time.perf_counter() - t_rung0)
         self._counts["prefill_tokens"] += sum(p[1] for p in plan)
@@ -2106,7 +2136,8 @@ class GenerationService:
                                              tokens, *b.operands[1:])
             step = _Flight(
                 b, next_tok, t_step0, time.perf_counter(), self._iteration,
-                frozenset(r.rid for _, r in b.rows))
+                frozenset(r.rid for _, r in b.rows),
+                self._programs.take_aux())
             self._counts["steps_drained" if last is None
                          else "steps_ahead"] += 1
             # the reads, both inside this span: the last step's, which the
@@ -2120,6 +2151,16 @@ class GenerationService:
             self._emit_flight(last, read)
         if not ahead:
             self._emit_flight(step, own)
+
+    def _count_aux(self, auxes) -> None:
+        """Sum finished programs' counts into ``stats()["counts"]``: every
+        one was dispatched before a step whose tokens have been read, so
+        the read here waits for nothing."""
+        import jax
+
+        for aux in jax.device_get(auxes):       # one transfer for them all
+            for name, value in aux.items():
+                self._counts[name] += int(value)
 
     def _land(self) -> None:
         """Read and emit the step in flight, if there is one: whatever
@@ -2144,6 +2185,7 @@ class GenerationService:
         was cancelled takes nothing: its token is dropped, and its K/V at
         ``ctx_len`` is past what the prefix index is ever shown."""
         with self._phase("emit", "serving.emit"):
+            self._count_aux(f.aux)
             traced = _trace.enabled()
             for i, r in f.step.rows:
                 if r.state != _RUNNING or r.cancel_requested:

@@ -189,27 +189,38 @@ class BlockAllocator:
 
 
 class PagedKVCache:
-    """The device-side pool: K/V arrays plus the allocator that parcels
-    their blocks out to requests.
+    """The device-side pool: the arrays a model's ``cache_spec()`` asks
+    for plus the allocator that parcels their blocks out to requests.
+
+    A pool is ``(n_layers, num_blocks, block_size, width)``; ``pools``
+    names them and gives their minor widths.  The default is the classic
+    pair — ``(("k", n_heads * d_head), ("v", n_heads * d_head))`` — and a
+    model with another kind of state says so: latent attention keeps ONE
+    pool a layer, a token's normed latent and its rotated shared key side
+    by side (``(("latent", kv_lora_rank + rope_dim),)``,
+    docs/generation.md "Latent attention").  Allocator, prefix index,
+    copy-on-write and preemption work on block ids and never look inside.
 
     The arrays are owned functionally, as one tuple ``pools``: the engine
     threads it through its donated compiled programs and stores the
     returned (aliased) arrays back via :meth:`swap` — the pool is updated
     in place on device, and this object always points at the live copy
-    (``k``, ``v``, ``k_scale``, ``v_scale`` read its members).
+    (``k``, ``v``, ``k_scale``, ``v_scale`` read the classic pair's
+    members).
 
-    ``kv_dtype="int8"`` (docs/quantization.md) stores the pool QUANTIZED:
-    K/V become int8 with symmetric per-``(layer, block, head)`` scales in
-    ``k_scale``/``v_scale`` (``(n_layers, num_blocks, n_heads)`` f32,
-    riding through the same donated programs).  The scatter path
+    ``kv_dtype="int8"`` (docs/quantization.md) stores the classic pair
+    QUANTIZED: K/V become int8 with symmetric per-``(layer, block, head)``
+    scales in ``k_scale``/``v_scale`` (``(n_layers, num_blocks, n_heads)``
+    f32, riding through the same donated programs).  The scatter path
     quantizes each chunk's K/V in-program and both attention paths
     dequantize at read — the pool then costs ~half the bf16 bytes, which
     is the ~2x block-budget headline (:meth:`num_blocks_for_bytes`).
     """
 
-    def __init__(self, n_layers: int, n_heads: int, d_head: int,
-                 num_blocks: int, block_size: int, dtype=None,
-                 kv_dtype: Optional[str] = None):
+    def __init__(self, n_layers: int, n_heads: Optional[int] = None,
+                 d_head: Optional[int] = None, num_blocks: int = 2,
+                 block_size: int = 16, dtype=None,
+                 kv_dtype: Optional[str] = None, pools=None):
         import jax.numpy as jnp
 
         self.num_blocks = int(num_blocks)
@@ -219,16 +230,21 @@ class PagedKVCache:
         if kv_dtype not in (None, "int8"):
             raise ValueError(
                 f"kv_dtype must be None or 'int8', got {kv_dtype!r}")
+        if kv_dtype == "int8" and pools is not None:
+            raise ValueError("kv_dtype='int8' quantizes the K/V pair only; "
+                             f"this cache holds {[n for n, _ in pools]}")
         self.kv_dtype = kv_dtype
         # heads folded into the minor dim: a block is a lane-dense
         # (block_size, H*D) tile, the layout the paged kernel's blocks
         # need on the chip (docs/pallas.md "block-layout rule")
-        shape = (int(n_layers), self.num_blocks, self.block_size,
-                 int(n_heads) * int(d_head))
+        self.layout = _pool_layout(n_heads, d_head, pools)
         store = jnp.dtype(jnp.int8) if kv_dtype == "int8" else self.dtype
         # the device arrays as the ONE operand every program takes, donates
-        # and returns: (k, v), or (k, v, k_scale, v_scale) for the int8 pool
-        self.pools = (jnp.zeros(shape, store), jnp.zeros(shape, store))
+        # and returns: one per entry of the layout, then (int8 pool) the
+        # two scale arrays
+        self.pools = tuple(
+            jnp.zeros((int(n_layers), self.num_blocks, self.block_size, w),
+                      store) for _, w in self.layout)
         if kv_dtype == "int8":
             sshape = (int(n_layers), self.num_blocks, int(n_heads))
             # unwritten blocks carry scale 1: their (masked-out-of-
@@ -260,7 +276,7 @@ class PagedKVCache:
 
     @property
     def shape(self):
-        return tuple(self.k.shape)
+        return tuple(self.pools[0].shape)
 
     def blocks_for(self, n_positions: int) -> int:
         return blocks_for(n_positions, self.block_size)
@@ -274,9 +290,10 @@ class PagedKVCache:
         self.pools = tuple(pools)
 
     def snapshot_blocks(self, blocks: List[int]) -> Dict[str, "object"]:
-        """Device-bit snapshot of the given physical blocks (K, V and —
-        int8 pool — their scales) as host numpy arrays.  Test/debug
-        helper for the speculative-decoding rollback guarantee
+        """Device-bit snapshot of the given physical blocks — every pool
+        under its name (``k`` and ``v``, or what the model's spec named)
+        and, int8 pool, ``k_scale`` / ``v_scale`` — as host numpy arrays.
+        Test/debug helper for the speculative-decoding rollback guarantee
         (docs/generation.md "Speculative decoding"): shared prefix
         blocks must be bit-identical before and after a verify step
         that rejected drafts, because rejected writes only ever land in
@@ -284,37 +301,44 @@ class PagedKVCache:
         import numpy as np
 
         idx = np.asarray([int(b) for b in blocks], np.int32)
-        out = {"k": np.asarray(self.k[:, idx]),
-               "v": np.asarray(self.v[:, idx])}
+        names = [n for n, _ in self.layout]
         if self.quantized:
-            out["k_scale"] = np.asarray(self.k_scale[:, idx])
-            out["v_scale"] = np.asarray(self.v_scale[:, idx])
-        return out
+            names += ["k_scale", "v_scale"]
+        return {n: np.asarray(p[:, idx]) for n, p in zip(names, self.pools)}
 
     def nbytes(self) -> int:
         return sum(int(p.nbytes) for p in self.pools)
 
     @staticmethod
-    def bytes_per_block(n_layers: int, n_heads: int, d_head: int,
-                       block_size: int, dtype=None,
-                       kv_dtype: Optional[str] = None) -> int:
-        """Device bytes one pool block costs (K + V + scales)."""
+    def bytes_per_block(n_layers: int, n_heads: Optional[int] = None,
+                       d_head: Optional[int] = None, block_size: int = 16,
+                       dtype=None, kv_dtype: Optional[str] = None,
+                       pools=None) -> int:
+        """Device bytes one pool block costs (every pool + scales), by the
+        arguments of the constructor."""
         import jax.numpy as jnp
 
         item = 1 if kv_dtype == "int8" else \
             jnp.dtype(dtype if dtype is not None else jnp.float32).itemsize
-        per = 2 * n_layers * block_size * n_heads * d_head * item
+        per = n_layers * block_size * item * sum(
+            w for _, w in _pool_layout(n_heads, d_head, pools))
         if kv_dtype == "int8":
             per += 2 * n_layers * n_heads * 4  # f32 k/v scales
         return per
 
     @classmethod
-    def num_blocks_for_bytes(cls, pool_bytes: int, n_layers: int,
-                             n_heads: int, d_head: int, block_size: int,
-                             dtype=None,
-                             kv_dtype: Optional[str] = None) -> int:
-        """How many blocks a byte budget buys — the density comparison:
-        at identical ``pool_bytes`` the int8 pool's budget is ~2x the
-        bf16 one (scales cost ``8/(block_size*d_head)`` of the win)."""
-        return int(pool_bytes) // cls.bytes_per_block(
-            n_layers, n_heads, d_head, block_size, dtype, kv_dtype)
+    def num_blocks_for_bytes(cls, pool_bytes: int, *spec, **spec_kw) -> int:
+        """How many blocks a byte budget buys, the spec given as to
+        :meth:`bytes_per_block` — the density comparison: at identical
+        ``pool_bytes`` the int8 pool's budget is ~2x the bf16 one (scales
+        cost ``8/(block_size*d_head)`` of the win)."""
+        return int(pool_bytes) // cls.bytes_per_block(*spec, **spec_kw)
+
+
+def _pool_layout(n_heads, d_head, pools):
+    """``((name, minor width), ...)`` of a cache: what the spec names, or
+    the classic K/V pair of folded heads."""
+    if pools is None:
+        width = int(n_heads) * int(d_head)
+        return (("k", width), ("v", width))
+    return tuple((str(n), int(w)) for n, w in pools)

@@ -1,8 +1,9 @@
 """The generation engine's compiled model programs.
 
 A program kind is ONE traced function, whatever the pool: the cache's
-device arrays travel as one operand, ``pools`` — ``(k, v)``, or ``(k, v,
-k_scale, v_scale)`` for the int8 pool (docs/quantization.md) — that every
+device arrays travel as one operand, ``pools`` — ``(k, v)``, ``(k, v,
+k_scale, v_scale)`` for the int8 pool (docs/quantization.md), or whatever
+the model's ``cache_spec()`` named (latent attention: one pool) — that every
 kind takes after the parameters, donates, and returns last, so the decode
 loop updates the cache in place on device instead of copying
 ``O(num_blocks)`` memory every token.  The kinds: ``gen_prefill`` (B=1,
@@ -16,13 +17,18 @@ A model (:func:`as_model`) is an object with ``step(params, tokens,
 positions, lengths, pools, block_tables, *, attention_kernel,
 mp_mesh=None, call=None, want_logits=True) -> (logits, pools, aux)`` —
 ``pools`` the structure that came in, ``aux`` whatever else the program
-must hand back — its ``vocab``, ``max_len``, ``heads``, ``cache_spec()``
+must hand back (a one-token model: None, or a dict of scalar counts the
+engine sums into ``stats()["counts"]`` under the names in the model's
+``counters``) — its ``vocab``, ``max_len``, ``heads``, ``cache_spec()``
 (what :class:`PagedKVCache` is built from), ``block_len`` (0: one token a
 row a step) and ``offers`` (the program families it can run).
 :class:`~mxnet_tpu.parallel.transformer.TransformerLM` (GPT-2's block,
 ``transformer_lm_decode``) is the first,
 :class:`~mxnet_tpu.parallel.sdar_moe.SdarMoeLM` (grouped-KV rotary block,
-sparse experts, generation by diffusion over blocks) the second.
+sparse experts, generation by diffusion over blocks) the second,
+:class:`~mxnet_tpu.parallel.latent_moe.LatentMoeLM` (latent attention over
+one latent pool, a gated dense layer, sigmoid-routed experts with a shared
+one, of which the chip holds a share) the third.
 
 No step waits for the device: :meth:`GenerationPrograms.run` hands back
 what the jitted call returned, and its caller reads the sampled tokens
@@ -146,7 +152,7 @@ def _model_step(params, pools, tokens, positions, lengths, block_tables,
 
     from ...ops.sampling import sample_logits
 
-    logits, pools, _ = model.step(
+    logits, pools, aux = model.step(
         params, tokens, positions, lengths, pools, block_tables,
         attention_kernel=attention_kernel, mp_mesh=mp_mesh)
     # logits at the LAST VALID position of each row feed the sampler
@@ -157,7 +163,7 @@ def _model_step(params, pools, tokens, positions, lengths, block_tables,
                                axis=1)[:, 0, :]
     next_tokens = sample_logits(last, seeds, counters, temperature,
                                 top_k, top_p)
-    return next_tokens, last, pools
+    return next_tokens, last, aux, pools
 
 
 def _verify_step(params, pools, tokens, positions, lengths, block_tables,
@@ -315,6 +321,7 @@ class GenerationPrograms:
         import jax
 
         self._carry_jit = jax.jit(_carry)   # traces nothing until called
+        self._aux: list = []                # run()s' aux nobody took yet
         self._lock = threading.Lock()
         self._stats: Dict[tuple, Dict[str, int]] = {}
 
@@ -363,7 +370,7 @@ class GenerationPrograms:
 
     def _key(self, kind: str, cache, tokens=None, block_tables=None,
              k: Optional[int] = None) -> tuple:
-        sig = (("kv_pool", cache.shape, str(cache.k.dtype)),)
+        sig = (("kv_pool", cache.shape, str(cache.pools[0].dtype)),)
         if tokens is not None:
             sig = (("tokens", tuple(tokens.shape), "int32"),
                    ("block_tables", tuple(block_tables.shape), "int32")) + sig
@@ -432,12 +439,26 @@ class GenerationPrograms:
         as the jitted call returned them, on the device and not waited
         for: the caller reads the tokens (:func:`_synced`) when it needs
         their values, which for a decode step is after it has dispatched
-        the next one (docs/generation.md "the step in flight").
+        the next one (docs/generation.md "the step in flight").  What
+        else the model's step handed back (its ``aux``: a dict of counts
+        the program made, or None) waits, on the device too, for
+        :meth:`take_aux`: ``run`` itself returns the pair its callers and
+        their wrappers unpack (the benchmark's drivers and tests among
+        them).
 
         ``cache`` is updated in place (donated pools swapped back)."""
-        return self._run(kind, cache, _step_args(
+        next_tokens, last, aux = self._run(kind, cache, _step_args(
             tokens, positions, lengths, block_tables, seeds, counters,
             temperature, top_k, top_p))
+        if aux is not None:
+            self._aux.append(aux)
+        return next_tokens, last
+
+    def take_aux(self) -> tuple:
+        """The ``aux`` of every :meth:`run` since the last call, oldest
+        first, as the programs returned them (not waited for)."""
+        aux, self._aux = tuple(self._aux), []
+        return aux
 
     def carry_tokens(self, prev, tokens, keep):
         """The ``tokens`` operand of a decode step dispatched while the

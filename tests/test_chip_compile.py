@@ -421,3 +421,101 @@ def test_block_prefill_program_compiles_at_the_cells_shapes(one_chip,
     assert text.count(f"_paged_call_w{W}_t{T}_prefill") >= 2
     assert "151936]" not in text.replace("bf16[151936,2048]", "")  # no head
     assert _pool_makers(text, 2) <= _IN_PLACE
+
+
+# the dots-vlm1 configuration's cell (PERF.md section 4): hidden 7168, 128
+# heads, latent 512 + 64 cached 640 wide, 16 held experts of 2048 of the
+# router's 256, bfloat16 parameters and a bfloat16 latent pool, 256 slots,
+# ONE table width of 512 blocks of 16, prefill chunks of 512
+def _latent_cell(sds, n_layers, num_blocks=4096):
+    from mxnet_tpu.parallel import latent_moe as lm
+
+    cfg = lm.LatentMoeConfig(vocab_size=16160, num_hidden_layers=n_layers,
+                             first_k_dense_replace=1)
+    model = lm.LatentMoeLM(cfg, max_len=8192, experts_held=(0, 16))
+    params = {k: sds(s, jnp.bfloat16) for k, s in
+              lm.latent_moe_param_shapes(cfg, (0, 16)).items()}
+    (_, lanes), = model.cache_spec()["pools"]
+    assert lanes == 640            # 576 padded to whole 128-lane tiles
+    return model, params, sds((n_layers, num_blocks, BS, lanes), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("B,T", [(256, 1), (1, 512)],
+                         ids=["decode", "prefill"])
+def test_latent_kernel_compiles_at_the_cells_shapes(one_chip, B, T):
+    """The latent body on the whole layered pool at the cell's one table
+    width (a 512 KB table in scalar memory): 128 query rows a decode tile,
+    eight tokens x 128 heads a prefill tile."""
+    from mxnet_tpu.ops import latent_attention as la
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = _compile(
+        functools.partial(la._mla_call.__wrapped__, v_width=512, scale=0.1,
+                          interpret=False),
+        sds((B, 512), jnp.int32), sds((B,), jnp.int32), sds((1,), jnp.int32),
+        sds((B, T, 128, 576), jnp.float32), sds((B, T), jnp.int32),
+        sds((5, 4096, BS, 640), jnp.bfloat16))
+    name = "_mla_call_w512_decode" if T == 1 else "_mla_call_w512_t512_prefill"
+    assert "tpu_custom_call" in text and name in text
+
+
+def test_latent_pool_576_wide_is_refused(one_chip):
+    """Why the pool is 640 wide: the chip stores 576 lanes as 640 and its
+    compiler refuses a page copy of 576 (docs/generation.md "Latent
+    attention")."""
+    from mxnet_tpu.ops import latent_attention as la
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(
+            functools.partial(la._mla_call.__wrapped__, v_width=512,
+                              scale=0.1, interpret=False),
+            sds((8, 64), jnp.int32), sds((8,), jnp.int32),
+            sds((1,), jnp.int32), sds((8, 1, 128, 576), jnp.float32),
+            sds((8, 1), jnp.int32), sds((1, 256, BS, 576), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("M,K,N", [(2048, 7168, 2048), (2048, 2048, 7168),
+                                   (4096, 7168, 2048), (4096, 2048, 7168)])
+def test_tiled_grouped_matmul_compiles_at_the_cells_shapes(one_chip, M, K, N):
+    """The held experts' products of a decode step (256 rows x top-8) and
+    of a 512-token prefill chunk: 29 MB a matrix, a column tile a grid
+    step."""
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = _compile(
+        functools.partial(gm._gmm_call.__wrapped__, interpret=False),
+        sds((M, K), jnp.bfloat16), sds((16, K, N), jnp.bfloat16),
+        sds((16,), jnp.int32))
+    assert "tpu_custom_call" in text and "_gmm_call" in text
+
+
+def test_latent_decode_step_program_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch):
+    """``gen_decode`` as the service dispatches it (256 rows, one width),
+    at depth 2 (one dense and one expert layer: layers repeat): the latent
+    pool is updated in place, the kernels are there, the counts come back."""
+    from mxnet_tpu.serving.generation import programs as gp
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    model, params, pool = _latent_cell(sds, 2)
+    S, W = 256, 512
+    fn = jax.jit(functools.partial(gp._model_step, model=model,
+                                   attention_kernel="paged"),
+                 donate_argnums=(1,))
+    compiled = fn.lower(
+        params, (pool,), sds((S, 1), jnp.int32), sds((S, 1), jnp.int32),
+        sds((S,), jnp.int32), sds((S, W), jnp.int32), sds((S,), jnp.uint32),
+        sds((S,), jnp.uint32), sds((S,), jnp.float32), sds((S,), jnp.int32),
+        sds((S,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("_mla_call_w512_decode") >= 2
+    assert len(re.findall(r"%_gmm_call[\w.\-]* = f32\[2048,", text)) == 3
+    whole = f"= bf16[2,4096,{BS},640]"
+    makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
+              for ln in text.splitlines() if whole in ln}
+    assert makers <= _IN_PLACE
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5e9
