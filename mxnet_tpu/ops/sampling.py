@@ -8,17 +8,34 @@ mix of greedy / temperature / top-k / top-p requests sharing a decode batch
 neighbours.  The registry entries expose the same math as framework ops
 (scalar-attr form), with numpy-parity tests in tests/test_generation.py.
 
+Inside that one program `sample_logits` does only what its rows ask for:
+a `lax.switch` on :func:`sampler_body`, a scalar computed on the device
+from the step's own ``temperature`` / ``top_k`` / ``top_p`` arrays, picks
+one of three bodies (:data:`SAMPLER_BODIES`):
+
+- ``greedy`` — no row samples: ``argmax`` of the raw logits, and nothing
+  else (no sort, no noise, no softmax);
+- ``draw`` — some row samples and none of those filters: temperature and
+  the Gumbel-max draw, no sort;
+- ``filter`` — some sampling row has a top-k or top-p filter on: ONE
+  descending sort over the vocabulary, both thresholds read from it.
+
+A row's token does not depend on the body its batch took: a greedy row is
+the raw argmax in all three, and a row whose filters are off passes
+through ``filter`` unchanged to the bit.
+
 Conventions (vLLM/HF-compatible):
 - ``temperature <= 0`` means greedy (argmax of the raw logits; top-k/top-p
   are ignored, matching the usual serving API contract);
 - ``top_k <= 0`` or ``top_k >= vocab`` disables top-k; ties at the k-th
   logit are all kept (the mask is a value threshold, not a rank cut);
-- ``top_p >= 1`` disables nucleus filtering; the kept set is the smallest
-  prefix of the probability-sorted vocab whose mass reaches ``top_p``
-  (the first token is always kept, so ``top_p <= 0`` degenerates to top-1);
+- ``top_p >= 1`` disables nucleus filtering, exactly: such a row keeps
+  every logit, however its softmax rounds; otherwise the kept set is the
+  smallest prefix of the probability-sorted vocab whose mass reaches
+  ``top_p`` (the first token is always kept, so ``top_p <= 0`` degenerates
+  to top-1);
 - sampling is Gumbel-max over the filtered, temperature-scaled logits —
-  exactly categorical sampling, but expressible as one argmax so greedy and
-  stochastic rows share a single traced expression.
+  exactly categorical sampling, expressed as one argmax.
 """
 from __future__ import annotations
 
@@ -27,8 +44,10 @@ import jax.numpy as jnp
 
 from .registry import register
 
-__all__ = ["temperature_scale", "top_k_mask", "top_p_mask", "sample_logits",
-           "speculative_verify", "fold_keys", "block_unmask", "NEG_INF"]
+__all__ = ["temperature_scale", "top_k_mask", "top_p_mask",
+           "top_k_top_p_mask", "sample_logits", "sampler_body",
+           "SAMPLER_BODIES", "speculative_verify", "fold_keys",
+           "block_unmask", "NEG_INF"]
 
 #: same finite -inf stand-in the attention masks use (exp() underflows to
 #: exactly 0.0 in f32, and finite values keep XLA's max/where paths simple)
@@ -45,38 +64,70 @@ def temperature_scale(logits, temperature):
     return jnp.where(t > 0, logits / jnp.where(t > 0, t, 1.0), logits)
 
 
+def _sorted_desc(logits):
+    return -jnp.sort(-logits, axis=-1)
+
+
+def _top_k_thresh(sorted_desc, k):
+    """The k-th largest value of each row, (..., 1); the row's smallest
+    where ``k`` disables the filter, so that ``>=`` keeps everything."""
+    vocab = sorted_desc.shape[-1]
+    kk = jnp.asarray(k, jnp.int32)
+    kk = jnp.broadcast_to(kk, sorted_desc.shape[:-1])
+    kk = jnp.where((kk <= 0) | (kk > vocab), vocab, kk)
+    return jnp.take_along_axis(sorted_desc, (kk - 1)[..., None], axis=-1)
+
+
+def _top_p_thresh(sorted_desc, p):
+    """The smallest value of each row's nucleus, (..., 1); ``-inf`` where
+    ``p >= 1`` disables the filter."""
+    pp = jnp.asarray(p, jnp.float32)
+    pp = jnp.broadcast_to(pp, sorted_desc.shape[:-1])[..., None]
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    # keep while the EXCLUSIVE prefix mass is still < p (so the token that
+    # crosses the threshold is included), and always keep rank 0
+    exclusive = jnp.cumsum(probs, axis=-1) - probs
+    keep = (exclusive < pp) | (
+        jnp.arange(sorted_desc.shape[-1]) == 0)
+    count = jnp.sum(keep.astype(jnp.int32), axis=-1, keepdims=True)
+    thresh = jnp.take_along_axis(sorted_desc, count - 1, axis=-1)
+    return jnp.where(pp >= 1, -jnp.inf, thresh)
+
+
 def top_k_mask(logits, k):
     """Mask all but the top-k logits per row to :data:`NEG_INF`.
 
     ``k`` is a per-row int array (or scalar); ``k <= 0`` or ``k >= vocab``
     keeps the row unfiltered.  Ties with the k-th value are kept."""
     logits = jnp.asarray(logits, jnp.float32)
-    vocab = logits.shape[-1]
-    kk = jnp.asarray(k, jnp.int32)
-    kk = jnp.broadcast_to(kk, logits.shape[:-1])
-    kk = jnp.where((kk <= 0) | (kk > vocab), vocab, kk)
-    sorted_desc = -jnp.sort(-logits, axis=-1)
-    thresh = jnp.take_along_axis(sorted_desc, (kk - 1)[..., None], axis=-1)
+    thresh = _top_k_thresh(_sorted_desc(logits), k)
     return jnp.where(logits >= thresh, logits, NEG_INF)
 
 
 def top_p_mask(logits, p):
     """Nucleus filtering: keep the smallest probability-sorted prefix with
     cumulative mass >= ``p`` (per-row array or scalar); the argmax token is
-    always kept; ``p >= 1`` disables the filter."""
+    always kept; ``p >= 1`` disables the filter (the row is returned as it
+    came, whatever its tail's mass rounds to)."""
     logits = jnp.asarray(logits, jnp.float32)
-    pp = jnp.asarray(p, jnp.float32)
-    pp = jnp.broadcast_to(pp, logits.shape[:-1])[..., None]
-    sorted_desc = -jnp.sort(-logits, axis=-1)
-    probs = jax.nn.softmax(sorted_desc, axis=-1)
-    # keep while the EXCLUSIVE prefix mass is still < p (so the token that
-    # crosses the threshold is included), and always keep rank 0
-    exclusive = jnp.cumsum(probs, axis=-1) - probs
-    keep = (exclusive < pp) | (
-        jnp.arange(logits.shape[-1]) == 0)
-    count = jnp.sum(keep.astype(jnp.int32), axis=-1, keepdims=True)
-    thresh = jnp.take_along_axis(sorted_desc, count - 1, axis=-1)
+    thresh = _top_p_thresh(_sorted_desc(logits), p)
     return jnp.where(logits >= thresh, logits, NEG_INF)
+
+
+def top_k_top_p_mask(logits, k, p):
+    """``top_p_mask(top_k_mask(logits, k), p)``, bit for bit, with one
+    sort where that composition has two (per-row ``k`` and ``p``, or
+    scalars): the top-k mask is a value threshold, so the sorted
+    top-k-masked row is the sorted row with its tail replaced — the same
+    values in the same order a second sort would give — and the nucleus
+    is read from that."""
+    logits = jnp.asarray(logits, jnp.float32)
+    sorted_desc = _sorted_desc(logits)
+    thresh_k = _top_k_thresh(sorted_desc, k)
+    thresh_p = _top_p_thresh(
+        jnp.where(sorted_desc >= thresh_k, sorted_desc, NEG_INF), p)
+    return jnp.where(logits >= jnp.maximum(thresh_k, thresh_p), logits,
+                     NEG_INF)
 
 
 def fold_keys(seeds, counters):
@@ -91,25 +142,62 @@ def fold_keys(seeds, counters):
     )(seeds, counters)
 
 
+#: the bodies of :func:`sample_logits`, by the index :func:`sampler_body`
+#: gives
+SAMPLER_BODIES = ("greedy", "draw", "filter")
+
+
+def sampler_body(temperature, top_k, top_p, vocab):
+    """Which of :data:`SAMPLER_BODIES` a step with these per-row knobs
+    takes, as an int32 scalar: 0 when no row samples, 1 when some row
+    samples and none of those filters, 2 when a sampling row has ``0 <
+    top_k < vocab`` or ``top_p < 1``.  One function over JAX arrays (the
+    compiled program's ``lax.switch``) or NumPy arrays (the engine's
+    count of the steps by body: the same arrays, the same answer)."""
+    samples = temperature > 0
+    filters = samples & (((top_k > 0) & (top_k < vocab)) | (top_p < 1))
+    return samples.any().astype("int32") + filters.any().astype("int32")
+
+
 def sample_logits(logits, seeds, counters, temperature, top_k, top_p):
     """One traced sampling step over a batch of logit rows.
 
     logits (B, V); seeds/counters/temperature/top_k/top_p all (B,).
     Rows with ``temperature <= 0`` take the raw argmax (greedy); the rest
     apply top-k then top-p filtering, temperature, and Gumbel-max draw.
+    The step runs the body :func:`sampler_body` names, so it sorts only
+    when a sampling row filters and draws noise only when a row samples;
+    a row's token is the same whichever body its neighbours led to.
     Returns int32 token ids (B,).
     """
     logits = jnp.asarray(logits, jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    filtered = top_p_mask(top_k_mask(logits, top_k), top_p)
-    scaled = temperature_scale(filtered, temperature)
-    keys = fold_keys(seeds, counters)
-    gumbel = jax.vmap(
-        lambda kd, row: jax.random.gumbel(kd, row.shape))(keys, scaled)
-    sampled = jnp.argmax(scaled + gumbel, axis=-1).astype(jnp.int32)
-    t = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32),
-                         greedy.shape)
-    return jnp.where(t > 0, sampled, greedy)
+    rows = logits.shape[:-1]
+    temperature = jnp.broadcast_to(
+        jnp.asarray(temperature, jnp.float32), rows)
+    top_k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), rows)
+    top_p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), rows)
+
+    def greedy(logits):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def draw(logits, kept=None):
+        # Gumbel-max over the kept logits / temperature, a row's noise
+        # keyed on its (seed, counter) alone and drawn over the whole
+        # vocabulary; a greedy row beside it takes the raw argmax
+        scaled = temperature_scale(logits if kept is None else kept,
+                                   temperature)
+        gumbel = jax.vmap(
+            lambda kd, row: jax.random.gumbel(kd, row.shape))(
+                fold_keys(seeds, counters), scaled)
+        sampled = jnp.argmax(scaled + gumbel, axis=-1).astype(jnp.int32)
+        return jnp.where(temperature > 0, sampled, greedy(logits))
+
+    def filter_draw(logits):
+        return draw(logits, top_k_top_p_mask(logits, top_k, top_p))
+
+    return jax.lax.switch(
+        sampler_body(temperature, top_k, top_p, logits.shape[-1]),
+        (greedy, draw, filter_draw), logits)
 
 
 def speculative_verify(logits, fed_tokens, seeds, counters, temperature,

@@ -53,6 +53,7 @@ from ...base import getenv
 from ...fault.inject import injector as _fault_injector
 from ...observability import flight_recorder as _flight
 from ...observability import tracing as _trace
+from ...ops.sampling import SAMPLER_BODIES, sampler_body
 from ..batcher import (BACKPRESSURE_POLICIES, DeadlineExceededError,
                        QueueFullError, RequestShedError, ServingClosedError,
                        ServingError)
@@ -780,6 +781,12 @@ class GenerationService:
                         # tokens were read, and those dispatched with
                         # nothing in flight
                         "steps_ahead": 0, "steps_drained": 0,
+                        # calls of a sampling program (a decode, verify
+                        # or multistep step, a prefill chunk), by the body
+                        # their rows' knobs make its sampler take
+                        # (ops/sampling.sampler_body)
+                        **{f"sampler_steps_{b}": 0
+                           for b in SAMPLER_BODIES},
                         # generation by diffusion over blocks: program
                         # calls, rows fed over them, rows on their commit
                         # pass, tokens emitted at commits, and (from the
@@ -868,6 +875,15 @@ class GenerationService:
             "generation_draft_accepted_tokens_total",
             help="proposed draft tokens the target model accepted "
                  "(emitted bitwise as its own tokens)")
+        self._c_sampler_steps = [
+            reg.counter(
+                "serving_sampler_steps_total", labels={"body": b},
+                help="calls of a sampling program (decode, verify and "
+                     "multistep steps, prefill chunks), by the body of its "
+                     "sampler their rows select: greedy (argmax alone), "
+                     "draw (temperature and noise, no sort), filter (one "
+                     "sort for top-k / top-p)")
+            for b in SAMPLER_BODIES]
 
     # -- submission ---------------------------------------------------------------
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
@@ -1921,6 +1937,9 @@ class GenerationService:
             r.seg("prefix_reuse", now)
             now = time.perf_counter()
         r.seg("prefill", now)
+        knobs = (_np.asarray([r.temperature], _np.float32),
+                 _np.asarray([r.top_k], _np.int32),
+                 _np.asarray([r.top_p], _np.float32))
         for (off, take, tb, wp) in plan:
             tokens, positions, table = self._chunk_inputs(r, off, take, tb,
                                                           wp)
@@ -1938,10 +1957,8 @@ class GenerationService:
                     "gen_prefill", self._cache, tokens, positions,
                     _np.asarray([take], _np.int32), table,
                     _np.asarray([r.seed], _np.uint32),
-                    _np.asarray([ctx], _np.uint32),
-                    _np.asarray([r.temperature], _np.float32),
-                    _np.asarray([r.top_k], _np.int32),
-                    _np.asarray([r.top_p], _np.float32))
+                    _np.asarray([ctx], _np.uint32), *knobs)
+                self._count_sampler_step(*knobs)
                 if not resumed and off + take >= ctx:
                     # the one read of a prefill: it waits for the chunks
                     # before it too (and for a decode step in flight)
@@ -2083,8 +2100,19 @@ class GenerationService:
                 f"injected decode-step failure "
                 f"(TPUMX_FAULT_GEN_STEP_FAIL) at iteration "
                 f"{self._iteration}, batch rids {sorted(rids)}")
+        if batch and sampler:
+            self._count_sampler_step(temperature, top_k, top_p)
         return _StepInputs(rows, int(w), tokens, positions, lengths, tables,
                            knobs)
+
+    def _count_sampler_step(self, temperature, top_k, top_p) -> None:
+        """Count one sampling program call by the body its sampler takes:
+        the program decides from these same arrays, by the same
+        function."""
+        body = int(sampler_body(temperature, top_k, top_p,
+                                self._model.vocab))
+        self._counts[f"sampler_steps_{SAMPLER_BODIES[body]}"] += 1
+        self._c_sampler_steps[body].inc()
 
     def _participated(self, r: _GenRequest, t0: float, t1: float,
                       running: int, iteration: Optional[int] = None,

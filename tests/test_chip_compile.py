@@ -323,6 +323,58 @@ def test_decode_step_program_compiles_mp4(topo, monkeypatch):
     assert "all-reduce" in text     # the row-parallel projections' psum
 
 
+@pytest.mark.parametrize("mp", [1, 4])
+def test_sampling_step_program_compiles(topo, one_chip, monkeypatch, mp):
+    """The decode program as the engine dispatches it, the sampler behind
+    the model step (``programs._model_step``): the sampler's three bodies
+    are one conditional, and the program's only sort over the vocabulary
+    sits inside a branch of it — on one chip, and as a GSPMD program over
+    ``mp=4`` whose head is sharded over the vocabulary (the branch index
+    comes from replicated ``(S,)`` arrays: every shard takes the same
+    body)."""
+    from mxnet_tpu.parallel import transformer as tr
+    from mxnet_tpu.parallel.partition_rules import make_param_specs
+    from mxnet_tpu.serving.generation import programs as gp
+
+    monkeypatch.setenv("TPUMX_PALLAS", "1")
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    cfg = tr.TransformerConfig(vocab=50257, d_model=HD, n_heads=H,
+                               n_layers=2, d_ff=3072, max_len=1024)
+    shapes = jax.eval_shape(lambda: tr.transformer_lm_init(
+        cfg, jax.random.PRNGKey(0)))
+    if mp == 1:
+        mesh = None
+        sds = lambda shape, dt, *spec: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=one_chip)
+        specs = {}
+    else:
+        mesh = Mesh(topo.devices, ("mp",))
+        sds = lambda shape, dt, *spec: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dt, sharding=NamedSharding(mesh, P(*spec)))
+        specs = make_param_specs(tr.transformer_partition_rules(),
+                                 {k: v.shape for k, v in shapes.items()},
+                                 mesh)
+        assert "mp" in tuple(specs["tok_emb"])      # the head's vocabulary
+    params = {k: sds(v.shape, v.dtype, *specs.get(k, ()))
+              for k, v in shapes.items()}
+    pool = sds((cfg.n_layers, NB, BS, HD), jnp.float32,
+               None, None, None, "mp")
+    row = lambda dt: sds((SLOTS,), dt)  # noqa: E731
+    args = [params, (pool, pool), sds((SLOTS, 1), jnp.int32),
+            sds((SLOTS, 1), jnp.int32), row(jnp.int32),
+            sds((SLOTS, 64), jnp.int32), row(jnp.uint32), row(jnp.uint32),
+            row(jnp.float32), row(jnp.int32), row(jnp.float32)]
+    step = functools.partial(gp._model_step, model=tr.TransformerLM(cfg),
+                             attention_kernel="paged", mp_mesh=mesh)
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        *args).compile().as_text()
+    assert len(re.findall(r" conditional\(", text)) == 1
+    sorts = [ln for ln in text.splitlines() if re.search(r" sort\(", ln)]
+    assert len(sorts) == 1 and "cond/branch_2_fun" in sorts[0]
+    entry = text[text.index("\nENTRY "):]
+    assert not re.search(r" sort\(", entry)
+
+
 # the sdar-30b-a3b configuration's cell (PERF.md section 4): hidden 2048,
 # 32 query heads over 4 KV heads of 128, 128 experts of 768 top-8,
 # vocabulary 151,936, 6 layers, bfloat16 parameters and a bfloat16 pool of

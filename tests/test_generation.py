@@ -166,6 +166,249 @@ def test_sampling_registry_ops():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+# -- the sampler's three bodies (ops/sampling.py) -----------------------------------
+def _two_sort_masks(logits, k, p):
+    """The oracle: top-k then top-p as the sampler composed them before it
+    had bodies, a sort over the vocabulary each (``p >= 1`` returns the
+    top-k mask as it is: the documented bypass)."""
+    logits = jnp.asarray(logits, jnp.float32)
+    vocab = logits.shape[-1]
+    kk = jnp.broadcast_to(jnp.asarray(k, jnp.int32), logits.shape[:-1])
+    kk = jnp.where((kk <= 0) | (kk > vocab), vocab, kk)
+    sorted_desc = -jnp.sort(-logits, axis=-1)
+    thresh = jnp.take_along_axis(sorted_desc, (kk - 1)[..., None], axis=-1)
+    top_k = jnp.where(logits >= thresh, logits, smp.NEG_INF)
+
+    pp = jnp.broadcast_to(jnp.asarray(p, jnp.float32),
+                          logits.shape[:-1])[..., None]
+    sorted_desc = -jnp.sort(-top_k, axis=-1)
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    exclusive = jnp.cumsum(probs, axis=-1) - probs
+    keep = (exclusive < pp) | (jnp.arange(vocab) == 0)
+    count = jnp.sum(keep.astype(jnp.int32), axis=-1, keepdims=True)
+    thresh = jnp.take_along_axis(sorted_desc, count - 1, axis=-1)
+    top_p = jnp.where(top_k >= thresh, top_k, smp.NEG_INF)
+    return np.asarray(jnp.where(pp >= 1, top_k, top_p))
+
+
+def _tied_logits(seed, rows=6, vocab=48):
+    """Random logits on a grid of 0.5, so that most values repeat, with a
+    run of equal values planted around every rank a filter can cut at."""
+    rs = np.random.RandomState(seed)
+    logits = (np.round(rs.randn(rows, vocab) * 4) / 2).astype(np.float32)
+    logits[1, :8] = logits[1].max() + 1.0           # eight tied maxima
+    logits[2, 3:9] = np.sort(logits[2])[-4]         # ties at the 4th value
+    logits[3] = 0.25                                # a flat row
+    return logits
+
+
+_SAMPLER_VOCAB = 48
+
+
+@pytest.mark.parametrize("p", [0.0, 0.5, 0.9, 1.0])
+@pytest.mark.parametrize("k", [0, 1, 4, _SAMPLER_VOCAB, _SAMPLER_VOCAB + 1])
+def test_one_sort_masks_are_the_two_sort_composition(k, p):
+    """(a) body ``filter``'s masks, from ONE sort, equal top-k then top-p
+    with a sort each, bit for bit, ties included."""
+    logits = _tied_logits(17 + k)
+    want = _two_sort_masks(logits, k, p)
+    np.testing.assert_array_equal(
+        np.asarray(smp.top_k_top_p_mask(logits, k, p)), want)
+    # per-row knobs: every row its own, the off values among them
+    ks = np.asarray([k, 0, 4, k, 1, _SAMPLER_VOCAB + 1], np.int32)
+    ps = np.asarray([p, p, 0.9, 1.0, p, 0.5], np.float32)
+    np.testing.assert_array_equal(
+        np.asarray(smp.top_k_top_p_mask(logits, ks, ps)),
+        _two_sort_masks(logits, ks, ps))
+    # the masks one at a time keep their results
+    np.testing.assert_array_equal(np.asarray(smp.top_k_mask(logits, k)),
+                                  _two_sort_masks(logits, k, 1.0))
+    np.testing.assert_array_equal(np.asarray(smp.top_p_mask(logits, p)),
+                                  _two_sort_masks(logits, 0, p))
+
+
+def _rounding_tail_row(vocab=_SAMPLER_VOCAB):
+    """Two likely tokens and a tail whose whole mass is ~1e-7: a tail
+    token's exclusive prefix mass rounds to 1.0, so ``exclusive < 1.0``
+    would cut what ``top_p = 1`` must keep."""
+    row = np.full(vocab, -10.0, np.float32)
+    row[[5, 11]] = 10.0
+    return row
+
+
+def test_top_p_one_keeps_a_tail_whose_mass_rounds_to_one():
+    row = _rounding_tail_row()[None]
+    sorted_desc = -np.sort(-row, axis=-1)
+    probs = np.asarray(jax.nn.softmax(jnp.asarray(sorted_desc), axis=-1))
+    assert (np.cumsum(probs, axis=-1) - probs)[0, -1] >= 1.0   # the case
+    np.testing.assert_array_equal(np.asarray(smp.top_p_mask(row, 1.0)), row)
+    np.testing.assert_array_equal(
+        np.asarray(smp.top_k_top_p_mask(row, 0, 1.0)), row)
+    cut = np.asarray(smp.top_p_mask(row, 0.999))
+    assert (cut > smp.NEG_INF / 2).sum() == 2
+
+
+# the row under test -> (temperature, top_k, top_p); the hot temperature
+# makes the rounding tail likely once the filter has let it through
+_SAMPLER_ROWS = {
+    "temperature": (0.8, 0, 1.0),
+    "top_p_one_rounding_tail": (50.0, 0, 1.0),
+    "top_k": (0.9, 4, 1.0),
+    "top_k_and_top_p": (1.3, 12, 0.9),
+}
+# its neighbours -> their (temperature, top_k, top_p), and the body the
+# batch then takes when the row itself has no filter on
+_SAMPLER_NEIGHBOURS = {
+    "none": ([], "draw"),
+    "greedy": ([(0.0, 3, 0.5), (0.0, 0, 1.0)], "draw"),
+    "temperature": ([(0.7, 0, 1.0), (1.5, _SAMPLER_VOCAB, 1.0)], "draw"),
+    "top_p": ([(0.0, 0, 1.0), (0.7, 0, 0.9)], "filter"),
+}
+
+
+@pytest.mark.parametrize("neighbours", sorted(_SAMPLER_NEIGHBOURS))
+@pytest.mark.parametrize("row", sorted(_SAMPLER_ROWS))
+def test_sampled_token_is_its_rows_alone_whatever_body_the_batch_takes(
+        row, neighbours):
+    """(b) a sampling row's token is Gumbel-max over ITS filtered, scaled
+    logits under ITS (seed, position) key — the same under ``draw`` and
+    ``filter``, alone and beside greedy, sampling and filtering rows; a
+    greedy neighbour's is the raw argmax beside any of them."""
+    t, k, p = _SAMPLER_ROWS[row]
+    others, body = _SAMPLER_NEIGHBOURS[neighbours]
+    rs = np.random.RandomState(23)
+    logits = (np.round(rs.randn(1 + len(others), _SAMPLER_VOCAB) * 4)
+              / 2).astype(np.float32)
+    if row == "top_p_one_rounding_tail":
+        logits[0] = _rounding_tail_row()
+    knobs = [(t, k, p)] + others
+    ts, ks, ps = (np.asarray(a, dt) for a, dt in zip(
+        zip(*knobs), (np.float32, np.int32, np.float32)))
+    if k == 0 and p == 1.0:
+        assert smp.SAMPLER_BODIES[int(smp.sampler_body(
+            ts, ks, ps, _SAMPLER_VOCAB))] == body
+    seeds = np.arange(40, 40 + len(knobs), dtype=np.uint32)
+    tails = []
+    for counter in range(1, 9):
+        counters = np.full(len(knobs), counter, np.uint32)
+        got = np.asarray(smp.sample_logits(logits, seeds, counters, ts, ks,
+                                           ps))
+        key = jax.random.fold_in(jax.random.PRNGKey(40), counter)
+        gumbel = jax.random.gumbel(key, (_SAMPLER_VOCAB,))
+        kept = _two_sort_masks(logits[:1], k, p)[0]
+        want = int(jnp.argmax(jnp.asarray(kept) / np.float32(t) + gumbel))
+        assert got[0] == want
+        tails.append(logits[0, want] < 0)
+        for j, (tj, _, _) in enumerate(others, 1):
+            if tj <= 0:
+                assert got[j] == int(np.argmax(logits[j]))
+    if row == "top_p_one_rounding_tail":
+        assert any(tails)       # the tail is drawn from, in every body
+
+
+def _count_sorts(jaxpr, in_cond=False):
+    """(sort equations outside any conditional, inside one) of a jaxpr,
+    walked through every sub-jaxpr an equation carries."""
+    outside = inside = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            outside, inside = (outside + (not in_cond), inside + in_cond)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    o, i = _count_sorts(
+                        sub, in_cond or eqn.primitive.name == "cond")
+                    outside, inside = outside + o, inside + i
+    return outside, inside
+
+
+@pytest.mark.parametrize("kind", ["gen_decode", "gen_verify",
+                                  "gen_multistep"])
+def test_a_sampling_program_sorts_once_and_only_in_a_branch(params, kind,
+                                                             monkeypatch):
+    """(c) a sampling program holds ONE sort over the vocabulary, inside a
+    conditional's branch (two, unconditional, before the bodies); the
+    greedy branch of the same program has none."""
+    import functools
+
+    from mxnet_tpu.serving.generation import programs as gp
+
+    monkeypatch.setenv("TPUMX_PALLAS", "0")
+    progs = gp.GenerationPrograms(params, CFG)
+    cache = PagedKVCache(num_blocks=16, block_size=8, n_layers=CFG.n_layers,
+                         n_heads=CFG.n_heads, d_head=CFG.d_head,
+                         dtype=jnp.float32)
+    S, T = 3, {"gen_decode": 1, "gen_verify": 4}.get(kind)
+    z = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
+    shape = (S, T) if T else (S,)
+    fn, _ = progs._kinds[kind]
+    kw = dict(progs._step_kw, **({} if T else {"k": 4}))
+    args = (progs._params, cache.pools, z(*shape), z(*shape), z(S), z(S, 4),
+            z(S).astype(np.uint32), z(S).astype(np.uint32),
+            z(S).astype(np.float32), z(S), np.ones(S, np.float32))
+    step = functools.partial(fn, **kw)
+    assert _count_sorts(jax.make_jaxpr(step)(*args).jaxpr) == (0, 1)
+    text = jax.jit(step).lower(*args).as_text()
+    assert text.count("stablehlo.sort") == 1 and "stablehlo.case" in text
+
+
+def test_greedy_step_returns_the_first_of_tied_maxima():
+    """(c) an all-greedy call is ``argmax`` of the raw logits: the first
+    of the tied, whatever ``top_k`` / ``top_p`` the rows carry."""
+    logits = _tied_logits(29)
+    n = len(logits)
+    assert (logits[1] == logits[1].max()).sum() == 8
+    got = np.asarray(smp.sample_logits(
+        logits, np.arange(n, dtype=np.uint32), np.full(n, 3, np.uint32),
+        np.zeros(n, np.float32), np.full(n, 4, np.int32),
+        np.full(n, 0.5, np.float32)))
+    np.testing.assert_array_equal(got, np.argmax(logits, axis=-1))
+    assert got[1] == 0 and got[3] == 0
+
+
+def _sampler_steps(where):
+    if isinstance(where, dict):
+        return {b: where["counts"][f"sampler_steps_{b}"]
+                for b in smp.SAMPLER_BODIES}
+    text = obs.registry().to_prometheus()
+    return {b: float(next(
+        line.rsplit(" ", 1)[1] for line in text.splitlines()
+        if line.startswith(f'serving_sampler_steps_total{{body="{b}"}}')))
+        for b in smp.SAMPLER_BODIES}
+
+
+@pytest.mark.parametrize("traffic", ["greedy", "mixed"])
+def test_sampler_steps_are_counted_by_body(params, traffic):
+    """(d) ``stats()["counts"]["sampler_steps_*"]`` and
+    ``serving_sampler_steps_total{body=...}``: a greedy-only service
+    counts ``greedy`` steps alone; one that is sent every kind of request
+    counts each body, as often as its programs were called."""
+    svc = GenerationService(params, CFG, _gc(max_slots=1), start=False)
+    svc.warmup()
+    assert sum(_sampler_steps(svc.stats()).values()) == 0   # not warm-up's
+    before = _sampler_steps("registry")
+    svc.start()
+    try:
+        prompt = np.arange(1, 8)
+        svc.generate(prompt, max_new_tokens=5, timeout=120)
+        if traffic == "mixed":
+            svc.generate(prompt, max_new_tokens=4, temperature=0.8, seed=2,
+                         top_k=CFG.vocab, timeout=120)
+            svc.generate(prompt, max_new_tokens=3, temperature=0.8, seed=2,
+                         top_p=0.9, timeout=120)
+            svc.generate(prompt, max_new_tokens=2, temperature=0.8, seed=2,
+                         top_k=5, timeout=120)
+    finally:
+        svc.stop()
+    # a request of n tokens: its prefill chunk and n - 1 decode steps
+    want = {"greedy": 5, "draw": 0, "filter": 0} if traffic == "greedy" \
+        else {"greedy": 5, "draw": 4, "filter": 5}
+    assert _sampler_steps(svc.stats()) == want
+    after = _sampler_steps("registry")
+    assert {b: after[b] - before[b] for b in want} == want
+
+
 # -- satellite/acceptance: paged-cache correctness ----------------------------------
 @pytest.mark.parametrize("compute_dtype", [None, "bfloat16"])
 def test_decode_with_cache_matches_full_apply(params, compute_dtype):

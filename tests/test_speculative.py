@@ -121,6 +121,42 @@ def test_speculative_verify_numpy_parity():
     np.testing.assert_array_equal(np.asarray(accepted), [3, 0, 1])
 
 
+# the verify batch's knobs -> the body its B x T rows take at once
+_VERIFY_MIXES = {
+    "greedy": ([0.0, 0.0, 0.0], [0, 4, 0], [1.0, 1.0, 0.5]),
+    "draw": ([0.0, 0.9, 0.7], [0, 13, 0], [1.0, 1.0, 1.0]),
+    "filter": ([0.8, 0.9, 0.7], [3, 5, 0], [1.0, 0.95, 0.9]),
+    "filter_beside_greedy_and_draw": ([0.0, 0.9, 0.7], [0, 0, 4],
+                                      [1.0, 1.0, 0.9]),
+}
+
+
+@pytest.mark.parametrize("mix", sorted(_VERIFY_MIXES))
+def test_verify_targets_are_the_target_only_stream_in_every_body(mix):
+    """Whatever body the verify step's B x T rows take together, each
+    target token is the one a single-row, single-position call — the
+    target-only stream's own step, which may take another body — gives at
+    that (seed, position)."""
+    rs = np.random.RandomState(19)
+    B, T, V = 3, 4, 13
+    logits = (np.round(rs.randn(B, T, V) * 4) / 2).astype(np.float32)
+    seeds = np.array([5, 6, 7], np.uint32)
+    counters = np.array([10, 3, 21], np.uint32)
+    temp, top_k, top_p = (np.asarray(a, dt) for a, dt in zip(
+        _VERIFY_MIXES[mix], (np.float32, np.int32, np.float32)))
+    assert smp.SAMPLER_BODIES[int(smp.sampler_body(
+        temp, top_k, top_p, V))] == mix.split("_")[0]
+    target, _ = smp.speculative_verify(
+        logits, np.zeros((B, T), np.int32), seeds, counters, temp, top_k,
+        top_p, np.full(B, T, np.int32))
+    for b in range(B):
+        for t in range(T):
+            alone = smp.sample_logits(
+                logits[b:b + 1, t], seeds[b:b + 1], counters[b:b + 1] + t,
+                temp[b:b + 1], top_k[b:b + 1], top_p[b:b + 1])
+            assert int(target[b, t]) == int(alone[0]), (b, t)
+
+
 def test_speculative_verify_t1_degenerates_to_plain_step():
     rs = np.random.RandomState(3)
     logits = rs.randn(2, 1, 9).astype(np.float32)
@@ -202,6 +238,10 @@ def test_spec_greedy_bitwise_matches_oracle_across_membership(params):
     spec = stats["speculative"]
     assert spec["spec_steps"] >= 1 and spec["proposed_tokens"] >= 1
     assert stats["decode_mode"] == "spec"
+    # a greedy draft verify never leaves the sampler's greedy body
+    counts = stats["counts"]
+    assert counts["sampler_steps_greedy"] >= spec["spec_steps"]
+    assert counts["sampler_steps_draw"] == counts["sampler_steps_filter"] == 0
     # per-request wide-event fields surface on the stream handle too
     for st in req_stats:
         assert st["decode_mode"] in ("spec", "single")
@@ -235,6 +275,9 @@ def test_spec_sampled_bitwise_matches_baseline(params):
     base, st_off = run(False)
     assert spec == base
     assert st_on["speculative"]["spec_steps"] >= 1
+    for st in (st_on, st_off):
+        assert st["counts"]["sampler_steps_filter"] >= 1
+        assert st["counts"]["sampler_steps_greedy"] == 0
     assert st_off["speculative"] is None
 
 
