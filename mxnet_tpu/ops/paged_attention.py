@@ -40,7 +40,22 @@ Two bodies, chosen by what the call sees in its shapes:
   at once.  Dead pages are never visited: the table's width bucket costs
   nothing.
 
-Both accumulate with the online-softmax m/l recurrence in f32 (the same
+* **tiles** (a call with a ``window``, a ``sink`` or V pages narrower
+  than its K pages — layers of two kinds in one model,
+  parallel/hybrid_moe.py): grid ``(B * tiles,)``, up to 256 of the
+  grouped query rows a tile, decode (one tile of the ``G`` query heads of
+  a KV head) and a prefill chunk alike.  As the decode body it fetches its
+  own pages, and only those its tile reads: from the first page its
+  earliest query's window reaches (``max(0, pos - window + 1) // bs``; 0
+  without a window) to its latest query's own, found in the table as a
+  RING — logical page ``p`` in column ``p % W`` — so that a window layer's
+  table is as wide as a window and not as a sequence.  The mask is
+  ``pos - window < j <= pos``; the sink is one learned logit a query head
+  that joins the softmax's denominator and no value: the recurrence starts
+  at ``m = sink, l = 1, acc = 0``.  K pages hold ``dk`` lanes a head, V
+  pages ``dv``, each sliced at its own width.
+
+All accumulate with the online-softmax m/l recurrence in f32 (the same
 scheme as ops/flash_attention.py's forward).  Masking is by cache-position
 <= query-position, exactly the dense path's mask, so bucketed table widths
 never perturb real rows.
@@ -79,36 +94,58 @@ def attention_scale(d_head: int) -> float:
     return float(_np.float32(1.0) / _np.sqrt(_np.float32(d_head)))
 
 
-def paged_attention_reference(q, k_ctx, v_ctx, attn_mask, scale):
+def paged_attention_reference(q, k_ctx, v_ctx, attn_mask, scale, sink=None):
     """The gather+dense attend, verbatim from transformer_lm_decode — the
     ``TPUMX_PALLAS=0`` path and the kernel's parity oracle.
 
-    q: (B, T, H, D); k_ctx/v_ctx: (B, W*bs, Hkv, D) gathered context,
-    ``Hkv`` dividing ``H`` (query head h reads KV head ``h // (H //
-    Hkv)``; K and V are never repeated); attn_mask: (B, T, W*bs) bool —
-    the caller's, so a block mask is the caller's too; scale: f32 scalar.
+    q: (B, T, H, D); k_ctx: (B, W*bs, Hkv, D), v_ctx: (B, W*bs, Hkv, Dv)
+    gathered context, ``Hkv`` dividing ``H`` (query head h reads KV head
+    ``h // (H // Hkv)``; K and V are never repeated); attn_mask: (B, T,
+    W*bs) bool — the caller's, so a block mask or a window is the caller's
+    too; scale: f32 scalar; sink: (H,) or, a row of queries each, (T, H)
+    f32 — a logit that takes its share of every query's softmax and adds
+    no value.  Returns (B, T, H, Dv).
     Same numerics as ring_attention.local_attention: f32 scores and
     accumulation, masked slots at exactly 0 probability.
     """
     B, T, H, D = q.shape
-    Hkv = k_ctx.shape[2]
+    Hkv, Dv = k_ctx.shape[2], v_ctx.shape[3]
     if Hkv != H:
         # the G query heads of a KV head ride the query axis: (B, G*T,
         # Hkv, D) against the unrepeated context
         G = H // Hkv
         q = q.reshape(B, T, Hkv, G, D).transpose(0, 3, 1, 2, 4) \
             .reshape(B, G * T, Hkv, D)
+        if sink is not None:
+            sink = _sink_rows(sink, Hkv, G, T)
         o = paged_attention_reference(q, k_ctx, v_ctx,
-                                      jnp.tile(attn_mask, (1, G, 1)), scale)
-        return o.reshape(B, G, T, Hkv, D).transpose(0, 2, 3, 1, 4) \
-            .reshape(B, T, H, D)
+                                      jnp.tile(attn_mask, (1, G, 1)), scale,
+                                      sink)
+        return o.reshape(B, G, T, Hkv, Dv).transpose(0, 2, 3, 1, 4) \
+            .reshape(B, T, H, Dv)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k_ctx,
                    preferred_element_type=jnp.float32) * scale
     s = jnp.where(attn_mask[:, None], s, _NEG)
-    p = jax.nn.softmax(s, axis=-1)
+    if sink is not None:
+        # one more column of scores, dropped again behind the softmax
+        col = jnp.broadcast_to(
+            jnp.atleast_2d(sink.astype(jnp.float32)).T[None, :, :, None],
+            s.shape[:3] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([s, col], axis=-1),
+                           axis=-1)[..., :-1]
+    else:
+        p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v_ctx.dtype), v_ctx,
                    preferred_element_type=jnp.float32).astype(q.dtype)
     return o
+
+
+def _sink_rows(sink, n_kv: int, groups: int, t: int):
+    """A sink ``(H,)`` a query head as ``(groups * t, n_kv)``: the grouped
+    query rows' (row ``g * t + i`` is query head ``h * groups + g`` of KV
+    head ``h``, whatever ``i``)."""
+    return jnp.repeat(sink.astype(jnp.float32).reshape(n_kv, groups).T, t,
+                      axis=0)
 
 
 def _paged_kernel(tables_ref, maxpos_ref, tilemax_ref, layer_ref, q_ref,
@@ -209,6 +246,23 @@ def _paged_kernel(tables_ref, maxpos_ref, tilemax_ref, layer_ref, q_ref,
                                ).astype(o_ref.dtype)
 
 
+def _start_copies(copies):
+    """Start the K and V copy of every page of a group that is fetched."""
+    for fetched, kc, vc in copies:
+        @pl.when(fetched)
+        def _():
+            kc.start()
+            vc.start()
+
+
+def _wait_copies(copies):
+    for fetched, kc, vc in copies:
+        @pl.when(fetched)
+        def _():
+            kc.wait()
+            vc.wait()
+
+
 def _live_page_fetch(tables_ref, maxpos_ref, layer, k_hbm, v_hbm, kbuf, vbuf,
                      sem, bs: int, pages: int):
     """What the bodies that fetch their own pages share: ``(groups,
@@ -244,21 +298,7 @@ def _live_page_fetch(tables_ref, maxpos_ref, layer, k_hbm, v_hbm, kbuf, vbuf,
                                               sem.at[1, half])))
         return out
 
-    def start(copies):
-        for fetched, kc, vc in copies:
-            @pl.when(fetched)
-            def _():
-                kc.start()
-                vc.start()
-
-    def wait(copies):
-        for fetched, kc, vc in copies:
-            @pl.when(fetched)
-            def _():
-                kc.wait()
-                vc.wait()
-
-    return groups, page_copies, start, wait
+    return groups, page_copies, _start_copies, _wait_copies
 
 
 def _decode_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, sel_ref,
@@ -443,6 +483,208 @@ def _rows_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, pos_ref, k_hbm,
         sl = slice(h * d_head, (h + 1) * d_head)
         o_ref[0, :, sl] = (acc_ref[:, sl] / l_all[:, h:h + 1]
                            ).astype(o_ref.dtype)
+
+
+def _tiles_kernel(tables_ref, first_ref, end_ref, layer_ref, q_ref, pos_ref,
+                  *refs, bs: int, pages: int, n_heads: int, d_key: int,
+                  d_value: int, scale: float, window: int, sink: bool):
+    # grid = (B * tiles,): step s is tile s % tiles of row s // tiles, up
+    # to 256 grouped query rows.  As the rows body, the pools stay in HBM
+    # and this body fetches its pages itself, double-buffered, the next
+    # step's first group behind this step's last — but only the logical
+    # pages first_ref[row, tile] .. end_ref[row, tile] - 1, which is where
+    # a window pays: pages before the tile's earliest window are never
+    # fetched.  The table is a ring: logical page p sits in column p % W
+    # (a table as wide as the sequence is one whose ring never wraps).
+    from jax.experimental.pallas import tpu as pltpu
+
+    if sink:
+        sink_ref, *refs = refs
+    k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, trip_ref = \
+        refs
+    step = pl.program_id(0)
+    n_steps = pl.num_programs(0)
+    n_tiles = first_ref.shape[1]
+    W = tables_ref.shape[1]
+    n = pages * bs
+    rows = q_ref.shape[1]
+    layer = layer_ref[0]
+
+    def span(s):
+        """(row, first logical page, pages) of step ``s``."""
+        b, t = jax.lax.div(s, n_tiles), jax.lax.rem(s, n_tiles)
+        return b, first_ref[b, t], end_ref[b, t] - first_ref[b, t]
+
+    def groups(s):
+        return jax.lax.div(span(s)[2] + pages - 1, pages)
+
+    def page_copies(s, g, half, enabled=True):
+        b, first, count = span(s)
+        out = []
+        for j in range(pages):
+            idx = g * pages + j
+            blk = tables_ref[b, jax.lax.rem(first + idx, W)]
+            out.append(((idx < count) & (blk != 0) & enabled,
+                        pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                              kbuf.at[half, j],
+                                              sem.at[0, half]),
+                        pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                              vbuf.at[half, j],
+                                              sem.at[1, half])))
+        return out
+
+    @pl.when(step == 0)
+    def _first_step():
+        trip_ref[0] = 0
+        # a page that is not fetched leaves its slot as it was: keep what
+        # a zero probability multiplies finite
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when((step == 0) | (groups(jnp.maximum(step - 1, 0)) == 0))
+    def _own_first_group():
+        _start_copies(page_copies(step, 0, jax.lax.rem(trip_ref[0], 2)))
+
+    # the sink joins the denominator and no value: the recurrence starts
+    # at m = sink, l = 1, acc = 0
+    m_ref[...] = sink_ref[...] if sink else jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.ones_like(l_ref) if sink else jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    mxu = kbuf.dtype if kbuf.dtype == jnp.bfloat16 else jnp.float32
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(mxu)   # (rows, Hkv*dk)
+    pos = pos_ref[0]                                           # (rows, 1)
+    _, first, _ = span(step)
+    n_groups = groups(step)
+    head = jax.lax.broadcasted_iota(jnp.int32, (rows, n_heads), 1)
+
+    def trip(g, _):
+        t = trip_ref[0]
+        half = jax.lax.rem(t, 2)
+        more = g + 1 < n_groups
+        _start_copies(page_copies(
+            jnp.where(more, step, jnp.minimum(step + 1, n_steps - 1)),
+            jnp.where(more, g + 1, 0), 1 - half,
+            more | (step + 1 < n_steps)))
+        _wait_copies(page_copies(step, g, half))
+        k = kbuf[half].reshape(n, -1).astype(mxu)              # (n, Hkv*dk)
+        v = vbuf[half].reshape(n, -1).astype(mxu)              # (n, Hkv*dv)
+        # a page that was not fetched lies past the row's last position or
+        # behind every window of the tile: the position mask covers it
+        ctx = (first + g * pages) * bs \
+            + jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1)
+        mask = ctx <= pos
+        if window:
+            mask &= ctx > pos - window
+        m_all, l_all = m_ref[...], l_ref[...]                  # (rows, Hkv)
+        for h in range(n_heads):
+            sk = slice(h * d_key, (h + 1) * d_key)
+            sv = slice(h * d_value, (h + 1) * d_value)
+            s = jax.lax.dot_general(q[:, sk], k[:, sk],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, _NEG)
+            m_old = m_all[:, h:h + 1]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_old - m_new)
+            l_new = alpha * l_all[:, h:h + 1] + jnp.sum(p, axis=1,
+                                                        keepdims=True)
+            acc_ref[:, sv] = acc_ref[:, sv] * alpha + jax.lax.dot_general(
+                p.astype(mxu), v[:, sv], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_all = jnp.where(head == h, m_new, m_all)
+            l_all = jnp.where(head == h, l_new, l_all)
+        m_ref[...] = m_all
+        l_ref[...] = l_all
+        trip_ref[0] = t + 1
+
+    jax.lax.fori_loop(0, n_groups, trip, None)
+    # tiles that ran no trip (inactive slots, padded rows) emit 0
+    l_all = jnp.maximum(l_ref[...], 1e-30)
+    for h in range(n_heads):
+        sv = slice(h * d_value, (h + 1) * d_value)
+        o_ref[0, :, sv] = (acc_ref[:, sv] / l_all[:, h:h + 1]
+                           ).astype(o_ref.dtype)
+
+
+_TILE_ROWS = 256        # grouped query rows a step of the tiles body
+# cache positions a trip of it: a trip costs ~1.5 us beside its bytes, and
+# the full kind's decode call read 5.00 ms at 512 against 6.08 at 256, a
+# 512-token chunk's 2.04 against 3.18 (PERF.md PR 32)
+_TILE_POSITIONS = 512
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_heads", "scale", "interpret", "groups", "call", "window"))
+def _tiles_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
+                sink=None, *, n_heads, scale, interpret, groups, call,
+                window):
+    """The tiles body's call.  q: (B, G*T, Hkv*dk) group-major; positions:
+    (B, G*T); sink: None or (G*T, Hkv) f32.  Returns (B, G*T, Hkv*dv)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, R, HDk = q.shape
+    bs, HDv = k_pool.shape[2], v_pool.shape[3]
+    W = tables.shape[1]
+    bt = min(_TILE_ROWS, -(-R // 8) * 8)
+    r_pad = -(-R // bt) * bt
+    if r_pad != R:
+        # padded query rows sit at position -1: they read nothing
+        q = jnp.pad(q, ((0, 0), (0, r_pad - R), (0, 0)))
+        positions = jnp.pad(positions, ((0, 0), (0, r_pad - R)),
+                            constant_values=-1)
+        if sink is not None:
+            sink = jnp.pad(sink, ((0, r_pad - R), (0, 0)))
+    n_tiles = r_pad // bt
+    pages = max(1, min(_TILE_POSITIONS // bs, W))
+    # the logical pages a tile reads: up to its latest query's own (and
+    # the row's last valid position), from the first its earliest query's
+    # window reaches
+    tiled = positions.reshape(B, n_tiles, bt)
+    hi = jnp.minimum(jnp.max(tiled, axis=2), max_pos[:, None])
+    end = jnp.where(hi >= 0, hi // bs + 1, 0)
+    lo = jnp.min(jnp.where(tiled >= 0, tiled, jnp.iinfo(jnp.int32).max),
+                 axis=2)
+    first = jnp.maximum(lo - (window - 1), 0) // bs if window \
+        else jnp.zeros_like(end)
+    first = jnp.minimum(first, end)
+    tile = lambda w: pl.BlockSpec(  # noqa: E731
+        (1, bt, w), lambda s, *_: (s // n_tiles, s % n_tiles, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [tile(HDk), tile(1)]
+    args = [tables, first, end, layer, q, positions[:, :, None]]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((bt, n_heads),
+                                     lambda s, *_: (s % n_tiles, 0)))
+        args.append(sink)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B * n_tiles,),
+        in_specs=in_specs + [hbm, hbm],
+        out_specs=tile(HDv),
+        scratch_shapes=[pltpu.VMEM((2, pages, bs, HDk), k_pool.dtype),
+                        pltpu.VMEM((2, pages, bs, HDv), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.VMEM((bt, n_heads), jnp.float32),     # m
+                        pltpu.VMEM((bt, n_heads), jnp.float32),     # l
+                        pltpu.VMEM((bt, HDv), jnp.float32),         # acc
+                        pltpu.SMEM((1,), jnp.int32)],               # trips
+    )
+    kernel = functools.partial(
+        _tiles_kernel, bs=bs, pages=pages, n_heads=n_heads,
+        d_key=HDk // n_heads, d_value=HDv // n_heads, scale=scale,
+        window=window, sink=sink is not None)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, r_pad, HDv), q.dtype),
+        # steps run in order: the double buffer's parity and the prefetch
+        # of the next step's first group carry from one step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=_call_name(R // groups, W, call),
+    )(*args, k_pool, v_pool)
+    return out[:, :R]
 
 
 def _query_tile(t: int, hd: int) -> int:
@@ -698,7 +940,7 @@ def _paged_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
 
 def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
                     scale=None, k_scale=None, v_scale=None, *, layer: int = 0,
-                    call=None):
+                    call=None, window: int = 0, sink=None):
     """Attention of ``q`` against a paged KV pool, walking the block table
     in-kernel.
 
@@ -735,9 +977,19 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
     call : str, optional — names the call in a device trace
         (``_paged_call_w<W>_t<T>_<call>``); default ``decode`` / ``prefill``
         by ``T``.
+    window : int — 0, or how many cache positions back a query reads, its
+        own among them (``pos - window < j <= pos``).  The table is then
+        a RING: logical page ``p`` in column ``p % W``, ``W`` at least the
+        pages a row's chunk and window span.
+    sink : (H,) f32, optional — one learned logit a query head that joins
+        the softmax's denominator and adds no value.
 
-    Returns (B, T, H, D) in q's dtype, matching
-    :func:`paged_attention_reference` at rtol 1e-5 (f32) on valid rows.
+    A call with a window, a sink or V pages narrower than K pages takes
+    the tiles body (float pools only).
+
+    Returns (B, T, H, dv) in q's dtype (``dv`` = D unless V pages are
+    narrower), matching :func:`paged_attention_reference` at rtol 1e-5
+    (f32) on valid rows.
     """
     from .pallas_kernels import _use_interpret
 
@@ -750,6 +1002,20 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
     positions = jnp.asarray(positions, jnp.int32)
     Hkv = k_pool.shape[3] // D
     G = H // Hkv
+    if window or sink is not None or k_pool.shape[3] != v_pool.shape[3]:
+        assert k_scale is None, "the tiles body reads float pools"
+        dv = v_pool.shape[3] // Hkv
+        q = q.reshape(B, T, Hkv, G, D).transpose(0, 3, 1, 2, 4)
+        out = _tiles_call(
+            jnp.asarray(block_tables, jnp.int32),
+            jnp.asarray(max_pos, jnp.int32), jnp.full((1,), layer, jnp.int32),
+            q.reshape(B, G * T, Hkv * D), jnp.tile(positions, (1, G)),
+            k_pool, v_pool,
+            None if sink is None else _sink_rows(sink, Hkv, G, T),
+            n_heads=Hkv, scale=float(scale), interpret=_use_interpret(),
+            groups=G, call=call, window=int(window))
+        return out.reshape(B, G, T, Hkv, dv).transpose(0, 2, 3, 1, 4) \
+            .reshape(B, T, H, dv)
     if G > 1:
         # (B, T, Hkv, G, D) -> (B, G*T, Hkv*D): group-major query rows, as
         # wide as a K/V page; positions repeat per group

@@ -1,0 +1,379 @@
+"""A block whose layers attend in two ways — over a window with a sink, or
+over everything — with head counts of their own, keys wider than values,
+and sigmoid-routed experts (``model_type`` ``mimo_v2``, as ``MiMo-V2.5``'s
+``config.json`` carries its keys; docs/generation.md "Cache kinds").
+
+The layers, with ``x`` the residual stream, ``H`` query heads, ``dq`` /
+``dr`` / ``dv`` the key, rotary and value head sizes; a layer's kind is
+``hybrid_layer_pattern``'s entry: ``F`` (0) has ``Hkv`` KV heads, rotary
+base ``rope_theta``, no window; ``W`` (1) has ``swa_num_key_value_heads``,
+base ``swa_rope_theta``, a window of ``sliding_window`` positions (the
+query's own among them) and a sink ``s_h`` a query head::
+
+    h = rms(x, g1)
+    q = (h Wq).reshape(H, dq);  k = (h Wk).reshape(Hkv, dq)
+    v = (h Wv).reshape(Hkv, dv) * attention_value_scale
+    q = [rope(q[:, :dr], pos) | q[:, dr:]];  k likewise        (rotate-half, the FIRST dr lanes)
+    cached: k, v   (F: every position; W: the last ``sliding_window``)
+    z[t,h,j] = q[t,h] . k[j, h // (H/Hkv)] * dq^-0.5     F: j <= t;  W: t - window < j <= t
+    F: p = softmax_j(z)        W: p[t,h,j] = exp(z[t,h,j]) / (exp(s_h) + sum_i exp(z[t,h,i]))
+    a[t,h] = sum_j p[t,h,j] v[j, h // (H/Hkv)];   x = x + a.reshape(H dv) Wo
+    h = rms(x, g2)
+    a dense layer (moe_layer_freq 0):  x = x + (silu(h Wg) * (h Wu)) Wd
+    an expert layer:  sc = sigmoid(h Wr)  (E, float32);  c = sc + b   (choosing only)
+        e = top_k(c);  w = sc[e] / (sum sc[e] + 1e-20)
+        x = x + sum_i w_i E_{e_i}(h);            E(h) = (silu(h Wg) * (h Wu)) Wd
+    logits = rms(x, gf) Wh
+
+The cache has TWO kinds (``cache_spec()["kinds"]``): ``full`` — the ``F``
+layers' K and V, every position, under the table every model has — and
+``window`` — the ``W`` layers', of which a row keeps the blocks its next
+query can still see: its table is a ring as wide as a window and a chunk
+(``serving/generation/kv_cache.py::CacheKind``).  Attention is
+``ops/paged_attention.py``'s tiles body (and, without the kernel, the same
+sums over the gathered pages); the router is ``latent_moe``'s with one
+group, the expert products ``sdar_moe``'s.
+
+``experts_held = (lo, hi)`` is the chip's share of the routed experts, as
+in ``latent_moe.py``: the router scores all of them, the products add this
+chip's experts' part, nothing stands in for the other chips.
+
+Parameters are a flat dict in ONE dtype and are never cast in the program:
+products take operands in that dtype and accumulate in float32; the
+residual stream, norms, sigmoid scores and softmax with its sink are
+float32; the pools have their own dtype (bfloat16 on the chip).
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .latent_moe import _gated, route_sigmoid_groups
+from .sdar_moe import _mm, _rms, _rope, expert_products
+from .transformer import paged_write_coords
+
+Params = Dict[str, jnp.ndarray]
+
+__all__ = ["HybridMoeConfig", "HybridMoeLM", "hybrid_moe_decode",
+           "hybrid_moe_param_shapes", "hybrid_moe_init"]
+
+COUNTERS = ("full_ctx_tokens", "window_ctx_tokens", "full_prefill_pairs",
+            "window_prefill_pairs", "expert_assignments",
+            "expert_assignments_held", "experts_touched", "expert_tokens_max")
+
+
+@dataclass(frozen=True)
+class HybridMoeConfig:
+    """The published ``config.json`` keys that shape the model (defaults:
+    ``MiMo-V2.5``'s language model)."""
+    vocab_size: int = 152576
+    hidden_size: int = 4096
+    intermediate_size: int = 16384
+    moe_intermediate_size: int = 2048
+    num_hidden_layers: int = 48
+    hybrid_layer_pattern: Tuple[int, ...] = (0, 1, 1, 1, 1) + (0, 1, 1, 1, 1, 1) * 7 + (0,)
+    moe_layer_freq: Tuple[int, ...] = (0,) + (1,) * 47
+    num_attention_heads: int = 64
+    num_key_value_heads: int = 4
+    head_dim: int = 192
+    v_head_dim: int = 128
+    swa_num_attention_heads: int = 64
+    swa_num_key_value_heads: int = 8
+    swa_head_dim: int = 192
+    swa_v_head_dim: int = 128
+    sliding_window: int = 128
+    add_swa_attention_sink_bias: bool = True
+    add_full_attention_sink_bias: bool = False
+    partial_rotary_factor: float = 0.334
+    rope_theta: float = 1e7
+    swa_rope_theta: float = 1e4
+    attention_value_scale: float = 0.707
+    n_routed_experts: int = 256
+    num_experts_per_tok: int = 8
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0      # published null
+    layernorm_epsilon: float = 1e-5
+    max_position_embeddings: int = 1048576
+
+    def __post_init__(self):
+        n = self.num_hidden_layers
+        assert len(self.hybrid_layer_pattern) == len(self.moe_layer_freq) == n
+        # one query layout and one pair of head sizes for both kinds: what
+        # differs between them is the KV heads, the base, the window, the sink
+        assert self.swa_num_attention_heads == self.num_attention_heads
+        assert (self.swa_head_dim, self.swa_v_head_dim) == \
+            (self.head_dim, self.v_head_dim)
+        assert self.num_attention_heads % self.num_key_value_heads == 0
+        assert self.num_attention_heads % self.swa_num_key_value_heads == 0
+        assert self.rotary_dim % 2 == 0
+        assert 0 in self.hybrid_layer_pattern and 1 in self.hybrid_layer_pattern
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.partial_rotary_factor * self.head_dim)
+
+    def layers_of(self, kind: int) -> Tuple[int, ...]:
+        """The layers of one attention kind (0 full, 1 window), in order:
+        a layer's place here is its layer in that kind's pools."""
+        return tuple(i for i, k in enumerate(self.hybrid_layer_pattern)
+                     if k == kind)
+
+    def kv_heads(self, kind: int) -> int:
+        return self.swa_num_key_value_heads if kind \
+            else self.num_key_value_heads
+
+    def has_sink(self, kind: int) -> bool:
+        return bool(self.add_swa_attention_sink_bias if kind
+                    else self.add_full_attention_sink_bias)
+
+
+def hybrid_moe_param_shapes(cfg: HybridMoeConfig,
+                            experts_held: Optional[Tuple[int, int]] = None
+                            ) -> Dict[str, Tuple[int, ...]]:
+    """Every parameter's shape, the routed experts' as this chip holds
+    them (``experts_held``; default all)."""
+    d, H, dq, dv = (cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim,
+                    cfg.v_head_dim)
+    f, F, E = (cfg.moe_intermediate_size, cfg.intermediate_size,
+               cfg.n_routed_experts)
+    lo, hi = experts_held or (0, E)
+    held = hi - lo
+    s = {"tok_emb": (cfg.vocab_size, d), "head": (d, cfg.vocab_size),
+         "norm_f": (d,)}
+    for i, kind in enumerate(cfg.hybrid_layer_pattern):
+        hkv = cfg.kv_heads(kind)
+        layer = {"norm1": (d,), "wq": (d, H * dq), "wk": (d, hkv * dq),
+                 "wv": (d, hkv * dv), "wo": (H * dv, d), "norm2": (d,)}
+        if cfg.has_sink(kind):
+            layer["sink"] = (H,)
+        if cfg.moe_layer_freq[i]:
+            layer.update(router=(d, E), router_bias=(E,), wg=(held, d, f),
+                         wu=(held, d, f), wd=(held, f, d))
+        else:
+            layer.update(wg=(d, F), wu=(d, F), wd=(F, d))
+        s.update({f"l{i}_{n}": shape for n, shape in layer.items()})
+    return s
+
+
+def hybrid_moe_init(cfg: HybridMoeConfig, key, dtype=jnp.float32,
+                    experts_held: Optional[Tuple[int, int]] = None) -> Params:
+    """Seeded weights in ``dtype``: products normal over the square root
+    of their fan-in (residual outputs divided by ``sqrt(2 x layers)``),
+    norm gains near one, a small correction bias and sinks n(0, 1), so
+    that a term left out shows.  (The benchmark makes its own,
+    ``perfbench/reference/mimo_v2.py``.)"""
+    res = (2.0 * cfg.num_hidden_layers) ** -0.5
+    p = {}
+    for i, (name, shape) in enumerate(sorted(
+            hybrid_moe_param_shapes(cfg, experts_held).items())):
+        z = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
+        kind = name.split("_", 1)[-1]
+        if "norm" in name:
+            z = 1.0 + 0.1 * z
+        elif kind == "router_bias":
+            z = 0.01 * z
+        elif name == "tok_emb":
+            z = 0.1 * z
+        elif kind != "sink":
+            z = z * shape[-2] ** -0.5 * (res if kind in ("wo", "wd") else 1.0)
+        p[name] = z.astype(dtype)
+    return p
+
+
+def name_of(kind: int) -> str:
+    """The cache kind of an attention kind, and its calls' name in a
+    device trace (``_paged_call_w<W>_t<T>_<full|window>_<decode|prefill>``)."""
+    return "window" if kind else "full"
+
+
+def _ring_positions(first_block, width: int, block_size: int):
+    """The cache position of every slot of a ring table's gathered pages,
+    ``(B, width * block_size)``: column ``s`` holds the one logical block
+    of ``first_block .. first_block + width - 1`` that is ``s`` modulo
+    ``width``."""
+    col = jnp.arange(width, dtype=jnp.int32)[None, :]
+    block = first_block[:, None] + (col - first_block[:, None]) % width
+    return (block[:, :, None] * block_size
+            + jnp.arange(block_size, dtype=jnp.int32)).reshape(
+        first_block.shape[0], width * block_size)
+
+
+def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
+                      block_tables, cfg: HybridMoeConfig, *,
+                      attention_kernel: Optional[str] = None,
+                      experts_held: Optional[Tuple[int, int]] = None,
+                      max_len: Optional[int] = None):
+    """Cache-aware forward over the two kinds' paged pools: ``pools`` is
+    ``(k_full, v_full, k_window, v_window)``, each ``(its kind's layers,
+    its kind's blocks, block_size, KV heads x lanes)``, and
+    ``block_tables`` is ``(full (B, W), window (B, Wr))`` — the window
+    kind's a RING: the logical block ``b`` of a row sits in column ``b %
+    Wr``, and the row holds no block that its first query here cannot
+    see (but for one of slack).
+
+    Arguments otherwise as ``transformer_lm_decode``.  Returns ``(logits
+    (B, T, vocab) float32, pools, aux)``; ``aux`` is the dict of this
+    call's counts (``COUNTERS``; docs/observability.md), made on the
+    device from what the program itself saw: valid queries only, except
+    ``experts_touched``, which counts the experts whose weights the
+    products read."""
+    from ..ops import paged_attention as _pa
+    from ..ops import pallas_kernels as _pk
+
+    B, T = tokens.shape
+    H, dq, dv, dr = (cfg.num_attention_heads, cfg.head_dim, cfg.v_head_dim,
+                     cfg.rotary_dim)
+    win = cfg.sliding_window
+    pools = list(pools)
+    tables = [jnp.asarray(t, jnp.int32) for t in block_tables]
+    bs = pools[0].shape[2]
+    positions, valid, phys_f, offs = paged_write_coords(
+        positions, lengths, tables[0], bs, max_len
+        or cfg.max_position_embeddings)
+    ring = tables[1].shape[1]
+    phys = (phys_f, jnp.where(valid, jnp.take_along_axis(
+        tables[1], (positions // bs) % ring, axis=1), 0))
+    use_kernel = (_pk.pallas_enabled() if attention_kernel is None
+                  else attention_kernel == "paged")
+    max_pos = jnp.max(jnp.where(valid, positions, -1), axis=1)
+    scale = float(dq) ** -0.5
+    if not use_kernel:
+        pos_f = jnp.arange(tables[0].shape[1] * bs, dtype=jnp.int32)[None]
+        pos_w = _ring_positions(
+            jnp.maximum(positions[:, 0] - (win - 1), 0) // bs, ring, bs)
+        at = positions[:, :, None]
+        masks = (pos_f[:, None, :] <= at,
+                 (pos_w[:, None, :] <= at) & (pos_w[:, None, :] > at - win))
+    lo, hi = experts_held or (0, cfg.n_routed_experts)
+    valid_flat = valid.reshape(-1)
+    reads = (jnp.sum(jnp.where(valid, positions + 1, 0)),
+             jnp.sum(jnp.where(valid, jnp.minimum(positions + 1, win), 0)))
+    zero = jnp.zeros((), jnp.int32)
+    phase = "decode" if T == 1 else "prefill"
+    aux = dict.fromkeys(COUNTERS, zero)
+    for kind in (0, 1):
+        aux[name_of(kind) + ("_ctx_tokens" if T == 1 else "_prefill_pairs")] \
+            = reads[kind]
+    eps = cfg.layernorm_epsilon
+    at_kind = [0, 0]            # the next layer of each kind's pools
+    x = params["tok_emb"][tokens].astype(jnp.float32)          # (B, T, d)
+    for i, kind in enumerate(cfg.hybrid_layer_pattern):
+        g = lambda n: params[f"l{i}_{n}"]  # noqa: B023 — read immediately
+        hkv, li = cfg.kv_heads(kind), at_kind[kind]
+        at_kind[kind] += 1
+        theta = cfg.swa_rope_theta if kind else cfg.rope_theta
+        h = _rms(x, g("norm1"), eps)
+
+        def rotated(t):     # rotate-half over the first dr lanes
+            return jnp.concatenate(
+                [_rope(t[..., :dr], positions, theta), t[..., dr:]], axis=-1)
+
+        q = rotated(_mm(h, g("wq")).reshape(B, T, H, dq))
+        k = rotated(_mm(h, g("wk")).reshape(B, T, hkv, dq))
+        v = _mm(h, g("wv")) * cfg.attention_value_scale
+        k_pool, v_pool = pools[2 * kind], pools[2 * kind + 1]
+        k_pool = k_pool.at[li, phys[kind], offs].set(
+            k.reshape(B, T, hkv * dq).astype(k_pool.dtype))
+        v_pool = v_pool.at[li, phys[kind], offs].set(v.astype(v_pool.dtype))
+        pools[2 * kind], pools[2 * kind + 1] = k_pool, v_pool
+        sink = g("sink") if cfg.has_sink(kind) else None
+        if use_kernel:
+            a = _pa.paged_attention(
+                q, k_pool, v_pool, tables[kind], positions, max_pos,
+                scale=scale, layer=li, call=f"{name_of(kind)}_{phase}",
+                window=win if kind else 0, sink=sink)
+        else:
+            gather = lambda pool, w: pool[li][tables[kind]].reshape(  # noqa: E731,B023
+                B, -1, hkv, w)
+            a = _pa.paged_attention_reference(
+                q, gather(k_pool, dq), gather(v_pool, dv), masks[kind],
+                scale, sink)
+        x = x + _mm(a.reshape(B, T, H * dv), g("wo"))
+        h = _rms(x, g("norm2"), eps)
+        if not cfg.moe_layer_freq[i]:
+            x = x + _gated(h, g("wg"), g("wu"), g("wd"))
+            continue
+        hf = h.reshape(B * T, -1)
+        w, e = route_sigmoid_groups(
+            _mm(hf, g("router")), g("router_bias"), cfg.num_experts_per_tok,
+            cfg.n_group, cfg.topk_group, cfg.norm_topk_prob,
+            cfg.routed_scaling_factor)
+        y, sizes = expert_products(hf, w, e, g("wg"), g("wu"), g("wd"),
+                                   (lo, hi), pallas=use_kernel)
+        x = x + y.reshape(B, T, -1)
+        mine = (e >= lo) & (e < hi) & valid_flat[:, None]
+        load = jnp.bincount(jnp.where(mine, e - lo, hi - lo).reshape(-1),
+                            length=hi - lo + 1)[:hi - lo]
+        aux["expert_assignments"] += (jnp.sum(valid_flat) * e.shape[1]
+                                      ).astype(jnp.int32)
+        aux["expert_assignments_held"] += jnp.sum(load).astype(jnp.int32)
+        aux["experts_touched"] += jnp.sum(sizes > 0).astype(jnp.int32)
+        aux["expert_tokens_max"] = jnp.maximum(
+            aux["expert_tokens_max"], jnp.max(load).astype(jnp.int32))
+    logits = _mm(_rms(x, params["norm_f"], eps), params["head"])
+    return logits, tuple(pools), aux
+
+
+@dataclass(frozen=True)
+class HybridMoeLM:
+    """The model as the generation engine takes one (the seam of
+    ``serving/generation/programs.py``): one token a row a step
+    (``block_len`` 0, so it rides the step in flight), a cache of two
+    kinds (``cache_spec``), the chip's share of the routed experts
+    (``experts_held``), and the counts its program hands back
+    (``counters``).  ``max_len`` is the service's longest position."""
+    cfg: HybridMoeConfig
+    max_len: int
+    experts_held: Optional[Tuple[int, int]] = None
+    kv_dtype: object = jnp.bfloat16
+    # the longest chunk a prefill program takes: its temporaries (the
+    # queries, the assignments' gathered rows) grow with the chunk, and so
+    # does what a row of the window kind owns while it is prefilled
+    longest_chunk: int = 512
+    block_len = 0
+    offers = frozenset({"sampling"})
+    counters = COUNTERS
+    # the tiles body fetches the pages a tile reads and no others, for a
+    # chunk as for one token: a table's width costs nothing, so the
+    # service keeps one
+    one_table_width = True
+
+    @property
+    def vocab(self) -> int:
+        return self.cfg.vocab_size
+
+    @property
+    def heads(self) -> int:
+        return self.cfg.num_attention_heads
+
+    def cache_spec(self) -> dict:
+        """Two kinds: ``full`` keeps every position and is sized by
+        tokens; ``window`` keeps what ``sliding_window`` positions can
+        still see and is sized by rows.  K pages hold a head's 192 lanes
+        as they are beside V pages of 128 (PERF.md PR 32 has the chip's
+        reading against 256 padded)."""
+        c = self.cfg
+
+        def kind(k, **more):
+            return dict(name=name_of(k), n_layers=len(c.layers_of(k)),
+                        pools=(("k", c.kv_heads(k) * c.head_dim),
+                               ("v", c.kv_heads(k) * c.v_head_dim)), **more)
+
+        return dict(dtype=self.kv_dtype,
+                    kinds=(kind(0), kind(1, window=c.sliding_window)))
+
+    def step(self, params, tokens, positions, lengths, pools, block_tables,
+             *, attention_kernel, mp_mesh=None, call=None, want_logits=True):
+        """The serving seam's one contract (``programs.py``): ``pools`` is
+        the two kinds' pools, one after the other, ``block_tables`` a
+        table a kind; returns ``(logits, pools, aux)``.  No mesh is
+        offered, so ``mp_mesh`` is always None."""
+        return hybrid_moe_decode(
+            params, tokens, positions, lengths, pools, block_tables,
+            self.cfg, attention_kernel=attention_kernel,
+            experts_held=self.experts_held, max_len=self.max_len)

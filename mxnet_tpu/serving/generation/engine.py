@@ -38,6 +38,7 @@ vLLM's paged KV cache, recast in tpu-mx's zero-recompile idiom
 """
 from __future__ import annotations
 
+import logging
 import os
 import queue
 import threading
@@ -59,7 +60,8 @@ from ..batcher import (BACKPRESSURE_POLICIES, DeadlineExceededError,
                        ServingError)
 from ..bucketing import (batch_buckets, bucket_batch, bucket_seq_len,
                          pad_tokens_right, seq_buckets)
-from .kv_cache import PagedKVCache, blocks_for
+from .kv_cache import (PagedKVCache, blocks_for, ring_width,
+                       window_blocks)
 from .programs import GenerationPrograms, _synced, as_model
 
 __all__ = ["GenerationConfig", "GenerationService", "GenerationStream",
@@ -287,7 +289,7 @@ class _GenRequest:
                  "decode_steps", "n_retries", "token_log", "wide_event",
                  "lock", "cached_len", "cached_total", "cow_copies",
                  "charged_blocks", "draft_proposed", "draft_accepted",
-                 "mode_tokens", "index_safe_len", "block",
+                 "mode_tokens", "index_safe_len", "block", "wins",
                  "block_masked", "block_at", "block_pass", "unmask_pass")
 
     def __init__(self, rid, prompt, bucket, max_new, temperature, top_k,
@@ -306,6 +308,9 @@ class _GenRequest:
         self.on_token = on_token
         self.state = _WAITING
         self.blocks: Optional[List[int]] = None
+        # a window kind each (docs/generation.md "Cache kinds"): [the
+        # first logical block the row still owns, its blocks from there]
+        self.wins: Optional[List[list]] = None
         self.ctx_len = 0
         self.n_generated = 0
         self.out_queue: "queue.Queue" = queue.Queue()
@@ -647,30 +652,6 @@ class GenerationService:
             raise ValueError(
                 f"block_size {cfg.block_size} and max_len {model.max_len} "
                 f"must be multiples of the model's block length {L}")
-        # the cache is what the model's spec says: the classic K/V pair
-        # of folded heads, or the pools it names (latent attention: one)
-        self._cache = PagedKVCache(
-            num_blocks=cfg.num_blocks, block_size=cfg.block_size,
-            kv_dtype=cfg.kv_dtype, **model.cache_spec())
-        self._cache.allocator.set_watermarks(cfg.watermark_high,
-                                             cfg.watermark_low)
-        # prefix caching (docs/generation.md "prefix caching"): the chain-
-        # hash index over resident full blocks.  None with the gate off —
-        # every code path below then stays byte-identical to pre-cache
-        # behavior (program keys, admission accounting, tokens).
-        from .prefix_cache import PrefixCacheIndex
-        self._prefix = (PrefixCacheIndex(
-            self._cache.allocator, cfg.block_size,
-            capacity_blocks=cfg.prefix_cache_blocks)
-            if cfg.prefix_cache else None)
-        self._pc_evictions_seen = 0
-        self._programs = GenerationPrograms(params, model,
-                                            mp_devices=cfg.mp_devices,
-                                            shard_rules=cfg.shard_rules,
-                                            kv_dtype=cfg.kv_dtype)
-        # mp + paged kernel: the pool lives head-sharded on the mp mesh
-        # (1/mp of the cache per chip, docs/generation.md)
-        self._programs.place_cache(self._cache)
         # prefill ladder: bounded by the model's position table — a prompt
         # must also leave room for at least one generated token
         max_prompt = model_cfg.max_len - 1
@@ -697,6 +678,50 @@ class GenerationService:
         if cap and cfg.chunked_prefill:
             self._seq_buckets = [b for b in self._seq_buckets if b <= cap] \
                 or self._seq_buckets[:1]
+        # the cache is what the model's spec says: the classic K/V pair
+        # of folded heads, or the pools it names (latent attention: one)
+        spec = model.cache_spec()
+        if "kinds" in spec:
+            # window kinds are sized by rows: what every slot owns at rest
+            # and the one row being prefilled owns besides
+            spec["window_rows"] = (cfg.max_slots, self._seq_buckets[-1])
+        self._cache = PagedKVCache(
+            num_blocks=cfg.num_blocks, block_size=cfg.block_size,
+            kv_dtype=cfg.kv_dtype, **spec)
+        self._cache.allocator.set_watermarks(cfg.watermark_high,
+                                             cfg.watermark_low)
+        # the kinds behind the first (docs/generation.md "Cache kinds"):
+        # empty for every model whose layers are of one kind, and nothing
+        # below that names them runs
+        self._windows = self._cache.kinds[1:]
+        # prefix caching (docs/generation.md "prefix caching"): the chain-
+        # hash index over resident full blocks.  None with the gate off —
+        # every code path below then stays byte-identical to pre-cache
+        # behavior (program keys, admission accounting, tokens).
+        from .prefix_cache import PrefixCacheIndex
+        self._prefix = (PrefixCacheIndex(
+            self._cache.allocator, cfg.block_size,
+            capacity_blocks=cfg.prefix_cache_blocks)
+            if cfg.prefix_cache and not self._windows else None)
+        if cfg.prefix_cache and self._windows:
+            # a hit at position p would also need the window layers' last
+            # positions before p, which their rows freed as they went: the
+            # index would have to keep a window of blocks with every
+            # prefix it names.  Such a model declines prefix reuse, and
+            # the service says so here and in stats()["prefix_cache"]
+            logging.getLogger(__name__).info(
+                "%s keeps window cache kinds %s: no prefix reuse (a row "
+                "frees the blocks behind its window, so no cached prefix "
+                "could serve them)", type(model).__name__,
+                [k.name for k in self._windows])
+        self._pc_evictions_seen = 0
+        self._programs = GenerationPrograms(params, model,
+                                            mp_devices=cfg.mp_devices,
+                                            shard_rules=cfg.shard_rules,
+                                            kv_dtype=cfg.kv_dtype)
+        # mp + paged kernel: the pool lives head-sharded on the mp mesh
+        # (1/mp of the cache per chip, docs/generation.md)
+        self._programs.place_cache(self._cache)
         # decode block-table widths: pow2 ladder up to the blocks needed to
         # address max_len positions (the cap itself kept, like batch_buckets)
         self._width_buckets = batch_buckets(
@@ -804,6 +829,10 @@ class GenerationService:
         # every step (``aux``; docs/observability.md), summed here when
         # the step's tokens are read
         self._counts.update(dict.fromkeys(getattr(model, "counters", ()), 0))
+        if self._windows:
+            # blocks of a window kind that slid out of their row's sight
+            # and went back to the kind's allocator
+            self._counts["window_blocks_freed"] = 0
         self._peak_occupancy = 0.0
         # host microseconds of the loop by phase, from the phase spans' own
         # clock reads (written by the engine thread only)
@@ -824,6 +853,15 @@ class GenerationService:
             help="fraction of the pool holding WRITTEN context — the "
                  "number reserve-ahead reservation wastes and incremental "
                  "allocation recovers (docs/generation.md)")
+        # a cache kind behind the first each (none for most models):
+        # the blocks its rows own now
+        self._g_kind_blocks = [
+            reg.gauge("generation_kv_kind_blocks_used",
+                      labels={"kind": k.name},
+                      help="blocks of a cache kind that rows own (a window "
+                           "kind: at most window_blocks() a row, whatever "
+                           "its length; docs/generation.md \"Cache kinds\")")
+            for k in self._windows]
         self._g_tps = reg.gauge("generation_tokens_per_sec")
         self._c_tokens = reg.counter("generation_tokens_total")
         self._c_requests = reg.counter("generation_requests_total")
@@ -1439,6 +1477,10 @@ class GenerationService:
                     if shared:
                         alloc.decref(shared)
                     break  # keep the growth headroom; readmit later
+            if not all(k.allocator.can_allocate(window_blocks(
+                    k.window, self._seq_buckets[-1], cfg.block_size))
+                    for k in self._windows):
+                break   # (sized by slots: a free slot has its blocks)
             blocks = self._alloc_reclaiming(grow)
             if blocks is None:
                 if shared:
@@ -1489,6 +1531,63 @@ class GenerationService:
             self._prefix.evict_blocks(int(n) - alloc.num_free)
             got = alloc.allocate(n)
         return got
+
+    def _slide(self, r, seen: int, upto: int) -> None:
+        """The window kinds' side of a row's advance (docs/generation.md
+        "Cache kinds"): of each, ``r`` keeps the blocks from the one that
+        holds position ``seen - window + 1`` — the first a query at
+        ``seen`` reads — to the one that holds ``upto - 1``.  Blocks that
+        slid out of every later query's sight go back to the kind's
+        allocator (the next allocation may hand them to another row while
+        a step that read them is still queued: programs run in order on
+        the device, so that step has read them before anything writes
+        them), new ones are taken for what the step will write.  ``seen``
+        is the HOST's context length, one behind the device's under a
+        step in flight: were that step's read to fail, the row is fed
+        from ``seen`` again and still owns what it reads.  A kind is
+        sized for every slot's share, so an allocation cannot fail."""
+        bs = self._config.block_size
+        if r.wins is None:
+            r.wins = [[0, []] for _ in self._windows]
+        for kind, win in zip(self._windows, r.wins):
+            first = max(0, seen - (kind.window - 1)) // bs
+            if first > win[0]:
+                gone = win[1][:first - win[0]]
+                if gone:
+                    with _obs.span("serving.window.slide", cat="serving"):
+                        kind.allocator.free(gone)
+                        del win[1][:len(gone)]
+                    self._counts["window_blocks_freed"] += len(gone)
+                win[0] = first
+            need = blocks_for(upto, bs) - win[0] - len(win[1])
+            if need > 0:
+                got = kind.allocator.allocate(need)
+                if got is None:
+                    raise ServingError(
+                        f"cache kind {kind.name!r} exhausted allocating "
+                        f"{need} blocks for request {r.rid}")
+                win[1].extend(got)
+
+    def _drop_windows(self, r: _GenRequest) -> None:
+        """Return every window-kind block of ``r`` (release, preemption:
+        a resumed row's re-prefill takes them anew as it goes)."""
+        for kind, win in zip(self._windows, r.wins or ()):
+            kind.allocator.free(win[1])
+        r.wins = None
+
+    def _ring_tables(self, rows, S: int, T: int) -> tuple:
+        """The window kinds' tables of a step that feeds ``T`` positions a
+        row, ``(S, ring_width)`` each: RINGS — the logical block ``b`` of a
+        row sits in column ``b % width``."""
+        out = []
+        for j, kind in enumerate(self._windows):
+            width = ring_width(kind.window, T, self._config.block_size)
+            table = _np.zeros((S, width), _np.int32)
+            for i, r in rows:
+                first, blocks = r.wins[j]
+                table[i, (first + _np.arange(len(blocks))) % width] = blocks
+            out.append(table)
+        return tuple(out)
 
     def _cow_for_write(self, r: _GenRequest, off: int, take: int) -> None:
         """Copy-on-write (docs/generation.md "prefix caching"): before a
@@ -1575,6 +1674,7 @@ class GenerationService:
                         r.seq_tokens[:self._index_safe_ctx(r)], r.blocks)
                 self._cache.allocator.free(r.blocks)
                 r.blocks = None
+            self._drop_windows(r)
             r.state = _WAITING
             self._waiting.appendleft(r)
             if counter == "preempted":
@@ -1677,6 +1777,7 @@ class GenerationService:
                     r.seq_tokens[:self._index_safe_ctx(r)], r.blocks)
             self._cache.allocator.free(r.blocks)
             r.blocks = None
+        self._drop_windows(r)
         self._finish_locked(r, reason=reason, error=error)
         self._not_full.notify_all()  # blocks freed: budget waiters re-check
 
@@ -1837,7 +1938,7 @@ class GenerationService:
             for L in range(step, self._model_cfg.max_len, step):
                 for (_, _, tb, w) in self._chunk_plan(L, force_chunked=True):
                     out.add((tb, w))
-        if cfg.prefix_cache:
+        if self._prefix is not None:
             # cache-hit suffixes (docs/generation.md "prefix caching"):
             # the rung walk from every block-aligned cached length to
             # every context length — memoized on (off, remaining) so the
@@ -1877,6 +1978,9 @@ class GenerationService:
             table = _np.zeros((1, wp), _np.int32)
             n = min(wp, len(r.blocks))
             table[0, :n] = r.blocks[:n]
+            if self._windows:
+                self._slide(r, off, off + take)
+                table = (table, *self._ring_tables([(0, r)], 1, tb))
             tokens = pad_tokens_right(
                 _np.asarray(r.seq_tokens[off:off + take], _np.int32),
                 tb)[None, :]
@@ -1966,6 +2070,10 @@ class GenerationService:
                     self._count_aux(self._programs.take_aux())
             r.rung_s[tb] = r.rung_s.get(tb, 0.0) \
                 + (time.perf_counter() - t_rung0)
+            if self._windows:
+                # what the chunk wrote and no later query sees goes back
+                # before the next row's prefill asks for blocks
+                self._slide(r, off + take, off + take)
         self._counts["prefill_tokens"] += sum(p[1] for p in plan)
         r.seg("decode", time.perf_counter())
         # make this context's full blocks available to the NEXT shared-
@@ -2069,6 +2177,8 @@ class GenerationService:
             # guarantee (shared prefix blocks are physically unreachable
             # from a speculative scatter)
             self._cow_for_write(r, c, span)
+            if self._windows:
+                self._slide(r, r.ctx_len, c + span)
             rows.append((i, r))
             flat[i * T:i * T + n] = fed
             ctx[i] = c
@@ -2092,6 +2202,8 @@ class GenerationService:
         for i, r in rows:
             blocks = r.blocks[:w]
             tables[i, :len(blocks)] = blocks
+        if self._windows:
+            tables = (tables, *self._ring_tables(rows, S, T))
         # warm-up's empty batch is no step: it does not advance the
         # injector's count of invocations
         if batch and _fault_injector().gen_step_fail(rids):
@@ -2714,6 +2826,8 @@ class GenerationService:
                 self._c_pc_evict.inc(ev - self._pc_evictions_seen)
                 self._pc_evictions_seen = ev
             self._counts["prefix_evictions"] = ev
+        for kind, gauge in zip(self._windows, self._g_kind_blocks):
+            gauge.set(kind.allocator.num_used)
         occ = alloc.occupancy()
         self._peak_occupancy = max(self._peak_occupancy, occ)
         self._g_occupancy.set(occ)
@@ -2773,6 +2887,13 @@ class GenerationService:
                 "live_occupancy": round(self.live_occupancy(), 4),
                 "peak_occupancy": round(self._peak_occupancy, 4),
             },
+            # every kind of the cache: the first is "kv_blocks" above
+            "cache_kinds": {
+                k.name: {"layers": k.n_layers, "window": k.window,
+                         "total": k.num_blocks - 1,
+                         "used": k.allocator.num_used,
+                         "free": k.allocator.num_free}
+                for k in self._cache.kinds},
             "prefix_cache": (None if self._prefix is None else {
                 "blocks": self._prefix.num_blocks,
                 "hits": counts["prefix_hits"],

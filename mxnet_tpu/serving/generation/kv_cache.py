@@ -47,12 +47,52 @@ from __future__ import annotations
 import threading
 from typing import Dict, List, Optional
 
-__all__ = ["BlockAllocator", "PagedKVCache", "blocks_for"]
+__all__ = ["BlockAllocator", "CacheKind", "PagedKVCache", "blocks_for",
+           "ring_width", "window_blocks"]
 
 
 def blocks_for(n_positions: int, block_size: int) -> int:
     """Number of cache blocks covering ``n_positions`` tokens."""
     return max(1, -(-int(n_positions) // int(block_size)))
+
+
+def window_blocks(window: int, chunk: int, block_size: int) -> int:
+    """The most blocks of a WINDOW kind one row ever owns while a step
+    feeds it ``chunk`` positions: ``ceil((window + chunk) / block_size) +
+    1`` — the ``window`` positions its first query still sees (one more
+    while the step before is in flight), the chunk it writes, and the block
+    both ends may straddle."""
+    return -(-(int(window) + int(chunk)) // int(block_size)) + 1
+
+
+def ring_width(window: int, chunk: int, block_size: int) -> int:
+    """Columns of a window kind's table for a step that feeds ``chunk``
+    positions a row: :func:`window_blocks` rounded up to a power of two.
+    The table is a RING: logical block ``b`` sits in column ``b % width``."""
+    return 1 << (window_blocks(window, chunk, block_size) - 1).bit_length()
+
+
+class CacheKind:
+    """One kind of a cache whose layers are not all of one kind
+    (docs/generation.md "Cache kinds"): its ``name``, how many layers keep
+    their state in it, the ``layout`` of its pools (``((name, minor
+    width), ...)``), its own block count and :class:`BlockAllocator`, and
+    ``window`` — 0 for a kind that keeps every position, else how many
+    positions back a query still reads: a row of such a kind owns at most
+    :func:`window_blocks` blocks whatever its length, its table is a RING
+    (logical block ``b`` sits in column ``b % width``), and the kind is
+    sized by slots where the others are sized by tokens.  ``span`` says
+    which members of :attr:`PagedKVCache.pools` are this kind's."""
+
+    def __init__(self, name, n_layers, layout, num_blocks, window, span,
+                 allocator=None):
+        self.name = str(name)
+        self.n_layers = int(n_layers)
+        self.layout = layout
+        self.num_blocks = int(num_blocks)
+        self.window = int(window)
+        self.span = span
+        self.allocator = allocator or BlockAllocator(self.num_blocks)
 
 
 class BlockAllocator:
@@ -201,6 +241,16 @@ class PagedKVCache:
     docs/generation.md "Latent attention").  Allocator, prefix index,
     copy-on-write and preemption work on block ids and never look inside.
 
+    A model whose layers are not all of one kind names ``kinds`` instead
+    (docs/generation.md "Cache kinds"): ``({"name", "n_layers", "pools"},
+    {"name", "n_layers", "pools", "window"}, ...)``.  The first keeps every
+    position and is this cache as described above (``num_blocks`` sizes
+    it, ``allocator`` is its allocator); each further one is a window kind
+    (:class:`CacheKind`) with an allocator and a block count of its own,
+    sized for ``window_rows = (rows, longest chunk)``.  ``pools`` stays ONE
+    tuple, kind after kind (``kinds[i].span`` says which).  A spec that
+    names no kinds has one, ``kinds[0]``, and is exactly today's cache.
+
     The arrays are owned functionally, as one tuple ``pools``: the engine
     threads it through its donated compiled programs and stores the
     returned (aliased) arrays back via :meth:`swap` — the pool is updated
@@ -217,11 +267,25 @@ class PagedKVCache:
     is the ~2x block-budget headline (:meth:`num_blocks_for_bytes`).
     """
 
-    def __init__(self, n_layers: int, n_heads: Optional[int] = None,
+    def __init__(self, n_layers: Optional[int] = None,
+                 n_heads: Optional[int] = None,
                  d_head: Optional[int] = None, num_blocks: int = 2,
                  block_size: int = 16, dtype=None,
-                 kv_dtype: Optional[str] = None, pools=None):
+                 kv_dtype: Optional[str] = None, pools=None, kinds=None,
+                 window_rows=None):
         import jax.numpy as jnp
+
+        if kinds is not None:
+            if kv_dtype is not None or pools is not None:
+                raise ValueError("a cache of several kinds names its pools "
+                                 "kind by kind and is not quantized")
+            first, rest = kinds[0], kinds[1:]
+            if first.get("window") or not all(k.get("window") for k in rest):
+                raise ValueError(
+                    "the first cache kind keeps every position (it is what "
+                    "num_blocks sizes) and every further kind is a window "
+                    f"kind, got {[(k['name'], k.get('window', 0)) for k in kinds]}")
+            n_layers, pools = first["n_layers"], first["pools"]
 
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
@@ -253,6 +317,26 @@ class PagedKVCache:
             self.pools += (jnp.ones(sshape, jnp.float32),
                            jnp.ones(sshape, jnp.float32))
         self.allocator = BlockAllocator(self.num_blocks)
+        # the kinds: one for every cache of today's — these pools under
+        # this allocator —, then a spec's window kinds, each with blocks
+        # for ``window_rows = (rows, longest chunk)``: what every row owns
+        # at rest and what the one row being prefilled owns besides
+        name = kinds[0]["name"] if kinds else "kv"
+        self.kinds = (CacheKind(name, n_layers, self.layout, self.num_blocks,
+                                0, slice(0, len(self.pools)),
+                                self.allocator),)
+        for k in (kinds or ())[1:]:
+            rows, chunk = window_rows
+            layout = _pool_layout(None, None, k["pools"])
+            n = 1 + rows * window_blocks(k["window"], 1, self.block_size) \
+                + window_blocks(k["window"], chunk, self.block_size)
+            at = len(self.pools)
+            self.pools += tuple(
+                jnp.zeros((int(k["n_layers"]), n, self.block_size, w), store)
+                for _, w in layout)
+            self.kinds += (CacheKind(k["name"], k["n_layers"], layout, n,
+                                     k["window"],
+                                     slice(at, len(self.pools))),)
 
     @property
     def quantized(self) -> bool:
@@ -304,6 +388,8 @@ class PagedKVCache:
         names = [n for n, _ in self.layout]
         if self.quantized:
             names += ["k_scale", "v_scale"]
+        # (of the first kind: a window kind's blocks are another
+        # allocator's numbers)
         return {n: np.asarray(p[:, idx]) for n, p in zip(names, self.pools)}
 
     def nbytes(self) -> int:

@@ -16,7 +16,10 @@ sampling kernel from :mod:`mxnet_tpu.ops.sampling`; ``gen_verify``,
 A model (:func:`as_model`) is an object with ``step(params, tokens,
 positions, lengths, pools, block_tables, *, attention_kernel,
 mp_mesh=None, call=None, want_logits=True) -> (logits, pools, aux)`` —
-``pools`` the structure that came in, ``aux`` whatever else the program
+``pools`` the structure that came in (a model whose ``cache_spec()`` names
+several cache kinds gets their pools one kind after the other, and
+``block_tables`` as a tuple, a table a kind: docs/generation.md "Cache
+kinds"), ``aux`` whatever else the program
 must hand back (a one-token model: None, or a dict of scalar counts the
 engine sums into ``stats()["counts"]`` under the names in the model's
 ``counters``) — its ``vocab``, ``max_len``, ``heads``, ``cache_spec()``
@@ -28,7 +31,9 @@ row a step) and ``offers`` (the program families it can run).
 sparse experts, generation by diffusion over blocks) the second,
 :class:`~mxnet_tpu.parallel.latent_moe.LatentMoeLM` (latent attention over
 one latent pool, a gated dense layer, sigmoid-routed experts with a shared
-one, of which the chip holds a share) the third.
+one, of which the chip holds a share) the third,
+:class:`~mxnet_tpu.parallel.hybrid_moe.HybridMoeLM` (window and full
+attention layers over a cache of two kinds) the fourth.
 
 No step waits for the device: :meth:`GenerationPrograms.run` hands back
 what the jitted call returned, and its caller reads the sampled tokens
@@ -97,8 +102,13 @@ def _step_args(tokens, positions, lengths, block_tables, *sampler):
 
     if not isinstance(tokens, jax.Array):
         tokens = _np.asarray(tokens, _np.int32)
+    if isinstance(block_tables, tuple):
+        # a table a cache kind (docs/generation.md "Cache kinds")
+        block_tables = tuple(_np.asarray(t, _np.int32) for t in block_tables)
+    else:
+        block_tables = _np.asarray(block_tables, _np.int32)
     return (tokens,) + tuple(_np.asarray(a, _np.int32) for a in
-                             (positions, lengths, block_tables)) + tuple(
+                             (positions, lengths)) + (block_tables,) + tuple(
         _np.asarray(a, dt) for a, dt in zip(sampler, _SAMPLER_DTYPES))
 
 
@@ -372,8 +382,11 @@ class GenerationPrograms:
              k: Optional[int] = None) -> tuple:
         sig = (("kv_pool", cache.shape, str(cache.pools[0].dtype)),)
         if tokens is not None:
+            widths = tuple(block_tables.shape) \
+                if not isinstance(block_tables, tuple) \
+                else tuple(tuple(t.shape) for t in block_tables)
             sig = (("tokens", tuple(tokens.shape), "int32"),
-                   ("block_tables", tuple(block_tables.shape), "int32")) + sig
+                   ("block_tables", widths, "int32")) + sig
         # the paged kernel variant keys its programs separately, while
         # gather (TPUMX_PALLAS=0) keys stay byte-identical to the
         # pre-kernel layout — warm caches and freeze sets carry over

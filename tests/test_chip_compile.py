@@ -571,3 +571,93 @@ def test_latent_decode_step_program_compiles_at_the_cells_shapes(
     assert makers <= _IN_PLACE
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.5e9
+
+
+# -- MiMo-V2.5's window and full attention layers (parallel/hybrid_moe.py) ----
+_HYB = dict(full=(4, 0, False, 1024), window=(8, 128, True, None))
+
+
+@pytest.mark.parametrize("B,T", [(128, 1), (1, 512)],
+                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_tiles_body_compiles_at_the_cells_shapes(one_chip, kind, B, T):
+    """The tiles body at ``mimo-v2.5``'s published shapes: ``Hkv x 192``
+    K pages beside ``Hkv x 128`` V pages, 16 (full) and 8 (window) query
+    heads a KV head as rows, the full kind's table 1,024 wide (a 512 KB
+    table in scalar memory) and the window kind's a ring of 16 (decode) or
+    64 (a 512-token chunk), with the window and the sink."""
+    from mxnet_tpu.ops import paged_attention as pa
+
+    hkv, window, sink, width = _HYB[kind]
+    G = 64 // hkv
+    W = width or (16 if T == 1 else 64)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    shapes = [sds((B, W), jnp.int32), sds((B,), jnp.int32),
+              sds((1,), jnp.int32), sds((B, G * T, hkv * 192), jnp.float32),
+              sds((B, G * T), jnp.int32),
+              sds((2, 2048, BS, hkv * 192), jnp.bfloat16),
+              sds((2, 2048, BS, hkv * 128), jnp.bfloat16)]
+    if sink:
+        shapes.append(sds((G * T, hkv), jnp.float32))
+    phase = "decode" if T == 1 else "prefill"
+    text = _compile(
+        functools.partial(pa._tiles_call.__wrapped__, n_heads=hkv,
+                          scale=192 ** -0.5, interpret=False, groups=G,
+                          call=f"{kind}_{phase}", window=window), *shapes)
+    assert "tpu_custom_call" in text
+    assert f"_paged_call_w{W}_t{T}_{kind}_{phase}" in text
+
+
+def _hybrid_cell(sds, n_layers=3):
+    from mxnet_tpu.parallel import hybrid_moe as hm
+
+    cfg = hm.HybridMoeConfig(
+        vocab_size=19072, num_hidden_layers=n_layers,
+        hybrid_layer_pattern=(0, 1, 1, 1, 1, 0, 1)[:n_layers],
+        moe_layer_freq=(0, 1, 1, 1, 1, 1, 1)[:n_layers])
+    model = hm.HybridMoeLM(cfg, max_len=16384, experts_held=(0, 16))
+    params = {k: sds(s, jnp.bfloat16) for k, s in
+              hm.hybrid_moe_param_shapes(cfg, (0, 16)).items()}
+    return model, params
+
+
+@pytest.mark.parametrize("S,T,ring", [(128, 1, 16), (1, 512, 64)],
+                         ids=["decode", "prefill"])
+def test_hybrid_step_program_compiles_at_the_cells_shapes(one_chip,
+                                                          monkeypatch, S, T,
+                                                          ring):
+    """``gen_decode`` (128 rows) and ``gen_prefill`` (a 512-token chunk) as
+    the service dispatches them, at depth 3 (the dense layer with full
+    attention and two window expert layers: layers repeat): both kinds'
+    pools are updated in place, a table a kind, every attention call is
+    named for its kind and phase, the counts come back."""
+    from mxnet_tpu.serving.generation import programs as gp
+    from mxnet_tpu.serving.generation.kv_cache import PagedKVCache
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    model, params = _hybrid_cell(sds)
+    full, window = model.cache_spec()["kinds"]
+    assert [w for _, w in full["pools"]] == [768, 512]
+    assert [w for _, w in window["pools"]] == [1536, 1024]
+    pools = tuple(sds((k["n_layers"], nb, BS, w), jnp.bfloat16)
+                  for k, nb in ((full, 4096), (window, 1322))
+                  for _, w in k["pools"])
+    fn = jax.jit(functools.partial(gp._model_step, model=model,
+                                   attention_kernel="paged"),
+                 donate_argnums=(1,))
+    compiled = fn.lower(
+        params, pools, sds((S, T), jnp.int32), sds((S, T), jnp.int32),
+        sds((S,), jnp.int32),
+        (sds((S, 1024), jnp.int32), sds((S, ring), jnp.int32)),
+        sds((S,), jnp.uint32), sds((S,), jnp.uint32), sds((S,), jnp.float32),
+        sds((S,), jnp.int32), sds((S,), jnp.float32)).compile()
+    text = compiled.as_text()
+    phase = "decode" if T == 1 else "prefill"
+    assert f"_paged_call_w1024_t{T}_full_{phase}" in text
+    assert text.count(f"_paged_call_w{ring}_t{T}_window_{phase}") >= 2
+    for shape in (f"= bf16[1,4096,{BS},768]", f"= bf16[2,1322,{BS},1536]"):
+        makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
+                  for ln in text.splitlines() if shape in ln}
+        assert makers <= _IN_PLACE, (shape, makers)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
