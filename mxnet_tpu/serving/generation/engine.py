@@ -343,11 +343,12 @@ class _GenRequest:
         self.mode_tokens: Dict[str, int] = {}
         self.index_safe_len: Optional[int] = None
         # generation by diffusion over blocks (docs/generation.md): the
-        # block in flight at positions ctx_len.. — its token ids, which of
-        # them still hold MASK (a flag per position, never read off the
-        # id: a prompt may hold the mask id), the pass each was unmasked
-        # at, the pass number — and, per generated token, the pass that
-        # unmasked it (what a reference needs to rebuild the block states)
+        # open block at positions ctx_len.., as of the last pass that
+        # was READ — its token ids, which of them still hold MASK (a flag
+        # per position, never read off the id: a prompt may hold the mask
+        # id), the pass each was unmasked at, the pass number — and, per
+        # generated token, the pass that unmasked it (what a reference
+        # needs to rebuild the block states)
         self.block: Optional[List[int]] = None
         self.block_masked: List[bool] = []
         self.block_at: List[int] = []
@@ -436,26 +437,46 @@ class _StepInputs(NamedTuple):
                 *self.sampler)
 
 
+class _BlockPass(NamedTuple):
+    """What a block pass in flight holds beside a one-token step's: the
+    ``tokens`` / ``masked`` operands it was fed (on the device where they
+    were carried there), the experts it touched as the program returned
+    them, where each row stands after it by counts alone (``after``: id
+    -> the number of its next pass and the MASKs its block then holds),
+    and the prefill ``(chunks, tokens)`` dispatched since the pass
+    before, which are counted where the pass is."""
+    tokens: object
+    masked: object
+    touched: object
+    after: dict
+    prefill: tuple
+
+
 class _Flight(NamedTuple):
-    """A one-token decode step that has been dispatched and not read
-    (docs/generation.md "the step in flight"): the rows it fed, its
-    sampled tokens as :meth:`GenerationPrograms.run` returned them, the
-    clock around its dispatch, the iteration its participation events
-    name, the ids of its rows, and the counts (``aux``) of the programs
-    dispatched up to and with it that nobody has read yet."""
+    """A decode step that has been dispatched and not read
+    (docs/generation.md "the step in flight"): the rows it fed; what it
+    returned for the next step to be fed from and the host to read, as
+    the program handed it back — a one-token step's sampled ``tokens
+    (S,)``, a block pass's ``unmasked (S, L)``; the clock around its
+    dispatch; the iteration its participation events name; its rows'
+    ``lead`` (id -> the positions the row's context moves on when the
+    step lands: 1 for a token, ``L`` for a block's commit pass, 0 for a
+    denoise pass); the counts (``aux``) of the programs dispatched up to
+    and with it that nobody has read yet; a block pass's own state."""
     step: _StepInputs
     tokens: object
     t0: float
     t1: float
     iteration: int
-    rids: frozenset
+    lead: dict
     aux: tuple = ()
+    block: Optional[_BlockPass] = None
 
 
 class _LandFirst(Exception):
     """Raised under the schedule when a row of the step in flight has to
     leave its slot — a preemption, a cancel, a deadline, a shutdown that
-    does not drain: its newest token is still on the device, so the
+    does not drain: what the step gave it is still on the device, so the
     engine reads and emits that step first and schedules again."""
 
 
@@ -777,16 +798,20 @@ class GenerationService:
         self._consec_step_failures = 0
         self._max_error_requeues = 3  # error-path requeue budget per request
         self._iteration = 0
-        # the one-token decode step dispatched and not read yet; only a
+        # the decode step dispatched and not read yet — a one-token step
+        # or a block pass: either is built from counts alone, and what
+        # the last one returned stays on the device for it.  Only a
         # service whose every decode step is that step leaves one in
-        # flight — a verify chunk's proposer, a scan and a block step all
-        # need the last values on the host before they can build.  Nor
-        # under an mp mesh: a step's tokens come back committed to the
-        # mesh, and fed onward they would key a second lowering of every
-        # decode width
+        # flight: a verify chunk's proposer and a scan need the last
+        # values on the host before they can build.  Nor under an mp
+        # mesh: a step's tokens come back committed to the mesh, and fed
+        # onward they would key a second lowering of every decode width
         self._flight: Optional[_Flight] = None
-        self._runs_ahead = not (cfg.speculative or self._ms_buckets or L
+        self._runs_ahead = not (cfg.speculative or self._ms_buckets
                                 or cfg.mp_devices > 1)
+        # a block-diffusion model's prefill [chunks, tokens] dispatched
+        # since the last block pass was: counted with the next one
+        self._prefill_uncounted = [0, 0]
         self._membership: "deque[Tuple[int, Tuple[int, ...]]]" = \
             deque(maxlen=4096)
         self._worker: Optional[threading.Thread] = None
@@ -1108,7 +1133,7 @@ class GenerationService:
                 z = zeros(1, w)
                 toks, _ = self._programs.run("gen_decode", self._cache,
                                              *z.operands)
-            if self._runs_ahead:
+            if self._runs_ahead and widths:
                 # the step in flight hands its tokens on through one
                 # slot-sized program with no model in it
                 _synced(self._programs.carry_tokens(toks, z.tokens,
@@ -1143,16 +1168,22 @@ class GenerationService:
 
     def _warmup_block(self, sigs, widths) -> None:
         """A block-diffusion model's program set: the cache-filling
-        prefill per (T, W), the block step per table width."""
+        prefill per (T, W), the block step per table width, and the
+        slot-sized program with no model in it through which the pass in
+        flight hands its block state on."""
         S, L = self._config.max_slots, self._block_len
         for tb, wp in sigs:
             self._programs.run_fill(self._cache, *self._build_step(
                 (), tb, sampler=False, slots=1, width=wp).operands)
+        masked = _np.zeros((S, L), bool)
         for w in widths:
-            self._programs.run_block(
-                self._cache,
-                *self._build_step((), L, sampler=False, width=w).operands,
-                _np.zeros((S, L), bool), _np.zeros(S, _np.int32))
+            z = self._build_step((), L, sampler=False, width=w)
+            unmasked, _, _ = self._programs.run_block(
+                self._cache, *z.operands, masked, _np.zeros(S, _np.int32))
+        if self._runs_ahead:
+            _synced(*self._programs.carry_block(
+                unmasked, z.tokens, masked, z.tokens, masked,
+                _np.zeros(S, bool)))
 
     def stop(self, drain: bool = True, timeout: Optional[float] = None,
              reject_queued: bool = False) -> None:
@@ -1303,11 +1334,11 @@ class GenerationService:
                     self._prefill(req)
                 except Exception as exc:  # noqa: BLE001 — isolate
                     self._requeue_or_fail(req, exc)
-            # a row whose token in flight is its last is not fed again
+            # a row whose token (or block) in flight is its last is not
+            # fed again
             running = [r for r in self._slots
                        if r is not None and r.state == _RUNNING
-                       and not (self._lead(r)
-                                and r.n_generated + 1 >= r.max_new)]
+                       and not self._ends_in_flight(r)]
             self._membership.append(
                 (self._iteration,
                  tuple(sorted(r.rid for r in running))))
@@ -1378,12 +1409,30 @@ class GenerationService:
                         for r in self._slots if r is not None}
         return admitted, progress
 
-    def _lead(self, r: _GenRequest) -> int:
-        """1 while ``r``'s newest token is that of the step in flight:
-        the host's ``ctx_len`` and ``n_generated`` are then one behind
-        what the device has written and sampled."""
+    def _flies(self, r: _GenRequest) -> bool:
+        """Whether ``r`` is a row of the step in flight: what that step
+        gave it — a token, a block's unmasked positions, its commit — is
+        on the device, and the host's view of the row one step behind."""
         f = self._flight
-        return int(f is not None and r.rid in f.rids)
+        return f is not None and r.rid in f.lead
+
+    def _lead(self, r: _GenRequest) -> int:
+        """The positions ``r``'s context moves on when the step in flight
+        lands, known from counts: 1 for a token, a block for a block's
+        commit pass, 0 for a denoise pass (and for a row not in flight).
+        The host's ``ctx_len`` is that far behind what the device has
+        written."""
+        f = self._flight
+        return 0 if f is None else f.lead.get(r.rid, 0)
+
+    def _ends_in_flight(self, r: _GenRequest) -> bool:
+        """Whether the step in flight brings ``r`` to its
+        ``max_new_tokens``: the token it sampled, or the block it
+        commits, whose tokens the sequence does not hold yet."""
+        lead = self._lead(r)
+        new = (r.ctx_len + lead - len(r.seq_tokens)) if self._block_len \
+            else lead
+        return new > 0 and r.n_generated + new >= r.max_new
 
     # -- scheduling (all _locked helpers hold self._lock) -------------------------
     def _purge_waiting_locked(self) -> None:
@@ -1410,14 +1459,14 @@ class GenerationService:
             if r is None:
                 continue
             if r.cancel_requested and r.state == _RUNNING:
-                if self._lead(r):
+                if self._flies(r):
                     raise _LandFirst
                 self._counts["cancelled"] += 1
                 self._release_slot_locked(i, reason=_CANCELLED)
             elif r.state in (_FINISHED, _FAILED, _CANCELLED):
                 self._release_slot_locked(i)
             elif r.expired(now):
-                if self._lead(r):
+                if self._flies(r):
                     raise _LandFirst
                 self._counts["expired"] += 1
                 self._release_slot_locked(i, error=DeadlineExceededError(
@@ -1652,7 +1701,7 @@ class GenerationService:
         it through the chunked-prefill rungs (tokens stay bit-identical:
         sampling is keyed on (seed, position) only)."""
         r = self._slots[i]
-        if self._lead(r):
+        if self._flies(r):
             raise _LandFirst
         r.seg("preempted", time.perf_counter())
         with _obs.span("serving.preempt", cat="serving",
@@ -2104,13 +2153,13 @@ class GenerationService:
         single-token step.  All three paths emit identical token VALUES —
         they differ only in how many tokens one device dispatch yields.
 
-        ``ahead`` lets the single-token step of a service that runs no
-        other kind leave its tokens on the device (docs/generation.md
-        "the step in flight"); every other step is read before it
-        returns."""
+        ``ahead`` lets the single-token step, or the block pass, of a
+        service that runs no other kind leave what it returned on the
+        device (docs/generation.md "the step in flight"); every other
+        step is read before it returns."""
         cfg = self._config
         if self._block_len:
-            self._block_step(batch)
+            self._block_step(batch, ahead and self._runs_ahead)
             return
         if cfg.speculative:
             drafts = self._propose_drafts(batch)
@@ -2127,14 +2176,14 @@ class GenerationService:
                     writes: Optional[int] = None, sampler: bool = True,
                     slots: Optional[int] = None,
                     width: Optional[int] = None,
-                    lead=frozenset()) -> _StepInputs:
+                    lead=None) -> _StepInputs:
         """The host side of one model step, written once for every step
         kind.  The slots are walked ONCE: a row is a slot whose running
         request is in ``batch`` (slots outside it stay inactive: length
         0, null-block table).  A row feeds ``feed(request)`` — up to ``T``
-        token ids — at positions ``ctx_len ..`` (one further for a row
-        whose id is in ``lead``: its newest token is that of the step in
-        flight, which wrote ``ctx_len``) and will write ``writes``
+        token ids — at positions ``ctx_len ..`` (as much further as
+        ``lead``, the step in flight's, says of a row: that step wrote
+        up to there) and will write ``writes``
         positions there (default: as many as it feeds); before that write
         its span is made private, then its ``tokens (S, T)``,
         ``positions``, ``lengths`` and, with ``sampler``, its seed, the
@@ -2149,6 +2198,7 @@ class GenerationService:
         cfg = self._config
         S = slots or cfg.max_slots
         rids = {r.rid for r in batch if r.state == _RUNNING}
+        lead = lead or {}
         # the token rows are laid out in one flat Python list and the
         # positions in one array expression, each converted once: a NumPy
         # assignment a row costs more than the row
@@ -2166,7 +2216,7 @@ class GenerationService:
             if r is None or r.state != _RUNNING or r.rid not in rids:
                 continue
             fed = feed(r)
-            n, c = len(fed), r.ctx_len + (r.rid in lead)
+            n, c = len(fed), r.ctx_len + lead.get(r.rid, 0)
             span = writes or n
             # copy-on-write append: a row about to scatter into a shared
             # block (refcount > 1) gets a private copy first — shared
@@ -2252,7 +2302,7 @@ class GenerationService:
         step's tokens are read and emitted.  With ``ahead`` the step
         stays in flight itself; otherwise it is read before returning."""
         last = self._flight
-        lead = last.rids if last is not None else frozenset()
+        lead = last.lead if last is not None else {}
         with self._phase("build", "serving.decode.build"):
             b = self._build_step(
                 batch, 1, lambda r: [0 if r.rid in lead
@@ -2276,21 +2326,35 @@ class GenerationService:
                                              tokens, *b.operands[1:])
             step = _Flight(
                 b, next_tok, t_step0, time.perf_counter(), self._iteration,
-                frozenset(r.rid for _, r in b.rows),
-                self._programs.take_aux())
-            self._counts["steps_drained" if last is None
-                         else "steps_ahead"] += 1
-            # the reads, both inside this span: the last step's, which the
-            # device has had a whole host iteration to finish (if it
-            # fails, that step stays the one in flight and this one, fed
-            # from it, is dropped), and without ``ahead`` this step's own
-            read = None if last is None else _synced(last.tokens)
-            self._flight = step if ahead else None
-            own = None if ahead else _synced(next_tok)
-        if last is not None:
-            self._emit_flight(last, read)
+                {r.rid: 1 for _, r in b.rows}, self._programs.take_aux())
+            reads = self._fly(step, ahead)
+        for f, read in reads:
+            self._emit_flight(f, read)
+
+    def _fly(self, step: _Flight, ahead: bool) -> list:
+        """What follows a step's dispatch, inside its span: count it, then
+        the reads — the last step's, which the device has had a whole
+        host iteration to finish (if it fails, that step stays the one
+        in flight and this one, fed from it, is dropped), and without
+        ``ahead`` this step's own, which otherwise stays in flight.
+        Returns ``[(step, what was read of it)]``, to be emitted in that
+        order."""
+        last = self._flight
+        self._counts["steps_drained" if last is None else "steps_ahead"] += 1
+        reads = [] if last is None else [(last, self._read(last))]
+        self._flight = step if ahead else None
         if not ahead:
-            self._emit_flight(step, own)
+            reads.append((step, self._read(step)))
+        return reads
+
+    @staticmethod
+    def _read(f: _Flight):
+        """Wait for a step and read what the host needs of it: its
+        tokens, and with a block pass's ``unmasked`` the experts it
+        touched, in one go."""
+        if f.block is None:
+            return _synced(f.tokens)
+        return _synced(f.tokens, f.block.touched)
 
     def _count_aux(self, auxes) -> None:
         """Sum finished programs' counts into ``stats()["counts"]``: every
@@ -2311,19 +2375,26 @@ class GenerationService:
         if f is None:
             return
         try:
-            with self._phase("step", "serving.decode",
+            with self._phase("step", "serving.decode" if f.block is None
+                             else "serving.block_step",
                              args={"iteration": f.iteration}):
-                read = _synced(f.tokens)
+                read = self._read(f)
         except Exception as exc:  # noqa: BLE001 — the device's error
             self._note_step_failure(exc)
             return
         self._emit_flight(f, read)
 
-    def _emit_flight(self, f: _Flight, next_tok) -> None:
-        """Emit a one-token step's tokens.  A row that ended since the
-        step was dispatched (an end-of-sequence id found a step late) or
-        was cancelled takes nothing: its token is dropped, and its K/V at
-        ``ctx_len`` is past what the prefix index is ever shown."""
+    def _emit_flight(self, f: _Flight, read) -> None:
+        """Emit what a step gave its rows: a one-token step's tokens, a
+        block pass's unmasked positions and commits.  A row that ended
+        since the step was dispatched (an end-of-sequence id found a
+        step late) or was cancelled takes nothing: its token is dropped,
+        and its K/V at ``ctx_len`` is past what the prefix index is ever
+        shown."""
+        if f.block is not None:
+            self._emit_block(f, *read)
+            return
+        next_tok = read
         with self._phase("emit", "serving.emit"):
             self._count_aux(f.aux)
             traced = _trace.enabled()
@@ -2507,15 +2578,17 @@ class GenerationService:
                                         table)
             r.rung_s[tb] = r.rung_s.get(tb, 0.0) \
                 + (time.perf_counter() - t_rung0)
-        self._counts["prefill_tokens"] += sum(p[1] for p in plan)
-        self._counts["block_prefill_chunks"] += len(plan)
+        # counted where the next block pass is, when that has been read
+        self._prefill_uncounted[0] += len(plan)
+        self._prefill_uncounted[1] += sum(p[1] for p in plan)
         r.seg("decode", time.perf_counter())
         if self._prefix is not None and not resumed and ctx > 0:
             self._prefix.insert(r.seq_tokens[:ctx], r.blocks)
         r.ctx_len = ctx
         self._open_block(r)
 
-    def _block_step(self, batch: List[_GenRequest]) -> None:
+    def _block_step(self, batch: List[_GenRequest],
+                    ahead: bool = False) -> None:
         """One pass of generation by diffusion over blocks, sibling of
         :meth:`_spec_step`: every running row feeds its block of ``L``
         token ids at ``ctx_len .. ctx_len + L - 1`` (K/V written there,
@@ -2524,45 +2597,108 @@ class GenerationService:
         pass: the program unmasks its most confident masked positions.  A
         row with none left is on its commit pass — the same program, so
         rows in different passes share one batch — after which the block's
-        K/V are those of its finished tokens: they are emitted at once,
-        ``ctx_len`` moves a block on, a fresh block opens."""
+        K/V are those of its finished tokens: ``ctx_len`` moves a block
+        on, a fresh block opens.
+
+        Like :meth:`_single_step` the pass is built from counts alone
+        and dispatched BEFORE the pass in flight is read: a denoise pass
+        unmasks ``min(n_unmask, MASKs left)`` positions whatever the
+        logits, so where a row of that pass stands after it — which pass
+        of which block, how many MASKs — is known, and WHICH positions it
+        unmasked with WHICH ids stays on the device: a row that
+        continues its block takes its tokens and flags from there
+        (``carry_block``), a row that opens a block (after its commit
+        pass, or on joining) brings them from the host.  With ``ahead``
+        the pass stays in flight itself; otherwise it is read before
+        returning.  The values — the block's tokens to emit, the pass
+        that unmasked each, an end-of-sequence id — reach the host when
+        the pass lands (:meth:`_emit_block`)."""
         S, L = self._config.max_slots, self._block_len
         schedule = self._model.unmask_schedule
+        last = self._flight
+        lead = last.lead if last is not None else {}
         with self._phase("build", "serving.block.build"):
-            b = self._build_step(batch, L, lambda r: r.block, sampler=False)
-            rows = b.rows
+            fresh = [self._model.mask_id] * L
+            b = self._build_step(
+                batch, L, lambda r: fresh if r.rid in lead else r.block,
+                sampler=False, lead=lead)
             masked = _np.zeros((S, L), bool)
             n_unmask = _np.zeros(S, _np.int32)
-            for i, r in rows:
-                masked[i] = r.block_masked
-                if any(r.block_masked):
-                    n_unmask[i] = schedule[min(r.block_pass,
-                                               len(schedule) - 1)]
+            keep = _np.zeros(S, bool)
+            after, moves = {}, {}
+            for i, r in b.rows:
+                if r.rid in lead:
+                    # past the pass in flight: on in its block, whose
+                    # state the device has, or at a fresh one, all MASK
+                    at, left = last.block.after[r.rid]
+                    keep[i] = not lead[r.rid]
+                    masked[i] = True
+                else:
+                    at, left = r.block_pass, sum(r.block_masked)
+                    masked[i] = r.block_masked
+                if left:
+                    n_unmask[i] = n = schedule[min(at, len(schedule) - 1)]
+                    after[r.rid] = (at + 1, left - min(n, left))
+                    moves[r.rid] = 0
+                else:
+                    after[r.rid] = (0, L)
+                    moves[r.rid] = L
+            tokens = b.tokens
+            if last is not None:
+                tokens, masked = self._programs.carry_block(
+                    last.tokens, last.block.tokens, last.block.masked,
+                    tokens, masked, keep)
+        t_step0 = time.perf_counter()
         with self._phase("step", "serving.block_step",
-                         args={"running": len(rows), "width": b.width,
-                               "iteration": self._iteration}):
+                         args={"running": len(b.rows), "width": b.width,
+                               "iteration": self._iteration,
+                               "ahead": last is not None}):
+            # (positional: a wrapper around run_block hands it through)
             unmasked, touched, _ = self._programs.run_block(
-                self._cache, *b.operands, masked, n_unmask)
+                self._cache, tokens, *b.operands[1:], masked, n_unmask,
+                False)
+            prefill, self._prefill_uncounted = \
+                tuple(self._prefill_uncounted), [0, 0]
+            reads = self._fly(_Flight(
+                b, unmasked, t_step0, time.perf_counter(), self._iteration,
+                moves, (), _BlockPass(tokens, masked, touched, after,
+                                      prefill)), ahead)
+        for f, read in reads:
+            self._emit_flight(f, read)
+
+    def _emit_block(self, f: _Flight, unmasked, touched) -> None:
+        """A block pass has been read: fill in what needed its values.
+        A row on a denoise pass takes the ids of the positions it
+        unmasked and the pass that unmasked them; a row on its commit
+        pass emits its block.  Every ``block_*`` count of the pass (and
+        of the prefill chunks dispatched before it) is made here, at one
+        point of its life, so that a reader of ``stats()`` finds them
+        belonging together."""
         with self._phase("emit", "serving.emit"):
             counts = self._counts
+            rows = f.step.rows
+            unmasked = unmasked.tolist()
             for i, r in rows:
+                if r.state != _RUNNING or r.cancel_requested:
+                    continue
                 r.decode_steps += 1
-                if not any(r.block_masked):
+                if f.lead[r.rid]:
                     counts["block_commit_row_passes"] += 1
                     self._commit_block(r)
                     continue
-                for j in range(L):
-                    if r.block_masked[j] and unmasked[i, j] >= 0:
-                        r.block[j] = int(unmasked[i, j])
+                for j, tok in enumerate(unmasked[i]):
+                    if tok >= 0 and r.block_masked[j]:
+                        r.block[j] = tok
                         r.block_masked[j] = False
                         r.block_at[j] = r.block_pass
                 r.block_pass += 1
             counts["block_passes"] += 1
-            counts["steps_drained"] += 1
             counts["block_row_passes"] += len(rows)
-            counts["block_ctx_tokens"] += int(b.positions[:, -1].sum()) \
-                + len(rows)
+            counts["block_ctx_tokens"] += \
+                int(f.step.positions[:, -1].sum()) + len(rows)
             counts["block_experts_touched"] += int(touched)
+            counts["block_prefill_chunks"] += f.block.prefill[0]
+            counts["prefill_tokens"] += f.block.prefill[1]
 
     def _commit_block(self, r: _GenRequest) -> None:
         """After a row's commit pass: emit the block's tokens the

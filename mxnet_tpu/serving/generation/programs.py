@@ -42,6 +42,9 @@ dispatched the NEXT decode step, whose ``tokens`` operand
 :meth:`GenerationPrograms.carry_tokens` merges on the device from the
 tokens still there and the host's (docs/generation.md "The step in
 flight"; one slot-sized program, no model in it, not a signature below).
+A block pass hands its block state on the same way
+(:meth:`GenerationPrograms.run_block` without ``read``,
+:meth:`GenerationPrograms.carry_block`).
 
 Each distinct ``(kind, batch, chunk, table-width)`` signature compiles
 exactly once.  Every kind runs through :meth:`GenerationPrograms._run`,
@@ -153,6 +156,21 @@ def _carry(prev, tokens, keep):
     import jax.numpy as jnp
 
     return jnp.where(keep[:, None], prev[:, None].astype(jnp.int32), tokens)
+
+
+def _carry_block(unmasked, prev_tokens, prev_masked, tokens, masked, keep):
+    """The next block pass's ``tokens (S, L)`` and ``masked (S, L)``: in
+    the rows that ``keep`` (they continue their block) what the last pass
+    was fed, ``prev_tokens`` / ``prev_masked``, with the positions it
+    unmasked filled in from its ``unmasked (S, L)`` (the new id, -1 where
+    nothing was unmasked); the host's everywhere else.  No model in it:
+    one slot-sized program a service."""
+    import jax.numpy as jnp
+
+    took = unmasked >= 0
+    keep = keep[:, None]
+    return (jnp.where(keep, jnp.where(took, unmasked, prev_tokens), tokens),
+            jnp.where(keep, prev_masked & ~took, masked))
 
 
 def _model_step(params, pools, tokens, positions, lengths, block_tables,
@@ -330,7 +348,8 @@ class GenerationPrograms:
         self._jits: Dict[tuple, object] = {}
         import jax
 
-        self._carry_jit = jax.jit(_carry)   # traces nothing until called
+        # (creating one traces nothing)
+        self._carry_jit = jax.jit(_carry_block if model.block_len else _carry)
         self._aux: list = []                # run()s' aux nobody took yet
         self._lock = threading.Lock()
         self._stats: Dict[tuple, Dict[str, int]] = {}
@@ -504,14 +523,36 @@ class GenerationPrograms:
             tokens, positions, lengths, block_tables))
 
     def run_block(self, cache, tokens, positions, lengths, block_tables,
-                  masked, n_unmask):
+                  masked, n_unmask, read=True):
         """One block step (site ``gen_block``): returns ``(unmasked np(S,
         L), experts touched np(), logits (S, L, vocab) on the device)``
-        — see :func:`_block_step`."""
+        — see :func:`_block_step`.  Without ``read`` all three come back
+        as the jitted call returned them, on the device and not waited
+        for (the engine's pass in flight: it reads the first two a pass
+        late, docs/generation.md "The step in flight"), and ``tokens``
+        and ``masked`` already on the device (:meth:`carry_block`) go in
+        as they are."""
+        import jax
+
+        if not isinstance(masked, jax.Array):
+            masked = _np.asarray(masked, _np.bool_)
         unmasked, touched, logits = self._run("gen_block", cache, _step_args(
             tokens, positions, lengths, block_tables) + (
-            _np.asarray(masked, _np.bool_), _np.asarray(n_unmask, _np.int32)))
+            masked, _np.asarray(n_unmask, _np.int32)))
+        if not read:
+            return unmasked, touched, logits
         return _synced(unmasked, touched) + (logits,)
+
+    def carry_block(self, unmasked, prev_tokens, prev_masked, tokens, masked,
+                    keep):
+        """The ``tokens`` and ``masked`` operands of a block pass
+        dispatched while the last one's ``unmasked (S, L)`` is still on
+        the device (:func:`_carry_block`): merged there without a read,
+        whatever of the rest is on the host."""
+        return self._carry_jit(
+            unmasked, prev_tokens, prev_masked,
+            _np.asarray(tokens, _np.int32), _np.asarray(masked, _np.bool_),
+            _np.asarray(keep, _np.bool_))
 
     def run_multistep(self, k: int, cache, tokens, positions, lengths,
                       block_tables, seeds, counters, temperature, top_k,

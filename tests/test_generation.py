@@ -1249,6 +1249,43 @@ def test_next_step_is_dispatched_before_the_last_one_is_read(
     assert stats["iterations"] == n + 1
 
 
+@pytest.mark.parametrize("news", [(6, 6), (3, 9)])
+def test_lead_counts_positions_on_the_one_token_path(params, news):
+    """``_lead`` speaks of positions since a block pass rides the step in
+    flight too: on the one-token path it reads 1 for a row of the step in
+    flight and 0 for any other, a row whose token in flight is its last
+    is not fed again, and the tokens and the ``steps_ahead`` share are
+    what they were."""
+    svc = GenerationService(params, CFG, _gc(max_slots=2), start=False)
+    svc.warmup()
+    rs = np.random.RandomState(11)
+    prompts = [rs.randint(0, CFG.vocab, n) for n in (5, 9)]
+    hs = [svc.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    reqs = [h._req for h in hs]
+    for _ in range(40):
+        if all(h.finished for h in hs):
+            break
+        svc._iterate()
+        f = svc._flight
+        for r in reqs:
+            flies = f is not None and r.rid in f.lead
+            assert svc._flies(r) == flies and svc._lead(r) == int(flies)
+            assert svc._ends_in_flight(r) == (
+                flies and r.n_generated + 1 >= r.max_new)
+    svc._iterate()                       # retires the last slot
+    c = svc.stats()["counts"]
+    fed = [set(rids) for _, rids in svc.membership_history()]
+    svc.stop(drain=False, timeout=30)
+    for h, p, n in zip(hs, prompts, news):
+        assert h.result(1) == _greedy_oracle(params, p, n)
+    # a request's first token is its prefill's: n - 1 decode steps feed it
+    for r, n in zip(reqs, news):
+        assert sum(r.rid in rids for rids in fed) == n - 1
+    steps = max(news) - 1
+    assert (c["steps_ahead"], c["steps_drained"]) == (steps - 1, 1)
+    assert svc._flight is None and c["failed"] == 0
+
+
 def test_end_of_sequence_row_takes_nothing_after_it(params):
     """(c) a row that ends on an end-of-sequence id is found a step late:
     the token of its extra step is dropped, and the prefix index is shown

@@ -22,6 +22,7 @@ from mxnet_tpu.ops.paged_attention import (paged_attention,
 from mxnet_tpu.parallel import sdar_moe as sm
 from mxnet_tpu.serving.bucketing import pad_tokens_right
 from mxnet_tpu.serving.generation import GenerationConfig, GenerationService
+from mxnet_tpu.serving.generation.engine import _LandFirst
 from mxnet_tpu.serving.generation.kv_cache import blocks_for
 from perfbench.reference import sdar_moe as ref
 
@@ -354,6 +355,7 @@ def test_prefix_cache_hit_gives_the_same_logits(params):
     prompt = np.random.default_rng(5).integers(0, 97, 27)
     first = svc.generate(prompt, max_new_tokens=6, timeout=120)
     n_first = len(seen)
+    assert n_first >= 2 * (STEPS + 1)
     second = svc.generate(prompt, max_new_tokens=6, timeout=120)
     st = svc.stats()
     svc.stop(drain=False, timeout=30)
@@ -379,6 +381,11 @@ def test_preemption_mid_block_resumes_to_the_same_tokens(params,
     for _ in range(after_passes):
         svc._iterate()
     r = stream._req
+    # a pass is in flight: a row leaves its slot at rest, as the
+    # scheduler has it (_LandFirst)
+    with svc._lock, pytest.raises(_LandFirst):
+        svc._preempt_slot_locked(svc._slots.index(r))
+    svc._land()
     assert r.block is not None and r.block_pass > 0 and any(r.block_masked)
     committed = list(r.generated)
     with svc._lock:
@@ -477,3 +484,326 @@ def test_block_unmask_picks_most_confident_masked_ties_to_lower_position():
                                            jnp.asarray(masked),
                                            jnp.asarray([4, 4, 4])))
     assert (out >= 0).tolist() == masked.tolist()
+
+
+# -- the pass in flight (docs/generation.md "The step in flight") -----------------
+def _drive(svc, streams, limit=2000):
+    """Run the loop by hand until every stream has ended."""
+    for _ in range(limit):
+        if all(st.finished for st in streams):
+            break
+        svc._iterate()
+    assert all(st.finished for st in streams)
+    svc._iterate()                    # the pass that retires the last slot
+
+
+def _watch_dispatches(svc, log):
+    """Log every block pass the engine dispatches: the first position and
+    the request of every fed row."""
+    inner = svc._programs.run_block
+
+    def run_block(cache, tokens, positions, lengths, *rest):
+        log.append({svc._slots[i].rid: int(positions[i, 0])
+                    for i in np.flatnonzero(lengths)})
+        return inner(cache, tokens, positions, lengths, *rest)
+
+    svc._programs.run_block = run_block
+
+
+@pytest.mark.parametrize("plens,news,joins", [
+    ((6, 17, 9), (7, 12, 5), ()),             # leftovers 2, 1, 1
+    ((16, 8), (8, 16), ((13, 9),)),           # whole blocks; one joins
+    ((3, 30, 12, 5), (4, 9, 16, 8), ((21, 11), (64, 6)))])
+def test_pass_in_flight_serves_the_reference_procedure(params, plens, news,
+                                                       joins):
+    """Pass n + 1 is dispatched before pass n is read, for rows in
+    different passes of different blocks, a row that joins while others
+    continue and prompts with a leftover: the served tokens and the pass
+    that unmasked each are the reference procedure's."""
+    svc = _service(params)
+    svc.warmup()
+    assert svc._runs_ahead
+    rng = np.random.default_rng(sum(plens))
+    prompts = [rng.integers(0, 97, n) for n in plens]
+    late = [(rng.integers(0, 97, n), m) for n, m in joins]
+    streams, pending = [], list(late)
+
+    def on_token(rid, tok):           # a request joins at a commit
+        if pending:
+            p, m = pending.pop()
+            streams.append(svc.submit(p, max_new_tokens=m))
+
+    for p, n in zip(prompts, news):
+        streams.append(svc.submit(p, max_new_tokens=n, on_token=on_token))
+    _drive(svc, streams)
+    c = svc.stats()["counts"]
+    svc.stop(drain=False, timeout=30)
+    for st, (p, n) in zip(streams, list(zip(prompts, news)) + late[::-1]):
+        want, passes = _ref_generate(params, p, n)
+        assert st.result(1) == want
+        assert st._req.unmask_pass == passes
+    assert len(streams) == len(plens) + len(joins)
+    assert c["steps_ahead"] + c["steps_drained"] == c["block_passes"]
+    assert c["steps_ahead"] >= 0.9 * c["block_passes"] > 0
+    assert c["failed"] == 0 and svc._flight is None
+    assert c["tokens"] == sum(news) + sum(m for _, m in joins)
+
+
+def test_next_pass_is_dispatched_before_the_last_one_is_read(params,
+                                                             monkeypatch):
+    from mxnet_tpu.serving.generation import engine as engine_mod
+
+    log, passes, kept = [], {}, []
+    synced = engine_mod._synced
+
+    def reading(*outs):
+        if id(outs[0]) in passes:
+            log.append(("read", passes[id(outs[0])]))
+        return synced(*outs)
+
+    monkeypatch.setattr(engine_mod, "_synced", reading)
+    svc = _service(params)
+    svc.warmup()
+    inner = svc._programs.run_block
+
+    def run_block(*args):
+        out = inner(*args)
+        kept.append(out[0])             # ids stay unique while these live
+        passes[id(out[0])] = len(passes)
+        log.append(("dispatch", passes[id(out[0])]))
+        return out
+
+    svc._programs.run_block = run_block
+    rng = np.random.default_rng(4)
+    streams = [svc.submit(rng.integers(0, 97, n), max_new_tokens=8)
+               for n in (8, 20, 12)]
+    _drive(svc, streams)
+    stats = svc.stats()
+    svc.stop(drain=False, timeout=30)
+    # two blocks of 4 + 1 passes a row, all admitted in the first pass
+    n = len(passes)
+    assert n == 10 and [e for e in log if e[0] == "read"] == [
+        ("read", i) for i in range(n)]
+    for i in range(n - 1):
+        assert log.index(("dispatch", i + 1)) < log.index(("read", i)), i
+    c = stats["counts"]
+    assert (c["steps_ahead"], c["steps_drained"]) == (n - 1, 1)
+    assert c["block_passes"] == n and c["block_row_passes"] == 3 * n
+    assert c["block_commit_row_passes"] == 6
+
+
+def test_eos_found_a_pass_late_emits_nothing_past_the_cut(params):
+    """The commit pass that holds an end-of-sequence id is read after the
+    next block's first pass was dispatched: that pass is dropped, nothing
+    is emitted past the cut, the prefix index is shown nothing the extra
+    pass wrote, and the slot and its pages come back."""
+    prompt = np.random.default_rng(123).integers(0, 97, 23)
+    full = _ref_generate(params, prompt, 13)[0]
+    eos = full[2]                       # in the second block of three
+    cut = full[:full.index(eos) + 1]
+    svc = _service(params)
+    svc.warmup()
+    fed, shown, seen = [], [], []
+    _watch_dispatches(svc, fed)
+    insert = svc._prefix.insert
+    svc._prefix.insert = lambda toks, blocks: (
+        shown.append(list(toks)), insert(toks, blocks))[1]
+    other = svc.submit(np.arange(11) % 97, max_new_tokens=16)
+    st = svc.submit(prompt, max_new_tokens=13, eos_token=eos,
+                    on_token=lambda rid, t: seen.append(t))
+    _drive(svc, [st, other])
+    c = svc.stats()["counts"]
+    assert st.result(1) == seen == cut and st.finish_reason == "eos"
+    assert other.result(1) == _ref_generate(params, np.arange(11) % 97,
+                                            16)[0]
+    r = st._req
+    assert r.ctx_len == len(prompt) + len(cut) and r.unmask_pass == \
+        _ref_generate(params, prompt, 13)[1][:len(cut)]
+    # the extra pass did run: the row was fed at the block after the one
+    # that held the id, and took nothing from it
+    ended_at = (len(prompt) + len(cut) - 1) // L * L
+    assert any(row.get(r.rid) == ended_at + L for row in fed)
+    assert r.decode_steps == 2 + STEPS + 1     # a block of 1, a block of 4
+    mine = [t for t in shown if t[:len(prompt)] == list(prompt)]
+    assert mine and max(len(t) for t in mine) == len(prompt) + len(cut)
+    assert all(s is None for s in svc._slots) and svc._flight is None
+    assert c["failed"] == 0 and c["tokens"] == len(cut) + 16
+    svc.stop(drain=False, timeout=30)
+    assert svc._cache.allocator.num_used == 0
+
+
+def _landing_first(svc, monkeypatch, calls):
+    """Record every slot a request leaves: refused with a pass in flight
+    (``land first``), or at rest."""
+    preempt = svc._preempt_slot_locked
+
+    def preempting(i, counter="preempted"):
+        r = svc._slots[i]
+        try:
+            preempt(i, counter)
+        except _LandFirst:
+            calls.append(("land first", r.rid, len(r.unmask_pass)))
+            raise
+        calls.append((counter, r.rid, len(r.unmask_pass)))
+
+    monkeypatch.setattr(svc, "_preempt_slot_locked", preempting)
+
+
+def test_preemption_lands_the_pass_in_flight_first(params, monkeypatch):
+    svc = _service(params, num_blocks=14, watermark_high=0.9,
+                   watermark_low=0.6)
+    svc.warmup()
+    calls = []
+    _landing_first(svc, monkeypatch, calls)
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(0, 97, n) for n in (20, 18, 22, 17)]
+    streams = [svc.submit(p, max_new_tokens=20) for p in prompts]
+    _drive(svc, streams, limit=4000)
+    c = svc.stats()["counts"]
+    svc.stop(drain=False, timeout=30)
+    for st, p in zip(streams, prompts):
+        want, passes = _ref_generate(params, p, 20)
+        assert st.result(1) == want and st._req.unmask_pass == passes
+    assert c["preempted"] >= 1 and c["failed"] == 0 and c["steps_ahead"] > 0
+    # the first preemption met a pass in flight: it was refused, the pass
+    # landed, and the same row left at rest
+    first = calls.index(next(x for x in calls if x[0] == "land first"))
+    assert calls[first + 1][:2] == ("preempted", calls[first][1])
+    assert calls[first + 1][2] >= calls[first][2]
+
+
+def test_cancel_lands_the_pass_in_flight_first(params):
+    svc = _service(params)
+    svc.warmup()
+    rng = np.random.default_rng(31)
+    p_keep, p_gone = rng.integers(0, 97, 10), rng.integers(0, 97, 14)
+    seen, handle = [], {}
+
+    def on_token(rid, tok):
+        seen.append(tok)
+        if len(seen) == 6:              # its first block of 4 and 2 more
+            handle["st"].cancel()
+
+    keep = svc.submit(p_keep, max_new_tokens=16)
+    handle["st"] = gone = svc.submit(p_gone, max_new_tokens=16,
+                                     on_token=on_token)
+    _drive(svc, [keep, gone])
+    c = svc.stats()["counts"]
+    want = _ref_generate(params, p_gone, 16)[0]
+    # cancelled at a commit, with its next block's first pass in flight:
+    # that pass lands, gives the row nothing, and the slot is free
+    assert gone.finish_reason == "cancelled" and seen == want[:len(seen)]
+    assert len(seen) == 6 and gone._req.n_generated == 6
+    assert keep.result(1) == _ref_generate(params, p_keep, 16)[0]
+    assert c["cancelled"] == 1 and c["failed"] == 0
+    assert all(s is None for s in svc._slots) and svc._flight is None
+    svc.stop(drain=False, timeout=30)
+
+
+def test_stop_without_drain_lands_the_pass_in_flight_first(params):
+    from mxnet_tpu.serving import ServingClosedError
+
+    svc = _service(params)
+    svc.warmup()
+    prompt = np.random.default_rng(41).integers(0, 97, 9)
+    seen = []
+    st = svc.submit(prompt, max_new_tokens=24,
+                    on_token=lambda rid, t: seen.append(t))
+    for _ in range(9):
+        svc._iterate()
+    before = list(seen)
+    assert svc._flight is not None and len(before) == 3   # leftover 1
+    with svc._lock:
+        svc._closed, svc._drain = True, False
+    assert svc._iterate() is False
+    # the pass in flight was the second block's commit: it was read and
+    # its tokens emitted before the stream was failed
+    want = _ref_generate(params, prompt, 24)[0]
+    assert svc._flight is None and seen == want[:7]
+    with pytest.raises(ServingClosedError):
+        st.result(1)
+    assert all(s is None for s in svc._slots)
+
+
+@pytest.mark.parametrize("fault", ["step", "read", "read_twice"])
+def test_failure_with_a_pass_in_flight_costs_no_token(params, monkeypatch,
+                                                      fault):
+    """An injected step failure (before the 5th dispatch, the 4th pass
+    unread) and a read of the pass in flight that raises once or twice:
+    the pass before lands, or is given up and its rows fed from the
+    host's view again; every stream is the reference's."""
+    from mxnet_tpu.fault.inject import injector
+    from mxnet_tpu.serving.generation import engine as engine_mod
+
+    if fault == "step":
+        monkeypatch.setenv("TPUMX_FAULT_GEN_STEP_FAIL", "5")
+    injector().reset()
+    svc = _service(params)
+    svc.warmup()
+    note, failed_at = svc._note_step_failure, []
+    monkeypatch.setattr(svc, "_note_step_failure", lambda exc: (
+        failed_at.append(svc._flight is not None), note(exc))[1])
+    synced, reads, raised = engine_mod._synced, [0], []
+    times = {"step": 0, "read": 1, "read_twice": 2}[fault]
+
+    def failing(*outs):
+        f = svc._flight
+        if f is not None and outs[0] is f.tokens:
+            reads[0] += 1
+            if reads[0] >= 4 and len(raised) < times:
+                raised.append(reads[0])
+                raise RuntimeError("injected read failure")
+        return synced(*outs)
+
+    monkeypatch.setattr(engine_mod, "_synced", failing)
+    rng = np.random.default_rng(51)
+    prompts = [rng.integers(0, 97, n) for n in (6, 15)]
+    seen = [[], []]
+    streams = [svc.submit(p, max_new_tokens=10,
+                          on_token=lambda rid, t, s=s: s.append(t))
+               for p, s in zip(prompts, seen)]
+    _drive(svc, streams)
+    c = svc.stats()["counts"]
+    svc.stop(drain=False, timeout=30)
+    monkeypatch.delenv("TPUMX_FAULT_GEN_STEP_FAIL", raising=False)
+    injector().reset()
+    for st, p, cb in zip(streams, prompts, seen):
+        want, passes = _ref_generate(params, p, 10)
+        assert st.result(1) == cb == want
+        assert st._req.unmask_pass == passes
+    assert c["step_failures"] == max(times, 1) and c["failed"] == 0
+    assert c["quarantined"] == 0 and c["tokens"] == 20
+    assert failed_at[0] is True and len(raised) == times
+
+
+def test_warmup_covers_the_carry_and_lowers_a_width_once(params,
+                                                         monkeypatch):
+    """The pass in flight adds no model program and compiles nothing
+    after warm-up, by this repo's count and by XLA's own: the block
+    state carried on the device is fed to the ``gen_block`` programs that
+    warm-up lowered from the host's operands."""
+    import jax.monitoring as mon
+    from mxnet_tpu.executor import compile_cache_stats
+
+    compiles = [0]
+    mon.register_event_duration_secs_listener(
+        lambda event, secs, **_: compiles.__setitem__(
+            0, compiles[0] + event.endswith("backend_compile_duration")))
+    svc = _service(params)
+    n = svc.warmup()
+    monkeypatch.setenv("TPUMX_FREEZE_COMPILES", "1")
+    assert n == len(svc._prefill_signatures()) + len(svc._width_buckets) + 1
+    warm = (compile_cache_stats()["misses"], compiles[0])
+    rng = np.random.default_rng(61)
+    streams = [svc.submit(rng.integers(0, 97, n), max_new_tokens=m)
+               for n, m in ((3, 9), (16, 12), (45, 30), (64, 6), (31, 17))]
+    _drive(svc, streams)
+    c = svc.stats()["counts"]
+    stats = svc.compile_stats()
+    svc.stop(drain=False, timeout=30)
+    assert (compile_cache_stats()["misses"], compiles[0]) == warm
+    assert c["steps_ahead"] >= 0.9 * c["block_passes"] and c["failed"] == 0
+    blocks = [v for k, v in stats.items() if k[0] == "gen_block"]
+    assert len(blocks) == len(svc._width_buckets)
+    assert all(v["misses"] == 1 for v in stats.values())
+    assert sum(v["hits"] for v in blocks) == c["block_passes"]
