@@ -33,6 +33,7 @@ from .context import Context
 from .ndarray.ndarray import NDArray
 from .symbol.graph import trace
 from . import random as _random
+from .observability import device_scopes as _device_scopes
 from .observability import tracing as _tracing
 
 __all__ = ["Executor", "compile_cache_stats", "reset_compile_cache_stats"]
@@ -751,15 +752,18 @@ class Executor:
                     # sums combine into the full-batch gradient, exactly what
                     # the 1-device trace computes (rescale_grad then divides
                     # by the GLOBAL batch in the optimizer, unchanged)
-                    grads = allreduce(
-                        {n: grads[n] for n in gnames if grads.get(n) is not None})
-                    # per-shard batch stats (BatchNorm running averages):
-                    # average across replicas so the committed aux carry is
-                    # replica-invariant
-                    aux_updates = {
-                        k: (jax.lax.pmean(v, axis)
-                            if jnp.issubdtype(v.dtype, jnp.inexact) else v)
-                        for k, v in aux_updates.items()}
+                    with jax.named_scope("kvstore.allreduce"):
+                        grads = allreduce(
+                            {n: grads[n] for n in gnames
+                             if grads.get(n) is not None})
+                        # per-shard batch stats (BatchNorm running
+                        # averages): average across replicas so the
+                        # committed aux carry is replica-invariant
+                        aux_updates = {
+                            k: (jax.lax.pmean(v, axis)
+                                if jnp.issubdtype(v.dtype, jnp.inexact)
+                                else v)
+                            for k, v in aux_updates.items()}
                 finite = None
                 if scaler is not None:
                     # all-finite check on the (scaled, already-reduced)
@@ -769,13 +773,15 @@ class Executor:
                     nonfin = scaler.nonfinite_count(
                         {n: g for n, g in grads.items() if g is not None})
                     if allreduce is not None:
-                        if kvstore is not None and hasattr(
-                                kvstore, "all_finite_in_program"):
-                            nonfin = kvstore.all_finite_in_program(nonfin,
-                                                                   axis)
-                        else:
-                            nonfin = allreduce({"_amp_nonfinite": nonfin})[
-                                "_amp_nonfinite"]
+                        with jax.named_scope("kvstore.allreduce"):
+                            if kvstore is not None and hasattr(
+                                    kvstore, "all_finite_in_program"):
+                                nonfin = kvstore.all_finite_in_program(
+                                    nonfin, axis)
+                            else:
+                                nonfin = allreduce(
+                                    {"_amp_nonfinite": nonfin})[
+                                    "_amp_nonfinite"]
                     finite = nonfin == 0
                     grads = {n: scaler.unscale(g, sc[0])
                              for n, g in grads.items() if g is not None}
@@ -796,11 +802,13 @@ class Executor:
 
                 def apply_updates(_):
                     new_p, new_s = {}, {}
-                    for n in gnames:
-                        lm, wm, dt = mults_by_name[n]
-                        new_p[n], new_s[n] = fused_apply_update(
-                            optimizer, pvals[n], new_grads[n], svals[n],
-                            lr_i * lm, wd * wm, t_i + dt, n in master_names)
+                    with jax.named_scope("optimizer.update"):
+                        for n in gnames:
+                            lm, wm, dt = mults_by_name[n]
+                            new_p[n], new_s[n] = fused_apply_update(
+                                optimizer, pvals[n], new_grads[n], svals[n],
+                                lr_i * lm, wd * wm, t_i + dt,
+                                n in master_names)
                     return new_p, new_s
 
                 if scaler is None:
@@ -872,10 +880,11 @@ class Executor:
                     # global-batch value
                     from .observability import telemetry as _obs_tele
 
-                    ret = ret + (_obs_tele.compute_in_program(
-                        outs, grads, p,
-                        scaler_state=sc if scaler is not None else None,
-                        pmean_axis=tele_pmean, psum_axes=tele_axes),)
+                    with jax.named_scope("telemetry"):
+                        ret = ret + (_obs_tele.compute_in_program(
+                            outs, grads, p,
+                            scaler_state=sc if scaler is not None else None,
+                            pmean_axis=tele_pmean, psum_axes=tele_axes),)
                 return ret
 
             if scaler is None:
@@ -1200,11 +1209,11 @@ class Executor:
             # once per program: the argument shapes, for fused_step_hlo()
             # (placement only where it was chosen: an uncommitted array
             # follows the others, as in the call itself)
-            self._fused_probe = (fn, jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(
-                    a.shape, a.dtype,
-                    sharding=a.sharding if getattr(a, "committed", False)
-                    else None), args))
+            # (the thunk holds the program and its shapes, not this
+            # executor: the device-scope resolver may read a profiler
+            # session after the executor is gone)
+            self._fused_probe = (fn, _device_scopes.text_thunk(fn, args))
+            _device_scopes.register(self)
         return fn, args, tele_on, rng
 
     def fused_step_hlo(self) -> str:
@@ -1214,8 +1223,15 @@ class Executor:
         chip_smoke.py ``--chips 4``)."""
         if self._fused_probe is None:
             raise MXNetError("fused_step_hlo: no fused step has run")
-        fn, avals = self._fused_probe
-        return fn.lower(*avals).compile().as_text()
+        return self._fused_probe[1]()
+
+    def device_programs(self):
+        """What ``observability.device_scopes`` reads: ``(kind, key,
+        launches: nobody counts them, thunk for the optimised HLO text)``
+        of the fused step this executor ran last."""
+        if self._fused_probe is None:
+            return []
+        return [("fused_step", 0, None, self._fused_probe[1])]
 
     # -- train telemetry ----------------------------------------------------------
     def _note_telemetry(self, vals: Dict[str, object]) -> None:

@@ -23,6 +23,9 @@ kvstore, io, amp and serving:
   (``TPUMX_EXPLAIN_RECOMPILES=1`` logs human-readable miss causes;
   ``TPUMX_FREEZE_COMPILES=1`` + :func:`mark_warm` makes any post-warmup
   miss raise);
+- :mod:`.device_scopes` — which part of the program a device operation
+  belongs to: the resolver over the ``jax.named_scope`` names the program
+  gives its device work (the device section of ``mx.profiler.dumps()``);
 - :mod:`.telemetry` — grad/param norms, step loss, loss scale and
   nonfinite/skip counts computed inside the donated fused train step and
   fetched only every ``TPUMX_TELEMETRY_EVERY`` steps
@@ -42,6 +45,7 @@ from .tracing import (span, current_span, span_stack, TraceContext,
                       recent_requests, recent_spans)
 from .recompile import (FreezeCompilesError, explain_key_diff,
                         last_explanations, mark_warm)
+from . import device_scopes
 from . import exposition
 from . import flight_recorder
 from . import metrics
@@ -55,7 +59,8 @@ __all__ = ["registry", "snapshot", "to_prometheus", "dump_prometheus",
            "recent_requests", "recent_spans",
            "last_explanations", "explain_key_diff", "FreezeCompilesError",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "DEFAULT_BUCKETS", "metrics", "tracing", "recompile",
+           "DEFAULT_BUCKETS", "device_scopes", "metrics", "tracing",
+           "recompile",
            "telemetry", "exposition", "flight_recorder"]
 
 #: the process-wide default registry every subsystem records into

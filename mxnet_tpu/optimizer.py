@@ -715,9 +715,12 @@ def fused_apply_update(optimizer, weight, grad, state, lr, wd, t, has_master):
     if not has_master:
         return optimizer.update_step(weight, grad, state, lr, wd, t)
     master, inner = state
-    new_master, new_inner = optimizer.update_step(
-        master, grad.astype(master.dtype), inner, lr, wd, t)
-    return new_master.astype(weight.dtype), (new_master, new_inner)
+    with jax.named_scope("amp.cast"):
+        grad = grad.astype(master.dtype)
+    new_master, new_inner = optimizer.update_step(master, grad, inner, lr,
+                                                  wd, t)
+    with jax.named_scope("amp.cast"):
+        return new_master.astype(weight.dtype), (new_master, new_inner)
 
 
 def uniquify_donated(trees):

@@ -261,61 +261,80 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
             = reads[kind]
     eps = cfg.layernorm_epsilon
     at_kind = [0, 0]            # the next layer of each kind's pools
-    x = params["tok_emb"][tokens].astype(jnp.float32)          # (B, T, d)
+    scope = jax.named_scope     # docs/observability.md "Device scopes"
+    with scope("embed"):
+        x = params["tok_emb"][tokens].astype(jnp.float32)      # (B, T, d)
     for i, kind in enumerate(cfg.hybrid_layer_pattern):
         g = lambda n: params[f"l{i}_{n}"]  # noqa: B023 — read immediately
         hkv, li = cfg.kv_heads(kind), at_kind[kind]
         at_kind[kind] += 1
         theta = cfg.swa_rope_theta if kind else cfg.rope_theta
-        h = _rms(x, g("norm1"), eps)
 
         def rotated(t):     # rotate-half over the first dr lanes
             return jnp.concatenate(
-                [_rope(t[..., :dr], positions, theta), t[..., dr:]], axis=-1)
+                [_rope(t[..., :dr], positions, theta), t[..., dr:]],  # noqa: B023
+                axis=-1)
 
-        q = rotated(_mm(h, g("wq")).reshape(B, T, H, dq))
-        k = rotated(_mm(h, g("wk")).reshape(B, T, hkv, dq))
-        v = _mm(h, g("wv")) * cfg.attention_value_scale
-        k_pool, v_pool = pools[2 * kind], pools[2 * kind + 1]
-        k_pool = k_pool.at[li, phys[kind], offs].set(
-            k.reshape(B, T, hkv * dq).astype(k_pool.dtype))
-        v_pool = v_pool.at[li, phys[kind], offs].set(v.astype(v_pool.dtype))
-        pools[2 * kind], pools[2 * kind + 1] = k_pool, v_pool
-        sink = g("sink") if cfg.has_sink(kind) else None
-        if use_kernel:
-            a = _pa.paged_attention(
-                q, k_pool, v_pool, tables[kind], positions, max_pos,
-                scale=scale, layer=li, call=f"{name_of(kind)}_{phase}",
-                window=win if kind else 0, sink=sink)
-        else:
-            gather = lambda pool, w: pool[li][tables[kind]].reshape(  # noqa: E731,B023
-                B, -1, hkv, w)
-            a = _pa.paged_attention_reference(
-                q, gather(k_pool, dq), gather(v_pool, dv), masks[kind],
-                scale, sink)
-        x = x + _mm(a.reshape(B, T, H * dv), g("wo"))
-        h = _rms(x, g("norm2"), eps)
-        if not cfg.moe_layer_freq[i]:
-            x = x + _gated(h, g("wg"), g("wu"), g("wd"))
-            continue
-        hf = h.reshape(B * T, -1)
-        w, e = route_sigmoid_groups(
-            _mm(hf, g("router")), g("router_bias"), cfg.num_experts_per_tok,
-            cfg.n_group, cfg.topk_group, cfg.norm_topk_prob,
-            cfg.routed_scaling_factor)
-        y, sizes = expert_products(hf, w, e, g("wg"), g("wu"), g("wd"),
-                                   (lo, hi), pallas=use_kernel)
-        x = x + y.reshape(B, T, -1)
-        mine = (e >= lo) & (e < hi) & valid_flat[:, None]
-        load = jnp.bincount(jnp.where(mine, e - lo, hi - lo).reshape(-1),
-                            length=hi - lo + 1)[:hi - lo]
-        aux["expert_assignments"] += (jnp.sum(valid_flat) * e.shape[1]
-                                      ).astype(jnp.int32)
-        aux["expert_assignments_held"] += jnp.sum(load).astype(jnp.int32)
-        aux["experts_touched"] += jnp.sum(sizes > 0).astype(jnp.int32)
-        aux["expert_tokens_max"] = jnp.maximum(
-            aux["expert_tokens_max"], jnp.max(load).astype(jnp.int32))
-    logits = _mm(_rms(x, params["norm_f"], eps), params["head"])
+        with scope(f"layer{i}"):
+            with scope("norm"):
+                h = _rms(x, g("norm1"), eps)
+            with scope("attn.proj"):
+                q = rotated(_mm(h, g("wq")).reshape(B, T, H, dq))
+                k = rotated(_mm(h, g("wk")).reshape(B, T, hkv, dq))
+                v = _mm(h, g("wv")) * cfg.attention_value_scale
+            with scope("attn.cache_write"):
+                k_pool, v_pool = pools[2 * kind], pools[2 * kind + 1]
+                k_pool = k_pool.at[li, phys[kind], offs].set(
+                    k.reshape(B, T, hkv * dq).astype(k_pool.dtype))
+                v_pool = v_pool.at[li, phys[kind], offs].set(
+                    v.astype(v_pool.dtype))
+                pools[2 * kind], pools[2 * kind + 1] = k_pool, v_pool
+            sink = g("sink") if cfg.has_sink(kind) else None
+            with scope("attn.kernel"):
+                if use_kernel:
+                    a = _pa.paged_attention(
+                        q, k_pool, v_pool, tables[kind], positions, max_pos,
+                        scale=scale, layer=li,
+                        call=f"{name_of(kind)}_{phase}",
+                        window=win if kind else 0, sink=sink)
+                else:
+                    gather = lambda pool, w: pool[li][tables[kind]].reshape(  # noqa: E731,B023
+                        B, -1, hkv, w)
+                    a = _pa.paged_attention_reference(
+                        q, gather(k_pool, dq), gather(v_pool, dv),
+                        masks[kind], scale, sink)
+            with scope("attn.proj"):
+                x = x + _mm(a.reshape(B, T, H * dv), g("wo"))
+            with scope("norm"):
+                h = _rms(x, g("norm2"), eps)
+            if not cfg.moe_layer_freq[i]:
+                with scope("ffn"):
+                    x = x + _gated(h, g("wg"), g("wu"), g("wd"))
+                continue
+            hf = h.reshape(B * T, -1)
+            with scope("moe.route"):
+                w, e = route_sigmoid_groups(
+                    _mm(hf, g("router")), g("router_bias"),
+                    cfg.num_experts_per_tok, cfg.n_group, cfg.topk_group,
+                    cfg.norm_topk_prob, cfg.routed_scaling_factor)
+            y, sizes = expert_products(hf, w, e, g("wg"), g("wu"), g("wd"),
+                                       (lo, hi), pallas=use_kernel)
+            with scope("moe.combine"):
+                x = x + y.reshape(B, T, -1)
+            with scope("moe.route"):    # the program's own counts
+                mine = (e >= lo) & (e < hi) & valid_flat[:, None]
+                load = jnp.bincount(
+                    jnp.where(mine, e - lo, hi - lo).reshape(-1),
+                    length=hi - lo + 1)[:hi - lo]
+                aux["expert_assignments"] += (
+                    jnp.sum(valid_flat) * e.shape[1]).astype(jnp.int32)
+                aux["expert_assignments_held"] += jnp.sum(load).astype(
+                    jnp.int32)
+                aux["experts_touched"] += jnp.sum(sizes > 0).astype(jnp.int32)
+                aux["expert_tokens_max"] = jnp.maximum(
+                    aux["expert_tokens_max"], jnp.max(load).astype(jnp.int32))
+    with scope("head"):
+        logits = _mm(_rms(x, params["norm_f"], eps), params["head"])
     return logits, tuple(pools), aux
 
 

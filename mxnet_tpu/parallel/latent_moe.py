@@ -287,58 +287,81 @@ def latent_moe_decode(params: Params, tokens, positions, lengths, pool,
            "latent_prefill_pairs": zero if T == 1 else reads,
            "expert_assignments": zero, "expert_assignments_held": zero,
            "experts_touched": zero, "expert_tokens_max": zero}
-    x = params["tok_emb"][tokens].astype(jnp.float32)          # (B, T, d)
+    scope = jax.named_scope     # docs/observability.md "Device scopes"
+    with scope("embed"):
+        x = params["tok_emb"][tokens].astype(jnp.float32)      # (B, T, d)
     for i in range(cfg.num_hidden_layers):
         g = lambda n: params[f"l{i}_{n}"]  # noqa: B023 — read immediately
-        h = _rms(x, g("norm1"), eps)
-        q = _mm(_rms(_mm(h, g("wqa")), g("q_norm"), eps), g("wqb")
-                ).reshape(B, T, H, dn + dr)
-        qn, qr = q[..., :dn], _rope(q[..., dn:], positions, inv_freq)
-        kva = _mm(h, g("wkva"))                                # (B, T, c+dr)
-        latent = jnp.concatenate(
-            [_rms(kva[..., :c], g("kv_norm"), eps),
-             _rope(kva[:, :, None, c:], positions, inv_freq)[:, :, 0]],
-            axis=-1).astype(pool.dtype)
-        if width != lat:
-            latent = jnp.pad(latent, ((0, 0), (0, 0), (0, width - lat)))
-        pool = pool.at[i, phys, offs].set(latent)
-        wkvb = g("wkvb").reshape(c, H, dn + dv)
-        ql = jnp.einsum("bthd,chd->bthc", qn.astype(wkvb.dtype),
-                        wkvb[..., :dn], preferred_element_type=jnp.float32)
-        qa = jnp.concatenate([ql, qr], axis=-1)                # (B, T, H, lat)
-        if use_kernel:
-            ol = _la.latent_attention(qa, pool, block_tables, positions,
-                                      max_pos, v_width=c, scale=scale,
-                                      layer=i, call=call)
-        else:
-            ctx = pool[i][block_tables].reshape(
-                B, W * block_size, width)[..., :lat]
-            ol = _la.latent_attention_reference(qa, ctx, attn_mask, c, scale)
-        a = jnp.einsum("bthc,chd->bthd", ol.astype(wkvb.dtype),
-                       wkvb[..., dn:], preferred_element_type=jnp.float32)
-        x = x + _mm(a.reshape(B, T, H * dv), g("wo"))
-        h = _rms(x, g("norm2"), eps)
-        if i < cfg.first_k_dense_replace:
-            x = x + _gated(h, g("wg"), g("wu"), g("wd"))
-            continue
-        hf = h.reshape(B * T, -1)
-        w, e = route_sigmoid_groups(
-            _mm(hf, g("router")), g("router_bias"), cfg.num_experts_per_tok,
-            cfg.n_group, cfg.topk_group, cfg.norm_topk_prob,
-            cfg.routed_scaling_factor)
-        y, sizes = expert_products(hf, w, e, g("wg"), g("wu"), g("wd"),
-                                   (lo, hi), pallas=use_kernel)
-        x = x + (y + _gated(hf, g("sg"), g("su"), g("sd"))).reshape(B, T, -1)
-        mine = (e >= lo) & (e < hi) & valid_flat[:, None]
-        load = jnp.bincount(jnp.where(mine, e - lo, hi - lo).reshape(-1),
-                            length=hi - lo + 1)[:hi - lo]
-        aux["expert_assignments"] += (jnp.sum(valid_flat) * e.shape[1]
-                                      ).astype(jnp.int32)
-        aux["expert_assignments_held"] += jnp.sum(load).astype(jnp.int32)
-        aux["experts_touched"] += jnp.sum(sizes > 0).astype(jnp.int32)
-        aux["expert_tokens_max"] = jnp.maximum(
-            aux["expert_tokens_max"], jnp.max(load).astype(jnp.int32))
-    logits = _mm(_rms(x, params["norm_f"], eps), params["head"])
+        with scope(f"layer{i}"):
+            with scope("norm"):
+                h = _rms(x, g("norm1"), eps)
+            with scope("attn.proj"):
+                q = _mm(_rms(_mm(h, g("wqa")), g("q_norm"), eps), g("wqb")
+                        ).reshape(B, T, H, dn + dr)
+                qn, qr = q[..., :dn], _rope(q[..., dn:], positions, inv_freq)
+                kva = _mm(h, g("wkva"))                        # (B, T, c+dr)
+                latent = jnp.concatenate(
+                    [_rms(kva[..., :c], g("kv_norm"), eps),
+                     _rope(kva[:, :, None, c:], positions, inv_freq)[:, :, 0]],
+                    axis=-1).astype(pool.dtype)
+            with scope("attn.cache_write"):
+                if width != lat:
+                    latent = jnp.pad(latent,
+                                     ((0, 0), (0, 0), (0, width - lat)))
+                pool = pool.at[i, phys, offs].set(latent)
+            with scope("attn.proj"):    # the absorbed product, query side
+                wkvb = g("wkvb").reshape(c, H, dn + dv)
+                ql = jnp.einsum("bthd,chd->bthc", qn.astype(wkvb.dtype),
+                                wkvb[..., :dn],
+                                preferred_element_type=jnp.float32)
+                qa = jnp.concatenate([ql, qr], axis=-1)        # (B, T, H, lat)
+            with scope("attn.kernel"):
+                if use_kernel:
+                    ol = _la.latent_attention(qa, pool, block_tables,
+                                              positions, max_pos, v_width=c,
+                                              scale=scale, layer=i, call=call)
+                else:
+                    ctx = pool[i][block_tables].reshape(
+                        B, W * block_size, width)[..., :lat]
+                    ol = _la.latent_attention_reference(qa, ctx, attn_mask,
+                                                        c, scale)
+            with scope("attn.proj"):    # ... and the value side, then wo
+                a = jnp.einsum("bthc,chd->bthd", ol.astype(wkvb.dtype),
+                               wkvb[..., dn:],
+                               preferred_element_type=jnp.float32)
+                x = x + _mm(a.reshape(B, T, H * dv), g("wo"))
+            with scope("norm"):
+                h = _rms(x, g("norm2"), eps)
+            if i < cfg.first_k_dense_replace:
+                with scope("ffn"):
+                    x = x + _gated(h, g("wg"), g("wu"), g("wd"))
+                continue
+            hf = h.reshape(B * T, -1)
+            with scope("moe.route"):
+                w, e = route_sigmoid_groups(
+                    _mm(hf, g("router")), g("router_bias"),
+                    cfg.num_experts_per_tok, cfg.n_group, cfg.topk_group,
+                    cfg.norm_topk_prob, cfg.routed_scaling_factor)
+            y, sizes = expert_products(hf, w, e, g("wg"), g("wu"), g("wd"),
+                                       (lo, hi), pallas=use_kernel)
+            with scope("ffn"):          # the shared expert
+                shared = _gated(hf, g("sg"), g("su"), g("sd"))
+            with scope("moe.combine"):
+                x = x + (y + shared).reshape(B, T, -1)
+            with scope("moe.route"):    # the program's own counts
+                mine = (e >= lo) & (e < hi) & valid_flat[:, None]
+                load = jnp.bincount(
+                    jnp.where(mine, e - lo, hi - lo).reshape(-1),
+                    length=hi - lo + 1)[:hi - lo]
+                aux["expert_assignments"] += (
+                    jnp.sum(valid_flat) * e.shape[1]).astype(jnp.int32)
+                aux["expert_assignments_held"] += jnp.sum(load).astype(
+                    jnp.int32)
+                aux["experts_touched"] += jnp.sum(sizes > 0).astype(jnp.int32)
+                aux["expert_tokens_max"] = jnp.maximum(
+                    aux["expert_tokens_max"], jnp.max(load).astype(jnp.int32))
+    with scope("head"):
+        logits = _mm(_rms(x, params["norm_f"], eps), params["head"])
     return logits, pool, aux
 
 

@@ -172,26 +172,34 @@ def expert_products(h, w, e, wg, wu, wd,
     lo, hi = experts_held if experts_held is not None else (0, wg.shape[0])
     held = hi - lo
     assert wg.shape[0] == held
-    local = e.reshape(-1) - lo
-    mine = (local >= 0) & (local < held)
-    # assignments to experts held elsewhere sort behind every group
-    order = jnp.argsort(jnp.where(mine, local, held), stable=True)
-    token = order // top_k
-    sizes = jnp.bincount(jnp.where(mine, local, held), length=held + 1
-                         )[:held].astype(jnp.int32)
-    xs = h.astype(wg.dtype)[token]                             # (N*k, d)
+    # device scopes (docs/observability.md "Device scopes"): the sort of
+    # the assignments belongs to the router, the rows' way to their experts
+    # and back onto their tokens is ``moe.combine``
+    scope = jax.named_scope
+    with scope("moe.route"):
+        local = e.reshape(-1) - lo
+        mine = (local >= 0) & (local < held)
+        # assignments to experts held elsewhere sort behind every group
+        order = jnp.argsort(jnp.where(mine, local, held), stable=True)
+        token = order // top_k
+        sizes = jnp.bincount(jnp.where(mine, local, held), length=held + 1
+                             )[:held].astype(jnp.int32)
+    with scope("moe.combine"):
+        xs = h.astype(wg.dtype)[token]                         # (N*k, d)
     if pallas:
         from ..ops.grouped_matmul import grouped_matmul
         rd = lambda a, b: grouped_matmul(a, b, sizes)  # noqa: E731
     else:
         rd = lambda a, b: jax.lax.ragged_dot(  # noqa: E731
             a, b, sizes, preferred_element_type=jnp.float32)
-    act = jax.nn.silu(rd(xs, wg)) * rd(xs, wu)                 # (N*k, F)
-    ys = rd(act.astype(wd.dtype), wd)                          # (N*k, d)
-    # rows behind the last group hold whatever the kernel left there
-    ys = jnp.where(mine[order][:, None], ys * w.reshape(-1)[order][:, None],
-                   0.0)
-    y = jnp.zeros((N, h.shape[1]), jnp.float32).at[token].add(ys)
+    with scope("moe.experts"):
+        act = jax.nn.silu(rd(xs, wg)) * rd(xs, wu)             # (N*k, F)
+        ys = rd(act.astype(wd.dtype), wd)                      # (N*k, d)
+    with scope("moe.combine"):
+        # rows behind the last group hold whatever the kernel left there
+        ys = jnp.where(mine[order][:, None],
+                       ys * w.reshape(-1)[order][:, None], 0.0)
+        y = jnp.zeros((N, h.shape[1]), jnp.float32).at[token].add(ys)
     return y, sizes
 
 
@@ -204,12 +212,14 @@ def moe_grouped(h, router, wg, wu, wd, top_k: int, norm_topk: bool = True,
 
     Returns ``(y (N, d) float32, touched)``: ``touched`` counts the held
     experts at least one token was routed to."""
-    p = jax.nn.softmax(_mm(h, router), axis=-1)                # (N, E) f32
-    w, e = jax.lax.top_k(p, top_k)                             # (N, k)
-    if norm_topk:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    with jax.named_scope("moe.route"):
+        p = jax.nn.softmax(_mm(h, router), axis=-1)            # (N, E) f32
+        w, e = jax.lax.top_k(p, top_k)                         # (N, k)
+        if norm_topk:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
     y, sizes = expert_products(h, w, e, wg, wu, wd, experts_held, pallas)
-    return y, jnp.sum(sizes > 0)
+    with jax.named_scope("moe.route"):
+        return y, jnp.sum(sizes > 0)
 
 
 def sdar_moe_decode(params: Params, tokens, positions, lengths, k_pool,
@@ -255,42 +265,54 @@ def sdar_moe_decode(params: Params, tokens, positions, lengths, k_pool,
         attn_mask = ctx_pos[None, None, :] <= block_end[:, :, None]
         scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
     eps = cfg.rms_norm_eps
-    x = params["tok_emb"][tokens].astype(jnp.float32)          # (B, T, d)
+    scope = jax.named_scope     # docs/observability.md "Device scopes"
+    with scope("embed"):
+        x = params["tok_emb"][tokens].astype(jnp.float32)      # (B, T, d)
     touched = jnp.zeros((), jnp.int32)
     for i in range(cfg.num_hidden_layers):
         g = lambda n: params[f"l{i}_{n}"]  # noqa: B023 — read immediately
-        h = _rms(x, g("norm1"), eps)
-        q = _rope(_rms(_mm(h, g("wq")).reshape(B, T, Hq, D), g("q_norm"),
-                       eps), positions, cfg.rope_theta)
-        k = _rope(_rms(_mm(h, g("wk")).reshape(B, T, Hkv, D), g("k_norm"),
-                       eps), positions, cfg.rope_theta)
-        v = _mm(h, g("wv"))
-        fold = lambda t: t.reshape(B, T, Hkv * D).astype(k_pool.dtype)  # noqa: E731
-        k_pool = k_pool.at[i, phys, offs].set(fold(k))
-        v_pool = v_pool.at[i, phys, offs].set(fold(v))
-        if use_paged:
-            # the kernel masks by the positions it is given: each query's
-            # block-end position (the rotary positions are applied already)
-            o = _pa.paged_attention(q, k_pool, v_pool, block_tables,
-                                    block_end, max_pos, scale=scale,
-                                    layer=i, call=call)
-        else:
-            gather = lambda pool: pool[i][block_tables].reshape(  # noqa: E731
-                B, W * block_size, Hkv, D)
-            o = _pa.paged_attention_reference(q, gather(k_pool),
-                                              gather(v_pool), attn_mask,
-                                              scale)
-        x = x + _mm(o.reshape(B, T, Hq * D), g("wo"))
-        h = _rms(x, g("norm2"), eps)
-        y, n = moe_grouped(h.reshape(B * T, -1), g("router"), g("wg"),
-                           g("wu"), g("wd"), cfg.num_experts_per_tok,
-                           cfg.norm_topk_prob, experts_held,
-                           pallas=use_paged)
-        x = x + y.reshape(B, T, -1)
-        touched = touched + n.astype(jnp.int32)
+        with scope(f"layer{i}"):
+            with scope("norm"):
+                h = _rms(x, g("norm1"), eps)
+            with scope("attn.proj"):
+                q = _rope(_rms(_mm(h, g("wq")).reshape(B, T, Hq, D),
+                               g("q_norm"), eps), positions, cfg.rope_theta)
+                k = _rope(_rms(_mm(h, g("wk")).reshape(B, T, Hkv, D),
+                               g("k_norm"), eps), positions, cfg.rope_theta)
+                v = _mm(h, g("wv"))
+            with scope("attn.cache_write"):
+                fold = lambda t: t.reshape(B, T, Hkv * D).astype(  # noqa: E731
+                    k_pool.dtype)
+                k_pool = k_pool.at[i, phys, offs].set(fold(k))
+                v_pool = v_pool.at[i, phys, offs].set(fold(v))
+            with scope("attn.kernel"):
+                if use_paged:
+                    # the kernel masks by the positions it is given: each
+                    # query's block-end position (the rotary positions are
+                    # applied already)
+                    o = _pa.paged_attention(q, k_pool, v_pool, block_tables,
+                                            block_end, max_pos, scale=scale,
+                                            layer=i, call=call)
+                else:
+                    gather = lambda pool: pool[i][block_tables].reshape(  # noqa: E731
+                        B, W * block_size, Hkv, D)
+                    o = _pa.paged_attention_reference(
+                        q, gather(k_pool), gather(v_pool), attn_mask, scale)
+            with scope("attn.proj"):
+                x = x + _mm(o.reshape(B, T, Hq * D), g("wo"))
+            with scope("norm"):
+                h = _rms(x, g("norm2"), eps)
+            y, n = moe_grouped(h.reshape(B * T, -1), g("router"), g("wg"),
+                               g("wu"), g("wd"), cfg.num_experts_per_tok,
+                               cfg.norm_topk_prob, experts_held,
+                               pallas=use_paged)
+            with scope("moe.combine"):
+                x = x + y.reshape(B, T, -1)
+            touched = touched + n.astype(jnp.int32)
     logits = None
     if want_logits:
-        logits = _mm(_rms(x, params["norm_f"], eps), params["head"])
+        with scope("head"):
+            logits = _mm(_rms(x, params["norm_f"], eps), params["head"])
     return logits, k_pool, v_pool, touched
 
 

@@ -370,69 +370,89 @@ def transformer_lm_decode(params: Params, tokens, positions, lengths,
     if quantized:
         nt = _touched_blocks(T, block_size)
 
-    x = params["tok_emb"][tokens] + jnp.take(params["pos_emb"], positions,
-                                             axis=0)
+    # device scopes (docs/observability.md "Device scopes"): every
+    # operation's ``op_name`` says which layer part it belongs to; read
+    # while tracing only, the lowered program is the same
+    scope = jax.named_scope
+    with scope("embed"):
+        x = params["tok_emb"][tokens] + jnp.take(params["pos_emb"],
+                                                 positions, axis=0)
     for i in range(cfg.n_layers):
         g = lambda n: params[f"l{i}_{n}"]  # noqa: B023 — read immediately
-        h = _ln(x, g("ln1_g"), g("ln1_b"), mesh=mp_mesh)
-        qkv = h @ g("wqkv")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        to_heads = lambda t: t.reshape(B, T, cfg.n_heads, cfg.d_head)
-        q, k, v = to_heads(q), to_heads(k), to_heads(v)
-        if quantized:
-            k_pool, k_scale = _scatter_kv_quantized(
-                k_pool, k_scale, i, k, block_tables, positions, valid,
-                max_pos, nt)
-            v_pool, v_scale = _scatter_kv_quantized(
-                v_pool, v_scale, i, v, block_tables, positions, valid,
-                max_pos, nt)
-        else:
-            fold = lambda t: t.reshape(B, T, cfg.d_model).astype(k_pool.dtype)
-            k_pool = k_pool.at[i, phys, offs].set(fold(k))
-            v_pool = v_pool.at[i, phys, offs].set(fold(v))
-        if use_paged:
-            # the kernel is handed the WHOLE pool and the layer's index and
-            # fetches its own pages: a ``k_pool[i]`` operand of an opaque
-            # kernel call would be copied, the whole pool once a step
-            kw = dict(scale=kernel_scale, layer=i,
-                      k_scale=k_scale[i] if quantized else None,
-                      v_scale=v_scale[i] if quantized else None)
-            if mp_mesh is not None:
-                o = _pa.paged_attention_sharded(
-                    q, k_pool, v_pool, block_tables, positions, max_pos,
-                    mesh=mp_mesh, axis="mp", **kw)
-            else:
-                o = _pa.paged_attention(q, k_pool, v_pool, block_tables,
-                                        positions, max_pos, **kw)
-        else:
-            if quantized:
-                # dequantize at read: per-(block, head) scales broadcast
-                # over the gathered context (docs/quantization.md)
-                deq = lambda pool, sc: (
-                    pool[block_tables].reshape(
-                        B, W, block_size, cfg.n_heads, cfg.d_head
-                    ).astype(jnp.float32)
-                    * sc[block_tables][:, :, None, :, None]
-                ).reshape(B, W * block_size, cfg.n_heads, cfg.d_head)
-                k_ctx = deq(k_pool[i], k_scale[i])
-                v_ctx = deq(v_pool[i], v_scale[i])
-            else:
-                k_ctx = k_pool[i][block_tables].reshape(
-                    B, W * block_size, cfg.n_heads, cfg.d_head)
-                v_ctx = v_pool[i][block_tables].reshape(
-                    B, W * block_size, cfg.n_heads, cfg.d_head)
-            # same numerics as ring_attention.local_attention (f32 scores
-            # and accumulation), with the causal mask generalized to
-            # cache-position <= query-position — padded/unwritten slots
-            # land at exactly 0 probability (exp(-1e30 - m) underflows),
-            # so bucketed table widths never perturb real rows
-            o = _pa_reference(q, k_ctx, v_ctx, attn_mask, scale)
-        x = x + o.reshape(B, T, cfg.d_model) @ g("wo")
-        h = _ln(x, g("ln2_g"), g("ln2_b"), mesh=mp_mesh)
-        x = x + jax.nn.gelu(h @ g("w1") + g("b1")) @ g("w2") + g("b2")
-    x = _ln(x, params["lnf_g"], params["lnf_b"],
-            mesh=mp_mesh)
-    logits = x @ params["tok_emb"].T
+        with scope(f"layer{i}"):
+            with scope("norm"):
+                h = _ln(x, g("ln1_g"), g("ln1_b"), mesh=mp_mesh)
+            with scope("attn.proj"):
+                qkv = h @ g("wqkv")
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                to_heads = lambda t: t.reshape(B, T, cfg.n_heads, cfg.d_head)
+                q, k, v = to_heads(q), to_heads(k), to_heads(v)
+            with scope("attn.cache_write"):
+                if quantized:
+                    k_pool, k_scale = _scatter_kv_quantized(
+                        k_pool, k_scale, i, k, block_tables, positions,
+                        valid, max_pos, nt)
+                    v_pool, v_scale = _scatter_kv_quantized(
+                        v_pool, v_scale, i, v, block_tables, positions,
+                        valid, max_pos, nt)
+                else:
+                    fold = lambda t: t.reshape(B, T, cfg.d_model).astype(
+                        k_pool.dtype)
+                    k_pool = k_pool.at[i, phys, offs].set(fold(k))
+                    v_pool = v_pool.at[i, phys, offs].set(fold(v))
+            with scope("attn.kernel"):
+                if use_paged:
+                    # the kernel is handed the WHOLE pool and the layer's
+                    # index and fetches its own pages: a ``k_pool[i]``
+                    # operand of an opaque kernel call would be copied, the
+                    # whole pool once a step
+                    kw = dict(scale=kernel_scale, layer=i,
+                              k_scale=k_scale[i] if quantized else None,
+                              v_scale=v_scale[i] if quantized else None)
+                    if mp_mesh is not None:
+                        o = _pa.paged_attention_sharded(
+                            q, k_pool, v_pool, block_tables, positions,
+                            max_pos, mesh=mp_mesh, axis="mp", **kw)
+                    else:
+                        o = _pa.paged_attention(q, k_pool, v_pool,
+                                                block_tables, positions,
+                                                max_pos, **kw)
+                else:
+                    if quantized:
+                        # dequantize at read: per-(block, head) scales
+                        # broadcast over the gathered context
+                        # (docs/quantization.md)
+                        deq = lambda pool, sc: (
+                            pool[block_tables].reshape(
+                                B, W, block_size, cfg.n_heads, cfg.d_head
+                            ).astype(jnp.float32)
+                            * sc[block_tables][:, :, None, :, None]
+                        ).reshape(B, W * block_size, cfg.n_heads, cfg.d_head)
+                        k_ctx = deq(k_pool[i], k_scale[i])
+                        v_ctx = deq(v_pool[i], v_scale[i])
+                    else:
+                        k_ctx = k_pool[i][block_tables].reshape(
+                            B, W * block_size, cfg.n_heads, cfg.d_head)
+                        v_ctx = v_pool[i][block_tables].reshape(
+                            B, W * block_size, cfg.n_heads, cfg.d_head)
+                    # same numerics as ring_attention.local_attention (f32
+                    # scores and accumulation), with the causal mask
+                    # generalized to cache-position <= query-position —
+                    # padded/unwritten slots land at exactly 0 probability
+                    # (exp(-1e30 - m) underflows), so bucketed table widths
+                    # never perturb real rows
+                    o = _pa_reference(q, k_ctx, v_ctx, attn_mask, scale)
+            with scope("attn.proj"):
+                x = x + o.reshape(B, T, cfg.d_model) @ g("wo")
+            with scope("norm"):
+                h = _ln(x, g("ln2_g"), g("ln2_b"), mesh=mp_mesh)
+            with scope("ffn"):
+                x = x + jax.nn.gelu(h @ g("w1") + g("b1")) @ g("w2") \
+                    + g("b2")
+    with scope("head"):
+        x = _ln(x, params["lnf_g"], params["lnf_b"],
+                mesh=mp_mesh)
+        logits = x @ params["tok_emb"].T
     if quantized:
         return logits.astype(jnp.float32), k_pool, v_pool, k_scale, v_scale
     return logits.astype(jnp.float32), k_pool, v_pool

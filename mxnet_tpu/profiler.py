@@ -63,6 +63,12 @@ def start(profile_process="worker"):
     already = _state["running"]
     _state["running"] = True
     _state["t0"] = time.perf_counter()
+    if not already:
+        # which programs run in this session: their launch counts now
+        # (docs/observability.md "Device scopes")
+        from .observability import device_scopes
+
+        device_scopes.session_start()
     trace_dir = os.environ.get("TPUMX_JAX_TRACE_DIR")
     # idempotent like the reference (set_state('run') twice is legal): a
     # second start must not re-enter jax.profiler.start_trace
@@ -80,11 +86,16 @@ def start(profile_process="worker"):
 
 
 def stop(profile_process="worker"):
+    if _state["running"]:
+        from .observability import device_scopes
+
+        device_scopes.session_stop()
     _state["running"] = False
     if _state.get("jax_trace_dir"):
         import jax
 
         jax.profiler.stop_trace()
+        _state["device_trace"] = _state["jax_trace_dir"]
         _state["jax_trace_dir"] = None
     if _state.get("continuous_dump"):
         dump()
@@ -148,7 +159,28 @@ def dumps(reset=False, format="table"):
     if reset:
         with _lock:
             _events.clear()
+    device = _device_section()
+    if device:
+        lines += ["", device]
     return "\n".join(lines)
+
+
+def _device_section() -> Optional[str]:
+    """Device milliseconds by scope and by program kind, from the jax
+    trace this profiler owned and has stopped (``TPUMX_JAX_TRACE_DIR``):
+    the reference's aggregate per-operator table for a fused program
+    (docs/observability.md "Device scopes").  Builds the scope table of the
+    session's programs: a compile of each, from the persistent cache where
+    one is on."""
+    trace_dir = _state.get("device_trace")
+    if not trace_dir or _state["running"]:
+        return None
+    from .observability import device_scopes
+
+    xplane = device_scopes.find_xplane(trace_dir)
+    if xplane is None:
+        return None
+    return device_scopes.format_table(device_scopes.device_table(xplane))
 
 
 def dump(finished=True, profile_process="worker"):

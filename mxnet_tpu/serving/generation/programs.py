@@ -72,6 +72,7 @@ from typing import Dict, Optional
 
 import numpy as _np
 
+from ...observability import device_scopes as _device_scopes
 from ...observability import tracing as _tracing
 
 __all__ = ["GenerationPrograms", "block_copy_pools", "as_model"]
@@ -139,23 +140,26 @@ def block_copy_pools(pools, src, dst):
     import jax
     import jax.numpy as jnp
 
-    s = jnp.asarray(src, jnp.int32)[0]
-    d = jnp.asarray(dst, jnp.int32)[0]
-
     def cp(pool):
         blk = jax.lax.dynamic_slice_in_dim(pool, s, 1, axis=1)
         return jax.lax.dynamic_update_slice_in_dim(pool, blk, d, axis=1)
 
-    return tuple(cp(pool) for pool in pools)
+    with jax.named_scope("block_copy"):
+        s = jnp.asarray(src, jnp.int32)[0]
+        d = jnp.asarray(dst, jnp.int32)[0]
+        return tuple(cp(pool) for pool in pools)
 
 
 def _carry(prev, tokens, keep):
     """The next step's ``tokens (S, 1)``: the last step's sampled token
     ``prev (S,)`` in the rows that ``keep``, the host's everywhere else.
     No model in it: one slot-sized program a service."""
+    import jax
     import jax.numpy as jnp
 
-    return jnp.where(keep[:, None], prev[:, None].astype(jnp.int32), tokens)
+    with jax.named_scope("carry"):
+        return jnp.where(keep[:, None], prev[:, None].astype(jnp.int32),
+                         tokens)
 
 
 def _carry_block(unmasked, prev_tokens, prev_masked, tokens, masked, keep):
@@ -165,32 +169,41 @@ def _carry_block(unmasked, prev_tokens, prev_masked, tokens, masked, keep):
     unmasked filled in from its ``unmasked (S, L)`` (the new id, -1 where
     nothing was unmasked); the host's everywhere else.  No model in it:
     one slot-sized program a service."""
+    import jax
     import jax.numpy as jnp
 
-    took = unmasked >= 0
-    keep = keep[:, None]
-    return (jnp.where(keep, jnp.where(took, unmasked, prev_tokens), tokens),
-            jnp.where(keep, prev_masked & ~took, masked))
+    with jax.named_scope("carry"):
+        took = unmasked >= 0
+        keep = keep[:, None]
+        return (jnp.where(keep, jnp.where(took, unmasked, prev_tokens),
+                          tokens),
+                jnp.where(keep, prev_masked & ~took, masked))
 
 
 def _model_step(params, pools, tokens, positions, lengths, block_tables,
                 seeds, counters, temperature, top_k, top_p, *, model,
                 attention_kernel="gather", mp_mesh=None):
+    import jax
     import jax.numpy as jnp
 
     from ...ops.sampling import sample_logits
 
-    logits, pools, aux = model.step(
-        params, tokens, positions, lengths, pools, block_tables,
-        attention_kernel=attention_kernel, mp_mesh=mp_mesh)
-    # logits at the LAST VALID position of each row feed the sampler
-    # (prefill: position len-1 predicts token len; decode: T=1 row 0)
-    last_idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0,
-                        tokens.shape[1] - 1)
-    last = jnp.take_along_axis(logits, last_idx[:, None, None],
-                               axis=1)[:, 0, :]
-    next_tokens = sample_logits(last, seeds, counters, temperature,
-                                top_k, top_p)
+    # the program's kind as the engine counts it, outermost in every
+    # operation's ``op_name`` (docs/observability.md "Device scopes")
+    with jax.named_scope("decode" if tokens.shape[1] == 1 else "prefill"):
+        logits, pools, aux = model.step(
+            params, tokens, positions, lengths, pools, block_tables,
+            attention_kernel=attention_kernel, mp_mesh=mp_mesh)
+        # logits at the LAST VALID position of each row feed the sampler
+        # (prefill: position len-1 predicts token len; decode: T=1 row 0)
+        with jax.named_scope("head"):
+            last_idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0,
+                                tokens.shape[1] - 1)
+            last = jnp.take_along_axis(logits, last_idx[:, None, None],
+                                       axis=1)[:, 0, :]
+        with jax.named_scope("sample"):
+            next_tokens = sample_logits(last, seeds, counters, temperature,
+                                        top_k, top_p)
     return next_tokens, last, aux, pools
 
 
@@ -203,14 +216,18 @@ def _verify_step(params, pools, tokens, positions, lengths, block_tables,
     positions feed the sampler (via ``speculative_verify``) instead of
     just the last one.  Returns per-position target tokens plus the
     leading accepted-draft count per row."""
+    import jax
+
     from ...ops.sampling import speculative_verify
 
-    logits, pools, _ = model.step(
-        params, tokens, positions, lengths, pools, block_tables,
-        attention_kernel=attention_kernel, mp_mesh=mp_mesh)
-    target, accepted = speculative_verify(
-        logits, tokens, seeds, counters, temperature, top_k, top_p,
-        lengths)
+    with jax.named_scope("verify"):
+        logits, pools, _ = model.step(
+            params, tokens, positions, lengths, pools, block_tables,
+            attention_kernel=attention_kernel, mp_mesh=mp_mesh)
+        with jax.named_scope("sample"):
+            target, accepted = speculative_verify(
+                logits, tokens, seeds, counters, temperature, top_k, top_p,
+                lengths)
     return target, accepted, pools
 
 
@@ -239,16 +256,18 @@ def _multistep(params, pools, tokens, positions, lengths, block_tables,
             params, tok[:, None], pos[:, None], lengths, pools,
             block_tables, attention_kernel=attention_kernel,
             mp_mesh=mp_mesh)
-        nxt = sample_logits(logits[:, 0, :], seeds, ctr, temperature,
-                            top_k, top_p)
+        with jax.named_scope("sample"):
+            nxt = sample_logits(logits[:, 0, :], seeds, ctr, temperature,
+                                top_k, top_p)
         return (pools, nxt, pos + 1, ctr + 1), nxt
 
-    init = (pools,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(positions, jnp.int32),
-            jnp.asarray(counters, jnp.uint32))
-    (pools, _, _, _), toks = jax.lax.scan(body, init, None, length=k)
-    return jnp.transpose(toks), pools  # (S, k)
+    with jax.named_scope("multistep"):
+        init = (pools,
+                jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(positions, jnp.int32),
+                jnp.asarray(counters, jnp.uint32))
+        (pools, _, _, _), toks = jax.lax.scan(body, init, None, length=k)
+        return jnp.transpose(toks), pools  # (S, k)
 
 
 def _block_fill(params, pools, tokens, positions, lengths, block_tables,
@@ -256,10 +275,13 @@ def _block_fill(params, pools, tokens, positions, lengths, block_tables,
     """Prefill of a block-diffusion model: whole blocks of context written
     into the cache, no logits — the first block step reads the first
     generated positions' own logits."""
-    _, pools, _ = model.step(
-        params, tokens, positions, lengths, pools, block_tables,
-        attention_kernel=attention_kernel, mp_mesh=mp_mesh, call="prefill",
-        want_logits=False)
+    import jax
+
+    with jax.named_scope("fill"):
+        _, pools, _ = model.step(
+            params, tokens, positions, lengths, pools, block_tables,
+            attention_kernel=attention_kernel, mp_mesh=mp_mesh,
+            call="prefill", want_logits=False)
     return (pools,)
 
 
@@ -276,12 +298,17 @@ def _block_step(params, pools, tokens, positions, lengths, block_tables,
     Returns ``(unmasked (S, L): the new token id, -1 where nothing was
     unmasked; experts touched, summed over layers; logits (S, L, vocab);
     pools)``."""
+    import jax
+
     from ...ops.sampling import block_unmask
 
-    logits, pools, touched = model.step(
-        params, tokens, positions, lengths, pools, block_tables,
-        attention_kernel=attention_kernel, mp_mesh=mp_mesh, call="block")
-    return block_unmask(logits, masked, n_unmask), touched, logits, pools
+    with jax.named_scope("block"):
+        logits, pools, touched = model.step(
+            params, tokens, positions, lengths, pools, block_tables,
+            attention_kernel=attention_kernel, mp_mesh=mp_mesh, call="block")
+        with jax.named_scope("sample"):
+            unmasked = block_unmask(logits, masked, n_unmask)
+    return unmasked, touched, logits, pools
 
 
 class GenerationPrograms:
@@ -353,6 +380,11 @@ class GenerationPrograms:
         self._aux: list = []                # run()s' aux nobody took yet
         self._lock = threading.Lock()
         self._stats: Dict[tuple, Dict[str, int]] = {}
+        # key -> thunk for the optimised HLO text, noted where a key is
+        # first seen; the carry's by the shape of the tokens it merges
+        # (observability.device_scopes)
+        self._texts: Dict[tuple, object] = {}
+        _device_scopes.register(self)
 
     def _place_params(self, params):
         import jax.numpy as jnp
@@ -454,6 +486,13 @@ class GenerationPrograms:
                 else:
                     jitted = jax.jit(fn, donate_argnums=(0,))
                 self._jits[fn, k] = jitted
+            if not hit:
+                self._texts[key] = _device_scopes.text_thunk(
+                    jitted, ((self._params,) if step else ())
+                    + (cache.pools,) + tuple(args))
+                if fn is _block_step or (fn is _model_step
+                                         and args[0].shape[1] == 1):
+                    self._note_carry(args[0].shape)
         _executor._note_cache(hit=hit, site=(site, ("lm",)), key=key)
         with self._lock:
             per["hits" if hit else "misses"] += 1
@@ -464,6 +503,40 @@ class GenerationPrograms:
             *out, pools = jitted(self._params, cache.pools, *args)
             cache.swap(pools)
         return tuple(out)
+
+    def _note_carry(self, rows):
+        """The carry that feeds steps whose ``tokens`` are ``rows`` (S, 1)
+        or (S, L): its shapes follow from theirs."""
+        import jax
+
+        if ("carry", rows) in self._texts:
+            return
+        i32 = jax.ShapeDtypeStruct(rows, _np.int32)
+        flag = jax.ShapeDtypeStruct(rows, _np.bool_)
+        keep = jax.ShapeDtypeStruct(rows[:1], _np.bool_)
+        self._texts["carry", rows] = _device_scopes.text_thunk(
+            self._carry_jit, (i32, i32, flag, i32, flag, keep)
+            if self._model.block_len else
+            (jax.ShapeDtypeStruct(rows[:1], _np.int32), i32, keep))
+
+    def device_programs(self):
+        """What ``observability.device_scopes`` reads: ``(None: the kind
+        is the text's outermost scope, key, launches, thunk for the
+        optimised HLO text)`` of every signature this object has run, and
+        of the carry (launched, for all anyone counts, as often as the
+        steps it feeds)."""
+        with self._lock:
+            launches = {key: sum(per.values())
+                        for key, per in self._stats.items()}
+            texts = list(self._texts.items())
+        out = []
+        for key, thunk in texts:
+            n = launches.get(key)
+            if n is None:       # the carry, keyed ("carry", tokens' shape)
+                n = sum(m for fed, m in launches.items()
+                        if ("tokens", key[1], "int32") in fed[1])
+            out.append((None, key, n, thunk))
+        return out
 
     def run(self, kind: str, cache, tokens, positions, lengths,
             block_tables, seeds, counters, temperature, top_k, top_p):

@@ -190,6 +190,11 @@ def trace(entries: Sequence[SymbolEntry], env: Dict[str, object], is_train: bool
             values[id(node)] = (env[node.name],)
             continue
         ins = [values[id(e.node)][e.index] for e in node.inputs]
-        values[id(node)] = eval_node(node, ins, is_train, rng_key, collect_aux)
+        # the device's name for this node's work: ``<operator>/<node name>``
+        # in every operation's ``op_name`` (docs/observability.md "Device
+        # scopes"); read while tracing only, the lowered program is the same
+        with jax.named_scope(node.op.name), jax.named_scope(node.name):
+            values[id(node)] = eval_node(node, ins, is_train, rng_key,
+                                         collect_aux)
 
     return [values[id(e.node)][e.index] for e in entries]

@@ -453,6 +453,52 @@ def test_block_step_program_compiles_at_the_cells_shapes(one_chip,
         + mem.output_size_in_bytes - mem.alias_size_in_bytes < 13e9
 
 
+def test_block_step_text_resolves_to_the_programs_scopes(one_chip,
+                                                         monkeypatch):
+    """What the CHIP's compiler writes is what the device-scope resolver
+    reads (docs/observability.md "Device scopes"): of the instructions
+    of the block step's entry computation that a trace would show, all but
+    a few copies resolve to a scope, the program's kind is its outermost
+    scope, and the kernels sit under the layer parts that call them."""
+    from mxnet_tpu.observability import device_scopes as ds
+    from mxnet_tpu.serving.generation import programs as gp
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    S, L, W = 64, 4, 256
+    model, params, pool = _sdar_cell(sds, 2)
+    lowered = jax.jit(functools.partial(
+        gp._block_step, model=model, attention_kernel="paged"),
+        donate_argnums=(1,)).lower(
+        params, (pool, pool), sds((S, L), jnp.int32), sds((S, L), jnp.int32),
+        sds((S,), jnp.int32), sds((S, W), jnp.int32), sds((S, L), jnp.bool_),
+        sds((S,), jnp.int32))
+    text = lowered.compile().as_text()
+    # this build's names, by the resolver's own test; and the chip's
+    # compiler takes the option a stale text is compiled again with
+    assert not ds.stale(lowered, text)
+    assert "fusion" in jax.jit(lambda x: jnp.tanh(x) * 2).lower(
+        sds((256, 256), jnp.float32)).compile(compiler_options={
+            "xla_dump_disable_metadata": False}).as_text()
+    table = ds.ProgramTable(None, text)
+    assert table.kind == "block"
+    shown = [n for n in table.order if re.search(
+        rf"%{re.escape(n)} = .*[\]\}}\)] (fusion|custom-call|copy|copy-start|"
+        r"copy-done|slice-start|slice-done|sort|while)\(", text)]
+    named = [n for n in shown if table.instrs[n][1] is not None]
+    assert len(shown) > 100 and len(named) >= 0.95 * len(shown)
+    scopes = {table.instrs[n][1].scope for n in named}
+    assert {"layer0/moe.experts/_gmm_call", "layer1/attn.cache_write",
+            "layer0/attn.kernel/_paged_call_w256_t4_block", "sample",
+            "head"} <= scopes
+    # a trace's whole HLO text of an instruction finds it too
+    gmm = next(ln for ln in text.splitlines() if "%_gmm_call" in ln
+               and " custom-call(" in ln)
+    event = gmm.strip().split(", metadata={")[0]
+    assert ds.Table([table]).resolve(event).scope.endswith(
+        "moe.experts/_gmm_call")
+
+
 @pytest.mark.parametrize("T,W", [(512, 32), (2048, 256)])
 def test_block_prefill_program_compiles_at_the_cells_shapes(one_chip,
                                                             monkeypatch,
