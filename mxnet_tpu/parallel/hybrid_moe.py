@@ -36,7 +36,9 @@ group, the expert products ``sdar_moe``'s.
 
 ``experts_held = (lo, hi)`` is the chip's share of the routed experts, as
 in ``latent_moe.py``: the router scores all of them, the products add this
-chip's experts' part, nothing stands in for the other chips.
+chip's experts' part — over this chip's rows alone, in tiles of twice a
+balanced router's share, the trips counted (``expert_trips``,
+``expert_trips_extra``) — and nothing stands in for the other chips.
 
 Parameters are a flat dict in ONE dtype and are never cast in the program:
 products take operands in that dtype and accumulate in float32; the
@@ -52,7 +54,8 @@ import jax
 import jax.numpy as jnp
 
 from .latent_moe import _gated, route_sigmoid_groups
-from .sdar_moe import _mm, _rms, _rope, expert_products
+from .sdar_moe import (_mm, _rms, _rope, count_trips, expert_products,
+                       trip_counters)
 from .transformer import paged_write_coords
 
 Params = Dict[str, jnp.ndarray]
@@ -218,7 +221,8 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
 
     Arguments otherwise as ``transformer_lm_decode``.  Returns ``(logits
     (B, T, vocab) float32, pools, aux)``; ``aux`` is the dict of this
-    call's counts (``COUNTERS``; docs/observability.md), made on the
+    call's counts (``COUNTERS``, and with a share of the experts held
+    ``sdar_moe.TRIP_COUNTERS``; docs/observability.md), made on the
     device from what the program itself saw: valid queries only, except
     ``experts_touched``, which counts the experts whose weights the
     products read."""
@@ -317,8 +321,9 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
                     _mm(hf, g("router")), g("router_bias"),
                     cfg.num_experts_per_tok, cfg.n_group, cfg.topk_group,
                     cfg.norm_topk_prob, cfg.routed_scaling_factor)
-            y, sizes = expert_products(hf, w, e, g("wg"), g("wu"), g("wd"),
-                                       (lo, hi), pallas=use_kernel)
+            y, sizes, trips = expert_products(
+                hf, w, e, g("wg"), g("wu"), g("wd"), (lo, hi),
+                pallas=use_kernel, n_experts=cfg.n_routed_experts)
             with scope("moe.combine"):
                 x = x + y.reshape(B, T, -1)
             with scope("moe.route"):    # the program's own counts
@@ -333,6 +338,7 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
                 aux["experts_touched"] += jnp.sum(sizes > 0).astype(jnp.int32)
                 aux["expert_tokens_max"] = jnp.maximum(
                     aux["expert_tokens_max"], jnp.max(load).astype(jnp.int32))
+                count_trips(aux, trips)
     with scope("head"):
         logits = _mm(_rms(x, params["norm_f"], eps), params["head"])
     return logits, tuple(pools), aux
@@ -356,11 +362,17 @@ class HybridMoeLM:
     longest_chunk: int = 512
     block_len = 0
     offers = frozenset({"sampling"})
-    counters = COUNTERS
     # the tiles body fetches the pages a tile reads and no others, for a
     # chunk as for one token: a table's width costs nothing, so the
     # service keeps one
     one_table_width = True
+
+    @property
+    def counters(self) -> Tuple[str, ...]:
+        """The names of the program's counts: with a share of the experts
+        held, the expert layers' trips too."""
+        return COUNTERS + trip_counters(self.experts_held,
+                                        self.cfg.n_routed_experts)
 
     @property
     def vocab(self) -> int:

@@ -43,7 +43,14 @@ seeded weights absorb.
 the router scores all ``n_routed_experts``, the expert products
 (``sdar_moe.expert_products``, shared with that model) add this chip's
 experts' part, the shared expert is computed whole, and nothing stands in
-for the other chips or their exchange.
+for the other chips or their exchange.  The products are told the
+router's width and so work on this chip's rows alone: with 16 of 256
+held, a sixteenth of the ``N x 8`` assignments, walked in tiles of twice
+that (512 rows of a 512-token chunk's 4,096).  One trip a layer call is
+the rule; the program counts the trips (``expert_trips``) and those beyond
+a call's first (``expert_trips_extra``: the router sent this chip more
+than twice its share), which cost another read of the touched experts'
+weights and change no result.
 
 Parameters are a flat dict in ONE dtype and are never cast in the
 program: products take operands in that dtype and accumulate in float32;
@@ -60,7 +67,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .sdar_moe import _mm, _rms, expert_products
+from .sdar_moe import (_mm, _rms, count_trips, expert_products,
+                       trip_counters)
 from .transformer import paged_write_coords
 
 Params = Dict[str, jnp.ndarray]
@@ -255,7 +263,8 @@ def latent_moe_decode(params: Params, tokens, positions, lengths, pool,
     own.
 
     Returns ``(logits (B, T, vocab) float32, pool, aux)``; ``aux`` is the
-    dict of this call's counts (``COUNTERS``; docs/observability.md), made
+    dict of this call's counts (``COUNTERS``, and with a share of the
+    experts held ``sdar_moe.TRIP_COUNTERS``; docs/observability.md), made
     on the device from what the program itself saw: valid queries only,
     except ``experts_touched``, which counts the experts whose weights the
     products read."""
@@ -342,8 +351,9 @@ def latent_moe_decode(params: Params, tokens, positions, lengths, pool,
                     _mm(hf, g("router")), g("router_bias"),
                     cfg.num_experts_per_tok, cfg.n_group, cfg.topk_group,
                     cfg.norm_topk_prob, cfg.routed_scaling_factor)
-            y, sizes = expert_products(hf, w, e, g("wg"), g("wu"), g("wd"),
-                                       (lo, hi), pallas=use_kernel)
+            y, sizes, trips = expert_products(
+                hf, w, e, g("wg"), g("wu"), g("wd"), (lo, hi),
+                pallas=use_kernel, n_experts=cfg.n_routed_experts)
             with scope("ffn"):          # the shared expert
                 shared = _gated(hf, g("sg"), g("su"), g("sd"))
             with scope("moe.combine"):
@@ -360,6 +370,7 @@ def latent_moe_decode(params: Params, tokens, positions, lengths, pool,
                 aux["experts_touched"] += jnp.sum(sizes > 0).astype(jnp.int32)
                 aux["expert_tokens_max"] = jnp.maximum(
                     aux["expert_tokens_max"], jnp.max(load).astype(jnp.int32))
+                count_trips(aux, trips)
     with scope("head"):
         logits = _mm(_rms(x, params["norm_f"], eps), params["head"])
     return logits, pool, aux
@@ -387,10 +398,16 @@ class LatentMoeLM:
     longest_chunk: int = 512
     block_len = 0
     offers = frozenset({"sampling"})
-    counters = COUNTERS
     # the latent kernel fetches live pages only, for a chunk as for one
     # token: a table's width costs nothing, so the service keeps one
     one_table_width = True
+
+    @property
+    def counters(self) -> Tuple[str, ...]:
+        """The names of the program's counts: with a share of the experts
+        held, the expert layers' trips too."""
+        return COUNTERS + trip_counters(self.experts_held,
+                                        self.cfg.n_routed_experts)
 
     @property
     def vocab(self) -> int:

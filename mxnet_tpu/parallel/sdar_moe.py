@@ -28,6 +28,7 @@ is ``perfbench/reference/sdar_moe.py``.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
@@ -39,7 +40,12 @@ from .transformer import paged_write_coords
 Params = Dict[str, jnp.ndarray]
 
 __all__ = ["SdarMoeConfig", "SdarMoeLM", "sdar_moe_init", "sdar_moe_decode",
-           "moe_grouped", "expert_products", "unmask_schedule"]
+           "moe_grouped", "expert_products", "count_trips", "trip_counters",
+           "TRIP_COUNTERS", "unmask_schedule"]
+
+#: what a model that holds a share of its experts counts of
+#: :func:`expert_products`' walks (docs/observability.md)
+TRIP_COUNTERS = ("expert_trips", "expert_trips_extra")
 
 
 @dataclass(frozen=True)
@@ -149,7 +155,7 @@ def _rope(x, positions, theta):
 
 def expert_products(h, w, e, wg, wu, wd,
                     experts_held: Optional[Tuple[int, int]] = None,
-                    pallas: bool = False):
+                    pallas: bool = False, n_experts: Optional[int] = None):
     """The expert layer behind its router, dropless: given every token's
     chosen experts ``e`` (N, k) — ids over ALL the layer's experts — and
     their weights ``w`` (N, k), sort the ``N * k`` assignments by expert
@@ -162,12 +168,26 @@ def expert_products(h, w, e, wg, wu, wd,
     sigmoid scores over groups in ``latent_moe.py``) is the caller.
 
     ``experts_held = (lo, hi)`` says which experts ``wg`` / ``wu`` / ``wd``
-    hold (default: as many as they hold, from 0): assignments to others add
-    nothing here — a chip that holds a share of the experts computes its
-    share of the result, and nothing stands in for the other chips.
+    hold (default: as many as they hold, from 0), ``n_experts`` how many
+    the router chooses among (default: the held ones): assignments to
+    experts held elsewhere add nothing here — a chip that holds a share of
+    the experts computes its share of the result, and nothing stands in
+    for the other chips.  Nor does it pay for their rows: the sort puts the
+    held assignments first, and with a share held the function walks THOSE
+    in tiles of ``cap`` rows — gather, the three products, weigh,
+    scatter-add, ``cap`` rows each — for ``ceil(held assignments / cap)``
+    trips of one ``lax.while_loop``.  ``cap`` is twice the share a
+    balanced router sends here, ``2 N k held / n_experts`` in whole row
+    tiles of the kernel (128) and at most ``N k``: one trip is the rule; a
+    second one reads the touched experts' weights again, is exact like the
+    first (a group that straddles a tile's edge is cut there) and is
+    counted.  With every expert held every row is real and there is one
+    pass over all of them, no loop.
 
-    Returns ``(y (N, d) float32, sizes (held,) int32)``: ``sizes`` counts
-    the assignments each held expert got."""
+    Returns ``(y (N, d) float32, sizes (held,) int32, trips)``: ``sizes``
+    counts the assignments each held expert got, ``trips`` (int32 scalar)
+    the tiles walked — None where every expert is held and nothing is
+    walked."""
     N, top_k = e.shape
     lo, hi = experts_held if experts_held is not None else (0, wg.shape[0])
     held = hi - lo
@@ -184,23 +204,85 @@ def expert_products(h, w, e, wg, wu, wd,
         token = order // top_k
         sizes = jnp.bincount(jnp.where(mine, local, held), length=held + 1
                              )[:held].astype(jnp.int32)
-    with scope("moe.combine"):
-        xs = h.astype(wg.dtype)[token]                         # (N*k, d)
     if pallas:
-        from ..ops.grouped_matmul import grouped_matmul
-        rd = lambda a, b: grouped_matmul(a, b, sizes)  # noqa: E731
+        from ..ops.grouped_matmul import grouped_matmul as rd
     else:
-        rd = lambda a, b: jax.lax.ragged_dot(  # noqa: E731
-            a, b, sizes, preferred_element_type=jnp.float32)
-    with scope("moe.experts"):
-        act = jax.nn.silu(rd(xs, wg)) * rd(xs, wu)             # (N*k, F)
-        ys = rd(act.astype(wd.dtype), wd)                      # (N*k, d)
-    with scope("moe.combine"):
-        # rows behind the last group hold whatever the kernel left there
-        ys = jnp.where(mine[order][:, None],
-                       ys * w.reshape(-1)[order][:, None], 0.0)
-        y = jnp.zeros((N, h.shape[1]), jnp.float32).at[token].add(ys)
-    return y, sizes
+        rd = functools.partial(jax.lax.ragged_dot,
+                               preferred_element_type=jnp.float32)
+    hx = h.astype(wg.dtype)
+
+    def products(tok, groups):
+        """The tokens ``tok`` of rows sorted by expert, ``groups`` rows an
+        expert, through their experts: ``(len(tok), d)`` float32."""
+        with scope("moe.combine"):
+            xs = hx[tok]
+        with scope("moe.experts"):
+            act = jax.nn.silu(rd(xs, wg, groups)) * rd(xs, wu, groups)
+            return rd(act.astype(wd.dtype), wd, groups)
+
+    if held >= (n_experts or held):
+        ys = products(token, sizes)                            # (N*k, d)
+        with scope("moe.combine"):
+            # rows behind the last group hold whatever the kernel left there
+            ys = jnp.where(mine[order][:, None],
+                           ys * w.reshape(-1)[order][:, None], 0.0)
+            y = jnp.zeros((N, h.shape[1]), jnp.float32).at[token].add(ys)
+        return y, sizes, None
+
+    M = N * top_k
+    cap = min(M, -(-2 * M * held // (n_experts * 128)) * 128)
+    with scope("moe.route"):
+        # whole tiles: rows behind the held ones are masked, wherever from
+        order = jnp.pad(order, (0, -M % cap))
+        ends = jnp.cumsum(sizes)
+        starts, n_mine = ends - sizes, ends[-1]
+        wf = w.reshape(-1)
+
+    def trip(carry):
+        t, y = carry
+        with scope("moe.route"):
+            at = t * cap
+            rows = jax.lax.dynamic_slice(order, (at,), (cap,))
+            # each group's [start, end) cut to the tile's [at, at + cap)
+            share = (jnp.clip(ends - at, 0, cap)
+                     - jnp.clip(starts - at, 0, cap))
+            tok = rows // top_k
+        ys = products(tok, share)                              # (cap, d)
+        with scope("moe.combine"):
+            live = at + jnp.arange(cap, dtype=jnp.int32) < n_mine
+            ys = jnp.where(live[:, None], ys * wf[rows][:, None], 0.0)
+            return t + 1, y.at[tok].add(ys)
+
+    def more(carry):
+        with scope("moe.route"):
+            return carry[0] * cap < n_mine
+
+    trips, y = jax.lax.while_loop(
+        more, trip, (jnp.zeros((), jnp.int32),
+                     jnp.zeros((N, h.shape[1]), jnp.float32)))
+    return y, sizes, trips
+
+
+def trip_counters(experts_held: Optional[Tuple[int, int]],
+                  n_experts: int) -> Tuple[str, ...]:
+    """The names :func:`count_trips` adds to the counts of a model whose
+    chip holds ``experts_held`` of ``n_experts``: none with every expert
+    held."""
+    whole = experts_held is None or \
+        experts_held[1] - experts_held[0] >= n_experts
+    return () if whole else TRIP_COUNTERS
+
+
+def count_trips(aux: dict, trips) -> None:
+    """Add one expert layer call's ``trips`` (:func:`expert_products`) to a
+    program's counts: the tiles walked, and those beyond the first.  None
+    (every expert held: nothing walked) adds nothing, not even the names:
+    such a program hands back what it did before."""
+    if trips is None:
+        return
+    zero = jnp.zeros((), jnp.int32)
+    for name, n in zip(TRIP_COUNTERS, (trips, jnp.maximum(trips - 1, 0))):
+        aux[name] = aux.get(name, zero) + n
 
 
 def moe_grouped(h, router, wg, wu, wd, top_k: int, norm_topk: bool = True,
@@ -217,7 +299,8 @@ def moe_grouped(h, router, wg, wu, wd, top_k: int, norm_topk: bool = True,
         w, e = jax.lax.top_k(p, top_k)                         # (N, k)
         if norm_topk:
             w = w / jnp.sum(w, axis=-1, keepdims=True)
-    y, sizes = expert_products(h, w, e, wg, wu, wd, experts_held, pallas)
+    y, sizes, _ = expert_products(h, w, e, wg, wu, wd, experts_held, pallas,
+                                  n_experts=router.shape[1])
     with jax.named_scope("moe.route"):
         return y, jnp.sum(sizes > 0)
 

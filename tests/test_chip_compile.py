@@ -610,13 +610,36 @@ def test_latent_decode_step_program_compiles_at_the_cells_shapes(
         sds((S,), jnp.float32)).compile()
     text = compiled.as_text()
     assert text.count("_mla_call_w512_decode") >= 2
-    assert len(re.findall(r"%_gmm_call[\w.\-]* = f32\[2048,", text)) == 3
+    # 16 of the router's 256 experts held: the expert layer walks the held
+    # rows in tiles of 2 x 256 x 8 / 16 = 256, ONE copy of the kernel's
+    # call a projection inside the loop, none over all 2,048 rows, and the
+    # experts' matrices go through the loop as they are (1.4 GB a layer)
+    assert len(re.findall(r"%_gmm_call[\w.\-]* = f32\[256,", text)) == 3
+    assert "f32[2048,7168]" not in text
+    _the_walk_is_named_and_copies_no_expert(text, 7168, 2048)
     whole = f"= bf16[2,4096,{BS},640]"
     makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
               for ln in text.splitlines() if whole in ln}
     assert makers <= _IN_PLACE
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.5e9
+
+
+def _the_walk_is_named_and_copies_no_expert(text, d, f):
+    """The program's ``while`` over the held rows' tiles: its body's
+    operations resolve to the expert layer's scopes (the loop is jax's
+    structure, not a scope), and nothing of an expert matrix's size is
+    copied on the way in."""
+    from mxnet_tpu.observability import device_scopes as ds
+
+    table = ds.ProgramTable(None, text)
+    scopes = {r.scope for _, r in table.instrs.values() if r is not None}
+    assert {"layer1/moe.experts/_gmm_call", "layer1/moe.combine",
+            "layer1/moe.route"} <= scopes
+    assert not [s for s in scopes if "while" in s or "body" in s]
+    experts = rf"bf16\[16,({d},{f}|{f},{d})\]"
+    assert re.search(experts, text)
+    assert not re.search(rf"= {experts}\S* (copy|fusion)\(", text)
 
 
 # -- MiMo-V2.5's window and full attention layers (parallel/hybrid_moe.py) ----
@@ -702,6 +725,12 @@ def test_hybrid_step_program_compiles_at_the_cells_shapes(one_chip,
     phase = "decode" if T == 1 else "prefill"
     assert f"_paged_call_w1024_t{T}_full_{phase}" in text
     assert text.count(f"_paged_call_w{ring}_t{T}_window_{phase}") >= 2
+    # the held rows' tiles: 2 x 128 x 8 / 16 = 128 rows a decode step,
+    # 512 a chunk, and never all S x T x 8 of them
+    rows = 2 * S * T * 8 // 16
+    assert len(re.findall(rf"%_gmm_call[\w.\-]* = f32\[{rows},", text)) == 6
+    assert f"f32[{S * T * 8},4096]" not in text
+    _the_walk_is_named_and_copies_no_expert(text, 4096, 2048)
     for shape in (f"= bf16[1,4096,{BS},768]", f"= bf16[2,1322,{BS},1536]"):
         makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
                   for ln in text.splitlines() if shape in ln}

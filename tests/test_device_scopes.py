@@ -128,9 +128,12 @@ def _service(model, **gc):
             moe_intermediate_size=16, n_routed_experts=8,
             num_experts_per_tok=2, n_group=1, topk_group=1, vocab_size=97,
             max_position_embeddings=64)
+        # a chip's share of the experts: the expert layer is a loop over
+        # the held rows' tiles (``sdar_moe.expert_products``), whose body
+        # keeps the vocabulary (``while/body`` is jax's, not a scope)
         m = hm.HybridMoeLM(cfg, max_len=64, kv_dtype=jnp.float32,
-                           longest_chunk=16)
-        params = hm.hybrid_moe_init(cfg, key)
+                           longest_chunk=16, experts_held=(2, 4))
+        params = hm.hybrid_moe_init(cfg, key, experts_held=(2, 4))
         kw.update(prefix_cache=None)
     kw.update(gc)
     with pytest.MonkeyPatch.context() as mp:

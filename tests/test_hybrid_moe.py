@@ -361,14 +361,14 @@ def test_the_sixteen_shares_of_an_expert_layer_sum_to_the_uncut_layer(
                              norm_topk=True, scaling=1.0)
     np.testing.assert_array_equal(np.asarray(e), np.asarray(e_ref))
     np.testing.assert_allclose(np.asarray(w), np.asarray(w_ref), atol=1e-6)
-    whole, sizes = expert_products(h, w, e, g("wg"), g("wu"), g("wd"),
-                                   pallas=pallas)
+    whole, sizes, _ = expert_products(h, w, e, g("wg"), g("wu"), g("wd"),
+                                      pallas=pallas)
     assert int(sizes.sum()) == 23 * 2
     parts, ref_parts = 0, 0
     for lo in range(16):
         one = slice(lo, lo + 1)
-        y, sz = expert_products(h, w, e, g("wg")[one], g("wu")[one],
-                                g("wd")[one], (lo, lo + 1), pallas=pallas)
+        y, sz, _ = expert_products(h, w, e, g("wg")[one], g("wu")[one],
+                                   g("wd")[one], (lo, lo + 1), pallas=pallas)
         np.testing.assert_array_equal(np.asarray(sz), np.asarray(sizes[one]))
         parts = parts + y
         ref_parts = ref_parts + ref._experts(
@@ -624,6 +624,41 @@ def test_the_programs_counts_and_the_kinds_gauges_reach_stats(params3):
     assert 'generation_kv_kind_blocks_used{kind="window"}' in \
         obs.registry().to_prometheus()
     svc.stop(drain=False, timeout=30)
+
+
+def test_the_expert_layers_trips_reach_stats_and_a_second_trip_is_exact():
+    """Experts 4-7 of 16 held and their router bias raised, so that every
+    token (a padded one too) chooses two of them: a 128-token chunk's 256
+    assignments are all this chip's, two tiles of 128 rows an expert layer,
+    a decode step's 8 one.  The program counts the tiles it walked, the
+    engine sums them, and the tokens are the reference's."""
+    c = dict(C3, experts_held=[4, 8])
+    p = dict(ref.init_params(3, c, "float32"))
+    for i in (1, 2):
+        p[f"l{i}_router_bias"] = p[f"l{i}_router_bias"].at[4:8].add(100.0)
+    model = hm.HybridMoeLM(_config(**CUT), max_len=MAX_LEN,
+                           kv_dtype=jnp.float32, longest_chunk=128,
+                           experts_held=(4, 8))
+    assert model.counters == hm.COUNTERS + ("expert_trips",
+                                            "expert_trips_extra")
+    assert _model().counters == hm.COUNTERS
+    svc = _service(p, model=model, seq_buckets=[8, 128, 400])
+    prompt = [int(t) for t in np.random.default_rng(4).integers(0, V, 133)]
+    chunks = [tb for _, _, tb, _ in svc._chunk_plan(len(prompt))]
+    assert chunks == [128, 8]
+    svc.start()
+    got = svc.generate(prompt, max_new_tokens=6, timeout=300)
+    counts = svc.stats()["counts"]
+    svc.stop(drain=False, timeout=30)
+    seq = list(prompt)
+    for _ in range(6):
+        seq.append(int(_ref_logits(p, seq, len(seq) - 1, c=c)[0].argmax()))
+    assert list(got) == seq[len(prompt):]
+    assert counts["expert_assignments_held"] == counts["expert_assignments"]
+    # two expert layers; a chunk of 128 x top-2 = 256 rows in tiles of 128,
+    # one of 8 and the 5 decode steps read (4 slots x 2) in one tile each
+    assert counts["expert_trips"] == 2 * (2 + 1 + 5)
+    assert counts["expert_trips_extra"] == 2 * 1
 
 
 @pytest.mark.parametrize("kernel,n_width", [("gather", 5), ("paged", 1)])

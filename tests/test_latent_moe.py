@@ -284,15 +284,16 @@ def test_the_shares_of_the_experts_sum_to_the_uncut_layer(params, monkeypatch,
     g = lambda n: params[f"l1_{n}"]  # noqa: E731
     w, e = lm.route_sigmoid_groups(h @ g("router"), g("router_bias"), 4, 4,
                                    2, True, 2.5)
-    whole, sizes = expert_products(h, w, e, g("wg"), g("wu"), g("wd"),
-                                   pallas=pallas)
+    whole, sizes, _ = expert_products(h, w, e, g("wg"), g("wu"), g("wd"),
+                                      pallas=pallas)
     assert int(sizes.sum()) == 23 * 4
     parts, ref_parts = 0, 0
     for lo in range(0, 16, 4):
         held = (lo, lo + 4)
-        y, sz = expert_products(h, w, e, g("wg")[lo:lo + 4],
-                                g("wu")[lo:lo + 4], g("wd")[lo:lo + 4],
-                                held, pallas=pallas)
+        y, sz, trips = expert_products(h, w, e, g("wg")[lo:lo + 4],
+                                       g("wu")[lo:lo + 4], g("wd")[lo:lo + 4],
+                                       held, pallas=pallas, n_experts=16)
+        assert int(trips) == 1
         np.testing.assert_array_equal(np.asarray(sz),
                                       np.asarray(sizes[lo:lo + 4]))
         parts = parts + y
@@ -480,6 +481,44 @@ def test_the_programs_counts_reach_stats(params):
     assert counts["expert_assignments_held"] == counts["expert_assignments"]
     assert 0 < counts["experts_touched"] <= 2 * 16 * (2 + 5)
     assert counts["expert_tokens_max"] >= 5
+
+
+def test_the_expert_layers_trips_reach_stats_and_a_second_trip_is_exact(
+        monkeypatch):
+    """Experts 4-7 of 16 held and their router bias raised, so that every
+    token (a padded one too) chooses them: a 64-token chunk's 256
+    assignments are all this chip's, two tiles of 128 rows an expert layer,
+    a decode step's 16 one.  The program counts the tiles it walked, the
+    engine sums them, and the tokens are the reference's."""
+    monkeypatch.setenv("TPUMX_PALLAS", "0")
+    c = dict(C, experts_held=[4, 8])
+    p = dict(ref.init_params(3, c, "float32"))
+    for i in (1, 2):
+        p[f"l{i}_router_bias"] = p[f"l{i}_router_bias"].at[4:8].add(100.0)
+    model = lm.LatentMoeLM(CFG, max_len=MAX_LEN, experts_held=(4, 8),
+                           kv_dtype=jnp.float32, longest_chunk=64)
+    assert model.counters == lm.COUNTERS + ("expert_trips",
+                                            "expert_trips_extra")
+    svc = _service(p, model=model)
+    prompt = [int(t) for t in np.random.default_rng(4).integers(0, V, 70)]
+    chunks = [tb for _, _, tb, _ in svc._chunk_plan(len(prompt))]
+    assert chunks == [64, 16]
+    svc.start()
+    got = svc.generate(prompt, max_new_tokens=6, timeout=120)
+    counts = svc.stats()["counts"]
+    svc.stop(drain=False, timeout=30)
+    seq = list(prompt)
+    for _ in range(6):
+        seq.append(int(_ref_logits(p, seq, len(seq) - 1, c=c)[0].argmax()))
+    assert list(got) == seq[len(prompt):]
+    assert counts["expert_assignments_held"] == counts["expert_assignments"]
+    # two expert layers; a chunk of 64 x top-4 = 256 rows in tiles of 128,
+    # one of 16 and the 5 decode steps read (4 slots x 4) in one tile each
+    assert counts["expert_trips"] == 2 * (2 + 1 + 5)
+    assert counts["expert_trips_extra"] == 2 * 1
+    # every expert held: nothing is walked, and nothing says it was
+    assert MODEL.counters == lm.COUNTERS
+    assert "expert_trips" not in _service(p, model=MODEL).stats()["counts"]
 
 
 def test_prefix_cache_hit_serves_the_same_tokens(params):

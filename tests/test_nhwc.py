@@ -6,6 +6,7 @@ TPU rationale: NHWC puts C on the 128-lane minor dim, avoiding relayouts for
 BN reductions and conv tiling (docs/perf_analysis.md).
 """
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import gluon, nd
@@ -119,30 +120,32 @@ def test_symbol_conv_nhwc_bind_and_run():
     np.testing.assert_allclose(out.transpose(0, 3, 1, 2), ref, atol=1e-4)
 
 
-def test_zoo_layouts_match():
+@pytest.mark.parametrize("name,sz", [
+    ("mobilenet0_25", 64), ("mobilenet_v2_0_25", 64), ("alexnet", 224),
+    ("vgg11", 64), ("squeezenet1_1", 224), ("densenet121", 224),
+    ("inception_v3", 299)])
+def test_zoo_layouts_match(name, sz):
     """MobileNet v1/v2, AlexNet, and VGG take layout="NHWC" with
     layout-independent parameter storage (same contract as the resnet
     zoo): identical params => identical outputs across layouts.  The
     Flatten-headed nets relayout to NCHW order before the classifier so
-    Dense weights stay checkpoint-compatible too."""
+    Dense weights stay checkpoint-compatible too.  (A case a model: the
+    seven take three minutes together, and a file of few long tests is
+    handed to a worker last.)"""
     from mxnet_tpu.gluon.model_zoo import vision
 
+    factory = getattr(vision, name)
     rng = np.random.RandomState(0)
-    cases = ((vision.mobilenet0_25, 64), (vision.mobilenet_v2_0_25, 64),
-             (vision.alexnet, 224), (vision.vgg11, 64),
-             (vision.squeezenet1_1, 224), (vision.densenet121, 224),
-             (vision.inception_v3, 299))
-    for factory, sz in cases:
-        a = factory(classes=10)
-        a.initialize()
-        x = rng.rand(1, 3, sz, sz).astype(np.float32)
-        oa = a(nd.array(x)).asnumpy()
-        b = factory(classes=10, layout="NHWC")
-        b.initialize()
-        xb = nd.array(np.transpose(x, (0, 2, 3, 1)))
-        b(xb)  # materialize deferred shapes
-        for qa, qb in zip(a.collect_params().values(),
-                          b.collect_params().values()):
-            qb.set_data(qa.data())
-        ob = b(xb).asnumpy()
-        assert np.allclose(oa, ob, atol=5e-4), factory.__name__
+    a = factory(classes=10)
+    a.initialize()
+    x = rng.rand(1, 3, sz, sz).astype(np.float32)
+    oa = a(nd.array(x)).asnumpy()
+    b = factory(classes=10, layout="NHWC")
+    b.initialize()
+    xb = nd.array(np.transpose(x, (0, 2, 3, 1)))
+    b(xb)  # materialize deferred shapes
+    for qa, qb in zip(a.collect_params().values(),
+                      b.collect_params().values()):
+        qb.set_data(qa.data())
+    ob = b(xb).asnumpy()
+    assert np.allclose(oa, ob, atol=5e-4)

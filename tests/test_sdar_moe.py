@@ -282,6 +282,98 @@ def test_grouped_expert_product_matches_loop_under_skew(n_tokens, pallas):
                                np.asarray(y), atol=1e-5, rtol=1e-5)
 
 
+def _expert_layer(seed, N, k, E, lo, hi, e=None, d=64, F=32):
+    """Seeded inputs of ``expert_products`` for a chip that holds experts
+    ``lo .. hi - 1`` of ``E``; ``e`` (N, k) forces the router's choice."""
+    rng = np.random.default_rng(seed)
+    if e is None:
+        e = np.stack([rng.choice(E, k, replace=False) for _ in range(N)])
+    held = hi - lo
+    f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
+    return (f32(rng.standard_normal((N, d))), f32(rng.random((N, k))),
+            jnp.asarray(e, jnp.int32),
+            f32(rng.standard_normal((held, d, F)) / 8),
+            f32(rng.standard_normal((held, d, F)) / 8),
+            f32(rng.standard_normal((held, F, d)) / 6))
+
+
+def _walked_and_whole(args, held, E, pallas):
+    """``expert_products`` told the router's width (a share held: the walk
+    over the held rows) and not told it (one pass over all ``N k`` rows,
+    the held ones masked: the function as it was before the walk)."""
+    walked = sm.expert_products(*args, held, pallas, n_experts=E)
+    y, sizes, none = sm.expert_products(*args, held, pallas)
+    assert none is None
+    return walked, (y, sizes)
+
+
+@pytest.mark.parametrize("pallas", [False, True],
+                         ids=["ragged_dot", "pallas"])
+@pytest.mark.parametrize("N,k,E,lo,hi", [(64, 8, 256, 0, 16),
+                                         (64, 8, 256, 240, 256),
+                                         (160, 2, 8, 2, 4)],
+                         ids=["16of256", "last16of256", "2of8"])
+def test_held_share_walks_its_rows_in_one_trip_bit_for_bit(N, k, E, lo, hi,
+                                                            pallas):
+    """A balanced router sends a chip half a tile: one trip, and the sums
+    are the whole pass's additions in the same order."""
+    args = _expert_layer(N, N, k, E, lo, hi)
+    (y, sizes, trips), (y0, sizes0) = _walked_and_whole(args, (lo, hi), E,
+                                                        pallas)
+    e = np.asarray(args[2])
+    assert int(sizes0.sum()) == ((e >= lo) & (e < hi)).sum() > 0
+    assert int(trips) == 1
+    np.testing.assert_array_equal(np.asarray(sizes), np.asarray(sizes0))
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y0))
+    assert float(jnp.abs(y).max()) > 0.1
+
+
+@pytest.mark.parametrize("pallas", [False, True],
+                         ids=["ragged_dot", "pallas"])
+@pytest.mark.parametrize("tokens_held,want", [(0, 0), (32, 2), (40, 3),
+                                              (64, 4)])
+def test_router_forced_onto_the_held_experts_takes_more_trips(tokens_held,
+                                                               want, pallas):
+    """64 tokens x top-8 over 256 experts, 16 held: tiles of 128 rows.
+    The first ``tokens_held`` tokens choose held experts only, the others
+    none: 0, 256, 320 (half a tile left over) and all 512 rows are held,
+    and no assignment is dropped whatever the trips."""
+    N, k, E, lo, hi = 64, 8, 256, 16, 32
+    rng = np.random.default_rng(tokens_held)
+    e = np.stack([rng.choice(np.arange(lo, hi) if i < tokens_held
+                             else np.arange(hi, E), k, replace=False)
+                  for i in range(N)])
+    args = _expert_layer(1, N, k, E, lo, hi, e)
+    (y, sizes, trips), (y0, sizes0) = _walked_and_whole(args, (lo, hi), E,
+                                                        pallas)
+    assert int(sizes.sum()) == tokens_held * k
+    assert int(trips) == want == -(-tokens_held * k // 128)
+    np.testing.assert_array_equal(np.asarray(sizes), np.asarray(sizes0))
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y0))
+    assert (np.asarray(y)[tokens_held:] == 0).all()
+    assert (np.abs(np.asarray(y)[:tokens_held]).max(axis=1) > 0).all()
+
+
+@pytest.mark.parametrize("pallas", [False, True],
+                         ids=["ragged_dot", "pallas"])
+def test_a_group_that_straddles_a_tiles_edge_is_cut_there(pallas):
+    """200 rows in tiles of 128 (the last one padded): expert 3 takes rows
+    0-99, expert 7 rows 100-199, so 28 of its rows fall to the first trip
+    and 72 to the second."""
+    N, E, lo, hi = 200, 256, 0, 16
+    e = np.where(np.arange(N) < 100, 3, 7)[:, None]
+    args = _expert_layer(5, N, 1, E, lo, hi, e)
+    (y, sizes, trips), (y0, _) = _walked_and_whole(args, (lo, hi), E, pallas)
+    assert int(trips) == 2
+    assert [int(n) for n in sizes] == [100 * (g in (3, 7)) for g in range(16)]
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y0))
+    h, w, _, wg, wu, wd = args
+    with jax.default_matmul_precision("highest"):
+        want = ref._experts(h, w, jnp.asarray(e), wg, wu, wd, jnp.float32)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-4,
+                               rtol=1e-4)
+
+
 @pytest.fixture(scope="module")
 def served(params):
     svc = _service(params)
