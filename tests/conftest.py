@@ -7,7 +7,36 @@ without real chips (SURVEY.md §4's "distributed without a real cluster"
 analogue).  Pallas kernels run in interpret mode here; the chip's compiler
 is asked separately (tests/test_chip_compile.py).
 """
+import contextlib
 import os
+import shutil
+import tempfile
+
+
+def _place_compile_cache():
+    """One persistent compilation cache for the run (docs/testing.md): every
+    executable is written once and read by whichever test or xdist worker
+    needs it next.  The xdist controller (or the single process) makes the
+    directory and the workers inherit it; an outer
+    ``JAX_COMPILATION_CACHE_DIR`` is used as it is and left in place.
+    Returns the directory this process must remove at session end."""
+    for name in ("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                 "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"):
+        os.environ.setdefault(name, "0")
+    # XLA:CPU logs two 3 KB lines at ERROR level for every executable it
+    # reads back (`prefer-no-scatter` / `prefer-no-gather` are tuning
+    # flags the host's feature list never has): they bury a failure's
+    # captured output and fill the pipe of a child nobody reads yet
+    os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
+    if (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or "PYTEST_XDIST_WORKER" in os.environ):
+        return None
+    made = tempfile.mkdtemp(prefix="tpumx-tests-xla-")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = made
+    return made
+
+
+_OWN_COMPILE_CACHE = _place_compile_cache()
 
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8").strip() \
@@ -20,6 +49,30 @@ jax.config.update("jax_platforms", "cpu")
 
 import numpy as _np
 import pytest
+
+
+@contextlib.contextmanager
+def compile_cache_off():
+    """The run's compilation cache off and forgotten, then back as it was."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", old)
+        cc.reset_cache()
+
+
+@pytest.fixture
+def no_compile_cache():
+    """For a test that counts XLA's own compiles: an executable read from
+    the run's cache is no compile event, so a program first asked for after
+    warm-up would go uncounted if another test had compiled it."""
+    with compile_cache_off():
+        yield
 
 
 @pytest.fixture(autouse=True)
@@ -124,6 +177,11 @@ def pytest_configure(config):
         "proposers, the multi-query verify step, multistep lax.scan "
         "decode, exact-match rejection sampling; docs/generation.md "
         "\"Speculative decoding\"; select with `pytest -m speculative`)")
+
+
+def pytest_unconfigure(config):
+    if _OWN_COMPILE_CACHE:
+        shutil.rmtree(_OWN_COMPILE_CACHE, ignore_errors=True)
 
 
 def pytest_collection_modifyitems(config, items):

@@ -24,6 +24,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from conftest import compile_cache_off
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
@@ -52,14 +53,8 @@ def one_chip(topo):
 def _no_compile_cache():
     # a compile for a described chip is written to the persistent cache
     # but cannot be read back without the chip — keep it off and silent
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    old = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", old)
-    cc.reset_cache()
+    with compile_cache_off():
+        yield
 
 
 def _compile(fn, *shapes):

@@ -62,7 +62,8 @@ def _toy_iter(n=320, dim=8, classes=4, batch=32):
 
 
 def _fit(ckdir=None, preempt_step=None, resume=False, num_epoch=2,
-         optimizer="sgd", opt_params=(("learning_rate", 0.1),), every=3):
+         optimizer="sgd", opt_params=(("learning_rate", 0.1),), every=3,
+         batch_end_callback=None):
     if preempt_step is not None:
         os.environ["TPUMX_FAULT_PREEMPT_AT_STEP"] = str(preempt_step)
     else:
@@ -74,7 +75,7 @@ def _fit(ckdir=None, preempt_step=None, resume=False, num_epoch=2,
     completed = mod.fit(_toy_iter(), num_epoch=num_epoch,
                         optimizer=optimizer, optimizer_params=opt_params,
                         checkpoint_dir=ckdir, checkpoint_every=every,
-                        resume=resume)
+                        resume=resume, batch_end_callback=batch_end_callback)
     arg, aux = mod.get_params()
     return completed, {k: v.asnumpy() for k, v in arg.items()}, mod
 
@@ -196,7 +197,12 @@ def test_resume_from_corrupt_newest_checkpoint(tmp_path):
     from the previous retained one — still completing the full epoch
     budget (more steps re-run, same final trajectory invariants)."""
     ckdir = str(tmp_path / "ck")
-    _fit(ckdir=ckdir, preempt_step=7)  # checkpoints at 3, 6, final 7
+    # an async save that finds the writer busy is skipped by design: let
+    # the writer land step 3 before the fit reaches step 6
+    _fit(ckdir=ckdir, preempt_step=7,  # checkpoints at 3, 6, final 7
+         batch_end_callback=lambda p: p.locals["_ckpt"].manager.wait())
+    assert sorted(os.listdir(ckdir)) == [
+        "ckpt-0000000003", "ckpt-0000000006", "ckpt-0000000007"]
     corrupt_checkpoint(os.path.join(ckdir, "ckpt-0000000007"), "flip")
     done, res, mod = _fit(ckdir=ckdir, resume=True)
     assert done

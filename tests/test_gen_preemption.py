@@ -7,23 +7,20 @@ worker.
 import threading
 import time
 
-import jax
 import numpy as np
 import pytest
 
 from mxnet_tpu import observability as obs
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.fault.inject import injector
-from mxnet_tpu.parallel import transformer as tr
 from mxnet_tpu.serving import (DeadlineExceededError, QueueFullError,
                                RequestShedError, ServingClosedError)
-from mxnet_tpu.serving.generation import (GenerationConfig, GenerationService,
+from mxnet_tpu.serving.generation import (GenerationService,
                                           GenerationStepError, blocks_for)
+from oracle import CFG, greedy_oracle, params  # noqa: F401 (fixture)
+from test_generation import _gc
 
 pytestmark = pytest.mark.generation
-
-CFG = tr.TransformerConfig(vocab=40, d_model=32, n_heads=4, n_layers=2,
-                           d_ff=64, max_len=64)
 
 
 @pytest.fixture(autouse=True)
@@ -33,31 +30,6 @@ def _fresh_state():
     yield
     obs.recompile.reset()
     injector().reset()
-
-
-@pytest.fixture(scope="module")
-def params():
-    return tr.transformer_lm_init(CFG, jax.random.PRNGKey(0))
-
-
-def _gc(**kw):
-    kw.setdefault("max_slots", 2)
-    kw.setdefault("block_size", 8)
-    kw.setdefault("num_blocks", 32)
-    kw.setdefault("seq_buckets", [16, 32])
-    kw.setdefault("max_new_tokens", 8)
-    return GenerationConfig(**kw)
-
-
-def _greedy_oracle(params, prompt, n_new):
-    toks = [int(t) for t in prompt]
-    import jax.numpy as jnp
-    for _ in range(n_new):
-        logits = tr.transformer_lm_apply(
-            params, jnp.asarray([toks], dtype=jnp.int32),
-            jnp.arange(len(toks), dtype=jnp.int32), CFG)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
 
 
 # -- incremental allocation ---------------------------------------------------------
@@ -100,7 +72,7 @@ def test_preempted_and_resumed_greedy_bit_identical(params):
     stats = svc.stats()
     svc.stop()
     for p, got in zip(prompts, outs):
-        assert got == _greedy_oracle(params, p, 12)
+        assert got == greedy_oracle(params, p, 12)
     assert stats["counts"]["preempted"] >= 1, \
         "the tight pool must have forced at least one preemption"
     # both were co-scheduled at some point (reserve-ahead could not)
@@ -282,7 +254,7 @@ def test_overload_soak_no_lost_or_hung_streams(params):
     for h, p, mn in handles:
         try:
             out = h.result(180)       # a hang here fails the test
-            assert out == _greedy_oracle(params, p, mn)
+            assert out == greedy_oracle(params, p, mn)
             completed += 1
         except RequestShedError:
             shed += 1
@@ -312,7 +284,7 @@ def test_transient_step_failure_retries_with_zero_blast_radius(
     stats = svc.stats()
     svc.stop()
     for p, got in zip(prompts, outs):
-        assert got == _greedy_oracle(params, p, 6)
+        assert got == greedy_oracle(params, p, 6)
     assert stats["counts"]["step_failures"] == 1
     assert stats["counts"]["quarantined"] == 0
     assert stats["counts"]["failed"] == 0
@@ -336,8 +308,8 @@ def test_poisoned_request_bisect_quarantined_others_survive(
     out2 = hs[2].result(60)
     stats = svc.stats()
     svc.stop()
-    assert out0 == _greedy_oracle(params, prompts[0], 6)
-    assert out2 == _greedy_oracle(params, prompts[2], 6)
+    assert out0 == greedy_oracle(params, prompts[0], 6)
+    assert out2 == greedy_oracle(params, prompts[2], 6)
     assert stats["counts"]["quarantined"] == 1
     assert stats["counts"]["step_failures"] >= 2   # original + retry at least
     assert hs[1].finish_reason == "error"
@@ -408,7 +380,7 @@ def test_preemption_and_failed_step_land_the_step_in_flight_first(
     stats = svc.stats()
     svc.stop()
     for p, got, cb in zip(prompts, outs, seen):
-        assert got == cb == _greedy_oracle(params, p, 12)
+        assert got == cb == greedy_oracle(params, p, 12)
     c = stats["counts"]
     assert c["preempted"] >= 1 and c["step_failures"] == 1
     assert c["failed"] == 0 and c["tokens"] == 24
@@ -458,7 +430,7 @@ def test_failed_read_of_the_step_in_flight_costs_no_token(
     svc.stop()
     assert len(raised) == times
     for p, got, cb in zip(prompts, outs, seen):
-        assert got == cb == _greedy_oracle(params, p, 10)
+        assert got == cb == greedy_oracle(params, p, 10)
     c = stats["counts"]
     assert c["step_failures"] == times and c["failed"] == 0
     assert c["quarantined"] == 0 and c["tokens"] == 20

@@ -22,11 +22,9 @@ from mxnet_tpu.serving import (DeadlineExceededError, QueueFullError,
 from mxnet_tpu.serving.generation import (BlockAllocator, GenerationConfig,
                                           GenerationService, PagedKVCache,
                                           blocks_for)
+from oracle import CFG, greedy_oracle, params  # noqa: F401 (fixture)
 
 pytestmark = pytest.mark.generation
-
-CFG = tr.TransformerConfig(vocab=40, d_model=32, n_heads=4, n_layers=2,
-                           d_ff=64, max_len=64)
 
 
 @pytest.fixture(autouse=True)
@@ -37,11 +35,6 @@ def _fresh_observability():
     obs.recompile.reset()
 
 
-@pytest.fixture(scope="module")
-def params():
-    return tr.transformer_lm_init(CFG, jax.random.PRNGKey(0))
-
-
 def _gc(**kw):
     kw.setdefault("max_slots", 2)
     kw.setdefault("block_size", 8)
@@ -49,17 +42,6 @@ def _gc(**kw):
     kw.setdefault("seq_buckets", [16, 32])
     kw.setdefault("max_new_tokens", 8)
     return GenerationConfig(**kw)
-
-
-def _greedy_oracle(params, prompt, n_new):
-    """Full-sequence greedy decoding via transformer_lm_apply — no cache."""
-    toks = [int(t) for t in prompt]
-    for _ in range(n_new):
-        logits = tr.transformer_lm_apply(
-            params, jnp.asarray([toks], dtype=jnp.int32),
-            jnp.arange(len(toks), dtype=jnp.int32), CFG)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
 
 
 # -- satellite: seq-len ladder ------------------------------------------------------
@@ -426,16 +408,24 @@ def test_decode_with_cache_matches_full_apply(params, compute_dtype):
     vp = jnp.zeros_like(kp)
     table = np.array([[1, 2, 3]], np.int32)
     tb = 16
+
+    def apply_padded(seq):
+        # op by op like the decode calls (a jitted pass rounds bf16
+        # elsewhere), at ONE length: a new length compiles every op anew
+        out = tr.transformer_lm_apply(
+            oracle_params,
+            pad_tokens_right(np.asarray(seq, np.int32), CFG.max_len)[None, :],
+            jnp.arange(CFG.max_len, dtype=jnp.int32), CFG)
+        return np.asarray(out[0, :len(seq)].astype(jnp.float32))
+
     logits, kp, vp = tr.transformer_lm_decode(
         params, pad_tokens_right(prompt.astype(np.int32), tb)[None, :],
         np.arange(tb, dtype=np.int32)[None, :],
         np.asarray([plen], np.int32), kp, vp, table[:, :2], CFG,
         compute_dtype=dt)
-    full = tr.transformer_lm_apply(
-        oracle_params, jnp.asarray([prompt], dtype=jnp.int32),
-        jnp.arange(plen, dtype=jnp.int32), CFG).astype(jnp.float32)
-    np.testing.assert_allclose(np.asarray(logits[0, :plen]),
-                               np.asarray(full[0]), rtol=1e-5, atol=1e-5)
+    full = apply_padded(prompt)
+    np.testing.assert_allclose(np.asarray(logits[0, :plen]), full,
+                               rtol=1e-5, atol=1e-5)
     toks = list(prompt)
     last = logits[0, plen - 1]
     for step in range(n_steps):
@@ -447,11 +437,7 @@ def test_decode_with_cache_matches_full_apply(params, compute_dtype):
             np.asarray([[pos]], np.int32), np.asarray([1], np.int32),
             kp, vp, table, CFG, compute_dtype=dt)
         last = logits[0, 0]
-        full = tr.transformer_lm_apply(
-            oracle_params, jnp.asarray([toks], dtype=jnp.int32),
-            jnp.arange(len(toks), dtype=jnp.int32), CFG
-        ).astype(jnp.float32)
-        np.testing.assert_allclose(np.asarray(last), np.asarray(full[0, -1]),
+        np.testing.assert_allclose(np.asarray(last), apply_padded(toks)[-1],
                                    rtol=1e-5, atol=1e-5)
     assert len(toks) > 16, "test must cross a block boundary"
 
@@ -516,7 +502,7 @@ def test_continuous_batching_membership_and_greedy_parity(params):
     svc.stop()
 
     for got, p, n in zip(results, prompts, new):
-        assert got == _greedy_oracle(params, p, n)
+        assert got == greedy_oracle(params, p, n)
 
     member = [set(m) for _, m in svc.membership_history()]
     # requests 0 and 1 share the batch; 2 joins only after 1 leaves
@@ -742,7 +728,7 @@ def test_amp_bf16_service_matches_bf16_oracle(params):
     svc.stop()
     cast = jax.tree_util.tree_map(
         lambda p: p.astype(jnp.bfloat16), params)
-    assert got == _greedy_oracle(cast, prompt, 5)
+    assert got == greedy_oracle(cast, prompt, 5)
 
 
 def test_observability_wiring(params):
@@ -782,578 +768,3 @@ def test_service_stats_and_compile_sites(params):
     assert "gen_prefill" in by_site and "gen_decode" in by_site
     assert by_site["gen_prefill"]["hits"] >= 1     # the real prefill
     assert by_site["gen_decode"]["hits"] >= 1
-
-
-# -- satellite: chunked prefill (docs/generation.md, PR 8) --------------------------
-def test_chunk_plan_shapes(params):
-    """Long prompts split into rung-sized chunks; short prompts and
-    chunking-off stay on the legacy single-rung plan."""
-    svc = GenerationService(params, CFG, _gc(chunked_prefill=True),
-                            start=False)
-    assert svc._chunk_plan(9) == [(0, 9, 16, blocks_for(16, 8))]
-    plan = svc._chunk_plan(30)
-    assert [c[:2] for c in plan] == [(0, 16), (16, 14)]
-    assert all(take <= tb for (_, take, tb, _) in plan)
-    # chunk widths cover every written position
-    for (off, take, tb, w) in plan:
-        assert w * 8 >= off + take
-    off_svc = GenerationService(params, CFG, _gc(chunked_prefill=False),
-                                start=False)
-    assert off_svc._chunk_plan(30) == [(0, 30, 32, blocks_for(32, 8))]
-    svc.stop()
-    off_svc.stop()
-
-
-def test_chunked_prefill_matches_unchunked_and_oracle(params):
-    """Greedy generations are identical with chunking on and off, and both
-    match the no-cache full-sequence oracle."""
-    rs = np.random.RandomState(3)
-    prompts = [rs.randint(0, CFG.vocab, n) for n in (3, 17, 25, 30, 16)]
-
-    def run(chunked):
-        svc = GenerationService(params, CFG,
-                                _gc(chunked_prefill=chunked), start=False)
-        svc.warmup()
-        svc.start()
-        outs = [svc.generate(p, max_new_tokens=6, temperature=0.0)
-                for p in prompts]
-        svc.stop()
-        return outs
-
-    on, off = run(True), run(False)
-    assert on == off
-    for p, toks in zip(prompts, on):
-        assert toks == _greedy_oracle(params, p, 6)
-
-
-def test_chunked_prefill_sampled_tokens_identical(params):
-    """The final chunk samples with the same seed/counter as the unchunked
-    program — temperature>0 tokens are bit-identical too."""
-    rs = np.random.RandomState(5)
-    prompt = rs.randint(0, CFG.vocab, 29)
-
-    def run(chunked):
-        svc = GenerationService(params, CFG,
-                                _gc(chunked_prefill=chunked), start=False)
-        svc.start()
-        out = svc.generate(prompt, max_new_tokens=8, temperature=0.9,
-                           top_k=10, seed=123)
-        svc.stop()
-        return out
-
-    assert run(True) == run(False)
-
-
-def test_chunked_prefill_zero_postwarmup_compiles(params, monkeypatch):
-    """Warmup enumerates every (T, W) pair the chunk planner can emit:
-    long prompts then run under TPUMX_FREEZE_COMPILES=1 with 1 miss per
-    signature."""
-    svc = GenerationService(params, CFG, _gc(chunked_prefill=True),
-                            start=False)
-    warmed = svc.warmup()
-    assert warmed == len(svc.compile_stats())
-    monkeypatch.setenv("TPUMX_FREEZE_COMPILES", "1")
-    rs = np.random.RandomState(11)
-    svc.start()
-    handles = [svc.submit(rs.randint(0, CFG.vocab, n), max_new_tokens=4)
-               for n in (31, 17, 24, 30, 5)]
-    for h in handles:
-        assert len(h.result(60)) == 4
-    stats = svc.compile_stats()
-    svc.stop()
-    monkeypatch.delenv("TPUMX_FREEZE_COMPILES")
-    assert all(v["misses"] == 1 for v in stats.values())
-
-
-def test_generation_mp_axis_matches_single_device(params):
-    """GenerationConfig(mp_devices=2): params live sharded over the mp
-    mesh (docs/sharding.md) and greedy decoding matches mp=1."""
-    rs = np.random.RandomState(7)
-    prompts = [rs.randint(0, CFG.vocab, n) for n in (4, 19, 30)]
-
-    def run(mp):
-        svc = GenerationService(params, CFG, _gc(mp_devices=mp),
-                                start=False)
-        if mp > 1:
-            emb = svc._programs._params["tok_emb"]
-            assert len(emb.sharding.device_set) == mp
-        svc.start()
-        outs = [svc.generate(p, max_new_tokens=5, temperature=0.0)
-                for p in prompts]
-        svc.stop()
-        return outs
-
-    assert run(2) == run(1)
-
-
-def test_gpt2_programs_through_the_model_seam_are_the_parents():
-    """GPT-2's block is the first model behind the engine's seam
-    (``programs.as_model``): at the benchmark cell's ladder and pool
-    geometry it warms the 15 programs the parent compiled — the same
-    kinds and signatures, no block-diffusion program among them."""
-    from mxnet_tpu.parallel.transformer import TransformerLM
-    from mxnet_tpu.serving.generation.programs import as_model
-
-    cfg = tr.TransformerConfig(vocab=61, d_model=16, n_heads=2,
-                               n_layers=1, d_ff=32, max_len=1024)
-    params = tr.transformer_lm_init(cfg, jax.random.PRNGKey(0))
-    model = as_model(cfg)
-    assert isinstance(model, TransformerLM) and as_model(model) is model
-    assert (model.vocab, model.max_len, model.heads, model.block_len) \
-        == (61, 1024, 2, 0)
-    assert model.cache_spec() == dict(n_layers=1, n_heads=2, d_head=8,
-                                      dtype=jnp.float32)
-    with pytest.raises(TypeError):
-        as_model(object())
-    svc = GenerationService(params, cfg, GenerationConfig(
-        max_slots=32, block_size=16, num_blocks=64,
-        seq_buckets=(128, 512, 1023)), start=False)
-    assert svc.warmup() == 15
-    pool = ("kv_pool", (1, 64, 16, 16), "float32")
-    want = {("gen_prefill", (("tokens", (1, t), "int32"),
-                             ("block_tables", (1, w), "int32"), pool))
-            for t, w in ((128, 8), (128, 16), (128, 32), (128, 64),
-                         (512, 32), (512, 64), (1023, 64))}
-    want |= {("gen_decode", (("tokens", (32, 1), "int32"),
-                             ("block_tables", (32, w), "int32"), pool))
-             for w in (1, 2, 4, 8, 16, 32, 64)}
-    want |= {("gen_block_copy", (pool,))}
-    assert set(svc.compile_stats()) == want
-    assert svc.stats()["decode_mode"] == "single"
-    assert svc.stats()["block_diffusion"] is None
-
-
-# -- one serving step: the batch builder and the program kinds ----------------------
-_X12, _Z30, _M = 13, 31, 39     # the pending tokens of rows X and Z; a MASK id
-
-# case -> (T, what a row feeds, positions written, sampler arrays asked for,
-#          tokens and lengths of rows X (slot 0) and Z (slot 3), table width)
-_BUILDER_CASES = {
-    "single": (1, lambda r: [r.seq_tokens[r.ctx_len]], None, True,
-               [_X12], 1, [_Z30], 1, 4),
-    # drafts of unequal length: X proposes two, Z none; Tk buckets to 4
-    "verify": (4, lambda r: [r.seq_tokens[r.ctx_len]] + {0: [7, 8]}.get(
-        r.rid, []), None, True, [_X12, 7, 8, 0], 3, [_Z30, 0, 0, 0], 1, 4),
-    # k = 4 writes 30 .. 33: a fifth block, so the width buckets to 8
-    "multistep": (1, lambda r: [r.seq_tokens[r.ctx_len]], 4, True,
-                  [_X12], 1, [_Z30], 1, 8),
-    "block": (4, lambda r: r.block, None, False,
-              [_X12, _M, _M, _M], 4, [_Z30, _M, _M, _M], 4, 8),
-}
-
-
-@pytest.mark.parametrize("case", sorted(_BUILDER_CASES))
-def test_step_builder_against_arrays_written_by_hand(params, case):
-    """The one batch builder of the four step kinds, on four slots — X, an
-    empty one, Y outside the batch, Z — against every operand written out
-    by hand: the rows, tokens / positions / lengths, the sampler arrays
-    (none for the block step), the widest table bucketed on the pow2
-    ladder, and the copy-on-write of X's shared tail block BEFORE it is
-    written."""
-    from mxnet_tpu.serving.generation.engine import _RUNNING, _GenRequest
-
-    T, feed, writes, sampler, x_tok, x_len, z_tok, z_len, w = \
-        _BUILDER_CASES[case]
-    svc = GenerationService(params, CFG, _gc(max_slots=4), start=False)
-    alloc = svc._cache.allocator
-
-    def request(rid, ctx, n_blocks, **kw):
-        r = _GenRequest(rid, list(range(1, ctx + 2)), 32, 16,
-                        kw.get("temperature", 0.0), kw.get("top_k", 0),
-                        kw.get("top_p", 1.0), kw.get("seed", 0), None, None,
-                        None)
-        r.state, r.ctx_len, r.blocks = _RUNNING, ctx, alloc.allocate(n_blocks)
-        r.block = [r.seq_tokens[ctx]] + [_M] * 3
-        return r
-
-    x = request(0, 12, 3, seed=5, temperature=0.7, top_k=3, top_p=0.9)
-    y = request(1, 20, 3, seed=7)
-    z = request(2, 30, 5, seed=9)
-    assert (x.blocks, y.blocks, z.blocks) == (
-        [1, 2, 3], [4, 5, 6], [7, 8, 9, 10, 11])
-    svc._slots[:] = [x, None, y, z]
-    # X's tail block (positions 8 .. 15) is shared and holds history
-    alloc.incref([2])
-    k, v = svc._cache.pools
-    svc._cache.swap((k.at[:, 2].set(1.5), v.at[:, 2].set(-2.5)))
-
-    b = svc._build_step([x, z], T, feed, writes=writes, sampler=sampler)
-
-    assert b.rows == [(0, x), (3, z)] and b.width == w
-    assert x.blocks == [1, 12, 3] and x.cow_copies == 1
-    assert alloc.refcount(2) == 1 and alloc.refcount(12) == 1
-    np.testing.assert_array_equal(np.asarray(svc._cache.k)[:, 12], 1.5)
-    np.testing.assert_array_equal(np.asarray(svc._cache.v)[:, 12], -2.5)
-    zero = [0] * T
-    np.testing.assert_array_equal(b.tokens, [x_tok, zero, zero, z_tok])
-    np.testing.assert_array_equal(
-        b.positions, [list(range(12, 12 + T)), zero, zero,
-                      list(range(30, 30 + T))])
-    np.testing.assert_array_equal(b.lengths, [x_len, 0, 0, z_len])
-    pad = [0] * (w - 4)
-    np.testing.assert_array_equal(
-        b.tables, [([1, 12, 3, 0] + pad), [0] * w, [0] * w,
-                   ([7, 8, 9, 10] + [11, 0, 0, 0][:w - 4])])
-    for a in (b.tokens, b.positions, b.lengths, b.tables):
-        assert a.dtype == np.int32
-    if not sampler:
-        assert b.sampler == () and len(b.operands) == 4
-        return
-    seeds, counters, temperature, top_k, top_p = b.sampler
-    assert b.operands[4:] == b.sampler
-    np.testing.assert_array_equal(seeds, np.asarray([5, 0, 0, 9], np.uint32))
-    np.testing.assert_array_equal(counters,
-                                  np.asarray([13, 0, 0, 31], np.uint32))
-    np.testing.assert_array_equal(temperature,
-                                  np.asarray([0.7, 0, 0, 0], np.float32))
-    np.testing.assert_array_equal(top_k, np.asarray([3, 0, 0, 0], np.int32))
-    np.testing.assert_array_equal(top_p,
-                                  np.asarray([0.9, 1, 1, 1], np.float32))
-    assert [a.dtype for a in b.sampler] == [
-        np.uint32, np.uint32, np.float32, np.int32, np.float32]
-
-
-@pytest.mark.parametrize("kv_dtype", [None, "int8"])
-def test_a_program_kind_is_one_function_and_notes_the_parents_keys(
-        params, monkeypatch, kv_dtype):
-    """Every program kind is traced from ONE function whatever the pool
-    (the cache's arrays travel as one operand), and a fixed script of calls
-    feeds ``executor._note_cache`` the (hit, site, key) sequence the twin
-    functions did (the tuples are the parent commit's output)."""
-    from mxnet_tpu import executor
-    from mxnet_tpu.serving.generation import programs as gp
-
-    monkeypatch.setenv("TPUMX_PALLAS", "0")
-    progs = gp.GenerationPrograms(params, CFG, kv_dtype=kv_dtype)
-    assert {kind: fn for kind, (fn, _) in progs._kinds.items()} == {
-        "gen_prefill": gp._model_step, "gen_decode": gp._model_step,
-        "gen_verify": gp._verify_step, "gen_multistep": gp._multistep,
-        "gen_block": gp._block_step, "gen_block_copy": gp.block_copy_pools}
-    assert not any(hasattr(gp, name) for name in (
-        "_model_step_q", "_verify_step_q", "_multistep_q"))
-    cache = PagedKVCache(num_blocks=16, block_size=8, kv_dtype=kv_dtype,
-                         n_layers=CFG.n_layers, n_heads=CFG.n_heads,
-                         d_head=CFG.d_head, dtype=jnp.float32)
-    assert len(cache.pools) == (4 if kv_dtype else 2)
-    assert cache.pools[:2] == (cache.k, cache.v)
-    seen = []
-    note = executor._note_cache
-    monkeypatch.setattr(
-        executor, "_note_cache",
-        lambda hit, site, key: (seen.append((hit, site, key)),
-                                note(hit=hit, site=site, key=key))[1])
-    S = 3
-    z = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
-    knobs = lambda n: (z(n), z(n), z(n).astype(np.float32), z(n),  # noqa: E731
-                       np.ones(n, np.float32))
-    progs.run("gen_prefill", cache, z(1, 16), z(1, 16), z(1), z(1, 2),
-              *knobs(1))
-    for _ in range(2):
-        progs.run("gen_decode", cache, z(S, 1), z(S, 1), z(S), z(S, 4),
-                  *knobs(S))
-    progs.run_verify(cache, z(S, 4), z(S, 4), z(S), z(S, 4), *knobs(S))
-    progs.run_multistep(4, cache, z(S), z(S), z(S), z(S, 4), *knobs(S))
-    for _ in range(2):
-        progs.copy_block(cache, 0, 0)
-    q = "_int8" if kv_dtype else ""
-    pool = (("kv_pool", (2, 16, 8, 32), "int8" if kv_dtype else "float32"),)
-    fam = pool + ((("kv_dtype", "int8"),) if kv_dtype else ())
-    sig = lambda t, w: (("tokens", t, "int32"),  # noqa: E731
-                        ("block_tables", w, "int32"))
-    decode = ("gen_decode", sig((3, 1), (3, 4)) + fam)
-    copy = ("gen_block_copy", fam)
-    assert seen == [
-        (False, ("gen_prefill" + q, ("lm",)),
-         ("gen_prefill", sig((1, 16), (1, 2)) + fam)),
-        (False, ("gen_decode" + q, ("lm",)), decode),
-        (True, ("gen_decode" + q, ("lm",)), decode),
-        (False, ("gen_verify" + q, ("lm",)),
-         ("gen_verify", sig((3, 4), (3, 4)) + fam)),
-        (False, ("gen_multistep" + q, ("lm",)),
-         ("gen_multistep", sig((3,), (3, 4)) + fam + (("k", 4),))),
-        (False, ("gen_block_copy" + q, ("lm",)), copy),
-        (True, ("gen_block_copy" + q, ("lm",)), copy)]
-    assert progs.compiled_signatures() == 5
-
-
-# -- the step in flight (docs/generation.md) ----------------------------------------
-_XLA_COMPILES = []
-
-
-def _xla_compiles():
-    """Backend compiles of this process since the first call, by jax's own
-    monitoring event (what the benchmark's ``compiles_after_warmup``
-    counts); the listener is registered once."""
-    if not _XLA_COMPILES:
-        import jax.monitoring as mon
-
-        _XLA_COMPILES.append(0)
-
-        def on_duration(event, secs, **_):
-            if event.endswith("backend_compile_duration"):
-                _XLA_COMPILES[0] += 1
-
-        mon.register_event_duration_secs_listener(on_duration)
-    return _XLA_COMPILES[0]
-
-
-def _mixed_prompts():
-    rs = np.random.RandomState(11)
-    shared = rs.randint(0, CFG.vocab, 16)
-    return {"greedy": rs.randint(0, CFG.vocab, 12),
-            "sampled": rs.randint(0, CFG.vocab, 7),
-            "eos": np.arange(3, 12) % CFG.vocab,
-            "cancel": rs.randint(0, CFG.vocab, 9),
-            "chunked": rs.randint(0, CFG.vocab, 30),
-            "shared_a": shared, "shared_b": shared,
-            "shared_long": np.concatenate([shared, [5, 6, 7]])}
-
-
-def _mixed_workload(params, ahead, eos, watch=None):
-    """Greedy and seeded-sampling rows on three slots; requests that end
-    by ``max_new_tokens``, by an end-of-sequence id and by a cancel from
-    their own callback; a chunked prompt and two prefix-cache hits (one a
-    whole cached prompt: copy-on-write) admitted mid-run from a callback.
-    Returns ``(streams, callback streams, finish reasons, stats)`` by
-    request name; ``watch(svc)`` runs on the warmed service before it
-    starts."""
-    svc = GenerationService(params, CFG,
-                            _gc(max_slots=3, num_blocks=48), start=False)
-    svc.warmup()
-    if not ahead:
-        svc._runs_ahead = False          # every step is read at once
-    if watch is not None:
-        watch(svc)
-    prompts = _mixed_prompts()
-    handles, seen = {}, {name: [] for name in prompts}
-
-    def submit(name, n, **kw):
-        def on_token(rid, tok):
-            seen[name].append(tok)
-            if name == "cancel" and len(seen[name]) == 4:
-                handles[name].cancel()
-            if name == "greedy" and len(seen[name]) == 3:
-                submit("chunked", 9)
-                submit("shared_b", 6)
-            if name == "greedy" and len(seen[name]) == 9:
-                submit("shared_long", 5, temperature=0.7, seed=21)
-        handles[name] = svc.submit(prompts[name], max_new_tokens=n,
-                                   on_token=on_token, **kw)
-
-    submit("greedy", 16)
-    submit("sampled", 12, temperature=0.8, top_k=10, seed=7)
-    submit("shared_a", 8)
-    submit("eos", 12, temperature=1.0, seed=3, eos_token=eos)
-    submit("cancel", 14)
-    svc.start()
-    try:
-        deadline = time.perf_counter() + 120
-        while len(handles) < len(prompts) and time.perf_counter() < deadline:
-            time.sleep(0.01)
-        out = {name: h.result(120) for name, h in handles.items()}
-        reasons = {name: h.finish_reason for name, h in handles.items()}
-        time.sleep(0.12)          # the loop retires the last slot and idles
-        return out, seen, reasons, svc.stats()
-    finally:
-        svc.stop()
-
-
-def _eos_of_the_mixed_workload(params):
-    """The "eos" row's sampled stream without an end-of-sequence id, and
-    the index of an id it first samples at a decode step, not at its
-    prefill."""
-    svc = GenerationService(params, CFG, _gc(max_slots=1))
-    try:
-        alone = svc.generate(_mixed_prompts()["eos"], max_new_tokens=12,
-                             temperature=1.0, seed=3, timeout=120)
-    finally:
-        svc.stop()
-    return alone, next(j for j in range(2, 12) if alone[j] not in alone[:j])
-
-
-def test_step_in_flight_serves_the_drained_streams(params):
-    """(a) token for token, whatever ends a request and whenever one joins:
-    the engine that reads step n after it dispatched step n + 1 serves
-    what the engine that reads every step at once serves."""
-    alone, j = _eos_of_the_mixed_workload(params)
-    got, got_cb, got_why, stats = _mixed_workload(params, True, alone[j])
-    ref, ref_cb, ref_why, ref_stats = _mixed_workload(params, False, alone[j])
-    assert got == ref and got_cb == ref_cb and got_why == ref_why
-    assert got_cb == got                      # every token delivered, once
-    assert got["eos"] == alone[:j + 1] and got_why["eos"] == "eos"
-    assert len(got["cancel"]) == 4 and got_why["cancel"] == "cancelled"
-    prompts = _mixed_prompts()
-    for name, n in (("greedy", 16), ("chunked", 9), ("shared_b", 6)):
-        assert got[name] == _greedy_oracle(params, prompts[name], n), name
-    for s in (stats, ref_stats):
-        assert s["counts"]["prefix_hits"] >= 2
-        assert s["counts"]["cow_copies"] >= 1
-        assert s["counts"]["failed"] == 0
-    c, rc = stats["counts"], ref_stats["counts"]
-    assert c["steps_ahead"] > c["steps_drained"] >= 1
-    assert rc["steps_ahead"] == 0 and rc["steps_drained"] > 0
-    assert c["tokens"] == rc["tokens"] == sum(len(t) for t in got.values())
-
-
-def test_next_step_is_dispatched_before_the_last_one_is_read(
-        params, monkeypatch):
-    """(b) with rows running and nothing to drain, step n + 1's dispatch
-    comes before step n's read; ``steps_ahead`` and ``steps_drained``
-    count every dispatched decode step between them."""
-    from mxnet_tpu.serving.generation import engine as engine_mod
-
-    log, steps = [], {}
-    synced = engine_mod._synced
-
-    def reading(*outs):
-        if id(outs[0]) in steps:
-            log.append(("read", steps[id(outs[0])]))
-        return synced(*outs)
-
-    monkeypatch.setattr(engine_mod, "_synced", reading)
-    svc = GenerationService(params, CFG, _gc(max_slots=3), start=False)
-    svc.warmup()
-    run = svc._programs.run
-    kept = []                            # ids stay unique while these live
-
-    def dispatching(kind, *args, **kw):
-        out = run(kind, *args, **kw)
-        if kind == "gen_decode":
-            kept.append(out[0])
-            steps[id(out[0])] = len(steps)
-            log.append(("dispatch", steps[id(out[0])]))
-        return out
-
-    monkeypatch.setattr(svc._programs, "run", dispatching)
-    rs = np.random.RandomState(2)
-    hs = [svc.submit(rs.randint(0, CFG.vocab, n), max_new_tokens=12)
-          for n in (5, 9, 14)]
-    svc.start()
-    try:
-        outs = [h.result(120) for h in hs]
-        time.sleep(0.12)
-        stats = svc.stats()
-    finally:
-        svc.stop()
-    assert all(len(o) == 12 for o in outs)
-    n = len(steps)
-    # all three are admitted in the first pass and end together: 11 decode
-    # steps, each but the first dispatched with the one before it unread
-    assert n == 11 and [e for e in log if e[0] == "read"] == [
-        ("read", i) for i in range(n)]
-    for i in range(n - 1):
-        assert log.index(("dispatch", i + 1)) < log.index(("read", i)), i
-    c = stats["counts"]
-    assert (c["steps_ahead"], c["steps_drained"]) == (n - 1, 1)
-    # the last pass dispatches nothing: it reads step n - 1
-    assert stats["iterations"] == n + 1
-
-
-@pytest.mark.parametrize("news", [(6, 6), (3, 9)])
-def test_lead_counts_positions_on_the_one_token_path(params, news):
-    """``_lead`` speaks of positions since a block pass rides the step in
-    flight too: on the one-token path it reads 1 for a row of the step in
-    flight and 0 for any other, a row whose token in flight is its last
-    is not fed again, and the tokens and the ``steps_ahead`` share are
-    what they were."""
-    svc = GenerationService(params, CFG, _gc(max_slots=2), start=False)
-    svc.warmup()
-    rs = np.random.RandomState(11)
-    prompts = [rs.randint(0, CFG.vocab, n) for n in (5, 9)]
-    hs = [svc.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
-    reqs = [h._req for h in hs]
-    for _ in range(40):
-        if all(h.finished for h in hs):
-            break
-        svc._iterate()
-        f = svc._flight
-        for r in reqs:
-            flies = f is not None and r.rid in f.lead
-            assert svc._flies(r) == flies and svc._lead(r) == int(flies)
-            assert svc._ends_in_flight(r) == (
-                flies and r.n_generated + 1 >= r.max_new)
-    svc._iterate()                       # retires the last slot
-    c = svc.stats()["counts"]
-    fed = [set(rids) for _, rids in svc.membership_history()]
-    svc.stop(drain=False, timeout=30)
-    for h, p, n in zip(hs, prompts, news):
-        assert h.result(1) == _greedy_oracle(params, p, n)
-    # a request's first token is its prefill's: n - 1 decode steps feed it
-    for r, n in zip(reqs, news):
-        assert sum(r.rid in rids for rids in fed) == n - 1
-    steps = max(news) - 1
-    assert (c["steps_ahead"], c["steps_drained"]) == (steps - 1, 1)
-    assert svc._flight is None and c["failed"] == 0
-
-
-def test_end_of_sequence_row_takes_nothing_after_it(params):
-    """(c) a row that ends on an end-of-sequence id is found a step late:
-    the token of its extra step is dropped, and the prefix index is shown
-    its context without the position that step wrote."""
-    alone, j = _eos_of_the_mixed_workload(params)
-    prompt = _mixed_prompts()["eos"]
-    svc = GenerationService(params, CFG, _gc(max_slots=2), start=False)
-    svc.warmup()
-    shown = []
-    insert = svc._prefix.insert
-    svc._prefix.insert = lambda toks, blocks: (
-        shown.append(list(toks)), insert(toks, blocks))[1]
-    fed = []                             # rows of every decode dispatch
-    run = svc._programs.run
-
-    def dispatching(kind, cache, tokens, positions, lengths, *rest):
-        if kind == "gen_decode":
-            fed.append([int(p) for p, n in zip(positions[:, 0], lengths)
-                        if n])
-        return run(kind, cache, tokens, positions, lengths, *rest)
-
-    svc._programs.run = dispatching
-    seen = []
-    other = svc.submit(np.arange(20) % CFG.vocab, max_new_tokens=16)
-    h = svc.submit(prompt, max_new_tokens=12, temperature=1.0, seed=3,
-                   eos_token=alone[j], on_token=lambda rid, t: seen.append(t))
-    svc.start()
-    try:
-        out = h.result(120)
-        other.result(120)
-        time.sleep(0.12)
-    finally:
-        svc.stop()
-    assert out == seen == alone[:j + 1] and h.finish_reason == "eos"
-    req = h._req
-    assert req.n_generated == j + 1 and req.decode_steps == j
-    assert req.ctx_len == len(prompt) + j
-    # the extra step did run: the row was fed at the position after its
-    # last token's, which nothing was emitted for
-    assert any(len(prompt) + j in row for row in fed)
-    mine = [t for t in shown if t[:len(prompt)] == list(prompt)]
-    assert mine and max(len(t) for t in mine) == len(prompt) + j
-    assert mine[-1] == list(prompt) + out[:-1]
-
-
-def test_warmup_compiles_the_parents_programs_and_nothing_after(
-        params, monkeypatch):
-    """(e) the step in flight adds no model program — ten for this
-    configuration, as on the parent commit: five prefill signatures, four
-    table widths of the decode step, the block copy — and a warmed service
-    compiles nothing under the mixed workload, by this repo's count and by
-    XLA's own."""
-    from mxnet_tpu.executor import compile_cache_stats
-
-    alone, j = _eos_of_the_mixed_workload(params)
-    monkeypatch.setenv("TPUMX_FREEZE_COMPILES", "1")
-    _xla_compiles()
-    marks = {}
-
-    def watch(svc):
-        kinds = sorted(k[0] for k in svc.compile_stats())
-        assert len(kinds) == 10 and kinds.count("gen_decode") == 4 \
-            and kinds.count("gen_prefill") == 5
-        marks["warm"] = (compile_cache_stats()["misses"], _xla_compiles())
-
-    got, _, _, stats = _mixed_workload(params, True, alone[j], watch=watch)
-    assert (compile_cache_stats()["misses"], _xla_compiles()) \
-        == marks["warm"]
-    assert stats["counts"]["steps_ahead"] > 0 \
-        and stats["counts"]["failed"] == 0
-    assert stats["compiled_signatures"] == 10

@@ -202,20 +202,20 @@ def test_model_zoo_smoke():
         assert out.shape == (1, 10), name
 
 
-def test_model_zoo_all_families():
+@pytest.mark.parametrize("name,sz", [
+    ("alexnet", 224), ("vgg11", 224), ("densenet121", 224),
+    ("mobilenet_v2_0_25", 96), ("inception_v3", 299)])
+def test_model_zoo_all_families(name, sz):
     # one representative per remaining family (reference:
     # python/mxnet/gluon/model_zoo/vision/ — alexnet/vgg/densenet/
     # mobilenet_v2/inception); string weight_initializer + HybridLambda
     # (relu6) + positional-scalar op attrs exercised here
     # sizes each architecture actually supports: densenet's head is a
     # fixed AvgPool2D(7) (reference), so inputs must reach a 7x7 final map
-    cases = {"alexnet": 224, "vgg11": 224, "densenet121": 224,
-             "mobilenet_v2_0_25": 96, "inception_v3": 299}
-    for name, sz in cases.items():
-        net = gluon.model_zoo.vision.get_model(name, classes=10)
-        net.initialize()
-        out = net(nd.array(np.random.rand(1, 3, sz, sz)))
-        assert out.shape == (1, 10), name
+    net = gluon.model_zoo.vision.get_model(name, classes=10)
+    net.initialize()
+    out = net(nd.array(np.random.rand(1, 3, sz, sz)))
+    assert out.shape == (1, 10)
 
 
 def test_dataloader():

@@ -23,14 +23,14 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from mxnet_tpu.ops import paged_attention as pa
 from mxnet_tpu.parallel import hybrid_moe as hm
 from mxnet_tpu.parallel.latent_moe import route_sigmoid_groups
 from mxnet_tpu.parallel.sdar_moe import expert_products
 from mxnet_tpu.serving.bucketing import pad_tokens_right
 from mxnet_tpu.serving.generation import GenerationConfig, GenerationService
 from mxnet_tpu.serving.generation.kv_cache import (PagedKVCache, blocks_for,
-                                                   ring_width, window_blocks)
+                                                   window_blocks)
+from oracle import greedy
 from perfbench.reference import mimo_v2 as ref
 
 C = dict(hybrid_layer_pattern=[0, 1, 1, 1, 1, 0, 1],
@@ -100,14 +100,6 @@ def svc7(params):
     svc.stop(drain=False, timeout=30)
 
 
-@pytest.fixture(scope="module")
-def paged3(params3):
-    """The 3-layer cut through the tiles body (interpreted)."""
-    svc = _service(params3, "paged", model=_model(_config(**CUT)))
-    yield svc
-    svc.stop(drain=False, timeout=30)
-
-
 def _ref_logits(params, tokens, at0, n_at=1, c=C, **kw):
     toks = np.zeros(MAX_LEN, np.int32)
     toks[:len(tokens)] = tokens
@@ -116,10 +108,8 @@ def _ref_logits(params, tokens, at0, n_at=1, c=C, **kw):
 
 
 def _ref_greedy(params, prompt, n):
-    seq = [int(t) for t in prompt]
-    for _ in range(n):
-        seq.append(int(_ref_logits(params, seq, len(seq) - 1)[0].argmax()))
-    return seq[len(prompt):]
+    return greedy(lambda seq: _ref_logits(params, seq, len(seq) - 1)[0],
+                  prompt, n)
 
 
 def _sampler(n, counter):
@@ -181,28 +171,36 @@ def _logits_through_the_cache(svc, seq, n_decode=4):
     return out
 
 
-@pytest.mark.parametrize("kernel,plen", [
-    ("gather", 3), ("gather", 16), ("gather", 37), ("gather", 70),
-    ("paged", 16), ("paged", 70)])
-def test_chunked_prefill_then_decode_match_reference_logits(
-        request, params, params3, kernel, plen):
+def _check_logits_through_the_cache(svc, p, c, kernel, plen, part):
     """Prefill through the chunk plan (every leftover length; past 8 + 16
     positions window blocks have been freed and reused), then greedy decode
     steps through both cache kinds, against the reference's full forward
-    over the whole sequence.  ``gather``: the sums over the gathered pages
-    and the gathered ring, at the 7 layers of the published pattern;
-    ``paged``: the tiles body (interpreted), at the 3-layer cut."""
-    svc = request.getfixturevalue("svc7" if kernel == "gather" else "paged3")
-    p, c = (params, C) if kernel == "gather" else (params3, C3)
+    over the whole sequence; ``part`` says which of the two is compared (a
+    program of the interpreted kernel is most of a minute to compile, and
+    the two parts need a program each)."""
     assert svc.stats()["decode_kernel"] == kernel
     freed = svc.stats()["counts"]["window_blocks_freed"]
     seq = [int(t) for t in np.random.default_rng(plen).integers(0, V, plen)]
-    for toks, last in _logits_through_the_cache(svc, seq):
+    if part == "prefill":
+        compared = _logits_through_the_cache(svc, seq, 0)
+    else:
+        compared = _logits_through_the_cache(svc, seq)[1:]
+    for toks, last in compared:
         np.testing.assert_allclose(
             last, _ref_logits(p, toks, len(toks) - 1, c=c)[0], atol=TOL,
             rtol=0)
     if plen > WIN + 16:
         assert svc.stats()["counts"]["window_blocks_freed"] > freed
+
+
+@pytest.mark.parametrize("part", ["prefill", "decode"])
+@pytest.mark.parametrize("plen", [3, 16, 37, 70])
+def test_chunked_prefill_then_decode_match_reference_logits(params, svc7,
+                                                            plen, part):
+    """The sums over the gathered pages and the gathered ring, at the 7
+    layers of the published pattern (the tiles body:
+    tests/test_hybrid_moe_kernel.py)."""
+    _check_logits_through_the_cache(svc7, params, C, "gather", plen, part)
 
 
 @pytest.mark.parametrize("fault", ["window_one_short", "no_sink",
@@ -241,106 +239,6 @@ def test_the_references_planted_faults_move_its_logits(params):
         base = want if c is C else _ref_logits(params, seq, 30, 7, c=c)
         assert np.abs(_ref_logits(params, seq, 30, 7, c=c, fault=fault)
                       - base).max() > 20 * TOL
-
-
-# -- the tiles body against its oracle ---------------------------------------
-def _ring_case(rng, B, T, hkv, G, dk, dv, window, bs, ring, n_blocks, dtype,
-               ctx):
-    """A paged pool, tables for rows that sit ``ctx[b]`` positions into
-    their sequences and feed ``T`` more, the window kind's as a ring that
-    holds only what those queries can see."""
-    H = hkv * G
-    k_pool = jnp.asarray(rng.normal(0, 1, (2, n_blocks, bs, hkv * dk)), dtype)
-    v_pool = jnp.asarray(rng.normal(0, 1, (2, n_blocks, bs, hkv * dv)), dtype)
-    q = jnp.asarray(rng.normal(0, 1, (B, T, H, dk)), jnp.float32)
-    positions = np.asarray(ctx)[:, None] + np.arange(T)[None, :]
-    max_pos = positions[:, -1].copy()
-    free = list(rng.permutation(np.arange(1, n_blocks)))
-    width = ring if window else blocks_for(int(max_pos.max()) + 1, bs)
-    tables = np.zeros((B, width), np.int32)
-    for b in range(B):
-        first = max(0, positions[b, 0] - (window - 1)) // bs if window else 0
-        for blk in range(first, max_pos[b] // bs + 1):
-            tables[b, blk % width] = free.pop()
-    return q, k_pool, v_pool, tables, positions.astype(np.int32), \
-        max_pos.astype(np.int32)
-
-
-def _oracle(q, k_pool, v_pool, tables, positions, hkv, window, sink, layer):
-    """``paged_attention_reference`` over the gathered pages, a ring's
-    slots at the positions they hold."""
-    B, T = positions.shape
-    bs, width = k_pool.shape[2], tables.shape[1]
-    gather = lambda pool: pool[layer][tables].reshape(  # noqa: E731
-        B, width * bs, hkv, -1).astype(jnp.float32)
-    if window:
-        first = jnp.maximum(positions[:, 0] - (window - 1), 0) // bs
-        at = hm._ring_positions(jnp.asarray(first), width, bs)
-    else:
-        at = jnp.arange(width * bs)[None, :]
-    mask = at[:, None, :] <= positions[:, :, None]
-    if window:
-        mask &= at[:, None, :] > positions[:, :, None] - window
-    return pa.paged_attention_reference(
-        q, gather(k_pool), gather(v_pool), mask, q.shape[-1] ** -0.5, sink)
-
-
-@pytest.mark.parametrize("T,window,sink,dtype", [
-    (1, 0, False, jnp.float32), (1, 16, True, jnp.float32),
-    (1, 16, True, jnp.bfloat16), (24, 0, False, jnp.float32),
-    (24, 16, True, jnp.float32), (24, 16, False, jnp.bfloat16),
-    (80, 16, True, jnp.float32)],
-    ids=["decode-full", "decode-window", "decode-window-bf16", "chunk-full",
-         "chunk-window", "chunk-window-nosink-bf16", "chunk-window-tiles"])
-def test_tiles_body_matches_its_oracle(T, window, sink, dtype):
-    """Grouped heads as rows (4 query heads a KV head), K pages 24 lanes a
-    head beside V pages 16, decode and chunks (80 x 4 rows: two tiles),
-    rows at different depths, one inactive; the window walk over a ring
-    that wrapped, the sink in the denominator."""
-    rng = np.random.default_rng(T + window)
-    hkv, G, dk, dv, bs = 2, 4, 24, 16, 8
-    ring = ring_width(window, T, bs) if window else 0
-    ctx = [0, 5, 37, 70]
-    q, kp, vp, tables, pos, max_pos = _ring_case(
-        rng, 4, T, hkv, G, dk, dv, window, bs, ring, 64, dtype, ctx)
-    max_pos[0] = -1                        # an inactive row
-    s = jnp.asarray(rng.normal(0, 1, hkv * G), jnp.float32) if sink else None
-    got = pa.paged_attention(q, kp, vp, tables, pos, max_pos,
-                             scale=dk ** -0.5, layer=1, window=window, sink=s,
-                             call="window_prefill")
-    want = _oracle(q, kp, vp, jnp.asarray(tables), jnp.asarray(pos), hkv,
-                   window, s, 1)
-    assert got.shape == (4, T, hkv * G, dv)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(got[1:]), np.asarray(want[1:]),
-                               atol=tol, rtol=0)
-    np.testing.assert_array_equal(np.asarray(got[0]), 0)
-
-
-def test_reference_attention_takes_a_sink_and_narrower_values():
-    """``paged_attention_reference`` (the ``TPUMX_PALLAS=0`` path) with a
-    sink: the softmax over the scores and one more column, dropped."""
-    rng = np.random.default_rng(4)
-    q = jnp.asarray(rng.normal(0, 1, (2, 3, 4, 6)), jnp.float32)
-    k = jnp.asarray(rng.normal(0, 1, (2, 10, 2, 6)), jnp.float32)
-    v = jnp.asarray(rng.normal(0, 1, (2, 10, 2, 5)), jnp.float32)
-    sink = jnp.asarray(rng.normal(0, 1, 4), jnp.float32)
-    mask = jnp.ones((2, 3, 10), bool)
-    got = pa.paged_attention_reference(q, k, v, mask, 0.5, sink)
-    kk, vv = jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 0.5
-    e = jnp.exp(s)
-    p = e / (jnp.exp(sink)[None, :, None, None] + e.sum(-1, keepdims=True))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(
-        jnp.einsum("bhqk,bkhd->bqhd", p, vv)), atol=1e-6, rtol=0)
-
-
-def test_call_names():
-    assert pa._call_name(1, 1024, "full_decode") == \
-        "_paged_call_w1024_t1_full_decode"
-    assert pa._call_name(512, 64, "window_prefill") == \
-        "_paged_call_w64_t512_window_prefill"
-    assert [hm.name_of(k) for k in (0, 1)] == ["full", "window"]
 
 
 # -- the experts ---------------------------------------------------------------
@@ -661,31 +559,16 @@ def test_the_expert_layers_trips_reach_stats_and_a_second_trip_is_exact():
     assert counts["expert_trips_extra"] == 2 * 1
 
 
-@pytest.mark.parametrize("kernel,n_width", [("gather", 5), ("paged", 1)])
-def test_warmup_covers_every_program_the_traffic_needs(request, params3,
-                                                       kernel, n_width):
-    """With the kernel a table's width is free, so the service keeps one
-    width: a decode program and a prefill program a chunk length, each
-    with the window kind's ring at its own width."""
+def _check_warmup_covers_the_traffic(svc, n_width):
+    """``warmup()`` makes a decode program a table width and a prefill
+    program a chunk length, each with the window kind's ring at its own
+    width; the traffic behind it compiles nothing."""
     from mxnet_tpu.executor import compile_cache_stats
 
-    if kernel == "paged":
-        svc = request.getfixturevalue("paged3")
-    else:
-        svc = _service(params3, seq_buckets=[8, 16, 40], model=hm.HybridMoeLM(
-            _config(**CUT), max_len=64, kv_dtype=jnp.float32,
-            longest_chunk=16))
     assert len(svc._width_buckets) == n_width
     done = svc._programs.compiled_signatures()
     n = svc.warmup()
-    sigs = svc._prefill_signatures()
-    assert n + done == len(sigs) + n_width
-    if kernel == "paged":
-        # the 400 rung only says how long a prompt may be
-        assert sigs == [(8, 128), (16, 128)] and svc._seq_buckets == [8, 16]
-        rings = {key[0]: key[1][1][1][1][1] for key in svc.compile_stats()}
-        # window_blocks(8, T) rounded up to a power of two
-        assert rings == {"gen_prefill": 8, "gen_decode": 4}
+    assert n + done == len(svc._prefill_signatures()) + n_width
     misses = compile_cache_stats()["misses"]
     svc.start()
     rng = np.random.default_rng(2)
@@ -694,6 +577,14 @@ def test_warmup_covers_every_program_the_traffic_needs(request, params3,
     for st in streams:
         assert len(st.result(300)) == 5
     assert compile_cache_stats()["misses"] == misses
+
+
+def test_warmup_covers_every_program_the_traffic_needs(params3):
+    """Over gathered pages a table's width is a shape: five widths."""
+    _check_warmup_covers_the_traffic(
+        _service(params3, seq_buckets=[8, 16, 40], model=hm.HybridMoeLM(
+            _config(**CUT), max_len=64, kv_dtype=jnp.float32,
+            longest_chunk=16)), 5)
 
 
 def test_the_published_layers_are_the_arithmetic_of_the_cut():

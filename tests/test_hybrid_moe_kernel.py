@@ -1,0 +1,144 @@
+"""The tiles body of ops/paged_attention.py as the window-and-full-attention
+model calls it (tests/test_hybrid_moe.py has the preset and the tolerance):
+against its oracle over gathered pages, and inside the service's programs at
+the 3-layer cut, through the Pallas interpreter.  A file of its own because a
+program of the interpreted kernel takes most of a minute to compile."""
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from mxnet_tpu.ops import paged_attention as pa
+from mxnet_tpu.parallel import hybrid_moe as hm
+from mxnet_tpu.serving.generation.kv_cache import blocks_for, ring_width
+from test_hybrid_moe import (C3, CUT, _check_logits_through_the_cache,
+                             _check_warmup_covers_the_traffic, _config,
+                             _model, _service, params3)  # noqa: F401
+
+
+# -- the tiles body against its oracle ---------------------------------------
+def _ring_case(rng, B, T, hkv, G, dk, dv, window, bs, ring, n_blocks, dtype,
+               ctx):
+    """A paged pool, tables for rows that sit ``ctx[b]`` positions into
+    their sequences and feed ``T`` more, the window kind's as a ring that
+    holds only what those queries can see."""
+    H = hkv * G
+    k_pool = jnp.asarray(rng.normal(0, 1, (2, n_blocks, bs, hkv * dk)), dtype)
+    v_pool = jnp.asarray(rng.normal(0, 1, (2, n_blocks, bs, hkv * dv)), dtype)
+    q = jnp.asarray(rng.normal(0, 1, (B, T, H, dk)), jnp.float32)
+    positions = np.asarray(ctx)[:, None] + np.arange(T)[None, :]
+    max_pos = positions[:, -1].copy()
+    free = list(rng.permutation(np.arange(1, n_blocks)))
+    width = ring if window else blocks_for(int(max_pos.max()) + 1, bs)
+    tables = np.zeros((B, width), np.int32)
+    for b in range(B):
+        first = max(0, positions[b, 0] - (window - 1)) // bs if window else 0
+        for blk in range(first, max_pos[b] // bs + 1):
+            tables[b, blk % width] = free.pop()
+    return q, k_pool, v_pool, tables, positions.astype(np.int32), \
+        max_pos.astype(np.int32)
+
+
+def _oracle(q, k_pool, v_pool, tables, positions, hkv, window, sink, layer):
+    """``paged_attention_reference`` over the gathered pages, a ring's
+    slots at the positions they hold."""
+    B, T = positions.shape
+    bs, width = k_pool.shape[2], tables.shape[1]
+    gather = lambda pool: pool[layer][tables].reshape(  # noqa: E731
+        B, width * bs, hkv, -1).astype(jnp.float32)
+    if window:
+        first = jnp.maximum(positions[:, 0] - (window - 1), 0) // bs
+        at = hm._ring_positions(jnp.asarray(first), width, bs)
+    else:
+        at = jnp.arange(width * bs)[None, :]
+    mask = at[:, None, :] <= positions[:, :, None]
+    if window:
+        mask &= at[:, None, :] > positions[:, :, None] - window
+    return pa.paged_attention_reference(
+        q, gather(k_pool), gather(v_pool), mask, q.shape[-1] ** -0.5, sink)
+
+
+@pytest.mark.parametrize("T,window,sink,dtype", [
+    (1, 0, False, jnp.float32), (1, 16, True, jnp.float32),
+    (1, 16, True, jnp.bfloat16), (24, 0, False, jnp.float32),
+    (24, 16, True, jnp.float32), (24, 16, False, jnp.bfloat16),
+    (80, 16, True, jnp.float32)],
+    ids=["decode-full", "decode-window", "decode-window-bf16", "chunk-full",
+         "chunk-window", "chunk-window-nosink-bf16", "chunk-window-tiles"])
+def test_tiles_body_matches_its_oracle(T, window, sink, dtype):
+    """Grouped heads as rows (4 query heads a KV head), K pages 24 lanes a
+    head beside V pages 16, decode and chunks (80 x 4 rows: two tiles),
+    rows at different depths, one inactive; the window walk over a ring
+    that wrapped, the sink in the denominator."""
+    rng = np.random.default_rng(T + window)
+    hkv, G, dk, dv, bs = 2, 4, 24, 16, 8
+    ring = ring_width(window, T, bs) if window else 0
+    ctx = [0, 5, 37, 70]
+    q, kp, vp, tables, pos, max_pos = _ring_case(
+        rng, 4, T, hkv, G, dk, dv, window, bs, ring, 64, dtype, ctx)
+    max_pos[0] = -1                        # an inactive row
+    s = jnp.asarray(rng.normal(0, 1, hkv * G), jnp.float32) if sink else None
+    got = pa.paged_attention(q, kp, vp, tables, pos, max_pos,
+                             scale=dk ** -0.5, layer=1, window=window, sink=s,
+                             call="window_prefill")
+    want = _oracle(q, kp, vp, jnp.asarray(tables), jnp.asarray(pos), hkv,
+                   window, s, 1)
+    assert got.shape == (4, T, hkv * G, dv)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got[1:]), np.asarray(want[1:]),
+                               atol=tol, rtol=0)
+    np.testing.assert_array_equal(np.asarray(got[0]), 0)
+
+
+def test_reference_attention_takes_a_sink_and_narrower_values():
+    """``paged_attention_reference`` (the ``TPUMX_PALLAS=0`` path) with a
+    sink: the softmax over the scores and one more column, dropped."""
+    rng = np.random.default_rng(4)
+    q = jnp.asarray(rng.normal(0, 1, (2, 3, 4, 6)), jnp.float32)
+    k = jnp.asarray(rng.normal(0, 1, (2, 10, 2, 6)), jnp.float32)
+    v = jnp.asarray(rng.normal(0, 1, (2, 10, 2, 5)), jnp.float32)
+    sink = jnp.asarray(rng.normal(0, 1, 4), jnp.float32)
+    mask = jnp.ones((2, 3, 10), bool)
+    got = pa.paged_attention_reference(q, k, v, mask, 0.5, sink)
+    kk, vv = jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 0.5
+    e = jnp.exp(s)
+    p = e / (jnp.exp(sink)[None, :, None, None] + e.sum(-1, keepdims=True))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(
+        jnp.einsum("bhqk,bkhd->bqhd", p, vv)), atol=1e-6, rtol=0)
+
+
+def test_call_names():
+    assert pa._call_name(1, 1024, "full_decode") == \
+        "_paged_call_w1024_t1_full_decode"
+    assert pa._call_name(512, 64, "window_prefill") == \
+        "_paged_call_w64_t512_window_prefill"
+    assert [hm.name_of(k) for k in (0, 1)] == ["full", "window"]
+
+
+# -- the tiles body inside the service's programs ------------------------------
+@pytest.fixture(scope="module")
+def paged3(params3):
+    """The 3-layer cut through the tiles body (interpreted)."""
+    svc = _service(params3, "paged", model=_model(_config(**CUT)))
+    yield svc
+    svc.stop(drain=False, timeout=30)
+
+
+@pytest.mark.parametrize("part", ["prefill", "decode"])
+@pytest.mark.parametrize("plen", [16, 70])
+def test_chunked_prefill_then_decode_match_reference_logits(params3, paged3,
+                                                            plen, part):
+    _check_logits_through_the_cache(paged3, params3, C3, "paged", plen, part)
+
+
+def test_warmup_covers_every_program_the_traffic_needs(paged3):
+    """With the kernel a table's width is free, so the service keeps one
+    width."""
+    _check_warmup_covers_the_traffic(paged3, 1)
+    # the 400 rung only says how long a prompt may be
+    assert paged3._prefill_signatures() == [(8, 128), (16, 128)] \
+        and paged3._seq_buckets == [8, 16]
+    rings = {key[0]: key[1][1][1][1][1] for key in paged3.compile_stats()}
+    # window_blocks(8, T) rounded up to a power of two
+    assert rings == {"gen_prefill": 8, "gen_decode": 4}

@@ -29,6 +29,7 @@ from mxnet_tpu.parallel.sdar_moe import expert_products
 from mxnet_tpu.serving.bucketing import pad_tokens_right
 from mxnet_tpu.serving.generation import GenerationConfig, GenerationService
 from mxnet_tpu.serving.generation.kv_cache import PagedKVCache, blocks_for
+from oracle import greedy
 from perfbench.reference import dots_vlm1 as ref
 
 C = dict(num_hidden_layers=3, first_k_dense_replace=1, hidden_size=64,
@@ -84,10 +85,8 @@ def _ref_logits(params, tokens, at0, n_at=1, dtype="float32", c=C):
 
 
 def _ref_greedy(params, prompt, n):
-    seq = [int(t) for t in prompt]
-    for _ in range(n):
-        seq.append(int(_ref_logits(params, seq, len(seq) - 1)[0].argmax()))
-    return seq[len(prompt):]
+    return greedy(lambda seq: _ref_logits(params, seq, len(seq) - 1)[0],
+                  prompt, n)
 
 
 def _sampler(n, counter):

@@ -13,28 +13,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mxnet_tpu import observability as obs
 from mxnet_tpu.ops import paged_attention as pa
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.parallel import transformer as tr
 from mxnet_tpu.serving import pad_tokens_right
 from mxnet_tpu.serving.generation import GenerationConfig, GenerationService
+from oracle import CFG, greedy_oracle, params  # noqa: F401 (fixture)
+from test_generation import _fresh_observability  # noqa: F401 (fixture)
 
 pytestmark = pytest.mark.pallas
-
-CFG = tr.TransformerConfig(vocab=40, d_model=32, n_heads=4, n_layers=2,
-                           d_ff=64, max_len=64)
-
-
-@pytest.fixture(autouse=True)
-def _fresh_observability():
-    yield
-    obs.recompile.reset()
-
-
-@pytest.fixture(scope="module")
-def params():
-    return tr.transformer_lm_init(CFG, jax.random.PRNGKey(0))
 
 
 @pytest.fixture
@@ -43,16 +30,6 @@ def paged(monkeypatch):
     interpreter leg through this)."""
     monkeypatch.setenv("TPUMX_PALLAS", "1")
     assert pk.pallas_enabled()
-
-
-def _greedy_oracle(params, prompt, n_new):
-    toks = [int(t) for t in prompt]
-    for _ in range(n_new):
-        logits = tr.transformer_lm_apply(
-            params, jnp.asarray([toks], dtype=jnp.int32),
-            jnp.arange(len(toks), dtype=jnp.int32), CFG)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
 
 
 def _dense_reference(q, kp, vp, tables, positions, scale):
@@ -495,7 +472,7 @@ def test_service_greedy_parity_chunked_prefill(params, paged, monkeypatch):
     assert svc.stats()["decode_kernel"] == "paged"
     svc.stop()
     for got, p in zip(results, prompts):
-        assert got == _greedy_oracle(params, p, 6)
+        assert got == greedy_oracle(params, p, 6)
 
 
 def test_zero_recompiles_under_freeze_paged(params, paged, monkeypatch):
@@ -615,7 +592,7 @@ def test_service_mp2_decodes_through_paged_kernel(params, paged):
     assert kern1 == kern2 == "paged"
     assert outs1 == outs2
     for got, p in zip(outs2, prompts):
-        assert got == _greedy_oracle(params, p, 4)
+        assert got == greedy_oracle(params, p, 4)
 
 
 def test_service_mp_indivisible_heads_fall_back_to_gather(params, paged):

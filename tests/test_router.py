@@ -5,22 +5,18 @@ shutdown, and the TPUMX_FAULT_GEN_KILL_REPLICA injection.
 """
 import time
 
-import jax
 import numpy as np
 import pytest
 
 from mxnet_tpu import observability as obs
 from mxnet_tpu.fault.inject import injector
-from mxnet_tpu.parallel import transformer as tr
-from mxnet_tpu.serving import (GenerationConfig, GenerationRouter,
-                               GenerationService, NoHealthyReplicaError,
-                               ReplicaDeadError, RouterConfig,
-                               ServingClosedError)
+from mxnet_tpu.serving import (GenerationRouter, GenerationService,
+                               NoHealthyReplicaError, ReplicaDeadError,
+                               RouterConfig, ServingClosedError)
+from oracle import CFG, greedy_oracle, params  # noqa: F401 (fixture)
+from test_generation import _gc
 
 pytestmark = pytest.mark.router
-
-CFG = tr.TransformerConfig(vocab=40, d_model=32, n_heads=4, n_layers=2,
-                           d_ff=64, max_len=64)
 
 
 @pytest.fixture(autouse=True)
@@ -28,20 +24,6 @@ def _fresh_state():
     yield
     obs.recompile.reset()
     injector().reset()
-
-
-@pytest.fixture(scope="module")
-def params():
-    return tr.transformer_lm_init(CFG, jax.random.PRNGKey(0))
-
-
-def _gc(**kw):
-    kw.setdefault("max_slots", 2)
-    kw.setdefault("block_size", 8)
-    kw.setdefault("num_blocks", 32)
-    kw.setdefault("seq_buckets", [16, 32])
-    kw.setdefault("max_new_tokens", 8)
-    return GenerationConfig(**kw)
 
 
 def _router(params, n=2, rc=None, start=True, **gc_kw):
@@ -54,17 +36,6 @@ def _router(params, n=2, rc=None, start=True, **gc_kw):
                             start=start)
 
 
-def _greedy_oracle(params, prompt, n_new):
-    import jax.numpy as jnp
-    toks = [int(t) for t in prompt]
-    for _ in range(n_new):
-        logits = tr.transformer_lm_apply(
-            params, jnp.asarray([toks], dtype=jnp.int32),
-            jnp.arange(len(toks), dtype=jnp.int32), CFG)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
-
-
 def test_least_loaded_dispatch_spreads_and_tokens_match_oracle(params):
     router = _router(params, n=2)
     rs = np.random.RandomState(1)
@@ -74,7 +45,7 @@ def test_least_loaded_dispatch_spreads_and_tokens_match_oracle(params):
     st = router.stats()
     router.stop()
     for p, got in zip(prompts, outs):
-        assert got == _greedy_oracle(params, p, 4)
+        assert got == greedy_oracle(params, p, 4)
     per_replica = [r["dispatches"] for r in st["replicas"]]
     assert sum(per_replica) == len(prompts)
     assert all(d > 0 for d in per_replica), \

@@ -868,8 +868,8 @@ def test_failure_with_a_pass_in_flight_costs_no_token(params, monkeypatch,
     assert failed_at[0] is True and len(raised) == times
 
 
-def test_warmup_covers_the_carry_and_lowers_a_width_once(params,
-                                                         monkeypatch):
+def test_warmup_covers_the_carry_and_lowers_a_width_once(
+        params, monkeypatch, no_compile_cache):
     """The pass in flight adds no model program and compiles nothing
     after warm-up, by this repo's count and by XLA's own: the block
     state carried on the device is fed to the ``gen_block`` programs that

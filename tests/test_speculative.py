@@ -9,51 +9,18 @@ and zero post-warmup recompiles with every speculative program frozen.
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from mxnet_tpu import engine as eng
-from mxnet_tpu import observability as obs
 from mxnet_tpu.ops import sampling as smp
 from mxnet_tpu.parallel import transformer as tr
-from mxnet_tpu.serving.generation import GenerationConfig, GenerationService
+from mxnet_tpu.serving.generation import GenerationService
 from mxnet_tpu.serving.generation.speculative import DraftModel, propose_ngram
+from oracle import CFG, greedy_oracle, params  # noqa: F401 (fixture)
+from test_generation import _fresh_observability, _gc  # noqa: F401 (fixture)
 
 pytestmark = [pytest.mark.generation, pytest.mark.speculative]
-
-CFG = tr.TransformerConfig(vocab=40, d_model=32, n_heads=4, n_layers=2,
-                           d_ff=64, max_len=64)
-
-
-@pytest.fixture(autouse=True)
-def _fresh_observability():
-    yield
-    obs.recompile.reset()
-
-
-@pytest.fixture(scope="module")
-def params():
-    return tr.transformer_lm_init(CFG, jax.random.PRNGKey(0))
-
-
-def _gc(**kw):
-    kw.setdefault("max_slots", 2)
-    kw.setdefault("block_size", 8)
-    kw.setdefault("num_blocks", 32)
-    kw.setdefault("seq_buckets", [16, 32])
-    kw.setdefault("max_new_tokens", 8)
-    return GenerationConfig(**kw)
-
-
-def _greedy_oracle(params, prompt, n_new):
-    toks = [int(t) for t in prompt]
-    for _ in range(n_new):
-        logits = tr.transformer_lm_apply(
-            params, jnp.asarray([toks], dtype=jnp.int32),
-            jnp.arange(len(toks), dtype=jnp.int32), CFG)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
 
 
 # repetitive prompts so the n-gram proposer actually fires
@@ -190,7 +157,7 @@ def test_draft_model_propose_matches_full_oracle(params):
     props = draft.propose(window, np.clip(positions, 0, CFG.max_len - 1),
                           np.array([n], np.int32))
     assert props.shape == (1, 4)
-    assert list(props[0]) == _greedy_oracle(params, toks, 4)
+    assert list(props[0]) == greedy_oracle(params, toks, 4)
     st = draft.compile_stats()
     assert len(st) == 1 and next(iter(st))[0] == "gen_draft"
 
@@ -233,7 +200,7 @@ def test_spec_greedy_bitwise_matches_oracle_across_membership(params):
     stats = svc.stats()
     svc.stop()
     for i, p in enumerate(REP + [np.array([11, 5, 11, 5, 11, 5, 2])]):
-        assert outs[i] == _greedy_oracle(params, p, 6 + (i % 4)), \
+        assert outs[i] == greedy_oracle(params, p, 6 + (i % 4)), \
             f"request {i} diverged from the greedy oracle"
     spec = stats["speculative"]
     assert spec["spec_steps"] >= 1 and spec["proposed_tokens"] >= 1
@@ -297,7 +264,7 @@ def test_spec_draft_model_full_acceptance(params):
     stats = svc.stats()
     svc.stop()
     for p, got in zip(prompts, outs):
-        assert got == _greedy_oracle(params, p, 8)
+        assert got == greedy_oracle(params, p, 8)
     spec = stats["speculative"]
     assert spec["draft_mode"] == "model"
     assert spec["proposed_tokens"] >= 1
@@ -324,7 +291,7 @@ def test_preemption_mid_speculation_bit_identical(params):
     stats = svc.stats()
     svc.stop()
     for p, got in zip(prompts, outs):
-        assert got == _greedy_oracle(params, p, 20)
+        assert got == greedy_oracle(params, p, 20)
     assert stats["counts"]["preempted"] >= 1, \
         "the tight pool must have forced at least one preemption"
     assert stats["speculative"]["spec_steps"] >= 1
@@ -376,7 +343,7 @@ def test_multistep_greedy_and_sampled_parity(params):
                            top_k=12, seed=42, timeout=180)
     stats = svc.stats()
     svc.stop()
-    assert greedy == _greedy_oracle(params, p0, 8)
+    assert greedy == greedy_oracle(params, p0, 8)
     base = GenerationService(params, CFG, _gc(), start=False)
     base.start()
     assert sampled == base.generate(p1, max_new_tokens=7, temperature=0.8,
@@ -513,7 +480,7 @@ def test_speculative_off_is_byte_identical(params, monkeypatch):
     stats = svc.stats()
     svc.stop()
     for p, got in zip(REP, outs):
-        assert got == _greedy_oracle(params, p, 6)
+        assert got == greedy_oracle(params, p, 6)
     assert stats["decode_mode"] == "single"
     assert stats["speculative"] is None
     assert stats["multistep"]["steps"] == 0
