@@ -36,14 +36,19 @@ from typing import Dict, Iterable, List, Optional, Sequence
 __all__ = ["register", "text_thunk", "session_start", "session_stop",
            "sources", "table", "resolve", "parse_op_name", "stale",
            "device_table",
-           "build_stats", "Resolved", "Table", "ProgramTable", "UNSCOPED",
-           "reset"]
+           "build_stats", "Resolved", "ArgumentCopy", "Table", "ProgramTable",
+           "UNSCOPED", "reset"]
 
 #: what :func:`resolve` returns: the program's kind (``fused_step``,
 #: ``decode``, ``prefill`` ...), the scope path below it (``"Convolution/
 #: conv1"``, ``"layer3/moe.combine"``; ``""``: the program's own
 #: operation outside every scope) and ``"forward"`` or ``"backward"``
 Resolved = namedtuple("Resolved", "kind scope direction")
+
+#: one entry of :attr:`ProgramTable.argument_copies`: the instruction, the
+#: bytes of its result, its scope (``""``: none) and the argument's name as
+#: the program's caller spelled it (``"params['l0_wq']"``)
+ArgumentCopy = namedtuple("ArgumentCopy", "name bytes scope argument")
 
 #: the row of device time that resolves to nothing
 UNSCOPED = "(unscoped)"
@@ -59,6 +64,18 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _LOC = re.compile(r'loc\("([^"/][^"]*)"')    # a name stack (no file's path)
 _CALLS = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
 _PRODUCT = re.compile(r"[\]\}\)] (convolution|dot)\(")
+_OPCODE = re.compile(r"[\]\}\)] ([\w\-]+)\(")
+_PARAMETER = re.compile(r" parameter\((\d+)\)")
+# what hands an operand on as it is, moved or cut but not computed with: a
+# weight's way from the program's argument to the copy that re-lays it out
+# (its prefetch is a ``slice-start`` / ``-done`` a piece under a
+# ``ConcatBitcast`` custom call, or a ``copy-start`` / ``-done``)
+_MOVES = frozenset({"bitcast", "reshape", "copy", "transpose", "slice",
+                    "copy-start", "copy-done", "slice-start", "slice-done",
+                    "get-tuple-element"})
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
+             "s16": 2, "u16": 2, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
+             "f64": 8}          # anything else: 4
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _SHAPE = re.compile(r"\b[a-z]\w*\[[\d,]*\]")
 _MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
@@ -268,7 +285,10 @@ class ProgramTable:
     BatchNorm's apply, a cast, a parameter's update — is counted with it),
     any other to the scope most of its body's operations have (its own
     ``op_name`` is its root's alone: a loop over a BatchNorm's backward
-    pass that ends in a cast would read as the cast)."""
+    pass that ends in a cast would read as the cast).
+
+    ``argument_copies`` lists what the text re-lays-out of the program's
+    own arguments (:class:`ArgumentCopy`)."""
 
     def __init__(self, kind: Optional[str], text: str):
         self.module = ""
@@ -281,6 +301,9 @@ class ProgramTable:
         members: Dict[str, List[str]] = {}      # computation -> its names
         products = set()                        # convolutions, dots
         shapes: Dict[str, tuple] = {}
+        opcode: Dict[str, str] = {}
+        argument: Dict[str, str] = {}       # ENTRY's parameters, as named
+        inside: Dict[str, tuple] = {}       # a body's parameter -> (body, k)
         comp, entry = None, False
         for line in text.splitlines():
             if not self.module:
@@ -305,6 +328,18 @@ class ProgramTable:
             own[name] = op.group(1) if op and "/" in op.group(1) else None
             if own[name] and _PRODUCT.search(head):
                 products.add(name)
+            code = _OPCODE.search(head)
+            opcode[name] = code.group(1) if code else ""
+            if opcode[name] == "custom-call" and \
+                    'custom_call_target="ConcatBitcast"' in head:
+                opcode[name] = "bitcast"
+            elif opcode[name] == "parameter":
+                k = int(_PARAMETER.search(head).group(1))
+                if entry:
+                    argument[name] = op.group(1).replace("\\'", "'") \
+                        if op else name
+                else:
+                    inside[name] = (comp, k)
             members.setdefault(comp, []).append(name)
             called = _CALLS.search(line)
             if called:
@@ -338,6 +373,32 @@ class ProgramTable:
                     return found
             return None
 
+        fusions: Dict[str, List[str]] = {}      # a body -> who calls it
+        for name, called in calls.items():
+            if opcode[name] in ("fusion", "call"):
+                fusions.setdefault(called, []).append(name)
+
+        def argument_of(name, depth=0):
+            """The ENTRY parameter that ``name`` hands on unchanged but for
+            its layout, or None: a ``%param_N`` of a fused computation is
+            that fusion's operand, followed to whoever called it."""
+            while depth < 32:
+                depth += 1
+                if name in argument:
+                    return name
+                if name in inside:
+                    body, k = inside[name]
+                    for caller in fusions.get(body, ()):
+                        if k < len(operands[caller]):
+                            found = argument_of(operands[caller][k], depth)
+                            if found:
+                                return found
+                    return None
+                if opcode.get(name) not in _MOVES or not operands[name]:
+                    return None
+                name = operands[name][0]
+            return None
+
         parsed = {name: parse_op_name(op) for name, op in
                   ((name, scope_of(name)) for name in own) if op}
         if kind is None:
@@ -359,6 +420,28 @@ class ProgramTable:
                 scope = below
             self.instrs[name] = (shapes[name],
                                  Resolved(kind, scope, direction))
+        #: an :class:`ArgumentCopy` for every ``copy`` and ``transpose`` —
+        #: executed alone or inside a fusion — of a program ARGUMENT (a
+        #: weight, a pool), straight or through its prefetch: a re-layout
+        #: the program pays in every call, for an operand that never
+        #: changes (docs/generation.md "A weight reaches its product as
+        #: stored"); largest first
+        self.argument_copies: List[ArgumentCopy] = []
+        for name, code in opcode.items():
+            if code not in ("copy", "transpose") or not operands[name]:
+                continue
+            source = argument_of(operands[name][0])
+            if source is None or not shapes[name]:
+                continue
+            dtype, _, dims = shapes[name][0].partition("[")
+            n = _ITEMSIZE.get(dtype, 4)
+            for dim in dims.rstrip("]").split(","):
+                n *= int(dim or 1)
+            resolved = self.instrs[name][1]
+            self.argument_copies.append(ArgumentCopy(
+                name, n, resolved.scope if resolved else "",
+                argument[source]))
+        self.argument_copies.sort(key=lambda c: -c.bytes)
 
     def lookup(self, name, shapes=None):
         """The entry of instruction ``name``, if the program has it (and,

@@ -54,8 +54,8 @@ import jax
 import jax.numpy as jnp
 
 from .latent_moe import _gated, route_sigmoid_groups
-from .sdar_moe import (_mm, _rms, _rope, count_trips, expert_products,
-                       trip_counters)
+from .sdar_moe import (_mm, _mm_as_stored, _rms, _rope, count_trips,
+                       expert_products, trip_counters)
 from .transformer import paged_write_coords
 
 Params = Dict[str, jnp.ndarray]
@@ -283,8 +283,8 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
             with scope("norm"):
                 h = _rms(x, g("norm1"), eps)
             with scope("attn.proj"):
-                q = rotated(_mm(h, g("wq")).reshape(B, T, H, dq))
-                k = rotated(_mm(h, g("wk")).reshape(B, T, hkv, dq))
+                q = rotated(_mm_as_stored(h, g("wq")).reshape(B, T, H, dq))
+                k = rotated(_mm_as_stored(h, g("wk")).reshape(B, T, hkv, dq))
                 v = _mm(h, g("wv")) * cfg.attention_value_scale
             with scope("attn.cache_write"):
                 k_pool, v_pool = pools[2 * kind], pools[2 * kind + 1]

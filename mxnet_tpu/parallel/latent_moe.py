@@ -67,8 +67,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .sdar_moe import (_mm, _rms, count_trips, expert_products,
-                       trip_counters)
+from .sdar_moe import (_mm, _mm_as_stored, _rms, count_trips,
+                       expert_products, trip_counters)
 from .transformer import paged_write_coords
 
 Params = Dict[str, jnp.ndarray]
@@ -305,8 +305,8 @@ def latent_moe_decode(params: Params, tokens, positions, lengths, pool,
             with scope("norm"):
                 h = _rms(x, g("norm1"), eps)
             with scope("attn.proj"):
-                q = _mm(_rms(_mm(h, g("wqa")), g("q_norm"), eps), g("wqb")
-                        ).reshape(B, T, H, dn + dr)
+                q = _mm_as_stored(_rms(_mm(h, g("wqa")), g("q_norm"), eps),
+                                  g("wqb")).reshape(B, T, H, dn + dr)
                 qn, qr = q[..., :dn], _rope(q[..., dn:], positions, inv_freq)
                 kva = _mm(h, g("wkva"))                        # (B, T, c+dr)
                 latent = jnp.concatenate(

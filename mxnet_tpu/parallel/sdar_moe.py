@@ -142,6 +142,19 @@ def _mm(a, w):
     return jnp.dot(a.astype(w.dtype), w, preferred_element_type=jnp.float32)
 
 
+def _mm_as_stored(a, w):
+    """:func:`_mm` for a weight whose columns are heads of a width that is
+    not whole 128-lane tiles (192 = 128 + 64 rotary lanes): the result
+    stays FLAT, ``(..., w.shape[1])``, until the compiler has laid the
+    program out.  Cutting such a result into heads moves data whoever pays;
+    written straight behind the product, the chip's compiler folds the cut
+    into the product and turns the WEIGHT round to suit it — a copy of the
+    whole matrix, 100 MB of ``wq`` a layer at ``mimo-v2.5``'s widths, in
+    every call — where behind the barrier it re-lays-out the activation
+    (docs/generation.md "A weight reaches its product as stored")."""
+    return jax.lax.optimization_barrier(_mm(a, w))
+
+
 def _rope(x, positions, theta):
     """Rotate-half rotary embedding of ``x`` (B, T, H, D) in float32."""
     D = x.shape[-1]

@@ -1178,8 +1178,13 @@ class GenerationService:
         masked = _np.zeros((S, L), bool)
         for w in widths:
             z = self._build_step((), L, sampler=False, width=w)
-            unmasked, _, _ = self._programs.run_block(
-                self._cache, *z.operands, masked, _np.zeros(S, _np.int32))
+            # (as a pass in flight calls it, ``read`` False and positional
+            # for a wrapper's sake: the logits stay as the program wrote
+            # them, and nothing logits-sized is made beside them)
+            unmasked, touched, _ = self._programs.run_block(
+                self._cache, *z.operands, masked, _np.zeros(S, _np.int32),
+                False)
+            _synced(unmasked, touched)
         if self._runs_ahead:
             _synced(*self._programs.carry_block(
                 unmasked, z.tokens, masked, z.tokens, masked,

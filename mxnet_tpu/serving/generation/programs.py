@@ -296,8 +296,17 @@ def _block_step(params, pools, tokens, positions, lengths, block_tables,
     ``n_unmask`` most confident masked positions are chosen.  A row with
     no MASK is on its commit pass: the same program, its tokens ignored.
     Returns ``(unmasked (S, L): the new token id, -1 where nothing was
-    unmasked; experts touched, summed over layers; logits (S, L, vocab);
-    pools)``."""
+    unmasked; experts touched, summed over layers; logits (L, S, vocab);
+    pools)``.
+
+    The logits go back POSITION-major, as the head's product wrote them:
+    its rows are ``S x L`` and a row tile holds 8, so the chip's compiler
+    puts ``L`` (4) outside and tiles ``S``, and the sampler reads them so.
+    Handed back ``(S, L, vocab)`` they cost one re-layout of the whole
+    array a pass (155 MB at ``sdar-30b-a3b``'s widths) for a result the
+    engine never reads; :meth:`GenerationPrograms.run_block` turns them
+    round for whoever does (docs/generation.md "A weight reaches its
+    product as stored")."""
     import jax
 
     from ...ops.sampling import block_unmask
@@ -308,6 +317,7 @@ def _block_step(params, pools, tokens, positions, lengths, block_tables,
             attention_kernel=attention_kernel, mp_mesh=mp_mesh, call="block")
         with jax.named_scope("sample"):
             unmasked = block_unmask(logits, masked, n_unmask)
+        logits = logits.swapaxes(0, 1)
     return unmasked, touched, logits, pools
 
 
@@ -601,8 +611,9 @@ class GenerationPrograms:
         L), experts touched np(), logits (S, L, vocab) on the device)``
         — see :func:`_block_step`.  Without ``read`` all three come back
         as the jitted call returned them, on the device and not waited
-        for (the engine's pass in flight: it reads the first two a pass
-        late, docs/generation.md "The step in flight"), and ``tokens``
+        for, the logits position-major ``(L, S, vocab)`` (the engine's
+        pass in flight: it reads the first two a pass late and the third
+        never, docs/generation.md "The step in flight"), and ``tokens``
         and ``masked`` already on the device (:meth:`carry_block`) go in
         as they are."""
         import jax
@@ -614,7 +625,7 @@ class GenerationPrograms:
             masked, _np.asarray(n_unmask, _np.int32)))
         if not read:
             return unmasked, touched, logits
-        return _synced(unmasked, touched) + (logits,)
+        return _synced(unmasked, touched) + (logits.swapaxes(0, 1),)
 
     def carry_block(self, unmasked, prev_tokens, prev_masked, tokens, masked,
                     keep):

@@ -62,6 +62,105 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _no_argument_copies(text, min_mb=8, but=None):
+    """No ``copy`` or ``transpose`` of ``min_mb`` MB or more whose operand
+    is a program ARGUMENT — a weight or a pool, straight or through its
+    prefetch — in the compiled ``text``: a program reads what it is handed
+    in the layout that is stored in (docs/generation.md "A weight reaches
+    its product as stored").  ``but = (pattern of the argument's name,
+    bytes)`` pins the one kind of copy a caller knows of and cannot
+    remove; returns how many of those there are."""
+    from mxnet_tpu.observability import device_scopes as ds
+
+    found = [c for c in ds.ProgramTable(None, text).argument_copies
+             if c.bytes >= min_mb << 20]
+    known = [c for c in found if but and re.fullmatch(but[0], c.argument)
+             and c.bytes == but[1]]
+    assert found == known, [c for c in found if c not in known]
+    return len(known)
+
+
+_ARGUMENT = '%w = bf16[4096,4096]{1,0:T(8,128)(2,1)} parameter(0), ' \
+    'metadata={op_name="params[\\\'l0_wq\\\']"}'
+_CANNED = {
+    # a weight turned round as it comes, and once more inside a fusion
+    # that is handed the argument itself
+    "straight": (2, f"""HloModule jit_step, is_scheduled=true
+
+%fused_computation.1 (param_0.1: bf16[4096,4096]) -> bf16[4096,4096] {{
+  %param_0.1 = bf16[4096,4096]{{1,0:T(8,128)(2,1)}} parameter(0)
+  ROOT %copy.2 = bf16[4096,4096]{{0,1:T(8,128)(2,1)}} copy(%param_0.1)
+}}
+
+ENTRY %main.1 (w: bf16[4096,4096], x: bf16[128,4096]) -> f32[128,4096] {{
+  {_ARGUMENT}
+  %x = bf16[128,4096]{{1,0:T(8,128)(2,1)}} parameter(1)
+  %copy.1 = bf16[4096,4096]{{0,1:T(8,128)(2,1)}} copy(%w), metadata={{op_name="params[\\'l0_wq\\']"}}
+  %bitcast.1 = bf16[32,128,4096]{{2,1,0:T(8,128)(2,1)}} bitcast(%copy.1)
+  %fusion.1 = bf16[4096,4096]{{0,1:T(8,128)(2,1)}} fusion(%w), kind=kLoop, calls=%fused_computation.1
+  ROOT %dot.1 = f32[128,4096]{{1,0:T(8,128)}} dot(%x, %fusion.1), lhs_contracting_dims={{1}}, rhs_contracting_dims={{0}}, metadata={{op_name="jit(step)/decode/layer0/attn.proj/dot_general"}}
+}}
+"""),
+    # ... behind its prefetch: slices in flight under a ConcatBitcast
+    "prefetched": (1, f"""HloModule jit_step, is_scheduled=true
+
+ENTRY %main.1 (w: bf16[4096,4096], x: bf16[128,4096]) -> f32[128,4096] {{
+  {_ARGUMENT}
+  %x = bf16[128,4096]{{1,0:T(8,128)(2,1)}} parameter(1)
+  %slice-start.1 = ((bf16[4096,4096]{{1,0:T(8,128)(2,1)}}), bf16[2048,4096]{{1,0:T(8,128)(2,1)S(1)}}, s32[]{{:T(128)}}) slice-start(%w), slice={{[0:2048], [0:4096]}}
+  %slice-start.2 = ((bf16[4096,4096]{{1,0:T(8,128)(2,1)}}), bf16[2048,4096]{{1,0:T(8,128)(2,1)S(1)}}, s32[]{{:T(128)}}) slice-start(%w), slice={{[2048:4096], [0:4096]}}
+  %slice-done.1 = bf16[2048,4096]{{1,0:T(8,128)(2,1)S(1)}} slice-done(%slice-start.1)
+  %slice-done.2 = bf16[2048,4096]{{1,0:T(8,128)(2,1)S(1)}} slice-done(%slice-start.2)
+  %custom-call.1 = bf16[4096,4096]{{1,0:T(8,128)(2,1)S(1)}} custom-call(%slice-done.1, %slice-done.2), custom_call_target="ConcatBitcast"
+  %copy.1 = bf16[4096,4096]{{0,1:T(8,128)(2,1)S(1)}} copy(%custom-call.1), metadata={{op_name="params[\\'l0_wq\\']"}}
+  ROOT %dot.1 = f32[128,4096]{{1,0:T(8,128)}} dot(%x, %copy.1), lhs_contracting_dims={{1}}, rhs_contracting_dims={{0}}, metadata={{op_name="jit(step)/decode/layer0/attn.proj/dot_general"}}
+}}
+"""),
+    # what the products RETURN may be turned round, alone or in a fusion
+    # whose %param_0 is that result and no argument of the program's
+    "activation": (0, f"""HloModule jit_step, is_scheduled=true
+
+%fused_computation.1 (param_0.1: f32[4096,4096]) -> f32[4096,4096] {{
+  %param_0.1 = f32[4096,4096]{{1,0:T(8,128)}} parameter(0)
+  ROOT %copy.2 = f32[4096,4096]{{0,1:T(8,128)}} copy(%param_0.1)
+}}
+
+ENTRY %main.1 (w: bf16[4096,4096], x: bf16[4096,4096]) -> f32[4096,4096] {{
+  {_ARGUMENT}
+  %x = bf16[4096,4096]{{1,0:T(8,128)(2,1)}} parameter(1)
+  %dot.1 = f32[4096,4096]{{1,0:T(8,128)}} dot(%x, %w), lhs_contracting_dims={{1}}, rhs_contracting_dims={{0}}, metadata={{op_name="jit(step)/decode/layer0/attn.proj/dot_general"}}
+  %copy.1 = f32[4096,4096]{{0,1:T(8,128)}} copy(%dot.1)
+  %fusion.1 = f32[4096,4096]{{0,1:T(8,128)}} fusion(%dot.1), kind=kLoop, calls=%fused_computation.1
+  ROOT %add.1 = f32[4096,4096]{{0,1:T(8,128)}} add(%copy.1, %fusion.1)
+}}
+""")}
+
+
+@pytest.mark.parametrize("case", sorted(_CANNED))
+def test_no_argument_copies_counts_a_weights_copies_and_no_results(case):
+    """The helper on short canned texts: an argument's copy counts —
+    straight, inside a fusion the argument is an operand of, behind its
+    prefetch — with its bytes, scope and the argument's name; a copy of
+    what a product returned does not, nor one in a fusion whose
+    ``%param_0`` is such a result."""
+    from mxnet_tpu.observability import device_scopes as ds
+
+    n, text = _CANNED[case]
+    copies = ds.ProgramTable(None, text).argument_copies
+    assert [(c.bytes, c.argument) for c in copies] == \
+        [(4096 * 4096 * 2, "params['l0_wq']")] * n
+    if n:
+        assert copies[-1].name == "copy.1" \
+            and copies[-1].scope == "layer0/attn.proj"
+        with pytest.raises(AssertionError, match="l0_wq"):
+            _no_argument_copies(text)
+        assert _no_argument_copies(text, min_mb=64) == 0
+        assert _no_argument_copies(
+            text, but=(r"params\['l\d_wq'\]", 4096 * 4096 * 2)) == n
+    else:
+        assert _no_argument_copies(text, min_mb=1) == 0
+
+
 LAYERS = 2                        # of the layered pool the kernels are handed
 
 
@@ -443,6 +542,13 @@ def test_block_step_program_compiles_at_the_cells_shapes(one_chip,
         == 3 * n_layers
     assert " sort(" in text and "f32[256,128,768]" not in text
     assert _pool_makers(text, n_layers) <= _IN_PLACE
+    # the logits go back position-major, as the head's product wrote them
+    # and the sampler read them: the program's third result is that
+    # product's, and nothing of the logits' size (155 MB) is copied
+    _no_argument_copies(text)
+    logits = r"f32\[(64,4|4,64|256),151936\]"
+    assert re.search(rf"ROOT %tuple[\w.]* = \(.*f32\[4,64,151936\]", text)
+    assert not re.search(rf"= {logits}\S* (copy|transpose)\(", text)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         + mem.output_size_in_bytes - mem.alias_size_in_bytes < 13e9
@@ -584,34 +690,51 @@ def test_tiled_grouped_matmul_compiles_at_the_cells_shapes(one_chip, M, K, N):
     assert "tpu_custom_call" in text and "_gmm_call" in text
 
 
+@pytest.mark.parametrize("S,T", [(256, 1), (1, 512)],
+                         ids=["decode", "prefill"])
 def test_latent_decode_step_program_compiles_at_the_cells_shapes(
-        one_chip, monkeypatch):
-    """``gen_decode`` as the service dispatches it (256 rows, one width),
-    at depth 2 (one dense and one expert layer: layers repeat): the latent
-    pool is updated in place, the kernels are there, the counts come back."""
+        one_chip, monkeypatch, S, T):
+    """``gen_decode`` (256 rows) and ``gen_prefill`` (a 512-token chunk) as
+    the service dispatches them (one width), at depth 2 (one dense and one
+    expert layer: layers repeat): the latent pool is updated in place, the
+    kernels are there, the counts come back, and ``wqb`` reaches its
+    product as stored."""
     from mxnet_tpu.serving.generation import programs as gp
 
     monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     model, params, pool = _latent_cell(sds, 2)
-    S, W = 256, 512
+    W = 512
     fn = jax.jit(functools.partial(gp._model_step, model=model,
                                    attention_kernel="paged"),
                  donate_argnums=(1,))
     compiled = fn.lower(
-        params, (pool,), sds((S, 1), jnp.int32), sds((S, 1), jnp.int32),
+        params, (pool,), sds((S, T), jnp.int32), sds((S, T), jnp.int32),
         sds((S,), jnp.int32), sds((S, W), jnp.int32), sds((S,), jnp.uint32),
         sds((S,), jnp.uint32), sds((S,), jnp.float32), sds((S,), jnp.int32),
         sds((S,), jnp.float32)).compile()
     text = compiled.as_text()
-    assert text.count("_mla_call_w512_decode") >= 2
+    assert text.count("_mla_call_w512_decode" if T == 1
+                      else "_mla_call_w512_t512_prefill") >= 2
     # 16 of the router's 256 experts held: the expert layer walks the held
-    # rows in tiles of 2 x 256 x 8 / 16 = 256, ONE copy of the kernel's
-    # call a projection inside the loop, none over all 2,048 rows, and the
-    # experts' matrices go through the loop as they are (1.4 GB a layer)
-    assert len(re.findall(r"%_gmm_call[\w.\-]* = f32\[256,", text)) == 3
-    assert "f32[2048,7168]" not in text
+    # rows in tiles of 2 x 256 x 8 / 16 = 256 (512 a chunk), ONE copy of
+    # the kernel's call a projection inside the loop, none over all 2,048
+    # (4,096) rows, and the experts' matrices go through the loop as they
+    # are (1.4 GB a layer)
+    rows = 2 * S * T * 8 // 16
+    assert len(re.findall(rf"%_gmm_call[\w.\-]* = f32\[{rows},", text)) == 3
+    assert f"f32[{S * T * 8},7168]" not in text
     _the_walk_is_named_and_copies_no_expert(text, 7168, 2048)
+    # no weight is turned round but ONE: the absorbed products are batched
+    # over the heads, the chip's compiler wants a batched product's
+    # operands head-major, and ``wkvb`` is stored ``(c, heads x 256)`` —
+    # latent-major, its heads inside the lanes: no layout of the stored
+    # bytes has the heads outside, so a layer copies its 33.5 MB at most
+    # once a call for both products (ROADMAP S16 keeps it; at the cell's
+    # depth of 5 the chip's own text has 4 in the chunk's program and none
+    # in the decode step's: PERF.md PR 39)
+    assert _no_argument_copies(
+        text, but=(r"params\['l\d_wkvb'\]", 512 * 128 * 256 * 2)) <= 2
     whole = f"= bf16[2,4096,{BS},640]"
     makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
               for ln in text.splitlines() if whole in ln}
@@ -730,4 +853,7 @@ def test_hybrid_step_program_compiles_at_the_cells_shapes(one_chip,
         makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
                   for ln in text.splitlines() if shape in ln}
         assert makers <= _IN_PLACE, (shape, makers)
+    # every weight reaches its product as stored: ``wq`` (100 MB a layer)
+    # and ``wk`` are multiplied flat and the RESULT is cut into heads of 192
+    _no_argument_copies(text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9

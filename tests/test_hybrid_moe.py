@@ -67,6 +67,14 @@ C3 = dict(C, hybrid_layer_pattern=[0, 1, 1], moe_layer_freq=[0, 1, 1],
           num_hidden_layers=3)
 CUT = dict(hybrid_layer_pattern=(0, 1, 1), moe_layer_freq=(0, 1, 1),
            num_hidden_layers=3)
+# the published head: 192 lanes of which the first 64 rotate (0.334 x 192),
+# beside values of 128 — not whole 128-lane tiles, which is what made the
+# chip's compiler turn ``wq`` round (docs/generation.md "A weight reaches
+# its product as stored"); two query heads are enough to catch a rotary cut
+# or a head's edge moved by a lane
+C192 = dict(C3, num_attention_heads=2, swa_num_attention_heads=2,
+            num_key_value_heads=1, swa_num_key_value_heads=2, head_dim=192,
+            swa_head_dim=192, v_head_dim=128, swa_v_head_dim=128)
 
 
 @pytest.fixture(scope="module")
@@ -88,6 +96,22 @@ def _service(params, kernel="gather", model=None, **kw):
         mp.setenv("TPUMX_PALLAS", "1" if kernel == "paged" else "0")
         return GenerationService(params, model or _model(),
                                  GenerationConfig(**gc), start=False)
+
+
+@pytest.fixture(scope="module")
+def params192():
+    return ref.init_params(3, C192, "float32")
+
+
+@pytest.fixture(scope="module")
+def svc192(params192):
+    """Three layers at the published head sizes, without the kernel."""
+    cut = {k: (tuple(v) if isinstance(v, list) else v)
+           for k, v in C192.items() if k in CUT or "head" in k}
+    assert _config(**cut).rotary_dim == 64
+    svc = _service(params192, model=_model(_config(**cut)))
+    yield svc
+    svc.stop(drain=False, timeout=30)
 
 
 @pytest.fixture(scope="module")
@@ -194,13 +218,21 @@ def _check_logits_through_the_cache(svc, p, c, kernel, plen, part):
 
 
 @pytest.mark.parametrize("part", ["prefill", "decode"])
-@pytest.mark.parametrize("plen", [3, 16, 37, 70])
-def test_chunked_prefill_then_decode_match_reference_logits(params, svc7,
+@pytest.mark.parametrize("widths,plen", [
+    ("tiny", 3), ("tiny", 16), ("tiny", 37), ("tiny", 70),
+    ("head192", 16), ("head192", 37)])
+def test_chunked_prefill_then_decode_match_reference_logits(request, widths,
                                                             plen, part):
     """The sums over the gathered pages and the gathered ring, at the 7
     layers of the published pattern (the tiles body:
-    tests/test_hybrid_moe_kernel.py)."""
-    _check_logits_through_the_cache(svc7, params, C, "gather", plen, part)
+    tests/test_hybrid_moe_kernel.py), and at the published HEAD — 192 =
+    64 rotary + 128, values 128 — on three layers, a chunk and a decode
+    step: the flat products' cut into heads and the rotary cut."""
+    svc, p, c = (("svc7", "params", C) if widths == "tiny"
+                 else ("svc192", "params192", C192))
+    _check_logits_through_the_cache(
+        request.getfixturevalue(svc), request.getfixturevalue(p), c,
+        "gather", plen, part)
 
 
 @pytest.mark.parametrize("fault", ["window_one_short", "no_sink",

@@ -60,11 +60,25 @@ def _config(c):
 CFG = _config(C)
 MODEL = lm.LatentMoeLM(CFG, max_len=MAX_LEN, kv_dtype=jnp.float32,
                        longest_chunk=64)
+# the published head: 128 lanes without position beside 64 rotary ones (192
+# a head in ``wqb``: not whole 128-lane tiles, which is what made the chip's
+# compiler turn ``wqb`` round; docs/generation.md "A weight reaches its
+# product as stored") and values of 128 (256 a head in ``wkvb``); two heads
+# are enough to catch a cut moved by a lane
+C192 = dict(C, num_attention_heads=2, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128)
+MODEL192 = lm.LatentMoeLM(_config(C192), max_len=MAX_LEN,
+                          kv_dtype=jnp.float32, longest_chunk=64)
 
 
 @pytest.fixture(scope="module")
 def params():
     return ref.init_params(3, C, "float32")
+
+
+@pytest.fixture(scope="module")
+def params192():
+    return ref.init_params(3, C192, "float32")
 
 
 def _service(params, monkeypatch=None, kernel=None, model=MODEL, **kw):
@@ -121,27 +135,35 @@ def _decode(svc, row, tok, pos, blocks, S=4):
     return int(nxt[row]), np.asarray(last[row])
 
 
-@pytest.mark.parametrize("kernel", ["gather", "paged"])
-@pytest.mark.parametrize("plen", [3, 16, 37, 70])
+@pytest.mark.parametrize("widths,kernel,plen", [
+    ("tiny", kernel, plen) for plen in (3, 16, 37, 70)
+    for kernel in ("gather", "paged")] + [
+    ("head192", "gather", 16), ("head192", "gather", 37)])
 def test_chunked_prefill_then_decode_match_reference_logits(
-        params, monkeypatch, kernel, plen):
+        request, monkeypatch, widths, kernel, plen):
     """Prefill through the chunk plan (every leftover length), then greedy
     decode steps through the latent cache, against the reference's full
     forward over the whole sequence (materialised attention).  ``gather``:
     the absorbed sums over the gathered pages; ``paged``: the absorbed
-    kernel (interpreted)."""
-    svc = _service(params, monkeypatch, kernel)
+    kernel (interpreted).  ``head192``: the published head sizes, 128 + 64
+    rotary and values of 128 — the flat product's cut into heads, its
+    rotary cut and ``wkvb``'s halves, a chunk and a decode step."""
+    p, c, model = (("params", C, MODEL) if widths == "tiny"
+                   else ("params192", C192, MODEL192))
+    params = request.getfixturevalue(p)
+    svc = _service(params, monkeypatch, kernel, model=model)
     assert svc.stats()["decode_kernel"] == kernel
     seq = [int(t) for t in np.random.default_rng(plen).integers(0, V, plen)]
     blocks = svc._alloc_reclaiming(blocks_for(plen + 5, 8))
     nxt, last = _prefill(svc, seq, blocks)
-    np.testing.assert_allclose(last, _ref_logits(params, seq, plen - 1)[0],
-                               atol=TOL, rtol=0)
+    np.testing.assert_allclose(
+        last, _ref_logits(params, seq, plen - 1, c=c)[0], atol=TOL, rtol=0)
     for _ in range(4):
         seq.append(nxt)
         nxt, last = _decode(svc, 2, seq[-1], len(seq) - 1, blocks)
         np.testing.assert_allclose(
-            last, _ref_logits(params, seq, len(seq) - 1)[0], atol=TOL, rtol=0)
+            last, _ref_logits(params, seq, len(seq) - 1, c=c)[0], atol=TOL,
+            rtol=0)
 
 
 def test_one_precision_down_is_outside_the_tolerance(params):

@@ -34,6 +34,13 @@ L, STEPS, MASK, MAX_LEN = 4, 4, 96, 128
 CFG = sm.SdarMoeConfig(max_position_embeddings=MAX_LEN, block_length=L,
                        denoising_steps=STEPS, mask_token_id=MASK, **C)
 MODEL = sm.SdarMoeLM(CFG, max_len=MAX_LEN, kv_dtype=jnp.float32)
+# the published head: 128 lanes, all rotary (the halves meet at lane 64);
+# two query heads over one KV head are enough to catch a cut moved by a lane
+C128 = dict(C, num_attention_heads=2, num_key_value_heads=1, head_dim=128)
+MODEL128 = sm.SdarMoeLM(
+    sm.SdarMoeConfig(max_position_embeddings=MAX_LEN, block_length=L,
+                     denoising_steps=STEPS, mask_token_id=MASK, **C128),
+    max_len=MAX_LEN, kv_dtype=jnp.float32)
 TOL = 2e-4      # float32 sums in another order, logits of spread ~1
 
 
@@ -42,19 +49,24 @@ def params():
     return ref.init_params(3, C, "float32")
 
 
-def _service(params, monkeypatch=None, kernel=None, **kw):
+@pytest.fixture(scope="module")
+def params128():
+    return ref.init_params(3, C128, "float32")
+
+
+def _service(params, monkeypatch=None, kernel=None, model=MODEL, **kw):
     if kernel is not None:
         monkeypatch.setenv("TPUMX_PALLAS", "1" if kernel == "paged" else "0")
     gc = dict(max_slots=4, block_size=8, num_blocks=64, seq_buckets=[16, 64])
     gc.update(kw)
-    return GenerationService(params, MODEL, GenerationConfig(**gc),
+    return GenerationService(params, model, GenerationConfig(**gc),
                              start=False)
 
 
-def _ref_logits(params, tokens, at0, dtype="float32"):
+def _ref_logits(params, tokens, at0, dtype="float32", c=C):
     toks = np.zeros(MAX_LEN, np.int32)
     toks[:len(tokens)] = tokens
-    return np.asarray(ref.logits(params, C, toks, len(tokens), at0, L,
+    return np.asarray(ref.logits(params, c, toks, len(tokens), at0, L,
                                  block_length=L, dtype=dtype))
 
 
@@ -79,14 +91,22 @@ def _prefill(svc, toks, blocks):
     return ctx
 
 
-@pytest.mark.parametrize("kernel", ["gather", "paged"])
-@pytest.mark.parametrize("plen", [3, 16, 37])
+@pytest.mark.parametrize("widths,kernel,plen", [
+    ("tiny", kernel, plen) for plen in (3, 16, 37)
+    for kernel in ("gather", "paged")] + [
+    ("head128", "gather", 16), ("head128", "gather", 37)])
 def test_prefill_then_block_passes_match_reference_logits(
-        params, monkeypatch, kernel, plen):
+        request, monkeypatch, widths, kernel, plen):
     """Prefill through the chunk plan, then block passes through the cache
     (0 to 4 MASKs a block, then its commit pass, then the next block),
-    against the reference's full forward at the same block states."""
-    svc = _service(params, monkeypatch, kernel)
+    against the reference's full forward at the same block states.  The
+    logits come back ``(S, L, vocab)`` from ``run_block``, which turns
+    round what the program hands back position-major.  ``head128``: the
+    published head size."""
+    p, c, model = (("params", C, MODEL) if widths == "tiny"
+                   else ("params128", C128, MODEL128))
+    params = request.getfixturevalue(p)
+    svc = _service(params, monkeypatch, kernel, model=model)
     assert svc.stats()["decode_kernel"] == kernel
     rng = np.random.default_rng(plen)
     seq = [int(t) for t in rng.integers(0, C["vocab_size"], plen)]
@@ -115,7 +135,8 @@ def test_prefill_then_block_passes_match_reference_logits(
             unmasked, _, lg = svc._programs.run_block(
                 svc._cache, tokens, positions, lengths, tables, flags,
                 np.asarray([0, 1, 0, 0], np.int32))
-            want = _ref_logits(params, seq[:ctx] + block, ctx)
+            want = _ref_logits(params, seq[:ctx] + block, ctx, c=c)
+            assert lg.shape == (S, L, C["vocab_size"])
             np.testing.assert_allclose(np.asarray(lg)[1], want, atol=TOL,
                                        rtol=0)
             # the program's choice is the reference's rule on its logits

@@ -7,7 +7,10 @@ runs the cell's traced run (``perfbench/run.py --trace 1``) in this process
 with its trace kept, then asks ``mxnet_tpu.observability.device_scopes`` for
 the table of the session's programs and prints device milliseconds by
 program kind and by scope, the share the resolver could name, the largest
-events it could not, and what building the table cost.  ``--keep DIR``
+events it could not, what building the table cost, and a line a program,
+"argument copies": every ``copy`` / ``transpose`` in its compiled text whose
+operand is a program argument (a weight turned round in every call:
+docs/generation.md "A weight reaches its product as stored").  ``--keep DIR``
 also writes the table as JSON, the programs' compiled texts and the trace
 there (gzip).  The cells whose metric set the benchmark's tests pin get
 their by-scope tables in PERF.md section 5 this way.
@@ -23,6 +26,18 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+
+
+def argument_copies_line(program, min_mb=1.0) -> str:
+    """"<kind>: argument copies: n, MB" of one ``ProgramTable``, and under
+    it each copy of ``min_mb`` or more: its bytes, scope and argument."""
+    copies = [c for c in program.argument_copies
+              if c.bytes >= min_mb * 2 ** 20]
+    lines = [f"{program.kind or program.module}: argument copies: "
+             f"{len(copies)}, {sum(c.bytes for c in copies) / 1e6:.1f} MB"]
+    lines += [f"  {c.bytes / 1e6:8.1f} MB  {c.scope or '(unscoped)':30s} "
+              f"{c.argument}  (%{c.name})" for c in copies]
+    return "\n".join(lines)
 
 
 def report(cell, keep=None, top=60, out=sys.stdout):
@@ -42,6 +57,8 @@ def report(cell, keep=None, top=60, out=sys.stdout):
           f"({ds.build_stats()}); trace read and resolved in "
           f"{time.perf_counter() - t0:.2f} s", file=out)
     print(ds.format_table(summary, top=top), file=out)
+    for program in table.programs:
+        print(argument_copies_line(program), file=out)
     print("unresolved events, largest first:", file=out)
     for name, ms in sorted(summary["unresolved"].items(),
                            key=lambda kv: -kv[1])[:25]:
