@@ -709,8 +709,16 @@ class GenerationService:
         self._cache = PagedKVCache(
             num_blocks=cfg.num_blocks, block_size=cfg.block_size,
             kv_dtype=cfg.kv_dtype, **spec)
-        self._cache.allocator.set_watermarks(cfg.watermark_high,
-                                             cfg.watermark_low)
+        # a kind that is a slot's state (docs/generation.md "Cache kinds")
+        # is sized by the slots and never grows: a row owns its one state
+        # from admission to release, so there is no headroom to keep and
+        # nothing for a watermark to preempt
+        self._state_kind = self._cache.kinds[0].state
+        if self._state_kind:
+            self._cache.allocator.set_watermarks(1.0, 1.0)
+        else:
+            self._cache.allocator.set_watermarks(cfg.watermark_high,
+                                                 cfg.watermark_low)
         # the kinds behind the first (docs/generation.md "Cache kinds"):
         # empty for every model whose layers are of one kind, and nothing
         # below that names them runs
@@ -723,7 +731,8 @@ class GenerationService:
         self._prefix = (PrefixCacheIndex(
             self._cache.allocator, cfg.block_size,
             capacity_blocks=cfg.prefix_cache_blocks)
-            if cfg.prefix_cache and not self._windows else None)
+            if cfg.prefix_cache and not self._windows
+            and not self._state_kind else None)
         if cfg.prefix_cache and self._windows:
             # a hit at position p would also need the window layers' last
             # positions before p, which their rows freed as they went: the
@@ -735,6 +744,15 @@ class GenerationService:
                 "frees the blocks behind its window, so no cached prefix "
                 "could serve them)", type(model).__name__,
                 [k.name for k in self._windows])
+        if cfg.prefix_cache and self._state_kind:
+            # a hit at position p would need the state as it stood AT p,
+            # and a row keeps only the state at its last position: the
+            # index would have to keep a snapshot with every prefix
+            logging.getLogger(__name__).info(
+                "%s keeps the state cache kind %s: no prefix reuse (a row "
+                "keeps its state at its last position alone, so no cached "
+                "prefix could serve one)", type(model).__name__,
+                [k.name for k in self._cache.kinds])
         self._pc_evictions_seen = 0
         self._programs = GenerationPrograms(params, model,
                                             mp_devices=cfg.mp_devices,
@@ -746,7 +764,7 @@ class GenerationService:
         # decode block-table widths: pow2 ladder up to the blocks needed to
         # address max_len positions (the cap itself kept, like batch_buckets)
         self._width_buckets = batch_buckets(
-            blocks_for(model_cfg.max_len, cfg.block_size))
+            self._cache.blocks_for(model_cfg.max_len))
         # a model whose kernels fetch live pages only, in prefill as in
         # decode, pays nothing for a table's width: one width, the widest,
         # and a program a chunk length instead of one a (length, width)
@@ -1002,11 +1020,11 @@ class GenerationService:
                 f"prompt ({prompt.size}) + max_new_tokens ({max_new}) = "
                 f"{total} exceeds the model's max_len "
                 f"{self._model_cfg.max_len}")
-        need = blocks_for(total, cfg.block_size)
-        if need > cfg.num_blocks - 1:
+        need = self._cache.blocks_for(total)
+        if need > self._cache.num_blocks - 1:
             raise ValueError(
                 f"request needs {need} cache blocks but the pool only has "
-                f"{cfg.num_blocks - 1} allocatable")
+                f"{self._cache.num_blocks - 1} allocatable")
         # overload accounting with the prefix cache on: blocks the index
         # would serve are not new demand — charge only the projected
         # uncached suffix plus one block of copy-on-write slack
@@ -1021,7 +1039,7 @@ class GenerationService:
             else cfg.default_deadline_ms
         deadline = None if ms is None else time.perf_counter() + ms / 1e3
 
-        budget = cfg.admission_budget * (cfg.num_blocks - 1)
+        budget = cfg.admission_budget * (self._cache.num_blocks - 1)
         with self._lock:
             if self._closed:
                 raise ServingClosedError("generation service is shut down")
@@ -1488,10 +1506,9 @@ class GenerationService:
         cfg = self._config
         if cfg.preemption:
             ctx = r.ctx_len if r.ctx_len > 0 else r.prompt_len
-            return blocks_for(
-                min(ctx + self._iter_span, r.prompt_len + r.max_new),
-                cfg.block_size)
-        return blocks_for(r.prompt_len + r.max_new, cfg.block_size)
+            return self._cache.blocks_for(
+                min(ctx + self._iter_span, r.prompt_len + r.max_new))
+        return self._cache.blocks_for(r.prompt_len + r.max_new)
 
     def _admit_locked(self) -> List[_GenRequest]:
         """Priority-class-then-FIFO admission: fill free slots while the
@@ -1502,7 +1519,7 @@ class GenerationService:
         nothing is running at all (the progress guarantee)."""
         cfg = self._config
         alloc = self._cache.allocator
-        total = cfg.num_blocks - 1
+        total = self._cache.num_blocks - 1
         admitted = []
         free = [i for i, s in enumerate(self._slots) if s is None]
         while free and self._waiting:
@@ -1521,13 +1538,13 @@ class GenerationService:
                 shared, cached = self._prefix.acquire(head.seq_tokens[:ctx])
             grow = need - len(shared)
             if cfg.preemption and any(s is not None for s in self._slots) \
-                    and alloc.num_used + grow > cfg.watermark_high * total:
+                    and alloc.num_used + grow > alloc.watermark_high * total:
                 # cache-only blocks are reclaimable headroom: evict before
                 # concluding the pool is too full to admit
-                over = alloc.num_used + grow - cfg.watermark_high * total
+                over = alloc.num_used + grow - alloc.watermark_high * total
                 if self._prefix is not None and over > 0:
                     self._prefix.evict_blocks(int(over) + 1)
-                if alloc.num_used + grow > cfg.watermark_high * total:
+                if alloc.num_used + grow > alloc.watermark_high * total:
                     if shared:
                         alloc.decref(shared)
                     break  # keep the growth headroom; readmit later
@@ -1771,7 +1788,6 @@ class GenerationService:
         one more block — oldest admitted first.  Exhaustion preempts the
         victim policy's pick; when the grower IS the pick, it preempts
         itself (it is the newest/lowest — latecomers yield)."""
-        cfg = self._config
         order = sorted(
             (i for i, r in enumerate(self._slots)
              if r is not None and r.state == _RUNNING),
@@ -1785,9 +1801,9 @@ class GenerationService:
             # positions); span 1 == the classic next-position arithmetic,
             # and the cap at prompt+max_new means single-token services
             # are byte-identical
-            need = blocks_for(
+            need = self._cache.blocks_for(
                 min(r.ctx_len + self._lead(r) + self._iter_span,
-                    r.prompt_len + r.max_new), cfg.block_size)
+                    r.prompt_len + r.max_new))
             while len(r.blocks) < need:
                 got = self._alloc_reclaiming(need - len(r.blocks))
                 if got is not None:
@@ -1806,15 +1822,13 @@ class GenerationService:
         minus the blocks the index projected to serve, plus CoW slack —
         a shared-prompt burst no longer rejects on demand the pool never
         actually sees.  Cache off: charge == the full worst case."""
-        bs = self._config.block_size
+        worst = self._cache.blocks_for
         total = 0
         for r in self._waiting:
-            total += (r.charged_blocks
-                      or blocks_for(r.prompt_len + r.max_new, bs))
+            total += r.charged_blocks or worst(r.prompt_len + r.max_new)
         for r in self._slots:
             if r is not None:
-                total += (r.charged_blocks
-                          or blocks_for(r.prompt_len + r.max_new, bs))
+                total += r.charged_blocks or worst(r.prompt_len + r.max_new)
         return total
 
     def _release_slot_locked(self, i: int, reason: str = _FINISHED,
@@ -1939,7 +1953,7 @@ class GenerationService:
                 fitting = [b for b in rungs if b <= rem]
                 tb = fitting[-1] if fitting else rungs[0]
                 take = min(rem, tb)
-                w = bucket_batch(blocks_for(off + tb, cfg.block_size),
+                w = bucket_batch(self._cache.blocks_for(off + tb),
                                  self._width_buckets)
                 chunks.append((off, take, tb, w))
                 off += take
@@ -1955,7 +1969,7 @@ class GenerationService:
             fitting = [b for b in rungs if b <= rem]
             tb = fitting[-1] if fitting else rungs[0]
             take = min(rem, tb)
-            w = bucket_batch(blocks_for(off + tb, cfg.block_size),
+            w = bucket_batch(self._cache.blocks_for(off + tb),
                              self._width_buckets)
             chunks.append((off, take, tb, w))
             off += take
@@ -1968,7 +1982,7 @@ class GenerationService:
         """Table width of a prompt padded whole to its rung: the blocks
         the rung spans, or the service's one width."""
         return self._width_buckets[0] if self._one_width \
-            else blocks_for(tb, self._config.block_size)
+            else self._cache.blocks_for(tb)
 
     def _prefill_signatures(self):
         """Every (T, W) prefill signature the chunk planner can emit —
@@ -2009,7 +2023,8 @@ class GenerationService:
                         tb = fitting[-1] if fitting else self._seq_buckets[0]
                         take = min(rem, tb)
                         out.add((tb, bucket_batch(
-                            blocks_for(off + tb, bs), self._width_buckets)))
+                            self._cache.blocks_for(off + tb),
+                            self._width_buckets)))
                         off += take
                         rem -= take
             # fully-cached prompts: the single-token logit recompute at
@@ -2018,8 +2033,9 @@ class GenerationService:
             tb0 = self._seq_buckets[0]
             for p in (() if self._block_len else
                       range(bs, self._prompt_buckets[-1] + 1, bs)):
-                out.add((tb0, bucket_batch(blocks_for(p - 1 + tb0, bs),
-                                           self._width_buckets)))
+                out.add((tb0, bucket_batch(
+                    self._cache.blocks_for(p - 1 + tb0),
+                    self._width_buckets)))
         return sorted(out)
 
     def _chunk_inputs(self, r: _GenRequest, off: int, take: int, tb: int,
@@ -2044,7 +2060,6 @@ class GenerationService:
     def _prefill(self, r: _GenRequest) -> None:
         if self._block_len:
             return self._block_prefill(r)
-        cfg = self._config
         next_tok = None
         # re-admission after preemption: replay the WHOLE cached context
         # (prompt + already-generated tokens) through the chunked-prefill
@@ -2072,7 +2087,7 @@ class GenerationService:
             start = ctx - 1
             tb0 = self._seq_buckets[0]
             plan = [(start, 1, tb0,
-                     bucket_batch(blocks_for(start + tb0, cfg.block_size),
+                     bucket_batch(self._cache.blocks_for(start + tb0),
                                   self._width_buckets))]
         elif cached > 0:
             # uncached suffix only, through the SAME (T, W) rung ladder
@@ -2251,7 +2266,7 @@ class GenerationService:
         positions = _np.where(lengths[:, None] > 0,
                               ctx[:, None] + _np.arange(T, dtype=_np.int32),
                               0)
-        w = width or bucket_batch(blocks_for(end, cfg.block_size),
+        w = width or bucket_batch(self._cache.blocks_for(end),
                                   self._width_buckets)
         tables = _np.zeros((S, w), _np.int32)
         for i, r in rows:
@@ -2934,8 +2949,7 @@ class GenerationService:
     def _live_blocks_locked(self) -> int:
         """Blocks holding WRITTEN context across the running slots (owned
         blocks minus reservation/growth headroom)."""
-        bs = self._config.block_size
-        return sum(blocks_for(r.ctx_len, bs)
+        return sum(self._cache.blocks_for(r.ctx_len)
                    for r in self._slots
                    if r is not None and r.ctx_len > 0)
 
@@ -2944,14 +2958,14 @@ class GenerationService:
         unlike ``allocator.occupancy()`` (owned blocks), reservation and
         growth headroom do not count.  The incremental-vs-reserve-ahead
         comparison in bench.py's ``overload_serving`` reads this."""
-        total = self._config.num_blocks - 1
+        total = self._cache.num_blocks - 1
         with self._lock:
             live = self._live_blocks_locked()
         return live / total if total else 0.0
 
     def _update_gauges_locked(self) -> None:
         alloc = self._cache.allocator
-        total = self._config.num_blocks - 1
+        total = self._cache.num_blocks - 1
         running = sum(1 for r in self._slots if r is not None)
         self._g_running.set(running)
         self._g_waiting.set(len(self._waiting))
@@ -3011,6 +3025,12 @@ class GenerationService:
             ttft = list(self._ttft)
             itl = list(self._itl)
         alloc = self._cache.allocator
+        if self._state_kind:
+            # two gauges among the counts (docs/observability.md): the
+            # slots whose state a row owns now, and what one slot holds
+            counts["state_slots_live"] = alloc.num_used
+            counts["state_bytes_per_slot"] = \
+                self._cache.nbytes() // self._cache.num_blocks
         pct = _smetrics.percentile
         return {
             "running": running,

@@ -82,17 +82,33 @@ class CacheKind:
     :func:`window_blocks` blocks whatever its length, its table is a RING
     (logical block ``b`` sits in column ``b % width``), and the kind is
     sized by slots where the others are sized by tokens.  ``span`` says
-    which members of :attr:`PagedKVCache.pools` are this kind's."""
+    which members of :attr:`PagedKVCache.pools` are this kind's.
+
+    ``state`` marks a kind that is no pages at all but a slot's recurrent
+    STATE (docs/generation.md "Cache kinds"): ``layout`` is then ``((name,
+    shape), ...)``, a pool is ``(n_layers, num_blocks, *shape)``, and the
+    one unit a row ever owns — whatever its length — is one index of the
+    second axis, handed out by the same :class:`BlockAllocator` (index 0 is
+    the scratch that idle rows point at).  Its "table" is one column wide
+    and holds that index; nothing grows, nothing is freed behind a
+    window, and ``num_blocks - 1`` is the number of slots."""
 
     def __init__(self, name, n_layers, layout, num_blocks, window, span,
-                 allocator=None):
+                 allocator=None, state=False, block_size=1):
         self.name = str(name)
         self.n_layers = int(n_layers)
         self.layout = layout
         self.num_blocks = int(num_blocks)
         self.window = int(window)
         self.span = span
+        self.state = bool(state)
+        self.block_size = int(block_size)
         self.allocator = allocator or BlockAllocator(self.num_blocks)
+
+    def blocks_for(self, n_positions: int) -> int:
+        """Units of this kind a row of ``n_positions`` owns: the blocks
+        that cover them, or — a state kind — its one slot."""
+        return 1 if self.state else blocks_for(n_positions, self.block_size)
 
 
 class BlockAllocator:
@@ -251,6 +267,13 @@ class PagedKVCache:
     tuple, kind after kind (``kinds[i].span`` says which).  A spec that
     names no kinds has one, ``kinds[0]``, and is exactly today's cache.
 
+    A spec whose ONLY kind is a state kind (``{"name", "n_layers", "state":
+    ((pool name, shape), ...), "dtype"}``: a recurrent model, no position
+    is kept) builds no paged pool and no allocator of token blocks: each
+    pool is ``(n_layers, rows + 1, *shape)`` for ``window_rows = (rows,
+    ...)``, ``allocator`` hands out the rows' indices (``num_blocks`` is
+    ``rows + 1``, index 0 the scratch) and :meth:`blocks_for` is 1.
+
     The arrays are owned functionally, as one tuple ``pools``: the engine
     threads it through its donated compiled programs and stores the
     returned (aliased) arrays back via :meth:`swap` — the pool is updated
@@ -279,6 +302,9 @@ class PagedKVCache:
             if kv_dtype is not None or pools is not None:
                 raise ValueError("a cache of several kinds names its pools "
                                  "kind by kind and is not quantized")
+            if any("state" in k for k in kinds):
+                self._init_state(kinds, window_rows, block_size, dtype)
+                return
             first, rest = kinds[0], kinds[1:]
             if first.get("window") or not all(k.get("window") for k in rest):
                 raise ValueError(
@@ -324,7 +350,7 @@ class PagedKVCache:
         name = kinds[0]["name"] if kinds else "kv"
         self.kinds = (CacheKind(name, n_layers, self.layout, self.num_blocks,
                                 0, slice(0, len(self.pools)),
-                                self.allocator),)
+                                self.allocator, block_size=self.block_size),)
         for k in (kinds or ())[1:]:
             rows, chunk = window_rows
             layout = _pool_layout(None, None, k["pools"])
@@ -335,8 +361,35 @@ class PagedKVCache:
                 jnp.zeros((int(k["n_layers"]), n, self.block_size, w), store)
                 for _, w in layout)
             self.kinds += (CacheKind(k["name"], k["n_layers"], layout, n,
-                                     k["window"],
-                                     slice(at, len(self.pools))),)
+                                     k["window"], slice(at, len(self.pools)),
+                                     block_size=self.block_size),)
+
+    def _init_state(self, kinds, window_rows, block_size, dtype):
+        """The cache of a spec whose one kind is a slot's state: no pages,
+        no token blocks — ``rows + 1`` states a layer (index 0 the scratch),
+        their indices under :attr:`allocator`."""
+        import jax.numpy as jnp
+
+        if len(kinds) != 1:
+            raise ValueError(
+                "a state kind stands alone: a state kind beside paged "
+                f"kinds in one model is not built, got "
+                f"{[k['name'] for k in kinds]}")
+        k = kinds[0]
+        self.kv_dtype = None
+        self.block_size = int(block_size)
+        self.dtype = jnp.dtype(dtype if dtype is not None else jnp.float32)
+        self.num_blocks = int(window_rows[0]) + 1
+        self.layout = tuple((str(n), tuple(int(d) for d in shape))
+                            for n, shape in k["state"])
+        self.pools = tuple(
+            jnp.zeros((int(k["n_layers"]), self.num_blocks) + shape,
+                      self.dtype) for _, shape in self.layout)
+        self.allocator = BlockAllocator(self.num_blocks)
+        self.kinds = (CacheKind(k["name"], k["n_layers"], self.layout,
+                                self.num_blocks, 0,
+                                slice(0, len(self.pools)), self.allocator,
+                                state=True),)
 
     @property
     def quantized(self) -> bool:
@@ -363,7 +416,9 @@ class PagedKVCache:
         return tuple(self.pools[0].shape)
 
     def blocks_for(self, n_positions: int) -> int:
-        return blocks_for(n_positions, self.block_size)
+        """Units of the first kind (the one under :attr:`allocator`) that a
+        row of ``n_positions`` owns."""
+        return self.kinds[0].blocks_for(n_positions)
 
     def max_positions(self) -> int:
         """Positions one request could address if it owned every block."""

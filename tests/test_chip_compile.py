@@ -857,3 +857,85 @@ def test_hybrid_step_program_compiles_at_the_cells_shapes(one_chip,
     # and ``wk`` are multiplied flat and the RESULT is cut into heads of 192
     _no_argument_copies(text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+# -- brumby-14b: power retention over a slot's state (PR 40) ----------------
+
+def _retention_cell(sds, n_layers=2):
+    from mxnet_tpu.parallel import retention_lm as rl
+
+    cfg = rl.RetentionConfig(num_hidden_layers=n_layers)
+    model = rl.RetentionLM(cfg, max_len=32768)
+    params = {k: sds(s, jnp.bfloat16)
+              for k, s in rl.retention_param_shapes(cfg).items()}
+    (kind,) = model.cache_spec()["kinds"]
+    pools = tuple(sds((n_layers, 25) + shape, jnp.float32)
+                  for _, shape in kind["state"])
+    return model, params, pools
+
+
+@pytest.mark.parametrize("B,T", [(24, 1), (1, 128), (1, 512)],
+                         ids=["decode", "prefill128", "prefill512"])
+def test_retention_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch,
+                                                       B, T):
+    """``_ret_call_decode`` (24 rows x 8 KV heads, a head's state of 65 x
+    128 x 128 float32 a block) and ``_ret_call_t<T>_prefill`` (a chunk of
+    the 5 query heads of a KV head) at ``brumby-14b``'s widths: the pools
+    are updated in place, never copied."""
+    from mxnet_tpu.ops import retention as rt
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pools = tuple(sds((6, 25) + s, jnp.float32)
+                  for _, s in rt.state_shapes(8, 128, 128))
+    assert [p.shape for p in pools] == [(6, 25, 8, 65, 136, 128)]
+    f32 = jnp.float32
+
+    def fn(q, k, v, g, fresh, pool, slots):
+        return rt.retention(q, k, v, g, fresh, pool, slots, layer=3,
+                            eps=1e-6, kernel=True)
+
+    text = jax.jit(fn, donate_argnums=(5,)).lower(
+        sds((B, T, 40, 128), f32), sds((B, T, 8, 128), f32),
+        sds((B, T, 8, 128), f32), sds((B, T, 8), f32), sds((B,), jnp.bool_),
+        *pools, sds((B,), jnp.int32)).compile().as_text()
+    assert ("_ret_call_decode" if T == 1 else f"_ret_call_t{T}_prefill") \
+        in text
+    assert not [ln for ln in text.splitlines()
+                if "f32[6,25,8,65,136" in ln and " copy(" in ln]
+
+
+@pytest.mark.parametrize("S,T", [(24, 1), (1, 128), (1, 512)],
+                         ids=["decode", "prefill128", "prefill512"])
+def test_retention_step_program_compiles_at_the_cells_shapes(one_chip,
+                                                             monkeypatch, S,
+                                                             T):
+    """The cell's three programs as the service dispatches them, at depth
+    2 (layers repeat): ``gen_decode`` (24 rows) and ``gen_prefill`` (a
+    128- and a 512-token chunk) over the state kind's one pool and a
+    one-column table, the pool updated in place, every weight read as
+    stored, the counts handed back."""
+    from mxnet_tpu.serving.generation import programs as gp
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    model, params, pools = _retention_cell(sds)
+    fn = jax.jit(functools.partial(gp._model_step, model=model,
+                                   attention_kernel="paged"),
+                 donate_argnums=(1,))
+    compiled = fn.lower(
+        params, pools, sds((S, T), jnp.int32), sds((S, T), jnp.int32),
+        sds((S,), jnp.int32), sds((S, 1), jnp.int32),
+        sds((S,), jnp.uint32), sds((S,), jnp.uint32), sds((S,), jnp.float32),
+        sds((S,), jnp.int32), sds((S,), jnp.float32)).compile()
+    text = compiled.as_text()
+    name = "_ret_call_decode" if T == 1 else f"_ret_call_t{T}_prefill"
+    assert text.count(name) >= 2
+    # (the kernel's first result IS its pool operand: aliased)
+    makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
+              for ln in text.splitlines() if "= f32[2,25,8,65,136,128]" in ln}
+    assert makers <= _IN_PLACE | {"get-tuple-element"}, makers
+    _no_argument_copies(text)
+    assert "retention_decode_rows" in model.counters
+    # a 512-token chunk's temporaries: the reckoning was 1-1.5 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
