@@ -1769,8 +1769,11 @@ class GenerationService:
         # preemption — dropping reusable history is strictly cheaper than
         # re-prefilling a live request
         if self._prefix is not None:
-            while alloc.above_low() and self._prefix.evict_blocks(1):
-                pass
+            # the whole crossing in one call: one walk of the index
+            asked = alloc.blocks_above_low()
+            with _obs.span("serving.watermark", cat="serving",
+                           args={"asked": asked}) as sp:
+                sp.args["freed"] = self._prefix.evict_blocks(asked)
             if not alloc.above_high():
                 return
         while alloc.above_low():
@@ -3063,6 +3066,8 @@ class GenerationService:
                 "prefill_tokens": counts["prefill_tokens"],
                 "cow_copies": counts["cow_copies"],
                 "evictions": self._prefix.evictions,
+                "evict_walks": self._prefix.evict_walks,
+                "evict_scanned": self._prefix.evict_scanned,
             }),
             "ttft_ms": {"p50": _ms(pct(ttft, 50)), "p99": _ms(pct(ttft, 99))},
             "inter_token_ms": {"p50": _ms(pct(itl, 50)),

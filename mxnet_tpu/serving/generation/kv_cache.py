@@ -161,6 +161,21 @@ class BlockAllocator:
         """Occupancy strictly above the low watermark (keep preempting)."""
         return self.occupancy() > self.watermark_low
 
+    def blocks_above_low(self) -> int:
+        """Blocks to free before :meth:`above_low` reads False: the count
+        a loop freeing one block a turn under that test would free."""
+        total = self.num_blocks - 1
+        if not total:
+            return 0
+        used = self.num_used
+        n = max(0, used - int(self.watermark_low * total))
+        # settle on the division above_low() makes, digit for digit
+        while n and (used - n + 1) / total <= self.watermark_low:
+            n -= 1
+        while (used - n) / total > self.watermark_low:
+            n += 1
+        return n
+
     @property
     def num_free(self) -> int:
         with self._lock:

@@ -136,6 +136,170 @@ def test_allocator_num_shared():
     a.free(blocks)
 
 
+# -- eviction: the one walk against the walk a victim --------------------------------
+def _walk_victim(entries, refcount, protect=()):
+    """The walk the index made for EVERY victim before it found a call's
+    victims in one (prefix_cache.py before PR 41, as it stood): the least
+    recently used cache-only leaf, the first strict minimum in dict order."""
+    victim = None
+    for e in entries.values():
+        if e.children or e.key in protect:
+            continue
+        if refcount(e.block) != 1:
+            continue  # some request still reads it: evicting frees nothing
+        if victim is None or e.tick < victim.tick:
+            victim = e
+    return victim
+
+
+class _WalkAVictim(PrefixCacheIndex):
+    """The oracle: the same index, its victims found a walk apiece."""
+
+    def _evict_locked(self, n, protect=()):
+        freed = 0
+        while freed < n:
+            victim = _walk_victim(self._entries, self._alloc.refcount,
+                                  protect)
+            if victim is None:
+                break
+            del self._entries[victim.key]
+            if victim.parent is not None:
+                victim.parent.children -= 1
+            self._alloc.decref([victim.block])
+            self.evictions += 1
+            freed += 1
+        return freed
+
+
+class _Pair:
+    """An index and the oracle over allocators of their own, fed one
+    history: block ids, free lists and entries must never part."""
+
+    def __init__(self, num_blocks, capacity):
+        self.allocs = [BlockAllocator(num_blocks) for _ in range(2)]
+        self.idx = [cls(a, block_size=4, capacity_blocks=capacity)
+                    for cls, a in zip((PrefixCacheIndex, _WalkAVictim),
+                                      self.allocs)]
+
+    def both(self, fn):
+        got, want = [fn(i, a) for i, a in zip(self.idx, self.allocs)]
+        assert got == want
+        return got
+
+    def check(self):
+        new, old = self.idx
+        # the victims and their ORDER: a freed block goes to the tail
+        assert self.allocs[0]._free == self.allocs[1]._free
+        assert self.allocs[0]._ref == self.allocs[1]._ref
+        assert new.evictions == old.evictions
+        assert list(new._entries) == list(old._entries)
+        assert [(e.block, e.tick, e.children) for e in new._entries.values()] \
+            == [(e.block, e.tick, e.children) for e in old._entries.values()]
+
+
+def _documents(rs, n=6):
+    """Token sequences that share prefixes of whole blocks: a tree with
+    forks, so an eviction can make a parent the next victim."""
+    docs = [rs.randint(0, 50, 40)]
+    for _ in range(n - 1):
+        base = docs[rs.randint(len(docs))]
+        cut = 4 * rs.randint(0, 6)
+        docs.append(np.concatenate([base[:cut], rs.randint(50, 99, 40 - cut)]))
+    return docs
+
+
+@pytest.mark.parametrize("capacity", [0, 12])
+@pytest.mark.parametrize("seed", range(6))
+def test_one_walk_evicts_what_a_walk_a_victim_evicted(seed, capacity):
+    """Random histories of insert / acquire / decref / free /
+    evict_blocks(n): the blocks evicted, their order, ``evictions`` and the
+    surviving keys equal the old walk's at every step."""
+    rs = np.random.RandomState(seed)
+    pair = _Pair(48, capacity)
+    docs = _documents(rs)
+    held = []          # block lists some "request" still references
+    within_call = skipped_held = ties = 0
+    for _ in range(400):
+        op = rs.choice(["serve", "serve", "touch", "release", "release",
+                        "evict", "coarsen"])
+        if op == "serve":
+            # a request: share what is cached, allocate the rest, index it
+            toks = docs[rs.randint(len(docs))][:rs.randint(4, 41)]
+            shared = pair.both(lambda i, a: i.acquire(toks))[0]
+            need = blocks_for(len(toks), 4) - len(shared)
+            fresh = pair.both(lambda i, a: a.allocate(need))
+            if fresh is None:
+                pair.both(lambda i, a: a.decref(shared))
+            else:
+                pair.both(lambda i, a: i.insert(toks, shared + fresh))
+                held.append(shared + fresh)
+        elif op == "touch":
+            toks = docs[rs.randint(len(docs))]
+            blocks = pair.both(lambda i, a: i.acquire(toks))[0]
+            if blocks:
+                held.append(blocks)
+        elif op == "release" and held:
+            blocks = held.pop(rs.randint(len(held)))
+            if rs.randint(2):
+                pair.both(lambda i, a: a.free(blocks))
+            else:
+                pair.both(lambda i, a: a.decref(blocks))
+        elif op == "coarsen":
+            # equal ticks on leaves of different chains (a monotone map
+            # keeps a parent no older than its child)
+            for i in pair.idx:
+                for e in i._entries.values():
+                    e.tick -= e.tick % 4
+        elif op == "evict":
+            new, old = pair.idx
+            leaves = [e for e in old._entries.values() if not e.children]
+            ticks = [e.tick for e in leaves]
+            ties += len(set(ticks)) < len(ticks)
+            skipped_held += any(
+                pair.allocs[1].refcount(e.block) > 1 for e in leaves)
+            inner = {k for k, e in old._entries.items() if e.children}
+            n = int(rs.randint(1, old.num_reclaimable() + 3))
+            walks = new.evict_walks
+            freed = pair.both(lambda i, a: i.evict_blocks(n))
+            assert freed <= n and new.evict_walks == walks + 1
+            # a leaf's eviction made its parent a victim of the same call
+            within_call += bool(inner - set(old._entries))
+        pair.check()
+    # the histories reach the cases the equality is claimed for
+    assert within_call and skipped_held and ties
+    for blocks in held:
+        pair.both(lambda i, a: a.free(blocks))
+    left = pair.idx[0].num_blocks
+    assert pair.both(lambda i, a: i.evict_blocks(left + 5)) == left
+    pair.check()
+    assert not pair.idx[0]._entries and not pair.allocs[0]._ref
+
+
+def test_a_crossings_cost_is_one_walk():
+    """``k`` evictions over an index of ``m`` entries visit the entries
+    once and then a parent a victim: no clock needed."""
+    alloc = BlockAllocator(512)
+    idx = PrefixCacheIndex(alloc, block_size=4)
+    rs = np.random.RandomState(0)
+    for _ in range(40):                      # 40 chains of 10 blocks
+        blocks = alloc.allocate(10)
+        idx.insert(rs.randint(0, 1000, 40), blocks)
+        alloc.free(blocks)
+    m, k = idx.num_blocks, 150
+    assert m == 400
+    assert idx.evict_blocks(k) == k
+    st = idx.stats()
+    assert st["evictions"] == k and st["evict_walks"] == 1
+    assert m <= st["evict_scanned"] <= m + k
+    # a call that finds nothing walks once and frees nothing; one that
+    # asks for nothing does not walk
+    held = alloc.allocate(4)
+    lone = PrefixCacheIndex(alloc, block_size=4)
+    lone.insert(np.arange(16), held)
+    assert lone.evict_blocks(3) == 0 and lone.evict_walks == 1
+    assert lone.evict_blocks(0) == 0 and lone.evict_walks == 1
+
+
 # -- hit-vs-miss bit-identity -------------------------------------------------------
 @pytest.mark.parametrize("variant", ["f32", "bf16", "int8"])
 def test_hit_vs_miss_greedy_bit_identity(params, variant):
@@ -220,6 +384,65 @@ def test_lru_eviction_under_watermark_pressure(params):
     assert stats["prefix_cache"]["evictions"] >= 1
     # the pool itself never exceeded its bound (sanity)
     assert stats["kv_blocks"]["used"] <= stats["kv_blocks"]["total"]
+
+
+def _copy_of(index, alloc):
+    """The oracle over a copy of an index's and its allocator's state."""
+    twin = BlockAllocator(alloc.num_blocks, alloc.watermark_high,
+                          alloc.watermark_low)
+    twin._free, twin._ref = list(alloc._free), dict(alloc._ref)
+    out = _WalkAVictim(twin, index.block_size)
+    for e in index._entries.values():
+        c = out._entries[e.key] = type(e)(
+            e.key, e.block, e.parent and out._entries[e.parent.key],
+            e.tick, e.seq)
+        c.children = e.children
+    return out, twin
+
+
+def test_a_crossing_is_one_walk_and_the_loops_victims(params):
+    """Rows that grow push the pool over ``watermark_high``: the index
+    evicts down to ``watermark_low`` in ONE walk, and frees the blocks, in
+    the order, that ``evict_blocks(1)`` in a loop frees on a copy."""
+    svc = GenerationService(params, CFG,
+                            _gc(num_blocks=41, max_new_tokens=48,
+                                preemption=True, prefix_cache=True),
+                            start=False)
+    alloc, index = svc._cache.allocator, svc._prefix
+    inner, crossings = svc._watermark_preempt_locked, []
+
+    def crossing():
+        if not alloc.above_high():
+            return inner()
+        oracle, twin = _copy_of(index, alloc)
+        while twin.above_low() and oracle.evict_blocks(1):
+            pass
+        n_free, walks = len(alloc._free), index.evict_walks
+        inner()
+        crossings.append((alloc._free[n_free:], twin._free[n_free:],
+                          alloc._ref == twin._ref,
+                          index.evict_walks - walks, alloc.above_low()))
+
+    svc._watermark_preempt_locked = crossing
+    svc.start()
+    rs = np.random.RandomState(5)
+    try:
+        for h in [svc.submit(rs.randint(0, CFG.vocab, 10), max_new_tokens=40)
+                  for _ in range(12)]:
+            assert len(h.result(180)) == 40
+        stats = svc.stats()
+    finally:
+        svc.stop()
+    assert len(crossings) >= 3
+    for freed, want, same_in_use, walks, still_above in crossings:
+        assert freed == want and len(freed) >= 7
+        assert same_in_use and walks == 1 and not still_above
+    pc = stats["prefix_cache"]
+    assert stats["counts"]["preempted"] == 0
+    assert pc["evictions"] >= sum(len(c[0]) for c in crossings)
+    assert pc["evict_walks"] >= len(crossings)
+    # a walk visits at most the pool's 40 blocks, then a parent a victim
+    assert pc["evict_scanned"] <= pc["evict_walks"] * 40 + pc["evictions"]
 
 
 def test_preemption_decref_and_resume_rehit(params):

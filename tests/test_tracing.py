@@ -808,8 +808,12 @@ def _serve(params, prompts, **kw):
 
 def test_engine_iteration_spans_children_and_phase_ms(params):
     rs = np.random.RandomState(5)
-    prompts = [rs.randint(0, CFG.vocab, n) for n in (5, 11, 20, 7)]
-    (_, stats), events = _profiled(lambda: _serve(params, prompts))
+    prompts = [rs.randint(0, CFG.vocab, n)
+               for n in (5, 11, 20, 7, 13, 9, 17, 6)]
+    # a pool this small crosses its high watermark as the rows grow: the
+    # prefix index then evicts under a span of its own
+    (_, stats), events = _profiled(lambda: _serve(
+        params, prompts, preemption=True, num_blocks=10))
     its = [e for e in events if e["name"] == "serving.iteration"]
     # the engine thread's spans (warm-up ran the programs on this one)
     mine = [e for e in events if e["name"].startswith("serving.")
@@ -826,8 +830,13 @@ def test_engine_iteration_spans_children_and_phase_ms(params):
     for child in ("serving.schedule", "serving.decode.build",
                   "serving.prefill", "serving.decode", "serving.emit"):
         assert parent_of[child] == {"serving.iteration"}, child
-    for child in ("serving.evict", "serving.admit"):
+    for child in ("serving.evict", "serving.watermark", "serving.admit"):
         assert parent_of[child] == {"serving.schedule"}, child
+    crossings = [e["args"] for e in mine if e["name"] == "serving.watermark"]
+    assert all(c["asked"] == c["freed"] > 0 for c in crossings)
+    assert sum(c["freed"] for c in crossings) \
+        <= stats["prefix_cache"]["evictions"]
+    assert len(crossings) <= stats["prefix_cache"]["evict_walks"]
     assert parent_of["serving.step.dispatch"] == parent_of[
         "serving.step.sync"] == {"serving.prefill", "serving.decode"}
     # the children of an iteration lie inside it and add up to no more
