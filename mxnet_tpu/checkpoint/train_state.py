@@ -164,6 +164,8 @@ def restore_train_state(mod, info, arrays, opt_tree) -> ResumePoint:
         mod._sync_params_from_exec()
     if opt_tree is not None and getattr(mod, "_updater", None) is not None:
         mod._updater.states = _states_from_host(opt_tree)
+        if hasattr(mod, "_drop_fused_plan"):
+            mod._drop_fused_plan()  # the fused step's plan held the old ones
     meta = info.meta
     opt_meta = meta.get("optimizer")
     if opt_meta and getattr(mod, "_optimizer", None) is not None:

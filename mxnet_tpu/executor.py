@@ -15,6 +15,7 @@ reference's in-place aux mutation.
 """
 from __future__ import annotations
 
+import operator
 import threading
 import warnings
 from typing import Dict, List, Optional
@@ -43,6 +44,19 @@ __all__ = ["Executor", "compile_cache_stats", "reset_compile_cache_stats"]
 # by snapshotting misses across a workload (mxnet_tpu.serving stats use it)
 _cache_stats = {"hits": 0, "misses": 0}
 _cache_by_site: dict = {}
+# the fused step's plan (docs/fused_step.md): a warm fit reads builds 0,
+# uniquify runs 0 and one reuse a step
+_PLAN_HELP = {
+    "fused_plan_builds": "Fused step plans built (first step, rebind, "
+                         "another optimizer, multipliers, scaler, "
+                         "telemetry, a loaded state)",
+    "fused_plan_reuses": "Fused steps launched from a kept plan with every "
+                         "donated array the last launch's own",
+    "fused_uniquify_runs": "Fused steps that checked their donated buffers "
+                           "for aliases (an array came from outside the "
+                           "last launch)",
+}
+_plan_stats = dict.fromkeys(_PLAN_HELP, 0)
 _cache_stats_lock = threading.Lock()
 
 
@@ -50,13 +64,16 @@ def compile_cache_stats() -> dict:
     """Process-wide executor compile-cache counters ({"hits", "misses"}),
     plus a ``"by_site"`` breakdown per program kind (fwd/fwdbwd/bwdg/
     fused_step).  A miss is a program compile (new ``_jit_cache``
-    signature); a hit reuses an already-compiled program.  Under
+    signature); a hit reuses an already-compiled program.  Beside them
+    the fused step's plan counters ``fused_plan_builds`` /
+    ``fused_plan_reuses`` / ``fused_uniquify_runs`` (docs/fused_step.md).  Under
     ``TPUMX_EXPLAIN_RECOMPILES``/``TPUMX_FREEZE_COMPILES`` every miss is
     additionally explained (and, post-warmup, refused) by
     :mod:`mxnet_tpu.observability.recompile`."""
     with _cache_stats_lock:
         out = dict(_cache_stats)
         out["by_site"] = {k: dict(v) for k, v in _cache_by_site.items()}
+        out.update(_plan_stats)
         return out
 
 
@@ -65,6 +82,8 @@ def reset_compile_cache_stats() -> None:
         _cache_stats["hits"] = 0
         _cache_stats["misses"] = 0
         _cache_by_site.clear()
+        for k in _plan_stats:
+            _plan_stats[k] = 0
 
 
 _recompile_mod = None
@@ -93,6 +112,65 @@ def _note_cache(hit: bool, site=None, key=None) -> None:
         _recompile_mod.note_miss(site, key)
 
 
+def _note_plan(what: str) -> None:
+    """Count a step plan's build, reuse or aliasing check
+    (:func:`compile_cache_stats`, and ``<what>_total`` in
+    ``observability.registry()``)."""
+    with _cache_stats_lock:
+        _plan_stats[what] += 1
+    from .observability import registry as _registry
+
+    _registry().counter(what + "_total", help=_PLAN_HELP[what]).inc()
+
+
+class _Fixed:
+    """A state leaf that is no ``NDArray`` (a plain number): read as it is
+    at every launch and never written, as ``optimizer._unpack_state_into``
+    leaves it."""
+
+    __slots__ = ("value",)
+
+    def __init__(self, value):
+        self.value = value
+
+    _data = property(lambda self: self.value, lambda self, v: None)
+
+
+def _state_holders(s):
+    """An optimizer state as ``optimizer._pack_state`` lays it out, with a
+    holder in each leaf's place (the ``NDArray`` itself): what a plan reads
+    at launch and writes when the step lands."""
+    if isinstance(s, (tuple, list)):
+        return tuple(_state_holders(x) for x in s)
+    return s if s is None or isinstance(s, NDArray) else _Fixed(s)
+
+
+class _FusedPlan:
+    """What :meth:`Executor.plan_fused_step` keeps for
+    :meth:`Executor.run_fused_step` (docs/fused_step.md "The step plan").
+    ``guard`` and ``states_of`` are its owner's (``Module``): what the plan
+    was built under, compared before each reuse."""
+
+    __slots__ = ("fn", "key", "site", "lookup_counted", "optimizer",
+                 "indices", "num_steps", "scaler", "tele_on", "gnames",
+                 "d_h", "s_tree", "b_names",
+                 "o_names", "a_names", "b_h", "r_h", "spmd", "ndev", "shard",
+                 "repl", "d_shardings", "seen_d", "seen_r", "ahead", "guard",
+                 "states_of")
+
+    def scalar(self, name, value):
+        """One of a step's scalars (``lr`` / ``wd`` / ``t``: floats, or a
+        tuple of them an inner step) as the program's argument: the device
+        array made for this value before — at the step before (a rate that
+        has not changed), or ahead of this one while the device ran (the
+        count a step on) — else a new one, kept for the next step."""
+        held = self.ahead.get(name)
+        if held is None or held[0] != value:
+            held = self.ahead[name] = (value, jax.device_put(
+                _np.asarray(value, _np.float32)))
+        return held[1]
+
+
 def _ones_cotangent(x):
     if jnp.issubdtype(x.dtype, jnp.inexact):
         return jnp.ones_like(x)
@@ -117,6 +195,7 @@ class Executor:
         self._monitor_callback = None
         self._jit_cache: Dict[tuple, object] = {}
         self._fused_probe = None  # (program, arg shapes) of the last fused_step
+        self._fused_plan = None  # the kept step plan (plan_fused_step)
         # SPMD data-parallel annotation (set_spmd): when a mesh is attached,
         # fused_step compiles ONE shard_map program over it — batch args
         # sharded on the dp axis, params/optimizer state replicated+donated,
@@ -1006,7 +1085,7 @@ class Executor:
                                                donate_argnums=(0, 1, 2))
             else:
                 self._jit_cache[key] = jax.jit(fused, donate_argnums=(0, 1, 2))
-        return self._jit_cache[key]
+        return self._jit_cache[key], key
 
     def fused_step(self, optimizer, states: Dict[str, object],
                    updates, feed: Optional[Dict[str, object]] = None,
@@ -1042,90 +1121,56 @@ class Executor:
         input/output.  ``multi_precision`` optimizers whose states carry
         ``(master_f32, inner)`` pytrees (low-precision weights) update the
         f32 master in-program and recast the weight from it each step.
+
+        This is :meth:`plan_fused_step` then :meth:`run_fused_step`: a
+        caller that steps again and again (``Module``) keeps the plan and
+        calls the second alone (docs/fused_step.md "The step plan").
         """
-        from . import engine as _engine
-        from .optimizer import _unpack_state_into
-
-        # two spans, so that a device-idle gap names its owner: the host
-        # work before dispatch, and the dispatch itself
-        with _tracing.span("executor.feed", cat="executor"):
-            fn, args, tele_on, rng = self._fused_feed(
-                optimizer, states, updates, feed, num_steps, kvstore,
-                loss_scaler)
-        with _tracing.span("executor.fused_step", cat="executor"):
-            res = fn(*args)
-        gnames = self._grad_arg_names
-        if tele_on:
-            res, tele_vals = res[:-1], res[-1]
-            self._note_telemetry(tele_vals)
-        if loss_scaler is None:
-            outs, aux_updates, new_grads, new_p, new_s = res
-        else:
-            outs, aux_updates, new_grads, new_p, new_s, new_sc = res
-            loss_scaler.set_state(new_sc)
-        self._outputs = [NDArray(o) for o in outs]
-        for k, v in aux_updates.items():
-            self.aux_dict[k]._data = v
-        for n in gnames:
-            self.arg_dict[n]._data = new_p[n]
-            self.grad_dict[n]._data = new_grads[n]
-            _unpack_state_into(states[n], new_s[n])
-        self._cached_grads = None
-        self._last_rng = rng
-        if _engine.is_naive():  # NaiveEngine forces sync, as everywhere else
-            for o in self._outputs:
-                o.wait_to_read()
-            for n in gnames:
-                self.arg_dict[n].wait_to_read()
-        if self._monitor_callback is not None:
-            for name, out in zip(self._out_names, self._outputs):
-                self._monitor_callback(name, out)
-        return self._outputs
-
-    def _fused_feed(self, optimizer, states, updates, feed, num_steps,
-                    kvstore, loss_scaler):
-        """The host part of :meth:`fused_step` before dispatch: checks, the
-        fed batch, the step's scalars, the cached program and its
-        arguments (placed on the mesh under SPMD, donated buffers made
-        unique).  Returns ``(fn, args, telemetry_on, rng)``."""
-        from . import engine as _engine
-        from .optimizer import (_pack_state, fused_counts_uniform,
-                                fused_update_plan, uniquify_donated)
-
-        if self._grouped is not None:
-            raise MXNetError("fused_step does not support group2ctx placement")
-        unames = [n for n, _ in updates]
-        if set(unames) != set(self._grad_arg_names):
-            raise MXNetError(
-                "fused_step: updates must cover exactly the gradient-taking "
-                f"arguments {self._grad_arg_names}, got {sorted(unames)}")
-        for k, v in (feed or {}).items():
-            if k not in self.arg_dict:
-                raise MXNetError(f"fused_step: unknown argument {k!r}")
-            self.arg_dict[k]._data = v._data if isinstance(v, NDArray) \
-                else jnp.asarray(v)
-        if num_steps is None:
-            num_steps = _engine.fusion_hint()
-        num_steps = max(1, int(num_steps))
-        if not fused_counts_uniform(optimizer, [idx for _, idx in updates]):
+        plan = self.plan_fused_step(optimizer, states, updates, num_steps,
+                                    kvstore, loss_scaler)
+        if not self.run_fused_step(plan, feed):
             raise MXNetError(
                 "fused_step: params carry mixed update counts; use the "
                 "legacy per-param update path")
-        lr_vec, wd, t_vec, mults_by_idx = fused_update_plan(
-            optimizer, [idx for _, idx in updates], num_steps)
+        return self._outputs
+
+    def plan_fused_step(self, optimizer, states, updates,
+                        num_steps: Optional[int] = None, kvstore=None,
+                        loss_scaler=None) -> "_FusedPlan":
+        """Everything a fused step derives from what does not change from
+        one step to the next (docs/fused_step.md "The step plan"): the
+        checks, the multipliers, the master-weight and state layouts, the
+        program and its key, and the ``NDArray`` HOLDERS whose ``_data`` are
+        the program's arguments and take its results — holders and not
+        arrays, so that ``set_params``, a loaded state or a caller's write
+        to ``arr._data`` between two steps is what the next step reads.
+        Arguments as :meth:`fused_step`'s.  The plan is kept as
+        ``self._fused_plan`` until something that it depends on changes:
+        who changes it drops it (``Module._drop_fused_plan``,
+        :meth:`set_monitor_callback`; a rebind makes a new executor)."""
+        from . import engine as _engine
+        from .observability import telemetry as _obs_tele
+        from .optimizer import _pack_state, fused_mults
+
+        if self._grouped is not None:
+            raise MXNetError("fused_step does not support group2ctx placement")
+        gnames = self._grad_arg_names
+        if {n for n, _ in updates} != set(gnames):
+            raise MXNetError(
+                "fused_step: updates must cover exactly the gradient-taking "
+                f"arguments {gnames}, got {sorted(n for n, _ in updates)}")
+        if num_steps is None:
+            num_steps = _engine.fusion_hint()
+        num_steps = max(1, int(num_steps))
+        indices = [idx for _, idx in updates]
+        mults_by_idx = fused_mults(optimizer, indices)
         mults_by_name = {n: mults_by_idx[idx] for n, idx in updates}
         spmd = self._spmd_total() > 1
         # static per-param master-weight layout (create_state_multi_precision
         # returns (master_f32, inner) exactly when _needs_master holds)
         master_names = frozenset(
-            n for n, _ in updates
-            if optimizer._needs_master(self.arg_dict[n]))
-        from .observability import telemetry as _obs_tele
-
+            n for n in gnames if optimizer._needs_master(self.arg_dict[n]))
         tele_on = _obs_tele.enabled()
-        gnames = self._grad_arg_names
-        pvals = {n: self.arg_dict[n]._data for n in gnames}
-        gvals = {n: self.grad_dict[n]._data for n in gnames}
         svals = {n: _pack_state(states[n]) for n in gnames}
         state_specs = None
         if spmd and self._spmd_param_specs:
@@ -1145,76 +1190,208 @@ class Executor:
                     svals[n])
 
             state_specs = {n: _sspecs(n) for n in gnames}
-        fn = self._get_fused_step(optimizer, mults_by_name, num_steps,
-                                  kvstore=kvstore if spmd else None,
-                                  scaler=loss_scaler,
-                                  master_names=master_names,
-                                  telemetry=tele_on,
-                                  state_specs=state_specs)
-        other = {n: self.arg_dict[n]._data for n in self._arg_names
-                 if n not in pvals}
-        aux_vals = {n: self.aux_dict[n]._data for n in self._aux_names}
-        rng = _random.next_key()
-        sc_args = () if loss_scaler is None else (loss_scaler.state(),)
+        fn, key = self._get_fused_step(
+            optimizer, mults_by_name, num_steps,
+            kvstore=kvstore if spmd else None, scaler=loss_scaler,
+            master_names=master_names, telemetry=tele_on,
+            state_specs=state_specs)
+        plan = _FusedPlan()
+        plan.fn, plan.key, plan.site = fn, key, self._site("fused_step")
+        plan.optimizer, plan.indices = optimizer, indices
+        plan.num_steps, plan.scaler, plan.tele_on = num_steps, loss_scaler, \
+            tele_on
+        plan.gnames = list(gnames)
+        # the state's leaves in the order the program's pytree flattens
+        # them, as holders (an NDArray is a leaf to jax; _pack_state's
+        # tuples for lists, so the structure is the packed one)
+        s_h, plan.s_tree = jax.tree_util.tree_flatten(
+            {n: _state_holders(states[n]) for n in gnames})
+        # the donated arguments' holders: parameters, gradients, state
+        plan.d_h = [self.arg_dict[n] for n in gnames] \
+            + [self.grad_dict[n] for n in gnames] + s_h
+        rest = [n for n in self._arg_names if n not in set(gnames)]
+        plan.b_names = [n for n in rest if spmd and n in self._spmd_batch_args]
+        plan.o_names = [n for n in rest if n not in set(plan.b_names)]
+        plan.a_names = list(self._aux_names)
+        plan.b_h = [self.arg_dict[n] for n in plan.b_names]
+        plan.r_h = [self.arg_dict[n] for n in plan.o_names] \
+            + [self.aux_dict[n] for n in plan.a_names]
+        plan.spmd = spmd
+        plan.seen_d = plan.seen_r = None
+        plan.ahead = {}
+        plan.lookup_counted = True
+        plan.guard = plan.states_of = None  # its owner's, if it has one
         if spmd:
             from jax.sharding import NamedSharding, PartitionSpec
 
-            mesh, axis = self._spmd_mesh, self._spmd_axis
-            ndev = self._spmd_ndev()
-            batch_vals = {n: other.pop(n) for n in list(other)
-                          if n in self._spmd_batch_args}
-            for n, v in batch_vals.items():
-                if not v.shape or v.shape[0] % ndev:
-                    raise MXNetError(
-                        f"fused_step: batch dim of {n!r} ({v.shape}) not "
-                        f"divisible by the dp mesh size {ndev}")
-            shard = NamedSharding(mesh, PartitionSpec(axis))
-            repl = NamedSharding(mesh, PartitionSpec())
-            # dedup donated buffers BEFORE replication: single-device buffer
-            # pointers are readable here, while multi-shard arrays only fall
-            # back to id() (constant-cache aliases would then slip through
-            # and XLA rejects a twice-donated buffer)
-            pvals, gvals, svals = uniquify_donated((pvals, gvals, svals))
-            # one device_put per array, no per-device Python splits: the
-            # batch lands sharded on the dp axis, everything else replicated
-            # — except rule-sharded params/grads/state, which land (and
-            # stay) in their PartitionSpec layout.  All of these are no-ops
-            # after the first step: program outputs carry these shardings.
-            batch_vals = {n: jax.device_put(v, shard)
-                          for n, v in batch_vals.items()}
+            mesh = self._spmd_mesh
+            plan.ndev = self._spmd_ndev()
+            plan.shard = NamedSharding(mesh, PartitionSpec(self._spmd_axis))
+            plan.repl = NamedSharding(mesh, PartitionSpec())
             if state_specs is not None:
-                pvals = {n: jax.device_put(v, NamedSharding(
-                    mesh, PartitionSpec(*self._spmd_param_specs.get(n, ()))))
-                    for n, v in pvals.items()}
-                gvals = {n: jax.device_put(v, NamedSharding(
-                    mesh, PartitionSpec(*self._spmd_param_specs.get(n, ()))))
-                    for n, v in gvals.items()}
-                svals = jax.device_put(svals, jax.tree_util.tree_map(
-                    lambda sp: NamedSharding(mesh, sp), state_specs))
-                other, aux_vals, sc_args = jax.device_put(
-                    (other, aux_vals, sc_args), repl)
+                # rule-sharded params, grads and state land (and stay) in
+                # their PartitionSpec layout
+                of = [NamedSharding(mesh, PartitionSpec(
+                    *self._spmd_param_specs.get(n, ()))) for n in gnames]
+                plan.d_shardings = of + of + [
+                    NamedSharding(mesh, sp) for sp in
+                    jax.tree_util.tree_leaves(state_specs)]
             else:
-                pvals, gvals, svals, other, aux_vals, sc_args = \
-                    jax.device_put(
-                        (pvals, gvals, svals, other, aux_vals, sc_args),
-                        repl)
-            self._spmd_active = True
-            args = (pvals, gvals, svals, batch_vals, other, aux_vals,
-                    lr_vec, wd, t_vec, rng, *sc_args)
+                plan.d_shardings = plan.repl
+        self._fused_plan = plan
+        _note_plan("fused_plan_builds")
+        return plan
+
+    def run_fused_step(self, plan: "_FusedPlan", feed=None) -> bool:
+        """One step of ``plan``: what is left of a step once its plan is
+        there — the update counts and the step's scalars, the fed batch,
+        the key, the holders' arrays, the call, and the results back into
+        the holders.  False, with nothing done, when the params carry mixed
+        update counts (the per-param loop's case)."""
+        from . import engine as _engine
+        from .optimizer import fused_advance
+
+        # two spans, so that a device-idle gap names its owner: the host
+        # work before dispatch, and the dispatch itself
+        with _tracing.span("executor.feed", cat="executor"):
+            scalars = fused_advance(plan.optimizer, plan.indices,
+                                    plan.num_steps)
+            if scalars is None:
+                return False
+            for k, v in (feed or {}).items():
+                if k not in self.arg_dict:
+                    raise MXNetError(f"fused_step: unknown argument {k!r}")
+                self.arg_dict[k]._data = v._data if isinstance(v, NDArray) \
+                    else jnp.asarray(v)
+            fn = plan.fn
+            args, rng = self._fused_args(plan, scalars)
+            if plan.lookup_counted:
+                plan.lookup_counted = False  # the build's own lookup
+            else:
+                # a reused program is a hit of the compile cache, as ever
+                _note_cache(True, site=plan.site, key=plan.key)
+            if self._fused_probe is None or self._fused_probe[0] is not fn:
+                # once per program: the argument shapes, for
+                # fused_step_hlo() (placement only where it was chosen: an
+                # uncommitted array follows the others, as in the call
+                # itself)
+                # (the thunk holds the program and its shapes, not this
+                # executor: the device-scope resolver may read a profiler
+                # session after the executor is gone)
+                self._fused_probe = (fn, _device_scopes.text_thunk(fn, args))
+                _device_scopes.register(self)
+        with _tracing.span("executor.fused_step", cat="executor"):
+            res = fn(*args)
+        if plan.tele_on:
+            res, tele_vals = res[:-1], res[-1]
+            self._note_telemetry(tele_vals)
+        outs, aux_updates, new_grads, new_p, new_s = res[:5]
+        if plan.scaler is not None:
+            plan.scaler.set_state(res[5])
+        self._outputs = [NDArray(o) for o in outs]
+        for k, v in aux_updates.items():
+            self.aux_dict[k]._data = v
+        gnames = plan.gnames
+        landed = [new_p[n] for n in gnames] + [new_grads[n] for n in gnames] \
+            + jax.tree_util.tree_leaves(new_s)
+        for h, v in zip(plan.d_h, landed):
+            h._data = v
+        # what the next launch may donate as it is: program outputs are
+        # always distinct, and carry the placement the program gave them
+        plan.seen_d = landed
+        if plan.spmd:
+            plan.seen_r = self._fused_rest(plan)
+        # the device runs the step now: what the next launch will need and
+        # that is known already is made ready here, not in the gap after
+        # the step (used only if it still holds then: the key if nobody
+        # draws in between, the count if nobody moves it)
+        plan.ahead["key"] = _random.split_ahead()
+        plan.scalar("t", tuple(t + plan.num_steps for t in scalars[2]))
+        self._cached_grads = None
+        self._last_rng = rng
+        if _engine.is_naive():  # NaiveEngine forces sync, as everywhere else
+            for o in self._outputs:
+                o.wait_to_read()
+            for h in plan.d_h[:len(gnames)]:
+                h.wait_to_read()
+        if self._monitor_callback is not None:
+            for name, out in zip(self._out_names, self._outputs):
+                self._monitor_callback(name, out)
+        return True
+
+    @staticmethod
+    def _fused_rest(plan):
+        """The arrays of a plan's non-donated, non-batch arguments now."""
+        rest = [h._data for h in plan.r_h]
+        if plan.scaler is not None:
+            rest.extend(plan.scaler.state())
+        return rest
+
+    def _fused_args(self, plan, scalars):
+        """The arguments of one launch, gathered from the plan's holders:
+        ``(args, rng)``.  Donated buffers are made unique, and under SPMD
+        everything is placed on the mesh, only when an array is not the one
+        the plan's last launch returned — the first step, or one after
+        ``set_params`` / ``init_params`` / a loaded state / a caller's
+        write."""
+        from .optimizer import uniquify_donated
+
+        donated = [h._data for h in plan.d_h]
+        seen = plan.seen_d
+        fresh = seen is None or not all(map(operator.is_, donated, seen))
+        if fresh:
+            # aliased buffers (jax's constant cache hands one zero buffer
+            # to several fresh arrays) can only come from outside a launch.
+            # Before replication: single-device buffer pointers are
+            # readable here, while multi-shard arrays only fall back to
+            # id() (constant-cache aliases would then slip through and XLA
+            # rejects a twice-donated buffer)
+            _note_plan("fused_uniquify_runs")
+            donated = uniquify_donated(donated)
+            if plan.spmd:
+                # one device_put per array, no per-device Python splits:
+                # everything replicated — except rule-sharded
+                # params/grads/state, which land in their PartitionSpec
+                # layout
+                donated = jax.device_put(donated, plan.d_shardings)
         else:
-            pvals, gvals, svals = uniquify_donated((pvals, gvals, svals))
-            args = (pvals, gvals, svals, other, aux_vals, lr_vec, wd,
-                    t_vec, rng, *sc_args)
-        if self._fused_probe is None or self._fused_probe[0] is not fn:
-            # once per program: the argument shapes, for fused_step_hlo()
-            # (placement only where it was chosen: an uncommitted array
-            # follows the others, as in the call itself)
-            # (the thunk holds the program and its shapes, not this
-            # executor: the device-scope resolver may read a profiler
-            # session after the executor is gone)
-            self._fused_probe = (fn, _device_scopes.text_thunk(fn, args))
-            _device_scopes.register(self)
-        return fn, args, tele_on, rng
+            _note_plan("fused_plan_reuses")
+        gnames = plan.gnames
+        n = len(gnames)
+        pvals = dict(zip(gnames, donated[:n]))
+        gvals = dict(zip(gnames, donated[n:2 * n]))
+        svals = jax.tree_util.tree_unflatten(plan.s_tree, donated[2 * n:])
+        rng = _random.next_key(plan.ahead.pop("key", None))
+        lr_vec, wd, t_vec = map(plan.scalar, ("lr", "wd", "t"), scalars)
+        rest = self._fused_rest(plan)
+        batch = ()
+        if plan.spmd:
+            # the batch lands sharded on the dp axis (Module.prepare has
+            # placed it as a rule, while the device ran the step before),
+            # everything else replicated: placed arrays go back into their
+            # holders, so that the next step knows them
+            batch_vals = {}
+            for name, h in zip(plan.b_names, plan.b_h):
+                v = h._data
+                if getattr(v, "sharding", None) != plan.shard:
+                    if not v.shape or v.shape[0] % plan.ndev:
+                        raise MXNetError(
+                            f"fused_step: batch dim of {name!r} ({v.shape}) "
+                            f"not divisible by the dp mesh size {plan.ndev}")
+                    v = h._data = jax.device_put(v, plan.shard)
+                batch_vals[name] = v
+            batch = (batch_vals,)
+            if plan.seen_r is None or not all(map(operator.is_, rest, plan.seen_r)):
+                rest = jax.device_put(rest, plan.repl)
+                for h, v in zip(plan.r_h, rest):
+                    h._data = v
+            self._spmd_active = True
+        no, nr = len(plan.o_names), len(plan.r_h)
+        other = dict(zip(plan.o_names, rest[:no]))
+        aux_vals = dict(zip(plan.a_names, rest[no:nr]))
+        sc_args = () if plan.scaler is None else (tuple(rest[nr:]),)
+        return (pvals, gvals, svals, *batch, other, aux_vals, lr_vec, wd,
+                t_vec, rng, *sc_args), rng
 
     def fused_step_hlo(self) -> str:
         """Optimised HLO text of the program the last :meth:`fused_step`
@@ -1324,6 +1501,9 @@ class Executor:
 
     def set_monitor_callback(self, callback, monitor_all=False):
         self._monitor_callback = callback
+        # a monitor wants the legacy path's per-step introspection: the
+        # plan's owner decides again
+        self._fused_plan = None
 
     def debug_str(self) -> str:
         lines = [f"Symbol outputs: {self._out_names}"]

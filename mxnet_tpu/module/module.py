@@ -9,6 +9,7 @@ reference's DataParallelExecutorGroup becomes a sharding annotation.
 from __future__ import annotations
 
 import logging
+import weakref
 from typing import Dict, List, Optional, Tuple
 
 import numpy as _np
@@ -22,6 +23,7 @@ from ..model import (_create_kvstore, _fused_step_allowed, _initialize_kvstore,
                      _update_params, _update_params_on_kvstore, load_checkpoint,
                      save_checkpoint)
 from ..ndarray.ndarray import NDArray
+from ..observability import tracing as _tracing
 from ..optimizer import Optimizer, Updater, create as _create_optimizer, get_updater
 from .base_module import BaseModule, _check_input_names
 
@@ -61,6 +63,7 @@ class Module(BaseModule):
         self._label_shapes = None
         self._compression_params = compression_params
         self._fused_step_count = 0
+        self._prepared = None  # (batch, plan) readied by prepare()
         self._shared_bound = False
         self._amp_cfg = None      # resolved at bind (env TPUMX_AMP*)
         self._loss_scaler = None  # created at init_optimizer when needed
@@ -473,6 +476,7 @@ class Module(BaseModule):
         self._kvstore = kv
         self._update_on_kvstore = update_on_kvstore
         self._updater = None
+        self._drop_fused_plan()  # another optimizer, updater and scaler
         if kv:
             if self._compression_params:
                 kv.set_gradient_compression(self._compression_params)
@@ -543,6 +547,9 @@ class Module(BaseModule):
 
     # -- fused whole-train-step ---------------------------------------------------
     def _fused_ready(self) -> bool:
+        """Whether a step of this module may be the fused program's: read
+        when a step plan is built, not at every step — what could change
+        the answer later drops the plan or sits in :meth:`_fused_guard`."""
         if not (self.binded and self.params_initialized
                 and self.optimizer_initialized):
             return False
@@ -580,15 +587,49 @@ class Module(BaseModule):
                 return False
         return True
 
-    def _try_fused_step(self, data_batch) -> bool:
-        """Forward + backward + full optimizer update as ONE donated XLA
-        program (Executor.fused_step).  Optimizer state lives in the legacy
-        Updater's slots (device-side, updated in place) so
-        save/load_optimizer_states round-trip unchanged."""
-        if not self._fused_ready():
-            return False
-        from ..optimizer import fused_counts_uniform
+    def _drop_fused_plan(self):
+        """Forget the fused step's plan (docs/fused_step.md): called by
+        whatever replaces something the plan holds — another optimizer or
+        updater, a loss scaler, the state holders of a loaded checkpoint.
+        The next step builds it again (or takes the legacy path)."""
+        self._prepared = None
+        if self._exec is not None:
+            self._exec._fused_plan = None
 
+    def _fused_guard(self) -> tuple:
+        """What a plan was built under that nobody can name a hook for:
+        the optimizer's baked-in hyperparameters and multipliers, the
+        scaler's, and the environment's switches.  One tuple compare a
+        step; everything else that a plan depends on drops it by name
+        (:meth:`_drop_fused_plan`)."""
+        import os
+
+        from ..observability import telemetry as _tele
+
+        opt, sc, env = self._optimizer, self._loss_scaler, os.environ.get
+        ndev = self._dp_size()
+        return (opt.fused_static_key(), getattr(opt, "_mult_epoch", 0),
+                sc, None if sc is None else sc.static_key(),
+                _tele.enabled(), ndev,
+                _fused_step_allowed(opt, self._kvstore,
+                                    self._update_on_kvstore, ndev),
+                env("TPUMX_MP_DEVICES"), env("TPUMX_PP_DEVICES"),
+                env("TPUMX_PALLAS"))
+
+    def _fused_plan(self, guard):
+        """The executor's kept plan if it still stands, else None."""
+        plan = self._exec._fused_plan
+        if plan is not None and plan.guard == guard \
+                and plan.states_of is self._updater.states:
+            return plan
+        return None
+
+    def _build_fused_plan(self, guard):
+        """The plan of this module's fused step, built when its first step
+        runs and after whatever dropped it; None when the step belongs to
+        the legacy path (:meth:`_fused_ready`)."""
+        if not self._fused_ready():
+            return None
         grad_names = set(self._exec._grad_arg_names)
         # idx: the legacy i*num_device+k slot scheme (k=0 slot), where
         # num_device is the CONTEXT count exactly as init_optimizer's
@@ -596,55 +637,110 @@ class Module(BaseModule):
         # checkpoints stay compatible with the per-device updater layout
         # (TPUMX_DP_DEVICES widens the mesh, not the slot scheme)
         nslot = len(self._context)
-        idx_of = {n: i * nslot for i, n in enumerate(self._param_names)
-                  if n in grad_names}
-        if not fused_counts_uniform(self._optimizer, list(idx_of.values())):
-            return False
-        feed = {}
-        for (name, _), arr in zip(self._data_shapes, data_batch.data):
-            feed[name] = arr
-        if self._label_shapes and data_batch.label:
-            for (name, _), arr in zip(self._label_shapes, data_batch.label):
-                feed[name] = arr
-        cur = dict(self._data_shapes)
-        new_shapes = {n: tuple(a.shape) for n, a in
-                      zip([s[0] for s in self._data_shapes], data_batch.data)}
-        if any(cur[n] != s for n, s in new_shapes.items()):
-            self._reshape_exec(data_batch)
-        if (self._dp_size() > 1 or self._mp_size() > 1
-                or self._pp_size() > 1) \
-                and self._exec._spmd_mesh is not None:
-            # one device_put per array with a NamedSharding on the batch
-            # axis, mutating the batch's NDArrays in place: executor feed AND
-            # device-side metrics (labels vs sharded outputs) stay consistent
-            # (dp=1 × mp>1 meshes still need the batch placed over the full
-            # mesh device set — P('dp') replicates it across mp)
-            from ..io import shard_data_batch
-
-            shard_data_batch(data_batch, self._exec._spmd_mesh,
-                             self._exec._spmd_axis)
-        updates, states = [], {}
-        for name, idx in idx_of.items():
+        updates = [(n, i * nslot) for i, n in enumerate(self._param_names)
+                   if n in grad_names]
+        states = {}
+        for name, idx in updates:
             if idx not in self._updater.states:
                 self._updater.states[idx] = \
                     self._optimizer.create_state_multi_precision(
                         idx, self._exec.arg_dict[name])
-            updates.append((name, idx))
             states[name] = self._updater.states[idx]
-        self._exec.fused_step(self._optimizer, states, updates,
-                              feed=feed, num_steps=1,
-                              kvstore=self._kvstore,
-                              loss_scaler=self._loss_scaler)
+        plan = self._exec.plan_fused_step(
+            self._optimizer, states, updates, num_steps=1,
+            kvstore=self._kvstore, loss_scaler=self._loss_scaler)
+        plan.guard, plan.states_of = guard, self._updater.states
+        return plan
+
+    def _prepare_fused(self, data_batch, at_call: bool):
+        """Ready the fused step's launch for ``data_batch`` — everything
+        that needs no result of the step before it and changes nothing a
+        callback can see: the plan validated, the batch's shapes checked
+        against the bound ones, the batch placed on the mesh.  Returns the
+        plan, or None when there is nothing to launch from.  ``prepare``
+        calls this while the device runs the step before; a batch that
+        nobody prepared gets the same at the call (``at_call``), which
+        alone may build a plan or rebind for another shape."""
+        if not (self.binded and self.params_initialized
+                and self.optimizer_initialized and self._updater is not None):
+            return None
+        with _tracing.span("executor.prepare", cat="executor"):
+            guard = self._fused_guard()
+            plan = self._fused_plan(guard)
+            if not at_call and plan is None:
+                return None
+            if any(shape != tuple(a.shape) for (_, shape), a in
+                   zip(self._data_shapes, data_batch.data)):
+                if not at_call or (plan is None and not self._fused_ready()):
+                    return None
+                self._reshape_exec(data_batch)  # a new executor: no plan
+                plan = None
+            if plan is None:
+                plan = self._build_fused_plan(guard)
+                if plan is None:
+                    return None
+            if plan.spmd:
+                # one device_put per array with a NamedSharding on the
+                # batch axis, mutating the batch's NDArrays in place:
+                # executor feed AND device-side metrics (labels vs sharded
+                # outputs) stay consistent (dp=1 × mp>1 meshes still need
+                # the batch placed over the full mesh device set — P('dp')
+                # replicates it across mp)
+                from ..io import shard_data_batch
+
+                shard_data_batch(data_batch, self._exec._spmd_mesh,
+                                 self._exec._spmd_axis)
+            return plan
+
+    def prepare(self, data_batch, sparse_row_id_fn=None):
+        """``fit`` hands the NEXT batch over between ``update_metric`` and
+        the callbacks, while the device still runs the step: ready its
+        launch now (docs/fused_step.md "What prepare does").  Nothing a
+        callback at this batch can see changes."""
+        self._prepared = None
+        if self._exec is not None and self._exec._fused_plan is not None:
+            plan = self._prepare_fused(data_batch, at_call=False)
+            if plan is not None:
+                try:  # weakly: a batch nobody steps on is not kept alive
+                    self._prepared = (weakref.ref(data_batch), plan)
+                except TypeError:
+                    pass  # a list of batches: prepared at its call
+
+    def _try_fused_step(self, data_batch) -> bool:
+        """Forward + backward + full optimizer update as ONE donated XLA
+        program (Executor.run_fused_step over the kept plan).  Optimizer
+        state lives in the legacy Updater's slots (device-side, updated in
+        place) so save/load_optimizer_states round-trip unchanged."""
+        prepared, self._prepared = self._prepared, None
+        plan = None
+        if prepared is not None and prepared[0]() is data_batch:
+            # a callback ran since: the plan must still stand
+            plan = self._fused_plan(self._fused_guard())
+            if plan is not prepared[1]:
+                plan = None
+        if plan is None:
+            plan = self._prepare_fused(data_batch, at_call=True)
+            if plan is None:
+                return False
+        ex = self._exec
+        arg_dict = ex.arg_dict
+        for (name, _), arr in zip(self._data_shapes, data_batch.data):
+            arg_dict[name]._data = arr._data
+        if self._label_shapes and data_batch.label:
+            for (name, _), arr in zip(self._label_shapes, data_batch.label):
+                arg_dict[name]._data = arr._data
+        if not ex.run_fused_step(plan):
+            return False  # mixed update counts: the per-param loop's
         self._params_dirty = True
         self._fused_step_count += 1
         # telemetry stays device-side across steps; only every
         # TPUMX_TELEMETRY_EVERY-th step materializes the handful of scalars
         # into registry gauges — the no-per-batch-asnumpy property holds
-        if self._exec._telemetry_last is not None:
+        if ex._telemetry_last is not None:
             from ..observability import telemetry as _tele
 
             if self._fused_step_count % _tele.every() == 0:
-                _tele.publish(self._exec.telemetry_snapshot())
+                _tele.publish(ex.telemetry_snapshot())
         return True
 
     def update(self):
@@ -723,6 +819,7 @@ class Module(BaseModule):
         else:
             with open(fname, "rb") as f:
                 self._updater.set_states(f.read())
+            self._drop_fused_plan()  # the state's holders are new ones
 
     def install_monitor(self, mon):
         assert self.binded
@@ -761,3 +858,4 @@ class Module(BaseModule):
         self._update_on_kvstore = shared_module._update_on_kvstore
         self._updater = shared_module._updater
         self.optimizer_initialized = True
+        self._drop_fused_plan()

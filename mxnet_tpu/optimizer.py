@@ -58,6 +58,9 @@ class Optimizer:
         self.lr_mult: Dict[str, float] = {}
         self.wd_mult: Dict[str, float] = {}
         self._sym_wd_mult: Dict[str, float] = {}
+        # bumped by set_lr_mult / set_wd_mult: a fused step plan bakes the
+        # multipliers into its program and is rebuilt when this moves
+        self._mult_epoch = 0
         if sym is not None:
             attrs = sym.attr_dict()
             for name, a in attrs.items():
@@ -79,6 +82,7 @@ class Optimizer:
 
     def set_lr_mult(self, args_lr_mult):
         self.lr_mult.update(args_lr_mult)
+        self._mult_epoch = getattr(self, "_mult_epoch", 0) + 1
 
     def set_wd_mult(self, args_wd_mult):
         self.wd_mult = {}
@@ -89,6 +93,7 @@ class Optimizer:
         # (reference optimizer.py set_wd_mult re-reads sym attrs)
         self.wd_mult.update(self._sym_wd_mult)
         self.wd_mult.update(args_wd_mult)
+        self._mult_epoch = getattr(self, "_mult_epoch", 0) + 1
 
     def _update_count(self, index):
         if index not in self._index_update_count:
@@ -749,42 +754,49 @@ def uniquify_donated(trees):
     return jax.tree_util.tree_map(fix, trees)
 
 
-def fused_counts_uniform(optimizer, indices) -> bool:
-    """A fused step applies one shared host-side lr correction per inner
-    step, which is only exact when every fused param carries the same update
-    count.  Mixed counts (a user interleaving partial legacy updates) must
-    take the per-param loop."""
-    counts = {optimizer._index_update_count.get(i, optimizer.begin_num_update)
-              for i in indices}
-    return len(counts) <= 1
+def fused_mults(optimizer, indices) -> dict:
+    """``{index: (lr_mult, wd_mult, count_delta)}``: Python floats a fused
+    program bakes in as constants (part of its compile-cache key).  They
+    change only through ``set_lr_mult`` / ``set_wd_mult`` (or a gluon
+    Parameter's own multipliers), so a step plan computes them once.
+    ``count_delta`` is a param's update count less the lead param's: 0.0,
+    since a fused step runs only while the counts are uniform
+    (:func:`fused_advance`)."""
+    return {idx: (float(optimizer._get_lr_mult(idx)),
+                  float(optimizer._get_wd_mult(idx)), 0.0)
+            for idx in indices}
 
 
-def fused_update_plan(optimizer, indices, num_steps=1):
-    """Host-side bookkeeping for a fused step covering ``indices``: bump the
-    per-param update counts exactly as the legacy per-param loop would
-    (``num_steps`` times), and return the traced scalars + static per-param
-    multipliers the trace needs:
+def fused_advance(optimizer, indices, num_steps=1):
+    """The per-step remainder of a fused step's bookkeeping, in one pass:
+    bump the update counts of ``indices`` exactly as the legacy per-param
+    loop would (``num_steps`` times: ``_index_update_count``, ``num_update``,
+    one scheduler call an inner step), and return the step's scalars
+    ``(lrs, wd, ts)`` as Python floats — ``lrs`` / ``ts`` are tuples with
+    one entry per inner step (the scheduler's lr after
+    :meth:`fused_host_lr`, and the update count).
 
-    ``(lr_vec, wd, t_vec, mults)`` where ``lr_vec``/``t_vec`` have one entry
-    per inner step (base scheduler lr and the lead param's update count) and
-    ``mults[index] = (lr_mult, wd_mult, count_delta)`` are Python floats baked
-    into the program as constants (part of the compile-cache key)."""
+    Returns None, with nothing advanced, when the params carry MIXED update
+    counts (a user interleaving partial legacy updates): one shared
+    host-side lr correction per inner step is only exact for a uniform
+    count, so such a step belongs to the per-param loop."""
+    counts = optimizer._index_update_count
+    begin = optimizer.begin_num_update
+    t = counts.get(indices[0], begin)
+    for idx in indices:
+        if counts.get(idx, begin) != t:
+            return None
     lrs, ts = [], []
     for _ in range(max(1, int(num_steps))):
-        for idx in indices:
-            optimizer._update_count(idx)
+        t += 1
+        if t > optimizer.num_update:
+            optimizer.num_update = t
         base = float(optimizer.lr_scheduler(optimizer.num_update)) \
             if optimizer.lr_scheduler else float(optimizer.lr)
-        t = optimizer._index_update_count[indices[0]]
         lrs.append(float(optimizer.fused_host_lr(base, t)))
         ts.append(float(t))
-    mults = {}
-    for idx in indices:
-        mults[idx] = (float(optimizer._get_lr_mult(idx)),
-                      float(optimizer._get_wd_mult(idx)),
-                      float(optimizer._index_update_count[idx] - ts[-1]))
-    return (jnp.asarray(lrs, jnp.float32), jnp.float32(optimizer.wd),
-            jnp.asarray(ts, jnp.float32), mults)
+    counts.update(dict.fromkeys(indices, t))
+    return tuple(lrs), float(optimizer.wd), tuple(ts)
 
 
 # compiled all-params optimizer programs for the standalone update path
@@ -841,12 +853,14 @@ class Updater:
                 return False
         except Exception:
             return False
-        if not fused_counts_uniform(opt, indices):
+        scalars = fused_advance(opt, indices)
+        if scalars is None:  # mixed update counts: the loop's
             return False
+        lr_vec, wd, t_vec = (_np.asarray(x, _np.float32) for x in scalars)
         for i, w in zip(indices, weights):
             if i not in self.states:
                 self.states[i] = opt.create_state_multi_precision(i, w)
-        lr_vec, wd, t_vec, mults = fused_update_plan(opt, indices)
+        mults = fused_mults(opt, indices)
         w_vals = [w._data for w in weights]
         g_vals = [g._data for g in grads]
         s_vals = uniquify_donated(

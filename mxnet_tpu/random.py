@@ -13,7 +13,8 @@ import threading
 
 import jax
 
-__all__ = ["seed", "next_key", "fold_in", "get_state", "set_state"]
+__all__ = ["seed", "next_key", "split_ahead", "fold_in", "get_state",
+           "set_state"]
 
 _state = threading.local()
 _DEFAULT_SEED = 0
@@ -84,9 +85,26 @@ def seed(seed_state: int, ctx=None) -> None:
         del _state.key
 
 
-def next_key():
-    """Split one subkey off the global stream."""
+def split_ahead():
+    """The split the NEXT :func:`next_key` will make, made now and not yet
+    taken: the stream does not move.  ``next_key(ahead=...)`` takes it if
+    nobody has drawn or seeded on this thread in between (the stream's key
+    is still the very object that was split), and splits afresh otherwise —
+    so the keys and their order are what they are without it.  For a
+    caller that knows its next draw and has time now (the fused train
+    step, while the device runs the step before)."""
     key = _get_key()
+    new, sub = jax.random.split(key)
+    return key, new, sub
+
+
+def next_key(ahead=None):
+    """Split one subkey off the global stream (``ahead``: a
+    :func:`split_ahead` made earlier, used if the stream has not moved)."""
+    key = _get_key()
+    if ahead is not None and ahead[0] is key:
+        _state.key, sub = ahead[1], ahead[2]
+        return sub
     _state.key, sub = jax.random.split(key)
     return sub
 
