@@ -19,7 +19,7 @@ metrics snapshot — dumps to ONE timestamped JSON file when something dies:
 ``TPUMX_FLIGHT_RECORDER_DIR`` (default: the system temp dir) as
 ``tpumx_flight_<utc timestamp>_<reason>_<pid>.json``.  Each dump also
 increments ``flight_recorder_dumps_total{reason}`` and remembers its path
-(:func:`last_dump` — bench.py attaches it to failed probe records).
+(:func:`last_dump`).
 """
 from __future__ import annotations
 

@@ -288,10 +288,10 @@ def batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3, momentum=0.
         if (os.environ.get("MXTPU_BN_PALLAS") == "1" and ax == data.ndim - 1
                 and data.shape[ax] % 128 == 0):
             # fused Pallas stats+normalize for channels-minor layouts
-            # (docs/perf_analysis.md: the train-fwd BN-stat passes).  NOTE:
+            # (one read for the train-forward batch statistics).  NOTE:
             # the env var is read at TRACE time and baked into jit caches —
-            # A/B it across fresh processes (tools/perf_sweep.py does), not
-            # by flipping os.environ mid-run.
+            # A/B it across fresh processes, not by flipping os.environ
+            # mid-run.
             from . import pallas_kernels as _pk
 
             out, mean, var = _pk.bn_train_fused(data, g, beta, float(eps), ax)

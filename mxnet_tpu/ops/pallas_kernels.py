@@ -164,9 +164,9 @@ from .flash_attention import flash_attention  # noqa: E402,F401
 # ---------------------------------------------------------------------------
 # BatchNorm train-mode stats + normalize (reference:
 # src/operator/nn/batch_norm.cc train-mode forward; cuDNN fuses these the
-# same way).  Measured r04 cost: train fwd = 61% of eval fwd purely from
-# the batch-stat passes (docs/perf_analysis.md).  Layout: channels-minor
-# (NHWC collapsed to (M, C)) so C rides the 128-lane dim.
+# same way): what train mode adds to the eval forward is the batch-stat
+# passes over the activation.  Layout: channels-minor (NHWC collapsed to
+# (M, C)) so C rides the 128-lane dim.
 #
 # stats kernel: ONE read of the activation produces both sum and sum-of-
 # squares (TPU grid steps run sequentially, so partial sums accumulate into

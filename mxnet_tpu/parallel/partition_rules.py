@@ -327,9 +327,8 @@ def rules_from_env(env: Optional[str] = None):
 def bytes_per_device(arrays) -> Dict[object, int]:
     """Per-device live bytes of a collection of (possibly sharded) device
     arrays — the memory-reduction headline's measurement (docs/sharding.md
-    memory math; bench.py ``mp_sharded_train_throughput`` and the sharding
-    tests assert on it).  Accepts any iterable / pytree of jax arrays or
-    NDArrays."""
+    memory math; the sharding tests assert on it).  Accepts any iterable /
+    pytree of jax arrays or NDArrays."""
     import jax
 
     out: Dict[object, int] = {}

@@ -66,8 +66,7 @@ class TransformerLM:
     cfg: TransformerConfig
     compute_dtype: object = None
     block_len = 0
-    offers = frozenset({"sampling", "speculative", "multistep", "int8",
-                        "mp", "amp"})
+    offers = frozenset({"sampling", "speculative", "int8", "mp", "amp"})
 
     @property
     def vocab(self) -> int:
@@ -464,7 +463,7 @@ def lm_loss(params: Params, tokens, labels, positions,
     """Mean next-token cross-entropy; `mask` (B, T) optionally excludes
     positions (e.g. padding) from the mean.  ``compute_dtype=jnp.bfloat16``
     casts params for the forward (f32 master weights stay outside — the
-    MXU recipe bench.py uses for ResNet)."""
+    MXU recipe of docs/amp.md)."""
     if compute_dtype is not None:
         params = jax.tree_util.tree_map(
             lambda p: p.astype(compute_dtype), params)

@@ -106,8 +106,7 @@ class GenerationConfig:
                  draft_mode: Optional[str] = None,
                  draft_k: Optional[int] = None,
                  draft_ngram: Optional[int] = None,
-                 draft_window: Optional[int] = None,
-                 multistep_k: Optional[int] = None):
+                 draft_window: Optional[int] = None):
         self.max_slots = int(max_slots if max_slots is not None
                              else getenv("TPUMX_GEN_SLOTS", 4))
         if self.max_slots < 1:
@@ -251,14 +250,6 @@ class GenerationConfig:
                                 else getenv("TPUMX_GEN_DRAFT_WINDOW", 32))
         if self.draft_window < 1:
             raise ValueError("draft_window must be >= 1")
-        # multi-step device scheduling: run up to k decode iterations
-        # inside one donated lax.scan program when batch membership is
-        # stable (chosen adaptively from queue depth / engine.fusion_hint
-        # so admission latency doesn't regress); 1 = off, byte-identical.
-        self.multistep_k = int(multistep_k if multistep_k is not None
-                               else getenv("TPUMX_GEN_MULTISTEP_K", 1))
-        if self.multistep_k < 1:
-            raise ValueError("multistep_k must be >= 1")
 
     def __repr__(self):
         return (f"GenerationConfig(max_slots={self.max_slots}, "
@@ -271,8 +262,7 @@ class GenerationConfig:
                 f"kv_dtype={self.kv_dtype!r}, "
                 f"preemption={self.preemption}, "
                 f"prefix_cache={self.prefix_cache}, "
-                f"speculative={self.speculative}, "
-                f"multistep_k={self.multistep_k})")
+                f"speculative={self.speculative})")
 
 
 class _GenRequest:
@@ -634,7 +624,7 @@ class GenerationService:
         :class:`~mxnet_tpu.parallel.sdar_moe.SdarMoeLM`.  A model with a
         ``block_len`` generates by diffusion over blocks
         (docs/generation.md); what a model does not offer (sampling
-        knobs, speculation, multistep, int8 KV, an mp mesh) is refused.
+        knobs, speculation, int8 KV, an mp mesh) is refused.
     config : :class:`GenerationConfig`, optional
     start : bool
         When False the engine loop is not launched until :meth:`start` —
@@ -659,7 +649,6 @@ class GenerationService:
         model = self._model = as_model(model_cfg, compute_dtype)
         for what, asked in (("amp", cfg.amp_dtype),
                             ("speculative", cfg.speculative),
-                            ("multistep", cfg.multistep_k >= 2),
                             ("int8", cfg.kv_dtype == "int8"),
                             ("mp", cfg.mp_devices > 1)):
             if asked and what not in model.offers:
@@ -775,18 +764,14 @@ class GenerationService:
         # multi-token decoding (docs/generation.md "Speculative
         # decoding"): the verify chunk length Tk = s + 1 (pending token +
         # s drafts) is pow2-bucketed so warmup enumerates the full
-        # (Tk, W) verify set; the multistep scan length k has its own
-        # ladder.  Both EMPTY with the gates off — the warmup set,
+        # (Tk, W) verify set.  EMPTY with the gate off — the warmup set,
         # program keys and growth arithmetic then stay byte-identical.
         self._verify_buckets = ([b for b in batch_buckets(cfg.draft_k + 1)
                                  if b >= 2] if cfg.speculative else [])
-        self._ms_buckets = ([b for b in batch_buckets(cfg.multistep_k)
-                             if b >= 2] if cfg.multistep_k >= 2 else [])
         # worst-case positions ONE iteration may write past ctx — block
         # growth reserves this span ahead (1 = classic single-token)
         self._iter_span = max(
-            1, (cfg.draft_k + 1) if cfg.speculative else 1,
-            cfg.multistep_k, L)
+            1, (cfg.draft_k + 1) if cfg.speculative else 1, L)
         self._draft = None
         if cfg.speculative and cfg.draft_mode == "model":
             if draft_params is None or draft_cfg is None:
@@ -820,13 +805,12 @@ class GenerationService:
         # or a block pass: either is built from counts alone, and what
         # the last one returned stays on the device for it.  Only a
         # service whose every decode step is that step leaves one in
-        # flight: a verify chunk's proposer and a scan need the last
-        # values on the host before they can build.  Nor under an mp
+        # flight: a verify chunk's proposer needs the last values on
+        # the host before it can build.  Nor under an mp
         # mesh: a step's tokens come back committed to the mesh, and fed
         # onward they would key a second lowering of every decode width
         self._flight: Optional[_Flight] = None
-        self._runs_ahead = not (cfg.speculative or self._ms_buckets
-                                or cfg.mp_devices > 1)
+        self._runs_ahead = not (cfg.speculative or cfg.mp_devices > 1)
         # a block-diffusion model's prefill [chunks, tokens] dispatched
         # since the last block pass was: counted with the next one
         self._prefill_uncounted = [0, 0]
@@ -844,13 +828,13 @@ class GenerationService:
                         "prefix_evictions": 0, "cached_tokens": 0,
                         "prefill_tokens": 0, "cow_copies": 0,
                         "draft_proposed": 0, "draft_accepted": 0,
-                        "spec_steps": 0, "multistep_steps": 0,
+                        "spec_steps": 0,
                         # decode steps dispatched before the last one's
                         # tokens were read, and those dispatched with
                         # nothing in flight
                         "steps_ahead": 0, "steps_drained": 0,
-                        # calls of a sampling program (a decode, verify
-                        # or multistep step, a prefill chunk), by the body
+                        # calls of a sampling program (a decode or verify
+                        # step, a prefill chunk), by the body
                         # their rows' knobs make its sampler take
                         # (ops/sampling.sampler_body)
                         **{f"sampler_steps_{b}": 0
@@ -959,8 +943,8 @@ class GenerationService:
         self._c_sampler_steps = [
             reg.counter(
                 "serving_sampler_steps_total", labels={"body": b},
-                help="calls of a sampling program (decode, verify and "
-                     "multistep steps, prefill chunks), by the body of its "
+                help="calls of a sampling program (decode and verify "
+                     "steps, prefill chunks), by the body of its "
                      "sampler their rows select: greedy (argmax alone), "
                      "draw (temperature and noise, no sort), filter (one "
                      "sort for top-k / top-p)")
@@ -1162,13 +1146,6 @@ class GenerationService:
                 for w in self._width_buckets:
                     self._programs.run_verify(self._cache,
                                               *zeros(tk, w).operands)
-            # multistep scan: one program per (k, W)
-            for k in self._ms_buckets:
-                for w in self._width_buckets:
-                    z = zeros(1, w)
-                    self._programs.run_multistep(
-                        k, self._cache, z.tokens[:, 0], z.positions[:, 0],
-                        *z.operands[2:])
             if self._draft is not None:
                 # the draft proposer is ONE (S, window, k) program
                 S = self._config.max_slots
@@ -1499,9 +1476,9 @@ class GenerationService:
         """Blocks an admission must secure for ``r``: under incremental
         allocation the current context plus the first iteration's write
         span — the request decodes in the very iteration that admits it,
-        before :meth:`_grow_blocks_locked` next runs, so a verify chunk or
-        multistep scan that crosses a block boundary there must already
-        own the block it writes (span 1 == the classic next position);
+        before :meth:`_grow_blocks_locked` next runs, so a verify chunk
+        that crosses a block boundary there must already own the block
+        it writes (span 1 == the classic next position);
         under reserve-ahead the full worst case."""
         cfg = self._config
         if cfg.preemption:
@@ -1800,10 +1777,10 @@ class GenerationService:
             if r is None or r.state != _RUNNING:
                 continue  # preempted by an earlier grower this pass
             # reserve the whole iteration's worst-case write span (the
-            # verify chunk / multistep scan may append up to _iter_span
-            # positions); span 1 == the classic next-position arithmetic,
-            # and the cap at prompt+max_new means single-token services
-            # are byte-identical
+            # verify chunk may append up to _iter_span positions); span 1
+            # == the classic next-position arithmetic, and the cap at
+            # prompt+max_new means single-token services are
+            # byte-identical
             need = self._cache.blocks_for(
                 min(r.ctx_len + self._lead(r) + self._iter_span,
                     r.prompt_len + r.max_new))
@@ -2170,11 +2147,10 @@ class GenerationService:
         Mode dispatch (docs/generation.md "Speculative decoding"): with
         speculative decoding on and at least one slot holding draft
         proposals, the iteration is ONE multi-query verify step (slots
-        without drafts ride along at chunk length 1); otherwise, when
-        multistep is enabled and the adaptive policy allows, k decode
-        iterations run inside one scanned program; otherwise the classic
-        single-token step.  All three paths emit identical token VALUES —
-        they differ only in how many tokens one device dispatch yields.
+        without drafts ride along at chunk length 1); otherwise the
+        classic single-token step.  Both paths emit identical token
+        VALUES — they differ only in how many tokens one device dispatch
+        yields.
 
         ``ahead`` lets the single-token step, or the block pass, of a
         service that runs no other kind leave what it returned on the
@@ -2189,15 +2165,10 @@ class GenerationService:
             if any(drafts.values()):
                 self._spec_step(batch, drafts)
                 return
-        k = self._choose_multistep_k(batch)
-        if k >= 2:
-            self._multistep_step(batch, k)
-            return
         self._single_step(batch, ahead and self._runs_ahead)
 
     def _build_step(self, batch: Sequence[_GenRequest], T: int, feed=None,
-                    writes: Optional[int] = None, sampler: bool = True,
-                    slots: Optional[int] = None,
+                    sampler: bool = True, slots: Optional[int] = None,
                     width: Optional[int] = None,
                     lead=None) -> _StepInputs:
         """The host side of one model step, written once for every step
@@ -2206,9 +2177,9 @@ class GenerationService:
         0, null-block table).  A row feeds ``feed(request)`` — up to ``T``
         token ids — at positions ``ctx_len ..`` (as much further as
         ``lead``, the step in flight's, says of a row: that step wrote
-        up to there) and will write ``writes``
-        positions there (default: as many as it feeds); before that write
-        its span is made private, then its ``tokens (S, T)``,
+        up to there) and will write as many positions there as it feeds;
+        before that write its span is made private, then its
+        ``tokens (S, T)``,
         ``positions``, ``lengths`` and, with ``sampler``, its seed, the
         index of the first token it produces (the counter) and its
         sampling knobs are filled.  The widest table needed is bucketed on
@@ -2240,7 +2211,6 @@ class GenerationService:
                 continue
             fed = feed(r)
             n, c = len(fed), r.ctx_len + lead.get(r.rid, 0)
-            span = writes or n
             # copy-on-write append: a row about to scatter into a shared
             # block (refcount > 1) gets a private copy first — shared
             # prompt history is read-only to every writer (idempotent, so
@@ -2249,9 +2219,9 @@ class GenerationService:
             # and must never touch a shared block — this is the rollback
             # guarantee (shared prefix blocks are physically unreachable
             # from a speculative scatter)
-            self._cow_for_write(r, c, span)
+            self._cow_for_write(r, c, n)
             if self._windows:
-                self._slide(r, r.ctx_len, c + span)
+                self._slide(r, r.ctx_len, c + n)
             rows.append((i, r))
             flat[i * T:i * T + n] = fed
             ctx[i] = c
@@ -2262,7 +2232,7 @@ class GenerationService:
                 temperature[i] = r.temperature
                 top_k[i] = r.top_k
                 top_p[i] = r.top_p
-            end = max(end, c + span)
+            end = max(end, c + n)
         tokens = _np.array(flat, _np.int32).reshape(S, T)
         # a row's tokens sit at ctx_len .. ctx_len + T - 1; the other rows
         # stay at 0
@@ -2477,8 +2447,8 @@ class GenerationService:
 
     def _emit_many(self, r: _GenRequest, toks: List[int]) -> int:
         """Emit consecutive tokens for one request; stops the moment a
-        token finishes it (eos / max_new) — surplus verified or scanned
-        tokens are simply discarded, exactly as if they were never
+        token finishes it (eos / max_new) — surplus verified tokens
+        are simply discarded, exactly as if they were never
         computed.  Returns the number emitted."""
         n = 0
         for t in toks:
@@ -2743,69 +2713,6 @@ class GenerationService:
             # the prefix index may see (full pages of it are whole blocks)
             r.ctx_len = len(r.seq_tokens)
 
-    def _choose_multistep_k(self, batch: List[_GenRequest]) -> int:
-        """Adaptive scan length (docs/generation.md "multi-step
-        decoding"): inside an ``engine.bulk`` scope the PR 3
-        ``fusion_hint`` drives k (the caller explicitly asked for
-        dispatch amortization); otherwise a non-empty waiting queue
-        forces k=1 so admission latency never regresses — a queued
-        request joins the batch at the very next token, exactly as
-        before.  The result is floored onto the pow2 ladder and bounded
-        by every row's remaining budget (a scanned token past max_new
-        would be computed only to be discarded)."""
-        cfg = self._config
-        if cfg.multistep_k < 2 or not self._ms_buckets:
-            return 1
-        rows = [r for r in batch if r.state == _RUNNING]
-        if not rows:
-            return 1
-        from ...engine import fusion_hint
-        hint = fusion_hint()
-        if hint > 1:
-            want = min(cfg.multistep_k, hint)
-        elif len(self._waiting) > 0:
-            return 1
-        else:
-            want = cfg.multistep_k
-        want = min(want, min(r.max_new - r.n_generated for r in rows))
-        k = 1
-        for b in self._ms_buckets:
-            if b <= want:
-                k = b
-        return k
-
-    def _multistep_step(self, batch: List[_GenRequest], k: int) -> None:
-        """k decode iterations inside one donated scanned program — the
-        same per-iteration math as :meth:`_single_step` (tokens and int8
-        write pattern bit-identical), with k-1 host↔device round trips
-        amortized away."""
-        with self._phase("build", "serving.decode.build"):
-            b = self._build_step(batch, 1,
-                                 lambda r: [r.seq_tokens[r.ctx_len]],
-                                 writes=k)
-        t_step0 = time.perf_counter()
-        with self._phase("step", "serving.multistep",
-                         args={"running": len(batch), "width": b.width,
-                               "k": int(k),
-                               "iteration": self._iteration}):
-            # the scan carries (S,) vectors: the FIRST iteration's values
-            toks = self._programs.run_multistep(
-                k, self._cache, b.tokens[:, 0], b.positions[:, 0],
-                *b.operands[2:])
-        t_step1 = time.perf_counter()
-        with self._phase("emit", "serving.emit"):
-            traced = _trace.enabled()
-            for i, r in b.rows:
-                r.decode_steps += 1
-                emitted = self._emit_many(r, [int(t) for t in toks[i]])
-                r.mode_tokens["multistep"] = \
-                    r.mode_tokens.get("multistep", 0) + emitted
-                if traced:
-                    self._participated(r, t_step0, t_step1, len(batch),
-                                       mode="multistep", k=int(k))
-        self._counts["multistep_steps"] += 1
-        self._counts["steps_drained"] += 1
-
     # -- failure isolation (docs/fault_tolerance.md serving rows) -----------------
     def _note_step_failure(self, exc: BaseException) -> None:
         self._counts["step_failures"] += 1
@@ -2959,8 +2866,8 @@ class GenerationService:
     def live_occupancy(self) -> float:
         """Fraction of the allocatable pool holding written KV context —
         unlike ``allocator.occupancy()`` (owned blocks), reservation and
-        growth headroom do not count.  The incremental-vs-reserve-ahead
-        comparison in bench.py's ``overload_serving`` reads this."""
+        growth headroom do not count (``stats()["kv_blocks"]
+        ["live_occupancy"]`` and its gauge)."""
         total = self._cache.num_blocks - 1
         with self._lock:
             live = self._live_blocks_locked()
@@ -3073,8 +2980,7 @@ class GenerationService:
             "inter_token_ms": {"p50": _ms(pct(itl, 50)),
                                "p99": _ms(pct(itl, 99))},
             "decode_mode": ("block" if self._block_len else
-                            "spec" if self._config.speculative else
-                            "multistep" if self._config.multistep_k >= 2
+                            "spec" if self._config.speculative
                             else "single"),
             "block_diffusion": (None if not self._block_len else {
                 "block_length": self._block_len,
@@ -3101,8 +3007,6 @@ class GenerationService:
                           / counts["spec_steps"], 4)),
                 "spec_steps": counts["spec_steps"],
             }),
-            "multistep": {"k": self._config.multistep_k,
-                          "steps": counts["multistep_steps"]},
             "compiled_signatures": self._programs.compiled_signatures(),
             "decode_kernel": self._programs.kernel,
             "kv_dtype": self._config.kv_dtype or str(self._cache.dtype),

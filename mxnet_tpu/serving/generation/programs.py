@@ -9,9 +9,9 @@ loop updates the cache in place on device instead of copying
 ``O(num_blocks)`` memory every token.  The kinds: ``gen_prefill`` (B=1,
 T=seq-bucket) and ``gen_decode`` (B=max_slots, T=1) are the same
 :func:`_model_step`, the MODEL's cache-aware step plus the per-row
-sampling kernel from :mod:`mxnet_tpu.ops.sampling`; ``gen_verify``,
-``gen_multistep``; a block-diffusion model's ``gen_prefill``
-(:func:`_block_fill`) and ``gen_block``; and ``gen_block_copy``.
+sampling kernel from :mod:`mxnet_tpu.ops.sampling`; ``gen_verify``; a
+block-diffusion model's ``gen_prefill`` (:func:`_block_fill`) and
+``gen_block``; and ``gen_block_copy``.
 
 A model (:func:`as_model`) is an object with ``step(params, tokens,
 positions, lengths, pools, block_tables, *, attention_kernel,
@@ -68,7 +68,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import Dict, Optional
+from typing import Dict
 
 import numpy as _np
 
@@ -231,45 +231,6 @@ def _verify_step(params, pools, tokens, positions, lengths, block_tables,
     return target, accepted, pools
 
 
-def _multistep(params, pools, tokens, positions, lengths, block_tables,
-               seeds, counters, temperature, top_k, top_p, *, k, model,
-               attention_kernel="gather", mp_mesh=None):
-    """``k`` decode iterations inside ONE donated program via
-    ``lax.scan`` (docs/generation.md "multi-step decoding") — each scan
-    iteration is exactly the single-step decode math (same (S, 1) model
-    call, same ``(seed, position)`` sampler keying, same one-position
-    scatter), so tokens match the step-at-a-time path and the int8 pool's
-    write pattern is bit-identical (the scales ride in the carry with
-    their pools, and the masked-absmax requantization touches blocks in
-    the order single-step decode would); only the host↔device round-trips
-    in between are amortized away.  ``tokens``/``positions``/``counters``
-    are the FIRST iteration's (S,) values; rows with ``lengths == 0`` are
-    inactive throughout (null-block writes).  Returns (S, k) tokens."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...ops.sampling import sample_logits
-
-    def body(carry, _):
-        pools, tok, pos, ctr = carry
-        logits, pools, _ = model.step(
-            params, tok[:, None], pos[:, None], lengths, pools,
-            block_tables, attention_kernel=attention_kernel,
-            mp_mesh=mp_mesh)
-        with jax.named_scope("sample"):
-            nxt = sample_logits(logits[:, 0, :], seeds, ctr, temperature,
-                                top_k, top_p)
-        return (pools, nxt, pos + 1, ctr + 1), nxt
-
-    with jax.named_scope("multistep"):
-        init = (pools,
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(counters, jnp.uint32))
-        (pools, _, _, _), toks = jax.lax.scan(body, init, None, length=k)
-        return jnp.transpose(toks), pools  # (S, k)
-
-
 def _block_fill(params, pools, tokens, positions, lengths, block_tables,
                 *, model, attention_kernel="gather", mp_mesh=None):
     """Prefill of a block-diffusion model: whole blocks of context written
@@ -379,10 +340,9 @@ class GenerationPrograms:
                  _block_fill if model.block_len else _model_step),
                 ("gen_decode", _model_step),
                 ("gen_verify", _verify_step),
-                ("gen_multistep", _multistep),
                 ("gen_block", _block_step),
                 ("gen_block_copy", block_copy_pools))}
-        self._jits: Dict[tuple, object] = {}
+        self._jits: Dict[object, object] = {}
         import jax
 
         # (creating one traces nothing)
@@ -439,8 +399,8 @@ class GenerationPrograms:
         ``TPUMX_PALLAS`` gate."""
         return self._kernel
 
-    def _key(self, kind: str, cache, tokens=None, block_tables=None,
-             k: Optional[int] = None) -> tuple:
+    def _key(self, kind: str, cache, tokens=None,
+             block_tables=None) -> tuple:
         sig = (("kv_pool", cache.shape, str(cache.pools[0].dtype)),)
         if tokens is not None:
             widths = tuple(block_tables.shape) \
@@ -457,11 +417,9 @@ class GenerationPrograms:
         # kv_dtype off leaves every pre-existing key byte-identical
         if self._kv_dtype == "int8":
             sig = sig + (("kv_dtype", "int8"),)
-        if k is not None:
-            sig = sig + (("k", k),)
         return (kind, sig)
 
-    def _run(self, kind: str, cache, args, k: Optional[int] = None) -> tuple:
+    def _run(self, kind: str, cache, args) -> tuple:
         """The one way a program runs: note the compile-cache lookup, call
         the kind's jitted function on the cache's ``pools`` (donated),
         swap what it returns, last among its outputs, back into the cache;
@@ -475,27 +433,25 @@ class GenerationPrograms:
         # the block copy has no model in it: no parameters, no tokens or
         # tables in its key, and its caller's serving.cow_copy span times it
         step = fn is not block_copy_pools
-        key = self._key(kind, cache, args[0], args[3], k) if step \
+        key = self._key(kind, cache, args[0], args[3]) if step \
             else self._key(kind, cache)
         with self._lock:
             per = self._stats.get(key)
             hit = per is not None
             if per is None:
                 per = self._stats[key] = {"hits": 0, "misses": 0}
-            jitted = self._jits.get((fn, k))
+            jitted = self._jits.get(fn)
             if jitted is None:
-                # one jit wrapper per (function, static k): creating one
-                # traces nothing
+                # one jit wrapper per function: creating one traces
+                # nothing
                 import jax
 
                 if step:
-                    kw = self._step_kw if k is None \
-                        else dict(self._step_kw, k=k)
-                    jitted = jax.jit(functools.partial(fn, **kw),
+                    jitted = jax.jit(functools.partial(fn, **self._step_kw),
                                      donate_argnums=(1,))
                 else:
                     jitted = jax.jit(fn, donate_argnums=(0,))
-                self._jits[fn, k] = jitted
+                self._jits[fn] = jitted
             if not hit:
                 self._texts[key] = _device_scopes.text_thunk(
                     jitted, ((self._params,) if step else ())
@@ -637,20 +593,6 @@ class GenerationPrograms:
             unmasked, prev_tokens, prev_masked,
             _np.asarray(tokens, _np.int32), _np.asarray(masked, _np.bool_),
             _np.asarray(keep, _np.bool_))
-
-    def run_multistep(self, k: int, cache, tokens, positions, lengths,
-                      block_tables, seeds, counters, temperature, top_k,
-                      top_p):
-        """``k`` decode iterations in one donated program (``lax.scan``).
-
-        ``tokens``/``positions``/``counters`` are the first iteration's
-        (S,) values; returns np (S, k) tokens per row.  Each k is its own
-        program signature (``("k", k)`` key component, site
-        ``gen_multistep``) — the engine's pow2 k-ladder keeps the family
-        finite for warmup."""
-        return _synced(*self._run("gen_multistep", cache, _step_args(
-            tokens, positions, lengths, block_tables, seeds, counters,
-            temperature, top_k, top_p), k=int(k)))
 
     def copy_block(self, cache, src: int, dst: int) -> None:
         """Copy pool block ``src`` onto ``dst`` (scales included for the

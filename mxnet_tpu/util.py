@@ -39,7 +39,7 @@ def enable_compile_cache():
     ``<checkout>/.jax_cache`` — a FIXED path (it is part of the cache key's
     environment, so a directory that moves never hits), listed in
     ``.gitignore``.  Called by the entry points that run on the chip
-    (chip_smoke.py, bench.py, tools/tpu_parity.py), never on
+    (chip_smoke.py, tools/tpu_parity.py), never on
     ``import mxnet_tpu``.
     """
     import jax

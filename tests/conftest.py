@@ -174,8 +174,8 @@ def pytest_configure(config):
         "markers",
         "speculative: speculative + multi-token decoding "
         "(mxnet_tpu.serving.generation.speculative — n-gram/draft-model "
-        "proposers, the multi-query verify step, multistep lax.scan "
-        "decode, exact-match rejection sampling; docs/generation.md "
+        "proposers, the multi-query verify step, exact-match rejection "
+        "sampling; docs/generation.md "
         "\"Speculative decoding\"; select with `pytest -m speculative`)")
 
 

@@ -146,16 +146,15 @@ def _service(model, **gc):
 
 @pytest.fixture(scope="module")
 def services():
-    """One warmed service a model (and GPT-2's speculative and multistep
-    ones), made when first asked for."""
+    """One warmed service a model (and GPT-2's speculative one), made
+    when first asked for."""
     made = {}
 
     def get(name):
         if name not in made:
             model, _, variant = name.partition("-")
             made[name] = _service(model, **{
-                "": {}, "verify": dict(speculative=True, draft_k=2),
-                "multistep": dict(multistep_k=2)}[variant])
+                "": {}, "verify": dict(speculative=True, draft_k=2)}[variant])
         return made[name]
 
     yield get
@@ -208,9 +207,8 @@ def test_model_step_names_its_layer_parts(services, model):
 
 @pytest.mark.parametrize("kind,service", [
     ("decode", "gpt2"), ("prefill", "gpt2"), ("carry", "gpt2"),
-    ("block_copy", "gpt2"), ("verify", "gpt2-verify"),
-    ("multistep", "gpt2-multistep"), ("block", "sdar"), ("fill", "sdar"),
-    ("carry", "sdar")])
+    ("block_copy", "gpt2"), ("verify", "gpt2-verify"), ("block", "sdar"),
+    ("fill", "sdar"), ("carry", "sdar")])
 def test_every_program_kind_says_its_kind(services, kind, service):
     """The outermost scope of each traced function is the program's kind
     as the engine counts it, and the label the resolver gives it."""
@@ -279,8 +277,8 @@ def test_serving_programs_lower_to_the_same_text_without_scopes(services,
      ("SoftmaxOutput/softmax", "backward")),
     ("jit(_unknown)/decode/layer3/moe.combine/scatter-add",
      ("decode/layer3/moe.combine", "forward")),
-    ("jit(f)/multistep/while/body/layer0/attn.kernel/bhqk,bkhd->bqhd/dot",
-     ("multistep/layer0/attn.kernel", "forward")),
+    ("jit(f)/decode/layer1/moe.experts/while/body/mk,kn->mn/dot_general",
+     ("decode/layer1/moe.experts", "forward")),
     ("jit(f)/decode/sample/cond/branch_2_fun/sort", ("decode/sample",
                                                      "forward")),
     ("jit(f)/decode/layer0/attn.kernel/broadcast_in_dim;jit(f)/decode/"

@@ -305,8 +305,7 @@ def _count_sorts(jaxpr, in_cond=False):
     return outside, inside
 
 
-@pytest.mark.parametrize("kind", ["gen_decode", "gen_verify",
-                                  "gen_multistep"])
+@pytest.mark.parametrize("kind", ["gen_decode", "gen_verify"])
 def test_a_sampling_program_sorts_once_and_only_in_a_branch(params, kind,
                                                              monkeypatch):
     """(c) a sampling program holds ONE sort over the vocabulary, inside a
@@ -321,15 +320,13 @@ def test_a_sampling_program_sorts_once_and_only_in_a_branch(params, kind,
     cache = PagedKVCache(num_blocks=16, block_size=8, n_layers=CFG.n_layers,
                          n_heads=CFG.n_heads, d_head=CFG.d_head,
                          dtype=jnp.float32)
-    S, T = 3, {"gen_decode": 1, "gen_verify": 4}.get(kind)
+    S, T = 3, {"gen_decode": 1, "gen_verify": 4}[kind]
     z = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
-    shape = (S, T) if T else (S,)
     fn, _ = progs._kinds[kind]
-    kw = dict(progs._step_kw, **({} if T else {"k": 4}))
-    args = (progs._params, cache.pools, z(*shape), z(*shape), z(S), z(S, 4),
+    args = (progs._params, cache.pools, z(S, T), z(S, T), z(S), z(S, 4),
             z(S).astype(np.uint32), z(S).astype(np.uint32),
             z(S).astype(np.float32), z(S), np.ones(S, np.float32))
-    step = functools.partial(fn, **kw)
+    step = functools.partial(fn, **progs._step_kw)
     assert _count_sorts(jax.make_jaxpr(step)(*args).jaxpr) == (0, 1)
     text = jax.jit(step).lower(*args).as_text()
     assert text.count("stablehlo.sort") == 1 and "stablehlo.case" in text

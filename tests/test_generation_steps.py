@@ -160,25 +160,23 @@ def test_gpt2_programs_through_the_model_seam_are_the_parents():
 # -- one serving step: the batch builder and the program kinds ----------------------
 _X12, _Z30, _M = 13, 31, 39     # the pending tokens of rows X and Z; a MASK id
 
-# case -> (T, what a row feeds, positions written, sampler arrays asked for,
-#          tokens and lengths of rows X (slot 0) and Z (slot 3), table width)
+# case -> (T, what a row feeds, sampler arrays asked for, tokens and lengths
+#          of rows X (slot 0) and Z (slot 3), table width)
 _BUILDER_CASES = {
-    "single": (1, lambda r: [r.seq_tokens[r.ctx_len]], None, True,
+    "single": (1, lambda r: [r.seq_tokens[r.ctx_len]], True,
                [_X12], 1, [_Z30], 1, 4),
     # drafts of unequal length: X proposes two, Z none; Tk buckets to 4
     "verify": (4, lambda r: [r.seq_tokens[r.ctx_len]] + {0: [7, 8]}.get(
-        r.rid, []), None, True, [_X12, 7, 8, 0], 3, [_Z30, 0, 0, 0], 1, 4),
-    # k = 4 writes 30 .. 33: a fifth block, so the width buckets to 8
-    "multistep": (1, lambda r: [r.seq_tokens[r.ctx_len]], 4, True,
-                  [_X12], 1, [_Z30], 1, 8),
-    "block": (4, lambda r: r.block, None, False,
+        r.rid, []), True, [_X12, 7, 8, 0], 3, [_Z30, 0, 0, 0], 1, 4),
+    # the block writes 30 .. 33: a fifth page, so the width buckets to 8
+    "block": (4, lambda r: r.block, False,
               [_X12, _M, _M, _M], 4, [_Z30, _M, _M, _M], 4, 8),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_BUILDER_CASES))
 def test_step_builder_against_arrays_written_by_hand(params, case):
-    """The one batch builder of the four step kinds, on four slots — X, an
+    """The one batch builder of the three step kinds, on four slots — X, an
     empty one, Y outside the batch, Z — against every operand written out
     by hand: the rows, tokens / positions / lengths, the sampler arrays
     (none for the block step), the widest table bucketed on the pow2
@@ -186,8 +184,7 @@ def test_step_builder_against_arrays_written_by_hand(params, case):
     written."""
     from mxnet_tpu.serving.generation.engine import _RUNNING, _GenRequest
 
-    T, feed, writes, sampler, x_tok, x_len, z_tok, z_len, w = \
-        _BUILDER_CASES[case]
+    T, feed, sampler, x_tok, x_len, z_tok, z_len, w = _BUILDER_CASES[case]
     svc = GenerationService(params, CFG, _gc(max_slots=4), start=False)
     alloc = svc._cache.allocator
 
@@ -211,7 +208,7 @@ def test_step_builder_against_arrays_written_by_hand(params, case):
     k, v = svc._cache.pools
     svc._cache.swap((k.at[:, 2].set(1.5), v.at[:, 2].set(-2.5)))
 
-    b = svc._build_step([x, z], T, feed, writes=writes, sampler=sampler)
+    b = svc._build_step([x, z], T, feed, sampler=sampler)
 
     assert b.rows == [(0, x), (3, z)] and b.width == w
     assert x.blocks == [1, 12, 3] and x.cow_copies == 1
@@ -261,10 +258,10 @@ def test_a_program_kind_is_one_function_and_notes_the_parents_keys(
     progs = gp.GenerationPrograms(params, CFG, kv_dtype=kv_dtype)
     assert {kind: fn for kind, (fn, _) in progs._kinds.items()} == {
         "gen_prefill": gp._model_step, "gen_decode": gp._model_step,
-        "gen_verify": gp._verify_step, "gen_multistep": gp._multistep,
+        "gen_verify": gp._verify_step,
         "gen_block": gp._block_step, "gen_block_copy": gp.block_copy_pools}
     assert not any(hasattr(gp, name) for name in (
-        "_model_step_q", "_verify_step_q", "_multistep_q"))
+        "_model_step_q", "_verify_step_q"))
     cache = PagedKVCache(num_blocks=16, block_size=8, kv_dtype=kv_dtype,
                          n_layers=CFG.n_layers, n_heads=CFG.n_heads,
                          d_head=CFG.d_head, dtype=jnp.float32)
@@ -286,7 +283,6 @@ def test_a_program_kind_is_one_function_and_notes_the_parents_keys(
         progs.run("gen_decode", cache, z(S, 1), z(S, 1), z(S), z(S, 4),
                   *knobs(S))
     progs.run_verify(cache, z(S, 4), z(S, 4), z(S), z(S, 4), *knobs(S))
-    progs.run_multistep(4, cache, z(S), z(S), z(S), z(S, 4), *knobs(S))
     for _ in range(2):
         progs.copy_block(cache, 0, 0)
     q = "_int8" if kv_dtype else ""
@@ -303,11 +299,9 @@ def test_a_program_kind_is_one_function_and_notes_the_parents_keys(
         (True, ("gen_decode" + q, ("lm",)), decode),
         (False, ("gen_verify" + q, ("lm",)),
          ("gen_verify", sig((3, 4), (3, 4)) + fam)),
-        (False, ("gen_multistep" + q, ("lm",)),
-         ("gen_multistep", sig((3,), (3, 4)) + fam + (("k", 4),))),
         (False, ("gen_block_copy" + q, ("lm",)), copy),
         (True, ("gen_block_copy" + q, ("lm",)), copy)]
-    assert progs.compiled_signatures() == 5
+    assert progs.compiled_signatures() == 4
 
 
 # -- the step in flight (docs/generation.md) ----------------------------------------

@@ -3,7 +3,7 @@ ResNet layout option producing the same numbers as the NCHW build from the
 same parameters.
 
 TPU rationale: NHWC puts C on the 128-lane minor dim, avoiding relayouts for
-BN reductions and conv tiling (docs/perf_analysis.md).
+BN reductions and conv tiling (docs/perf_guide.md section 4).
 """
 import numpy as np
 import pytest

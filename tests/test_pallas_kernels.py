@@ -106,9 +106,9 @@ def test_flash_attention_nd_op():
 
 
 def test_bn_train_fused_parity():
-    """Fused BN stats+normalize kernel (docs/perf_analysis.md train-fwd
-    cost; reference src/operator/nn/batch_norm.cc): fwd + grads match the
-    jnp var-form implementation, bf16 preserved."""
+    """Fused BN stats+normalize kernel (one read of the activation for
+    both batch statistics; reference src/operator/nn/batch_norm.cc): fwd +
+    grads match the jnp var-form implementation, bf16 preserved."""
     import jax
     import jax.numpy as jnp
 

@@ -389,12 +389,11 @@ def test_the_prefix_cache_is_declined_and_the_service_says_so(params, svc,
 
 @pytest.mark.parametrize("what,kw", [
     ("speculative", dict(speculative=True)),
-    ("multistep", dict(multistep_k=4)),
     ("int8", dict(kv_dtype="int8")),
     ("mp", dict(mp_devices=2))])
 def test_what_a_state_cannot_do_is_declined(params, what, kw):
     """A rejected draft cannot be rolled out of a sum: speculation (and
-    the scan, int8 and a mesh, which nothing here builds) is refused with
+    int8 and a mesh, which nothing here builds) is refused with
     the message every model's decline has."""
     with pytest.raises(ValueError, match=f"does not offer '{what}'"):
         _service(params, **kw)

@@ -534,9 +534,8 @@ def test_submit_refuses_sampling(params, served, kw):
 
 
 @pytest.mark.parametrize("kw,what", [
-    (dict(speculative=True), "speculative"), (dict(multistep_k=4), "multistep"),
-    (dict(kv_dtype="int8"), "int8"), (dict(mp_devices=2), "mp"),
-    (dict(amp_dtype="bfloat16"), "amp")])
+    (dict(speculative=True), "speculative"), (dict(kv_dtype="int8"), "int8"),
+    (dict(mp_devices=2), "mp"), (dict(amp_dtype="bfloat16"), "amp")])
 def test_service_refuses_what_the_model_does_not_offer(params, kw, what):
     with pytest.raises(ValueError, match=f"does not offer '{what}'"):
         _service(params, **kw)

@@ -3,8 +3,8 @@ speculative, docs/generation.md "Speculative decoding"): n-gram and
 draft-model proposers, exact-match rejection sampling parity, the
 multi-query verify step vs the greedy oracle across batch-membership
 changes, preemption mid-speculation, int8 shared-block isolation under
-rejection, multistep scan decode + the engine.bulk fusion-hint policy,
-and zero post-warmup recompiles with every speculative program frozen.
+rejection, and zero post-warmup recompiles with every speculative program
+frozen.
 """
 import time
 
@@ -12,7 +12,6 @@ import jax
 import numpy as np
 import pytest
 
-from mxnet_tpu import engine as eng
 from mxnet_tpu.ops import sampling as smp
 from mxnet_tpu.parallel import transformer as tr
 from mxnet_tpu.serving.generation import GenerationService
@@ -251,7 +250,7 @@ def test_spec_sampled_bitwise_matches_baseline(params):
 def test_spec_draft_model_full_acceptance(params):
     """Draft model == target model: every proposal is the target's own
     greedy token, so acceptance is total and outputs still match the
-    oracle (the self-draft upper bound bench.py measures)."""
+    oracle (the self-draft upper bound)."""
     svc = GenerationService(
         params, CFG,
         _gc(speculative=True, draft_mode="model", draft_k=3,
@@ -329,90 +328,18 @@ def test_int8_shared_blocks_untouched_by_rejecting_verify(params):
     assert stats["prefix_cache"]["hits"] >= 2
 
 
-# -- multistep scan + the engine.bulk fusion hint -----------------------------------
-def test_multistep_greedy_and_sampled_parity(params):
-    """k scanned decode iterations per dispatch emit the same tokens as
-    k single-token iterations — greedy vs the oracle, sampled vs the
-    single-step baseline."""
-    svc = GenerationService(params, CFG, _gc(multistep_k=4), start=False)
-    svc.warmup()
-    svc.start()
-    p0, p1 = np.array([4, 7, 1, 9, 2, 6]), np.array([12, 3, 5])
-    greedy = svc.generate(p0, max_new_tokens=8, timeout=180)
-    sampled = svc.generate(p1, max_new_tokens=7, temperature=0.8,
-                           top_k=12, seed=42, timeout=180)
-    stats = svc.stats()
-    svc.stop()
-    assert greedy == greedy_oracle(params, p0, 8)
-    base = GenerationService(params, CFG, _gc(), start=False)
-    base.start()
-    assert sampled == base.generate(p1, max_new_tokens=7, temperature=0.8,
-                                    top_k=12, seed=42, timeout=180)
-    base.stop()
-    assert stats["multistep"]["steps"] >= 1
-    assert stats["decode_mode"] == "multistep"
-
-
-def test_multistep_int8_bit_identical_to_single_step(params):
-    """The scanned path performs the identical int8 quantize/scatter per
-    iteration — int8 tokens match the int8 single-step service exactly."""
-    def run(k):
-        svc = GenerationService(params, CFG,
-                                _gc(kv_dtype="int8", multistep_k=k),
-                                start=False)
-        svc.start()
-        outs = [svc.generate(p, max_new_tokens=8, timeout=180) for p in REP]
-        svc.stop()
-        return outs
-
-    assert run(4) == run(1)
-
-
-def test_multistep_policy_pins_bulk_and_queue_pressure(params):
-    """The adaptive-k decision (satellite: engine.bulk / fusion_hint
-    wiring): queue pressure forces k=1 so admission latency never
-    regresses, an explicit bulk scope overrides it with min(config k,
-    bulk size), and the result lands on the pow2 ladder."""
-    svc = GenerationService(params, CFG,
-                            _gc(max_slots=1, multistep_k=4), start=False)
-    svc.submit(np.arange(6), max_new_tokens=8)
-    svc.submit(np.arange(5), max_new_tokens=8)
-    with svc._lock:
-        batch = svc._admit_locked()
-    assert len(batch) == 1 and len(svc._waiting) == 1
-    assert svc._choose_multistep_k(batch) == 1      # waiters -> latency wins
-    with eng.bulk(2):
-        assert svc._choose_multistep_k(batch) == 2  # explicit amortization
-    with eng.bulk(64):
-        assert svc._choose_multistep_k(batch) == 4  # capped at config k
-    with eng.bulk(3):
-        assert svc._choose_multistep_k(batch) == 2  # floored onto the ladder
-    assert eng.fusion_hint() == 1                   # scope exited cleanly
-    svc.stop(drain=False)
-
-    # no waiters: the full configured k, bounded by remaining budget
-    svc2 = GenerationService(params, CFG,
-                             _gc(max_slots=2, multistep_k=8), start=False)
-    svc2.submit(np.arange(6), max_new_tokens=3)
-    with svc2._lock:
-        batch2 = svc2._admit_locked()
-    assert svc2._choose_multistep_k(batch2) == 2    # min(8, remaining 3) -> 2
-    svc2.stop(drain=False)
-
-
 # -- zero post-warmup recompiles ----------------------------------------------------
-def test_zero_recompiles_spec_and_multistep_under_freeze(params, monkeypatch):
-    """Warmup enumerates the verify (Tk, W) ladder, every multistep (k, W)
-    program and the draft proposer; a mixed speculative workload then runs
-    under TPUMX_FREEZE_COMPILES=1 with one miss per signature."""
+def test_zero_recompiles_speculative_under_freeze(params, monkeypatch):
+    """Warmup enumerates the verify (Tk, W) ladder; a mixed speculative
+    workload then runs under TPUMX_FREEZE_COMPILES=1 with one miss per
+    signature."""
     svc = GenerationService(
-        params, CFG,
-        _gc(max_slots=3, speculative=True, draft_k=4, multistep_k=4),
+        params, CFG, _gc(max_slots=3, speculative=True, draft_k=4),
         start=False)
     warmed = svc.warmup()
     assert warmed == len(svc.compile_stats())
     kinds = {k[0] for k in svc.compile_stats()}
-    assert "gen_verify" in kinds and "gen_multistep" in kinds
+    assert "gen_verify" in kinds
     monkeypatch.setenv("TPUMX_FREEZE_COMPILES", "1")
     svc.start()
     handles = []
@@ -460,21 +387,19 @@ def test_zero_recompiles_draft_model_under_freeze(params, monkeypatch):
 def test_speculative_off_is_byte_identical(params, monkeypatch):
     """TPUMX_GEN_SPECULATIVE=0 (the default) keeps the engine's program
     set, growth arithmetic and tokens exactly as before the feature:
-    no verify/multistep/draft signatures exist, the reserve span is 1,
+    no verify/draft signatures exist, the reserve span is 1,
     and the dispatcher runs the classic single-token step."""
     monkeypatch.setenv("TPUMX_GEN_SPECULATIVE", "0")
-    monkeypatch.setenv("TPUMX_GEN_MULTISTEP_K", "1")
     cfg = _gc()
-    assert cfg.speculative is False and cfg.multistep_k == 1
+    assert cfg.speculative is False
     monkeypatch.delenv("TPUMX_GEN_SPECULATIVE")
-    monkeypatch.delenv("TPUMX_GEN_MULTISTEP_K")
     svc = GenerationService(params, CFG, cfg, start=False)
-    assert svc._verify_buckets == [] and svc._ms_buckets == []
+    assert svc._verify_buckets == []
     assert svc._iter_span == 1 and svc._draft is None
     warmed = svc.warmup()
     assert warmed == len(svc.compile_stats())
     kinds = {k[0] for k in svc.compile_stats()}
-    assert kinds.isdisjoint({"gen_verify", "gen_multistep", "gen_draft"})
+    assert kinds.isdisjoint({"gen_verify", "gen_draft"})
     svc.start()
     outs = [svc.generate(p, max_new_tokens=6, timeout=180) for p in REP]
     stats = svc.stats()
@@ -483,7 +408,6 @@ def test_speculative_off_is_byte_identical(params, monkeypatch):
         assert got == greedy_oracle(params, p, 6)
     assert stats["decode_mode"] == "single"
     assert stats["speculative"] is None
-    assert stats["multistep"]["steps"] == 0
     assert stats["counts"]["spec_steps"] == 0
 
 
@@ -493,13 +417,10 @@ def test_env_gates_parse(monkeypatch):
     monkeypatch.setenv("TPUMX_GEN_DRAFT_K", "6")
     monkeypatch.setenv("TPUMX_GEN_DRAFT_NGRAM", "2")
     monkeypatch.setenv("TPUMX_GEN_DRAFT_WINDOW", "24")
-    monkeypatch.setenv("TPUMX_GEN_MULTISTEP_K", "8")
     cfg = _gc()
     assert cfg.speculative is True and cfg.draft_mode == "ngram"
     assert cfg.draft_k == 6 and cfg.draft_ngram == 2
-    assert cfg.draft_window == 24 and cfg.multistep_k == 8
+    assert cfg.draft_window == 24
     assert "speculative=True" in repr(cfg)
     with pytest.raises(ValueError):
         _gc(speculative=True, draft_k=0)
-    with pytest.raises(ValueError):
-        _gc(multistep_k=0)
