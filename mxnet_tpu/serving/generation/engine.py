@@ -692,26 +692,35 @@ class GenerationService:
         # of folded heads, or the pools it names (latent attention: one)
         spec = model.cache_spec()
         if "kinds" in spec:
-            # window kinds are sized by rows: what every slot owns at rest
-            # and the one row being prefilled owns besides
+            # the kinds behind the first are sized by rows: a window kind
+            # by what every slot owns at rest and the one row being
+            # prefilled owns besides, a state kind by a unit a slot
             spec["window_rows"] = (cfg.max_slots, self._seq_buckets[-1])
         self._cache = PagedKVCache(
             num_blocks=cfg.num_blocks, block_size=cfg.block_size,
             kv_dtype=cfg.kv_dtype, **spec)
-        # a kind that is a slot's state (docs/generation.md "Cache kinds")
-        # is sized by the slots and never grows: a row owns its one state
-        # from admission to release, so there is no headroom to keep and
-        # nothing for a watermark to preempt
+        # admission is by a free slot AND by the first kind's units
+        # (docs/generation.md "Cache kinds").  Where the first kind is
+        # paged, those are blocks under its watermark; where the cache's
+        # ONE kind is a slot's state, a row owns its one state from
+        # admission to release and nothing grows, so there is no headroom
+        # to keep and nothing for a watermark to preempt.  Every kind
+        # behind the first is sized by the slots: a free slot has its units
         self._state_kind = self._cache.kinds[0].state
         if self._state_kind:
             self._cache.allocator.set_watermarks(1.0, 1.0)
         else:
             self._cache.allocator.set_watermarks(cfg.watermark_high,
                                                  cfg.watermark_low)
-        # the kinds behind the first (docs/generation.md "Cache kinds"):
-        # empty for every model whose layers are of one kind, and nothing
-        # below that names them runs
+        # the kinds behind the first (docs/generation.md "Cache kinds"),
+        # window kinds and a slot's state beside them: empty for every
+        # model whose layers are of one kind, and nothing below that names
+        # them runs
         self._windows = self._cache.kinds[1:]
+        # a model that runs part of itself at a prompt's last position
+        # alone (docs/generation.md "The prefill skip"): every chunk but
+        # the last goes through the fill program, which has no head
+        self._fills = bool(getattr(model, "fills_without_head", False))
         # prefix caching (docs/generation.md "prefix caching"): the chain-
         # hash index over resident full blocks.  None with the gate off —
         # every code path below then stays byte-identical to pre-cache
@@ -722,7 +731,9 @@ class GenerationService:
             capacity_blocks=cfg.prefix_cache_blocks)
             if cfg.prefix_cache and not self._windows
             and not self._state_kind else None)
-        if cfg.prefix_cache and self._windows:
+        windows = [k.name for k in self._windows if not k.state]
+        states = [k.name for k in self._cache.kinds if k.state]
+        if cfg.prefix_cache and windows:
             # a hit at position p would also need the window layers' last
             # positions before p, which their rows freed as they went: the
             # index would have to keep a window of blocks with every
@@ -732,16 +743,15 @@ class GenerationService:
                 "%s keeps window cache kinds %s: no prefix reuse (a row "
                 "frees the blocks behind its window, so no cached prefix "
                 "could serve them)", type(model).__name__,
-                [k.name for k in self._windows])
-        if cfg.prefix_cache and self._state_kind:
+                windows)
+        if cfg.prefix_cache and states:
             # a hit at position p would need the state as it stood AT p,
             # and a row keeps only the state at its last position: the
             # index would have to keep a snapshot with every prefix
             logging.getLogger(__name__).info(
                 "%s keeps the state cache kind %s: no prefix reuse (a row "
                 "keeps its state at its last position alone, so no cached "
-                "prefix could serve one)", type(model).__name__,
-                [k.name for k in self._cache.kinds])
+                "prefix could serve one)", type(model).__name__, states)
         self._pc_evictions_seen = 0
         self._programs = GenerationPrograms(params, model,
                                             mp_devices=cfg.mp_devices,
@@ -880,15 +890,16 @@ class GenerationService:
             help="fraction of the pool holding WRITTEN context — the "
                  "number reserve-ahead reservation wastes and incremental "
                  "allocation recovers (docs/generation.md)")
-        # a cache kind behind the first each (none for most models):
-        # the blocks its rows own now
+        # a cache kind each of a cache of several (none for most models):
+        # the units its rows own now
         self._g_kind_blocks = [
             reg.gauge("generation_kv_kind_blocks_used",
                       labels={"kind": k.name},
                       help="blocks of a cache kind that rows own (a window "
                            "kind: at most window_blocks() a row, whatever "
-                           "its length; docs/generation.md \"Cache kinds\")")
-            for k in self._windows]
+                           "its length; a state kind: slots; "
+                           "docs/generation.md \"Cache kinds\")")
+            for k in (self._cache.kinds if self._windows else ())]
         self._g_tps = reg.gauge("generation_tokens_per_sec")
         self._c_tokens = reg.counter("generation_tokens_total")
         self._c_requests = reg.counter("generation_requests_total")
@@ -1131,6 +1142,9 @@ class GenerationService:
             for tb, wp in sigs:
                 self._programs.run("gen_prefill", self._cache,
                                    *zeros(tb, wp, slots=1).operands)
+                if self._fills:
+                    self._programs.run_fill(self._cache, *zeros(
+                        tb, wp, slots=1, sampler=False).operands)
             for w in widths:
                 z = zeros(1, w)
                 toks, _ = self._programs.run("gen_decode", self._cache,
@@ -1525,10 +1539,11 @@ class GenerationService:
                     if shared:
                         alloc.decref(shared)
                     break  # keep the growth headroom; readmit later
-            if not all(k.allocator.can_allocate(window_blocks(
-                    k.window, self._seq_buckets[-1], cfg.block_size))
+            if not all(k.allocator.can_allocate(
+                    1 if k.state else window_blocks(
+                        k.window, self._seq_buckets[-1], cfg.block_size))
                     for k in self._windows):
-                break   # (sized by slots: a free slot has its blocks)
+                break   # (sized by slots: a free slot has its units)
             blocks = self._alloc_reclaiming(grow)
             if blocks is None:
                 if shared:
@@ -1581,9 +1596,11 @@ class GenerationService:
         return got
 
     def _slide(self, r, seen: int, upto: int) -> None:
-        """The window kinds' side of a row's advance (docs/generation.md
-        "Cache kinds"): of each, ``r`` keeps the blocks from the one that
-        holds position ``seen - window + 1`` — the first a query at
+        """The side of a row's advance that the kinds behind the first
+        take (docs/generation.md "Cache kinds").  Of a state kind ``r``
+        owns one unit, its slot's state, taken at its first step and kept
+        to its release.  Of a window kind ``r`` keeps the blocks from the
+        one that holds position ``seen - window + 1`` — the first a query at
         ``seen`` reads — to the one that holds ``upto - 1``.  Blocks that
         slid out of every later query's sight go back to the kind's
         allocator (the next allocation may hand them to another row while
@@ -1598,6 +1615,13 @@ class GenerationService:
         if r.wins is None:
             r.wins = [[0, []] for _ in self._windows]
         for kind, win in zip(self._windows, r.wins):
+            if kind.state:
+                win[1] = win[1] or kind.allocator.allocate(1)
+                if not win[1]:
+                    raise ServingError(
+                        f"cache kind {kind.name!r} has no free slot for "
+                        f"request {r.rid}")
+                continue
             first = max(0, seen - (kind.window - 1)) // bs
             if first > win[0]:
                 gone = win[1][:first - win[0]]
@@ -1617,19 +1641,23 @@ class GenerationService:
                 win[1].extend(got)
 
     def _drop_windows(self, r: _GenRequest) -> None:
-        """Return every window-kind block of ``r`` (release, preemption:
-        a resumed row's re-prefill takes them anew as it goes)."""
+        """Return what ``r`` owns of every kind behind the first (release,
+        preemption: a resumed row's re-prefill takes window blocks anew as
+        it goes, and a state from zero: a state has no snapshot, so every
+        token is prefilled again)."""
         for kind, win in zip(self._windows, r.wins or ()):
             kind.allocator.free(win[1])
         r.wins = None
 
     def _ring_tables(self, rows, S: int, T: int) -> tuple:
-        """The window kinds' tables of a step that feeds ``T`` positions a
-        row, ``(S, ring_width)`` each: RINGS — the logical block ``b`` of a
-        row sits in column ``b % width``."""
+        """The tables of the kinds behind the first, of a step that feeds
+        ``T`` positions a row.  A window kind's is ``(S, ring_width)``, a
+        RING — the logical block ``b`` of a row sits in column ``b %
+        width``; a state kind's is one column, the row's slot."""
         out = []
         for j, kind in enumerate(self._windows):
-            width = ring_width(kind.window, T, self._config.block_size)
+            width = 1 if kind.state else ring_width(
+                kind.window, T, self._config.block_size)
             table = _np.zeros((S, width), _np.int32)
             for i, r in rows:
                 first, blocks = r.wins[j]
@@ -2105,13 +2133,19 @@ class GenerationService:
                 # the sampler reads the chunk's last VALID row; only the
                 # final chunk's sample (global position prompt_len-1, the
                 # same seed/counter as the unchunked program) is emitted —
-                # intermediate chunks exist to fill the cache
-                next_tok, _ = self._programs.run(
-                    "gen_prefill", self._cache, tokens, positions,
-                    _np.asarray([take], _np.int32), table,
-                    _np.asarray([r.seed], _np.uint32),
-                    _np.asarray([ctx], _np.uint32), *knobs)
-                self._count_sampler_step(*knobs)
+                # intermediate chunks exist to fill the cache, and a model
+                # that ``fills_without_head`` runs them with no head at all
+                if self._fills and off + take < ctx:
+                    self._programs.run_fill(
+                        self._cache, tokens, positions,
+                        _np.asarray([take], _np.int32), table)
+                else:
+                    next_tok, _ = self._programs.run(
+                        "gen_prefill", self._cache, tokens, positions,
+                        _np.asarray([take], _np.int32), table,
+                        _np.asarray([r.seed], _np.uint32),
+                        _np.asarray([ctx], _np.uint32), *knobs)
+                    self._count_sampler_step(*knobs)
                 if not resumed and off + take >= ctx:
                     # the one read of a prefill: it waits for the chunks
                     # before it too (and for a decode step in flight)
@@ -2891,7 +2925,7 @@ class GenerationService:
                 self._c_pc_evict.inc(ev - self._pc_evictions_seen)
                 self._pc_evictions_seen = ev
             self._counts["prefix_evictions"] = ev
-        for kind, gauge in zip(self._windows, self._g_kind_blocks):
+        for kind, gauge in zip(self._cache.kinds, self._g_kind_blocks):
             gauge.set(kind.allocator.num_used)
         occ = alloc.occupancy()
         self._peak_occupancy = max(self._peak_occupancy, occ)
@@ -2935,12 +2969,14 @@ class GenerationService:
             ttft = list(self._ttft)
             itl = list(self._itl)
         alloc = self._cache.allocator
-        if self._state_kind:
-            # two gauges among the counts (docs/observability.md): the
-            # slots whose state a row owns now, and what one slot holds
-            counts["state_slots_live"] = alloc.num_used
-            counts["state_bytes_per_slot"] = \
-                self._cache.nbytes() // self._cache.num_blocks
+        for k in self._cache.kinds:
+            if k.state:
+                # two gauges among the counts (docs/observability.md): the
+                # slots whose state a row owns now, and what one slot holds
+                counts["state_slots_live"] = k.allocator.num_used
+                counts["state_bytes_per_slot"] = sum(
+                    int(p.nbytes) for p in self._cache.pools[k.span]) \
+                    // k.num_blocks
         pct = _smetrics.percentile
         return {
             "running": running,

@@ -91,12 +91,28 @@ class CacheKind:
     second axis, handed out by the same :class:`BlockAllocator` (index 0 is
     the scratch that idle rows point at).  Its "table" is one column wide
     and holds that index; nothing grows, nothing is freed behind a
-    window, and ``num_blocks - 1`` is the number of slots."""
+    window, and ``num_blocks - 1`` is the number of slots.  Such a kind
+    may be a cache's only one, or stand BEHIND a paged first kind, beside
+    window kinds: it is sized by slots as they are, and a row then owns
+    its one state unit and blocks of the paged kinds.
+
+    ``writers`` / ``readers`` are the model's layers that write and read
+    the kind, where its spec names them (else None): layer ``writers[j]``
+    owns pool row ``j``, and a reader that is no writer — a cross layer
+    over another layer's K and V — reads the row of the one writer
+    (:meth:`pool_row`).  One writer and many readers is ``n_layers`` 1."""
 
     def __init__(self, name, n_layers, layout, num_blocks, window, span,
-                 allocator=None, state=False, block_size=1):
+                 allocator=None, state=False, block_size=1, writers=None,
+                 readers=None):
         self.name = str(name)
         self.n_layers = int(n_layers)
+        self.writers = None if writers is None else tuple(writers)
+        self.readers = None if readers is None else tuple(readers)
+        if self.writers is not None and len(self.writers) != self.n_layers:
+            raise ValueError(
+                f"cache kind {name!r}: {len(self.writers)} writers for "
+                f"{n_layers} pool rows (a layer that writes owns one row)")
         self.layout = layout
         self.num_blocks = int(num_blocks)
         self.window = int(window)
@@ -109,6 +125,17 @@ class CacheKind:
         """Units of this kind a row of ``n_positions`` owns: the blocks
         that cover them, or — a state kind — its one slot."""
         return 1 if self.state else blocks_for(n_positions, self.block_size)
+
+    def pool_row(self, layer: int) -> int:
+        """The row of this kind's pools that the model's ``layer`` reads:
+        its own where it writes one, else the one writer's."""
+        writers, readers = self.writers or (), self.readers or ()
+        if layer in writers:
+            return writers.index(layer)
+        if layer in readers and len(writers) == 1:
+            return 0
+        raise ValueError(f"layer {layer} reads no row of cache kind "
+                         f"{self.name!r}")
 
 
 class BlockAllocator:
@@ -289,6 +316,15 @@ class PagedKVCache:
     ...)``, ``allocator`` hands out the rows' indices (``num_blocks`` is
     ``rows + 1``, index 0 the scratch) and :meth:`blocks_for` is 1.
 
+    A state kind may also stand BEHIND the first kind, beside window kinds
+    (a model of state-space, window and full attention layers): the same
+    pools and an allocator of its own over ``rows + 1`` indices, in its own
+    ``dtype`` where it names one.  Every kind behind the first is sized by
+    rows, so a free slot always has its units; the first alone is sized by
+    tokens and admits under a watermark.  A kind may name the layers that
+    write and read it (``writers`` / ``readers``): a kind one layer writes
+    and several read has ONE pool row (:meth:`CacheKind.pool_row`).
+
     The arrays are owned functionally, as one tuple ``pools``: the engine
     threads it through its donated compiled programs and stores the
     returned (aliased) arrays back via :meth:`swap` — the pool is updated
@@ -317,15 +353,18 @@ class PagedKVCache:
             if kv_dtype is not None or pools is not None:
                 raise ValueError("a cache of several kinds names its pools "
                                  "kind by kind and is not quantized")
-            if any("state" in k for k in kinds):
-                self._init_state(kinds, window_rows, block_size, dtype)
+            if len(kinds) == 1 and "state" in kinds[0]:
+                self._init_state(kinds[0], window_rows, block_size, dtype)
                 return
             first, rest = kinds[0], kinds[1:]
-            if first.get("window") or not all(k.get("window") for k in rest):
+            if first.get("window") or "state" in first or not all(
+                    bool(k.get("window")) != ("state" in k) for k in rest):
                 raise ValueError(
                     "the first cache kind keeps every position (it is what "
                     "num_blocks sizes) and every further kind is a window "
-                    f"kind, got {[(k['name'], k.get('window', 0)) for k in kinds]}")
+                    "kind or a slot's state, got " + str(
+                        [(k["name"], k.get("window", 0), "state" in k)
+                         for k in kinds]))
             n_layers, pools = first["n_layers"], first["pools"]
 
         self.num_blocks = int(num_blocks)
@@ -362,49 +401,59 @@ class PagedKVCache:
         # this allocator —, then a spec's window kinds, each with blocks
         # for ``window_rows = (rows, longest chunk)``: what every row owns
         # at rest and what the one row being prefilled owns besides
-        name = kinds[0]["name"] if kinds else "kv"
-        self.kinds = (CacheKind(name, n_layers, self.layout, self.num_blocks,
-                                0, slice(0, len(self.pools)),
-                                self.allocator, block_size=self.block_size),)
+        first = kinds[0] if kinds else {"name": "kv"}
+        self.kinds = (CacheKind(first["name"], n_layers, self.layout,
+                                self.num_blocks, 0, slice(0, len(self.pools)),
+                                self.allocator, block_size=self.block_size,
+                                **_layers_of(first)),)
         for k in (kinds or ())[1:]:
             rows, chunk = window_rows
+            at = len(self.pools)
+            if "state" in k:
+                self.kinds += (self._state_kind(k, rows, at, self.dtype),)
+                continue
             layout = _pool_layout(None, None, k["pools"])
             n = 1 + rows * window_blocks(k["window"], 1, self.block_size) \
                 + window_blocks(k["window"], chunk, self.block_size)
-            at = len(self.pools)
             self.pools += tuple(
                 jnp.zeros((int(k["n_layers"]), n, self.block_size, w), store)
                 for _, w in layout)
             self.kinds += (CacheKind(k["name"], k["n_layers"], layout, n,
                                      k["window"], slice(at, len(self.pools)),
-                                     block_size=self.block_size),)
+                                     block_size=self.block_size,
+                                     **_layers_of(k)),)
 
-    def _init_state(self, kinds, window_rows, block_size, dtype):
-        """The cache of a spec whose one kind is a slot's state: no pages,
-        no token blocks — ``rows + 1`` states a layer (index 0 the scratch),
-        their indices under :attr:`allocator`."""
+    def _state_kind(self, k, rows, at, dtype, allocator=None):
+        """A kind that is a slot's state, its pools appended to
+        :attr:`pools`: no pages, no token blocks — ``rows + 1`` states a
+        layer (index 0 the scratch), their indices under an allocator."""
         import jax.numpy as jnp
 
-        if len(kinds) != 1:
-            raise ValueError(
-                "a state kind stands alone: a state kind beside paged "
-                f"kinds in one model is not built, got "
-                f"{[k['name'] for k in kinds]}")
-        k = kinds[0]
+        layout = tuple((str(n), tuple(int(d) for d in shape))
+                       for n, shape in k["state"])
+        dt = jnp.dtype(k.get("dtype") or dtype or jnp.float32)
+        self.pools += tuple(
+            jnp.zeros((int(k["n_layers"]), int(rows) + 1) + shape, dt)
+            for _, shape in layout)
+        return CacheKind(k["name"], k["n_layers"], layout, int(rows) + 1, 0,
+                         slice(at, len(self.pools)), allocator, state=True,
+                         **_layers_of(k))
+
+    def _init_state(self, k, window_rows, block_size, dtype):
+        """The cache of a spec whose ONE kind is a slot's state: that
+        kind's pools alone, the slots' indices under :attr:`allocator`."""
+        import jax.numpy as jnp
+
         self.kv_dtype = None
         self.block_size = int(block_size)
         self.dtype = jnp.dtype(dtype if dtype is not None else jnp.float32)
         self.num_blocks = int(window_rows[0]) + 1
-        self.layout = tuple((str(n), tuple(int(d) for d in shape))
-                            for n, shape in k["state"])
-        self.pools = tuple(
-            jnp.zeros((int(k["n_layers"]), self.num_blocks) + shape,
-                      self.dtype) for _, shape in self.layout)
         self.allocator = BlockAllocator(self.num_blocks)
-        self.kinds = (CacheKind(k["name"], k["n_layers"], self.layout,
-                                self.num_blocks, 0,
-                                slice(0, len(self.pools)), self.allocator,
-                                state=True),)
+        self.pools = ()
+        kind = self._state_kind(k, window_rows[0], 0, self.dtype,
+                                self.allocator)
+        self.layout = kind.layout
+        self.kinds = (kind,)
 
     @property
     def quantized(self) -> bool:
@@ -489,6 +538,12 @@ class PagedKVCache:
         ``pool_bytes`` the int8 pool's budget is ~2x the bf16 one (scales
         cost ``8/(block_size*d_head)`` of the win)."""
         return int(pool_bytes) // cls.bytes_per_block(*spec, **spec_kw)
+
+
+def _layers_of(k) -> dict:
+    """The layers a kind's spec says write and read it, for
+    :class:`CacheKind`."""
+    return {n: k[n] for n in ("writers", "readers") if n in k}
 
 
 def _pool_layout(n_heads, d_head, pools):

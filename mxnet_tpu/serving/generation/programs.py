@@ -11,7 +11,9 @@ T=seq-bucket) and ``gen_decode`` (B=max_slots, T=1) are the same
 :func:`_model_step`, the MODEL's cache-aware step plus the per-row
 sampling kernel from :mod:`mxnet_tpu.ops.sampling`; ``gen_verify``; a
 block-diffusion model's ``gen_prefill`` (:func:`_block_fill`) and
-``gen_block``; and ``gen_block_copy``.
+``gen_block``; ``gen_fill`` (:func:`_fill_step`: a one-token model's chunk
+that is not a prompt's last, where the model skips what such a chunk need
+not run); and ``gen_block_copy``.
 
 A model (:func:`as_model`) is an object with ``step(params, tokens,
 positions, lengths, pools, block_tables, *, attention_kernel,
@@ -33,7 +35,13 @@ sparse experts, generation by diffusion over blocks) the second,
 one latent pool, a gated dense layer, sigmoid-routed experts with a shared
 one, of which the chip holds a share) the third,
 :class:`~mxnet_tpu.parallel.hybrid_moe.HybridMoeLM` (window and full
-attention layers over a cache of two kinds) the fourth.
+attention layers over a cache of two kinds) the fourth,
+:class:`~mxnet_tpu.parallel.retention_lm.RetentionLM` (power retention over
+a cache kind that is a slot's state) the fifth,
+:class:`~mxnet_tpu.parallel.sambay_lm.SambaYLM` (state-space, window and
+full attention layers over three kinds side by side, a table a kind and a
+slot's index among them; ``fills_without_head``: it runs its cross-decoder
+at a prompt's last position alone) the sixth.
 
 No step waits for the device: :meth:`GenerationPrograms.run` hands back
 what the jitted call returned, and its caller reads the sampled tokens
@@ -197,8 +205,10 @@ def _model_step(params, pools, tokens, positions, lengths, block_tables,
         # logits at the LAST VALID position of each row feed the sampler
         # (prefill: position len-1 predicts token len; decode: T=1 row 0)
         with jax.named_scope("head"):
+            # (a model that skips all but a prompt's last position hands
+            # back that one row's)
             last_idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0,
-                                tokens.shape[1] - 1)
+                                logits.shape[1] - 1)
             last = jnp.take_along_axis(logits, last_idx[:, None, None],
                                        axis=1)[:, 0, :]
         with jax.named_scope("sample"):
@@ -244,6 +254,23 @@ def _block_fill(params, pools, tokens, positions, lengths, block_tables,
             attention_kernel=attention_kernel, mp_mesh=mp_mesh,
             call="prefill", want_logits=False)
     return (pools,)
+
+
+def _fill_step(params, pools, tokens, positions, lengths, block_tables,
+               *, model, attention_kernel="gather", mp_mesh=None):
+    """A chunk that is not a prompt's last, of a one-token model that
+    ``fills_without_head`` (docs/generation.md "The prefill skip"): the
+    caches and states the chunk's positions leave behind, no logits, no
+    sample — the model leaves out every layer that writes none.  Returns
+    the program's counts beside the pools."""
+    import jax
+
+    with jax.named_scope("fill"):
+        _, pools, aux = model.step(
+            params, tokens, positions, lengths, pools, block_tables,
+            attention_kernel=attention_kernel, mp_mesh=mp_mesh,
+            call="prefill", want_logits=False)
+    return aux, pools
 
 
 def _block_step(params, pools, tokens, positions, lengths, block_tables,
@@ -341,6 +368,7 @@ class GenerationPrograms:
                 ("gen_decode", _model_step),
                 ("gen_verify", _verify_step),
                 ("gen_block", _block_step),
+                ("gen_fill", _fill_step),
                 ("gen_block_copy", block_copy_pools))}
         self._jits: Dict[object, object] = {}
         import jax
@@ -556,10 +584,17 @@ class GenerationPrograms:
             temperature, top_k, top_p)))
 
     def run_fill(self, cache, tokens, positions, lengths, block_tables):
-        """A block-diffusion model's prefill chunk (site ``gen_prefill``):
-        fills the cache, returns nothing to read."""
-        self._run("gen_prefill", cache, _step_args(
-            tokens, positions, lengths, block_tables))
+        """A chunk that fills the cache and returns nothing to read: a
+        block-diffusion model's prefill chunk (site ``gen_prefill``), or a
+        one-token model's chunk that is not its prompt's last (site
+        ``gen_fill``; its counts wait for :meth:`take_aux`)."""
+        args = _step_args(tokens, positions, lengths, block_tables)
+        if self._model.block_len:
+            self._run("gen_prefill", cache, args)
+            return
+        (aux,) = self._run("gen_fill", cache, args)
+        if aux is not None:
+            self._aux.append(aux)
 
     def run_block(self, cache, tokens, positions, lengths, block_tables,
                   masked, n_unmask, read=True):

@@ -939,3 +939,114 @@ def test_retention_step_program_compiles_at_the_cells_shapes(one_chip,
     assert "retention_decode_rows" in model.counters
     # a 512-token chunk's temporaries: the reckoning was 1-1.5 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# -- phi-4-mini-flash: state, window and ONE full pool side by side (PR 44) --
+
+_SBY_KINDS = ("ssm", "swa", "ssm", "full", "gmu", "cross")
+
+
+def _sambay_cell(sds, kinds=_SBY_KINDS, **model_kw):
+    """The published widths at a depth that has every mixer once (the
+    Mamba layers twice: the second hands on its memory), with the cell's
+    pools: 18,000 blocks of 32 of the full kind (a table 1,024 wide: one
+    of 2,048 a row, blocks of 16, is 1 MB of scalars at 128 rows and does
+    not fit the tiles body's fast scalar memory), the window kind and the
+    states of 128 slots."""
+    from mxnet_tpu.parallel import sambay_lm as sl
+
+    cfg = sl.SambaYConfig(layer_kinds=kinds, num_hidden_layers=len(kinds))
+    model = sl.SambaYLM(cfg, max_len=32768, **model_kw)
+    params = {k: sds(s, jnp.bfloat16)
+              for k, s in sl.sambay_param_shapes(cfg).items()}
+    full, window, state = model.cache_spec()["kinds"]
+    pools = tuple(sds((1, 18000, 32, w), jnp.bfloat16)
+                  for _, w in full["pools"]) \
+        + tuple(sds((window["n_layers"], 2338, 32, w), jnp.bfloat16)
+                for _, w in window["pools"]) \
+        + tuple(sds((state["n_layers"], 129) + s, jnp.float32)
+                for _, s in state["state"])
+    return model, params, pools
+
+
+@pytest.mark.parametrize("B,T", [(128, 1), (1, 128), (1, 512)],
+                         ids=["decode", "prefill128", "prefill512"])
+def test_selective_scan_kernels_compile_at_the_cells_shapes(one_chip,
+                                                            monkeypatch, B,
+                                                            T):
+    """``_ssm_call_decode`` (128 rows, a row's state of 16 x 5,120 float32
+    a block: the first 16 of the pool's 24 sublanes) and ``_ssm_call_t<T>_prefill`` (rows x 10 tiles of 512
+    channels, the chunk's positions in order) at
+    ``phi-4-mini-flash``'s widths: the pool is updated in place, never
+    copied."""
+    from mxnet_tpu.ops import selective_scan as ss
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    f32 = jnp.float32
+
+    def fn(step, u, Bm, Cm, A, fresh, pool, slots, conv):
+        old = ss.conv_state(pool, 3, slots, 4, kernel=True)
+        return ss.selective_scan(step, u + old[:, :1], Bm, Cm, A, fresh, pool,
+                                 slots, conv, layer=3, kernel=True)
+
+    text = jax.jit(fn, donate_argnums=(6,)).lower(
+        sds((B, T, 5120), f32), sds((B, T, 5120), f32), sds((B, T, 16), f32),
+        sds((B, T, 16), f32), sds((16, 5120), f32), sds((B,), jnp.bool_),
+        sds((9, 129, 24, 5120), f32), sds((B,), jnp.int32),
+        sds((B, 3, 5120), f32)).compile().as_text()
+    assert "_ssm_call_conv_decode" in text
+    assert ("_ssm_call_decode" if T == 1 else f"_ssm_call_t{T}_prefill") \
+        in text
+    assert not [ln for ln in text.splitlines()
+                if "f32[9,129,24,5120" in ln and " copy(" in ln]
+
+
+@pytest.mark.parametrize("kind,S,T,ring", [
+    ("gen_decode", 128, 1, 32), ("gen_fill", 1, 512, 64),
+    ("gen_prefill", 1, 512, 64), ("gen_prefill", 1, 128, 32)],
+    ids=["decode", "fill512", "final512", "final128"])
+def test_sambay_step_program_compiles_at_the_cells_shapes(one_chip,
+                                                          monkeypatch, kind,
+                                                          S, T, ring):
+    """The cell's programs as the service dispatches them, at a depth that
+    has every mixer: ``gen_decode`` (128 rows), ``gen_fill`` (a chunk that
+    is not a prompt's last: no head) and ``gen_prefill`` (a prompt's last
+    chunk: the cross-decoder at one position) over the three kinds' pools
+    and a table a kind, every pool updated in place, every weight read as
+    stored — the tied embedding as the head's weight among them —, the
+    counts handed back."""
+    from mxnet_tpu.serving.generation import programs as gp
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    model, params, pools = _sambay_cell(sds)
+    i32 = jnp.int32
+    args = (params, pools, sds((S, T), i32), sds((S, T), i32), sds((S,), i32),
+            (sds((S, 1024), i32), sds((S, ring), i32), sds((S, 1), i32)))
+    if kind == "gen_fill":
+        step = gp._fill_step
+    else:
+        step = gp._model_step
+        args += (sds((S,), jnp.uint32), sds((S,), jnp.uint32),
+                 sds((S,), jnp.float32), sds((S,), i32),
+                 sds((S,), jnp.float32))
+    compiled = jax.jit(functools.partial(
+        step, model=model, attention_kernel="paged"),
+        donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    phase = "decode" if T == 1 else "prefill"
+    assert text.count("_ssm_call_decode" if T == 1
+                      else f"_ssm_call_t{T}_prefill") >= 2
+    assert f"_t{T}_swa_{phase}" in text
+    # a chunk that fills runs no attention of the full kind and no head
+    assert (f"_t1_cross_{phase}" in text) == (kind != "gen_fill")
+    assert (f"_t1_full_{phase}" in text) == (kind != "gen_fill")
+    for shape in ("bf16[1,18000,32,1280]", "bf16[1,2338,32,1280]",
+                  "f32[2,129,24,5120]"):
+        makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
+                  for ln in text.splitlines() if f"= {shape}" in ln}
+        assert makers <= _IN_PLACE | {"get-tuple-element"}, (shape, makers)
+    _no_argument_copies(text)
+    assert "ssm_decode_rows" in model.counters
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

@@ -259,7 +259,8 @@ def test_a_program_kind_is_one_function_and_notes_the_parents_keys(
     assert {kind: fn for kind, (fn, _) in progs._kinds.items()} == {
         "gen_prefill": gp._model_step, "gen_decode": gp._model_step,
         "gen_verify": gp._verify_step,
-        "gen_block": gp._block_step, "gen_block_copy": gp.block_copy_pools}
+        "gen_block": gp._block_step, "gen_fill": gp._fill_step,
+        "gen_block_copy": gp.block_copy_pools}
     assert not any(hasattr(gp, name) for name in (
         "_model_step_q", "_verify_step_q"))
     cache = PagedKVCache(num_blocks=16, block_size=8, kv_dtype=kv_dtype,

@@ -210,11 +210,19 @@ def test_cache_is_built_from_the_state_kind(svc):
     classic = PagedKVCache(n_layers=2, n_heads=2, d_head=8, num_blocks=9,
                            block_size=4)
     assert not classic.kinds[0].state and classic.blocks_for(9) == 3
-    with pytest.raises(ValueError, match="stands alone"):
-        PagedKVCache(block_size=4, window_rows=(2, 8), kinds=(
-            dict(name="full", n_layers=1, pools=(("k", 8), ("v", 8))),
-            dict(name="state", n_layers=1,
-                 state=(("state", (2, 5, 16, 8)),))))
+    # since PR 44 a state kind may stand BEHIND a paged kind (the slots'
+    # indices under an allocator of its own); it may not lead one
+    kinds = (dict(name="full", n_layers=1, pools=(("k", 8), ("v", 8))),
+             dict(name="state", n_layers=1,
+                  state=(("state", (2, 5, 16, 8)),)))
+    beside = PagedKVCache(num_blocks=9, block_size=4, window_rows=(2, 8),
+                          kinds=kinds)
+    assert [k.state for k in beside.kinds] == [False, True]
+    assert beside.kinds[1].num_blocks == 3 \
+        and beside.kinds[1].allocator is not beside.allocator
+    with pytest.raises(ValueError, match="first cache kind keeps every"):
+        PagedKVCache(num_blocks=9, block_size=4, window_rows=(2, 8),
+                     kinds=kinds[::-1])
 
 
 def test_a_padded_position_and_an_idle_row_leave_the_state_bit_equal(
