@@ -463,11 +463,24 @@ class _Flight(NamedTuple):
     block: Optional[_BlockPass] = None
 
 
+class _First(NamedTuple):
+    """A prefill's first token, dispatched and not read (docs/generation.md
+    "the step in flight"): the request, the ``(1,)`` token as the last
+    chunk's program handed it back, the counts (``aux``) of the programs
+    dispatched up to that chunk that nobody has read yet, and the tokens
+    the chunks fed, which are counted where the programs' counts are."""
+    req: _GenRequest
+    token: object
+    aux: tuple = ()
+    prefill_tokens: int = 0
+
+
 class _LandFirst(Exception):
     """Raised under the schedule when a row of the step in flight has to
     leave its slot — a preemption, a cancel, a deadline, a shutdown that
     does not drain: what the step gave it is still on the device, so the
-    engine reads and emits that step first and schedules again."""
+    engine reads and emits that step first and schedules again.  A first
+    token that a prefill left on the device lands with it."""
 
 
 class GenerationStream:
@@ -821,6 +834,14 @@ class GenerationService:
         # onward they would key a second lowering of every decode width
         self._flight: Optional[_Flight] = None
         self._runs_ahead = not (cfg.speculative or cfg.mp_devices > 1)
+        # the first tokens that this pass's prefills left on the device,
+        # by request id: the decode step of the pass is fed from them
+        # (``place_first``) and dispatched before they are read.  Where
+        # nothing may stay in flight each is read as its prefill ends
+        self._firsts: Dict[int, _First] = {}
+        # what a first token is placed into where no step is in flight
+        # (warm-up leaves one on the device)
+        self._no_tokens = _np.zeros(cfg.max_slots, _np.int32)
         # a block-diffusion model's prefill [chunks, tokens] dispatched
         # since the last block pass was: counted with the next one
         self._prefill_uncounted = [0, 0]
@@ -843,6 +864,11 @@ class GenerationService:
                         # tokens were read, and those dispatched with
                         # nothing in flight
                         "steps_ahead": 0, "steps_drained": 0,
+                        # first tokens of prefills read after the decode
+                        # step they fed was dispatched, and those read
+                        # at once (nothing may stay in flight, or the
+                        # pass had nothing to decode)
+                        "prefills_ahead": 0, "prefills_read": 0,
                         # calls of a sampling program (a decode or verify
                         # step, a prefill chunk), by the body
                         # their rows' knobs make its sampler take
@@ -960,6 +986,16 @@ class GenerationService:
                      "draw (temperature and noise, no sort), filter (one "
                      "sort for top-k / top-p)")
             for b in SAMPLER_BODIES]
+        self._c_prefills = {
+            key: reg.counter(
+                "serving_prefill_first_tokens_total", labels={"read": how},
+                help="first tokens of prefills by when the host read "
+                     "them: ahead (left on the device, fed to the pass's "
+                     "decode step from there and read after its "
+                     "dispatch) or at_once (speculation, an mp mesh, a "
+                     "pass with nothing to decode)")
+            for key, how in (("prefills_ahead", "ahead"),
+                             ("prefills_read", "at_once"))}
 
     # -- submission ---------------------------------------------------------------
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
@@ -1140,8 +1176,9 @@ class GenerationService:
             # every (T, W) pair the chunk planner can emit — the plain
             # per-rung ladder when chunked prefill is off
             for tb, wp in sigs:
-                self._programs.run("gen_prefill", self._cache,
-                                   *zeros(tb, wp, slots=1).operands)
+                first, _ = self._programs.run(
+                    "gen_prefill", self._cache,
+                    *zeros(tb, wp, slots=1).operands)
                 if self._fills:
                     self._programs.run_fill(self._cache, *zeros(
                         tb, wp, slots=1, sampler=False).operands)
@@ -1151,9 +1188,14 @@ class GenerationService:
                                              *z.operands)
             if self._runs_ahead and widths:
                 # the step in flight hands its tokens on through one
-                # slot-sized program with no model in it
-                _synced(self._programs.carry_tokens(toks, z.tokens,
-                                                    z.lengths > 0))
+                # slot-sized program with no model in it, and a prefill
+                # its first token through another: placed into the
+                # tokens of a step, and where none is in flight into the
+                # array this leaves on the device
+                self._no_tokens = self._programs.place_first(
+                    self._programs.place_first(toks, first, 0), first, 0)
+                _synced(self._programs.carry_tokens(
+                    self._no_tokens, z.tokens, z.lengths > 0))
             # speculative verify (docs/generation.md "Speculative
             # decoding"): every (Tk, W) pair on the ladders
             for tk in self._verify_buckets:
@@ -1330,8 +1372,10 @@ class GenerationService:
 
     def _iterate(self) -> bool:
         """One pass of the loop with requests queued or running: schedule
-        under the lock, prefill what was admitted, dispatch one decode
-        step over what runs, and read and emit the step before it
+        under the lock, dispatch the prefill chunks of what was admitted,
+        dispatch one decode step over what runs — a row that joins from
+        its prefill fed its first token from the device — and only then
+        read and emit the step before it and those first tokens
         (docs/generation.md "the step in flight").  False ends the
         loop."""
         try:
@@ -1359,7 +1403,8 @@ class GenerationService:
             if running:
                 self._decode_isolated(running)
             else:
-                # nothing left to feed: the pass reads the last step
+                # nothing left to feed: the pass reads the last step, and
+                # the first tokens of what it admitted
                 self._land()
         except Exception as exc:  # noqa: BLE001 — the loop must survive
             # any per-iteration surprise with minimum blast radius:
@@ -1426,9 +1471,10 @@ class GenerationService:
     def _flies(self, r: _GenRequest) -> bool:
         """Whether ``r`` is a row of the step in flight: what that step
         gave it — a token, a block's unmasked positions, its commit — is
-        on the device, and the host's view of the row one step behind."""
+        on the device, and the host's view of the row one step behind.
+        Or its prefill's first token is, unread."""
         f = self._flight
-        return f is not None and r.rid in f.lead
+        return (f is not None and r.rid in f.lead) or r.rid in self._firsts
 
     def _lead(self, r: _GenRequest) -> int:
         """The positions ``r``'s context moves on when the step in flight
@@ -1440,12 +1486,13 @@ class GenerationService:
         return 0 if f is None else f.lead.get(r.rid, 0)
 
     def _ends_in_flight(self, r: _GenRequest) -> bool:
-        """Whether the step in flight brings ``r`` to its
-        ``max_new_tokens``: the token it sampled, or the block it
-        commits, whose tokens the sequence does not hold yet."""
+        """Whether what is on the device unread brings ``r`` to its
+        ``max_new_tokens``: the token the step in flight sampled (its
+        prefill's first token counts like it), or the block it commits,
+        whose tokens the sequence does not hold yet."""
         lead = self._lead(r)
         new = (r.ctx_len + lead - len(r.seq_tokens)) if self._block_len \
-            else lead
+            else lead + (r.rid in self._firsts)
         return new > 0 and r.n_generated + new >= r.max_new
 
     # -- scheduling (all _locked helpers hold self._lock) -------------------------
@@ -2146,29 +2193,76 @@ class GenerationService:
                         _np.asarray([r.seed], _np.uint32),
                         _np.asarray([ctx], _np.uint32), *knobs)
                     self._count_sampler_step(*knobs)
-                if not resumed and off + take >= ctx:
-                    # the one read of a prefill: it waits for the chunks
-                    # before it too (and for a decode step in flight)
-                    next_tok = _synced(next_tok)
-                    self._count_aux(self._programs.take_aux())
             r.rung_s[tb] = r.rung_s.get(tb, 0.0) \
                 + (time.perf_counter() - t_rung0)
             if self._windows:
                 # what the chunk wrote and no later query sees goes back
                 # before the next row's prefill asks for blocks
                 self._slide(r, off + take, off + take)
-        self._counts["prefill_tokens"] += sum(p[1] for p in plan)
-        r.seg("decode", time.perf_counter())
-        # make this context's full blocks available to the NEXT shared-
-        # prompt arrival immediately (not only at finish): concurrent
-        # identical prompts then hit while the first is still decoding
-        if self._prefix is not None and not resumed:
-            self._prefix.insert(r.seq_tokens[:ctx], r.blocks)
+        fed = sum(p[1] for p in plan)
         if resumed:
+            self._counts["prefill_tokens"] += fed
+            r.seg("decode", time.perf_counter())
             return
+        # nothing of the prefill is read here: the context stands at the
+        # prompt's end by count, and the first token stays on the device
+        # for the decode step of this pass to be fed from, read after
+        # that step's dispatch (:meth:`_land_firsts`) — at once where
+        # nothing may stay in flight
         r.ctx_len = r.prompt_len
-        with self._phase("emit", "serving.emit"):
-            self._emit_token(r, int(next_tok[0]))
+        self._firsts[r.rid] = _First(r, next_tok, self._programs.take_aux(),
+                                     fed)
+        if not self._runs_ahead:
+            self._land_firsts()
+
+    def _land_firsts(self, ahead: bool = False) -> None:
+        """Read and emit the first tokens that prefills left on the
+        device, each as its own read in the order of the prefills: a
+        prompt's token is served when its chunks have ended, not when
+        the last admitted prompt's have.  With ``ahead`` the decode step
+        they fed has been dispatched (they were ready before it started:
+        the wait is for the chunks, with that step queued behind them);
+        otherwise the pass had nothing to decode, a step failed before
+        its dispatch, or the service leaves nothing in flight.  A
+        prompt's full blocks are shown to the prefix index when its
+        token has been read, as they were when a prefill read it itself:
+        concurrent identical prompts then hit while the first is still
+        decoding.  A read that fails costs no token: the request whose
+        prefill it was is requeued, or failed past its budget, with
+        nothing of it served, and the step fed from the token drops its
+        row."""
+        if not self._firsts:
+            return
+        firsts, self._firsts = self._firsts, {}
+        which = "prefills_ahead" if ahead else "prefills_read"
+        for r, token, aux, fed in firsts.values():
+            try:
+                with self._phase("step", "serving.prefill",
+                                 args={"rid": r.rid, "first_token": True,
+                                       "ahead": ahead}, ctx=r.trace):
+                    tok = _synced(token, of="prefill")
+            except Exception as exc:  # noqa: BLE001 — the device's error
+                f = self._flight
+                # (the flight's dict and list, changed in place: a step
+                # emits to the rows it still names)
+                if f is not None and f.lead.pop(r.rid, None) is not None:
+                    f.step.rows[:] = [row for row in f.step.rows
+                                      if row[1] is not r]
+                r.ctx_len = 0            # prefilled anew, as a fresh one
+                self._requeue_or_fail(r, exc)
+                continue
+            self._counts[which] += 1
+            self._c_prefills[which].inc()
+            with self._phase("emit", "serving.emit"):
+                # (the host's count of the tokens fed beside the programs'
+                # own counts of them: a reader of stats() finds them
+                # agreeing)
+                self._count_aux(aux)
+                self._counts["prefill_tokens"] += fed
+                if self._prefix is not None:
+                    self._prefix.insert(r.seq_tokens[:r.ctx_len], r.blocks)
+                r.seg("decode", time.perf_counter())
+                self._emit_token(r, int(tok[0]))
 
     def _decode_step(self, batch: List[_GenRequest],
                      ahead: bool = False) -> None:
@@ -2325,26 +2419,35 @@ class GenerationService:
         per running row).  With a step in flight this one is built from
         counts alone — a row of that step sits one position further and
         takes its token from the device (``carry_tokens``), a row that
-        joins brings its own from the host — and dispatched BEFORE that
-        step's tokens are read and emitted.  With ``ahead`` the step
-        stays in flight itself; otherwise it is read before returning."""
-        last = self._flight
+        joins from its prefill in this pass takes the token that left
+        there (``place_first``), any other row that joins brings its own
+        from the host — and dispatched BEFORE that step's tokens, and
+        those first tokens, are read and emitted.  With ``ahead`` the
+        step stays in flight itself; otherwise it is read before
+        returning (a retry, a bisection, a service that leaves nothing
+        in flight: no first token is unread by then)."""
+        last, firsts = self._flight, self._firsts
         lead = last.lead if last is not None else {}
         with self._phase("build", "serving.decode.build"):
             b = self._build_step(
-                batch, 1, lambda r: [0 if r.rid in lead
+                batch, 1, lambda r: [0 if r.rid in lead or r.rid in firsts
                                      else r.seq_tokens[r.ctx_len]],
                 lead=lead)
             tokens = b.tokens
-            if last is not None:
+            if last is not None or firsts:
                 # whatever run() returned for the last step is what the
                 # rows that continue are fed, as it is what they are
-                # served
+                # served; a row that joins from its prefill is fed what
+                # that returned, put at its slot of the same array
                 keep = _np.zeros(len(b.lengths), bool)
+                prev = last.tokens if last is not None else self._no_tokens
                 for i, r in b.rows:
-                    keep[i] = r.rid in lead
-                tokens = self._programs.carry_tokens(last.tokens, tokens,
-                                                     keep)
+                    first = firsts.get(r.rid)
+                    keep[i] = first is not None or r.rid in lead
+                    if first is not None:
+                        prev = self._programs.place_first(prev, first.token,
+                                                          i)
+                tokens = self._programs.carry_tokens(prev, tokens, keep)
         t_step0 = time.perf_counter()
         with self._phase("step", "serving.decode",
                          args={"running": len(batch), "width": b.width,
@@ -2357,6 +2460,7 @@ class GenerationService:
             reads = self._fly(step, ahead)
         for f, read in reads:
             self._emit_flight(f, read)
+        self._land_firsts(ahead=True)
 
     def _fly(self, step: _Flight, ahead: bool) -> list:
         """What follows a step's dispatch, inside its span: count it, then
@@ -2394,22 +2498,23 @@ class GenerationService:
                 self._counts[name] += int(value)
 
     def _land(self) -> None:
-        """Read and emit the step in flight, if there is one: whatever
-        needs the values on the host or the rows at rest calls this
-        first.  A read that fails costs no token: the rows keep the
-        host's view, and the next step feeds them from it again."""
+        """Read and emit the step in flight, if there is one, and the
+        first tokens that prefills left on the device: whatever needs
+        the values on the host or the rows at rest calls this first.  A
+        read that fails costs no token: the rows keep the host's view,
+        and the next step feeds them from it again."""
         f, self._flight = self._flight, None
-        if f is None:
-            return
-        try:
-            with self._phase("step", "serving.decode" if f.block is None
-                             else "serving.block_step",
-                             args={"iteration": f.iteration}):
-                read = self._read(f)
-        except Exception as exc:  # noqa: BLE001 — the device's error
-            self._note_step_failure(exc)
-            return
-        self._emit_flight(f, read)
+        if f is not None:
+            try:
+                with self._phase("step", "serving.decode" if f.block is None
+                                 else "serving.block_step",
+                                 args={"iteration": f.iteration}):
+                    read = self._read(f)
+            except Exception as exc:  # noqa: BLE001 — the device's error
+                self._note_step_failure(exc)
+            else:
+                self._emit_flight(f, read)
+        self._land_firsts()
 
     def _emit_flight(self, f: _Flight, read) -> None:
         """Emit what a step gave its rows: a one-token step's tokens, a

@@ -50,6 +50,9 @@ dispatched the NEXT decode step, whose ``tokens`` operand
 :meth:`GenerationPrograms.carry_tokens` merges on the device from the
 tokens still there and the host's (docs/generation.md "The step in
 flight"; one slot-sized program, no model in it, not a signature below).
+A prefill's first token joins that step the same way:
+:meth:`GenerationPrograms.place_first` puts it, unread, at its row of the
+tokens the carry merges (a second slot-sized program, the slot an operand).
 A block pass hands its block state on the same way
 (:meth:`GenerationPrograms.run_block` without ``read``,
 :meth:`GenerationPrograms.carry_block`).
@@ -124,13 +127,17 @@ def _step_args(tokens, positions, lengths, block_tables, *sampler):
         _np.asarray(a, dt) for a, dt in zip(sampler, _SAMPLER_DTYPES))
 
 
-def _synced(*outs):
+def _synced(*outs, of=None):
     """The step's sampled tokens as NumPy arrays: the read that waits for
     the device, under its own span so that the wait is not mistaken for
     host work (``serving.step.dispatch`` ends where the call returned).
     :meth:`GenerationPrograms.run` leaves this read to its caller, which
-    may make it after it has dispatched the next step."""
-    with _tracing.span("serving.step.sync", cat="serving"):
+    may make it after it has dispatched the next step.  ``of`` says in
+    the span's arguments what is read where that is not a decode step's
+    tokens (``"prefill"``: first tokens, read after the decode step they
+    fed was dispatched)."""
+    with _tracing.span("serving.step.sync", cat="serving",
+                       args={"of": of} if of else None):
         arrays = tuple(_np.asarray(o) for o in outs)
     return arrays[0] if len(arrays) == 1 else arrays
 
@@ -168,6 +175,20 @@ def _carry(prev, tokens, keep):
     with jax.named_scope("carry"):
         return jnp.where(keep[:, None], prev[:, None].astype(jnp.int32),
                          tokens)
+
+
+def _place_first(prev, first, slot):
+    """``prev (S,)``, tokens on the device that the next step's rows are
+    fed from (:func:`_carry`), with a prefill's ``first (1,)`` token at
+    row ``slot (1,)``: the row joins the step from its prefill without
+    the host reading the token.  The slot is an operand: one slot-sized
+    program a service however many rows a pass admits, no model in it."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("first_token"):
+        return jax.lax.dynamic_update_slice(
+            prev.astype(jnp.int32), first.astype(jnp.int32), (slot[0],))
 
 
 def _carry_block(unmasked, prev_tokens, prev_masked, tokens, masked, keep):
@@ -375,6 +396,8 @@ class GenerationPrograms:
 
         # (creating one traces nothing)
         self._carry_jit = jax.jit(_carry_block if model.block_len else _carry)
+        self._place_jit = jax.jit(_place_first)
+        self._placed = 0                    # first tokens placed so far
         self._aux: list = []                # run()s' aux nobody took yet
         self._lock = threading.Lock()
         self._stats: Dict[tuple, Dict[str, int]] = {}
@@ -526,7 +549,9 @@ class GenerationPrograms:
         out = []
         for key, thunk in texts:
             n = launches.get(key)
-            if n is None:       # the carry, keyed ("carry", tokens' shape)
+            if n is None and key[0] == "first_token":
+                n = self._placed
+            elif n is None:     # the carry, keyed ("carry", tokens' shape)
                 n = sum(m for fed, m in launches.items()
                         if ("tokens", key[1], "int32") in fed[1])
             out.append((None, key, n, thunk))
@@ -570,6 +595,23 @@ class GenerationPrograms:
         if isinstance(prev, _np.ndarray):
             return _np.where(keep[:, None], prev[:, None], tokens)
         return self._carry_jit(prev, tokens, keep)
+
+    def place_first(self, prev, first, slot: int):
+        """``prev (S,)`` — the last step's tokens, or any ``(S,)`` int32
+        array where no step is in flight — with a prefill's ``first
+        (1,)`` token, as :meth:`run` returned it, at row ``slot``
+        (:func:`_place_first`): what :meth:`carry_tokens` then feeds the
+        row from.  Nothing is read; ``prev`` itself stays as it was."""
+        key = ("first_token", tuple(prev.shape))
+        if key not in self._texts:
+            import jax
+
+            i32 = jax.ShapeDtypeStruct(key[1], _np.int32)
+            one = jax.ShapeDtypeStruct((1,), _np.int32)
+            self._texts[key] = _device_scopes.text_thunk(
+                self._place_jit, (i32, one, one))
+        self._placed += 1
+        return self._place_jit(prev, first, _np.asarray([slot], _np.int32))
 
     def run_verify(self, cache, tokens, positions, lengths, block_tables,
                    seeds, counters, temperature, top_k, top_p):

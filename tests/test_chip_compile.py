@@ -375,6 +375,28 @@ def test_decode_step_program_compiles(one_chip, monkeypatch, kv_dtype):
                     "copy-done"}, made
 
 
+@pytest.mark.parametrize("slots", [32, 128, 256])
+def test_first_token_programs_compile_at_the_cells_slots(one_chip, slots):
+    """The two slot-sized programs with no model in them through which a
+    decode step is fed what is still on the device — a prefill's first
+    token placed at its row (the slot an operand: one program whatever
+    the number of rows a pass admits), then the merge with the host's
+    tokens — at the serving cells' slot counts: an update in place of a
+    dynamic slice and a select, no gather, scatter or loop."""
+    from mxnet_tpu.serving.generation import programs as gp
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = sds((slots,), jnp.int32)
+    one = sds((1,), jnp.int32)
+    placed = _compile(gp._place_first, i32, one, one)
+    assert "dynamic-update-slice" in placed
+    carried = _compile(gp._carry, i32, sds((slots, 1), jnp.int32),
+                       sds((slots,), jnp.bool_))
+    for text in (placed, carried):
+        assert not re.search(r"\b(gather|scatter|while|custom-call)\(",
+                             text)
+
+
 def test_decode_step_program_compiles_mp4(topo, monkeypatch):
     """The same step as a GSPMD program over ``mp=4`` (GenerationConfig(
     mp_devices=4)): params placed by the transformer's partition rules,

@@ -207,7 +207,7 @@ def test_model_step_names_its_layer_parts(services, model):
 
 @pytest.mark.parametrize("kind,service", [
     ("decode", "gpt2"), ("prefill", "gpt2"), ("carry", "gpt2"),
-    ("block_copy", "gpt2"), ("verify", "gpt2-verify"), ("block", "sdar"),
+    ("first_token", "gpt2"), ("block_copy", "gpt2"), ("verify", "gpt2-verify"), ("block", "sdar"),
     ("fill", "sdar"), ("carry", "sdar")])
 def test_every_program_kind_says_its_kind(services, kind, service):
     """The outermost scope of each traced function is the program's kind

@@ -407,14 +407,14 @@ def test_failed_read_of_the_step_in_flight_costs_no_token(
     svc.warmup()
     synced, reads, raised = engine_mod._synced, [0], []
 
-    def failing(*outs):
+    def failing(*outs, **kw):
         f = svc._flight
         if f is not None and outs[0] is f.tokens:
             reads[0] += 1
             if reads[0] >= 3 and len(raised) < times:
                 raised.append(reads[0])
                 raise RuntimeError("injected read failure")
-        return synced(*outs)
+        return synced(*outs, **kw)
 
     monkeypatch.setattr(engine_mod, "_synced", failing)
     rs = np.random.RandomState(8)

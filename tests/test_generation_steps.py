@@ -434,10 +434,10 @@ def test_next_step_is_dispatched_before_the_last_one_is_read(
     log, steps = [], {}
     synced = engine_mod._synced
 
-    def reading(*outs):
+    def reading(*outs, **kw):
         if id(outs[0]) in steps:
             log.append(("read", steps[id(outs[0])]))
-        return synced(*outs)
+        return synced(*outs, **kw)
 
     monkeypatch.setattr(engine_mod, "_synced", reading)
     svc = GenerationService(params, CFG, _gc(max_slots=3), start=False)
@@ -586,3 +586,299 @@ def test_warmup_compiles_the_parents_programs_and_nothing_after(
     assert stats["counts"]["steps_ahead"] > 0 \
         and stats["counts"]["failed"] == 0
     assert stats["compiled_signatures"] == 10
+
+
+# -- a prefill's first token stays on the device (docs/generation.md) ---------------
+def _drive(svc, streams, limit=400):
+    """The loop by hand, a pass a call, until every stream has ended or
+    the loop says it is over."""
+    for _ in range(limit):
+        if all(h.finished for h in streams) or not svc._iterate():
+            break
+
+
+def _admitting_workload(params, ahead, sampled):
+    """Twelve clients on three slots with outputs of 2 to 5 tokens, so that
+    most passes admit: prompts of one chunk and of several, three of them
+    the same two full blocks (the later ones hit the prefix index whole:
+    one position recomputed, copy-on-write), one of a single token to
+    generate.  Returns ``(tokens by request, stats)``."""
+    svc = GenerationService(params, CFG, _gc(max_slots=3, num_blocks=64),
+                            start=False)
+    if not ahead:
+        svc._runs_ahead = False          # every first token is read at once
+    rs = np.random.RandomState(17)
+    shared = rs.randint(0, CFG.vocab, 16)
+    lens = (5, 30, 16, 9, 31, 16, 7, 23, 1, 16, 12, 27)
+    news = (3, 2, 4, 5, 2, 3, 1, 4, 5, 2, 3, 2)
+    prompts = [shared if n == 16 else rs.randint(0, CFG.vocab, n)
+               for n in lens]
+    kw = [dict(temperature=0.8, top_k=10, seed=100 + i) if sampled else {}
+          for i in range(len(prompts))]
+    hs = [svc.submit(p, max_new_tokens=n, **k)
+          for p, n, k in zip(prompts, news, kw)]
+    _drive(svc, hs)
+    svc._iterate()                       # retires the last slot
+    outs = [h.result(1) for h in hs]
+    stats = svc.stats()
+    svc.stop(drain=False, timeout=30)
+    assert [len(o) for o in outs] == list(news)
+    return prompts, outs, stats
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "seeded"])
+def test_first_tokens_carried_serve_the_synchronous_streams(params, sampled):
+    """(1) over a schedule in which most passes admit, the engine that
+    leaves a prefill's first token on the device for the pass's decode
+    step serves, token for token, what the engine that reads every first
+    token at once serves."""
+    prompts, got, stats = _admitting_workload(params, True, sampled)
+    _, ref, ref_stats = _admitting_workload(params, False, sampled)
+    assert got == ref
+    if not sampled:
+        for p, toks in zip(prompts, got):
+            assert toks == greedy_oracle(params, p, len(toks))
+    c, rc = stats["counts"], ref_stats["counts"]
+    for s in (c, rc):
+        assert s["prefills_ahead"] + s["prefills_read"] == s["submitted"] \
+            == 12
+        assert s["prefix_hits"] >= 2 and s["cow_copies"] >= 2
+        assert s["failed"] == 0 and s["tokens"] == sum(map(len, got))
+    assert rc["prefills_ahead"] == 0
+    # a request of one token to generate is not fed to a decode step; the
+    # others all joined the step of the pass that admitted them
+    assert c["prefills_ahead"] >= 10
+    assert c["prefill_tokens"] == rc["prefill_tokens"]
+    assert c["cached_tokens"] == rc["cached_tokens"]
+
+
+def test_nothing_is_read_between_a_prompts_chunks_and_the_decode_dispatch(
+        params, monkeypatch):
+    """(2) in a pass that admits and decodes, no read lies between the
+    first chunk's dispatch and the decode step's; the first tokens are
+    read after it, behind the last step's; ``prefills_ahead`` counts the
+    admissions of the passes that decoded and ``prefills_read`` the
+    others'."""
+    from mxnet_tpu.serving.generation import engine as engine_mod
+
+    log = []
+    synced = engine_mod._synced
+    monkeypatch.setattr(engine_mod, "_synced", lambda *outs, **kw: (
+        log.append(("read", kw.get("of") or "step")), synced(*outs, **kw))[1])
+    svc = GenerationService(params, CFG, _gc(max_slots=3), start=False)
+    svc.warmup()
+    run, prefill = svc._programs.run, svc._prefill
+    monkeypatch.setattr(svc._programs, "run", lambda kind, *a, **kw: (
+        log.append(("dispatch", kind)), run(kind, *a, **kw))[1])
+    monkeypatch.setattr(svc, "_prefill", lambda r: (
+        log.append(("admit", r.rid)), prefill(r))[1])
+    rs = np.random.RandomState(23)
+    waves = [[(6, 1)],                   # alone, one token: nothing decodes
+             [(9, 6), (30, 5), (14, 4)],  # the cold pass: all slots
+             [(31, 3)], [(5, 2), (20, 3)]]
+    hs, ahead, read, passes = [], 0, 0, []
+    for wave in waves:
+        hs += [svc.submit(rs.randint(0, CFG.vocab, n), max_new_tokens=m)
+               for n, m in wave]
+        for _ in range(60):
+            if not svc._waiting:
+                break
+            del log[:]
+            svc._iterate()
+            admits = [e for e in log if e[0] == "admit"]
+            if not admits:
+                continue
+            decode = ("dispatch", "gen_decode")
+            passes.append((len(admits), decode in log))
+            if decode not in log:
+                read += len(admits)
+                assert ("read", "prefill") in log
+                continue
+            ahead += len(admits)
+            first = log.index(("dispatch", "gen_prefill"))
+            at = log.index(decode)
+            assert not [e for e in log[first:at] if e[0] == "read"], log
+            # the last step's tokens are read first (they were ready
+            # first), then each admitted prompt's token on its own
+            reads = [e for e in log[at + 1:] if e[0] == "read"]
+            assert reads[-len(admits):] == [("read", "prefill")] * len(admits)
+            assert reads.count(("read", "prefill")) == len(admits)
+    _drive(svc, hs)
+    svc._iterate()
+    c = svc.stats()["counts"]
+    svc.stop(drain=False, timeout=30)
+    assert all(h.result(1) for h in hs)
+    assert (c["prefills_ahead"], c["prefills_read"]) == (ahead, read)
+    assert read == 1 and ahead == 6 and (3, True) in passes
+    assert c["steps_ahead"] + c["steps_drained"] == sum(
+        1 for _, rids in svc.membership_history() if rids)
+
+
+_EDGES = ["max_new_1", "eos_first", "cancel", "deadline", "preempt",
+          "read_fails", "read_fails_past_budget", "stop_no_drain", "kill"]
+
+
+@pytest.mark.parametrize("edge", _EDGES)
+def test_a_first_token_left_on_the_device_at_the_edges(params, monkeypatch,
+                                                      edge):
+    """(3) two rows decode; a third is admitted beside them and ``edge``
+    happens while its first token is on the device unread (at the
+    dispatch of the decode step of the pass that admitted it).  Whatever
+    it is, the other rows are served the oracle's tokens, each once."""
+    from mxnet_tpu.serving import ServingClosedError
+    from mxnet_tpu.serving.batcher import DeadlineExceededError
+    from mxnet_tpu.serving.generation import GenerationStepError
+    from mxnet_tpu.serving.generation import engine as engine_mod
+
+    svc = GenerationService(params, CFG, _gc(max_slots=3), start=False)
+    rs = np.random.RandomState(13)
+    prompts = [rs.randint(0, CFG.vocab, n) for n in (6, 11, 19)]
+    want = [greedy_oracle(params, p, 8) for p in prompts]
+    seen = [[] for _ in prompts]
+
+    def submit(i, **kw):
+        kw.setdefault("max_new_tokens", 8)
+        return svc.submit(prompts[i], on_token=lambda rid, t: seen[i].append(
+            t), **kw)
+
+    hs = [submit(0), submit(1)]
+    svc._iterate()
+    svc._iterate()
+    assert [len(s) for s in seen] == [2, 2, 0] and svc._flight is not None
+    the = submit(2, **{"max_new_1": dict(max_new_tokens=1),
+                       "eos_first": dict(eos_token=want[2][0])}.get(edge, {}))
+    hs.append(the)
+    req, fired, at_kill = the._req, [], []
+    run, synced = svc._programs.run, engine_mod._synced
+
+    def dispatching(kind, *a, **kw):
+        if kind == "gen_decode" and req.rid in svc._firsts and not fired:
+            fired.append(svc._slots.index(req))
+            assert svc._flies(req) and len(req.seq_tokens) == req.ctx_len
+            if edge == "cancel":
+                the.cancel()
+            elif edge == "deadline":
+                req.deadline = time.perf_counter() - 1.0
+            elif edge == "preempt":
+                with svc._lock, pytest.raises(engine_mod._LandFirst):
+                    svc._preempt_slot_locked(fired[0])
+            elif edge == "stop_no_drain":
+                svc.stop(drain=False)
+            elif edge == "kill":
+                svc.kill()
+                at_kill.extend(len(s) for s in seen)
+        return run(kind, *a, **kw)
+
+    def reading(*outs, **kw):
+        if edge.startswith("read_fails") and kw.get("of") == "prefill" \
+                and len(fired) == 1:
+            fired.append("raised")
+            raise RuntimeError("injected read failure")
+        return synced(*outs, **kw)
+
+    monkeypatch.setattr(svc._programs, "run", dispatching)
+    monkeypatch.setattr(engine_mod, "_synced", reading)
+    if edge == "read_fails_past_budget":
+        svc._max_error_requeues = 0
+    svc._iterate()                       # admits the third beside the two
+    assert fired and not svc._firsts
+    if edge == "preempt":
+        # its first token has landed; the step it fed is in flight: the
+        # preemption is refused until that has landed too
+        assert seen[2] == want[2][:1] and svc._flies(req)
+        svc._land()
+        with svc._lock:
+            svc._preempt_slot_locked(fired[0])
+    _drive(svc, hs)
+    svc._iterate()
+    c = svc.stats()["counts"]
+    fed = sum(req.rid in rids for _, rids in svc.membership_history())
+    for i, h in enumerate(hs[:2]):
+        if edge == "stop_no_drain":
+            with pytest.raises(ServingClosedError):
+                h.result(1)
+        elif edge == "kill":
+            assert not h.finished and len(seen[i]) == at_kill[i]
+        else:
+            assert h.result(1) == want[i]
+        assert seen[i] == want[i][:len(seen[i])]       # each token once
+    if edge == "max_new_1":
+        assert the.result(1) == seen[2] == want[2][:1] and fed == 0
+        assert the.finish_reason == "max_new_tokens"
+        assert c["prefills_ahead"] == 3
+    elif edge == "eos_first":
+        # found a step late: fed once, that token dropped, and its K/V
+        # past what the prefix index is shown
+        assert the.result(1) == seen[2] == want[2][:1] and fed == 1
+        assert the.finish_reason == "eos" and req.decode_steps == 0
+        assert req.ctx_len == len(prompts[2])
+    elif edge == "cancel":
+        assert the.result(1) == seen[2] == want[2][:1]
+        assert the.finish_reason == "cancelled" and c["cancelled"] == 1
+    elif edge == "deadline":
+        # (the step its token fed lands before the row leaves its slot,
+        # as it does for any row of a step in flight)
+        with pytest.raises(DeadlineExceededError):
+            the.result(1)
+        assert seen[2] == want[2][:2] and c["expired"] == 1
+    elif edge == "preempt":
+        assert the.result(1) == seen[2] == want[2]
+        assert req.n_preempted == 1 and c["preempted"] == 1
+    elif edge == "read_fails":
+        # requeued with nothing of it served, prefilled anew; the step
+        # that was fed its token dropped its row
+        assert the.result(1) == seen[2] == want[2]
+        assert (req.n_requeues, c["requeued"], c["failed"]) == (1, 1, 0)
+        assert req.decode_steps == 7 and c["prefills_ahead"] == 3
+    elif edge == "read_fails_past_budget":
+        with pytest.raises(GenerationStepError):
+            the.result(1)
+        assert seen[2] == [] and c["failed"] == 1
+    elif edge == "stop_no_drain":
+        with pytest.raises(ServingClosedError):
+            the.result(1)
+        assert seen[2] == want[2][:2]
+    elif edge == "kill":
+        assert not the.finished and seen[2] == []
+    assert svc._flight is None or edge == "kill"
+    if edge not in ("stop_no_drain", "kill"):
+        assert c["tokens"] == sum(map(len, seen))
+        # nothing leaks: what is left in the pool is what the index keeps
+        assert svc._cache.allocator.num_used == svc._prefix.num_blocks
+        svc.stop(drain=False, timeout=30)
+
+
+@pytest.mark.parametrize("admits", [1, 2, 3])
+def test_admissions_of_any_size_compile_nothing_after_warmup(
+        params, monkeypatch, no_compile_cache, admits):
+    """The program that places a first token takes its slot as an
+    operand: compiled once in ``warmup()``, whatever the number of rows a
+    pass admits (one, several, every slot), with and without a step in
+    flight; it is no model program and not among
+    ``compiled_signatures()``."""
+    from mxnet_tpu.executor import compile_cache_stats
+
+    monkeypatch.setenv("TPUMX_FREEZE_COMPILES", "1")
+    _xla_compiles()
+    svc = GenerationService(params, CFG, _gc(max_slots=3), start=False)
+    n = svc.warmup()
+    assert n == svc.stats()["compiled_signatures"] == 10
+    assert ("first_token", (3,)) in svc._programs._texts
+    placed = svc._programs._placed
+    warm = (compile_cache_stats()["misses"], _xla_compiles())
+    rs = np.random.RandomState(31)
+    hs = []
+    for wave in range(3):                # cold, then beside rows in flight
+        hs += [svc.submit(rs.randint(0, CFG.vocab, 5 + 9 * i + wave),
+                          max_new_tokens=3) for i in range(admits)]
+        svc._iterate()
+        svc._iterate()
+    _drive(svc, hs)
+    svc._iterate()
+    c = svc.stats()
+    svc.stop(drain=False, timeout=30)
+    assert all(len(h.result(1)) == 3 for h in hs)
+    assert (compile_cache_stats()["misses"], _xla_compiles()) == warm
+    assert c["compiled_signatures"] == 10
+    assert c["counts"]["prefills_ahead"] == 3 * admits
+    assert svc._programs._placed - placed == 3 * admits

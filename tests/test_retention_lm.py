@@ -291,6 +291,32 @@ def test_service_generation_matches_reference_greedy(params, svc, plen,
         _ref_greedy(params, prompt, n_new)
 
 
+def test_admitting_passes_carry_first_tokens_and_serve_the_reference(
+        params, svc):
+    """Ten clients on four slots with outputs of 2 to 5 tokens, so that
+    most passes admit, prompts of one chunk and of several: every first
+    token stays on the device for the decode step of the pass that
+    admitted its row (docs/generation.md "The step in flight"), a slot's state its
+    whole cache,
+    and every request is served the reference's tokens."""
+    svc.start()
+    before = svc.stats()["counts"]
+    rng = np.random.default_rng(45)
+    prompts = [[int(t) for t in rng.integers(0, V, n)]
+               for n in (5, 41, 8, 23, 3, 70, 16, 33, 7, 19)]
+    news = (3, 2, 5, 4, 2, 3, 5, 2, 4, 3)
+    streams = [svc.submit(p, max_new_tokens=n)
+               for p, n in zip(prompts, news)]
+    for st, p, n in zip(streams, prompts, news):
+        assert st.result(300) == _ref_greedy(params, p, n)
+    after = svc.stats()["counts"]
+    ahead = after["prefills_ahead"] - before["prefills_ahead"]
+    read = after["prefills_read"] - before["prefills_read"]
+    # (a request that ends a pass alone has nothing to decode after it)
+    assert ahead + read == 10 and ahead >= 8
+    assert after["failed"] == before["failed"]
+
+
 def test_the_kernels_serve_the_reference_s_tokens(params):
     """The Pallas calls (through the interpreter) behind the same service:
     chunks of 16 and a leftover, then the one-token steps."""
