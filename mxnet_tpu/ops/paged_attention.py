@@ -49,7 +49,15 @@ Two bodies, chosen by what the call sees in its shapes:
   earliest query's window reaches (``max(0, pos - window + 1) // bs``; 0
   without a window) to its latest query's own, found in the table as a
   RING — logical page ``p`` in column ``p % W`` — so that a window layer's
-  table is as wide as a window and not as a sequence.  The mask is
+  table is as wide as a window and not as a sequence.  A trip computes
+  every position of its page group, fetched or not, so its size is the
+  call's own: WITHOUT a window 512 positions, the row's pages in
+  ``ceil(live / pages)`` trips; WITH one the pages its tile's queries can
+  reach, ``ceil((t + window - 1) / bs) + 1`` for ``t`` consecutive
+  positions (17 for one token at 512 / 32, 9 at 128 / 16), in ONE trip
+  wherever K and V fit double-buffered (``_tile_pages``: static, from
+  ``window``, the block length and the table's width) — the next trip
+  prefetched is then the next row's.  The mask is
   ``pos - window < j <= pos``; the sink is one learned logit a query head
   that joins the softmax's denominator and no value: the recurrence starts
   at ``m = sink, l = 1, acc = 0``.  K pages hold ``dk`` lanes a head, V
@@ -82,7 +90,7 @@ _NEG = -1e30
 _GROUPED_PAGES = 8      # K/V pages a grid step of the chunk body, grouped heads
 
 __all__ = ["paged_attention", "paged_attention_reference", "attention_scale",
-           "paged_attention_sharded"]
+           "paged_attention_sharded", "tiles_decode_trips"]
 
 
 def attention_scale(d_head: int) -> float:
@@ -607,10 +615,87 @@ def _tiles_kernel(tables_ref, first_ref, end_ref, layer_ref, q_ref, pos_ref,
 
 
 _TILE_ROWS = 256        # grouped query rows a step of the tiles body
-# cache positions a trip of it: a trip costs ~1.5 us beside its bytes, and
-# the full kind's decode call read 5.00 ms at 512 against 6.08 at 256, a
-# 512-token chunk's 2.04 against 3.18 (PERF.md PR 32)
+# cache positions a trip of it WITHOUT a window: a trip costs ~1.5 us beside
+# its bytes, and the full kind's decode call read 5.00 ms at 512 against
+# 6.08 at 256, a 512-token chunk's 2.04 against 3.18 (PERF.md PR 32).  A
+# call WITH a window sizes its trip from the pages its tile can reach
+# (_tile_pages): a trip computes all its positions, fetched or not
 _TILE_POSITIONS = 512
+# K and V, double-buffered, of a trip sized from a window's reach.  Beside a
+# 256-row tile's queries, output and accumulator the chip's compiler takes
+# 5.2 MB (512 positions of 2,560 bfloat16 lanes) and refused 6.9 (21 pages
+# of 32 of them: fast memory exhausted, PERF.md PR 46); a decode call's 17
+# such pages are 5.6
+_TILE_KV_BYTES = 6 << 20
+
+
+def _tile_pages(span: int, bs: int, w: int, window: int,
+                page_bytes: int) -> int:
+    """Pages a trip of the tiles body fetches and computes.  ``span``
+    consecutive query positions with a ``window`` read at most ``reach =
+    ceil((span + window - 1) / bs) + 1`` pages (the window's positions, and
+    the page both ends may straddle), so such a call takes them in ONE
+    trip wherever a K and a V page of ``page_bytes`` together, ``reach`` of
+    them double-buffered, stay under ``_TILE_KV_BYTES``; a call without a
+    window, or whose reach does not fit, ``_TILE_POSITIONS`` positions a
+    trip.  Never more pages than the table is wide."""
+    pages = _TILE_POSITIONS // bs
+    if window:
+        reach = -(-(span + window - 1) // bs) + 1
+        if 2 * reach * page_bytes <= _TILE_KV_BYTES:
+            pages = reach
+    return max(1, min(pages, w))
+
+
+def _tiles_geometry(rows: int, groups: int, bs: int, w: int, window: int,
+                    page_bytes: int):
+    """``(query rows a tile, pages a trip)`` of a tiles call over ``rows``
+    grouped query rows, ``rows // groups`` consecutive positions a group.
+    A tile that is whole inside a group spans its own rows' positions; one
+    that crosses groups may span the chunk's."""
+    bt = min(_TILE_ROWS, -(-rows // 8) * 8)
+    t = rows // groups
+    return bt, _tile_pages(bt if t % bt == 0 else t, bs, w, window,
+                           page_bytes)
+
+
+def _tile_spans(positions, max_pos, bt: int, bs: int, window: int):
+    """``(first, end)`` logical pages, ``(B, tiles)`` each, that every tile
+    of ``bt`` of the grouped query rows at ``positions (B, R)`` reads (``R``
+    whole tiles; a padded row sits at -1): up to its latest query's own
+    (and the row's last valid position), from the first its earliest
+    query's window reaches."""
+    B, R = positions.shape
+    tiled = positions.reshape(B, R // bt, bt)
+    hi = jnp.minimum(jnp.max(tiled, axis=2), max_pos[:, None])
+    end = jnp.where(hi >= 0, hi // bs + 1, 0)
+    lo = jnp.min(jnp.where(tiled >= 0, tiled, jnp.iinfo(jnp.int32).max),
+                 axis=2)
+    first = jnp.maximum(lo - (window - 1), 0) // bs if window \
+        else jnp.zeros_like(end)
+    return jnp.minimum(first, end), end
+
+
+def _page_bytes(k_pool, v_pool) -> int:
+    """A K page and a V page together."""
+    return (k_pool.shape[3] + v_pool.shape[3]) * k_pool.shape[2] \
+        * jnp.dtype(k_pool.dtype).itemsize
+
+
+def tiles_decode_trips(positions, max_pos, k_pool, v_pool, table_width: int,
+                       *, groups: int, window: int):
+    """Trips of the tiles body over one DECODE call with these operands
+    (``positions (B, 1)``, ``groups`` query heads a KV head): the sum over
+    its rows of ``ceil(pages read / pages a trip)``, int32 — what a model
+    counts as ``window_decode_trips`` (docs/observability.md), from the
+    geometry :func:`_tiles_call` itself takes."""
+    bs = k_pool.shape[2]
+    _, pages = _tiles_geometry(groups, groups, bs, table_width, window,
+                               _page_bytes(k_pool, v_pool))
+    # a row's one tile holds its one position, once a query head
+    first, end = _tile_spans(jnp.asarray(positions, jnp.int32),
+                             jnp.asarray(max_pos, jnp.int32), 1, bs, window)
+    return jnp.sum((end - first + pages - 1) // pages).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -625,7 +710,8 @@ def _tiles_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
     B, R, HDk = q.shape
     bs, HDv = k_pool.shape[2], v_pool.shape[3]
     W = tables.shape[1]
-    bt = min(_TILE_ROWS, -(-R // 8) * 8)
+    bt, pages = _tiles_geometry(R, groups, bs, W, window,
+                                _page_bytes(k_pool, v_pool))
     r_pad = -(-R // bt) * bt
     if r_pad != R:
         # padded query rows sit at position -1: they read nothing
@@ -635,18 +721,7 @@ def _tiles_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
         if sink is not None:
             sink = jnp.pad(sink, ((0, r_pad - R), (0, 0)))
     n_tiles = r_pad // bt
-    pages = max(1, min(_TILE_POSITIONS // bs, W))
-    # the logical pages a tile reads: up to its latest query's own (and
-    # the row's last valid position), from the first its earliest query's
-    # window reaches
-    tiled = positions.reshape(B, n_tiles, bt)
-    hi = jnp.minimum(jnp.max(tiled, axis=2), max_pos[:, None])
-    end = jnp.where(hi >= 0, hi // bs + 1, 0)
-    lo = jnp.min(jnp.where(tiled >= 0, tiled, jnp.iinfo(jnp.int32).max),
-                 axis=2)
-    first = jnp.maximum(lo - (window - 1), 0) // bs if window \
-        else jnp.zeros_like(end)
-    first = jnp.minimum(first, end)
+    first, end = _tile_spans(positions, max_pos, bt, bs, window)
     tile = lambda w: pl.BlockSpec(  # noqa: E731
         (1, bt, w), lambda s, *_: (s // n_tiles, s % n_tiles, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)

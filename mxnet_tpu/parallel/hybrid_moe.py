@@ -65,7 +65,8 @@ __all__ = ["HybridMoeConfig", "HybridMoeLM", "hybrid_moe_decode",
 
 COUNTERS = ("full_ctx_tokens", "window_ctx_tokens", "full_prefill_pairs",
             "window_prefill_pairs", "expert_assignments",
-            "expert_assignments_held", "experts_touched", "expert_tokens_max")
+            "expert_assignments_held", "experts_touched", "expert_tokens_max",
+            "window_decode_trips")
 
 
 @dataclass(frozen=True)
@@ -263,6 +264,13 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
     for kind in (0, 1):
         aux[name_of(kind) + ("_ctx_tokens" if T == 1 else "_prefill_pairs")] \
             = reads[kind]
+    if use_kernel and T == 1:
+        # the tiles body's trips over the window layers' calls: one a live
+        # row a layer is a trip as long as a window's reach
+        aux["window_decode_trips"] = sum(cfg.hybrid_layer_pattern) \
+            * _pa.tiles_decode_trips(
+                positions, max_pos, pools[2], pools[3], ring,
+                groups=H // cfg.kv_heads(1), window=win)
     eps = cfg.layernorm_epsilon
     at_kind = [0, 0]            # the next layer of each kind's pools
     scope = jax.named_scope     # docs/observability.md "Device scopes"

@@ -66,7 +66,8 @@ __all__ = ["SambaYConfig", "SambaYLM", "sambay_lm_decode",
 COUNTERS = ("ssm_decode_rows", "ssm_prefill_tokens", "ssm_prefill_chunks",
             "ssm_rows_started", "window_ctx_tokens", "full_ctx_tokens",
             "window_prefill_pairs", "full_prefill_pairs",
-            "cross_positions_run", "cross_positions_skipped")
+            "cross_positions_run", "cross_positions_skipped",
+            "window_decode_trips")
 
 
 @dataclass(frozen=True)
@@ -195,6 +196,7 @@ def sambay_lm_decode(params: Params, tokens, positions, lengths, pools,
     aux)``; ``aux`` is the dict of this call's counts (``COUNTERS``;
     docs/observability.md)."""
     from ..ops import diff_attention as _da
+    from ..ops.paged_attention import tiles_decode_trips
     from ..ops.selective_scan import conv_state, selective_scan
 
     B, T = tokens.shape
@@ -233,9 +235,17 @@ def sambay_lm_decode(params: Params, tokens, positions, lengths, pools,
     run = (n_live if skip else n_valid) if want_logits else 0
     aux = dict.fromkeys(COUNTERS, jnp.zeros((), jnp.int32))
     aux["ssm_rows_started"] = jnp.sum(fresh).astype(jnp.int32)
+    max_pos = jnp.max(jnp.where(valid, positions, -1), axis=1)
     if T == 1:
         aux.update(ssm_decode_rows=n_valid, full_ctx_tokens=jnp.sum(seen),
                    window_ctx_tokens=jnp.sum(jnp.minimum(seen, win)))
+        if kernel:
+            # the tiles body's trips over the window layers' calls (a KV
+            # pair is one head to it): one a live row a layer is a trip as
+            # long as a window's reach
+            aux["window_decode_trips"] = cfg.kinds.count("swa") \
+                * tiles_decode_trips(positions, max_pos, k_win, v_win, ring,
+                                     groups=2 * H // hkv, window=win)
     else:
         aux.update(
             ssm_prefill_tokens=n_valid, ssm_prefill_chunks=n_live,
@@ -266,7 +276,6 @@ def sambay_lm_decode(params: Params, tokens, positions, lengths, pools,
 
     scope = jax.named_scope     # docs/observability.md "Device scopes"
     at_kind = {"ssm": 0, "swa": 0}      # the next layer of a kind's pools
-    max_pos = jnp.max(jnp.where(valid, positions, -1), axis=1)
     memory, asked = None, valid         # asked: the queries that count
     with scope("embed"):
         x = params["tok_emb"][tokens].astype(jnp.float32)      # (B, T, d)

@@ -783,35 +783,48 @@ def _the_walk_is_named_and_copies_no_expert(text, d, f):
 
 
 # -- MiMo-V2.5's window and full attention layers (parallel/hybrid_moe.py) ----
-_HYB = dict(full=(4, 0, False, 1024), window=(8, 128, True, None))
+# kind: (KV heads, query heads a KV head, K lanes, V lanes, block, window,
+# sink, table width or None for the ring)
+_TILES = dict(full=(4, 16, 192, 128, BS, 0, False, 1024),
+              window=(8, 8, 192, 128, BS, 128, True, None),
+              # phi-4-mini-flash's window layers by way of
+              # ops/diff_attention.py: a KV pair is one head of 128 lanes
+              swa=(10, 4, 128, 128, 32, 512, False, None))
 
 
-@pytest.mark.parametrize("B,T", [(128, 1), (1, 512)],
-                         ids=["decode", "prefill"])
-@pytest.mark.parametrize("kind", ["full", "window"])
+@pytest.mark.parametrize("kind,B,T", [
+    ("full", 128, 1), ("window", 128, 1), ("full", 1, 512),
+    ("window", 1, 512), ("swa", 128, 1)],
+    ids=["full-decode", "window-decode", "full-prefill", "window-prefill",
+         "swa-decode"])
 def test_tiles_body_compiles_at_the_cells_shapes(one_chip, kind, B, T):
     """The tiles body at ``mimo-v2.5``'s published shapes: ``Hkv x 192``
     K pages beside ``Hkv x 128`` V pages, 16 (full) and 8 (window) query
     heads a KV head as rows, the full kind's table 1,024 wide (a 512 KB
     table in scalar memory) and the window kind's a ring of 16 (decode) or
-    64 (a 512-token chunk), with the window and the sink."""
+    64 (a 512-token chunk), with the window and the sink; and at
+    ``phi-4-mini-flash``'s swa decode call: 10 pairs of 128 lanes, blocks
+    and a ring of 32, a window of 512, so a trip of 17 pages (PR 46)."""
     from mxnet_tpu.ops import paged_attention as pa
+    from mxnet_tpu.serving.generation.kv_cache import ring_width
 
-    hkv, window, sink, width = _HYB[kind]
-    G = 64 // hkv
-    W = width or (16 if T == 1 else 64)
+    hkv, G, dk, dv, bs, window, sink, width = _TILES[kind]
+    W = width or ring_width(window, T, bs)
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     shapes = [sds((B, W), jnp.int32), sds((B,), jnp.int32),
-              sds((1,), jnp.int32), sds((B, G * T, hkv * 192), jnp.float32),
+              sds((1,), jnp.int32), sds((B, G * T, hkv * dk), jnp.float32),
               sds((B, G * T), jnp.int32),
-              sds((2, 2048, BS, hkv * 192), jnp.bfloat16),
-              sds((2, 2048, BS, hkv * 128), jnp.bfloat16)]
+              sds((2, 2048, bs, hkv * dk), jnp.bfloat16),
+              sds((2, 2048, bs, hkv * dv), jnp.bfloat16)]
     if sink:
         shapes.append(sds((G * T, hkv), jnp.float32))
+    if kind == "swa":
+        assert (W, pa._tiles_geometry(G * T, G, bs, W, window, pa._page_bytes(
+            *shapes[5:7]))) == (32, (8, 17))
     phase = "decode" if T == 1 else "prefill"
     text = _compile(
         functools.partial(pa._tiles_call.__wrapped__, n_heads=hkv,
-                          scale=192 ** -0.5, interpret=False, groups=G,
+                          scale=dk ** -0.5, interpret=False, groups=G,
                           call=f"{kind}_{phase}", window=window), *shapes)
     assert "tpu_custom_call" in text
     assert f"_paged_call_w{W}_t{T}_{kind}_{phase}" in text

@@ -571,6 +571,7 @@ def test_the_programs_counts_and_the_kinds_gauges_reach_stats(params3):
     # read; a step dispatched ahead of the end is dropped unread)
     assert counts["full_ctx_tokens"] == sum(range(22, 27))
     assert counts["window_ctx_tokens"] == 5 * WIN
+    assert counts["window_decode_trips"] == 0       # no kernel, no trip
     assert counts["expert_assignments"] == 2 * 2 * (21 + 5)
     assert counts["expert_assignments_held"] == counts["expert_assignments"]
     assert counts["window_blocks_freed"] >= 3

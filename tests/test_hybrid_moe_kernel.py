@@ -11,7 +11,7 @@ import jax.numpy as jnp
 from mxnet_tpu.ops import paged_attention as pa
 from mxnet_tpu.parallel import hybrid_moe as hm
 from mxnet_tpu.serving.generation.kv_cache import blocks_for, ring_width
-from test_hybrid_moe import (C3, CUT, _check_logits_through_the_cache,
+from test_hybrid_moe import (C3, CUT, WIN, _check_logits_through_the_cache,
                              _check_warmup_covers_the_traffic, _config,
                              _model, _service, params3)  # noqa: F401
 
@@ -90,6 +90,89 @@ def test_tiles_body_matches_its_oracle(T, window, sink, dtype):
     np.testing.assert_array_equal(np.asarray(got[0]), 0)
 
 
+# -- a windowed call's trip is sized from what its tile can reach (PR 46) ----
+_PHI = dict(window=512, bs=32, ring=32, sink=False)     # phi-4-mini-flash
+_MIMO = dict(window=128, bs=16, ring=16, sink=True)     # mimo-v2.5
+
+
+@pytest.mark.parametrize("shape,ctx,dead", [
+    (_PHI, [2048, 1024, 4096], ()), (_PHI, [2078, 1054, 542], ()),
+    (_PHI, [2079, 1055, 543], ()), (_PHI, [1023, 1024, 1185], ()),
+    (_PHI, [0, 100, 510], ()), (_PHI, [2050, 2051, 2077], (1,)),
+    (_MIMO, [1024, 256, 4096], ()), (_MIMO, [1038, 270, 4110], ()),
+    (_MIMO, [1039, 271, 127], ())],
+    ids=["w512-p0", "w512-p30", "w512-p31", "w512-ring-wraps",
+         "w512-shorter-than-the-window", "w512-inactive-between",
+         "w128-sink-p0", "w128-sink-p14", "w128-sink-p15"])
+def test_a_windowed_decode_call_reads_its_reach_in_one_trip(shape, ctx, dead):
+    """The decode call at the two cells' window, block length and ring, at
+    the page boundaries the geometry creates: a query whose window
+    straddles ``reach`` pages (17 of 32; 9 of 16) and one whose window
+    ends on a page's last position (16; 8), a ring that wrapped, rows
+    shorter than the window, an inactive row between live ones; the sink
+    in the denominator.  The results are the oracle's and every live row
+    takes one trip."""
+    window, bs, ring = shape["window"], shape["bs"], shape["ring"]
+    rng = np.random.default_rng(sum(ctx))
+    hkv, G, d = 2, 4, 16
+    q, kp, vp, tables, pos, max_pos = _ring_case(
+        rng, 3, 1, hkv, G, d, d, window, bs, ring, 64, jnp.float32, ctx)
+    for b in dead:
+        max_pos[b] = -1
+    s = jnp.asarray(rng.normal(0, 1, hkv * G), jnp.float32) \
+        if shape["sink"] else None
+    got = pa.paged_attention(q, kp, vp, tables, pos, max_pos,
+                             scale=d ** -0.5, layer=1, window=window, sink=s,
+                             call="window_decode")
+    want = _oracle(q, kp, vp, jnp.asarray(tables), jnp.asarray(pos), hkv,
+                   window, s, 1)
+    live = [b for b in range(3) if b not in dead]
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=0)
+    np.testing.assert_array_equal(np.asarray(got)[list(dead)], 0)
+    reach = -(-window // bs) + 1
+    assert pa._tiles_geometry(G, G, bs, ring, window,
+                              pa._page_bytes(kp, vp)) == (8, reach)
+    assert int(pa.tiles_decode_trips(pos, max_pos, kp, vp, ring, groups=G,
+                                     window=window)) == len(live)
+
+
+@pytest.mark.parametrize("span,bs,width,window,page_bytes,pages", [
+    (1, 32, 32, 512, 2 * 32 * 1280 * 2, 17),      # phi-4-mini-flash, decode
+    (1, 16, 16, 128, 16 * (1536 + 1024) * 2, 9),  # mimo-v2.5, decode
+    (256, 16, 64, 128, 16 * (1536 + 1024) * 2, 25),   # its 512-token chunk
+    # a chunk tile of phi-4-mini-flash reaches 25 (21) pages: 8.2 (6.9) MB
+    # of K and V beside 256 query rows, which the chip's compiler refused
+    (256, 32, 64, 512, 2 * 32 * 1280 * 2, 16),
+    (128, 32, 32, 512, 2 * 32 * 1280 * 2, 16),
+    (1, 32, 1024, 0, 2 * 32 * 1280 * 2, 16),      # no window: 512 positions
+    (256, 16, 1024, 0, 16 * (768 + 512) * 2, 32),
+    (1, 32, 8, 512, 2 * 32 * 1280 * 2, 8),        # never more than the table
+    (1, 16, 4, 0, 16 * 1280 * 2, 4), (1, 4, 4, 8, 4 * 48 * 4, 3)],
+    ids=["phi-decode", "mimo-decode", "mimo-chunk", "phi-chunk512-keeps",
+         "phi-chunk128-keeps", "full-decode", "full-chunk", "narrow-ring",
+         "narrow-table", "tiny"])
+def test_the_pages_a_trip_of_the_tiles_body(span, bs, width, window,
+                                            page_bytes, pages):
+    """``_tile_pages``: with a window the pages ``span`` consecutive
+    queries can reach, where K and V fit; without one, and where they do
+    not, ``_TILE_POSITIONS`` positions; never more than the table's
+    width."""
+    assert pa._tile_pages(span, bs, width, window, page_bytes) == pages
+    assert pa._TILE_ROWS == 256 and pa._TILE_POSITIONS == 512
+
+
+def test_a_tiles_span_is_its_own_rows_or_the_chunks():
+    """A tile whole inside a group spans its rows' positions; a decode
+    row's tile one; a tile across groups the chunk."""
+    geometry = lambda rows, groups: pa._tiles_geometry(  # noqa: E731
+        rows, groups, 16, 64, 128, 16 * 2560 * 2)
+    assert geometry(8, 8) == (8, 9)                   # one position
+    assert geometry(8 * 512, 8) == (256, 25)          # 256 of a group's 512
+    assert geometry(8 * 128, 8) == (256, 17)          # two groups' same 128
+    assert geometry(4 * 80, 4) == (256, 14)           # across groups: 80
+
+
 def test_reference_attention_takes_a_sink_and_narrower_values():
     """``paged_attention_reference`` (the ``TPUMX_PALLAS=0`` path) with a
     sink: the softmax over the scores and one more column, dropped."""
@@ -142,3 +225,19 @@ def test_warmup_covers_every_program_the_traffic_needs(paged3):
     rings = {key[0]: key[1][1][1][1][1] for key in paged3.compile_stats()}
     # window_blocks(8, T) rounded up to a power of two
     assert rings == {"gen_prefill": 8, "gen_decode": 4}
+
+
+def test_the_window_calls_trips_reach_stats_one_a_row_a_layer(paged3):
+    """``window_decode_trips``: the tiles body's trips over the window
+    layers' decode calls, counted by the program from the call's own
+    geometry — one a live row a window layer a step (the cut has two
+    window layers; every step's row is deeper than the window)."""
+    paged3.start()
+    before = dict(paged3.stats()["counts"])
+    paged3.generate(np.arange(21), max_new_tokens=6, timeout=600)
+    counts = paged3.stats()["counts"]
+    rows = (counts["window_ctx_tokens"] - before["window_ctx_tokens"]) \
+        // WIN
+    assert rows >= 5
+    assert counts["window_decode_trips"] - before["window_decode_trips"] \
+        == 2 * rows

@@ -478,6 +478,7 @@ def test_the_programs_counts_and_the_gauges_reach_stats(params):
     assert counts["ssm_decode_rows"] == 5
     assert counts["full_ctx_tokens"] == sum(range(22, 27))
     assert counts["window_ctx_tokens"] == 5 * WIN
+    assert counts["window_decode_trips"] == 0       # no kernel, no trip
     assert counts["window_blocks_freed"] >= 3
     # one sampling program a prompt (its last chunk) and a decode step
     assert counts["sampler_steps_greedy"] >= 1 + 5
@@ -513,18 +514,37 @@ def test_warmup_covers_every_program_the_traffic_needs(params,
     svc.stop(drain=False, timeout=30)
 
 
-def test_the_kernels_behind_the_service_serve_the_references_tokens(params):
+@pytest.fixture(scope="module")
+def paged(params):
     """``TPUMX_PALLAS=1``: the scan's two calls, the convolution's read and
-    the tiles body through the interpreter, ONE table width: five programs
-    (decode, a fill and a last chunk a rung)."""
-    svc = _service(params, kernel="paged", seq_buckets=[8, 16, 200])
-    assert svc.stats()["decode_kernel"] == "paged"
-    assert len(svc._width_buckets) == 1
-    assert svc.warmup() == 5
+    the tiles body through the interpreter (minutes to warm: shared)."""
+    made = _service(params, kernel="paged", seq_buckets=[8, 16, 200])
+    yield made
+    made.stop(drain=False, timeout=30)
+
+
+def test_the_kernels_behind_the_service_serve_the_references_tokens(params,
+                                                                    paged):
+    """ONE table width: five programs (decode, a fill and a last chunk a
+    rung)."""
+    assert paged.stats()["decode_kernel"] == "paged"
+    assert len(paged._width_buckets) == 1
+    assert paged.warmup() == 5
     for plen in (3, 37):
-        got = _logits_through_the_cache(svc, _prompt(plen), 2)
+        got = _logits_through_the_cache(paged, _prompt(plen), 2)
         for toks, last in got:
             np.testing.assert_allclose(
                 last, _ref_logits(params, toks, len(toks) - 1)[0], atol=TOL,
                 rtol=0)
-    svc.stop(drain=False, timeout=30)
+
+
+def test_the_window_calls_trips_reach_stats_one_a_row_a_layer(paged):
+    """``window_decode_trips``: the tiles body's trips over the window
+    layers' decode calls, counted by the program from the call's own
+    geometry — one a live row a window layer a step."""
+    paged.start()
+    paged.generate(np.arange(21), max_new_tokens=6, timeout=600)
+    counts = paged.stats()["counts"]
+    assert counts["ssm_decode_rows"] >= 5
+    assert counts["window_decode_trips"] == \
+        _config().kinds.count("swa") * counts["ssm_decode_rows"]

@@ -860,8 +860,8 @@ def test_phase_ms_accounts_for_the_iterations(params):
     of the iterations, read from the span ring, within 5%."""
     # wide enough that a step outweighs the loop's unnamed glue (list
     # building, span bookkeeping: some tens of microseconds a pass)
-    cfg = tr.TransformerConfig(vocab=40, d_model=256, n_heads=4, n_layers=6,
-                               d_ff=1024, max_len=64)
+    cfg = tr.TransformerConfig(vocab=40, d_model=512, n_heads=4, n_layers=6,
+                               d_ff=2048, max_len=64)
     big = tr.transformer_lm_init(cfg, jax.random.PRNGKey(1))
     svc = GenerationService(big, cfg, _gc(), start=False)
     svc.warmup()
