@@ -41,8 +41,8 @@ Two bodies, chosen by what the call sees in its shapes:
   nothing.
 
 * **tiles** (a call with a ``window``, a ``sink`` or V pages narrower
-  than its K pages — layers of two kinds in one model,
-  parallel/hybrid_moe.py): grid ``(B * tiles,)``, up to 256 of the
+  than its K pages, or one that asks for it — layers of two kinds in one
+  model, parallel/hybrid_moe.py): grid ``(B * tiles,)``, up to 256 of the
   grouped query rows a tile, decode (one tile of the ``G`` query heads of
   a KV head) and a prefill chunk alike.  As the decode body it fetches its
   own pages, and only those its tile reads: from the first page its
@@ -1015,7 +1015,8 @@ def _paged_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
 
 def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
                     scale=None, k_scale=None, v_scale=None, *, layer: int = 0,
-                    call=None, window: int = 0, sink=None):
+                    call=None, window: int = 0, sink=None,
+                    tiles: bool = False):
     """Attention of ``q`` against a paged KV pool, walking the block table
     in-kernel.
 
@@ -1059,8 +1060,13 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
     sink : (H,) f32, optional — one learned logit a query head that joins
         the softmax's denominator and adds no value.
 
+    tiles : bool — take the tiles body whatever the call has: a model
+        whose window layers take it asks it for its full layers too
+        (parallel/hybrid_moe.py), so that a table's width costs them
+        nothing either.
+
     A call with a window, a sink or V pages narrower than K pages takes
-    the tiles body (float pools only).
+    the tiles body (float pools only), and so does one that asks for it.
 
     Returns (B, T, H, dv) in q's dtype (``dv`` = D unless V pages are
     narrower), matching :func:`paged_attention_reference` at rtol 1e-5
@@ -1077,7 +1083,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, max_pos,
     positions = jnp.asarray(positions, jnp.int32)
     Hkv = k_pool.shape[3] // D
     G = H // Hkv
-    if window or sink is not None or k_pool.shape[3] != v_pool.shape[3]:
+    if tiles or window or sink is not None \
+            or k_pool.shape[3] != v_pool.shape[3]:
         assert k_scale is None, "the tiles body reads float pools"
         dv = v_pool.shape[3] // Hkv
         q = q.reshape(B, T, Hkv, G, D).transpose(0, 3, 1, 2, 4)

@@ -1,28 +1,45 @@
-"""A block whose layers attend in two ways — over a window with a sink, or
-over everything — with head counts of their own, keys wider than values,
-and sigmoid-routed experts (``model_type`` ``mimo_v2``, as ``MiMo-V2.5``'s
-``config.json`` carries its keys; docs/generation.md "Cache kinds").
+"""A block whose layers attend in two ways — over a window, or over
+everything — above sigmoid-routed experts: ONE layer loop for TWO published
+models (docs/generation.md "Cache kinds").  ``HybridMoeConfig``'s defaults
+are the first's; every term of the second is a field that is off there.
+
+* ``mimo_v2`` (``MiMo-V2.5``): head counts a kind, keys wider than values,
+  a sink in the window layers, partial rotary on EVERY layer with a base a
+  kind, scaled values, one norm before each half, no shared expert.
+* ``afmoe`` (``Trinity-Mini``; ``HybridMoeConfig.from_afmoe``): one pair of
+  head counts, keys as wide as values, no sink; a sigmoid GATE on the
+  heads' output, an RMS norm over each head's q and k, rotary over all the
+  lanes of the WINDOW layers and no positional term at all in the full
+  ones, a norm on each branch's OUTPUT besides the one on its input
+  (sandwich), a shared expert beside the routed ones, the embedding times
+  ``sqrt(hidden_size)``, router weights times ``route_scale``.
 
 The layers, with ``x`` the residual stream, ``H`` query heads, ``dq`` /
 ``dr`` / ``dv`` the key, rotary and value head sizes; a layer's kind is
 ``hybrid_layer_pattern``'s entry: ``F`` (0) has ``Hkv`` KV heads, rotary
 base ``rope_theta``, no window; ``W`` (1) has ``swa_num_key_value_heads``,
 base ``swa_rope_theta``, a window of ``sliding_window`` positions (the
-query's own among them) and a sink ``s_h`` a query head::
+query's own among them) and — ``mimo_v2`` — a sink ``s_h`` a query head.
+Terms in [brackets] are ``afmoe``'s::
 
+    x = Emb[token] [* sqrt(d)]
     h = rms(x, g1)
     q = (h Wq).reshape(H, dq);  k = (h Wk).reshape(Hkv, dq)
     v = (h Wv).reshape(Hkv, dv) * attention_value_scale
-    q = [rope(q[:, :dr], pos) | q[:, dr:]];  k likewise        (rotate-half, the FIRST dr lanes)
+    [q = rms(q, gq);  k = rms(k, gk)                     over a head's lanes]
+    q = [rope(q[:, :dr], pos) | q[:, dr:]];  k likewise        (rotate-half, the FIRST dr lanes;
+                                                 [dr = dq in W layers, NO rotation in F layers])
     cached: k, v   (F: every position; W: the last ``sliding_window``)
     z[t,h,j] = q[t,h] . k[j, h // (H/Hkv)] * dq^-0.5     F: j <= t;  W: t - window < j <= t
-    F: p = softmax_j(z)        W: p[t,h,j] = exp(z[t,h,j]) / (exp(s_h) + sum_i exp(z[t,h,i]))
-    a[t,h] = sum_j p[t,h,j] v[j, h // (H/Hkv)];   x = x + a.reshape(H dv) Wo
+    p = softmax_j(z);  with a sink  p[t,h,j] = exp(z[t,h,j]) / (exp(s_h) + sum_i exp(z[t,h,i]))
+    a[t,h] = sum_j p[t,h,j] v[j, h // (H/Hkv)]
+    y = (a.reshape(H dv) [* sigmoid(h Wgate)]) Wo;       x = x + [rms(] y [, g1')]
     h = rms(x, g2)
-    a dense layer (moe_layer_freq 0):  x = x + (silu(h Wg) * (h Wu)) Wd
+    a dense layer (moe_layer_freq 0):  y = (silu(h Wg) * (h Wu)) Wd
     an expert layer:  sc = sigmoid(h Wr)  (E, float32);  c = sc + b   (choosing only)
-        e = top_k(c);  w = sc[e] / (sum sc[e] + 1e-20)
-        x = x + sum_i w_i E_{e_i}(h);            E(h) = (silu(h Wg) * (h Wu)) Wd
+        e = top_k(c);  w = sc[e] / (sum sc[e] + 1e-20) * routed_scaling_factor
+        y = sum_i w_i E_{e_i}(h) [+ E_shared(h)];        E(h) = (silu(h Wg) * (h Wu)) Wd
+    x = x + [rms(] y [, g2')]
     logits = rms(x, gf) Wh
 
 The cache has TWO kinds (``cache_spec()["kinds"]``): ``full`` — the ``F``
@@ -30,24 +47,28 @@ layers' K and V, every position, under the table every model has — and
 ``window`` — the ``W`` layers', of which a row keeps the blocks its next
 query can still see: its table is a ring as wide as a window and a chunk
 (``serving/generation/kv_cache.py::CacheKind``).  Attention is
-``ops/paged_attention.py``'s tiles body (and, without the kernel, the same
-sums over the gathered pages); the router is ``latent_moe``'s with one
-group, the expert products ``sdar_moe``'s.
+``ops/paged_attention.py``'s tiles body for both kinds of both models
+(and, without the kernel, the same sums over the gathered pages); the
+router is ``latent_moe``'s with one group, the expert products
+``sdar_moe``'s, the shared expert ``latent_moe``'s.
 
 ``experts_held = (lo, hi)`` is the chip's share of the routed experts, as
 in ``latent_moe.py``: the router scores all of them, the products add this
 chip's experts' part — over this chip's rows alone, in tiles of twice a
 balanced router's share, the trips counted (``expert_trips``,
-``expert_trips_extra``) — and nothing stands in for the other chips.
+``expert_trips_extra``) — the shared expert is computed whole, and nothing
+stands in for the other chips.
 
 Parameters are a flat dict in ONE dtype and are never cast in the program:
 products take operands in that dtype and accumulate in float32; the
-residual stream, norms, sigmoid scores and softmax with its sink are
-float32; the pools have their own dtype (bfloat16 on the chip).
+residual stream, every norm, the gate's and the router's sigmoid and the
+softmax with its sink are float32; the pools have their own dtype
+(bfloat16 on the chip).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -67,6 +88,11 @@ COUNTERS = ("full_ctx_tokens", "window_ctx_tokens", "full_prefill_pairs",
             "window_prefill_pairs", "expert_assignments",
             "expert_assignments_held", "experts_touched", "expert_tokens_max",
             "window_decode_trips")
+# what a model with a shared expert counts besides (``afmoe``): the decode
+# rows at or past the window, where the window kind reads less than the
+# full kind does, and the tokens the shared expert ran, a layer.  (Counted
+# for that model alone: ``mimo_v2``'s programs keep the outputs they had.)
+AFMOE_COUNTERS = ("window_rows_past", "shared_expert_tokens")
 
 
 @dataclass(frozen=True)
@@ -103,6 +129,53 @@ class HybridMoeConfig:
     routed_scaling_factor: float = 1.0      # published null
     layernorm_epsilon: float = 1e-5
     max_position_embeddings: int = 1048576
+    # ``afmoe``'s terms (the module's docstring), off for ``mimo_v2``
+    attention_gate: bool = False    # sigmoid(h Wgate) times the heads' output
+    qk_norm: bool = False           # an RMS norm over each head's q and k
+    rope_on_full: bool = True       # False: the full layers have no rotary
+    sandwich_norm: bool = False     # a norm on each branch's output as well
+    n_shared_experts: int = 0
+    embedding_multiplier: float = 1.0
+
+    @classmethod
+    def from_afmoe(cls, c: dict, **over) -> "HybridMoeConfig":
+        """The config of an ``afmoe`` ``config.json``'s keys (``c``;
+        ``Trinity-Mini``), its first ``num_hidden_layers`` layers:
+        ``layer_types`` is the pattern, ``num_dense_layers`` the leading
+        dense layers, ``route_norm`` / ``route_scale`` the router's
+        weights, ``mup_enabled`` the embedding's multiplier; one pair of
+        head counts and sizes for both kinds, rotary over all the lanes.
+        ``over`` replaces fields (the router's published width where the
+        file's ``num_experts`` counts the experts held)."""
+        n = c["num_hidden_layers"]
+        heads = dict(num_attention_heads=c["num_attention_heads"],
+                     num_key_value_heads=c["num_key_value_heads"],
+                     head_dim=c["head_dim"], v_head_dim=c["head_dim"])
+        return replace(cls(
+            **heads, **{"swa_" + k: v for k, v in heads.items()},
+            vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+            intermediate_size=c["intermediate_size"],
+            moe_intermediate_size=c["moe_intermediate_size"],
+            num_hidden_layers=n,
+            hybrid_layer_pattern=tuple(int(t == "sliding_attention")
+                                       for t in c["layer_types"][:n]),
+            moe_layer_freq=tuple(int(i >= c["num_dense_layers"])
+                                 for i in range(n)),
+            sliding_window=c["sliding_window"],
+            add_swa_attention_sink_bias=False, partial_rotary_factor=1.0,
+            rope_theta=float(c["rope_theta"]),
+            swa_rope_theta=float(c["rope_theta"]), attention_value_scale=1.0,
+            n_routed_experts=c["num_experts"],
+            num_experts_per_tok=c["num_experts_per_tok"],
+            n_group=c["n_group"], topk_group=c["topk_group"],
+            norm_topk_prob=bool(c["route_norm"]),
+            routed_scaling_factor=float(c["route_scale"]),
+            layernorm_epsilon=float(c["rms_norm_eps"]),
+            max_position_embeddings=c["max_position_embeddings"],
+            attention_gate=True, qk_norm=True, rope_on_full=False,
+            sandwich_norm=True, n_shared_experts=c["num_shared_experts"],
+            embedding_multiplier=math.sqrt(c["hidden_size"])
+            if c["mup_enabled"] else 1.0), **over)
 
     def __post_init__(self):
         n = self.num_hidden_layers
@@ -135,6 +208,14 @@ class HybridMoeConfig:
         return bool(self.add_swa_attention_sink_bias if kind
                     else self.add_full_attention_sink_bias)
 
+    def has_rope(self, kind: int) -> bool:
+        return bool(kind or self.rope_on_full)
+
+    @property
+    def counters(self) -> Tuple[str, ...]:
+        """The names of the counts the program makes for this model."""
+        return COUNTERS + (AFMOE_COUNTERS if self.n_shared_experts else ())
+
 
 def hybrid_moe_param_shapes(cfg: HybridMoeConfig,
                             experts_held: Optional[Tuple[int, int]] = None
@@ -146,7 +227,7 @@ def hybrid_moe_param_shapes(cfg: HybridMoeConfig,
     f, F, E = (cfg.moe_intermediate_size, cfg.intermediate_size,
                cfg.n_routed_experts)
     lo, hi = experts_held or (0, E)
-    held = hi - lo
+    held, fs = hi - lo, cfg.n_shared_experts * f
     s = {"tok_emb": (cfg.vocab_size, d), "head": (d, cfg.vocab_size),
          "norm_f": (d,)}
     for i, kind in enumerate(cfg.hybrid_layer_pattern):
@@ -155,9 +236,17 @@ def hybrid_moe_param_shapes(cfg: HybridMoeConfig,
                  "wv": (d, hkv * dv), "wo": (H * dv, d), "norm2": (d,)}
         if cfg.has_sink(kind):
             layer["sink"] = (H,)
+        if cfg.attention_gate:
+            layer["wgate"] = (d, H * dv)
+        if cfg.qk_norm:
+            layer.update(q_norm=(dq,), k_norm=(dq,))
+        if cfg.sandwich_norm:
+            layer.update(norm1_post=(d,), norm2_post=(d,))
         if cfg.moe_layer_freq[i]:
             layer.update(router=(d, E), router_bias=(E,), wg=(held, d, f),
                          wu=(held, d, f), wd=(held, f, d))
+            if fs:
+                layer.update(sg=(d, fs), su=(d, fs), sd=(fs, d))
         else:
             layer.update(wg=(d, F), wu=(d, F), wd=(F, d))
         s.update({f"l{i}_{n}": shape for n, shape in layer.items()})
@@ -184,7 +273,8 @@ def hybrid_moe_init(cfg: HybridMoeConfig, key, dtype=jnp.float32,
         elif name == "tok_emb":
             z = 0.1 * z
         elif kind != "sink":
-            z = z * shape[-2] ** -0.5 * (res if kind in ("wo", "wd") else 1.0)
+            z = z * shape[-2] ** -0.5 * (res if kind in ("wo", "wd", "sd")
+                                         else 1.0)
         p[name] = z.astype(dtype)
     return p
 
@@ -222,7 +312,7 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
 
     Arguments otherwise as ``transformer_lm_decode``.  Returns ``(logits
     (B, T, vocab) float32, pools, aux)``; ``aux`` is the dict of this
-    call's counts (``COUNTERS``, and with a share of the experts held
+    call's counts (``cfg.counters``, and with a share of the experts held
     ``sdar_moe.TRIP_COUNTERS``; docs/observability.md), made on the
     device from what the program itself saw: valid queries only, except
     ``experts_touched``, which counts the experts whose weights the
@@ -260,7 +350,7 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
              jnp.sum(jnp.where(valid, jnp.minimum(positions + 1, win), 0)))
     zero = jnp.zeros((), jnp.int32)
     phase = "decode" if T == 1 else "prefill"
-    aux = dict.fromkeys(COUNTERS, zero)
+    aux = dict.fromkeys(cfg.counters, zero)
     for kind in (0, 1):
         aux[name_of(kind) + ("_ctx_tokens" if T == 1 else "_prefill_pairs")] \
             = reads[kind]
@@ -271,29 +361,58 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
             * _pa.tiles_decode_trips(
                 positions, max_pos, pools[2], pools[3], ring,
                 groups=H // cfg.kv_heads(1), window=win)
+    if T == 1 and "window_rows_past" in aux:
+        aux["window_rows_past"] = jnp.sum(
+            valid & (positions >= win)).astype(jnp.int32)
     eps = cfg.layernorm_epsilon
     at_kind = [0, 0]            # the next layer of each kind's pools
     scope = jax.named_scope     # docs/observability.md "Device scopes"
     with scope("embed"):
         x = params["tok_emb"][tokens].astype(jnp.float32)      # (B, T, d)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
     for i, kind in enumerate(cfg.hybrid_layer_pattern):
         g = lambda n: params[f"l{i}_{n}"]  # noqa: B023 — read immediately
         hkv, li = cfg.kv_heads(kind), at_kind[kind]
         at_kind[kind] += 1
         theta = cfg.swa_rope_theta if kind else cfg.rope_theta
 
-        def rotated(t):     # rotate-half over the first dr lanes
-            return jnp.concatenate(
-                [_rope(t[..., :dr], positions, theta), t[..., dr:]],  # noqa: B023
-                axis=-1)
+        def heads_of(name, n):
+            """A projection cut into ``n`` heads, normed over each head's
+            lanes where the model norms it, rotate-half over the first
+            ``dr`` lanes where this layer's kind has rotary."""
+            with scope("attn.proj"):
+                t = _mm_as_stored(h, g(name)).reshape(B, T, n, dq)  # noqa: B023
+            if cfg.qk_norm:
+                with scope("attn.qk_norm"):
+                    t = _rms(t, g(name[1] + "_norm"), eps)
+            if not cfg.has_rope(kind):  # noqa: B023
+                return t
+            with scope("attn.proj"):
+                if dr == dq:
+                    return _rope(t, positions, theta)  # noqa: B023
+                return jnp.concatenate(
+                    [_rope(t[..., :dr], positions, theta), t[..., dr:]],  # noqa: B023
+                    axis=-1)
+
+        def added(y, norm, into):
+            """``x`` and a branch's output (the sum in the scope ``into``),
+            normed first where the model norms it (sandwich)."""
+            if cfg.sandwich_norm:
+                with scope("norm.post"):
+                    y = _rms(y, g(norm), eps)
+            with scope(into):
+                return x + y  # noqa: B023
 
         with scope(f"layer{i}"):
             with scope("norm"):
                 h = _rms(x, g("norm1"), eps)
+            q, k = heads_of("wq", H), heads_of("wk", hkv)
             with scope("attn.proj"):
-                q = rotated(_mm_as_stored(h, g("wq")).reshape(B, T, H, dq))
-                k = rotated(_mm_as_stored(h, g("wk")).reshape(B, T, hkv, dq))
                 v = _mm(h, g("wv")) * cfg.attention_value_scale
+            if cfg.attention_gate:
+                with scope("attn.gate"):
+                    gate = jax.nn.sigmoid(_mm(h, g("wgate")))
             with scope("attn.cache_write"):
                 k_pool, v_pool = pools[2 * kind], pools[2 * kind + 1]
                 k_pool = k_pool.at[li, phys[kind], offs].set(
@@ -308,20 +427,26 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
                         q, k_pool, v_pool, tables[kind], positions, max_pos,
                         scale=scale, layer=li,
                         call=f"{name_of(kind)}_{phase}",
-                        window=win if kind else 0, sink=sink)
+                        window=win if kind else 0, sink=sink, tiles=True)
                 else:
                     gather = lambda pool, w: pool[li][tables[kind]].reshape(  # noqa: E731,B023
                         B, -1, hkv, w)
                     a = _pa.paged_attention_reference(
                         q, gather(k_pool, dq), gather(v_pool, dv),
                         masks[kind], scale, sink)
+            a = a.reshape(B, T, H * dv)
+            if cfg.attention_gate:
+                with scope("attn.gate"):
+                    a = a * gate
             with scope("attn.proj"):
-                x = x + _mm(a.reshape(B, T, H * dv), g("wo"))
+                y = _mm(a, g("wo"))
+            x = added(y, "norm1_post", "attn.proj")
             with scope("norm"):
                 h = _rms(x, g("norm2"), eps)
             if not cfg.moe_layer_freq[i]:
                 with scope("ffn"):
-                    x = x + _gated(h, g("wg"), g("wu"), g("wd"))
+                    y = _gated(h, g("wg"), g("wu"), g("wd"))
+                x = added(y, "norm2_post", "ffn")
                 continue
             hf = h.reshape(B * T, -1)
             with scope("moe.route"):
@@ -332,8 +457,12 @@ def hybrid_moe_decode(params: Params, tokens, positions, lengths, pools,
             y, sizes, trips = expert_products(
                 hf, w, e, g("wg"), g("wu"), g("wd"), (lo, hi),
                 pallas=use_kernel, n_experts=cfg.n_routed_experts)
-            with scope("moe.combine"):
-                x = x + y.reshape(B, T, -1)
+            if cfg.n_shared_experts:
+                with scope("moe.shared"):
+                    y = y + _gated(hf, g("sg"), g("su"), g("sd"))
+                    aux["shared_expert_tokens"] += jnp.sum(
+                        valid_flat).astype(jnp.int32)
+            x = added(y.reshape(B, T, -1), "norm2_post", "moe.combine")
             with scope("moe.route"):    # the program's own counts
                 mine = (e >= lo) & (e < hi) & valid_flat[:, None]
                 load = jnp.bincount(
@@ -379,8 +508,8 @@ class HybridMoeLM:
     def counters(self) -> Tuple[str, ...]:
         """The names of the program's counts: with a share of the experts
         held, the expert layers' trips too."""
-        return COUNTERS + trip_counters(self.experts_held,
-                                        self.cfg.n_routed_experts)
+        return self.cfg.counters + trip_counters(self.experts_held,
+                                                 self.cfg.n_routed_experts)
 
     @property
     def vocab(self) -> int:
@@ -393,9 +522,10 @@ class HybridMoeLM:
     def cache_spec(self) -> dict:
         """Two kinds: ``full`` keeps every position and is sized by
         tokens; ``window`` keeps what ``sliding_window`` positions can
-        still see and is sized by rows.  K pages hold a head's 192 lanes
-        as they are beside V pages of 128 (PERF.md PR 32 has the chip's
-        reading against 256 padded)."""
+        still see and is sized by rows.  K pages hold a head's lanes as
+        they are: ``mimo_v2``'s 192 beside V pages of 128 (PERF.md PR 32
+        has the chip's reading against 256 padded), ``afmoe``'s 128 and
+        128."""
         c = self.cfg
 
         def kind(k, **more):

@@ -61,7 +61,9 @@ def window_blocks(window: int, chunk: int, block_size: int) -> int:
     feeds it ``chunk`` positions: ``ceil((window + chunk) / block_size) +
     1`` — the ``window`` positions its first query still sees (one more
     while the step before is in flight), the chunk it writes, and the block
-    both ends may straddle."""
+    both ends may straddle.  A window of 128 over blocks of 16 is 10 blocks
+    at rest (``chunk`` 1) and 41 inside a 512-token chunk; one of 2,048
+    over blocks of 32 is 66 and 81."""
     return -(-(int(window) + int(chunk)) // int(block_size)) + 1
 
 
@@ -81,7 +83,13 @@ class CacheKind:
     positions back a query still reads: a row of such a kind owns at most
     :func:`window_blocks` blocks whatever its length, its table is a RING
     (logical block ``b`` sits in column ``b % width``), and the kind is
-    sized by slots where the others are sized by tokens.  ``span`` says
+    sized by slots where the others are sized by tokens: ``1 + rows x
+    window_blocks(window, 1) + window_blocks(window, longest chunk)``
+    blocks, HELD whatever the rows' lengths.  That is small where the
+    window is (128 positions: 0.5 GB of ``mimo-v2.5``'s 13) and the
+    largest thing in the cache where it is not (2,048 positions at 64
+    slots: 3.4 GB, of which rows shorter than the window leave their part
+    unused; docs/generation.md "Cache kinds").  ``span`` says
     which members of :attr:`PagedKVCache.pools` are this kind's.
 
     ``state`` marks a kind that is no pages at all but a slot's recurrent

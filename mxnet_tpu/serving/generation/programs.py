@@ -35,7 +35,8 @@ sparse experts, generation by diffusion over blocks) the second,
 one latent pool, a gated dense layer, sigmoid-routed experts with a shared
 one, of which the chip holds a share) the third,
 :class:`~mxnet_tpu.parallel.hybrid_moe.HybridMoeLM` (window and full
-attention layers over a cache of two kinds) the fourth,
+attention layers over a cache of two kinds: ``mimo_v2``'s block and, the
+same loop with its own terms on, ``afmoe``'s) the fourth,
 :class:`~mxnet_tpu.parallel.retention_lm.RetentionLM` (power retention over
 a cache kind that is a slot's state) the fifth,
 :class:`~mxnet_tpu.parallel.sambay_lm.SambaYLM` (state-space, window and

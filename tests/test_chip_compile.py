@@ -789,14 +789,20 @@ _TILES = dict(full=(4, 16, 192, 128, BS, 0, False, 1024),
               window=(8, 8, 192, 128, BS, 128, True, None),
               # phi-4-mini-flash's window layers by way of
               # ops/diff_attention.py: a KV pair is one head of 128 lanes
-              swa=(10, 4, 128, 128, 32, 512, False, None))
+              swa=(10, 4, 128, 128, 32, 512, False, None),
+              # trinity-mini's two kinds: 4 KV heads of 128 lanes, 8 query
+              # heads each, blocks of 32, a window of 2,048
+              afm_full=(4, 8, 128, 128, 32, 0, False, 1024),
+              afm_window=(4, 8, 128, 128, 32, 2048, False, None))
 
 
 @pytest.mark.parametrize("kind,B,T", [
     ("full", 128, 1), ("window", 128, 1), ("full", 1, 512),
-    ("window", 1, 512), ("swa", 128, 1)],
+    ("window", 1, 512), ("swa", 128, 1), ("afm_full", 64, 1),
+    ("afm_window", 64, 1), ("afm_full", 1, 512), ("afm_window", 1, 512)],
     ids=["full-decode", "window-decode", "full-prefill", "window-prefill",
-         "swa-decode"])
+         "swa-decode", "afm-full-decode", "afm-window-decode",
+         "afm-full-prefill", "afm-window-prefill"])
 def test_tiles_body_compiles_at_the_cells_shapes(one_chip, kind, B, T):
     """The tiles body at ``mimo-v2.5``'s published shapes: ``Hkv x 192``
     K pages beside ``Hkv x 128`` V pages, 16 (full) and 8 (window) query
@@ -804,7 +810,11 @@ def test_tiles_body_compiles_at_the_cells_shapes(one_chip, kind, B, T):
     table in scalar memory) and the window kind's a ring of 16 (decode) or
     64 (a 512-token chunk), with the window and the sink; and at
     ``phi-4-mini-flash``'s swa decode call: 10 pairs of 128 lanes, blocks
-    and a ring of 32, a window of 512, so a trip of 17 pages (PR 46)."""
+    and a ring of 32, a window of 512, so a trip of 17 pages (PR 46); and
+    at ``trinity-mini``'s: a window of 2,048 over blocks of 32 reaches 65
+    pages of 64 KB, 8.5 MB double-buffered and over what a trip may hold,
+    so a window decode call takes 16 pages a trip — FIVE trips a row — on
+    a ring of 128 columns, as its 512-token chunk does (PR 47)."""
     from mxnet_tpu.ops import paged_attention as pa
     from mxnet_tpu.serving.generation.kv_cache import ring_width
 
@@ -821,6 +831,9 @@ def test_tiles_body_compiles_at_the_cells_shapes(one_chip, kind, B, T):
     if kind == "swa":
         assert (W, pa._tiles_geometry(G * T, G, bs, W, window, pa._page_bytes(
             *shapes[5:7]))) == (32, (8, 17))
+    if kind == "afm_window":
+        assert (W, pa._tiles_geometry(G * T, G, bs, W, window, pa._page_bytes(
+            *shapes[5:7]))) == (128, (8 if T == 1 else 256, 16))
     phase = "decode" if T == 1 else "prefill"
     text = _compile(
         functools.partial(pa._tiles_call.__wrapped__, n_heads=hkv,
@@ -892,6 +905,74 @@ def test_hybrid_step_program_compiles_at_the_cells_shapes(one_chip,
     # and ``wk`` are multiplied flat and the RESULT is cut into heads of 192
     _no_argument_copies(text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def _afmoe_cell(sds):
+    """``trinity-mini`` at depth 4: both dense layers (window), a window
+    and a full expert layer, 16 of 128 experts held, the vocabulary
+    whole."""
+    from mxnet_tpu.parallel import hybrid_moe as hm
+    from perfbench import harness
+
+    c = harness.load_json("configs", "trinity-mini.json")
+    cfg = hm.HybridMoeConfig.from_afmoe(
+        dict(c, num_hidden_layers=4, layer_types=c["layer_types"][:4]),
+        n_routed_experts=c["published"]["num_experts"])
+    held = tuple(c["experts_held"])
+    model = hm.HybridMoeLM(cfg, max_len=c["max_len"], experts_held=held)
+    params = {k: sds(s, jnp.bfloat16) for k, s in
+              hm.hybrid_moe_param_shapes(cfg, held).items()}
+    return model, params, c["service"]
+
+
+@pytest.mark.parametrize("T", [1, 512], ids=["decode", "prefill"])
+def test_afmoe_step_program_compiles_at_the_cells_shapes(one_chip,
+                                                         monkeypatch, T):
+    """``trinity-mini``'s ``gen_decode`` (64 rows) and ``gen_prefill`` (a
+    512-token chunk) as the service dispatches them, at depth 4: both
+    kinds' calls take the tiles body and are named for kind and phase, the
+    window kind's on a ring of 128 columns; the pools of the cell's own
+    sizes (16,000 and 4,306 blocks of 32) are updated in place; the held
+    rows' tiles are 2 x S x T x 8 / 8 rows; the temporaries of a chunk
+    over the whole vocabulary's head stay under 1.5 GB."""
+    from mxnet_tpu.serving.generation import programs as gp
+    from mxnet_tpu.serving.generation.kv_cache import (ring_width,
+                                                       window_blocks)
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    model, params, service = _afmoe_cell(sds)
+    S = service["max_slots"] if T == 1 else 1
+    bs = service["block_size"]
+    full, window = model.cache_spec()["kinds"]
+    held = 1 + service["max_slots"] * window_blocks(2048, 1, bs) \
+        + window_blocks(2048, 512, bs)
+    assert held == 4306 and ring_width(2048, T, bs) == 128
+    pools = tuple(sds((k["n_layers"], nb, bs, w), jnp.bfloat16)
+                  for k, nb in ((full, service["num_blocks"]), (window, held))
+                  for _, w in k["pools"])
+    fn = jax.jit(functools.partial(gp._model_step, model=model,
+                                   attention_kernel="paged"),
+                 donate_argnums=(1,))
+    compiled = fn.lower(
+        params, pools, sds((S, T), jnp.int32), sds((S, T), jnp.int32),
+        sds((S,), jnp.int32),
+        (sds((S, 1024), jnp.int32), sds((S, 128), jnp.int32)),
+        sds((S,), jnp.uint32), sds((S,), jnp.uint32), sds((S,), jnp.float32),
+        sds((S,), jnp.int32), sds((S,), jnp.float32)).compile()
+    text = compiled.as_text()
+    phase = "decode" if T == 1 else "prefill"
+    assert f"_paged_call_w1024_t{T}_full_{phase}" in text
+    assert text.count(f"_paged_call_w128_t{T}_window_{phase}") >= 3
+    rows = 2 * S * T * 8 // 8
+    assert len(re.findall(rf"%_gmm_call[\w.\-]* = f32\[{rows},", text)) == 6
+    for shape in (f"= bf16[1,{service['num_blocks']},{bs},512]",
+                  f"= bf16[3,{held},{bs},512]"):
+        makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
+                  for ln in text.splitlines() if shape in ln}
+        assert makers <= _IN_PLACE, (shape, makers)
+    _no_argument_copies(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
 # -- brumby-14b: power retention over a slot's state (PR 40) ----------------

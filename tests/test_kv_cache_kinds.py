@@ -122,3 +122,98 @@ def test_a_kind_cannot_be_a_window_and_a_state():
 
 def test_the_state_pools_start_at_zero(cache):
     assert not np.asarray(cache.pools[4]).any()
+
+
+# -- a window as wide as trinity-mini's: 2,048 positions over blocks of 32 ------
+WIDE, WBS, WROWS, WCHUNK = 2048, 32, 64, 512
+
+
+def test_a_wide_windows_blocks_ring_and_pool():
+    """What a row owns at most: the 2,048 positions its next query sees
+    are 64 blocks, 65 where they straddle, one more while the step before
+    is in flight — 66 at rest, 81 inside a 512-token chunk; every table is
+    a ring of 128 columns; and the kind HOLDS 1 + 64 x 66 + 81 = 4,306
+    blocks whatever the rows' lengths (3.39 GB at 12 layers of 2 KB a
+    position) where a window of 128 over blocks of 16 holds 1,322."""
+    assert window_blocks(WIDE, 1, WBS) == 66
+    assert window_blocks(WIDE, 128, WBS) == 69
+    assert window_blocks(WIDE, WCHUNK, WBS) == 81
+    assert {ring_width(WIDE, t, WBS) for t in (1, 128, WCHUNK)} == {128}
+    assert 1 + 128 * window_blocks(128, 1, 16) + window_blocks(128, 512, 16) \
+        == 1322
+    cache = PagedKVCache(num_blocks=8, block_size=WBS, dtype=jnp.bfloat16,
+                         window_rows=(WROWS, WCHUNK), kinds=(
+        dict(name="full", n_layers=1, pools=(("k", 8), ("v", 8))),
+        dict(name="window", n_layers=2, pools=(("k", 8), ("v", 8)),
+             window=WIDE)))
+    window = cache.kinds[1]
+    assert window.num_blocks == 4306 and window.window == WIDE
+    assert [tuple(p.shape) for p in cache.pools[window.span]] == \
+        [(2, 4306, WBS, 8)] * 2
+    # at the cell's widths a block is 12 x 32 x (512 + 512) x 2 B
+    assert window.num_blocks * 12 * WBS * 1024 * 2 == 3386376192
+
+
+def test_a_wide_window_slides_while_its_row_decodes():
+    """The engine's side (``_slide``, ``_ring_tables``), no program run: a
+    row prefilled to 300 positions decodes to 5,000.  Under the window it
+    owns its length's blocks; past it 64 or 65 (66 is the bound: one more
+    while the step before is in flight and the host is a position
+    behind); every block that slid out went back to the kind; the ring of
+    128 columns never holds two of its blocks in one column, and holds
+    each at its logical block modulo 128."""
+    import jax
+
+    from mxnet_tpu.parallel import hybrid_moe as hm
+    from mxnet_tpu.serving.generation import (GenerationConfig,
+                                              GenerationService)
+
+    cfg = hm.HybridMoeConfig(
+        vocab_size=31, hidden_size=16, intermediate_size=16,
+        moe_intermediate_size=8, num_hidden_layers=2,
+        hybrid_layer_pattern=(0, 1), moe_layer_freq=(0, 1),
+        num_attention_heads=2, num_key_value_heads=1, head_dim=8,
+        v_head_dim=8, swa_num_attention_heads=2, swa_num_key_value_heads=1,
+        swa_head_dim=8, swa_v_head_dim=8, sliding_window=WIDE,
+        partial_rotary_factor=1.0, n_routed_experts=2, num_experts_per_tok=1,
+        max_position_embeddings=8192)
+    model = hm.HybridMoeLM(cfg, max_len=8192, kv_dtype=jnp.float32)
+    svc = GenerationService(
+        hm.hybrid_moe_init(cfg, jax.random.PRNGKey(0)), model,
+        GenerationConfig(max_slots=2, block_size=WBS, num_blocks=300,
+                         seq_buckets=[128, 512], prefix_cache=False),
+        start=False)
+    kind = svc._cache.kinds[1]
+    assert kind.num_blocks == 1 + 2 * 66 + 81
+
+    class Row:
+        rid, wins = 0, None
+    row = Row()
+    svc._slide(row, 0, 300)
+    svc._slide(row, 300, 300)
+    assert len(row.wins[0][1]) == 10 == kind.allocator.num_used
+    most = 0
+    for pos in range(300, 5000):
+        svc._slide(row, pos, pos + 1)
+        first, blocks = row.wins[0]
+        most = max(most, len(blocks))
+        assert len(blocks) == kind.allocator.num_used
+        if pos < WIDE:
+            assert (first, len(blocks)) == (0, pos // WBS + 1)
+        elif pos >= WIDE + WBS:
+            assert len(blocks) in (64, 65)
+            # the first position the query at ``pos`` reads is held
+            assert first * WBS <= pos - (WIDE - 1) < (first + 1) * WBS
+        if pos % 257 == 0:
+            (table,) = svc._ring_tables([(1, row)], 2, 1)
+            assert table.shape == (2, 128) and not table[0].any()
+            cols = np.nonzero(table[1])[0]
+            assert len(cols) == len(blocks)
+            for j, b in enumerate(blocks):
+                assert table[1, (first + j) % 128] == b
+    assert most == 65 < window_blocks(WIDE, 1, WBS)
+    assert svc.stats()["counts"]["window_blocks_freed"] \
+        == 5000 // WBS + 1 - len(row.wins[0][1])
+    svc._drop_windows(row)
+    assert kind.allocator.num_used == 0
+    svc.stop(drain=False, timeout=30)
