@@ -49,15 +49,18 @@ Two bodies, chosen by what the call sees in its shapes:
   earliest query's window reaches (``max(0, pos - window + 1) // bs``; 0
   without a window) to its latest query's own, found in the table as a
   RING — logical page ``p`` in column ``p % W`` — so that a window layer's
-  table is as wide as a window and not as a sequence.  A trip computes
-  every position of its page group, fetched or not, so its size is the
-  call's own: WITHOUT a window 512 positions, the row's pages in
+  table is as wide as a window and not as a sequence.  A trip's size is
+  the call's own: WITHOUT a window 512 positions, the row's pages in
   ``ceil(live / pages)`` trips; WITH one the pages its tile's queries can
   reach, ``ceil((t + window - 1) / bs) + 1`` for ``t`` consecutive
-  positions (17 for one token at 512 / 32, 9 at 128 / 16), in ONE trip
-  wherever K and V fit double-buffered (``_tile_pages``: static, from
-  ``window``, the block length and the table's width) — the next trip
-  prefetched is then the next row's.  The mask is
+  positions (17 for one token at 512 / 32, 9 at 128 / 16, 65 at 2,048 /
+  32), in ONE trip wherever K and V fit fast memory double-buffered beside
+  the tile's own query rows (``_tile_pages``: static, from the tile's
+  rows, ``window``, the block length, the page's bytes and the table's
+  width) — the next trip prefetched is then the next row's.  A trip
+  computes every position of its page group, fetched or not; one of two
+  runs of 512 positions or more computes the fewest whole runs that hold
+  its tile's pages, in one update (``_trip_part``).  The mask is
   ``pos - window < j <= pos``; the sink is one learned logit a query head
   that joins the softmax's denominator and no value: the recurrence starts
   at ``m = sink, l = 1, acc = 0``.  K pages hold ``dk`` lanes a head, V
@@ -494,8 +497,9 @@ def _rows_kernel(tables_ref, maxpos_ref, layer_ref, q_ref, pos_ref, k_hbm,
 
 
 def _tiles_kernel(tables_ref, first_ref, end_ref, layer_ref, q_ref, pos_ref,
-                  *refs, bs: int, pages: int, n_heads: int, d_key: int,
-                  d_value: int, scale: float, window: int, sink: bool):
+                  *refs, bs: int, pages: int, part: int, n_heads: int,
+                  d_key: int, d_value: int, scale: float, window: int,
+                  sink: bool):
     # grid = (B * tiles,): step s is tile s % tiles of row s // tiles, up
     # to 256 grouped query rows.  As the rows body, the pools stay in HBM
     # and this body fetches its pages itself, double-buffered, the next
@@ -504,6 +508,10 @@ def _tiles_kernel(tables_ref, first_ref, end_ref, layer_ref, q_ref, pos_ref,
     # a window pays: pages before the tile's earliest window are never
     # fetched.  The table is a ring: logical page p sits in column p % W
     # (a table as wide as the sequence is one whose ring never wraps).
+    # A trip of ``pages`` page slots is computed whole (``part == pages``)
+    # or, a long one, over the fewest whole runs of ``part`` slots that
+    # hold its tile's pages: a row shorter than the trip pays for what it
+    # has, to a part.
     from jax.experimental.pallas import tpu as pltpu
 
     if sink:
@@ -526,10 +534,10 @@ def _tiles_kernel(tables_ref, first_ref, end_ref, layer_ref, q_ref, pos_ref,
     def groups(s):
         return jax.lax.div(span(s)[2] + pages - 1, pages)
 
-    def page_copies(s, g, half, enabled=True):
+    def page_copies(s, g, half, enabled=True, slots=range(pages)):
         b, first, count = span(s)
         out = []
-        for j in range(pages):
+        for j in slots:
             idx = g * pages + j
             blk = tables_ref[b, jax.lax.rem(first + idx, W)]
             out.append(((idx < count) & (blk != 0) & enabled,
@@ -548,9 +556,36 @@ def _tiles_kernel(tables_ref, first_ref, end_ref, layer_ref, q_ref, pos_ref,
         # a zero probability multiplies finite
         vbuf[...] = jnp.zeros_like(vbuf)
 
+    def held(s, g):
+        """Page slots of trip ``g`` of step ``s`` that hold a page."""
+        return span(s)[2] - g * pages
+
+    def by_parts(act, s, g, half, enabled=True):
+        """``act`` on the trip's copies: all of them or, a trip of
+        several parts, those of the parts that hold a page — a loop over
+        the whole parts (a part's slots unrolled, as a short trip's are:
+        65 slots unrolled at three places made the program slow to load)
+        and the slots left over."""
+        if part == pages:
+            return act(page_copies(s, g, half, enabled))
+        live = jnp.where(enabled, held(s, g), 0)
+        whole = pages // part
+
+        def one(p, _):
+            act(page_copies(s, g, half,
+                            slots=[p * part + j for j in range(part)]))
+
+        jax.lax.fori_loop(
+            0, jnp.clip(jax.lax.div(live + part - 1, part), 0, whole), one,
+            None)
+        if pages % part:
+            @pl.when(live > whole * part)
+            def _():
+                act(page_copies(s, g, half, slots=range(whole * part, pages)))
+
     @pl.when((step == 0) | (groups(jnp.maximum(step - 1, 0)) == 0))
     def _own_first_group():
-        _start_copies(page_copies(step, 0, jax.lax.rem(trip_ref[0], 2)))
+        by_parts(_start_copies, step, 0, jax.lax.rem(trip_ref[0], 2))
 
     # the sink joins the denominator and no value: the recurrence starts
     # at m = sink, l = 1, acc = 0
@@ -564,21 +599,13 @@ def _tiles_kernel(tables_ref, first_ref, end_ref, layer_ref, q_ref, pos_ref,
     n_groups = groups(step)
     head = jax.lax.broadcasted_iota(jnp.int32, (rows, n_heads), 1)
 
-    def trip(g, _):
-        t = trip_ref[0]
-        half = jax.lax.rem(t, 2)
-        more = g + 1 < n_groups
-        _start_copies(page_copies(
-            jnp.where(more, step, jnp.minimum(step + 1, n_steps - 1)),
-            jnp.where(more, g + 1, 0), 1 - half,
-            more | (step + 1 < n_steps)))
-        _wait_copies(page_copies(step, g, half))
-        k = kbuf[half].reshape(n, -1).astype(mxu)              # (n, Hkv*dk)
-        v = vbuf[half].reshape(n, -1).astype(mxu)              # (n, Hkv*dv)
+    def update(k, v, page):
+        """The online-softmax update over the cache positions of ``k`` and
+        ``v``, which start at logical page ``page``."""
+        n = k.shape[0]
         # a page that was not fetched lies past the row's last position or
         # behind every window of the tile: the position mask covers it
-        ctx = (first + g * pages) * bs \
-            + jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1)
+        ctx = page * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1)
         mask = ctx <= pos
         if window:
             mask &= ctx > pos - window
@@ -603,6 +630,35 @@ def _tiles_kernel(tables_ref, first_ref, end_ref, layer_ref, q_ref, pos_ref,
             l_all = jnp.where(head == h, l_new, l_all)
         m_ref[...] = m_all
         l_ref[...] = l_all
+
+    def trip(g, _):
+        t = trip_ref[0]
+        half = jax.lax.rem(t, 2)
+        more = g + 1 < n_groups
+        by_parts(_start_copies,
+                 jnp.where(more, step, jnp.minimum(step + 1, n_steps - 1)),
+                 jnp.where(more, g + 1, 0), 1 - half,
+                 more | (step + 1 < n_steps))
+        by_parts(_wait_copies, step, g, half)
+        if part == pages:
+            update(kbuf[half].reshape(n, -1).astype(mxu),      # (n, Hkv*dk)
+                   vbuf[half].reshape(n, -1).astype(mxu),      # (n, Hkv*dv)
+                   first + g * pages)
+        else:
+            # ONE update over the fewest whole parts that hold the tile's
+            # pages (the whole trip once less than a part would be left
+            # over): an update costs ~1 us whatever it holds, so several
+            # short ones cost more than they save (PERF.md PR 48)
+            live, lo = held(step, g), 0
+            for size in (*range(part, pages - part + 1, part), pages):
+                @pl.when((live > lo) if size == pages
+                         else (live > lo) & (live <= size))
+                def _(size=size):
+                    update(kbuf[half, :size].reshape(size * bs, -1)
+                           .astype(mxu),
+                           vbuf[half, :size].reshape(size * bs, -1)
+                           .astype(mxu), first + g * pages)
+                lo = size
         trip_ref[0] = t + 1
 
     jax.lax.fori_loop(0, n_groups, trip, None)
@@ -619,32 +675,50 @@ _TILE_ROWS = 256        # grouped query rows a step of the tiles body
 # its bytes, and the full kind's decode call read 5.00 ms at 512 against
 # 6.08 at 256, a 512-token chunk's 2.04 against 3.18 (PERF.md PR 32).  A
 # call WITH a window sizes its trip from the pages its tile can reach
-# (_tile_pages): a trip computes all its positions, fetched or not
+# (_tile_pages), and a trip of two such runs or more is computed over the
+# fewest of them that hold its tile's pages (_trip_part)
 _TILE_POSITIONS = 512
-# K and V, double-buffered, of a trip sized from a window's reach.  Beside a
-# 256-row tile's queries, output and accumulator the chip's compiler takes
-# 5.2 MB (512 positions of 2,560 bfloat16 lanes) and refused 6.9 (21 pages
-# of 32 of them: fast memory exhausted, PERF.md PR 46); a decode call's 17
-# such pages are 5.6
-_TILE_KV_BYTES = 6 << 20
+# fast memory a tiles call may fill: the 16 MB the chip's compiler gives a
+# kernel, less 1 MB for what it keeps there itself.  Reckoned as
+# _tile_pages reckons, a 256-row chunk tile of 2,560 bfloat16 lanes a
+# position (K and V) was taken with 5.2 MB of double-buffered K and V
+# beside it (14.7 MB in all) and refused with 6.9 (16.8: "ran out of
+# memory in memory space vmem", PERF.md PR 46); an 8-row decode tile was
+# taken with 8.5 MB (8.8 in all, PERF.md PR 48)
+_TILE_VMEM_BYTES = 15 << 20
 
 
-def _tile_pages(span: int, bs: int, w: int, window: int,
+def _tile_pages(rows: int, span: int, bs: int, w: int, window: int,
                 page_bytes: int) -> int:
-    """Pages a trip of the tiles body fetches and computes.  ``span``
-    consecutive query positions with a ``window`` read at most ``reach =
-    ceil((span + window - 1) / bs) + 1`` pages (the window's positions, and
-    the page both ends may straddle), so such a call takes them in ONE
-    trip wherever a K and a V page of ``page_bytes`` together, ``reach`` of
-    them double-buffered, stay under ``_TILE_KV_BYTES``; a call without a
-    window, or whose reach does not fit, ``_TILE_POSITIONS`` positions a
-    trip.  Never more pages than the table is wide."""
+    """Pages a trip of the tiles body fetches.  ``span`` consecutive query
+    positions with a ``window`` read at most ``reach = ceil((span + window
+    - 1) / bs) + 1`` pages (the window's positions, and the page both ends
+    may straddle), so such a call takes them in ONE trip wherever the
+    trip fits fast memory beside the tile that runs it: K and V pages of
+    ``page_bytes`` a pair, ``reach`` pairs double-buffered, and the tile's
+    own ``rows`` query rows — a row's queries and output (float32,
+    double-buffered), its accumulator and running maximum and sum, at most
+    six cached positions' K and V together, and a trip's scores three
+    times over (the scores, their mask, the probabilities) — stay under
+    ``_TILE_VMEM_BYTES``.  A call without a window, or whose reach does
+    not fit, takes ``_TILE_POSITIONS`` positions a trip.  Never more pages
+    than the table is wide."""
     pages = _TILE_POSITIONS // bs
     if window:
         reach = -(-(span + window - 1) // bs) + 1
-        if 2 * reach * page_bytes <= _TILE_KV_BYTES:
+        tile = rows * (6 * page_bytes // bs + 3 * 4 * reach * bs)
+        if 2 * reach * page_bytes + tile <= _TILE_VMEM_BYTES:
             pages = reach
     return max(1, min(pages, w))
+
+
+def _trip_part(pages: int, bs: int) -> int:
+    """Pages a part of a trip: the trip itself (computed whole), or
+    ``_TILE_POSITIONS`` positions of a trip that holds two such runs or
+    more, whose ONE update reads the fewest whole parts that hold its
+    tile's pages."""
+    part = _TILE_POSITIONS // bs
+    return part if pages >= 2 * part else pages
 
 
 def _tiles_geometry(rows: int, groups: int, bs: int, w: int, window: int,
@@ -655,7 +729,7 @@ def _tiles_geometry(rows: int, groups: int, bs: int, w: int, window: int,
     that crosses groups may span the chunk's."""
     bt = min(_TILE_ROWS, -(-rows // 8) * 8)
     t = rows // groups
-    return bt, _tile_pages(bt if t % bt == 0 else t, bs, w, window,
+    return bt, _tile_pages(bt, bt if t % bt == 0 else t, bs, w, window,
                            page_bytes)
 
 
@@ -745,9 +819,9 @@ def _tiles_call(tables, max_pos, layer, q, positions, k_pool, v_pool,
                         pltpu.SMEM((1,), jnp.int32)],               # trips
     )
     kernel = functools.partial(
-        _tiles_kernel, bs=bs, pages=pages, n_heads=n_heads,
-        d_key=HDk // n_heads, d_value=HDv // n_heads, scale=scale,
-        window=window, sink=sink is not None)
+        _tiles_kernel, bs=bs, pages=pages, part=_trip_part(pages, bs),
+        n_heads=n_heads, d_key=HDk // n_heads, d_value=HDv // n_heads,
+        scale=scale, window=window, sink=sink is not None)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -812,9 +812,11 @@ def test_tiles_body_compiles_at_the_cells_shapes(one_chip, kind, B, T):
     ``phi-4-mini-flash``'s swa decode call: 10 pairs of 128 lanes, blocks
     and a ring of 32, a window of 512, so a trip of 17 pages (PR 46); and
     at ``trinity-mini``'s: a window of 2,048 over blocks of 32 reaches 65
-    pages of 64 KB, 8.5 MB double-buffered and over what a trip may hold,
-    so a window decode call takes 16 pages a trip — FIVE trips a row — on
-    a ring of 128 columns, as its 512-token chunk does (PR 47)."""
+    pages of 64 KB, 8.5 MB double-buffered, which a decode tile's 8 query
+    rows leave room for: ONE trip a row on a ring of 128 columns, computed
+    over the 16-page parts a row's pages fill (PR 48; five trips of 16
+    before); its 512-token
+    chunk's 256-row tile reaches 73 and keeps 16 pages a trip (PR 47)."""
     from mxnet_tpu.ops import paged_attention as pa
     from mxnet_tpu.serving.generation.kv_cache import ring_width
 
@@ -833,7 +835,7 @@ def test_tiles_body_compiles_at_the_cells_shapes(one_chip, kind, B, T):
             *shapes[5:7]))) == (32, (8, 17))
     if kind == "afm_window":
         assert (W, pa._tiles_geometry(G * T, G, bs, W, window, pa._page_bytes(
-            *shapes[5:7]))) == (128, (8 if T == 1 else 256, 16))
+            *shapes[5:7]))) == (128, (8, 65) if T == 1 else (256, 16))
     phase = "decode" if T == 1 else "prefill"
     text = _compile(
         functools.partial(pa._tiles_call.__wrapped__, n_heads=hkv,

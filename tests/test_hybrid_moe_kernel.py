@@ -93,6 +93,7 @@ def test_tiles_body_matches_its_oracle(T, window, sink, dtype):
 # -- a windowed call's trip is sized from what its tile can reach (PR 46) ----
 _PHI = dict(window=512, bs=32, ring=32, sink=False)     # phi-4-mini-flash
 _MIMO = dict(window=128, bs=16, ring=16, sink=True)     # mimo-v2.5
+_AFM = dict(window=2048, bs=32, ring=128, sink=False)   # trinity-mini
 
 
 @pytest.mark.parametrize("shape,ctx,dead", [
@@ -100,23 +101,38 @@ _MIMO = dict(window=128, bs=16, ring=16, sink=True)     # mimo-v2.5
     (_PHI, [2079, 1055, 543], ()), (_PHI, [1023, 1024, 1185], ()),
     (_PHI, [0, 100, 510], ()), (_PHI, [2050, 2051, 2077], (1,)),
     (_MIMO, [1024, 256, 4096], ()), (_MIMO, [1038, 270, 4110], ()),
-    (_MIMO, [1039, 271, 127], ())],
+    (_MIMO, [1039, 271, 127], ()),
+    (_AFM, [0, 100, 513], ()), (_AFM, [1055, 2047, 2048], ()),
+    (_AFM, [2079, 4200, 2048], ()), (_AFM, [4200, 2047, 1055], (1,)),
+    (_AFM, [511, 1023, 1535], ()), (_AFM, [512, 1024, 1536], ())],
     ids=["w512-p0", "w512-p30", "w512-p31", "w512-ring-wraps",
          "w512-shorter-than-the-window", "w512-inactive-between",
-         "w128-sink-p0", "w128-sink-p14", "w128-sink-p15"])
+         "w128-sink-p0", "w128-sink-p14", "w128-sink-p15",
+         "w2048-under-a-part-and-across-its-edge",
+         "w2048-one-short-of-the-window-and-at-it",
+         "w2048-64-pages-and-a-ring-that-wrapped",
+         "w2048-inactive-between", "w2048-a-part-full",
+         "w2048-a-page-into-the-next-part"])
 def test_a_windowed_decode_call_reads_its_reach_in_one_trip(shape, ctx, dead):
-    """The decode call at the two cells' window, block length and ring, at
-    the page boundaries the geometry creates: a query whose window
+    """The decode call at the three cells' window, block length and ring,
+    at the page boundaries the geometry creates: a query whose window
     straddles ``reach`` pages (17 of 32; 9 of 16) and one whose window
     ends on a page's last position (16; 8), a ring that wrapped, rows
     shorter than the window, an inactive row between live ones; the sink
-    in the denominator.  The results are the oracle's and every live row
-    takes one trip."""
+    in the denominator.  ``trinity-mini``'s trip of 65 pages computes
+    the fewest parts of 16 that hold a row's pages (PR 48): a row at 0,
+    under one part (100), across a part's edge (513: 17 pages; 1,055: 33),
+    one short of the window (2,047: 64), at it (2,048: all 65), 31
+    positions later (2,079: 64 pages), a ring that wrapped (4,200), rows
+    that fill 1, 2 and 3 parts to the last position (511, 1,023, 1,535) and
+    rows one position further.  The results are the oracle's and every
+    live row takes one trip."""
     window, bs, ring = shape["window"], shape["bs"], shape["ring"]
     rng = np.random.default_rng(sum(ctx))
     hkv, G, d = 2, 4, 16
     q, kp, vp, tables, pos, max_pos = _ring_case(
-        rng, 3, 1, hkv, G, d, d, window, bs, ring, 64, jnp.float32, ctx)
+        rng, 3, 1, hkv, G, d, d, window, bs, ring, 64 if ring < 128 else 256,
+        jnp.float32, ctx)
     for b in dead:
         max_pos[b] = -1
     s = jnp.asarray(rng.normal(0, 1, hkv * G), jnp.float32) \
@@ -137,29 +153,49 @@ def test_a_windowed_decode_call_reads_its_reach_in_one_trip(shape, ctx, dead):
                                      window=window)) == len(live)
 
 
-@pytest.mark.parametrize("span,bs,width,window,page_bytes,pages", [
-    (1, 32, 32, 512, 2 * 32 * 1280 * 2, 17),      # phi-4-mini-flash, decode
-    (1, 16, 16, 128, 16 * (1536 + 1024) * 2, 9),  # mimo-v2.5, decode
-    (256, 16, 64, 128, 16 * (1536 + 1024) * 2, 25),   # its 512-token chunk
+@pytest.mark.parametrize("rows,span,bs,width,window,page_bytes,pages", [
+    (8, 1, 32, 32, 512, 2 * 32 * 1280 * 2, 17),   # phi-4-mini-flash, decode
+    (8, 1, 16, 16, 128, 16 * (1536 + 1024) * 2, 9),   # mimo-v2.5, decode
+    (256, 256, 16, 64, 128, 16 * (1536 + 1024) * 2, 25),  # its 512-token
+    (256, 128, 16, 32, 128, 16 * (1536 + 1024) * 2, 17),  # and 128-token chunk
     # a chunk tile of phi-4-mini-flash reaches 25 (21) pages: 8.2 (6.9) MB
     # of K and V beside 256 query rows, which the chip's compiler refused
-    (256, 32, 64, 512, 2 * 32 * 1280 * 2, 16),
-    (128, 32, 32, 512, 2 * 32 * 1280 * 2, 16),
-    (1, 32, 1024, 0, 2 * 32 * 1280 * 2, 16),      # no window: 512 positions
-    (256, 16, 1024, 0, 16 * (768 + 512) * 2, 32),
-    (1, 32, 8, 512, 2 * 32 * 1280 * 2, 8),        # never more than the table
-    (1, 16, 4, 0, 16 * 1280 * 2, 4), (1, 4, 4, 8, 4 * 48 * 4, 3)],
-    ids=["phi-decode", "mimo-decode", "mimo-chunk", "phi-chunk512-keeps",
-         "phi-chunk128-keeps", "full-decode", "full-chunk", "narrow-ring",
+    (256, 256, 32, 64, 512, 2 * 32 * 1280 * 2, 16),
+    (256, 128, 32, 32, 512, 2 * 32 * 1280 * 2, 16),
+    # trinity-mini: 65 pages of 64 KB are 8.5 MB, which fit beside a decode
+    # tile's 8 rows; its 512-token chunk's 73 beside 256 rows (9.6 MB and
+    # 2,336 positions of scores a row) do not
+    (8, 1, 32, 128, 2048, 2 * 32 * 512 * 2, 65),
+    (256, 256, 32, 128, 2048, 2 * 32 * 512 * 2, 16),
+    (8, 1, 32, 1024, 0, 2 * 32 * 1280 * 2, 16),   # no window: 512 positions
+    (256, 256, 16, 1024, 0, 16 * (768 + 512) * 2, 32),
+    (8, 1, 32, 8, 512, 2 * 32 * 1280 * 2, 8),     # never more than the table
+    (8, 1, 16, 4, 0, 16 * 1280 * 2, 4), (8, 1, 4, 4, 8, 4 * 48 * 4, 3)],
+    ids=["phi-decode", "mimo-decode", "mimo-chunk", "mimo-chunk128",
+         "phi-chunk512-keeps", "phi-chunk128-keeps", "afm-decode",
+         "afm-chunk512-keeps", "full-decode", "full-chunk", "narrow-ring",
          "narrow-table", "tiny"])
-def test_the_pages_a_trip_of_the_tiles_body(span, bs, width, window,
+def test_the_pages_a_trip_of_the_tiles_body(rows, span, bs, width, window,
                                             page_bytes, pages):
     """``_tile_pages``: with a window the pages ``span`` consecutive
-    queries can reach, where K and V fit; without one, and where they do
-    not, ``_TILE_POSITIONS`` positions; never more than the table's
-    width."""
-    assert pa._tile_pages(span, bs, width, window, page_bytes) == pages
+    queries can reach, where K and V fit fast memory beside the tile's own
+    ``rows``; without one, and where they do not, ``_TILE_POSITIONS``
+    positions; never more than the table's width."""
+    assert pa._tile_pages(rows, span, bs, width, window, page_bytes) == pages
     assert pa._TILE_ROWS == 256 and pa._TILE_POSITIONS == 512
+
+
+@pytest.mark.parametrize("pages,bs,part", [
+    (65, 32, 16), (33, 32, 16), (32, 32, 16), (31, 32, 31), (17, 32, 17),
+    (25, 16, 25), (9, 16, 9), (16, 32, 16), (32, 16, 32), (64, 16, 32)],
+    ids=["afm-decode", "two-parts-and-a-page", "two-parts", "under-two",
+         "phi-decode", "mimo-chunk", "mimo-decode", "full-32", "full-16",
+         "two-parts-of-16"])
+def test_a_long_trip_is_computed_to_a_part(pages, bs, part):
+    """``_trip_part``: a trip that holds two runs of ``_TILE_POSITIONS``
+    positions or more computes the fewest whole runs that hold its tile's
+    pages; every shorter one is computed whole, as it always was."""
+    assert pa._trip_part(pages, bs) == part
 
 
 def test_a_tiles_span_is_its_own_rows_or_the_chunks():
