@@ -290,6 +290,10 @@ class BaseModule:
         # a trace id, and the async checkpoint writer inherits it across
         # its thread boundary.  attach(None) is a no-op (TPUMX_TRACING=0).
         _fit_trace_token = _obs.tracing.attach(_obs.tracing.new_trace())
+        # a collection's pause is a span of its own (``fit.gc``), not time
+        # of whichever span the loop had open
+        _gc_watch = _obs.GcWatch("fit.gc")
+        _gc_watch.open()
         try:
           for epoch in range(begin_epoch, num_epoch):
             with _obs.span(f"fit.epoch[{epoch}]", cat="fit"):
@@ -389,6 +393,7 @@ class BaseModule:
                         self.logger.info("Epoch[%d] Validation-%s=%f", epoch, name, val)
                 train_data.reset()
         finally:
+            _gc_watch.close()
             _obs.tracing.detach(_fit_trace_token)
             if _preempt is not None:
                 _preempt.uninstall()

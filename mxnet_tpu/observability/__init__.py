@@ -16,6 +16,10 @@ kvstore, io, amp and serving:
   trace-context layer (``TraceContext`` ids propagated thread-locally and
   handed off explicitly across queue/thread/replica boundaries) and the
   per-request **wide-event** records behind :func:`recent_requests`;
+- :mod:`.gc_watch` — the interpreter's collector on the loops' timeline:
+  while a :class:`GcWatch` is open (an engine loop, a ``Module.fit`` call)
+  every collection is a span (``serving.gc``, ``fit.gc``) and its pause a
+  counter;
 - :mod:`.flight_recorder` — the crash black box: bounded rings of recent
   spans/wide events/notes that dump to a timestamped JSON file on crash,
   SIGTERM, decode-step quarantine, and circuit-breaker open;
@@ -43,11 +47,13 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
 from .tracing import (span, current_span, span_stack, TraceContext,
                       new_trace, current_trace, use_context,
                       recent_requests, recent_spans)
+from .gc_watch import GcWatch
 from .recompile import (FreezeCompilesError, explain_key_diff,
                         last_explanations, mark_warm)
 from . import device_scopes
 from . import exposition
 from . import flight_recorder
+from . import gc_watch
 from . import metrics
 from . import recompile
 from . import telemetry
@@ -56,12 +62,12 @@ from . import tracing
 __all__ = ["registry", "snapshot", "to_prometheus", "dump_prometheus",
            "reset", "span", "current_span", "span_stack", "mark_warm",
            "TraceContext", "new_trace", "current_trace", "use_context",
-           "recent_requests", "recent_spans",
+           "recent_requests", "recent_spans", "GcWatch",
            "last_explanations", "explain_key_diff", "FreezeCompilesError",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
            "DEFAULT_BUCKETS", "device_scopes", "metrics", "tracing",
            "recompile",
-           "telemetry", "exposition", "flight_recorder"]
+           "telemetry", "exposition", "flight_recorder", "gc_watch"]
 
 #: the process-wide default registry every subsystem records into
 _default_registry = MetricsRegistry()

@@ -36,11 +36,19 @@ span timing/profiler behavior — and everything the engine computes — stays
 byte-identical (docs/observability.md).  Cost discipline with tracing on
 and the profiler stopped: a span is two ``time.perf_counter`` calls, a
 list push/pop, and one deque append — cheap enough for per-batch and
-per-decode-step scopes.  On the chip (PERF.md section 6, PR 24) the ten
-spans of a 106 ms engine iteration and the seven of a 203 ms fit step do
-not show: tokens/s at the default and with ``TPUMX_TRACING=0`` read within
-0.4% of each other on two seeds of three and inside the cell's own 4%
-run-to-run spread on the third.
+per-decode-step scopes.  On the chip (PERF.md section 6, PR 49:
+``gpt2-large-decode-sat``, 32 rows, an 11.6 ms engine pass of which 4.4 are
+the host's, hidden under the device's step) the default reads 2,754.4
+tokens/s and ``TPUMX_TRACING=0`` 2,783.2 on one seed: 1.0%, at the edge of
+the cell's 0.8% run-to-run spread.  What it is made of: the emit phase
+(32 participation records a step) takes 0.55 ms a pass for 0.40, hidden;
+and the records the rings keep alive give the interpreter's collector one
+full collection of ~350 ms a 51 s window (``stats()["counts"]
+["gc_pause_us"]`` 379-402 ms, 31 with tracing off and no full collection
+at all), which is not hidden.  With ``mx.profiler`` running as well (the
+benchmark's traced slice) every span and record is also a profiler event:
+``serving.emit`` reads 1.13 ms a pass in the slice (0.85 with tracing off)
+and the traced run's window 2.0% fewer tokens than the untraced one's.
 
 Whether a span emits a *profiler* event is captured at entry (same rule as
 ``profiler.scope``): a span that started under a stopped profiler emits
@@ -269,13 +277,16 @@ class span:
 
 def record_event(name: str, cat: str, t0: float, t1: float,
                  ctx: Optional[TraceContext] = None,
-                 args: Optional[dict] = None) -> Optional[str]:
+                 args: Optional[dict] = None,
+                 traced: Optional[bool] = None) -> Optional[str]:
     """Record a completed interval ``[t0, t1]`` (perf_counter seconds) as a
     span of ``ctx``'s trace — the Orca-attribution primitive: a SHARED step
     (one decode program serving many requests) calls this once per
     participating request, so each trace shows its own participation slice
-    without the step running once per request.  Returns the span id."""
-    if not enabled():
+    without the step running once per request.  Returns the span id.
+    ``traced`` is what :func:`enabled` told a caller that asked it once
+    for the whole step: the fan-out then does not ask again a request."""
+    if not (enabled() if traced is None else traced):
         return None
     sid = _next_span_id()
     trace_id = parent_id = None

@@ -898,9 +898,27 @@ class GenerationService:
             self._counts["window_blocks_freed"] = 0
         self._peak_occupancy = 0.0
         # host microseconds of the loop by phase, from the phase spans' own
-        # clock reads (written by the engine thread only)
+        # clock reads (written by the engine thread only).  ``sync_wait``,
+        # the waits for the device, lies inside ``step``; ``lock_wait`` is
+        # the loop's wait for its own lock at the top of a pass
         self._phase_us = {"schedule": 0.0, "build": 0.0, "step": 0.0,
-                          "emit": 0.0, "idle_wait": 0.0}
+                          "emit": 0.0, "idle_wait": 0.0, "sync_wait": 0.0,
+                          "lock_wait": 0.0}
+        # wall microseconds of the passes, from ``serving.iteration``'s own
+        # clock reads, by what a pass was: one that dispatched a decode
+        # step while neither it nor the pass before it dispatched a prefill
+        # or fill chunk decoded alone (with a step in flight the step a
+        # pass waits for was queued behind the chunks of the pass that
+        # dispatched it: their device time lands a pass late); every other
+        # one is ``admitting``
+        self._iter_us = {"decode_only": 0.0, "admitting": 0.0}
+        self._iters_decode_only = 0
+        # prefill or fill chunks this pass dispatched, and whether the
+        # pass before dispatched any
+        self._pass_chunks = 0
+        self._chunks_before = False
+        # the collector's pauses while the loop runs (``serving.gc``)
+        self._gc = _obs.GcWatch("serving.gc")
         self._ttft: "deque[float]" = deque(maxlen=4096)
         self._itl: "deque[float]" = deque(maxlen=4096)
         self._token_times: "deque[float]" = deque(maxlen=8192)
@@ -1354,21 +1372,44 @@ class GenerationService:
                       _obs.span(name, cat="serving", args=args, ctx=ctx))
 
     def _loop(self) -> None:
-        while True:
-            with self._lock:
-                if self._killed:
-                    return  # crashed-replica simulation: vanish, no cleanup
-                if not self._closed and not self._waiting \
-                        and all(r is None for r in self._slots):
-                    # nothing queued, nothing running: not an iteration
-                    self._update_gauges_locked()
-                    with self._phase("idle_wait", "serving.idle_wait"):
-                        self._not_empty.wait(0.05)
-                    continue
-            with _obs.span("serving.iteration", cat="serving",
-                           args={"iteration": self._iteration}):
-                if not self._iterate():
-                    return
+        with self._gc:
+            while self._pass():
+                pass
+
+    def _pass(self) -> bool:
+        """One turn of the loop: the idle wait, or an iteration under its
+        span, whose wall time is counted by what the pass was
+        (``stats()["counts"]["iter_us_decode_only"]`` / ``_admitting``).
+        False ends the loop."""
+        if not self._lock.acquire(False):
+            # a client's submit or a reader of stats() holds it: the wait
+            # is the loop's, and would lie in no span
+            with self._phase("lock_wait", "serving.lock_wait"):
+                self._lock.acquire()
+        try:
+            if self._killed:
+                return False  # crashed-replica simulation: vanish, no cleanup
+            if not self._closed and not self._waiting \
+                    and all(r is None for r in self._slots):
+                # nothing queued, nothing running: not an iteration
+                self._update_gauges_locked()
+                with self._phase("idle_wait", "serving.idle_wait"):
+                    self._not_empty.wait(0.05)
+                return True
+        finally:
+            self._lock.release()
+        steps = self._counts["steps_ahead"] + self._counts["steps_drained"]
+        with _obs.span("serving.iteration", cat="serving",
+                       args={"iteration": self._iteration}) as it:
+            alive = self._iterate()
+        chunked, self._pass_chunks = self._pass_chunks > 0, 0
+        alone = not (chunked or self._chunks_before) and steps < \
+            self._counts["steps_ahead"] + self._counts["steps_drained"]
+        self._chunks_before = chunked
+        self._iter_us["decode_only" if alone else "admitting"] += \
+            it.duration_us
+        self._iters_decode_only += alone
+        return alive
 
     def _iterate(self) -> bool:
         """One pass of the loop with requests queued or running: schedule
@@ -2182,6 +2223,7 @@ class GenerationService:
                 # same seed/counter as the unchunked program) is emitted —
                 # intermediate chunks exist to fill the cache, and a model
                 # that ``fills_without_head`` runs them with no head at all
+                self._pass_chunks += 1
                 if self._fills and off + take < ctx:
                     self._programs.run_fill(
                         self._cache, tokens, positions,
@@ -2240,7 +2282,8 @@ class GenerationService:
                 with self._phase("step", "serving.prefill",
                                  args={"rid": r.rid, "first_token": True,
                                        "ahead": ahead}, ctx=r.trace):
-                    tok = _synced(token, of="prefill")
+                    tok = _synced(token, of="prefill",
+                                  waited=self._phase_us)
             except Exception as exc:  # noqa: BLE001 — the device's error
                 f = self._flight
                 # (the flight's dict and list, changed in place: a step
@@ -2406,7 +2449,7 @@ class GenerationService:
         if r.trace is not None:
             _trace.record_event(
                 "serving.decode.participate", "serving", t0, t1,
-                ctx=r.trace,
+                ctx=r.trace, traced=True,
                 args={"rid": r.rid,
                       "iteration": (self._iteration if iteration is None
                                     else iteration),
@@ -2478,14 +2521,13 @@ class GenerationService:
             reads.append((step, self._read(step)))
         return reads
 
-    @staticmethod
-    def _read(f: _Flight):
+    def _read(self, f: _Flight):
         """Wait for a step and read what the host needs of it: its
         tokens, and with a block pass's ``unmasked`` the experts it
         touched, in one go."""
         if f.block is None:
-            return _synced(f.tokens)
-        return _synced(f.tokens, f.block.touched)
+            return _synced(f.tokens, waited=self._phase_us)
+        return _synced(f.tokens, f.block.touched, waited=self._phase_us)
 
     def _count_aux(self, auxes) -> None:
         """Sum finished programs' counts into ``stats()["counts"]``: every
@@ -2617,8 +2659,8 @@ class GenerationService:
                          args={"running": len(batch), "width": b.width,
                                "chunk": int(tk),
                                "iteration": self._iteration}):
-            target, accepted = self._programs.run_verify(self._cache,
-                                                         *b.operands)
+            target, accepted = self._programs.run_verify(
+                self._cache, *b.operands, waited=self._phase_us)
         t_step1 = time.perf_counter()
         with self._phase("emit", "serving.emit"):
             traced = _trace.enabled()
@@ -2705,6 +2747,7 @@ class GenerationService:
                              args={"rid": r.rid, "len": ctx, "bucket": tb,
                                    "off": off, "chunks": len(plan),
                                    "resumed": resumed}, ctx=r.trace):
+                self._pass_chunks += 1
                 self._programs.run_fill(self._cache, tokens, positions,
                                         _np.asarray([take], _np.int32),
                                         table)
@@ -3082,13 +3125,25 @@ class GenerationService:
                 counts["state_bytes_per_slot"] = sum(
                     int(p.nbytes) for p in self._cache.pools[k.span]) \
                     // k.num_blocks
+        # the loop's clocks, whole microseconds each: ``phase_ms`` and the
+        # counts are two views of the same accumulators
+        phase_us = {k: int(v) for k, v in dict(self._phase_us).items()}
+        gc_us = int(self._gc.pause_us)
+        alone, admitting = (int(self._iter_us[k])
+                            for k in ("decode_only", "admitting"))
+        counts.update({f"phase_us_{k}": v for k, v in phase_us.items()},
+                      gc_pause_us=gc_us,
+                      gc_collections_gen2=self._gc.collections[2],
+                      iter_us=alone + admitting, iter_us_decode_only=alone,
+                      iter_us_admitting=admitting,
+                      iters_decode_only=self._iters_decode_only)
         pct = _smetrics.percentile
         return {
             "running": running,
             "waiting": waiting,
             "iterations": self._iteration,
-            "phase_ms": {k: round(v / 1e3, 3)
-                         for k, v in dict(self._phase_us).items()},
+            "phase_ms": {**{k: v / 1e3 for k, v in phase_us.items()},
+                         "gc": gc_us / 1e3},
             "counts": counts,
             "kv_blocks": {
                 "total": self._cache.num_blocks - 1,

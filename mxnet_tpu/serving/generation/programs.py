@@ -128,7 +128,7 @@ def _step_args(tokens, positions, lengths, block_tables, *sampler):
         _np.asarray(a, dt) for a, dt in zip(sampler, _SAMPLER_DTYPES))
 
 
-def _synced(*outs, of=None):
+def _synced(*outs, of=None, waited=None):
     """The step's sampled tokens as NumPy arrays: the read that waits for
     the device, under its own span so that the wait is not mistaken for
     host work (``serving.step.dispatch`` ends where the call returned).
@@ -136,10 +136,17 @@ def _synced(*outs, of=None):
     may make it after it has dispatched the next step.  ``of`` says in
     the span's arguments what is read where that is not a decode step's
     tokens (``"prefill"``: first tokens, read after the decode step they
-    fed was dispatched)."""
-    with _tracing.span("serving.step.sync", cat="serving",
-                       args={"of": of} if of else None):
-        arrays = tuple(_np.asarray(o) for o in outs)
+    fed was dispatched).  ``waited``, the caller's microseconds by phase,
+    is handed the span's own duration under ``sync_wait`` (the engine's
+    ``stats()["phase_ms"]``), whether or not the read raised."""
+    sync = _tracing.span("serving.step.sync", cat="serving",
+                         args={"of": of} if of else None)
+    try:
+        with sync:
+            arrays = tuple(_np.asarray(o) for o in outs)
+    finally:
+        if waited is not None:
+            waited["sync_wait"] += sync.duration_us
     return arrays[0] if len(arrays) == 1 else arrays
 
 
@@ -615,16 +622,17 @@ class GenerationPrograms:
         return self._place_jit(prev, first, _np.asarray([slot], _np.int32))
 
     def run_verify(self, cache, tokens, positions, lengths, block_tables,
-                   seeds, counters, temperature, top_k, top_p):
+                   seeds, counters, temperature, top_k, top_p, waited=None):
         """One speculative verify step: ``tokens`` (S, Tk) holds
         ``[pending, d_1..d_s]`` per row (right-padded; ``lengths`` counts
         the valid columns).  Returns ``(target np(S, Tk), accepted
         np(S,))`` — see :func:`~mxnet_tpu.ops.sampling.speculative_verify`
         for the emit contract.  Site ``gen_verify``; keys share the
-        :meth:`run` namespace so warmup enumerates the (Tk, W) ladder."""
+        :meth:`run` namespace so warmup enumerates the (Tk, W) ladder.
+        ``waited`` as :func:`_synced` takes it: the step is read here."""
         return _synced(*self._run("gen_verify", cache, _step_args(
             tokens, positions, lengths, block_tables, seeds, counters,
-            temperature, top_k, top_p)))
+            temperature, top_k, top_p)), waited=waited)
 
     def run_fill(self, cache, tokens, positions, lengths, block_tables):
         """A chunk that fills the cache and returns nothing to read: a

@@ -668,10 +668,10 @@ def test_next_pass_is_dispatched_before_the_last_one_is_read(params,
     log, passes, kept = [], {}, []
     synced = engine_mod._synced
 
-    def reading(*outs):
+    def reading(*outs, **kw):
         if id(outs[0]) in passes:
             log.append(("read", passes[id(outs[0])]))
-        return synced(*outs)
+        return synced(*outs, **kw)
 
     monkeypatch.setattr(engine_mod, "_synced", reading)
     svc = _service(params)
@@ -858,14 +858,14 @@ def test_failure_with_a_pass_in_flight_costs_no_token(params, monkeypatch,
     synced, reads, raised = engine_mod._synced, [0], []
     times = {"step": 0, "read": 1, "read_twice": 2}[fault]
 
-    def failing(*outs):
+    def failing(*outs, **kw):
         f = svc._flight
         if f is not None and outs[0] is f.tokens:
             reads[0] += 1
             if reads[0] >= 4 and len(raised) < times:
                 raised.append(reads[0])
                 raise RuntimeError("injected read failure")
-        return synced(*outs)
+        return synced(*outs, **kw)
 
     monkeypatch.setattr(engine_mod, "_synced", failing)
     rng = np.random.default_rng(51)

@@ -856,7 +856,8 @@ def test_engine_iteration_spans_children_and_phase_ms(params):
 
 def test_phase_ms_accounts_for_the_iterations(params):
     """stats()["phase_ms"] is fed by the phase spans' own clock reads:
-    its busy parts (everything but the idle wait) add up to the wall time
+    its busy parts (everything but the waits outside a pass, and the wait
+    for the device, which lies inside ``step``) add up to the wall time
     of the iterations, read from the span ring, within 5%."""
     # wide enough that a step outweighs the loop's unnamed glue (list
     # building, span bookkeeping: some tens of microseconds a pass)
@@ -866,7 +867,8 @@ def test_phase_ms_accounts_for_the_iterations(params):
     svc = GenerationService(big, cfg, _gc(), start=False)
     svc.warmup()
     before = svc.stats()["phase_ms"]
-    assert set(before) == {"schedule", "build", "step", "emit", "idle_wait"}
+    assert set(before) == {"schedule", "build", "step", "emit", "idle_wait",
+                           "sync_wait", "lock_wait", "gc"}
     tracing.clear()
     svc.start()
     try:
@@ -889,12 +891,18 @@ def test_phase_ms_accounts_for_the_iterations(params):
                 "serving.decode.build": "build",
                 "serving.prefill.build": "build",
                 "serving.prefill": "step", "serving.decode": "step",
-                "serving.idle_wait": "idle_wait"}
+                "serving.idle_wait": "idle_wait",
+                "serving.step.sync": "sync_wait",
+                "serving.lock_wait": "lock_wait"}
     ring = [s for s in tracing.recent_spans() if s["name"] in phase_of]
-    for k in before:
+    # (the collector's pauses are no spans of the ring: GcWatch)
+    for k in set(before) - {"gc"}:
         spans_ms = sum(s["dur_us"] for s in ring
                        if phase_of[s["name"]] == k) / 1e3
         assert after[k] - before[k] == pytest.approx(spans_ms, abs=0.01), k
+    # a pass holds neither the idle wait nor the wait for the lock, and
+    # its waits for the device are part of its steps
+    outside = ("idle_wait", "lock_wait", "sync_wait")
     # and the busy parts cover an iteration: never more than its wall
     # time, and in the median iteration within 5% of it (one pass that the
     # scheduler of a loaded test host interrupts must not decide this)
@@ -902,7 +910,7 @@ def test_phase_ms_accounts_for_the_iterations(params):
     for it in tracing.recent_spans(name="serving.iteration"):
         t0, t1 = it["ts_us"], it["ts_us"] + it["dur_us"]
         parts = sum(s["dur_us"] for s in ring if t0 <= s["ts_us"] < t1
-                    and phase_of[s["name"]] != "idle_wait")
+                    and phase_of[s["name"]] not in outside)
         assert parts <= it["dur_us"] + 1.0
         shares.append(parts / it["dur_us"])
     assert len(shares) >= 24 and np.median(shares) >= 0.95, shares
@@ -1028,3 +1036,296 @@ def test_profiler_starts_jax_trace_without_python_frames(tmp_path,
     finally:
         profiler.set_config()
     assert seen == [(str(tmp_path), 0), (str(tmp_path), 1)]
+
+
+# -- the loop's whole window from inside (docs/observability.md section 2) ----------
+def _until(cond, timeout=30.0):
+    t_end = time.perf_counter() + timeout
+    while not cond():
+        assert time.perf_counter() < t_end, "timed out"
+        time.sleep(0.005)
+
+
+def _clock_counts(stats):
+    """The loop's clocks among ``stats()["counts"]``."""
+    return {k: v for k, v in stats["counts"].items()
+            if k.startswith(("phase_us_", "iter_us", "iters_", "gc_"))}
+
+
+def test_a_collection_is_a_span_and_a_counter_and_the_hook_goes(params):
+    """While a started service's profiler runs, a forced collection is ONE
+    ``serving.gc`` record (two services watch under one name), the
+    services' ``gc`` clocks grow by its duration, and the process's one
+    hook leaves ``gc.callbacks`` with the last of them."""
+    import gc
+
+    from mxnet_tpu.observability import gc_watch
+
+    watching = gc_watch.watchers()
+    a, b = (GenerationService(params, CFG, _gc(), start=False)
+            for _ in range(2))
+    assert _clock_counts(a.stats())["gc_pause_us"] == 0
+    was_enabled = gc.isenabled()
+
+    def run():
+        a.start()
+        b.start()
+        try:
+            _until(lambda: gc_watch.watchers() == watching + 2)
+            assert gc.callbacks.count(gc_watch._hook) == 1
+            gc.disable()                 # the forced one is the only one
+            before = [s.stats() for s in (a, b)]
+            gc.collect()
+            after = [s.stats() for s in (a, b)]
+            a.stop()
+            assert gc_watch.watchers() == watching + 1
+            assert gc_watch._hook in gc.callbacks
+            gc.collect()                 # a's clock has stopped, b's has not
+            late = [s.stats() for s in (a, b)]
+        finally:
+            if was_enabled:
+                gc.enable()
+            a.stop()
+            b.stop()
+        return before, after, late
+
+    (before, after, late), events = _profiled(run)
+    assert gc_watch.watchers() == watching
+    assert (gc_watch._hook in gc.callbacks) == (watching > 0)
+    first, second = [e for e in events if e["name"] == "serving.gc"]
+    assert first["args"]["generation"] == second["args"]["generation"] == 2
+    assert first["args"]["collected"] >= 0 and first["cat"] == "serving"
+    assert first["tid"] == threading.get_ident()
+    # the hook stays out of the span ring
+    assert tracing.recent_spans(name="serving.gc") == []
+    for was, now in zip(before, after):
+        grew = now["counts"]["gc_pause_us"] - was["counts"]["gc_pause_us"]
+        assert grew == pytest.approx(first["dur"], abs=1.5) and grew > 0
+        assert now["phase_ms"]["gc"] == now["counts"]["gc_pause_us"] / 1e3
+        assert now["counts"]["gc_collections_gen2"] \
+            - was["counts"]["gc_collections_gen2"] == 1
+    assert _clock_counts(late[0])["gc_pause_us"] \
+        == _clock_counts(after[0])["gc_pause_us"]
+    assert late[1]["counts"]["gc_pause_us"] \
+        - after[1]["counts"]["gc_pause_us"] \
+        == pytest.approx(second["dur"], abs=1.5)
+
+
+@pytest.mark.parametrize("under", ["span_ring", "profiler"])
+def test_a_collection_under_a_tracing_lock_does_not_hang(under, monkeypatch):
+    """A collection can start on the thread that holds the span ring's
+    lock or the profiler's, inside the append: the hook takes neither."""
+    import gc
+    from collections import deque
+
+    from mxnet_tpu.observability import GcWatch
+
+    collected = []
+
+    def collecting(base):
+        class Collecting(base):
+            def append(self, record):
+                if not collected:
+                    collected.append(gc.collect())
+                base.append(self, record)
+        return Collecting
+
+    if under == "span_ring":
+        monkeypatch.setattr(tracing, "_SPAN_RING",
+                            collecting(deque)(maxlen=16))
+    else:
+        monkeypatch.setattr(profiler, "_events", collecting(list)())
+
+    def spanning():
+        with GcWatch("serving.gc"), obs.span("serving.emit", cat="serving"):
+            pass
+
+    profiler.set_state("run")
+    try:
+        t = threading.Thread(target=spanning, daemon=True)
+        t.start()
+        t.join(20)
+        assert not t.is_alive(), "the hook waited for a lock its thread holds"
+    finally:
+        profiler.set_state("stop")
+    assert collected
+    names = [e["name"] for e in profiler._events]
+    assert "serving.gc" in names and "serving.emit" in names
+
+
+def test_a_held_lock_is_a_lock_wait_span_and_an_uncontended_pass_is_none(
+        params):
+    svc = GenerationService(params, CFG, _gc(), start=False)
+    svc.warmup()
+    tracing.clear()
+    h = svc.submit(np.arange(6) % CFG.vocab, max_new_tokens=6)
+    held, hold_s = threading.Event(), 0.03
+
+    def holder():
+        with svc._lock:
+            held.set()
+            time.sleep(hold_s)
+
+    t = threading.Thread(target=holder, daemon=True)
+    t.start()
+    assert held.wait(10)
+    t0 = time.perf_counter()
+    assert svc._pass()                    # the loop's turn, by hand
+    waited_s = time.perf_counter() - t0
+    t.join(10)
+    assert not t.is_alive()
+    while not h.finished:
+        assert svc._pass()
+    (wait,) = tracing.recent_spans(name="serving.lock_wait")
+    # from the call to the holder's release: what is left of its 30 ms
+    assert 0.5 * hold_s * 1e6 <= wait["dur_us"] <= waited_s * 1e6
+    stats = svc.stats()
+    assert stats["counts"]["phase_us_lock_wait"] == int(wait["dur_us"])
+    assert stats["phase_ms"]["lock_wait"] \
+        == stats["counts"]["phase_us_lock_wait"] / 1e3
+    # the wait is the loop's own and no part of a pass
+    first = tracing.recent_spans(name="serving.iteration")[0]
+    assert wait["ts_us"] + wait["dur_us"] <= first["ts_us"]
+    assert len(tracing.recent_spans(name="serving.iteration")) >= 6
+
+
+def test_sync_wait_is_the_sync_spans_and_lies_inside_step(params):
+    svc = GenerationService(params, CFG, _gc(), start=False)
+    svc.warmup()                          # its reads are nobody's wait
+    tracing.clear()
+    assert svc.stats()["counts"]["phase_us_sync_wait"] == 0
+    svc.start()
+    try:
+        rs = np.random.RandomState(11)
+        for h in [svc.submit(rs.randint(0, CFG.vocab, n), max_new_tokens=10)
+                  for n in (5, 12, 20, 7)]:
+            h.result(120)
+    finally:
+        svc.stop()
+    stats = svc.stats()
+    syncs = tracing.recent_spans(name="serving.step.sync")
+    assert {s["args"].get("of") for s in syncs} == {None, "prefill"}
+    assert stats["counts"]["phase_us_sync_wait"] \
+        == pytest.approx(sum(s["dur_us"] for s in syncs), abs=1.0)
+    assert 0 < stats["counts"]["phase_us_sync_wait"] \
+        <= stats["counts"]["phase_us_step"]
+
+
+@pytest.mark.speculative
+def test_sync_wait_counts_a_verify_steps_read(params):
+    svc = GenerationService(params, CFG, _gc(speculative=True, draft_k=2),
+                            start=False)
+    svc.warmup()
+    tracing.clear()
+    svc.start()
+    try:
+        svc.generate(np.asarray([3, 7, 3, 7, 3, 7, 3]), max_new_tokens=10,
+                     timeout=120)
+    finally:
+        svc.stop()
+    stats = svc.stats()
+    assert stats["speculative"]["spec_steps"] > 0
+    syncs = tracing.recent_spans(name="serving.step.sync")
+    assert stats["counts"]["phase_us_sync_wait"] \
+        == pytest.approx(sum(s["dur_us"] for s in syncs), abs=1.0)
+
+
+def test_a_pass_says_whether_it_decoded_alone(params):
+    """One long prompt behind two decoding rows: the pass that prefills
+    AND the pass after it (whose step was queued behind the chunks) are
+    admitting; the others decoded alone."""
+    svc = GenerationService(params, CFG, _gc(max_slots=3), start=False)
+    svc.warmup()
+    tracing.clear()
+    rs = np.random.RandomState(2)
+    hs = [svc.submit(rs.randint(0, CFG.vocab, n), max_new_tokens=12)
+          for n in (4, 6)]
+    alone = []
+
+    def turn():
+        was = svc.stats()["counts"]["iters_decode_only"]
+        assert svc._pass()
+        alone.append(svc.stats()["counts"]["iters_decode_only"] - was)
+
+    for _ in range(4):
+        turn()
+    hs.append(svc.submit(rs.randint(0, CFG.vocab, 30), max_new_tokens=4))
+    for _ in range(3):
+        turn()
+    #                admits, after, alone x 2, admits, after, alone
+    assert alone == [0, 0, 1, 1, 0, 0, 1]
+    while not all(h.finished for h in hs):
+        assert svc._pass()
+    c = svc.stats()["counts"]
+    its = tracing.recent_spans(name="serving.iteration")
+    assert c["iter_us_decode_only"] + c["iter_us_admitting"] == c["iter_us"]
+    assert c["iter_us"] == pytest.approx(sum(s["dur_us"] for s in its),
+                                         abs=2.0)
+    assert 0 < c["iters_decode_only"] <= len(its) - 4
+    # a pass that decodes nothing (it lands the last step) is not alone
+    assert c["iter_us_decode_only"] < c["iter_us"]
+
+
+def test_the_loops_clocks_are_whole_microseconds_that_never_fall(params):
+    svc = GenerationService(params, CFG, _gc(), start=False)
+    new = {"phase_us_schedule", "phase_us_build", "phase_us_step",
+           "phase_us_emit", "phase_us_idle_wait", "phase_us_sync_wait",
+           "phase_us_lock_wait", "gc_pause_us", "gc_collections_gen2",
+           "iter_us", "iter_us_decode_only", "iters_decode_only",
+           "iter_us_admitting"}
+    # there from the construction, at 0: the benchmark's drivers subtract
+    # the counts at a window's open from those at its close key by key
+    born = _clock_counts(svc.stats())
+    assert set(born) == new and not any(born.values())
+    svc.warmup()
+    svc.start()
+    reads = [svc.stats()]
+    try:
+        rs = np.random.RandomState(4)
+        hs = [svc.submit(rs.randint(0, CFG.vocab, n), max_new_tokens=12)
+              for n in (5, 9, 14, 6)]
+        while not all(h.finished for h in hs):
+            reads.append(svc.stats())
+            time.sleep(0.002)
+        time.sleep(0.08)
+    finally:
+        svc.stop()
+    reads.append(svc.stats())
+    for was, now in zip(reads, reads[1:]):
+        a, b = _clock_counts(was), _clock_counts(now)
+        assert all(type(v) is int for v in b.values()), b
+        assert all(b[k] >= a[k] for k in new), (a, b)
+        for k, ms in now["phase_ms"].items():
+            assert ms == b["gc_pause_us" if k == "gc"
+                           else "phase_us_" + k] / 1e3
+        assert b["iter_us"] == b["iter_us_decode_only"] \
+            + b["iter_us_admitting"]
+    last = _clock_counts(reads[-1])
+    assert last["iters_decode_only"] > 0 and last["phase_us_idle_wait"] > 0
+
+
+def test_a_collection_under_fit_is_a_fit_gc_span():
+    import gc
+
+    from mxnet_tpu.observability import gc_watch
+
+    watching = gc_watch.watchers()
+    seen = []
+
+    def on_batch(param):
+        seen.append(gc_watch.watchers())
+        gc.collect()
+
+    n = 3
+    _, events = _profiled(lambda: _tiny_fit(n, callback=on_batch))
+    assert seen == [watching + 1] * n and gc_watch.watchers() == watching
+    forced = [e for e in events if e["name"] == "fit.gc"
+              and e["args"]["generation"] == 2]
+    assert len(forced) >= n and all(e["cat"] == "fit" for e in forced)
+    # each lies inside the callbacks span that set it off
+    callbacks = [(e["ts"], e["ts"] + e["dur"]) for e in events
+                 if e["name"] == "fit.callbacks"]
+    inside = [e for e in forced
+              if any(t0 <= e["ts"] and e["ts"] + e["dur"] <= t1
+                     for t0, t1 in callbacks)]
+    assert len(inside) >= n
