@@ -493,10 +493,18 @@ class HybridMoeLM:
     max_len: int
     experts_held: Optional[Tuple[int, int]] = None
     kv_dtype: object = jnp.bfloat16
-    # the longest chunk a prefill program takes: its temporaries (the
-    # queries, the assignments' gathered rows) grow with the chunk, and so
-    # does what a row of the window kind owns while it is prefilled
-    longest_chunk: int = 512
+    # the longest chunk a prefill program takes on the chip.  A prompt is
+    # cheapest in the longest chunks: the held experts' products are bound
+    # by their weights' bytes (a `_gmm_call` launch reads 0.383 ms at 512
+    # rows and 0.407 at 1,024 on `mimo-v2.5`).  What grows with the chunk
+    # is the program's temporaries (the queries, the assignments' gathered
+    # rows, the logits of every position: 0.83 GB at 1,024 over
+    # `trinity-mini`'s vocabulary), the window kind's pool and its chunk
+    # call's ring.  512 -> 1,024 read +10.2% tokens on `mimo-v2.5`; 2,048
+    # read +1.4% / +1.2% more on the two cells and left `trinity-mini`
+    # 0.56 GB of the chip's memory (15.19 GB): not worth a cell that can
+    # fail (PERF.md, PR 50)
+    longest_chunk: int = 1024
     block_len = 0
     offers = frozenset({"sampling"})
     # the tiles body fetches the pages a tile reads and no others, for a

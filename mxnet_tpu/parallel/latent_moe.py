@@ -391,11 +391,16 @@ class LatentMoeLM:
     max_len: int
     experts_held: Optional[Tuple[int, int]] = None
     kv_dtype: object = jnp.bfloat16
-    # the longest chunk a prefill program takes: its temporaries (the
-    # absorbed queries, the assignments' gathered rows) grow with the
-    # chunk, 0.43 GB at 512 tokens and the published widths; a seq bucket
-    # above it only says how long a prompt may be
-    longest_chunk: int = 512
+    # the longest chunk a prefill program takes on the chip: the held
+    # experts' products cost a chunk the same at 512 tokens as at 1,024
+    # (bound by their weights' bytes), so a long prompt is cheapest in
+    # long chunks; its temporaries (the absorbed queries, the assignments'
+    # gathered rows) grow with the chunk, `dots-vlm1`'s peak 13.73 ->
+    # 14.03 GB at 1,024.  2,048 would hold FEWER tokens a call on prompts
+    # of a median 1,024 (563 for 593: half of them never fill one) at
+    # twice the temporaries again (PERF.md, PR 50).  A seq bucket above it
+    # only says how long a prompt may be
+    longest_chunk: int = 1024
     block_len = 0
     offers = frozenset({"sampling"})
     # the latent kernel fetches live pages only, for a chunk as for one

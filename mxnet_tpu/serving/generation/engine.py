@@ -468,11 +468,13 @@ class _First(NamedTuple):
     "the step in flight"): the request, the ``(1,)`` token as the last
     chunk's program handed it back, the counts (``aux``) of the programs
     dispatched up to that chunk that nobody has read yet, and the tokens
-    the chunks fed, which are counted where the programs' counts are."""
+    the chunks fed and how many chunks they were, which are counted where
+    the programs' counts are."""
     req: _GenRequest
     token: object
     aux: tuple = ()
     prefill_tokens: int = 0
+    prefill_chunks: int = 0
 
 
 class _LandFirst(Exception):
@@ -695,12 +697,15 @@ class GenerationService:
         # the ladder's top is the longest prompt the service takes; the
         # rungs a prompt is cut into stop at the longest chunk the model's
         # prefill program takes, where it names one (its temporaries grow
-        # with the chunk) and prompts are chunked at all
+        # with the chunk) and prompts are chunked at all.  That chunk is
+        # itself the top rung where a prompt can be as long: a long prompt
+        # then walks in the model's chunks, whatever rungs were configured
         self._prompt_buckets = self._seq_buckets
         cap = getattr(model, "longest_chunk", None)
         if cap and cfg.chunked_prefill:
-            self._seq_buckets = [b for b in self._seq_buckets if b <= cap] \
-                or self._seq_buckets[:1]
+            self._seq_buckets = sorted(
+                {b for b in self._seq_buckets if b < cap}
+                | {min(cap, self._seq_buckets[-1])})
         # the cache is what the model's spec says: the classic K/V pair
         # of folded heads, or the pools it names (latent attention: one)
         spec = model.cache_spec()
@@ -857,7 +862,10 @@ class GenerationService:
                         "requeued": 0, "quarantined": 0, "step_failures": 0,
                         "prefix_hits": 0, "prefix_misses": 0,
                         "prefix_evictions": 0, "cached_tokens": 0,
-                        "prefill_tokens": 0, "cow_copies": 0,
+                        # positions the prefill and fill calls fed, and
+                        # those calls
+                        "prefill_tokens": 0, "prefill_chunks": 0,
+                        "cow_copies": 0,
                         "draft_proposed": 0, "draft_accepted": 0,
                         "spec_steps": 0,
                         # decode steps dispatched before the last one's
@@ -2039,29 +2047,24 @@ class GenerationService:
         re-bucketed onto the SAME (T, W) ladder, which is why a cache hit
         mints no new program shapes.
         """
-        cfg = self._config
         rungs = self._seq_buckets
         if start > 0:
-            chunks = []
-            off = start
-            while off < prompt_len:
-                rem = prompt_len - off
-                fitting = [b for b in rungs if b <= rem]
-                tb = fitting[-1] if fitting else rungs[0]
-                take = min(rem, tb)
-                w = bucket_batch(self._cache.blocks_for(off + tb),
-                                 self._width_buckets)
-                chunks.append((off, take, tb, w))
-                off += take
-            return chunks
-        chunked = cfg.chunked_prefill or force_chunked
-        if not chunked or prompt_len <= rungs[0]:
+            return self._walk(start, prompt_len)
+        chunked = self._config.chunked_prefill or force_chunked
+        chunks = self._walk(0, prompt_len) \
+            if chunked and prompt_len > rungs[0] else ()
+        if len(chunks) <= 1:  # no walk, or exactly one rung: the legacy plan
             tb = bucket_seq_len(prompt_len, rungs)
             return [(0, prompt_len, tb, self._rung_width(tb))]
-        chunks = []
-        off = 0
-        while off < prompt_len:
-            rem = prompt_len - off
+        return chunks
+
+    def _walk(self, off: int, end: int):
+        """The greedy walk of positions ``[off, end)`` down the rungs: the
+        longest rung that fits what is left, the shortest for a last
+        leftover under every rung."""
+        rungs, chunks = self._seq_buckets, []
+        while off < end:
+            rem = end - off
             fitting = [b for b in rungs if b <= rem]
             tb = fitting[-1] if fitting else rungs[0]
             take = min(rem, tb)
@@ -2069,9 +2072,6 @@ class GenerationService:
                              self._width_buckets)
             chunks.append((off, take, tb, w))
             off += take
-        if len(chunks) == 1:  # exactly one rung: identical to legacy
-            tb = bucket_seq_len(prompt_len, rungs)
-            return [(0, prompt_len, tb, self._rung_width(tb))]
         return chunks
 
     def _rung_width(self, tb: int) -> int:
@@ -2244,6 +2244,7 @@ class GenerationService:
         fed = sum(p[1] for p in plan)
         if resumed:
             self._counts["prefill_tokens"] += fed
+            self._counts["prefill_chunks"] += len(plan)
             r.seg("decode", time.perf_counter())
             return
         # nothing of the prefill is read here: the context stands at the
@@ -2253,7 +2254,7 @@ class GenerationService:
         # nothing may stay in flight
         r.ctx_len = r.prompt_len
         self._firsts[r.rid] = _First(r, next_tok, self._programs.take_aux(),
-                                     fed)
+                                     fed, len(plan))
         if not self._runs_ahead:
             self._land_firsts()
 
@@ -2277,7 +2278,7 @@ class GenerationService:
             return
         firsts, self._firsts = self._firsts, {}
         which = "prefills_ahead" if ahead else "prefills_read"
-        for r, token, aux, fed in firsts.values():
+        for r, token, aux, fed, chunks in firsts.values():
             try:
                 with self._phase("step", "serving.prefill",
                                  args={"rid": r.rid, "first_token": True,
@@ -2302,6 +2303,7 @@ class GenerationService:
                 # agreeing)
                 self._count_aux(aux)
                 self._counts["prefill_tokens"] += fed
+                self._counts["prefill_chunks"] += chunks
                 if self._prefix is not None:
                     self._prefix.insert(r.seq_tokens[:r.ctx_len], r.blocks)
                 r.seg("decode", time.perf_counter())
@@ -2873,6 +2875,7 @@ class GenerationService:
                 int(f.step.positions[:, -1].sum()) + len(rows)
             counts["block_experts_touched"] += int(touched)
             counts["block_prefill_chunks"] += f.block.prefill[0]
+            counts["prefill_chunks"] += f.block.prefill[0]
             counts["prefill_tokens"] += f.block.prefill[1]
 
     def _commit_block(self, r: _GenRequest) -> None:

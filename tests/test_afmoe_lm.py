@@ -89,6 +89,20 @@ def svc8(params):
     svc.stop(drain=False, timeout=30)
 
 
+# a chunk no configured rung names, between 16 and the ladder's top: the
+# model's own longest chunk is then the top rung of every walk
+LONG = 32
+
+
+@pytest.fixture(scope="module")
+def svc8_long(params):
+    """``svc8`` with a model whose prefill program takes chunks of 32."""
+    svc = _service(params, model=_model(longest_chunk=LONG))
+    assert svc._seq_buckets == [8, 16, LONG]
+    yield svc
+    svc.stop(drain=False, timeout=30)
+
+
 def _ref_logits(params, tokens, at0, n_at=1, c=C, **kw):
     toks = np.zeros(MAX_LEN, np.int32)
     toks[:len(tokens)] = tokens
@@ -102,16 +116,25 @@ def _ref_greedy(params, prompt, n, c=C):
 
 
 @pytest.mark.parametrize("part", ["prefill", "decode"])
-@pytest.mark.parametrize("plen", [3, 5, 16, 37, 70])
-def test_chunked_prefill_then_decode_match_reference_logits(svc8, params,
-                                                            plen, part):
-    """Prefill through the chunk plan (every leftover length; past 8 + 16
-    positions window blocks have been freed and reused), then greedy decode
+@pytest.mark.parametrize("chunk,plen", [
+    (16, 3), (16, 5), (16, 16), (16, 37), (16, 70),
+    (LONG, 32), (LONG, 37), (LONG, 70), (LONG, 100)])
+def test_chunked_prefill_then_decode_match_reference_logits(request, params,
+                                                            chunk, plen,
+                                                            part):
+    """Prefill through the chunk plan (every leftover length; past the
+    window and a chunk, 8 + 16 positions, window blocks have been freed
+    and reused), then greedy decode
     steps through both cache kinds with the other slots idle, against the
     reference's full forward over the whole sequence.  The prompts of 3
     and 5 cross the window of 8 WHILE THEY DECODE (positions 3..10 and
     5..12): under the second the first block slides out at a decode
-    step."""
+    step.  At the model's longer chunk (32: one chunk, one and a leftover,
+    two and a leftover past window + chunk, three) the same comparison
+    holds within the same tolerance."""
+    svc8 = request.getfixturevalue("svc8" if chunk == 16 else "svc8_long")
+    if plen >= chunk:
+        assert svc8._chunk_plan(plen)[0][:3] == (0, chunk, chunk)
     freed = svc8.stats()["counts"]["window_blocks_freed"]
     seq = [int(t) for t in np.random.default_rng(plen).integers(0, V, plen)]
     if part == "prefill":
@@ -122,7 +145,7 @@ def test_chunked_prefill_then_decode_match_reference_logits(svc8, params,
         np.testing.assert_allclose(
             last, _ref_logits(params, toks, len(toks) - 1)[0], atol=TOL,
             rtol=0)
-    if plen > WIN + 16 or (part == "decode" and plen == 5):
+    if plen > WIN + chunk or (part == "decode" and plen == 5):
         assert svc8.stats()["counts"]["window_blocks_freed"] > freed
 
 
@@ -265,7 +288,7 @@ def test_the_cut_is_the_arithmetic_of_the_configuration_file():
     assert window["pools"] == full["pools"]
     model = hm.HybridMoeLM(cfg, max_len=config["max_len"],
                            experts_held=(lo, hi))
-    assert model.offers == {"sampling"} and model.longest_chunk == 512
+    assert model.offers == {"sampling"} and model.longest_chunk == 1024
     assert model.one_table_width and model.vocab == 200192
     assert model.counters == hm.COUNTERS + hm.AFMOE_COUNTERS + (
         "expert_trips", "expert_trips_extra")
@@ -285,13 +308,17 @@ def test_cache_is_built_from_the_models_kinds(svc8):
         [(6, 24, BS, 32), (6, 24, BS, 32)]
 
 
-@pytest.mark.parametrize("plen,n_new", [(3, 12), (16, 8), (23, 13), (70, 30)])
-def test_service_generation_matches_reference_greedy(params, svc8, plen,
-                                                     n_new):
+@pytest.mark.parametrize("chunk,plen,n_new", [
+    (16, 3, 12), (16, 16, 8), (16, 23, 13), (16, 70, 30),
+    (LONG, 37, 6), (LONG, 70, 30), (LONG, 100, 12)])
+def test_service_generation_matches_reference_greedy(request, params, chunk,
+                                                     plen, n_new):
     """Whole generations through submit / the scheduler / the step in
     flight / both cache kinds, token for token (float32 on both sides; the
     seeds give no tie).  The prompt of 3 crosses the window at its sixth
-    decode step."""
+    decode step.  Cut at the model's longer chunk (a wider ring, a window
+    pool for the longer chunk) the tokens are the same reference's."""
+    svc8 = request.getfixturevalue("svc8" if chunk == 16 else "svc8_long")
     svc8.start()
     prompt = np.random.default_rng(100 + plen).integers(0, V, plen)
     assert svc8.generate(prompt, max_new_tokens=n_new, timeout=300) \

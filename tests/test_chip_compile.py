@@ -661,8 +661,8 @@ def _latent_cell(sds, n_layers, num_blocks=4096):
     return model, params, sds((n_layers, num_blocks, BS, lanes), jnp.bfloat16)
 
 
-@pytest.mark.parametrize("B,T", [(256, 1), (1, 512)],
-                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("B,T", [(256, 1), (1, 512), (1, 1024)],
+                         ids=["decode", "prefill", "prefill1024"])
 def test_latent_kernel_compiles_at_the_cells_shapes(one_chip, B, T):
     """The latent body on the whole layered pool at the cell's one table
     width (a 512 KB table in scalar memory): 128 query rows a decode tile,
@@ -676,7 +676,8 @@ def test_latent_kernel_compiles_at_the_cells_shapes(one_chip, B, T):
         sds((B, 512), jnp.int32), sds((B,), jnp.int32), sds((1,), jnp.int32),
         sds((B, T, 128, 576), jnp.float32), sds((B, T), jnp.int32),
         sds((5, 4096, BS, 640), jnp.bfloat16))
-    name = "_mla_call_w512_decode" if T == 1 else "_mla_call_w512_t512_prefill"
+    name = "_mla_call_w512_decode" if T == 1 \
+        else f"_mla_call_w512_t{T}_prefill"
     assert "tpu_custom_call" in text and name in text
 
 
@@ -712,11 +713,12 @@ def test_tiled_grouped_matmul_compiles_at_the_cells_shapes(one_chip, M, K, N):
     assert "tpu_custom_call" in text and "_gmm_call" in text
 
 
-@pytest.mark.parametrize("S,T", [(256, 1), (1, 512)],
-                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("S,T", [(256, 1), (1, 512), (1, 1024)],
+                         ids=["decode", "prefill", "prefill1024"])
 def test_latent_decode_step_program_compiles_at_the_cells_shapes(
         one_chip, monkeypatch, S, T):
-    """``gen_decode`` (256 rows) and ``gen_prefill`` (a 512-token chunk) as
+    """``gen_decode`` (256 rows) and ``gen_prefill`` (a 512-token chunk and
+    the model's longest, 1,024) as
     the service dispatches them (one width), at depth 2 (one dense and one
     expert layer: layers repeat): the latent pool is updated in place, the
     kernels are there, the counts come back, and ``wqb`` reaches its
@@ -737,7 +739,7 @@ def test_latent_decode_step_program_compiles_at_the_cells_shapes(
         sds((S,), jnp.float32)).compile()
     text = compiled.as_text()
     assert text.count("_mla_call_w512_decode" if T == 1
-                      else "_mla_call_w512_t512_prefill") >= 2
+                      else f"_mla_call_w512_t{T}_prefill") >= 2
     # 16 of the router's 256 experts held: the expert layer walks the held
     # rows in tiles of 2 x 256 x 8 / 16 = 256 (512 a chunk), ONE copy of
     # the kernel's call a projection inside the loop, none over all 2,048
@@ -762,7 +764,8 @@ def test_latent_decode_step_program_compiles_at_the_cells_shapes(
               for ln in text.splitlines() if whole in ln}
     assert makers <= _IN_PLACE
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 0.5e9
+    print(f"latent S={S} T={T} temp {mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < (1.0e9 if T == 1024 else 0.5e9)
 
 
 def _the_walk_is_named_and_copies_no_expert(text, d, f):
@@ -799,10 +802,14 @@ _TILES = dict(full=(4, 16, 192, 128, BS, 0, False, 1024),
 @pytest.mark.parametrize("kind,B,T", [
     ("full", 128, 1), ("window", 128, 1), ("full", 1, 512),
     ("window", 1, 512), ("swa", 128, 1), ("afm_full", 64, 1),
-    ("afm_window", 64, 1), ("afm_full", 1, 512), ("afm_window", 1, 512)],
+    ("afm_window", 64, 1), ("afm_full", 1, 512), ("afm_window", 1, 512),
+    ("full", 1, 1024), ("window", 1, 1024), ("afm_full", 1, 1024),
+    ("afm_window", 1, 1024)],
     ids=["full-decode", "window-decode", "full-prefill", "window-prefill",
          "swa-decode", "afm-full-decode", "afm-window-decode",
-         "afm-full-prefill", "afm-window-prefill"])
+         "afm-full-prefill", "afm-window-prefill", "full-prefill1024",
+         "window-prefill1024", "afm-full-prefill1024",
+         "afm-window-prefill1024"])
 def test_tiles_body_compiles_at_the_cells_shapes(one_chip, kind, B, T):
     """The tiles body at ``mimo-v2.5``'s published shapes: ``Hkv x 192``
     K pages beside ``Hkv x 128`` V pages, 16 (full) and 8 (window) query
@@ -816,7 +823,9 @@ def test_tiles_body_compiles_at_the_cells_shapes(one_chip, kind, B, T):
     rows leave room for: ONE trip a row on a ring of 128 columns, computed
     over the 16-page parts a row's pages fill (PR 48; five trips of 16
     before); its 512-token
-    chunk's 256-row tile reaches 73 and keeps 16 pages a trip (PR 47)."""
+    chunk's 256-row tile reaches 73 and keeps 16 pages a trip (PR 47).  A
+    chunk of 1,024, the model's longest (PR 50), has the same tiles: 256
+    query rows whatever the chunk, a ring of 128 columns on both cells."""
     from mxnet_tpu.ops import paged_attention as pa
     from mxnet_tpu.serving.generation.kv_cache import ring_width
 
@@ -858,18 +867,20 @@ def _hybrid_cell(sds, n_layers=3):
     return model, params
 
 
-@pytest.mark.parametrize("S,T,ring", [(128, 1, 16), (1, 512, 64)],
-                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("S,T,ring", [(128, 1, 16), (1, 512, 64),
+                                      (1, 1024, 128)],
+                         ids=["decode", "prefill", "prefill1024"])
 def test_hybrid_step_program_compiles_at_the_cells_shapes(one_chip,
                                                           monkeypatch, S, T,
                                                           ring):
-    """``gen_decode`` (128 rows) and ``gen_prefill`` (a 512-token chunk) as
+    """``gen_decode`` (128 rows) and ``gen_prefill`` (a 512-token chunk and
+    the model's longest, 1,024, on a ring twice as wide) as
     the service dispatches them, at depth 3 (the dense layer with full
     attention and two window expert layers: layers repeat): both kinds'
     pools are updated in place, a table a kind, every attention call is
     named for its kind and phase, the counts come back."""
     from mxnet_tpu.serving.generation import programs as gp
-    from mxnet_tpu.serving.generation.kv_cache import PagedKVCache
+    from mxnet_tpu.serving.generation.kv_cache import window_blocks
 
     monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
@@ -877,8 +888,13 @@ def test_hybrid_step_program_compiles_at_the_cells_shapes(one_chip,
     full, window = model.cache_spec()["kinds"]
     assert [w for _, w in full["pools"]] == [768, 512]
     assert [w for _, w in window["pools"]] == [1536, 1024]
+    # the window kind as the cell holds it: 128 slots at rest and the one
+    # row being prefilled in the model's longest chunks
+    held = 1 + 128 * window_blocks(128, 1, BS) \
+        + window_blocks(128, model.longest_chunk, BS)
+    assert held == 1354
     pools = tuple(sds((k["n_layers"], nb, BS, w), jnp.bfloat16)
-                  for k, nb in ((full, 4096), (window, 1322))
+                  for k, nb in ((full, 4096), (window, held))
                   for _, w in k["pools"])
     fn = jax.jit(functools.partial(gp._model_step, model=model,
                                    attention_kernel="paged"),
@@ -899,14 +915,16 @@ def test_hybrid_step_program_compiles_at_the_cells_shapes(one_chip,
     assert len(re.findall(rf"%_gmm_call[\w.\-]* = f32\[{rows},", text)) == 6
     assert f"f32[{S * T * 8},4096]" not in text
     _the_walk_is_named_and_copies_no_expert(text, 4096, 2048)
-    for shape in (f"= bf16[1,4096,{BS},768]", f"= bf16[2,1322,{BS},1536]"):
+    for shape in (f"= bf16[1,4096,{BS},768]", f"= bf16[2,{held},{BS},1536]"):
         makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
                   for ln in text.splitlines() if shape in ln}
         assert makers <= _IN_PLACE, (shape, makers)
     # every weight reaches its product as stored: ``wq`` (100 MB a layer)
     # and ``wk`` are multiplied flat and the RESULT is cut into heads of 192
     _no_argument_copies(text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    mem = compiled.memory_analysis()
+    print(f"hybrid S={S} T={T} temp {mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < 1.0e9
 
 
 def _afmoe_cell(sds):
@@ -927,14 +945,16 @@ def _afmoe_cell(sds):
     return model, params, c["service"]
 
 
-@pytest.mark.parametrize("T", [1, 512], ids=["decode", "prefill"])
+@pytest.mark.parametrize("T", [1, 512, 1024],
+                         ids=["decode", "prefill", "prefill1024"])
 def test_afmoe_step_program_compiles_at_the_cells_shapes(one_chip,
                                                          monkeypatch, T):
     """``trinity-mini``'s ``gen_decode`` (64 rows) and ``gen_prefill`` (a
-    512-token chunk) as the service dispatches them, at depth 4: both
+    512-token chunk and the model's longest, 1,024) as the service
+    dispatches them, at depth 4: both
     kinds' calls take the tiles body and are named for kind and phase, the
     window kind's on a ring of 128 columns; the pools of the cell's own
-    sizes (16,000 and 4,306 blocks of 32) are updated in place; the held
+    sizes (16,000 and 4,322 blocks of 32) are updated in place; the held
     rows' tiles are 2 x S x T x 8 / 8 rows; the temporaries of a chunk
     over the whole vocabulary's head stay under 1.5 GB."""
     from mxnet_tpu.serving.generation import programs as gp
@@ -948,8 +968,8 @@ def test_afmoe_step_program_compiles_at_the_cells_shapes(one_chip,
     bs = service["block_size"]
     full, window = model.cache_spec()["kinds"]
     held = 1 + service["max_slots"] * window_blocks(2048, 1, bs) \
-        + window_blocks(2048, 512, bs)
-    assert held == 4306 and ring_width(2048, T, bs) == 128
+        + window_blocks(2048, model.longest_chunk, bs)
+    assert held == 4322 and ring_width(2048, T, bs) == 128
     pools = tuple(sds((k["n_layers"], nb, bs, w), jnp.bfloat16)
                   for k, nb in ((full, service["num_blocks"]), (window, held))
                   for _, w in k["pools"])
@@ -974,7 +994,9 @@ def test_afmoe_step_program_compiles_at_the_cells_shapes(one_chip,
                   for ln in text.splitlines() if shape in ln}
         assert makers <= _IN_PLACE, (shape, makers)
     _no_argument_copies(text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    mem = compiled.memory_analysis()
+    print(f"afmoe T={T} temp {mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < 1.5e9
 
 
 # -- brumby-14b: power retention over a slot's state (PR 40) ----------------

@@ -2,6 +2,7 @@
 prefill, the mp axis, the model seam, the batch builder and the program
 kinds, the step in flight.  The service's lifecycle, its cache and its
 samplers: tests/test_generation.py."""
+import dataclasses
 import time
 
 import jax
@@ -97,6 +98,116 @@ def test_chunked_prefill_zero_postwarmup_compiles(params, monkeypatch):
     svc.stop()
     monkeypatch.delenv("TPUMX_FREEZE_COMPILES")
     assert all(v["misses"] == 1 for v in stats.values())
+
+
+# -- the model's longest chunk is the ladder's top rung (PR 50) ---------------------
+@dataclasses.dataclass(frozen=True)
+class _ChunkedLM(tr.TransformerLM):
+    """GPT-2's block naming the longest chunk its prefill program takes,
+    as the expert models do."""
+    longest_chunk: int = 24
+
+
+def _chunked_service(params, model=None, **kw):
+    kw.setdefault("seq_buckets", [8, 16, 56])
+    return GenerationService(params, model or _ChunkedLM(CFG), _gc(**kw),
+                             start=False)
+
+
+@pytest.mark.parametrize("what, args, chunks", [
+    ("under", dict(prompt_len=20), [(0, 16, 16), (16, 4, 8)]),
+    ("at", dict(prompt_len=24), [(0, 24, 24)]),
+    ("over", dict(prompt_len=50), [(0, 24, 24), (24, 24, 24), (48, 2, 8)]),
+    ("a prefix hit's suffix", dict(prompt_len=50, start=16),
+     [(16, 24, 24), (40, 8, 8), (48, 2, 8)]),
+    ("a re-prefill", dict(prompt_len=60, force_chunked=True),
+     [(0, 24, 24), (24, 24, 24), (48, 8, 8), (56, 4, 8)]),
+])
+def test_the_models_longest_chunk_is_the_top_rung(params, what, args,
+                                                   chunks):
+    """A ``longest_chunk`` above every configured rung but the top is
+    itself a rung: every walk cuts in it, and warm-up knows its (T, W)."""
+    svc = _chunked_service(params, preemption=True, prefix_cache=True)
+    assert svc._seq_buckets == [8, 16, 24]
+    assert svc._prompt_buckets == [8, 16, 56]
+    plan = svc._chunk_plan(**args)
+    assert [c[:3] for c in plan] == chunks, what
+    assert {(tb, w) for _, _, tb, w in plan} <= set(svc._prefill_signatures())
+    svc.stop()
+
+
+@pytest.mark.parametrize("longest_chunk, rungs", [
+    (16, [8, 16]),          # a configured rung: the ladder up to it
+    (24, [8, 16, 24]),      # between two: a rung of its own
+    (4, [4]),               # under every rung: the one chunk the model takes
+    (56, [8, 16, 56]),      # the ladder's top
+    (512, [8, 16, 56]),     # past the longest prompt: no rung to add
+])
+def test_the_ladder_follows_the_models_attribute(params, longest_chunk,
+                                                 rungs):
+    model = _ChunkedLM(CFG, longest_chunk=longest_chunk)
+    svc = _chunked_service(params, model)
+    assert svc._seq_buckets == rungs == svc.stats()["seq_buckets"]
+    unchunked = _chunked_service(params, model, chunked_prefill=False)
+    assert unchunked._seq_buckets == [8, 16, 56]
+    svc.stop()
+    unchunked.stop()
+
+
+def test_a_model_that_names_no_chunk_keeps_the_configured_ladder(params):
+    """``TransformerLM`` has no ``longest_chunk``: the rungs, the plans and
+    the warm-up set are the configured ladder's."""
+    svc = _chunked_service(params, CFG, preemption=True)
+    assert svc._seq_buckets == svc._prompt_buckets == [8, 16, 56]
+    assert [c[:3] for c in svc._chunk_plan(50)] == [
+        (0, 16, 16), (16, 16, 16), (32, 16, 16), (48, 2, 8)]
+    assert svc._chunk_plan(56) == [(0, 56, 56, blocks_for(56, 8))]
+    assert [c[:3] for c in svc._chunk_plan(60, force_chunked=True)] == [
+        (0, 56, 56), (56, 4, 8)]
+    assert {tb for tb, _ in svc._prefill_signatures()} == {8, 16, 56}
+    svc.stop()
+
+
+def test_the_longest_chunk_compiles_nothing_after_warmup(params, monkeypatch):
+    """Prompts under, at and over the model's chunk, a prefix hit's suffix
+    and a preempted row's re-prefill run under TPUMX_FREEZE_COMPILES=1
+    with one miss a signature, tokens equal to the oracle's, and the
+    host's counts of tokens and chunks agree with the plans."""
+    svc = _chunked_service(params, max_slots=2, num_blocks=12,
+                           preemption=True, prefix_cache=True)
+    warmed = svc.warmup()
+    assert warmed == len(svc.compile_stats())
+    assert any(k[0] == "gen_prefill" and k[1][0][1] == (1, 24)
+               for k in svc.compile_stats())
+    monkeypatch.setenv("TPUMX_FREEZE_COMPILES", "1")
+    rs = np.random.RandomState(50)
+    prompts = [rs.randint(0, CFG.vocab, n) for n in (20, 24, 50)]
+    svc.start()
+    for p in prompts:
+        assert svc.generate(p, max_new_tokens=4, timeout=180) \
+            == greedy_oracle(params, p, 4)
+    counts = svc.stats()["counts"]
+    plans = [svc._chunk_plan(len(p)) for p in prompts]
+    assert counts["prefill_tokens"] == 20 + 24 + 50
+    assert counts["prefill_chunks"] == sum(map(len, plans)) == 2 + 1 + 3
+    # the same long prompt again: its full blocks are cached
+    assert svc.generate(prompts[2], max_new_tokens=4, timeout=180) \
+        == greedy_oracle(params, prompts[2], 4)
+    after = svc.stats()["counts"]
+    hit = svc._chunk_plan(50, start=48)
+    assert after["prefix_hits"] == 1
+    assert after["prefill_tokens"] - counts["prefill_tokens"] == 2
+    assert after["prefill_chunks"] - counts["prefill_chunks"] == len(hit) == 1
+    # two rows that outgrow the pool: one is preempted and prefilled anew
+    hs = [svc.submit(rs.randint(0, CFG.vocab, 30), max_new_tokens=24)
+          for _ in range(2)]
+    for h in hs:
+        assert len(h.result(180)) == 24
+    stats = svc.compile_stats()
+    assert svc.stats()["counts"]["preempted"] >= 1
+    svc.stop()
+    for key, st in stats.items():
+        assert st["misses"] == 1, f"recompile at {key}: {st}"
 
 
 def test_generation_mp_axis_matches_single_device(params):

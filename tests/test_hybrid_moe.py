@@ -56,8 +56,9 @@ def _config(**over):
 
 
 def _model(cfg=None, **kw):
+    kw.setdefault("longest_chunk", 16)
     return hm.HybridMoeLM(cfg or _config(), max_len=MAX_LEN,
-                          kv_dtype=jnp.float32, longest_chunk=16, **kw)
+                          kv_dtype=jnp.float32, **kw)
 
 
 # the dense layer and two window expert layers: what a test that needs a
@@ -120,6 +121,21 @@ def svc7(params):
     every test that does not need its own (a test that needs the engine
     running starts it, and leaves it idle)."""
     svc = _service(params)
+    yield svc
+    svc.stop(drain=False, timeout=30)
+
+
+# a chunk no configured rung names, between 16 and the ladder's top: the
+# model's own longest chunk is then the top rung of every walk
+LONG = 32
+
+
+@pytest.fixture(scope="module")
+def svc7_long(params):
+    """``svc7`` with a model whose prefill program takes chunks of 32:
+    rungs 8, 16 and 32, a ring and a window pool for the longer chunk."""
+    svc = _service(params, model=_model(longest_chunk=LONG))
+    assert svc._seq_buckets == [8, 16, LONG]
     yield svc
     svc.stop(drain=False, timeout=30)
 
@@ -196,8 +212,9 @@ def _logits_through_the_cache(svc, seq, n_decode=4):
 
 
 def _check_logits_through_the_cache(svc, p, c, kernel, plen, part):
-    """Prefill through the chunk plan (every leftover length; past 8 + 16
-    positions window blocks have been freed and reused), then greedy decode
+    """Prefill through the chunk plan (every leftover length; past the
+    window and a chunk, 8 + 16 positions at ``svc7``'s rungs, window blocks
+    have been freed and reused), then greedy decode
     steps through both cache kinds, against the reference's full forward
     over the whole sequence; ``part`` says which of the two is compared (a
     program of the interpreted kernel is most of a minute to compile, and
@@ -213,13 +230,14 @@ def _check_logits_through_the_cache(svc, p, c, kernel, plen, part):
         np.testing.assert_allclose(
             last, _ref_logits(p, toks, len(toks) - 1, c=c)[0], atol=TOL,
             rtol=0)
-    if plen > WIN + 16:
+    if plen > WIN + svc._seq_buckets[-1]:
         assert svc.stats()["counts"]["window_blocks_freed"] > freed
 
 
 @pytest.mark.parametrize("part", ["prefill", "decode"])
 @pytest.mark.parametrize("widths,plen", [
     ("tiny", 3), ("tiny", 16), ("tiny", 37), ("tiny", 70),
+    ("long", 32), ("long", 37), ("long", 70), ("long", 100),
     ("head192", 16), ("head192", 37)])
 def test_chunked_prefill_then_decode_match_reference_logits(request, widths,
                                                             plen, part):
@@ -227,9 +245,12 @@ def test_chunked_prefill_then_decode_match_reference_logits(request, widths,
     layers of the published pattern (the tiles body:
     tests/test_hybrid_moe_kernel.py), and at the published HEAD — 192 =
     64 rotary + 128, values 128 — on three layers, a chunk and a decode
-    step: the flat products' cut into heads and the rotary cut."""
-    svc, p, c = (("svc7", "params", C) if widths == "tiny"
-                 else ("svc192", "params192", C192))
+    step: the flat products' cut into heads and the rotary cut.  ``long``:
+    the same 7 layers cut at the model's longer chunk (32: one chunk, one
+    and a leftover, two and a leftover past window + chunk, three)."""
+    svc, p, c = {"tiny": ("svc7", "params", C),
+                 "long": ("svc7_long", "params", C),
+                 "head192": ("svc192", "params192", C192)}[widths]
     _check_logits_through_the_cache(
         request.getfixturevalue(svc), request.getfixturevalue(p), c,
         "gather", plen, part)
@@ -365,6 +386,39 @@ def test_service_generation_matches_reference_greedy(params, svc7, plen,
     prompt = np.random.default_rng(100 + plen).integers(0, V, plen)
     assert svc7.generate(prompt, max_new_tokens=n_new, timeout=300) \
         == _ref_greedy(params, prompt, n_new)
+
+
+@pytest.mark.parametrize("plen,n_new", [(37, 6), (70, 30), (100, 12)])
+def test_a_longer_chunk_serves_the_shorter_chunks_tokens_and_logits(
+        params, svc7, svc7_long, plen, n_new):
+    """The same prompt cut at 32 and at 16: the chunks differ, the last
+    position's logits agree within the tolerance both hold against the
+    reference, and the services' greedy tokens are the reference's; the
+    longer chunk's ring and window pool are the wider ones, and its row
+    frees the blocks behind its window as it goes."""
+    long, short = svc7_long, svc7
+    prompt = [int(t) for t in
+              np.random.default_rng(200 + plen).integers(0, V, plen)]
+    plans = [[c[:3] for c in s._chunk_plan(plen)] for s in (long, short)]
+    assert plans[0] != plans[1] and plans[0][0] == (0, LONG, LONG)
+    assert {tb for _, _, tb in plans[1]} <= {8, 16}
+    wl, ws = long._cache.kinds[1], short._cache.kinds[1]
+    assert wl.num_blocks - ws.num_blocks == \
+        window_blocks(WIN, LONG, BS) - window_blocks(WIN, 16, BS) == 4
+    assert long._ring_tables([], 1, LONG)[0].shape == (1, 16)
+    assert short._ring_tables([], 1, 16)[0].shape == (1, 8)
+    freed = long.stats()["counts"]["window_blocks_freed"]
+    (_, at_long), = _logits_through_the_cache(long, prompt, 0)
+    (_, at_short), = _logits_through_the_cache(short, prompt, 0)
+    np.testing.assert_allclose(at_long, at_short, atol=TOL, rtol=0)
+    if plen > WIN + LONG:
+        assert long.stats()["counts"]["window_blocks_freed"] > freed
+    assert wl.allocator.num_used == 0 == ws.allocator.num_used
+    long.start()
+    short.start()
+    want = _ref_greedy(params, prompt, n_new)
+    assert long.generate(prompt, max_new_tokens=n_new, timeout=300) == want
+    assert short.generate(prompt, max_new_tokens=n_new, timeout=300) == want
 
 
 def test_admitting_passes_carry_first_tokens_and_serve_the_reference(

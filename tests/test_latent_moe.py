@@ -69,6 +69,11 @@ C192 = dict(C, num_attention_heads=2, qk_nope_head_dim=128,
             qk_rope_head_dim=64, v_head_dim=128)
 MODEL192 = lm.LatentMoeLM(_config(C192), max_len=MAX_LEN,
                           kv_dtype=jnp.float32, longest_chunk=64)
+# a chunk no configured rung names, between 64 and the ladder's top: the
+# model's own longest chunk is then the top rung of every walk
+LONG = 96
+MODEL_LONG = lm.LatentMoeLM(CFG, max_len=MAX_LEN, kv_dtype=jnp.float32,
+                            longest_chunk=LONG)
 
 
 @pytest.fixture(scope="module")
@@ -138,7 +143,8 @@ def _decode(svc, row, tok, pos, blocks, S=4):
 @pytest.mark.parametrize("widths,kernel,plen", [
     ("tiny", kernel, plen) for plen in (3, 16, 37, 70)
     for kernel in ("gather", "paged")] + [
-    ("head192", "gather", 16), ("head192", "gather", 37)])
+    ("head192", "gather", 16), ("head192", "gather", 37),
+    ("long", "gather", 100), ("long", "gather", 115), ("long", "paged", 100)])
 def test_chunked_prefill_then_decode_match_reference_logits(
         request, monkeypatch, widths, kernel, plen):
     """Prefill through the chunk plan (every leftover length), then greedy
@@ -147,12 +153,17 @@ def test_chunked_prefill_then_decode_match_reference_logits(
     the absorbed sums over the gathered pages; ``paged``: the absorbed
     kernel (interpreted).  ``head192``: the published head sizes, 128 + 64
     rotary and values of 128 — the flat product's cut into heads, its
-    rotary cut and ``wkvb``'s halves, a chunk and a decode step."""
-    p, c, model = (("params", C, MODEL) if widths == "tiny"
-                   else ("params192", C192, MODEL192))
+    rotary cut and ``wkvb``'s halves, a chunk and a decode step.  ``long``:
+    the model's longer chunk (96, a rung of its own above the configured
+    64), then a leftover."""
+    p, c, model = {"tiny": ("params", C, MODEL),
+                   "long": ("params", C, MODEL_LONG),
+                   "head192": ("params192", C192, MODEL192)}[widths]
     params = request.getfixturevalue(p)
     svc = _service(params, monkeypatch, kernel, model=model)
     assert svc.stats()["decode_kernel"] == kernel
+    assert svc._seq_buckets == ([16, 64, LONG] if widths == "long"
+                                else [16, 64])
     seq = [int(t) for t in np.random.default_rng(plen).integers(0, V, plen)]
     blocks = svc._alloc_reclaiming(blocks_for(plen + 5, 8))
     nxt, last = _prefill(svc, seq, blocks)
@@ -471,15 +482,44 @@ def served(params):
     svc.stop(drain=False, timeout=30)
 
 
-@pytest.mark.parametrize("plen,n_new", [(3, 4), (16, 8), (23, 13), (70, 6)])
-def test_service_generation_matches_reference_greedy(params, served, plen,
-                                                     n_new):
+@pytest.fixture(scope="module")
+def served_long(params):
+    svc = _service(params, model=MODEL_LONG)
+    svc.start()
+    yield svc
+    svc.stop(drain=False, timeout=30)
+
+
+@pytest.mark.parametrize("chunk,plen,n_new", [
+    (64, 3, 4), (64, 16, 8), (64, 23, 13), (64, 70, 6),
+    (LONG, 70, 6), (LONG, 100, 8), (LONG, 115, 6)])
+def test_service_generation_matches_reference_greedy(request, params, chunk,
+                                                     plen, n_new):
     """Whole generations through submit / the scheduler / the step in
     flight / the latent cache, token for token (float32 on both sides; the
-    seeds give no tie)."""
+    seeds give no tie).  Cut at the model's longer chunk the tokens are
+    the same reference's."""
+    served = request.getfixturevalue("served" if chunk == 64
+                                     else "served_long")
     prompt = np.random.default_rng(100 + plen).integers(0, V, plen)
     assert served.generate(prompt, max_new_tokens=n_new, timeout=120) \
         == _ref_greedy(params, prompt, n_new)
+
+
+@pytest.mark.parametrize("plen", [100, 115])
+def test_a_longer_chunk_gives_the_shorter_chunks_logits(served, served_long,
+                                                        plen):
+    """The same prompt cut at 96 and at 64: the plans differ, and the last
+    position's logits agree within the tolerance both hold against the
+    reference."""
+    seq = [int(t) for t in np.random.default_rng(plen).integers(0, V, plen)]
+    lasts = []
+    for svc, first in ((served_long, LONG), (served, 64)):
+        assert svc._chunk_plan(plen)[0][:3] == (0, first, first)
+        blocks = svc._alloc_reclaiming(blocks_for(plen + 1, 8))
+        lasts.append(_prefill(svc, seq, blocks)[1])
+        svc._cache.allocator.free(blocks)
+    np.testing.assert_allclose(lasts[0], lasts[1], atol=TOL, rtol=0)
 
 
 def test_the_programs_counts_reach_stats(params):
