@@ -1190,3 +1190,104 @@ def test_sambay_step_program_compiles_at_the_cells_shapes(one_chip,
     _no_argument_copies(text)
     assert "ssm_decode_rows" in model.counters
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# -- granite-4.0-h-micro: a matrix state larger than the paged K and V (PR 51)
+
+def _granite_cell(sds, n_blocks=8192, slots=64):
+    """The published widths at FULL depth (40 layers: the memory read
+    below is the cell's) with the cell's pools: 8,192 blocks of 32 of the
+    four attention layers' K and V (a table 1,024 wide) and the states of
+    64 slots and the scratch, 5.21 GB."""
+    from mxnet_tpu.parallel import granite_hybrid as gh
+
+    cfg = gh.GraniteHybridConfig()
+    model = gh.GraniteHybridLM(cfg, max_len=32768)
+    params = {k: sds(s, jnp.bfloat16)
+              for k, s in gh.granite_hybrid_param_shapes(cfg).items()}
+    full, state = model.cache_spec()["kinds"]
+    pools = tuple(sds((full["n_layers"], n_blocks, 32, w), jnp.bfloat16)
+                  for _, w in full["pools"]) \
+        + tuple(sds((state["n_layers"], slots + 1) + s, jnp.float32)
+                for _, s in state["state"])
+    return model, params, pools
+
+
+@pytest.mark.parametrize("B,T", [(64, 1), (1, 128), (1, 1024)],
+                         ids=["decode", "prefill128", "prefill1024"])
+def test_ssd_kernels_compile_at_the_cells_shapes(one_chip, monkeypatch, B, T):
+    """``_ssd_call_decode`` (64 rows, a row's state of 136 x 4,096 float32 a
+    block) and ``_ssd_call_t<T>_prefill`` (rows x 8 lane tiles of 512 x
+    sub-chunks of 128 positions, a head's column of the running decay
+    spread over lanes inside the kernel) at ``granite-4.0-h-micro``'s
+    widths: the pool is updated in place, never copied."""
+    from mxnet_tpu.ops import ssd
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    f32 = jnp.float32
+    H, di, N = 64, 4096, 128
+
+    def fn(step, x, Bm, Cm, A, fresh, pool, slots, conv):
+        old = ssd.conv_state(pool, 3, slots, 4, 2 * N, kernel=True)
+        return ssd.ssd(step, x + old[:, :1, :di], Bm, Cm, A, fresh, pool,
+                       slots, conv, layer=3, kernel=True)
+
+    text = jax.jit(fn, donate_argnums=(6,)).lower(
+        sds((B, T, H), f32), sds((B, T, di), f32), sds((B, T, N), f32),
+        sds((B, T, N), f32), sds((H,), f32), sds((B,), jnp.bool_),
+        sds((36, 65, N + 8, di), f32), sds((B,), jnp.int32),
+        sds((B, 3, di + 2 * N), f32)).compile().as_text()
+    assert "_ssd_call_conv_decode" in text
+    assert ("_ssd_call_decode" if T == 1 else f"_ssd_call_t{T}_prefill") \
+        in text
+    assert not [ln for ln in text.splitlines()
+                if "f32[36,65,136,4096" in ln and " copy(" in ln]
+
+
+@pytest.mark.parametrize("kind,S,T", [
+    ("gen_decode", 64, 1), ("gen_prefill", 1, 1024)],
+    ids=["decode", "final1024"])
+def test_granite_step_program_compiles_at_the_cells_shapes(one_chip,
+                                                           monkeypatch, kind,
+                                                           S, T):
+    """The cell's decode program (64 rows) and its longest prefill program
+    (a prompt's last chunk of 1,024 positions: every layer, the head at one
+    position) as the service dispatches them, at the published widths and
+    depth over the cell's pools: both kinds' pools updated in place, every
+    weight read as stored — the tied embedding as the head's weight among
+    them —, XLA never indexing the 5.2 GB state pool itself, and the peak
+    of device memory under the chip's 15.75 GB."""
+    from mxnet_tpu.serving.generation import programs as gp
+
+    monkeypatch.setenv("TPUMX_PALLAS_INTERPRET", "0")
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    model, params, pools = _granite_cell(sds)
+    i32 = jnp.int32
+    args = (params, pools, sds((S, T), i32), sds((S, T), i32), sds((S,), i32),
+            (sds((S, 1024), i32), sds((S, 1), i32)), sds((S,), jnp.uint32),
+            sds((S,), jnp.uint32), sds((S,), jnp.float32), sds((S,), i32),
+            sds((S,), jnp.float32))
+    compiled = jax.jit(functools.partial(
+        gp._model_step, model=model, attention_kernel="paged"),
+        donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    phase = "decode" if T == 1 else "prefill"
+    assert text.count("_ssd_call_decode" if T == 1
+                      else f"_ssd_call_t{T}_prefill") >= 36
+    assert text.count(f"_ssd_call_conv_{phase}") >= 36
+    assert f"_t{T}_full_{phase}" in text
+    for shape in ("bf16[4,8192,32,512]", "f32[36,65,136,4096]"):
+        makers = {re.search(r"\} ([a-z\-]+)\(", ln).group(1)
+                  for ln in text.splitlines() if f"= {shape}" in ln}
+        assert makers <= _IN_PLACE | {"get-tuple-element"}, \
+            (shape, makers)
+    _no_argument_copies(text)
+    assert "ssd_decode_rows" in model.counters
+    mem = compiled.memory_analysis()
+    # arguments (weights 6.38 GB, K and V 2.15, states 5.21) and what the
+    # program needs besides them; the donated pools come back in place
+    peak = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 13.7e9 < peak < 15.75e9, peak
+    assert mem.temp_size_in_bytes < 1.0e9

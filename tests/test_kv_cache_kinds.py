@@ -217,3 +217,62 @@ def test_a_wide_window_slides_while_its_row_decodes():
     svc._drop_windows(row)
     assert kind.allocator.num_used == 0
     svc.stop(drain=False, timeout=30)
+
+
+# -- granite-4.0-h-micro: a state kind LARGER than the paged kind beside it ---
+G_SLOTS = 64
+
+
+def _matrix_state_cache(num_blocks, slots, n_attn=4, n_mamba=36):
+    """The published widths with the paged pools' lanes and the blocks cut
+    (nothing of this size is made on the test's CPU but the spec)."""
+    from mxnet_tpu.ops.ssd import state_shapes
+
+    return dict(num_blocks=num_blocks, block_size=32, dtype=jnp.bfloat16,
+                window_rows=(slots, 1024), kinds=(
+        dict(name="full", n_layers=n_attn, pools=(("k", 512), ("v", 512)),
+             writers=(5, 15, 25, 35)[:n_attn],
+             readers=(5, 15, 25, 35)[:n_attn]),
+        dict(name="state", n_layers=n_mamba, dtype=jnp.float32,
+             state=state_shapes(64, 64, 128, 4),
+             writers=tuple(range(n_mamba)), readers=tuple(range(n_mamba)))))
+
+
+@pytest.mark.parametrize("what", ["bytes a slot", "slot 0 the scratch",
+                                  "larger than the paged kind"])
+def test_a_matrix_state_kind_behind_a_paged_kind(what):
+    """Mamba-2's kind: ONE pool a layer of (128 + 8) x 4,096 float32 — a
+    head's matrix state, the convolution's 3 x 4,352 inputs in the last
+    sublane tile —, sized by slots, index 0 the scratch idle rows point at;
+    and the LARGEST thing in a cache that also has a paged kind: 80.2 MB a
+    slot where a token's K and V are 8,192 B."""
+    if what == "bytes a slot":
+        from mxnet_tpu.ops.ssd import state_shapes
+
+        ((name, shape),) = state_shapes(64, 64, 128, 4)
+        assert (name, shape) == ("ssd", (136, 4096))
+        assert 36 * 136 * 4096 * 4 == 80216064       # as stored
+        assert 36 * (64 * 64 * 128 + 3 * 4352) * 4 == 77377536
+        return
+    # two Mamba-2 layers and two slots of the published widths: 13 MB
+    cache = PagedKVCache(**_matrix_state_cache(8, 2, n_attn=1, n_mamba=2))
+    full, state = cache.kinds
+    assert (full.state, state.state) == (False, True)
+    assert [tuple(p.shape) for p in cache.pools] == [
+        (1, 8, 32, 512), (1, 8, 32, 512), (2, 3, 136, 4096)]
+    assert [str(p.dtype) for p in cache.pools] == ["bfloat16"] * 2 \
+        + ["float32"]
+    if what == "slot 0 the scratch":
+        assert state.num_blocks == 3 and state.allocator.num_free == 2
+        got = [state.allocator.allocate(1)[0] for _ in range(2)]
+        assert sorted(got) == [1, 2] and state.allocator.allocate(1) is None
+        assert state.blocks_for(1) == state.blocks_for(32768) == 1
+        assert state.allocator is not cache.allocator
+        return
+    # at the cell's sizes (64 slots, 8,192 blocks of 32): 5.21 GB of state
+    # beside 2.15 GB of K and V — slots are what admission runs out of
+    slot = int(cache.pools[2].nbytes) // state.num_blocks // 2 * 36
+    block = sum(int(p.nbytes) for p in cache.pools[:2]) // full.num_blocks * 4
+    assert (slot, block) == (80216064, 32 * 8192)
+    assert (G_SLOTS + 1) * slot == 5214044160 > 8192 * block == 2147483648
+    assert slot // (block // 32) == 9792     # a slot is ~9.8 k tokens of K/V
